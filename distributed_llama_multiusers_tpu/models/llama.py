@@ -25,13 +25,13 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from ..formats.model_file import HiddenAct
 from ..ops.activations import gelu, silu
-from ..ops.linear import matmul, shared_q80_acts
+from ..ops.linear import matmul, pallas_kernel_active, shared_q80_acts
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope
-from ..jax_compat import shard_map
 from .config import LlamaConfig
 
 
@@ -397,12 +397,22 @@ def llama_forward(
 
         # shared predicate with the runtime_setup startup log
         use_q80_sync = q80_sync_engages(h_cfg, dict(mesh.shape))
+    from ..quants.packed import PackedQ40
+
     use_ring_sync = False
+    # pure-TP mesh + Q40 planes + the kernel: every matmul runs the kernel
+    # on its LOCAL shard under shard_map (ops/ring_collective.py) — the
+    # GSPMD custom_partitioning wrapper behind ``matmul`` cannot compile
+    # for real chips (libtpu has no custom-call partitioner)
+    tp_local = False
     if mesh is not None:
         from ..ops.ring_collective import (
+            pure_tp,
             ring_sync_engages,
             ring_sync_matmul,
             ring_sync_supported,
+            tp_reduced_matmul,
+            tp_sliced_matmul,
         )
 
         # ring-overlapped TP sync (default on, DLLAMA_RING_SYNC=off escape
@@ -410,11 +420,23 @@ def llama_forward(
         # through the chunked ring instead of GSPMD's post-matmul
         # all-reduce; with q80_sync the gather half ships the Q80 wire
         use_ring_sync = ring_sync_engages(h_cfg, dict(mesh.shape))
+        tp_local = pure_tp(dict(mesh.shape)) and pallas_kernel_active()
+
+    def shard_local(w) -> bool:
+        return tp_local and isinstance(w, PackedQ40) and w.packed.ndim == 2
+
+    def sliced_matmul(y, w):
+        """A column-parallel (row-sliced) matmul — wq/wk/wv/w1/w3/wcls: the
+        output stays sharded on d_out, no sync."""
+        if shard_local(w):
+            return tp_sliced_matmul(y, w, mesh)
+        return matmul(y, w)
 
     def synced_matmul(y, w):
         """A row-parallel (col-sliced) wo/w2 matmul plus its TP sync:
         ring-overlapped (optionally Q80-wire), Q80 psum_scatter+gather, or
-        the plain GSPMD matmul whose all-reduce XLA inserts."""
+        the plain psum (shard-local on a pure-TP mesh, else the GSPMD
+        matmul whose all-reduce XLA inserts)."""
         if use_ring_sync:
             d_out = w.d_out if hasattr(w, "d_out") else w.shape[-1]
             if ring_sync_supported(d_out, mesh.shape["tp"], use_q80_sync):
@@ -424,18 +446,17 @@ def llama_forward(
                 return out if use_q80_sync else maybe_qdq(out)
         if use_q80_sync:
             return q80_sync_matmul(y, w, mesh)
+        if shard_local(w):
+            return maybe_qdq(tp_reduced_matmul(y, w, mesh))
         return maybe_qdq(matmul(y, w))
 
     # Shared Q80 activation operands (ops/pallas_q40.Q80Acts): wq/wk/wv
     # consume one normed x and w1/w3 another, so each site builds its
     # activation-quant + relayout operands ONCE instead of once per
     # matmul (one build feeds three dots at the attention site, two at
-    # the FFN site). Single-chip only: under a mesh the matmuls go
-    # through the GSPMD custom_partitioning wrapper, which takes raw
+    # the FFN site). Single-chip only: under a mesh the matmuls take raw
     # activations. shared_q80_acts itself no-ops when the Pallas kernel
     # is off, so every other path sees the plain activation.
-    from ..quants.packed import PackedQ40
-
     share = mesh is None and isinstance(
         getattr(params.layers, "wq", None), PackedQ40
     )
@@ -475,9 +496,9 @@ def llama_forward(
 
         y = rms_norm(x, lp.rms_att, eps)
         yq = share_q80(maybe_qdq(y))  # one operand build for wq/wk/wv
-        q = _maybe_bias(matmul(yq, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
-        k = _maybe_bias(matmul(yq, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
-        v = _maybe_bias(matmul(yq, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
+        q = _maybe_bias(sliced_matmul(yq, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
+        k = _maybe_bias(sliced_matmul(yq, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
+        v = _maybe_bias(sliced_matmul(yq, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
 
         q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
         k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
@@ -547,8 +568,8 @@ def llama_forward(
             x = x + maybe_qdq(d)
         else:
             yqs = share_q80(yq)  # one operand build for w1/w3
-            g = act_fn(matmul(yqs, lp.w1))
-            u = matmul(yqs, lp.w3)
+            g = act_fn(sliced_matmul(yqs, lp.w1))
+            u = sliced_matmul(yqs, lp.w3)
             x = x + synced_matmul(maybe_qdq(g * u), lp.w2)
 
         return x, (k_cache, v_cache)
@@ -556,7 +577,7 @@ def llama_forward(
     x, (new_k, new_v) = jax.lax.scan(layer_step, x, (params.layers, cache.k, cache.v))
 
     y = rms_norm(x, params.rms_final, eps)
-    logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)  # [B, T, vocab]
+    logits = sliced_matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)  # [B, T, vocab]
     # wcls may be padded past vocab_size for the slab kernel's wide tiles
     # (quants/packed.pad_packed_d_out); identity slice otherwise
     out_cache = (

@@ -334,8 +334,8 @@ def quantize_params(params: LlamaParams, to_device: bool = True) -> LlamaParams:
     """Quantize a dense params pytree to PackedQ40 layer matmuls + wcls
     (through the bit-exact Q40 encoder). Fully host-side for numpy inputs —
     combine with ``params_from_random(..., to_device=False)`` so multi-GB
-    dense weights never cross the host<->device link (which can be a slow
-    tunnel); with ``to_device=False`` the packed planes also stay numpy for
+    dense weights never cross the host<->device link; with
+    ``to_device=False`` the packed planes also stay numpy for
     the caller to place (e.g. with mesh shardings)."""
     up = jnp.asarray if to_device else (lambda x: x)
 

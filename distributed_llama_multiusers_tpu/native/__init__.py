@@ -30,7 +30,9 @@ _load_failed = False
 
 # single source of truth for the build lines; the Makefile targets shell out
 # to this module so the paths cannot drift
-BUILD_FLAGS = ["-O3", "-march=native", "-shared", "-fPIC", "-std=c++17"]
+# no -march=native: the .so is git-ignored but travels with a copied tree,
+# and one built for another machine's CPU would die on an illegal instruction
+BUILD_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17"]
 # ASan+UBSan build (the reference's test strategy leans on sanitizer CI,
 # SURVEY.md §5.2): `make sanitize` builds this variant and runs the native
 # test suite against it with libasan preloaded

@@ -6,10 +6,9 @@ touched it. So "auto" cannot mean "measure and switch live" — it means
 resolve each matmul site's mode ONCE, deterministically, from a small
 persisted selection table keyed by (d_in, d_out, m-class), before
 ``warmup_engine`` compiles the step families. The table is checked in
-(ops/dequant_table.json), seeded from PERF.md round-5 hardware evidence,
-and refreshed out-of-band by the measurement loops (bench.py's in-bench
-micro-A/B, scripts/kernel_sweep.py --update-table via evidence_loop.sh,
-scripts/kernel_lab3.py --adopt) through ``record_win``.
+(ops/dequant_table.json) and refreshed out-of-band by the measurement
+loops (bench.py's in-bench micro-A/B, scripts/kernel_sweep.py
+--update-table, scripts/kernel_lab3.py --adopt) through ``record_win``.
 
 Everything in this module is HOST state: rules are plain python dicts and
 strings. No device arrays may ever be constructed into the table or the
@@ -190,7 +189,7 @@ def dequant_stats() -> dict:
 
 
 def bench_stamp(prefix: str) -> dict:
-    """Phase-prefixed dequant attribution for BENCH_LIVE.json: every phase
+    """Phase-prefixed dequant attribution for bench.py: every phase
     result records the resolved mode (and table provenance) next to its
     tok/s number so kernel A/B rows stay attributable after the fact."""
     s = dequant_stats()
@@ -210,7 +209,7 @@ def bench_stamp(prefix: str) -> dict:
 def record_win(d_in, d_out, m_class: str, mode: str, source: str,
                path: str | None = None) -> str:
     """Feed a measured (shape -> mode) winner back into the persisted
-    table (scripts/evidence_loop.sh sweep phase, bench.py in-bench A/B,
+    table (scripts/kernel_sweep.py --update-table, bench.py in-bench A/B,
     kernel_lab3 --adopt). Upserts the matching rule and rewrites the file
     atomically. Writes the FILE only: a live process's resolution stays
     whatever it froze at — the next serving start picks the row up."""

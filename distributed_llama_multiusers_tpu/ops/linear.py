@@ -67,22 +67,16 @@ def set_pallas_w_dtype(dtype) -> None:
 
 @lru_cache(maxsize=1)
 def _pallas_q40_matmul():
-    """The Pallas kernel entry, or None off-TPU / when disabled."""
+    """The Pallas kernel entry on a TPU; None on any other platform and
+    under DLLAMA_NO_PALLAS=1, the one explicit switch. A backend that does
+    not answer or a kernel module that does not import raises here: the XLA
+    dequant path must never stand in for the kernel unannounced."""
     if os.environ.get("DLLAMA_NO_PALLAS") == "1":
         return None
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # no backend at all (e.g. misconfigured platform)
+    if jax.devices()[0].platform != "tpu":
         return None
-    if not on_tpu:
-        return None
-    try:
-        from .pallas_q40 import q40_matmul_pallas
-    except ImportError as e:
-        import warnings
+    from .pallas_q40 import q40_matmul_pallas
 
-        warnings.warn(f"Pallas Q40 kernel unavailable, using XLA fallback: {e}")
-        return None
     return q40_matmul_pallas
 
 
@@ -100,20 +94,16 @@ def shared_q80_acts(x: jnp.ndarray):
         return x
     if x.shape[-1] % 32 != 0:
         return x
-    try:
-        from .pallas_q40 import make_q80_acts
-    except ImportError:
-        return x
+    from .pallas_q40 import make_q80_acts
+
     return make_q80_acts(x, shared=True)
 
 
 def _raw_x(x):
     """Unwrap a Q80Acts bundle to its original activation for every
     non-kernel path (dense weights, XLA fallback)."""
-    try:
-        from .pallas_q40 import Q80Acts
-    except ImportError:
-        return x
+    from .pallas_q40 import Q80Acts
+
     return x.x if isinstance(x, Q80Acts) else x
 
 

@@ -49,9 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.6 spells pltpu.CompilerParams "TPUCompilerParams" (same kwargs)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 from ..quants.packed import (
     PALLAS_SUB as SUB_TILE,
     PackedQ40,
@@ -155,6 +152,12 @@ SWEEP_COMBOS = {
 DEFAULT_COMBO = "slab1M_blk1M"
 M_TILE = 256
 ROW_ALIGN = 8  # x rows padded to this multiple
+# Mosaic's default scoped-VMEM limit (16 MiB) refuses the prefill-shaped
+# plans: a 256-row m tile against an 8192-wide slab needs 18.5 MiB (f32
+# accumulator + double-buffered output block), i.e. every prefill bucket
+# >= 256 against w1/w3/wcls. The v5e has 128 MiB of VMEM; the limit is a
+# ceiling, not a reservation, so decode-shaped calls are unaffected.
+VMEM_LIMIT_BYTES = 64 << 20
 
 
 def _f16_bits_to_f32(h: jnp.ndarray) -> jnp.ndarray:
@@ -277,12 +280,14 @@ def _q40_slab_kernel(x_lo_ref, x_hi_ref, bsum_t_ref, packed_ref, scales_ref,
     for t in sub_tiles:
         s = _f16_bits_to_f32(scales_ref[:, off:off + t])  # [n_blk, t] f32
         if mode == "u8chain":
-            # mask on native 8-bit lanes BEFORE any widening: the other
-            # chains pay a uint8->int32 expansion relayout up front
+            # low-nibble mask on native 8-bit lanes BEFORE any widening
+            # (the other chains pay a uint8->int32 expansion up front);
+            # the high nibble shifts AFTER widening — Mosaic cannot
+            # legalize arith.shrui on 8-bit lanes for the v5e
             p8 = packed_ref[:, off:off + t]
             s3 = s.astype(jnp.bfloat16)[:, None, :]
             lo8 = (p8 & jnp.uint8(0x0F)).astype(jnp.int8)
-            hi8 = (p8 >> jnp.uint8(4)).astype(jnp.int8)
+            hi8 = (p8.astype(jnp.int32) >> 4).astype(jnp.int8)
             w_lo = (lo8.astype(jnp.bfloat16).reshape(n_blk, 16, t) * s3)
             w_hi = (hi8.astype(jnp.bfloat16).reshape(n_blk, 16, t) * s3)
             w_lo = w_lo.reshape(rows, t)
@@ -368,7 +373,7 @@ def _q40_blockdot_kernel(xlt_ref, xht_ref, bsum_t_ref, packed_ref, scales_ref,
                 nib_hi[16 * b:16 * (b + 1), :], dn,
                 preferred_element_type=jnp.float32,
             )
-            contrib = (lo + hi - 8.0 * bs[b, :, None]) * s[b][None, :]
+            contrib = (lo + hi - 8.0 * bs[b][:, None]) * s[b][None, :]
             part = contrib if part is None else part + contrib
         _acc_epilogue(part, off, t, k, n_k, out_ref, acc_ref)
         off += t
@@ -403,7 +408,8 @@ def _q40_i8blockdot_kernel(xlt_ref, xht_ref, aux_ref, packed_ref, scales_ref,
     for t in sub_tiles:
         p8 = packed_ref[:, off:off + t]
         nib_lo = (p8 & jnp.uint8(0x0F)).astype(jnp.int8)
-        nib_hi = (p8 >> jnp.uint8(4)).astype(jnp.int8)
+        # shift after widening: no 8-bit-lane arith.shrui on the v5e
+        nib_hi = (p8.astype(jnp.int32) >> 4).astype(jnp.int8)
         s = _f16_bits_to_f32(scales_ref[:, off:off + t])  # [n_blk, t]
         part = None
         for b in range(n_blk):
@@ -666,8 +672,9 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         scratch_shapes=[
             pltpu.VMEM((m_tile, w_tile if n_k > 1 else SUB_TILE), jnp.float32)
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * m_pad * d_in * d_out,
@@ -682,7 +689,10 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
 
 
 # ---------------------------------------------------------------------------
-# GSPMD integration: a partitioning rule for the kernel.
+# GSPMD integration: a partitioning rule for the kernel. VIRTUAL CPU DEVICES
+# ONLY: libtpu implements no custom-call partitioner, so on real chips a mesh
+# reaches the kernel through shard_map instead (ops/ring_collective.py, pure
+# TP) and app/runtime_setup.py refuses the other layouts.
 #
 # Pallas calls are opaque to the SPMD partitioner, so without this a sharded
 # forward would have to fall back to XLA dequant (round 1 disabled the kernel
@@ -778,27 +788,18 @@ def _contraction_sync(y, k_spec, mesh):
 
 
 _q40_mm = custom_partitioning(_q40_mm_impl, static_argnums=(3, 4))
-try:
-    _q40_mm.def_partition(
-        partition=_q40_mm_partition,
-        infer_sharding_from_operands=_q40_mm_infer_sharding,
-        # x [..., (b*32)], packed [(b*16), n], scales [b, n] -> [..., n]:
-        # b = quant blocks of the contraction (reduction); the intra-block
-        # subfactors must never be split across devices
-        sharding_rule="... (b t), (b s) n, b n -> ... n",
-        reduction_factors=("b",),
-        need_replication_factors=("t", "s"),
-        t=32,
-        s=16,
-    )
-except TypeError:
-    # older jax: no shardy sharding_rule/factor kwargs — GSPMD partitions
-    # through the infer/partition callbacks alone, which carry the same
-    # constraints, so dropping the rule only loses shardy support
-    _q40_mm.def_partition(
-        partition=_q40_mm_partition,
-        infer_sharding_from_operands=_q40_mm_infer_sharding,
-    )
+_q40_mm.def_partition(
+    partition=_q40_mm_partition,
+    infer_sharding_from_operands=_q40_mm_infer_sharding,
+    # x [..., (b*32)], packed [(b*16), n], scales [b, n] -> [..., n]:
+    # b = quant blocks of the contraction (reduction); the intra-block
+    # subfactors must never be split across devices
+    sharding_rule="... (b t), (b s) n, b n -> ... n",
+    reduction_factors=("b",),
+    need_replication_factors=("t", "s"),
+    t=32,
+    s=16,
+)
 
 
 def q40_matmul_partitioned(x: jnp.ndarray, w: PackedQ40, interpret: bool = False,

@@ -30,8 +30,8 @@ distributed-Pallas idiom, SNIPPETS.md [1]) can overlap with compute:
   pods, ``DLLAMA_RING_RDMA=on`` opts the pure-TP shard_map paths into a
   Pallas hop built on ``pltpu.make_async_remote_copy`` (the ICI RDMA
   idiom of SNIPPETS.md [1]) that skips the HLO collective boundary;
-  opt-in because no backend in this environment can execute it, and a
-  Mosaic gap would only surface at compile time.
+  opt-in because it has never run on a chip; once opted in, a hop that
+  fails to lower or compile raises instead of taking the ppermute ring.
 
 Escape hatch: ``DLLAMA_RING_SYNC=off`` (or ``set_ring_sync(False)``)
 disables every ring path and restores the plain ``lax.psum`` sync
@@ -49,9 +49,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jax_compat import shard_map
 from ..quants.jax_codec import Q80_BLOCK, q80_decode_blocks, q80_encode_blocks
 
 _ring_sync = os.environ.get("DLLAMA_RING_SYNC", "on").lower() not in (
@@ -86,12 +86,7 @@ def ring_sync_engages(config, mesh_shape: dict) -> bool:
     are ``dim`` wide and must split into whole per-hop chunks."""
     if not _ring_sync:
         return False
-    tp = mesh_shape.get("tp", 1)
-    if tp <= 1:
-        return False
-    if any(mesh_shape.get(ax, 1) > 1 for ax in ("dp", "sp", "ep", "pp")):
-        return False
-    return config.dim % tp == 0
+    return pure_tp(mesh_shape) and config.dim % mesh_shape["tp"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -107,16 +102,11 @@ def _use_rdma() -> bool:
     """Pallas remote-DMA hop: OPT-IN (``DLLAMA_RING_RDMA=on``) and real TPU
     backends only. The HLO collective-permute ring is the shipping hop —
     same schedule, testable on the virtual CPU mesh; the RDMA kernel skips
-    the HLO collective boundary but no backend in this environment can
-    execute it, and a Mosaic gap would surface at COMPILE time (after
-    tracing), where the except-and-fall-back below cannot catch it. Flip
-    it on only on a pod where one warmup has been seen to pass."""
+    the HLO collective boundary but has never run on a chip. Once opted
+    in, a hop that fails to lower or compile is an error (``_shift``)."""
     if os.environ.get("DLLAMA_RING_RDMA", "off").lower() not in ("on", "1", "true"):
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 # rdma_ok threading: ``device_id=(right,)`` addresses the neighbor by its
@@ -158,9 +148,7 @@ def _rdma_shift(x: jnp.ndarray, axis: str, n: int, chan: int) -> jnp.ndarray:
         in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=pltpu.CompilerParams(collective_id=chan)
-        if hasattr(pltpu, "CompilerParams")
-        else pltpu.TPUCompilerParams(collective_id=chan),
+        compiler_params=pltpu.CompilerParams(collective_id=chan),
     )(x)
 
 
@@ -170,10 +158,9 @@ def _shift(x: jnp.ndarray, axis: str, n: int, rdma_ok: bool = False,
     device (r-1)'s buffer). ``chan``: see ``_rdma_shift`` — concurrent
     (data-independent) hop chains need distinct channels."""
     if rdma_ok and _use_rdma():
-        try:
-            return _rdma_shift(x, axis, n, chan)
-        except Exception:  # Pallas/Mosaic gap on this backend: same ring via HLO
-            pass
+        # opted in (DLLAMA_RING_RDMA=on): a hop that fails to lower raises —
+        # quietly taking the ppermute ring would time the wrong transport
+        return _rdma_shift(x, axis, n, chan)
     return jax.lax.ppermute(x, axis, _ring_perm(n))
 
 
@@ -266,6 +253,65 @@ def ring_all_reduce(x: jnp.ndarray, axis: str, n: int) -> jnp.ndarray:
     if n <= 1 or x.shape[-1] % n != 0:
         return jax.lax.psum(x, axis)
     return ring_all_gather(ring_reduce_scatter(x, axis, n), axis, n)
+
+
+# ---------------------------------------------------------------------------
+# Pure-TP matmuls on LOCAL shards (shard_map), for PackedQ40 weights.
+#
+# libtpu implements no custom-call partitioner ("Custom emitter for
+# CustomSPMDPartitioning not found", seen on four real v5e chips, PR 21), so
+# the GSPMD wrapper around the kernel (pallas_q40.q40_matmul_partitioned)
+# compiles on virtual CPU devices only. On a pure-TP mesh every Q40 matmul of
+# llama_forward therefore runs the kernel per shard under shard_map: these
+# two, and ring_sync_matmul / q80_sync_matmul for the synced outputs.
+# ---------------------------------------------------------------------------
+
+
+def pure_tp(mesh_shape: dict) -> bool:
+    """tp > 1 and every other mesh axis trivial: the reference's layout, and
+    the one on which activations are replicated outside the matmuls."""
+    return mesh_shape.get("tp", 1) > 1 and all(
+        mesh_shape.get(ax, 1) == 1 for ax in ("dp", "sp", "ep", "pp")
+    )
+
+
+def tp_sliced_matmul(x: jnp.ndarray, w, mesh: Mesh,
+                     axis: str = "tp") -> jnp.ndarray:
+    """y = x @ dequant(w) for a row-sliced PackedQ40 weight (d_out sharded
+    over ``axis``: wq/wk/wv/w1/w3/wcls). x replicated; each shard runs the
+    local kernel on its column slice; the output stays sharded on d_out — no
+    sync (reference sliceRowMatmul, src/nn/nn-core.cpp:207-217)."""
+    from ..ops.linear import q40_matmul_local
+    from ..quants.packed import PackedQ40
+
+    return shard_map(
+        lambda xl, pk, sc: q40_matmul_local(xl, PackedQ40(pk, sc)),
+        mesh=mesh,
+        in_specs=(P(), P(None, axis), P(None, axis)),
+        out_specs=P(*([None] * (x.ndim - 1) + [axis])),
+        check_vma=False,
+    )(x, w.packed, w.scales)
+
+
+def tp_reduced_matmul(x: jnp.ndarray, w, mesh: Mesh,
+                      axis: str = "tp") -> jnp.ndarray:
+    """y = x @ dequant(w) for a col-sliced PackedQ40 weight (d_in sharded:
+    wo/w2) with the plain ``psum`` sync — what ``ring_sync_matmul`` replaces
+    when the ring engages, and the path ``--ring-sync off`` restores."""
+    from ..ops.linear import q40_matmul_local
+    from ..quants.packed import PackedQ40
+
+    nd = x.ndim
+    return shard_map(
+        lambda xl, pk, sc: jax.lax.psum(
+            q40_matmul_local(xl, PackedQ40(pk, sc)), axis
+        ),
+        mesh=mesh,
+        in_specs=(P(*([None] * (nd - 1) + [axis])), P(axis, None),
+                  P(axis, None)),
+        out_specs=P(*([None] * nd)),
+        check_vma=False,
+    )(x, w.packed, w.scales)
 
 
 # ---------------------------------------------------------------------------

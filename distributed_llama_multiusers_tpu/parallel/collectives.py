@@ -13,9 +13,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..jax_compat import shard_map
 
 from ..quants.jax_codec import Q80_BLOCK, q80_decode_blocks, q80_encode_blocks
 
@@ -52,11 +51,11 @@ def q80_sync_engages(config, mesh_shape: dict) -> bool:
     - whole Q80 blocks per tp shard of every synced output (wo -> dim;
       the dense-FFN w2 additionally needs hidden-sharded planes; MoE FFNs
       never route w2 through the wire sync)."""
-    tp = mesh_shape.get("tp", 1)
-    if tp <= 1:
+    from ..ops.ring_collective import pure_tp
+
+    if not pure_tp(mesh_shape):
         return False
-    if any(mesh_shape.get(ax, 1) > 1 for ax in ("dp", "sp", "ep", "pp")):
-        return False
+    tp = mesh_shape["tp"]
     return q80_sync_supported(config.dim, tp) and (
         config.n_experts > 0 or q80_sync_supported(config.hidden_dim, tp)
     )

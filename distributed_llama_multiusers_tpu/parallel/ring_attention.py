@@ -29,8 +29,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..jax_compat import shard_map
 
 
 def _block_stats(q, k, v, mask):

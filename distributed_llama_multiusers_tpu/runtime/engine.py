@@ -2648,8 +2648,11 @@ def warmup_engine(
             with jitcheck.warming():
                 try:
                     coll()
-                except Exception:  # the probe is evidence, never a startup blocker
-                    pass
+                except Exception as e:  # noqa: BLE001 — the probe is evidence, never a startup blocker
+                    log_event(
+                        "collective_probe_failed",
+                        error=f"{type(e).__name__}: {e}",
+                    )
     # from here on a new XLA backend compile is a broken invariant: every
     # one bumps stats.jit_compiles_after_warmup (surfaced on /stats,
     # bridged to /metrics, banked by the bench phases), and under

@@ -441,6 +441,10 @@ class ApiServer:
         from ..ops.dequant_select import dequant_stats
 
         out.update(dequant_stats())
+        # which device and weight/kernel path this process really serves
+        # from (app/runtime_setup.load_stack — the runtime_device start-up
+        # line carries the same facts); absent on engines built without it
+        out.update(getattr(sched.engine, "device_facts", None) or {})
         leak_counts = getattr(sched, "leak_counts", None)
         if callable(leak_counts):
             out["resources_live"] = leak_counts()

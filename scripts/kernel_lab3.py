@@ -44,9 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.6 spells pltpu.CompilerParams "TPUCompilerParams" (same kwargs)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 sys.path.insert(0, ".")
 
 from distributed_llama_multiusers_tpu.ops.pallas_q40 import (  # noqa: E402
@@ -299,7 +296,7 @@ def _call_i8blockdot(xf, packed, sbits, d_in, d_out, chunk, tile):
         ],
         out_specs=pl.BlockSpec((M, tile), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((M, d_out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_INTERPRET,
@@ -356,7 +353,7 @@ def _call_kernel(name, xf, packed, sbits, d_in, d_out, chunk, tile):
         ],
         out_specs=pl.BlockSpec((M, tile), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((M, d_out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_INTERPRET,
@@ -437,7 +434,7 @@ def main():
     s_spec = pl.BlockSpec((1, CHUNK // 32, TILE), lambda l, j, k: (l, k, j))
     o_spec = pl.BlockSpec((M, TILE), lambda l, j, k: (0, j))
     o_shape = jax.ShapeDtypeStruct((M, d_out), jnp.float32)
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "parallel", "arbitrary"),
     )
 

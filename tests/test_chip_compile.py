@@ -1,0 +1,163 @@
+"""Ahead-of-time compiles for a DESCRIBED TPU v5e (no chip attached).
+
+Interpret mode proves a Pallas kernel's arithmetic; only the chip's own
+compiler (Mosaic, inside libtpu, which is installed here) proves the kernel
+exists on the chip. PR 21 found three of the six dequant chains refused by
+it while every interpret-mode test was green — an 8-bit-lane shift in
+u8chain / i8blockdot, a gather in blockdot — and every chain refused at
+prefill widths (a 256-row m tile against an 8192-wide slab overran the
+default scoped-VMEM limit). These compiles guard every later PR at no chip
+time: each mode `--dequant` offers, and each mode ops/dequant_table.json can
+resolve `auto` to, must compile at the matmul shapes of Llama-3.2-1B and
+Llama-3.1-8B. A compile that passes is not a chip run — numerics on the chip
+are chip_smoke.py's kernel phase.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep libtpu's logs out of /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from distributed_llama_multiusers_tpu.ops import (
+    dequant_select,
+    linear,
+    pallas_q40 as pq,
+    ring_collective,
+)
+from distributed_llama_multiusers_tpu.quants.packed import PackedQ40
+
+# (d_in, d_out): 1B wq/wo, wk/wv, w1/w3, w2, wcls (vocab padded to the wide
+# tile); 8B w1/w3, w2
+SHAPES = [
+    (2048, 2048), (2048, 512), (2048, 8192), (8192, 2048), (2048, 131072),
+    (4096, 14336), (14336, 4096),
+]
+# one single-chunk plan (direct write, 64 unrolled quant blocks) and one
+# multi-chunk, two-wide-tile plan (the f32 accumulator path)
+TWO_SHAPES = [(2048, 512), (4096, 14336)]
+DEFAULT_MODE = "v4"
+OTHER_MODES = [m for m in pq.SELECTABLE_MODES if m != DEFAULT_MODE]
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four described chips of a v5e 2x2 host, persistent compile cache
+    off around the module: an AOT executable is written to the cache but
+    cannot be read back without a chip, and the next compile would warn
+    about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to compile with
+        pytest.skip(f"cannot describe a v5e topology: {type(e).__name__}: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield list(topo.devices)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_devices):
+    return SingleDeviceSharding(v5e_devices[0])
+
+
+def _compile(sharding, mode: str, d_in: int, d_out: int, m: int) -> str:
+    """Compile the bf16-dot kernel the way q40_matmul_pallas routes it and
+    return the optimized HLO text."""
+    x = jax.ShapeDtypeStruct((m, d_in), jnp.bfloat16, sharding=sharding)
+    w = PackedQ40(
+        packed=jax.ShapeDtypeStruct((d_in // 2, d_out), jnp.uint8,
+                                    sharding=sharding),
+        scales=jax.ShapeDtypeStruct((d_in // 32, d_out), jnp.float16,
+                                    sharding=sharding),
+    )
+    if mode == "auto":
+        mode = dequant_select.DequantTable().resolve(
+            d_in, d_out, dequant_select.m_class_of(m)
+        )
+    if mode in ("blockdot", "i8blockdot") and m > pq.BLOCKDOT_MAX_M:
+        mode = "bf16chain"
+    return pq._q40_matmul_pallas_impl.lower(
+        x, w, interpret=False, w_dtype=jnp.bfloat16, mode=mode
+    ).compile().as_text()
+
+
+def test_default_mode_is_what_this_file_calls_default(monkeypatch):
+    monkeypatch.delenv("DLLAMA_DEQUANT", raising=False)
+    assert pq._env_dequant_default() == DEFAULT_MODE
+
+
+# m = 1: decode. m = 1024: the widest default prefill bucket — four 256-row
+# m tiles, the plan with the largest VMEM footprint (m = 128 is one smaller
+# tile of the same plan).
+@pytest.mark.parametrize("m", [1, 1024])
+@pytest.mark.parametrize("d_in,d_out", SHAPES)
+def test_default_mode_compiles_for_v5e(v5e, d_in, d_out, m):
+    assert "tpu_custom_call" in _compile(v5e, DEFAULT_MODE, d_in, d_out, m)
+
+
+@pytest.mark.parametrize("d_in,d_out", TWO_SHAPES)
+@pytest.mark.parametrize("mode", OTHER_MODES)
+def test_every_selectable_mode_compiles_for_v5e(v5e, mode, d_in, d_out):
+    assert "tpu_custom_call" in _compile(v5e, mode, d_in, d_out, 1)
+
+
+@pytest.mark.parametrize("d_in,d_out", [(2048, 131072), (4096, 14336)])
+def test_auto_prefill_class_compiles_for_v5e(v5e, d_in, d_out):
+    """What `auto` resolves prefill-wide calls to, at the two widest slabs."""
+    assert "tpu_custom_call" in _compile(v5e, "auto", d_in, d_out, 1024)
+
+
+@pytest.mark.parametrize("fn,d_in,d_out,x_spec,w_spec,collective", [
+    # wq/wk/wv/w1/w3/wcls: d_out sharded, no sync
+    (ring_collective.tp_sliced_matmul, 2048, 8192, P(), P(None, "tp"), None),
+    # wo/w2 with the ring off: d_in sharded, psum
+    (ring_collective.tp_reduced_matmul, 8192, 2048, P(None, "tp"),
+     P("tp", None), "all-reduce"),
+    # wo/w2 by default: d_in sharded, ring-overlapped
+    (ring_collective.ring_sync_matmul, 8192, 2048, P(None, "tp"),
+     P("tp", None), "collective-permute"),
+])
+def test_pure_tp_kernel_paths_compile_for_a_v5e_mesh(
+    v5e_devices, monkeypatch, fn, d_in, d_out, x_spec, w_spec, collective
+):
+    """libtpu has no custom-call partitioner, so a mesh reaches the kernel
+    through shard_map only: each pure-TP form compiles for four described
+    chips with the kernel and its collective in the program."""
+    mesh = Mesh(np.array(v5e_devices).reshape(4), ("tp",))
+    # ops/linear.py asks jax.devices(), which is the CPU here: steer it
+    monkeypatch.setattr(
+        linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas
+    )
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    x = sds((8, d_in), jnp.bfloat16, x_spec)
+    w = PackedQ40(packed=sds((d_in // 2, d_out), jnp.uint8, w_spec),
+                  scales=sds((d_in // 32, d_out), jnp.float16, w_spec))
+    hlo = jax.jit(lambda x, w: fn(x, w, mesh)).lower(x, w).compile().as_text()
+    assert "tpu_custom_call" in hlo and "CustomSPMDPartitioning" not in hlo
+    assert collective is None or collective in hlo
+
+
+def test_selection_table_resolves_only_to_compile_tested_modes():
+    """`auto` may only land on a mode the grid above compiles."""
+    modes = {r["mode"] for r in dequant_select.DequantTable().rules}
+    assert modes <= set(OTHER_MODES) | {DEFAULT_MODE}
+    assert dequant_select.FALLBACK_MODE in pq.DEQUANT_MODES

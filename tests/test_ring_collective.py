@@ -20,9 +20,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributed_llama_multiusers_tpu.jax_compat import shard_map
 from distributed_llama_multiusers_tpu.ops import ring_collective as rc
 from distributed_llama_multiusers_tpu.parallel import MeshPlan, make_mesh
 from distributed_llama_multiusers_tpu.quants.jax_codec import qdq_q80
@@ -358,3 +358,62 @@ def test_forward_ring_on_off_parity():
     assert np.abs(psum - ref).max() < 1e-4
     # greedy decisions identical: the serving stream-parity class
     assert np.array_equal(ring.argmax(-1), ref.argmax(-1))
+
+
+@pytest.mark.parametrize("ring", [True, False])
+def test_pure_tp_packed_forward_needs_no_custom_partitioner(ring, monkeypatch):
+    """libtpu has no custom-call partitioner, so on a pure-TP mesh the
+    Q40 forward must not contain the GSPMD kernel wrapper at all: every
+    matmul runs the kernel per shard under shard_map (sliced: no sync;
+    synced: ring, or shard-local psum with the ring off) — and still
+    matches the dense mesh-free forward."""
+    import distributed_llama_multiusers_tpu.ops.pallas_q40 as pq
+    from distributed_llama_multiusers_tpu.models import (
+        init_kv_cache,
+        llama_forward,
+        params_from_random,
+    )
+    from distributed_llama_multiusers_tpu.models.config import LlamaConfig
+    from distributed_llama_multiusers_tpu.models.loader import quantize_params
+    from distributed_llama_multiusers_tpu.ops import linear
+    from distributed_llama_multiusers_tpu.parallel.sharding import shard_params
+
+    config = LlamaConfig(dim=256, hidden_dim=512, n_layers=2, n_heads=8,
+                         n_kv_heads=4, vocab_size=512, seq_len=32)
+    dense = params_from_random(config, seed=3, dtype=jnp.float32)
+    packed = quantize_params(dense)
+    mesh = make_mesh(MeshPlan(tp=2))
+    toks = jnp.asarray([[3, 9, 27, 81]], jnp.int32)
+    pos = jnp.arange(4, dtype=jnp.int32)[None]
+
+    calls = {"n": 0}
+    real_kernel = pq.q40_matmul_pallas
+
+    def counting_kernel(x, w, interpret=False, **kw):
+        calls["n"] += 1
+        return real_kernel(x, w, interpret=interpret, **kw)
+
+    monkeypatch.setattr(pq, "q40_matmul_pallas", counting_kernel)
+    prev = rc.ring_sync_enabled()
+    linear.set_pallas_interpret(True)
+    try:
+        rc.set_ring_sync(ring)
+        # the reference dequantizes the SAME Q40 planes through XLA
+        linear.set_pallas_enabled(False)
+        ref, _ = llama_forward(config, packed, toks, pos, init_kv_cache(config, 1))
+        linear.set_pallas_enabled(True)
+        fwd = jax.jit(
+            lambda p, c: llama_forward(config, p, toks, pos, c, mesh=mesh)[0]
+        )
+        args = (shard_params(packed, mesh), init_kv_cache(config, 1))
+        hlo = fwd.lower(*args).as_text()
+        got = fwd(*args)
+    finally:
+        linear.set_pallas_enabled(True)
+        linear.set_pallas_interpret(False)
+        rc.set_ring_sync(prev)
+    assert "CustomSPMDPartitioning" not in hlo
+    assert calls["n"] > 0, "the sharded forward never reached the kernel"
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), atol=2e-3, rtol=2e-3
+    )

@@ -498,9 +498,15 @@ def test_metrics_endpoint_parses_and_reconciles_with_stats(mock_server):
     base, sched, tel = mock_server
     _post(base, "/v1/completions",
           {"prompt": "hello world", "max_tokens": 5, "temperature": 0})
-    # idle now: /stats and /metrics sample the same counters
-    _, stats_raw = _get_raw(base, "/stats")
-    stats = json.loads(stats_raw)
+    # the response returns while the pipelined chain may still consume its
+    # one overshoot step: wait until /stats stands still, then /stats and
+    # /metrics sample the same counters
+    stats = None
+    for _ in range(100):
+        prev, stats = stats, json.loads(_get_raw(base, "/stats")[1])
+        if prev is not None and prev["decode_steps"] == stats["decode_steps"]:
+            break
+        time.sleep(0.05)
     headers, metrics_raw = _get_raw(base, "/metrics")
     assert headers["Content-Type"].startswith("text/plain; version=0.0.4")
     samples = parse_prometheus(metrics_raw.decode())
