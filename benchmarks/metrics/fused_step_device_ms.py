@@ -1,0 +1,9 @@
+"""Step programs: median device duration of the fused prefill + decode
+programs (`_decode_prefill`, every bucket) in the traced stretch."""
+from harness.xplane import program_median_ms
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return program_median_ms(ctx.trace, ("_decode_prefill",))

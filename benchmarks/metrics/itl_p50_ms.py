@@ -1,0 +1,7 @@
+"""Median gap between consecutive deltas of one stream, all streams pooled."""
+from harness.stats import percentile
+from harness.window import itl_gaps_ms
+
+
+def read(ctx):
+    return percentile(itl_gaps_ms(ctx), 50)
