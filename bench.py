@@ -1232,11 +1232,8 @@ def _phase_pod_serving(config, small):
     )
 
     # measured per-step sync split (profiler probe; rewrites cache slot 0,
-    # safe after the workload) — fed into the telemetry histogram so the
-    # bench numbers and a pod's scraped dllama_sync_seconds reconcile
-    probe_steps = 4
-    sync = engine.measured_sync_stats(steps=probe_steps)
-    telemetry.observe_sync_probe(sync, steps=probe_steps)
+    # safe after the workload)
+    sync = engine.measured_sync_stats(steps=4)
 
     def pct_ms(hist, q):
         v = hist.quantile(q)

@@ -40,6 +40,12 @@ from ..lockcheck import make_lock
 
 ENV_FLAG = "DLLAMA_JITCHECK"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# persistent-cache traffic of this process: a restart on the same tree must
+# show hits, and the start-up lines (warmup_program, warmup_done) say so
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile_cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile_cache_misses",
+}
 
 _forced: bool | None = None
 # guards the registry below (never held around a sink's stats lock or
@@ -51,6 +57,7 @@ _pause_depth = 0
 _armed = False
 _sinks: list = []  # weakrefs to EngineStats-like sinks
 _total_compiles = 0  # process lifetime, diagnostics
+_cache_counts = dict.fromkeys(CACHE_EVENTS.values(), 0)
 
 
 class RecompileAfterWarmup(AssertionError):
@@ -111,10 +118,19 @@ def _on_duration(event: str, duration: float, **kw) -> None:
         )
 
 
-def _install() -> None:
-    """Register the process-global listener once. Caller holds no lock;
-    jax import happens here, lazily — the arming site already runs under
-    jax by construction (it just finished a warmup)."""
+def _on_event(event: str, **kw) -> None:
+    """The jax.monitoring listener for the persistent cache's events."""
+    key = CACHE_EVENTS.get(event)
+    if key is not None:
+        with _lock:
+            _cache_counts[key] += 1
+
+
+def install() -> None:
+    """Register the process-global listeners once (``arm`` does it too;
+    ``warmup_engine`` and ``enable_compilation_cache`` call it earlier so
+    the start-up's own compiles and cache loads are counted). Caller
+    holds no lock; jax import happens here, lazily."""
     global _installed
     with _lock:
         if _installed:
@@ -123,6 +139,7 @@ def _install() -> None:
     import jax.monitoring
 
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
 
 
 @contextlib.contextmanager
@@ -144,7 +161,7 @@ def arm(stats) -> None:
     """Start witnessing for ``stats`` (an ``EngineStats``: needs
     ``.lock`` and ``.jit_compiles_after_warmup``). Idempotent per
     object; sinks are weak so dead engines cost nothing."""
-    _install()
+    install()
     with _lock:
         global _armed
         _armed = True
@@ -159,7 +176,14 @@ def armed() -> bool:
 
 
 def total_compiles() -> int:
-    """Process-lifetime backend compile count (0 until a witness was
-    armed at least once — the listener installs lazily)."""
+    """Process-lifetime backend compile count (0 until ``install`` ran —
+    the listener installs lazily)."""
     with _lock:
         return _total_compiles
+
+
+def cache_counts() -> dict:
+    """``{compile_cache_hits, compile_cache_misses}`` of this process
+    since ``install`` (both 0 with no persistent cache configured)."""
+    with _lock:
+        return dict(_cache_counts)

@@ -11,6 +11,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from ..analysis import jitcheck
 from ..formats import load_model_header
 from ..models import load_params_from_m
 from ..models.loader import load_params_from_m_quantized
@@ -61,27 +62,11 @@ def enable_compilation_cache() -> str | None:
         path = DEFAULT_COMPILE_CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-    global _cache_listening
-    if not _cache_listening:
-        _cache_listening = True
-        jax.monitoring.register_event_listener(_on_cache_event)
+    # persistent-cache traffic of this process, fed by jax.monitoring
+    # (analysis/jitcheck.py): a restart on the same tree must show hits,
+    # and the warmup_program / warmup_done lines say whether it did
+    jitcheck.install()
     return path
-
-
-# persistent-cache traffic of this process, fed by jax.monitoring: a restart
-# on the same tree must show hits, and the start-up lines say whether it did
-_CACHE_EVENTS = {
-    "/jax/compilation_cache/cache_hits": "compile_cache_hits",
-    "/jax/compilation_cache/cache_misses": "compile_cache_misses",
-}
-_cache_counts = dict.fromkeys(_CACHE_EVENTS.values(), 0)
-_cache_listening = False
-
-
-def _on_cache_event(event: str, **_kw) -> None:
-    key = _CACHE_EVENTS.get(event)
-    if key is not None:
-        _cache_counts[key] += 1
 
 
 def load_stack(args, n_lanes: int | None = None):
@@ -427,6 +412,7 @@ def make_scheduler(engine, tokenizer, args=None) -> ContinuousBatchingScheduler:
     warmup_engine(engine, spec=speculative, multi_step=sched.multi_step)
     warmup_s = time.perf_counter() - t0
     log("⏳", f"Warmup done in {warmup_s:.1f}s")
-    log_event("warmup_done", warmup_s=round(warmup_s, 2), **_cache_counts)
+    log_event("warmup_done", warmup_s=round(warmup_s, 2),
+              **jitcheck.cache_counts())
     sched.start()
     return sched

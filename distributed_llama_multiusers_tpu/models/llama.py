@@ -32,6 +32,16 @@ from ..ops.activations import gelu, silu
 from ..ops.linear import matmul, pallas_kernel_active, shared_q80_acts
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope
+from ..telemetry.names import (
+    SCOPE_ATTENTION,
+    SCOPE_ATTN_OUT,
+    SCOPE_EMBED,
+    SCOPE_FFN,
+    SCOPE_HEAD,
+    SCOPE_KV_WRITE,
+    SCOPE_LAYERS,
+    SCOPE_QKV,
+)
 from .config import LlamaConfig
 
 
@@ -462,12 +472,17 @@ def llama_forward(
     )
     share_q80 = shared_q80_acts if share else (lambda y: y)
 
-    x = params.embedding[tokens]  # [B, T, dim]
+    # device scopes (telemetry/names.py): every part of the step carries a
+    # fixed ``dl.*`` name in its HLO metadata, which is what a device trace
+    # is reduced by — no cost on the device, none in the compile-cache key
+    with jax.named_scope(SCOPE_EMBED):
+        x = params.embedding[tokens]  # [B, T, dim]
     lane_idx = jnp.arange(b)[:, None]  # [B, 1]
 
-    # cache index validity: query at position p attends to cache slots s <= p
-    s_idx = jnp.arange(h_cfg.seq_len)  # [S]
-    attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
+    with jax.named_scope(SCOPE_ATTENTION):
+        # cache index validity: query at position p attends to cache slots s <= p
+        s_idx = jnp.arange(h_cfg.seq_len)  # [S]
+        attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
 
     if paged:
         # page indirection, computed ONCE (the table is layer-invariant):
@@ -480,28 +495,31 @@ def llama_forward(
         n_pages, page = cache.k.shape[1], cache.k.shape[2]
         table = cache.table  # [B, blocks_per_lane]
         n_blocks = table.shape[1]
-        w_blk = jnp.clip(positions // page, 0, n_blocks - 1)
-        w_page = jnp.take_along_axis(table, w_blk, axis=1)  # [B, T]
-        w_page = jnp.where(positions < h_cfg.seq_len, w_page, n_pages)
-        w_slot = positions % page
-        gather_idx = (
-            table[:, :, None] * page
-            + jnp.arange(page, dtype=jnp.int32)[None, None, :]
-        ).reshape(b, n_blocks * page)[:, : h_cfg.seq_len]  # [B, S]
+        with jax.named_scope(SCOPE_KV_WRITE):
+            w_blk = jnp.clip(positions // page, 0, n_blocks - 1)
+            w_page = jnp.take_along_axis(table, w_blk, axis=1)  # [B, T]
+            w_page = jnp.where(positions < h_cfg.seq_len, w_page, n_pages)
+            w_slot = positions % page
+        with jax.named_scope(SCOPE_ATTENTION):
+            gather_idx = (
+                table[:, :, None] * page
+                + jnp.arange(page, dtype=jnp.int32)[None, None, :]
+            ).reshape(b, n_blocks * page)[:, : h_cfg.seq_len]  # [B, S]
 
     def layer_step(x, layer_in):
         lp, k_cache, v_cache = layer_in  # contiguous: [B, S, n_kv, hd];
         # paged: [n_pages, page_size, n_kv, hd]
         dtype = x.dtype
 
-        y = rms_norm(x, lp.rms_att, eps)
-        yq = share_q80(maybe_qdq(y))  # one operand build for wq/wk/wv
-        q = _maybe_bias(sliced_matmul(yq, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
-        k = _maybe_bias(sliced_matmul(yq, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
-        v = _maybe_bias(sliced_matmul(yq, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
+        with jax.named_scope(SCOPE_QKV):
+            y = rms_norm(x, lp.rms_att, eps)
+            yq = share_q80(maybe_qdq(y))  # one operand build for wq/wk/wv
+            q = _maybe_bias(sliced_matmul(yq, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
+            k = _maybe_bias(sliced_matmul(yq, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
+            v = _maybe_bias(sliced_matmul(yq, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
 
-        q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
-        k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
+            q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
+            k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
 
         # KV append at per-lane positions (reference OP_SHIFT, scatter on
         # TPU). mode="drop" pins JAX's default out-of-bounds scatter
@@ -511,81 +529,90 @@ def llama_forward(
         # Paged caches scatter through the page table to (page, slot)
         # instead of (lane, position) — same drop rule, and unmapped
         # sentinel entries drop the write too.
-        if paged:
-            k_cache = k_cache.at[w_page, w_slot].set(
-                _to_cache_dtype(k, k_cache.dtype), mode="drop"
-            )
-            v_cache = v_cache.at[w_page, w_slot].set(
-                _to_cache_dtype(v, v_cache.dtype), mode="drop"
-            )
-        else:
-            k_cache = k_cache.at[lane_idx, positions].set(
-                _to_cache_dtype(k, k_cache.dtype), mode="drop"
-            )
-            v_cache = v_cache.at[lane_idx, positions].set(
-                _to_cache_dtype(v, v_cache.dtype), mode="drop"
-            )
+        with jax.named_scope(SCOPE_KV_WRITE):
+            if paged:
+                k_cache = k_cache.at[w_page, w_slot].set(
+                    _to_cache_dtype(k, k_cache.dtype), mode="drop"
+                )
+                v_cache = v_cache.at[w_page, w_slot].set(
+                    _to_cache_dtype(v, v_cache.dtype), mode="drop"
+                )
+            else:
+                k_cache = k_cache.at[lane_idx, positions].set(
+                    _to_cache_dtype(k, k_cache.dtype), mode="drop"
+                )
+                v_cache = v_cache.at[lane_idx, positions].set(
+                    _to_cache_dtype(v, v_cache.dtype), mode="drop"
+                )
 
         # GQA attention in f32 (reference multiheadAtt_F32, nn-cpu-ops.cpp:749-784)
-        group = n_heads // n_kv
-        qf = q.astype(jnp.float32).reshape(b, t, n_kv, group, hd)
-        scale = 1.0 / float(hd) ** 0.5
-        if paged:
-            # gather each lane's logical [S] view through the page table:
-            # the same values a contiguous lane plane would hold, in the
-            # same order, so the f32 attention below is byte-identical to
-            # the contiguous path (pinned by tests/test_prefix_cache.py)
-            kf = k_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
-            vf = v_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
-            attn = _dense_attention(
-                qf, kf.astype(jnp.float32), vf.astype(jnp.float32),
-                attn_mask, scale,
-            )
-        elif use_sp:
-            from ..parallel.ring_attention import sp_attention
+        with jax.named_scope(SCOPE_ATTENTION):
+            group = n_heads // n_kv
+            qf = q.astype(jnp.float32).reshape(b, t, n_kv, group, hd)
+            scale = 1.0 / float(hd) ** 0.5
+            if paged:
+                # gather each lane's logical [S] view through the page table:
+                # the same values a contiguous lane plane would hold, in the
+                # same order, so the f32 attention below is byte-identical to
+                # the contiguous path (pinned by tests/test_prefix_cache.py)
+                kf = k_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
+                vf = v_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
+                attn = _dense_attention(
+                    qf, kf.astype(jnp.float32), vf.astype(jnp.float32),
+                    attn_mask, scale,
+                )
+            elif use_sp:
+                from ..parallel.ring_attention import sp_attention
 
-            attn = sp_attention(qf, k_cache, v_cache, positions, mesh, scale)
-        else:
-            attn = _dense_attention(
-                qf, k_cache.astype(jnp.float32), v_cache.astype(jnp.float32),
-                attn_mask, scale,
-            )
-        attn = attn.reshape(b, t, n_heads * hd).astype(dtype)
+                attn = sp_attention(qf, k_cache, v_cache, positions, mesh, scale)
+            else:
+                attn = _dense_attention(
+                    qf, k_cache.astype(jnp.float32), v_cache.astype(jnp.float32),
+                    attn_mask, scale,
+                )
+            attn = attn.reshape(b, t, n_heads * hd).astype(dtype)
 
         # sync-boundary cast (ZQ pipe) + merge_add; with a compressed wire
         # (q80/ring-q80) the quantization happens ON the wire instead of as
         # an output-side qdq cast
-        x = x + synced_matmul(maybe_qdq(attn), lp.wo)
+        with jax.named_scope(SCOPE_ATTN_OUT):
+            x = x + synced_matmul(maybe_qdq(attn), lp.wo)
 
-        y = rms_norm(x, lp.rms_ffn, eps)
-        yq = maybe_qdq(y)
-        if h_cfg.n_experts > 0:
-            d = _moe_ffn(
-                y, yq, lp, act_fn, h_cfg.n_active_experts, maybe_qdq,
-                ep_sharded=mesh is not None and mesh.shape.get("ep", 1) > 1,
-                mesh=mesh,
-            )
-            x = x + maybe_qdq(d)
-        else:
-            yqs = share_q80(yq)  # one operand build for w1/w3
-            g = act_fn(sliced_matmul(yqs, lp.w1))
-            u = sliced_matmul(yqs, lp.w3)
-            x = x + synced_matmul(maybe_qdq(g * u), lp.w2)
+        with jax.named_scope(SCOPE_FFN):
+            y = rms_norm(x, lp.rms_ffn, eps)
+            yq = maybe_qdq(y)
+            if h_cfg.n_experts > 0:
+                d = _moe_ffn(
+                    y, yq, lp, act_fn, h_cfg.n_active_experts, maybe_qdq,
+                    ep_sharded=mesh is not None and mesh.shape.get("ep", 1) > 1,
+                    mesh=mesh,
+                )
+                x = x + maybe_qdq(d)
+            else:
+                yqs = share_q80(yq)  # one operand build for w1/w3
+                g = act_fn(sliced_matmul(yqs, lp.w1))
+                u = sliced_matmul(yqs, lp.w3)
+                x = x + synced_matmul(maybe_qdq(g * u), lp.w2)
 
         return x, (k_cache, v_cache)
 
-    x, (new_k, new_v) = jax.lax.scan(layer_step, x, (params.layers, cache.k, cache.v))
+    with jax.named_scope(SCOPE_LAYERS):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_step, x, (params.layers, cache.k, cache.v)
+        )
 
-    y = rms_norm(x, params.rms_final, eps)
-    logits = sliced_matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)  # [B, T, vocab]
-    # wcls may be padded past vocab_size for the slab kernel's wide tiles
-    # (quants/packed.pad_packed_d_out); identity slice otherwise
+    with jax.named_scope(SCOPE_HEAD):
+        y = rms_norm(x, params.rms_final, eps)
+        logits = sliced_matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)  # [B, T, vocab]
+        # wcls may be padded past vocab_size for the slab kernel's wide tiles
+        # (quants/packed.pad_packed_d_out); identity slice otherwise
+        logits = logits[..., : h_cfg.vocab_size]
     out_cache = (
         PagedKVCache(k=new_k, v=new_v, table=cache.table)
         if paged
         else KVCache(k=new_k, v=new_v)
     )
-    return logits[..., : h_cfg.vocab_size], out_cache
+    return logits, out_cache
 
 
 def llama_forward_train(

@@ -53,6 +53,13 @@ from ..analysis import jitcheck, leakcheck
 from ..lockcheck import make_lock
 from ..serving.watchdog import deadline_from_env
 from ..telemetry import Telemetry
+from ..telemetry.names import (
+    LOOP_ADMIT,
+    LOOP_DISPATCH,
+    LOOP_STREAM,
+    LOOP_TRACK,
+    LOOP_WAIT,
+)
 from ..tokenizer import EosDetector, EosResult, Sampler, Tokenizer, TokenizerChatStops
 from ..utils import faults
 from ..utils.seeds import fresh_seed
@@ -432,6 +439,16 @@ class ContinuousBatchingScheduler:
         self.queue = queue_ or QosQueue()
         self.deadlines = deadlines or DeadlinePolicy()
         self.telemetry = telemetry or Telemetry()
+        if self.telemetry.annotation_factory is None:
+            # the loop's spans ride the profiler's clock too (telemetry/
+            # itself imports no jax: the factory is handed in here). With
+            # no profiler session an annotation is one atomic load.
+            from jax.profiler import TraceAnnotation
+
+            self.telemetry.annotation_factory = TraceAnnotation
+        # sequence number of the pipelined dispatches: the `step` every
+        # loop.* span and step slice of one dispatch carries
+        self._step_seq = 0
         # queue-wait histogram source: the queue's own pop-time measurement
         # when it offers one (reconciles with queue_popped exactly), else
         # observed at lane-claim time
@@ -1249,6 +1266,7 @@ class ContinuousBatchingScheduler:
         req = lane.request
         chunk = lane.pending[: self.engine.max_chunk()]
         t_chunk = time.perf_counter()
+        self.telemetry.on_prefill_dispatch(req, time.monotonic())
         wd = self.watchdog
         if wd is not None:
             wd.begin_step()
@@ -1282,7 +1300,9 @@ class ContinuousBatchingScheduler:
         self._paged_commit(lane_idx)
         if lane.pending:
             return True
-        # prompt complete: pick the first generated token
+        # prompt complete: pick the first generated token (which the next
+        # decode step's consume emits: first_token_hold_ms)
+        self.telemetry.on_prefill_done(req, time.monotonic())
         if req.temperature == 0.0:
             first = int(greedy)
         elif lane.host_exact:
@@ -1670,7 +1690,7 @@ class ContinuousBatchingScheduler:
         step's packed token readback and run the host work the synchronous
         loop does inline — stream decode, EOS/stop, cancel/budget checks —
         while the younger dispatches keep the device busy. ``entry`` is
-        ``(step_lanes, fused, t_dispatch, spec_drafted)`` recorded AT
+        ``(step_lanes, fused, t_dispatch, spec_drafted, step)`` recorded AT
         DISPATCH TIME: ``step_lanes`` pairs each live lane index with its
         lane OBJECT — the identity check skips both lanes that finished at
         an earlier consumed step AND lanes already reclaimed by a NEW
@@ -1692,21 +1712,40 @@ class ContinuousBatchingScheduler:
         bench acceptance ratio below its [1, K+1] class). ``t_dispatch``
         is the step's dispatch stamp: the telemetry slice spans dispatch
         -> this lagged readback, recorded HERE (the consume half) so the
-        dispatch half stays span-free (dlint pipeline-sync)."""
+        dispatch half stays span-free (dlint pipeline-sync); ``step`` is
+        the dispatch's sequence number, which this half's ``loop.wait`` and
+        ``loop.stream`` spans and the step slice carry."""
+        step_lanes, fused, t_dispatch, spec_drafted, step = entry
+        span_args = {"step": step}
         wd = self.watchdog
         if wd is not None:
             wd.begin_step()
         try:
-            out_a, out_b = self.engine.pipeline_consume()
+            # loop.wait: the lagged readback alone — the one span of the
+            # loop in which the host has nothing to do but wait
+            with self.telemetry.span(LOOP_WAIT, LOOP_TRACK, args=span_args):
+                out_a, out_b = self.engine.pipeline_consume()
         finally:
             if wd is not None:
                 wd.step_done()
+        # loop.stream: everything the host does with the step's tokens
+        with self.telemetry.span(LOOP_STREAM, LOOP_TRACK, args=span_args):
+            self._pipeline_stream(
+                live, step_lanes, fused, t_dispatch, spec_drafted, step,
+                out_a, out_b,
+            )
+
+    def _pipeline_stream(self, live: dict, step_lanes, fused, t_dispatch,
+                         spec_drafted, step: int, out_a, out_b) -> None:
+        """The host half of one consumed step (``_pipeline_consume``'s
+        contract): per-lane ``_consume`` with detokenize/``on_delta``,
+        finishes, and the fused boundary token."""
         self.breaker.record_success()
         now = time.monotonic()
-        step_lanes, fused, t_dispatch, spec_drafted = entry
         is_spec = spec_drafted is not None
         self.telemetry.on_pipelined_step(
-            t_dispatch, fused, kind="spec_pipelined" if is_spec else "pipelined"
+            t_dispatch, fused,
+            kind="spec_pipelined" if is_spec else "pipelined", step=step,
         )
         if is_spec:
             emitted, n_emit = out_a, out_b
@@ -1781,6 +1820,7 @@ class ContinuousBatchingScheduler:
                 # Spec packs carry the boundary pair in the extra ROW's
                 # first two columns; token packs in the extra COLUMN.
                 req = lane.request
+                self.telemetry.on_prefill_done(req, now)
                 if is_spec:
                     b_greedy = int(emitted[-1, 0])
                     b_sampled = int(emitted[-1, 1])
@@ -1793,6 +1833,61 @@ class ContinuousBatchingScheduler:
                 # mirror: start state advanced by the boundary emission
                 self._g_adv(lane, lane.next_token)
                 req.state = RequestState.GENERATING
+
+    def _pipeline_admit(self, live: dict, admitting: dict, fused: bool,
+                        spec_chain: bool, probe_drafts: bool) -> bool:
+        """The admission part of one iteration of the pipelined loop (the
+        ``loop.admit`` span): sweep the queue, drop admitting requests
+        that were cancelled or ran out of budget, claim queued requests
+        into free lanes (tokenizing them). Returns whether the chain must
+        flush."""
+        now = time.monotonic()
+        # queued cancels/expiries must not wait out a long chain
+        # (throttled internally to ~20 Hz)
+        self._sweep_queue(now)
+        # an admitting request cancelled/expired mid-prompt: stop
+        # streaming its chunks; the in-flight ones are junk-KV-safe
+        for i in [
+            j for j, l in admitting.items()
+            if l.request._cancelled.is_set()
+            or budget_expired(l.request, self.deadlines, now)
+        ]:
+            lane = admitting.pop(i)
+            if lane.request._cancelled.is_set():
+                self._finish(i, lane.request, reason="cancelled")
+            else:
+                self.budget_timeouts += 1
+                self._finish(i, lane.request, reason="timeout")
+        flush = (
+            self._stop.is_set()
+            or self._wd_abort.is_set()  # watchdog: abort the chain
+            or (not live and not admitting)
+        )
+        if not flush and fused:
+            # a claimed lane whose chunks cannot ride the chain (a
+            # host-exact admission): only the synchronous path can
+            # serve it — keep flushing until it does. Checked every
+            # iteration, not just at claim time, so the lane is never
+            # starved behind a long-lived chain.
+            flush = any(
+                l.request is not None
+                and l.pending
+                and i not in admitting
+                and i not in live
+                for i, l in enumerate(self._lanes)
+            )
+        if not flush and not self.queue.empty():
+            if fused:
+                # admissions ride the chain; only a host-exact claim
+                # still needs the synchronous path
+                flush = not self._claim_admissions(admitting)
+            else:
+                flush = True
+        if not flush and probe_drafts and not spec_chain:
+            # engines without the in-chain verify family: a draft hit
+            # still flushes to the synchronous spec path
+            flush = self._drafts_pending(live)
+        return flush
 
     def _run_pipelined(self, active) -> None:
         """Steady-state pipelined decode: keep the ring at ``pipeline_depth``
@@ -1847,9 +1942,10 @@ class ContinuousBatchingScheduler:
         feed = np.zeros(engine.n_lanes, np.int32)
         for i, lane in live.items():
             feed[i] = lane.next_token
-        # (live lanes, fused info, dispatch stamp, spec-drafted set) per
-        # dispatch — positions no longer tracked host-side: they ride the
-        # device carry (spec accept counts are only known one step behind)
+        # (live lanes, fused info, dispatch stamp, spec-drafted set, step
+        # number) per dispatch — positions no longer tracked host-side:
+        # they ride the device carry (spec accept counts are only known
+        # one step behind)
         meta: deque = deque()
         host_feed = True  # first dispatch reseeds the chain from host tokens
         dispatched_any = False
@@ -1857,53 +1953,16 @@ class ContinuousBatchingScheduler:
         # branch) just probed the drafters; skip the duplicate probe on
         # the first iteration of the hot loop
         probe_drafts = False
+        tel = self.telemetry
         while True:
-            now = time.monotonic()
-            # queued cancels/expiries must not wait out a long chain
-            # (throttled internally to ~20 Hz)
-            self._sweep_queue(now)
-            # an admitting request cancelled/expired mid-prompt: stop
-            # streaming its chunks; the in-flight ones are junk-KV-safe
-            for i in [
-                j for j, l in admitting.items()
-                if l.request._cancelled.is_set()
-                or budget_expired(l.request, self.deadlines, now)
-            ]:
-                lane = admitting.pop(i)
-                if lane.request._cancelled.is_set():
-                    self._finish(i, lane.request, reason="cancelled")
-                else:
-                    self.budget_timeouts += 1
-                    self._finish(i, lane.request, reason="timeout")
-            flush = (
-                self._stop.is_set()
-                or self._wd_abort.is_set()  # watchdog: abort the chain
-                or (not live and not admitting)
-            )
-            if not flush and fused:
-                # a claimed lane whose chunks cannot ride the chain (a
-                # host-exact admission): only the synchronous path can
-                # serve it — keep flushing until it does. Checked every
-                # iteration, not just at claim time, so the lane is never
-                # starved behind a long-lived chain.
-                flush = any(
-                    l.request is not None
-                    and l.pending
-                    and i not in admitting
-                    and i not in live
-                    for i, l in enumerate(self._lanes)
+            # one iteration = loop.admit, loop.dispatch (per dispatch),
+            # loop.wait, loop.stream: four spans on the ring's `loop` track
+            # and, as dl.loop.* annotations, on the profiler's clock
+            with tel.span(LOOP_ADMIT, LOOP_TRACK,
+                          args={"step": self._step_seq + 1}):
+                flush = self._pipeline_admit(
+                    live, admitting, fused, spec_chain, probe_drafts
                 )
-            if not flush and not self.queue.empty():
-                if fused:
-                    # admissions ride the chain; only a host-exact claim
-                    # still needs the synchronous path
-                    flush = not self._claim_admissions(admitting)
-                else:
-                    flush = True
-            if not flush and probe_drafts and not spec_chain:
-                # engines without the in-chain verify family: a draft hit
-                # still flushes to the synchronous spec path
-                flush = self._drafts_pending(live)
             probe_drafts = True  # entry gates probed already; re-check
             # from the second iteration on (new tokens land per consume)
             while not flush and engine.pipeline_inflight() < depth:
@@ -1912,6 +1971,7 @@ class ContinuousBatchingScheduler:
                 # the step's trace slice (no tracer call — no lock, no
                 # sync — ever runs inside _pipeline_dispatch itself)
                 t_d = time.perf_counter()
+                t_mono = time.monotonic()
                 # spec drafts align only at ring lag <= 1 with no other
                 # spec step in flight (the host's carry candidate is one
                 # step behind — see _pipeline_dispatch)
@@ -1920,22 +1980,29 @@ class ContinuousBatchingScheduler:
                     and engine.pipeline_inflight() <= 1
                     and not any(m[3] is not None for m in meta)
                 )
-                fused_info, spec_drafted = self._pipeline_dispatch(
-                    live, admitting, feed if host_feed else None, spec_ok
-                )
+                self._step_seq += 1
+                step = self._step_seq
+                # the span wraps the CALL; nothing is recorded inside the
+                # dispatch half (dlint pipeline-sync)
+                with tel.span(LOOP_DISPATCH, LOOP_TRACK, args={"step": step}):
+                    fused_info, spec_drafted = self._pipeline_dispatch(
+                        live, admitting, feed if host_feed else None, spec_ok
+                    )
                 host_feed = False
                 dispatched_any = True
                 meta.append(
-                    (tuple(live.items()), fused_info, t_d, spec_drafted)
+                    (tuple(live.items()), fused_info, t_d, spec_drafted, step)
                 )
-                if fused_info is not None and fused_info[2]:
-                    # final chunk dispatched: the lane joins the decode
-                    # half from the NEXT dispatch — the device carry holds
-                    # both its first token AND its position (set by the
-                    # fused program), no host round-trip involved
-                    i, lane, _, _ = fused_info
-                    admitting.pop(i)
-                    live[i] = lane
+                if fused_info is not None:
+                    i, lane, final, _ = fused_info
+                    tel.on_prefill_dispatch(lane.request, t_mono)
+                    if final:
+                        # final chunk dispatched: the lane joins the decode
+                        # half from the NEXT dispatch — the device carry
+                        # holds both its first token AND its position (set
+                        # by the fused program), no host round-trip involved
+                        admitting.pop(i)
+                        live[i] = lane
             if engine.pipeline_inflight() == 0:
                 break
             self._pipeline_consume(live, meta.popleft())

@@ -9,6 +9,8 @@ logger (logs.py), and exposes the lifecycle hooks the scheduler calls:
     chunk  -> on_prefill_chunk   (lane slice, step-duration histogram)
     token  -> on_token           (TTFT on first, inter-token gaps after)
     step   -> on_step / on_pipelined_step  (pipeline-track slices)
+    loop   -> span(name, track)  (a slice AND a profiler annotation: the
+              batching loop's own work, on the ring and on the device's clock)
     end    -> on_finish / on_unadmitted / on_error  (summary, counters,
               one JSON log line, finish instant)
 
@@ -31,11 +33,44 @@ import time
 
 from .logs import JsonLogger, default_logger
 from .metrics import LATENCY_BUCKETS_S, MetricsRegistry
+from .names import ANNOTATION_PREFIX
 from .spans import RequestTrace, SpanTracer
 from .trace import dump_chrome_trace, tracer_chrome_trace
 from .tracectx import trace_id_of
 
 STATS_PREFIX = "dllama_stats_"
+
+
+class _Span:
+    """One open ``Telemetry.span``: the annotation (if a factory is set)
+    is held open from enter to exit, and the ring slice is appended on
+    exit, as ``SpanTracer.slice`` would. Always on: with no profiler
+    session a ``jax.profiler.TraceAnnotation`` is one atomic load."""
+
+    __slots__ = ("_tel", "_name", "_track", "_req_id", "_args", "_t0", "_ann")
+
+    def __init__(self, tel, name, track, req_id, args):
+        self._tel, self._name, self._track = tel, name, track
+        self._req_id, self._args = req_id, args
+
+    def __enter__(self):
+        factory = self._tel.annotation_factory
+        self._ann = ann = (
+            None if factory is None else factory(ANNOTATION_PREFIX + self._name)
+        )
+        self._t0 = time.perf_counter()
+        if ann is not None:
+            ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tel = self._tel
+        tel.tracer.slice(self._name, self._track, self._t0,
+                         req_id=self._req_id,
+                         args=tel.span_args(extra=self._args))
+        return False
 
 
 class Telemetry:
@@ -55,6 +90,11 @@ class Telemetry:
         # Set at construction or later by the server once it knows its id
         # (ApiServer stamps it when the scheduler built its own hub).
         self.replica = replica
+        # what holds a span open on the PROFILER's clock: a callable
+        # ``name -> context manager``, injected by the scheduler
+        # (``jax.profiler.TraceAnnotation``) so this package stays free of
+        # jax. None: spans are ring-only.
+        self.annotation_factory = None
         reg = self.registry
         self.ttft = reg.histogram(
             "dllama_ttft_seconds",
@@ -94,18 +134,11 @@ class Telemetry:
         # pod-serving sync cost next to TTFT/TBT: the estimated collective
         # payload accrued per decode-family dispatch (reconciles with the
         # /stats sync_bytes_total field the bridge republishes — same
-        # source, delta-fed below) and the MEASURED per-step collective
-        # time from profiler probes (engine.measured_sync_stats)
+        # source, delta-fed below)
         self.sync_bytes = reg.counter(
             "dllama_sync_bytes_total",
             "estimated collective payload bytes (per chip) dispatched with "
             "decode-family steps, from the compiled program's post-SPMD HLO",
-        )
-        self.sync_seconds = reg.histogram(
-            "dllama_sync_seconds",
-            "measured per-decode-step collective time (profiler probe: "
-            "engine.measured_sync_stats)",
-            LATENCY_BUCKETS_S,
         )
         # failure containment (serving/breaker.py, runtime/scheduler.py):
         # the breaker state machine as a gauge and classified failures as
@@ -233,6 +266,17 @@ class Telemetry:
             args["replica"] = self.replica
         return args or None
 
+    def span(self, name: str, track: str, req_id: int | None = None,
+             args: dict | None = None) -> _Span:
+        """Context manager: ``with telemetry.span("loop.wait", "loop"):``
+        appends the slice ``name`` to the ring on exit AND holds the
+        annotation ``dl.<name>`` open while it runs, so a profiler trace
+        carries the same span on the device's clock. The one entry point
+        for work that is timed where it happens; work timed after the
+        fact (a step's dispatch -> lagged consume) stays with
+        ``tracer.slice``."""
+        return _Span(self, name, track, req_id, args)
+
     def on_submit(self, req) -> None:
         tel = self.trace_of(req)
         self.tracer.instant("submitted", "queue", ts=tel.span_t0,
@@ -266,13 +310,29 @@ class Telemetry:
         still pending)."""
         self.trace_of(req).fused_admitted = True
 
+    def on_prefill_dispatch(self, req, now: float) -> None:
+        """A prompt chunk of ``req`` is handed to the engine (``now`` =
+        time.monotonic(), taken before the call): the first one stamps
+        ``first_dispatch_at``."""
+        tel = self.trace_of(req)
+        if tel.first_dispatch_at is None:
+            tel.first_dispatch_at = now
+
+    def on_prefill_done(self, req, now: float) -> None:
+        """The step that carried ``req``'s final prompt chunk has been
+        read back: the host now knows the boundary token."""
+        self.trace_of(req).prefill_done_at = now
+
     def on_prefill_chunk(self, req, lane: int, t0: float, n_tokens: int,
-                         fused: bool = False) -> None:
+                         fused: bool = False, step: int | None = None) -> None:
         now_pc = self.tracer.now()
+        extra = {"tokens": n_tokens}
+        if step is not None:
+            extra["step"] = step
         self.tracer.slice(
             "prefill.fused" if fused else "prefill.sync", f"lane{lane}",
             t0, now_pc, req_id=req.id,
-            args=self.span_args(req, {"tokens": n_tokens}),
+            args=self.span_args(req, extra),
         )
         if not fused:
             # fused chunks ride a pipelined dispatch that on_pipelined_step
@@ -304,7 +364,8 @@ class Telemetry:
         self.step_duration.observe(max(0.0, now_pc - t0))
 
     def on_pipelined_step(self, t_dispatch: float, fused_info=None,
-                          kind: str = "pipelined") -> None:
+                          kind: str = "pipelined",
+                          step: int | None = None) -> None:
         """One pipelined step, recorded at CONSUME time (one step behind):
         the slice spans dispatch -> lagged readback completion. ``kind``
         distinguishes the in-chain spec verify steps
@@ -312,11 +373,13 @@ class Telemetry:
         plain pipelined decodes on the trace. For a fused prefill+decode
         step, ``fused_info`` is the scheduler's
         ``(lane_idx, lane, final, n_chunk)`` and the admitting lane also
-        gets a ``prefill.fused`` slice on its own track."""
+        gets a ``prefill.fused`` slice on its own track. ``step`` is the
+        dispatch's sequence number, the one its ``loop.*`` spans carry."""
         now_pc = self.tracer.now()
+        step_args = {} if step is None else {"step": step}
         if fused_info is None:
             self.tracer.slice(f"step.{kind}", "pipeline", t_dispatch,
-                              now_pc, args=self.span_args())
+                              now_pc, args=self.span_args(extra=step_args))
         else:
             lane_idx, lane, final, n_chunk = fused_info
             req = lane.request
@@ -328,24 +391,14 @@ class Telemetry:
             name = "step.fused" if kind == "pipelined" else "step.spec_fused"
             self.tracer.slice(
                 name, "pipeline", t_dispatch, now_pc, req_id=req_id,
-                args=self.span_args(req, {"chunk": n_chunk, "final": final}),
+                args=self.span_args(
+                    req, {"chunk": n_chunk, "final": final, **step_args}
+                ),
             )
             if req is not None:
                 self.on_prefill_chunk(req, lane_idx, t_dispatch, n_chunk,
-                                      fused=True)
+                                      fused=True, step=step)
         self.step_duration.observe(max(0.0, now_pc - t_dispatch))
-
-    def observe_sync_probe(self, breakdown: dict, steps: int = 1) -> None:
-        """Feed a measured per-step sync split (the dict from
-        ``engine.measured_sync_stats`` / ``measured_step_breakdown``) into
-        the ``dllama_sync_seconds`` histogram — one observation per
-        measured step, so the histogram count reads as probed steps. No-op
-        when the probe had no collective data (off-mesh, wall-only)."""
-        ms = breakdown.get("sync_ms")
-        if ms is None:
-            return
-        for _ in range(max(1, int(steps))):
-            self.sync_seconds.observe(ms / 1e3)
 
     def on_flush(self, live: int, admitting: int) -> None:
         self.tracer.instant(

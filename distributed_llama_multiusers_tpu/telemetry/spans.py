@@ -18,13 +18,19 @@ exactly the timebase Chrome trace events want (µs offsets, not wall
 time). The ring evicts oldest-first under overflow and counts what it
 dropped, so a trace pulled from a long-lived server is the most recent
 window, honestly labelled.
+
+The ring's clock is the host's own. The same spans reach the DEVICE's
+clock through ``Telemetry.span`` (hub.py), which holds a profiler
+annotation open around the span it records here: inside a
+``jax.profiler`` session the host plane of the ``.xplane.pb`` then carries
+``dl.<name>`` beside the device's operations, with no clock arithmetic.
 """
 
 from __future__ import annotations
 
 import time
 from collections import deque
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 
 from ..lockcheck import make_lock
 from .tracectx import trace_id_of
@@ -38,7 +44,9 @@ from .tracectx import trace_id_of
 #   step.sync/spec/multi  X  one synchronous engine dispatch
 #   step.pipelined  X  pipelined step, dispatch -> lagged consume
 #   step.fused      X  fused prefill+decode step, dispatch -> lagged consume
-#   submitted / admitted / finish.<reason> / pipeline.flush   i  instants
+#   loop.admit/dispatch/wait/stream  X  the four parts of one iteration of
+#                      the pipelined batching loop (names.py), track "loop"
+#   submitted / finish.<reason> / pipeline.flush   i  instants
 
 
 @dataclass(frozen=True)
@@ -92,10 +100,13 @@ class SpanTracer:
     def now(self) -> float:
         return time.perf_counter()
 
-    def _append(self, ev: SpanEvent) -> None:
+    def _append(self, name: str, ph: str, ts: float, dur: float, track: str,
+                req_id: int | None, args: dict | None) -> None:
         with self._trace_lock:
             self._trace_seq += 1
-            ev = replace(ev, seq=self._trace_seq)
+            # built once, with its cursor: the lock is what orders `seq`
+            ev = SpanEvent(name, ph, ts, dur, track, req_id, args,
+                           self._trace_seq)
             if len(self._trace_ring) >= self.capacity:
                 old = self._trace_ring.popleft()
                 self._trace_dropped += 1
@@ -110,15 +121,13 @@ class SpanTracer:
         """Record a complete span [t0, t1] (t1 defaults to now)."""
         if t1 is None:
             t1 = time.perf_counter()
-        self._append(SpanEvent(
-            name, "X", t0, max(0.0, t1 - t0), track, req_id, args
-        ))
+        self._append(name, "X", t0, max(0.0, t1 - t0), track, req_id, args)
 
     def instant(self, name: str, track: str, ts: float | None = None,
                 req_id: int | None = None, args: dict | None = None) -> None:
         if ts is None:
             ts = time.perf_counter()
-        self._append(SpanEvent(name, "i", ts, 0.0, track, req_id, args))
+        self._append(name, "i", ts, 0.0, track, req_id, args)
 
     def snapshot(self, since: int = 0,
                  trace_id: str | None = None) -> list[SpanEvent]:
@@ -155,6 +164,23 @@ class SpanTracer:
             }
 
 
+def _ttft_parts(submitted, admitted, first_dispatch, prefill_done,
+                first_token) -> tuple:
+    """(queue wait, dispatch wait, prefill, first-token hold) in seconds,
+    each None without a first token or an admission. Consecutive
+    differences of one monotone chain, so they sum to ``first_token -
+    submitted`` exactly."""
+    if first_token is None or admitted is None:
+        return (None, None, None, None)
+    chain = [submitted, admitted]
+    for stamp in (first_dispatch, prefill_done):
+        # missing, or out of order: collapse onto the stamp before it
+        chain.append(chain[-1] if stamp is None else
+                     min(max(stamp, chain[-1]), first_token))
+    chain.append(first_token)
+    return tuple(b - a for a, b in zip(chain, chain[1:]))
+
+
 class RequestTrace:
     """Per-request latency record, attached to a ``Request`` at submit.
 
@@ -164,9 +190,9 @@ class RequestTrace:
     machinery orders after the scheduler's last write."""
 
     __slots__ = (
-        "submitted_at", "admitted_at", "first_token_at", "last_token_at",
-        "gaps", "n_tokens", "fused_admitted", "prefix_saved",
-        "span_t0", "lane", "swap_in_s", "sync_s",
+        "submitted_at", "admitted_at", "first_dispatch_at",
+        "prefill_done_at", "first_token_at", "last_token_at", "gaps", "n_tokens", "fused_admitted", "prefix_saved",
+        "span_t0", "lane", "swap_in_s",
     )
 
     def __init__(self, submitted_at: float | None = None):
@@ -175,6 +201,12 @@ class RequestTrace:
             time.monotonic() if submitted_at is None else submitted_at
         )
         self.admitted_at: float | None = None
+        # the request's first prompt chunk handed to the engine (fused or
+        # synchronous), and the readback of the step that carried its
+        # final chunk — where the host learns the boundary token, which
+        # the NEXT consumed step emits as the first token
+        self.first_dispatch_at: float | None = None
+        self.prefill_done_at: float | None = None
         self.first_token_at: float | None = None
         self.last_token_at: float | None = None
         self.gaps: list[float] = []  # inter-token gaps, seconds
@@ -184,11 +216,9 @@ class RequestTrace:
         # span clock (perf_counter) for the lifecycle slices
         self.span_t0 = time.perf_counter()
         self.lane: int | None = None
-        # phase attribution extras: host-tier swap-in cost paid at this
-        # request's admission, and measured per-request collective time
-        # (mesh runs only — stays 0 off-mesh)
+        # phase attribution extra: host-tier swap-in cost paid at this
+        # request's admission
         self.swap_in_s = 0.0
-        self.sync_s = 0.0
 
     def on_token(self, now: float) -> None:
         """Stamp one consumed token (``now`` = time.monotonic())."""
@@ -231,9 +261,15 @@ class RequestTrace:
         replica cannot see its own death; the router stamps the measured
         gap into the record it forwards when a stream was spliced."""
         ms = lambda v: 0.0 if v is None else round(max(0.0, v) * 1e3, 3)
-        prefill_s = None
-        if self.admitted_at is not None and self.first_token_at is not None:
-            prefill_s = self.first_token_at - self.admitted_at
+        # time to first token, cut at the stamps the scheduler leaves:
+        # submitted -> admitted -> first dispatch -> prefill done -> first
+        # token. A stamp that is missing (a request that ended early)
+        # falls back to the one before it, so the four always add up to
+        # ``ttft_ms`` where there is one.
+        waits = _ttft_parts(
+            self.submitted_at, self.admitted_at, self.first_dispatch_at,
+            self.prefill_done_at, self.first_token_at,
+        )
         decode_s = None
         if self.first_token_at is not None and self.last_token_at is not None:
             decode_s = self.last_token_at - self.first_token_at
@@ -242,13 +278,14 @@ class RequestTrace:
             total_s = self.last_token_at - self.submitted_at
         return {
             "queue_wait_ms": ms(self.queued_s),
-            "prefill_ms": ms(prefill_s),
+            "dispatch_wait_ms": ms(waits[1]),
+            "prefill_ms": ms(waits[2]),
+            "first_token_hold_ms": ms(waits[3]),
             "decode_ms": ms(decode_s),
             "itl_p50_ms": ms(self.tbt_quantile(0.50)),
             "itl_p99_ms": ms(self.tbt_quantile(0.99)),
             "migration_gap_ms": 0.0,
             "swap_in_ms": ms(self.swap_in_s),
-            "sync_ms": ms(self.sync_s),
             "ttft_ms": ms(self.ttft_s),
             "total_ms": ms(total_s),
         }

@@ -103,12 +103,14 @@ def trace_id_of(wire: str | None) -> str | None:
     return None if ctx is None else ctx.trace_id
 
 
-# phase keys every producer emits, in display order. ``sync_ms`` is only
-# non-zero when the mesh reports measured collective time; off-mesh it
-# stays 0 rather than absent so consumers need no key probing.
+# phase keys every producer emits, in display order.
+# The first four partition ``ttft_ms`` (RequestTrace.phases): queued,
+# admitted but not yet dispatched, prompt on the device, and the boundary
+# token held by the host until the next consumed step emits it.
 PHASE_KEYS = (
-    "queue_wait_ms", "prefill_ms", "decode_ms", "itl_p50_ms", "itl_p99_ms",
-    "migration_gap_ms", "swap_in_ms", "sync_ms", "ttft_ms", "total_ms",
+    "queue_wait_ms", "dispatch_wait_ms", "prefill_ms", "first_token_hold_ms",
+    "decode_ms", "itl_p50_ms", "itl_p99_ms",
+    "migration_gap_ms", "swap_in_ms", "ttft_ms", "total_ms",
 )
 
 

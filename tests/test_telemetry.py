@@ -658,19 +658,3 @@ def test_kv_swap_bridge_is_delta_fed_by_direction():
     assert re.search(r"^dllama_stats_pool_host_pages 7(\.0)?$", render, re.M)
     assert re.search(r"^dllama_stats_pool_host_bytes 448(\.0)?$", render, re.M)
     assert re.search(r"^dllama_stats_swap_in_ms 1\.25$", render, re.M)
-
-
-def test_observe_sync_probe_feeds_histogram():
-    """``observe_sync_probe`` turns a measured_step_breakdown dict into one
-    dllama_sync_seconds observation per probed step; wall-only breakdowns
-    (no collective data, e.g. off-mesh) observe nothing."""
-    tel = Telemetry(logger=JsonLogger(stream=io.StringIO()))
-    tel.observe_sync_probe({"step_ms": 5.0, "sync_ms": None}, steps=4)
-    assert tel.sync_seconds.count == 0
-    tel.observe_sync_probe({"step_ms": 5.0}, steps=4)  # key absent entirely
-    assert tel.sync_seconds.count == 0
-    tel.observe_sync_probe({"step_ms": 5.0, "sync_ms": 2.0}, steps=4)
-    assert tel.sync_seconds.count == 4
-    # the observed value is seconds (2 ms each)
-    q = tel.sync_seconds.quantile(0.5)
-    assert q is not None and 5e-4 < q < 5e-3

@@ -1,0 +1,395 @@
+"""One trace from inside the program (telemetry/names.py).
+
+(a) every step family carries the ``dl.*`` device scopes, and its heavy
+    operations all sit under one;
+(b) a pipelined scheduler run leaves, per dispatch, the ``loop.*`` slices in
+    order, apart, covering the loop's wall time, with nothing dropped;
+(c) per finished request the four phases of the first token add up to
+    ``ttft_ms``;
+(d) ``Telemetry.span`` holds an annotation ``dl.<name>`` open around every
+    slice it records, and records the same ring without a factory.
+"""
+
+import re
+import time
+
+import numpy as np
+import pytest
+import jax.numpy as jnp
+
+from distributed_llama_multiusers_tpu.formats import load_model_header
+from distributed_llama_multiusers_tpu.formats.synthetic import (
+    tiny_header,
+    write_synthetic_model,
+)
+from distributed_llama_multiusers_tpu.models import load_params_from_m
+from distributed_llama_multiusers_tpu.runtime import (
+    ContinuousBatchingScheduler,
+    InferenceEngine,
+    Request,
+)
+from distributed_llama_multiusers_tpu.telemetry import PHASE_KEYS, Telemetry
+from distributed_llama_multiusers_tpu.telemetry import names
+from distributed_llama_multiusers_tpu.utils.testing import (
+    MockAsyncEngine,
+    StubStreamTokenizer,
+)
+
+# ---------------------------------------------------------------------------
+# (a) device scopes in the lowered step programs
+# ---------------------------------------------------------------------------
+
+MODELS = {
+    "dense": {},
+    "biased": dict(dim=64, hidden_dim=160, vocab_size=96, seq_len=48, qkv_bias=1),
+    "moe": dict(n_experts=4, n_active_experts=2),
+}
+PROGRAMS = {
+    # attribute of the engine -> how to make it run once
+    "_decode_pl_fn": lambda e, z: (e.decode_pipelined(z, tokens=z), e.pipeline_flush()),
+    "_decode_prefill_fn": lambda e, z: (
+        e.decode_prefill_fused(np.full(len(z), e.config.seq_len, np.int32),
+                               p_lane=0, chunk=[1, 2, 3], tokens=z),
+        e.pipeline_flush()),
+    "_decode_nologits_fn": lambda e, z: e.decode(z, z, want_logits=False),
+    "_prefill_fn": lambda e, z: e.prefill_chunk(0, [1, 2, 3], 0),
+}
+# operations that do the step's work: each must sit under some dl.* scope
+HEAVY = re.compile(
+    r"\b(stablehlo\.dot_general|stablehlo\.sort|chlo\.top_k|stablehlo\.scatter|"
+    r"stablehlo\.dynamic_update_slice|stablehlo\.custom_call)\b")
+LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
+LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
+
+
+@pytest.fixture(scope="module")
+def engines(tmp_path_factory):
+    d = tmp_path_factory.mktemp("tracing_models")
+    made = {}
+
+    def get(model: str, paged: bool):
+        key = (model, paged)
+        if key not in made:
+            path = str(d / f"{model}.m")
+            write_synthetic_model(path, tiny_header(**MODELS[model]), seed=5)
+            config, params = load_params_from_m(
+                path, load_model_header(path), dtype=jnp.float32)
+            made[key] = InferenceEngine(config, params, n_lanes=2,
+                                        prefill_buckets=(4,), paged_kv=paged)
+        return made[key]
+
+    return get
+
+
+def lowered_with_debug_info(engine, attr: str) -> str:
+    """The StableHLO text, locations included, of the program ``attr`` as the
+    engine's own entry point calls it."""
+    fn, seen = getattr(engine, attr), []
+
+    def spy(*args):
+        seen.append(fn.lower(*args).as_text(debug_info=True))
+        return fn(*args)
+
+    setattr(engine, attr, spy)
+    try:
+        PROGRAMS[attr](engine, np.zeros(engine.n_lanes, np.int32))
+    finally:
+        setattr(engine, attr, fn)
+    return seen[0]
+
+
+@pytest.mark.parametrize("attr", sorted(PROGRAMS))
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_step_program_carries_every_scope(engines, model, paged, attr):
+    text = lowered_with_debug_info(engines(model, paged), attr)
+    locs = dict(LOC_DEF.findall(text))
+    for scope in names.ALL_SCOPES:
+        assert any(scope in names.scope_path(n) for n in locs.values()), scope
+    heavy = unscoped = 0
+    for line in text.splitlines():
+        if not HEAVY.search(line.split(" loc(")[0]):
+            continue
+        use = LOC_USE.search(line)
+        if use is None:   # an op with a region: its location closes the region
+            continue
+        heavy += 1
+        if names.scope_of(locs.get(use.group(1), "")) is None:
+            unscoped += 1
+            print("no scope:", line.strip()[:160], locs.get(use.group(1)))
+    assert heavy >= 8 and unscoped == 0
+
+
+def test_scope_of_takes_the_deepest_component():
+    p = "jit(_decode_pl)/jit(main)/dl.layers/while/body/closed_call/dl.attention/dot_general:"
+    assert names.scope_path(p) == ["dl.layers", "dl.attention"]
+    assert names.scope_of(p) == "dl.attention"
+    assert names.scope_of("jit(_decode_pl)/dl.sampler/cond/branch_1_fun/vmap()/top_k") == "dl.sampler"
+    assert names.scope_of("jit(_decode_pl)/dl.layers/while/body/dynamic_update_slice") == "dl.layers"
+    assert names.scope_of("jit(_decode_pl)/vmap(dl.sampler)/sort") == "dl.sampler"
+    assert names.scope_of("jit(model.embed)/gather") is None and names.scope_of("") is None
+    assert set(names.LEAF_SCOPES) | {names.SCOPE_LAYERS} == set(names.ALL_SCOPES)
+    assert set(names.LAYER_SCOPES) < set(names.LEAF_SCOPES)
+
+
+# ---------------------------------------------------------------------------
+# (b) - (d) the batching loop's spans and the first token's stamps
+# ---------------------------------------------------------------------------
+
+
+class FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs its open and close."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        FakeAnnotation.log.append(("open", self.name, time.perf_counter()))
+        return self
+
+    def __exit__(self, *exc):
+        FakeAnnotation.log.append(("close", self.name, time.perf_counter()))
+        return False
+
+
+def run_scheduler(reqs, stagger=True, annotate=None, prompt_tokens=8, **kw):
+    """A few requests through the real scheduler loop over the mock engine:
+    the first is admitted synchronously (idle scheduler), the others ride the
+    live chain as fused admissions."""
+    engine = MockAsyncEngine(n_lanes=4, step_s=0.002, max_chunk=16)
+    tel = Telemetry()
+    if annotate is not None:
+        tel.annotation_factory = annotate
+    sched = ContinuousBatchingScheduler(
+        engine, StubStreamTokenizer(prompt_tokens=prompt_tokens), telemetry=tel,
+        speculative=False, multi_step=0, prefix_min_tokens=0, **kw)
+    sched.start()
+    try:
+        sched.submit(reqs[0])
+        deadline = time.monotonic() + 60
+        while stagger and len(reqs[0].generated_tokens) < 3:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        for r in reqs[1:]:
+            sched.submit(r)
+            time.sleep(0.003 if stagger else 0)
+        for r in reqs:
+            r.future.result(timeout=60)
+    finally:
+        sched.stop()
+    assert all(r.error is None for r in reqs)
+    return sched, tel
+
+
+def some_requests(n=5, max_tokens=24, prompt="hello there"):
+    return [Request(prompt=prompt, max_tokens=max_tokens - i, temperature=0.0)
+            for i in range(n)]
+
+
+@pytest.fixture(scope="module")
+def traced_run():
+    FakeAnnotation.log = []
+    reqs = some_requests()
+    sched, tel = run_scheduler(reqs, annotate=FakeAnnotation)
+    return reqs, sched, tel, list(FakeAnnotation.log)
+
+
+def loop_slices(tel):
+    return [e for e in tel.tracer.snapshot() if e.track == names.LOOP_TRACK]
+
+
+def test_loop_slices_partition_every_step(traced_run):
+    _reqs, sched, tel, _log = traced_run
+    counts = tel.tracer.counts()
+    assert counts["trace_events_dropped"] == 0
+    loop = loop_slices(tel)
+    assert {e.name for e in loop} == set(names.LOOP_SPANS)
+    by_step: dict = {}
+    for e in loop:
+        by_step.setdefault(e.args["step"], {}).setdefault(e.name, []).append(e)
+    steps = sorted(s for s, d in by_step.items() if names.LOOP_DISPATCH in d)
+    assert steps == list(range(1, sched._step_seq + 1)) and len(steps) > 20
+    order = (names.LOOP_ADMIT, names.LOOP_DISPATCH, names.LOOP_WAIT, names.LOOP_STREAM)
+    with_admit = 0
+    for s in steps:
+        d = by_step[s]
+        # every dispatched step was waited for and streamed, once each; the
+        # admission that precedes a dispatch carries the same step (a ring
+        # fill dispatches twice behind one admission)
+        assert [len(d.get(n, [])) for n in order[1:]] == [1, 1, 1], (s, d)
+        assert len(d.get(names.LOOP_ADMIT, [])) <= 1
+        with_admit += names.LOOP_ADMIT in d
+        present = [d[n][0] for n in order if n in d]
+        for a, b in zip(present, present[1:]):
+            assert a.ts + a.dur <= b.ts + 1e-9, (s, a, b)
+    assert with_admit >= len(steps) - 4
+    # the step slice of the same dispatch carries the same number
+    stepped = [e for e in tel.tracer.snapshot()
+               if e.name in ("step.pipelined", "step.fused")]
+    assert sorted(e.args["step"] for e in stepped) == steps
+    fused_steps = {e.args["step"] for e in stepped if e.name == "step.fused"}
+    chunks = [e for e in tel.tracer.snapshot() if e.name == "prefill.fused"]
+    assert fused_steps and {e.args["step"] for e in chunks} == fused_steps
+
+
+def test_loop_slices_do_not_overlap_and_cover_the_loop(traced_run):
+    _reqs, _sched, tel, _log = traced_run
+    loop = sorted(loop_slices(tel), key=lambda e: e.ts)
+    for a, b in zip(loop, loop[1:]):
+        assert a.ts + a.dur <= b.ts + 1e-9, (a, b)
+    # one pipelined run per burst of work: measure coverage inside each
+    # stretch of consecutive iterations (a gap of over 50 ms is the loop
+    # parked on an empty queue, which is not the pipelined loop's time)
+    covered = wall = 0.0
+    start = loop[0].ts
+    for a, b in zip(loop, loop[1:] + [None]):
+        covered += a.dur
+        if b is None or b.ts - (a.ts + a.dur) > 0.05:
+            wall += a.ts + a.dur - start
+            start = b.ts if b is not None else start
+    assert covered / wall >= 0.95, covered / wall
+
+
+def test_every_span_slice_has_its_annotation(traced_run):
+    _reqs, _sched, tel, log = traced_run
+    loop = sorted(loop_slices(tel), key=lambda e: e.seq)
+    opens = [(n, t) for kind, n, t in log if kind == "open"]
+    closes = [(n, t) for kind, n, t in log if kind == "close"]
+    assert len(opens) == len(closes) == len(loop)
+    # annotations never nest here, so the k-th open pairs with the k-th close
+    # slice timestamps are perf_counter values, as the log's are
+    for ev, (n_open, t_open), (n_close, t_close) in zip(loop, opens, closes):
+        assert n_open == n_close == names.ANNOTATION_PREFIX + ev.name
+        assert t_open <= t_close
+        # the slice lies inside its annotation (opened just before, closed
+        # just before the slice was appended)
+        assert t_open - 1e-3 <= ev.ts <= ev.ts + ev.dur <= t_close + 1e-3
+
+
+def test_without_a_factory_the_ring_is_the_same():
+    tel = Telemetry()
+    assert tel.annotation_factory is None
+    with tel.span("loop.wait", "loop", args={"step": 7}):
+        pass
+    FakeAnnotation.log = []
+    tel2 = Telemetry()
+    tel2.annotation_factory = FakeAnnotation
+    with tel2.span("loop.wait", "loop", args={"step": 7}):
+        pass
+    (a,), (b,) = tel.tracer.snapshot(), tel2.tracer.snapshot()
+    assert (a.name, a.ph, a.track, a.req_id, a.args, a.seq) == \
+        (b.name, b.ph, b.track, b.req_id, b.args, b.seq) == \
+        ("loop.wait", "X", "loop", None, {"step": 7}, 1)
+    assert [(k, n) for k, n, _t in FakeAnnotation.log] == [
+        ("open", "dl.loop.wait"), ("close", "dl.loop.wait")]
+
+
+def test_span_records_its_slice_when_the_body_raises():
+    tel = Telemetry()
+    tel.annotation_factory = FakeAnnotation
+    FakeAnnotation.log = []
+    with pytest.raises(RuntimeError):
+        with tel.span("loop.dispatch", "loop"):
+            raise RuntimeError("dispatch failed")
+    assert [e.name for e in tel.tracer.snapshot()] == ["loop.dispatch"]
+    assert [k for k, _n, _t in FakeAnnotation.log] == ["open", "close"]
+
+
+def test_scheduler_injects_the_profiler_annotation():
+    import jax
+
+    sched = ContinuousBatchingScheduler(
+        MockAsyncEngine(n_lanes=2), StubStreamTokenizer(), telemetry=Telemetry())
+    assert sched.telemetry.annotation_factory is jax.profiler.TraceAnnotation
+    own = Telemetry()
+    own.annotation_factory = FakeAnnotation
+    sched = ContinuousBatchingScheduler(
+        MockAsyncEngine(n_lanes=2), StubStreamTokenizer(), telemetry=own)
+    assert sched.telemetry.annotation_factory is FakeAnnotation
+
+
+def test_event_seq_is_assigned_once_at_construction():
+    tel = Telemetry(trace_capacity=4)
+    for i in range(6):
+        tel.tracer.instant(f"i{i}", "queue")
+    evs = tel.tracer.snapshot()
+    assert [e.seq for e in evs] == [3, 4, 5, 6] and [e.name for e in evs] == ["i2", "i3", "i4", "i5"]
+    assert tel.tracer.snapshot(since=5)[0].seq == 6
+    assert tel.tracer.counts()["trace_events_dropped"] == 2
+
+
+FIRST_TOKEN_PHASES = ("queue_wait_ms", "dispatch_wait_ms", "prefill_ms", "first_token_hold_ms")
+
+ADMISSIONS = {
+    # name -> (scheduler kwargs, prompt length in tokens, index of the request looked at)
+    "fused": (dict(pipelined=True, fused_prefill=True), 8, 2),
+    "synchronous": (dict(pipelined=False), 8, 1),
+    "several_chunks_fused": (dict(pipelined=True, fused_prefill=True), 40, 2),
+    "several_chunks_synchronous": (dict(pipelined=True, fused_prefill=False), 40, 1),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(ADMISSIONS))
+def test_first_token_phases_add_up_to_ttft(kind):
+    kw, prompt_tokens, idx = ADMISSIONS[kind]
+    reqs = some_requests(4, max_tokens=12, prompt="p" * 64)
+    run_scheduler(reqs, prompt_tokens=prompt_tokens, **kw)
+    for r in reqs:
+        ph = r.summary["phases"]
+        assert set(ph) == set(PHASE_KEYS)
+        assert sum(ph[k] for k in FIRST_TOKEN_PHASES) == pytest.approx(ph["ttft_ms"], abs=0.01)
+        tel = r.tel
+        assert tel.submitted_at <= tel.admitted_at <= tel.first_dispatch_at \
+            <= tel.prefill_done_at <= tel.first_token_at
+    r = reqs[idx]
+    assert r.tel.fused_admitted == kind.endswith("fused")
+    ph = r.summary["phases"]
+    if r.tel.fused_admitted:
+        # the prompt rode the (mock) device's steps: 2 ms a chunk at least
+        # (the mock's synchronous prefill takes no device time)
+        n_chunks = -(-prompt_tokens // 16)
+        assert ph["prefill_ms"] >= 2.0 * n_chunks * 0.9
+    # and the first token waited for the next consumed step
+    assert ph["first_token_hold_ms"] >= 1.0
+
+
+def test_phases_without_a_first_token_are_zero_not_missing():
+    from distributed_llama_multiusers_tpu.telemetry import RequestTrace
+
+    tel = RequestTrace(submitted_at=10.0)
+    tel.admitted_at = 10.5
+    ph = tel.phases()
+    assert ph["queue_wait_ms"] == 500.0
+    assert [ph[k] for k in FIRST_TOKEN_PHASES[1:]] == [0.0, 0.0, 0.0] and ph["ttft_ms"] == 0.0
+    # a missing middle stamp collapses onto the one before it
+    tel.first_token_at = tel.last_token_at = 11.0
+    ph = tel.phases()
+    assert (ph["dispatch_wait_ms"], ph["prefill_ms"], ph["first_token_hold_ms"]) == (0.0, 0.0, 500.0)
+    assert sum(ph[k] for k in FIRST_TOKEN_PHASES) == ph["ttft_ms"] == 1000.0
+
+
+def test_warmup_logs_one_line_a_program(engines, capsys):
+    import io
+    import json
+
+    from distributed_llama_multiusers_tpu.runtime.engine import warmup_engine
+    from distributed_llama_multiusers_tpu.telemetry import logs
+
+    stream = io.StringIO()
+    old = logs.default_logger().stream
+    logs.default_logger().stream = stream
+    try:
+        warmup_engine(engines("dense", False), spec=False, multi_step=0)
+    finally:
+        logs.default_logger().stream = old
+    lines = [json.loads(x) for x in stream.getvalue().splitlines()]
+    progs = [x for x in lines if x["event"] == "warmup_program"]
+    assert [p["program"] for p in progs] == [
+        "prefill[4]", "decode", "decode_nologits", "decode_pl", "decode_prefill[4]",
+        "copy_lane", "sample_one"]
+    for p in progs:
+        assert p["seconds"] >= 0 and p["source"] in ("compiled", "cache", "memory")
+        assert (p["compiled"] > 0) == (p["source"] == "compiled")
+    assert lines[-1]["event"] == "warmup_engine"
