@@ -14,6 +14,20 @@ with GQA attention over a pre-allocated per-lane KV cache, interleaved RoPE,
 and SiLU/GELU gated FFN. All reductions and attention math run in float32;
 matmuls run in the params' dtype (bf16 on TPU) with f32 accumulation.
 
+How the cache moves. The stacked K and V arrays (``[L, ...]``) ride the
+layer scan as its CARRY, next to the residual stream; the scanned input is
+the layers' weights and the layer index ``l``. Layer ``l`` scatters its fresh
+rows straight into the stack (``k_all.at[l, lane, position]``; paged:
+``k_all.at[l, page, slot]``) and then reads its plane back out of the carry,
+rows included. Nothing of the cache's size is a scanned input or a stacked
+output: every step family donates the cache (``donate_argnums`` in
+runtime/engine.py), and a donated buffer aliases through a loop only as its
+carry. With the stack as scanned input and stacked output XLA kept both, and
+a one-row append cost two whole-cache copies plus a slice and a write-back of
+every layer's plane (two thirds of a 7B decode step on a v5e, PERF.md
+section 6, PR 27). The scan runs over one run of identical layers whose cache
+is addressed by layer index (ROADMAP D5's shape).
+
 Optional ``emulate_q80_activations`` reproduces the reference's lossy
 activation quantization (cast to Q80 before each quantized matmul and at the
 TP sync boundary, src/llm.cpp:232-239,308-314) for numerical parity testing.
@@ -26,6 +40,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import shard_map
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import HiddenAct
 from ..ops.activations import gelu, silu
@@ -369,6 +384,13 @@ def llama_forward(
     Works for prefill (T > 1) and decode (T = 1) alike; the KV cache is
     per-lane (fixes reference defect (c) where all lanes shared one cache).
 
+    The cache is the layer scan's carry and is appended in place (module
+    header, "How the cache moves"): a caller that donates ``cache`` gets the
+    same buffers back with ``B * T`` rows a layer written, and no copy of a
+    plane or of the stack is part of the program's dataflow. Attention reads
+    layer ``l``'s plane AFTER that layer's append, so a query sees its own
+    fresh key, as it did when each plane was updated on its own.
+
     ``cache`` may be a :class:`PagedKVCache` (paged attention): K/V are
     gathered per lane through the page table into the same ``[B, S, ...]``
     view the contiguous path reads — identical values in identical order,
@@ -506,9 +528,13 @@ def llama_forward(
                 + jnp.arange(page, dtype=jnp.int32)[None, None, :]
             ).reshape(b, n_blocks * page)[:, : h_cfg.seq_len]  # [B, S]
 
-    def layer_step(x, layer_in):
-        lp, k_cache, v_cache = layer_in  # contiguous: [B, S, n_kv, hd];
-        # paged: [n_pages, page_size, n_kv, hd]
+    row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
+
+    def layer_step(carry, layer_in):
+        # the stacked cache rides the carry ([L, ...]; module header, "How
+        # the cache moves"); ``l`` is this layer's index into it
+        x, k_all, v_all = carry
+        lp, l = layer_in
         dtype = x.dtype
 
         with jax.named_scope(SCOPE_QKV):
@@ -520,6 +546,13 @@ def llama_forward(
 
             q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
             k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
+            # all three projections finish before the cache is touched. Left
+            # to itself XLA schedules the wq kernel between the K plane's read
+            # and the scores that use it, the kernel claims the fast memory
+            # the plane could sit in, and the plane is parked in HBM instead:
+            # written and read once more, 6.5 ms against 2.9 a 7B decode step
+            # on a v5e (PERF.md section 6, PR 27). An identity on the values.
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
 
         # KV append at per-lane positions (reference OP_SHIFT, scatter on
         # TPU). mode="drop" pins JAX's default out-of-bounds scatter
@@ -530,26 +563,30 @@ def llama_forward(
         # instead of (lane, position) — same drop rule, and unmapped
         # sentinel entries drop the write too.
         with jax.named_scope(SCOPE_KV_WRITE):
-            if paged:
-                k_cache = k_cache.at[w_page, w_slot].set(
-                    _to_cache_dtype(k, k_cache.dtype), mode="drop"
-                )
-                v_cache = v_cache.at[w_page, w_slot].set(
-                    _to_cache_dtype(v, v_cache.dtype), mode="drop"
-                )
-            else:
-                k_cache = k_cache.at[lane_idx, positions].set(
-                    _to_cache_dtype(k, k_cache.dtype), mode="drop"
-                )
-                v_cache = v_cache.at[lane_idx, positions].set(
-                    _to_cache_dtype(v, v_cache.dtype), mode="drop"
-                )
+            at = (l, w_page, w_slot) if paged else (l, lane_idx, positions)
+            k_all = k_all.at[at].set(_to_cache_dtype(k, k_all.dtype), mode="drop")
+            v_all = v_all.at[at].set(_to_cache_dtype(v, v_all.dtype), mode="drop")
+            # the stack keeps the row-major layout it arrives and leaves in.
+            # Left free, XLA gives the carry of a loop whose attention is wide
+            # (a 1024-token prefill chunk at 4 kv heads) a kv-head-major
+            # layout and converts the WHOLE carry to the scatter's layout
+            # and back in every layer (PERF.md section 6, PR 27). One device
+            # only: GSPMD cannot partition the constraint and would gather
+            # a sharded cache to apply it
+            if mesh is None:
+                k_all = with_layout_constraint(k_all, row_major)
+                v_all = with_layout_constraint(v_all, row_major)
 
         # GQA attention in f32 (reference multiheadAtt_F32, nn-cpu-ops.cpp:749-784)
         with jax.named_scope(SCOPE_ATTENTION):
             group = n_heads // n_kv
             qf = q.astype(jnp.float32).reshape(b, t, n_kv, group, hd)
             scale = 1.0 / float(hd) ** 0.5
+            # layer l's plane, read out of the carry AFTER the append: the
+            # fresh rows are in it (contiguous: [B, S, n_kv, hd]; paged:
+            # [n_pages, page_size, n_kv, hd])
+            k_cache = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
+            v_cache = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
             if paged:
                 # gather each lane's logical [S] view through the page table:
                 # the same values a contiguous lane plane would hold, in the
@@ -594,11 +631,12 @@ def llama_forward(
                 u = sliced_matmul(yqs, lp.w3)
                 x = x + synced_matmul(maybe_qdq(g * u), lp.w2)
 
-        return x, (k_cache, v_cache)
+        return (x, k_all, v_all), None
 
     with jax.named_scope(SCOPE_LAYERS):
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_step, x, (params.layers, cache.k, cache.v)
+        layer_index = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+        (x, new_k, new_v), _ = jax.lax.scan(
+            layer_step, (x, cache.k, cache.v), (params.layers, layer_index)
         )
 
     with jax.named_scope(SCOPE_HEAD):
