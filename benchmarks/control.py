@@ -30,7 +30,7 @@ ROOT = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, BENCH_DIR)
 
-from harness import cells, correct, weights  # noqa: E402
+from harness import cells, correct  # noqa: E402
 
 VARIANTS = {
     "as_configured": {},
@@ -48,13 +48,13 @@ COMPARED = ("prefill_rel_err", "decode_rel_err", "route_greedy_gap",
             "route_nucleus_excess", "route_kv_rel_err")
 
 
-def readings(cfg: dict, variant: str, seeds, log=print) -> list[dict]:
+def readings(family, cfg: dict, variant: str, seeds, log=print) -> list[dict]:
     import jax.numpy as jnp
 
     from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
 
     serving = cfg["serving"]
-    config = cells.llama_config(cfg)
+    config = family.program_config(cfg)
     dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
               "float8_e4m3fn": jnp.float8_e4m3fn}
     kw = dict(VARIANTS[variant])
@@ -68,16 +68,16 @@ def readings(cfg: dict, variant: str, seeds, log=print) -> list[dict]:
         if isinstance(subject, InferenceEngine):
             subject.params = None  # free last seed's weights before the next
         gc.collect()
-        tensors = weights.device_weights(config, seed, dtypes[serving["activations"]])
+        tensors = family.device_weights(config, seed, dtypes[serving["activations"]])
         if subject is None:
             subject = InferenceEngine(
-                config, weights.assemble_params(config, tensors),
+                config, family.assemble_params(config, tensors),
                 n_lanes=int(serving["lanes"]), cache_dtype=cache_dtype, **kw,
             )
         elif isinstance(subject, InferenceEngine):
             # the weights are an operand of every program: nothing recompiles
-            subject.params = weights.assemble_params(config, tensors)
-        r = correct.compare(cfg, tensors, subject, seed, fault=fault, keep_rows=True)
+            subject.params = family.assemble_params(config, tensors)
+        r = correct.compare(family, cfg, tensors, subject, seed, fault=fault, keep_rows=True)
         r.update(seed=seed, variant=variant, seconds=round(time.monotonic() - t0, 1))
         log(json.dumps({k: v for k, v in r.items() if k != "row_errors"}))
         out.append(r)
@@ -105,12 +105,13 @@ def main() -> int:
     dev = jax.devices()[0]
     bench = cells.load_benchmark()
     cfg = cells.load_config_file(bench, args.config)
+    family = cells.load_family(cfg, bench.get("families_dir"))
     result = {"config": args.config, "device": dev.device_kind, "platform": dev.platform}
     chosen = args.variants.split(",")
     for variant in chosen:
         n = args.seeds if variant == "as_configured" else args.control_seeds
         seeds = [args.first_seed + 7919 * i for i in range(n)]
-        result[variant] = readings(cfg, variant, seeds)
+        result[variant] = readings(family, cfg, variant, seeds)
     summary = {"config": args.config, "device": dev.device_kind}
     for key in COMPARED:
         summary[key] = {

@@ -1,23 +1,29 @@
-"""Cells, configurations and traffic mixes, found by the names in BENCHMARK.json.
+"""Cells, configurations, families and traffic mixes, found by the names in
+BENCHMARK.json.
 
 A cell is one entry of ``workloads``: it names a configuration (a file under
 ``benchmarks/configs/``, given by the configuration's ``file``) and a traffic
-mix (``benchmarks/traffic/<traffic>.json``). Nothing here knows a cell, a
-configuration or a mix by name: adding one adds files and an entry, and edits
-no file that is there.
+mix (``benchmarks/traffic/<traffic>.json``). A configuration file names its
+architecture's family module (``benchmarks/families/<family>.py``), which is
+all the harness knows of a model. Nothing here knows a cell, a configuration,
+a family or a mix by name: adding one adds files and an entry, and edits no
+file that is there.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
-# activation functions the program's LlamaConfig knows (formats/model_file.py
-# HiddenAct): the published ``hidden_act`` string -> that enum's value
-_HIDDEN_ACT = {"gelu": 0, "silu": 1}
+# the family of a configuration file that names none
+DEFAULT_FAMILY = "llama"
+# what a family module exports: all the harness asks of an architecture
+FAMILY_EXPORTS = ("program_config", "device_weights", "assemble_params",
+                  "reference_logits", "lane_state_rel_err")
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -69,29 +75,41 @@ def cell_metrics(bench: dict, cell_name: str, kind: str) -> list[dict]:
     ]
 
 
-def llama_config(cfg: dict):
-    """The program's LlamaConfig from a published ``config.json``'s keys, as
-    the configuration file holds them (``max_position_embeddings`` is the
-    serving context the file states)."""
-    from distributed_llama_multiusers_tpu.models.config import LlamaConfig
+def load_module(path: str, name: str):
+    """A benchmark file (a family, a metric's reader) as a module, by path."""
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
-    head = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-    if head * cfg["num_attention_heads"] != cfg["hidden_size"]:
-        raise SystemExit(
-            "the program derives the head size as hidden_size / heads; "
-            f"head_dim {head} x {cfg['num_attention_heads']} heads is not "
-            f"hidden_size {cfg['hidden_size']}"
-        )
-    return LlamaConfig(
-        dim=cfg["hidden_size"],
-        hidden_dim=cfg["intermediate_size"],
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        vocab_size=cfg["vocab_size"],
-        seq_len=cfg["max_position_embeddings"],
-        hidden_act=_HIDDEN_ACT[cfg["hidden_act"]],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_epsilon=float(cfg["rms_norm_eps"]),
-        qkv_bias=1 if cfg.get("attention_bias") else 0,
-    )
+
+def load_family(cfg: dict, families_dir: str | None = None):
+    """The module of the configuration's ``family``, loaded by its path:
+    ``<families_dir>/<family>.py`` where a benchmark file gives that directory
+    (relative to the root, as the rehearsal's does) and the file is there,
+    else ``benchmarks/families/<family>.py``. It exports FAMILY_EXPORTS:
+
+    - ``program_config(cfg)``: the object ``InferenceEngine`` takes; the
+      harness reads its ``vocab_size`` and ``seq_len`` and nothing else;
+    - ``device_weights(config, seed, dtype)``: name -> device array, made in
+      one jitted program from the seed (any whole number up to 2**32);
+    - ``assemble_params(config, tensors)``: the program's parameter tree
+      around those arrays;
+    - ``reference_logits(cfg, tensors, tokens, row_positions, lossy=None)``:
+      float32 logits ``[B, R, vocab]`` of the plain reference, which imports
+      nothing of the program; ``lossy`` names a type the control rounds to;
+    - ``lane_state_rel_err(engine, lane_x, lane_y, n)``: the largest relative
+      difference between what two lanes hold for their first ``n``
+      positions, over every kind of per-lane state, or None."""
+    name = cfg.get("family", DEFAULT_FAMILY)
+    dirs = [os.path.join(BENCH_DIR, "families")]
+    if families_dir:
+        dirs.insert(0, os.path.join(ROOT, families_dir))
+    path = next((p for d in dirs if os.path.exists(p := os.path.join(d, name + ".py"))), None)
+    if path is None:
+        raise SystemExit(f"no family {name!r}: no {name}.py under {dirs}")
+    mod = load_module(path, "bench_family_" + name)
+    missing = [f for f in FAMILY_EXPORTS if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"family {name!r} ({path}) does not export {', '.join(missing)}")
+    return mod

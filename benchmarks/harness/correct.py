@@ -32,15 +32,17 @@ largest logit it lies, in standard deviations of the row: 0 unless two logits
 tie); each sampled token against the nucleus computed here on the host from
 those logits (how far past ``top_p`` the probability before it reaches: 0
 inside the nucleus); the boundary token of each fused admission likewise
-against the logits `engine.prefill` gave for the same prompt; and the keys and
-values the fused step wrote against those `engine.prefill` wrote.
+against the logits `engine.prefill` gave for the same prompt; and the per-lane
+state the fused step wrote against what `engine.prefill` wrote.
+
+The model is reached through the configuration's family module alone
+(`cells.load_family`): its plain reference and its comparison of two lanes'
+state. Nothing here knows an architecture.
 """
 
 from __future__ import annotations
 
 import numpy as np
-
-from . import reference
 
 
 def sample_sequences(cfg: dict, seed: int):
@@ -101,10 +103,10 @@ def engine_logits(engine, prompts, forced, prefixes):
 REFERENCE_BATCH = 4  # sequences to a forward pass: what fits beside the engine
 
 
-def plain_logits(cfg: dict, weights: dict, prompts, forced, prefixes, lossy=None):
-    """The reference's logits at the same positions: a full forward pass over
-    prompt + forced tokens, a few sequences at a time. ``lossy``: see
-    `reference.reference_logits` (the control only)."""
+def plain_logits(family, cfg: dict, weights: dict, prompts, forced, prefixes, lossy=None):
+    """The family's reference logits at the same positions: a full forward
+    pass over prompt + forced tokens, a few sequences at a time. ``lossy``
+    (the control only) names the type the reference rounds to."""
     steps = len(forced[0])
     out = []
     for lo in range(0, len(prompts), REFERENCE_BATCH):
@@ -117,7 +119,7 @@ def plain_logits(cfg: dict, weights: dict, prompts, forced, prefixes, lossy=None
             tokens[row, : len(p) + steps] = p + forced[i]
             positions.append([n_p - 1 for n_p in prefixes[i]]
                              + list(range(len(p) - 1, len(p) + steps)))
-        out.append(reference.reference_logits(
+        out.append(family.reference_logits(
             cfg, weights, tokens, np.asarray(positions, np.int32), lossy=lossy))
     return np.concatenate(out)
 
@@ -156,23 +158,7 @@ def nucleus_excess(row: np.ndarray, token: int, temp: float, topp: float):
     return max(0.0, float(ahead[rank] - topp)), float(np.mean(ahead < topp))
 
 
-def _kv_rel_err(cache, lane_x: int, lane_y: int, n: int):
-    """Largest difference between two lanes' first n positions of keys and of
-    values, over the largest magnitude there; None for a cache that is not
-    the contiguous ``[layers, lanes, positions, heads, head size]`` pair."""
-    import jax.numpy as jnp
-
-    if getattr(cache, "table", None) is not None or cache.k.ndim != 5:
-        return None
-    worst = 0.0
-    for plane in (cache.k, cache.v):
-        x = np.asarray(plane[:, lane_x, :n].astype(jnp.float32))
-        y = np.asarray(plane[:, lane_y, :n].astype(jnp.float32))
-        worst = max(worst, float(np.abs(x - y).max() / max(np.abs(y).max(), 1e-30)))
-    return worst
-
-
-def route_check(cfg: dict, engine, prompts, forced, prompt_rows, seed: int,
+def route_check(family, cfg: dict, engine, prompts, forced, prompt_rows, seed: int,
                 fault: str | None = None) -> dict:
     """The pipelined and the fused programs against the synchronous ones, on
     the lanes `engine_logits` left filled (module docstring, **Routes**).
@@ -262,11 +248,12 @@ def route_check(cfg: dict, engine, prompts, forced, prompt_rows, seed: int,
         elif out is out_c:  # lane_b with its own (sampled)
             feed[lane_b], pos[lane_b] = out[1][n], len(prompts[ib])
     # the admissions: boundary tokens against engine.prefill's logits for the
-    # same prompt, and the keys and values written
+    # same prompt, and the per-lane state written (reported under the name it
+    # had when keys and values were the only kind)
     check_token(prompt_rows[ia], out_b[0][n], out_b[1][n], 0.0)
     check_token(prompt_rows[ib], out_c[0][n], out_c[1][n], temp)
-    kv = [_kv_rel_err(engine.cache, lane_a, ia, len(prompts[ia])),
-          _kv_rel_err(engine.cache, lane_b, ib, len(prompts[ib]))]
+    kv = [family.lane_state_rel_err(engine, lane_a, ia, len(prompts[ia])),
+          family.lane_state_rel_err(engine, lane_b, ib, len(prompts[ib]))]
     return {
         "route_greedy_gap": max(gaps),
         "route_nucleus_excess": max(excesses),
@@ -277,7 +264,7 @@ def route_check(cfg: dict, engine, prompts, forced, prompt_rows, seed: int,
     }
 
 
-def compare(cfg: dict, weights: dict, engine, seed: int, fault: str | None = None,
+def compare(family, cfg: dict, weights: dict, engine, seed: int, fault: str | None = None,
             keep_rows: bool = False) -> dict:
     """The numbers compared, each beside its limit, and the verdict.
     ``engine`` may be a type's name instead: the control then puts the
@@ -287,11 +274,11 @@ def compare(cfg: dict, weights: dict, engine, seed: int, fault: str | None = Non
     n_pre = len(prefixes[0]) + 1
     routes = {}
     if isinstance(engine, str):
-        got = plain_logits(cfg, weights, prompts, forced, prefixes, lossy=engine)
+        got = plain_logits(family, cfg, weights, prompts, forced, prefixes, lossy=engine)
     else:
         got = engine_logits(engine, prompts, forced, prefixes)
-        routes = route_check(cfg, engine, prompts, forced, got[:, n_pre - 1], seed, fault)
-    want = plain_logits(cfg, weights, prompts, forced, prefixes)
+        routes = route_check(family, cfg, engine, prompts, forced, got[:, n_pre - 1], seed, fault)
+    want = plain_logits(family, cfg, weights, prompts, forced, prefixes)
     err = relative_errors(got, want)
     out = {
         "prefill_rel_err": _rms(err[:, :n_pre]),

@@ -1,4 +1,7 @@
-"""Seeded random weights, made on the device in one jitted call.
+"""Seeded random weights of the Llama family (`families/llama.py` exports
+`device_weights` and `assemble_params` from here), made on the device in one
+jitted call. `seed_key`, `q40_plane` and the reasoning behind `GAIN` serve any
+family's generator that draws Q40 planes.
 
 Copied from bench.py (`_weight_specs`, `_device_packed_params`,
 `_assemble_params`) and changed in three ways: the nibbles are symmetric about
@@ -23,7 +26,8 @@ def weight_specs(config) -> dict:
     L, d, h = config.n_layers, config.dim, config.hidden_dim
     kv = config.n_kv_heads * config.head_size
     if config.n_experts > 0:
-        raise SystemExit("the benchmark's generator makes dense models only")
+        raise SystemExit("the llama family's generator makes dense models only: "
+                         "a configuration with experts names a family of its own")
     from distributed_llama_multiusers_tpu.quants.packed import padded_d_out
 
     return {
@@ -73,22 +77,29 @@ GAIN = {"wq": 2.0, "wk": 2.0, "wv": 1.0, "wo": 0.3,
 _RMS_PER_A = (17.5 * (36.0 + 100.0 / 12.0)) ** 0.5
 
 
-def _generate(config, key, dtype):
+def q40_plane(kp, ks, lead, d_in: int, d_out: int, gain: float, live_out: int | None = None):
+    """One seeded Q40 tensor in the layout the program serves from, stacked
+    along ``lead``, whose product with an input of rms 1 has rms ``gain``.
+    Columns from ``live_out`` on (the loader's padding of a vocabulary) get
+    zero scales and dequantize to exact zeros."""
     from distributed_llama_multiusers_tpu.quants.packed import PackedQ40
 
+    pk = _symmetric_nibbles(jax.random.bits(kp, (*lead, d_in // 2, d_out), jnp.uint8))
+    sc = jax.random.uniform(ks, (*lead, d_in // 32, d_out), jnp.float32)
+    sc = (sc * 10.0 + 1.0) * (gain / (_RMS_PER_A * d_in ** 0.5))
+    if live_out is not None and d_out > live_out:
+        sc = jnp.where(jnp.arange(d_out) < live_out, sc, 0.0)
+    return PackedQ40(packed=pk, scales=sc.astype(jnp.float16))
+
+
+def _generate(config, key, dtype):
     L, d = config.n_layers, config.dim
     kv = config.n_kv_heads * config.head_size
     out = {}
     for name, (d_in, d_out, lead) in weight_specs(config).items():
         key, kp, ks = jax.random.split(key, 3)
-        pk = _symmetric_nibbles(jax.random.bits(kp, (*lead, d_in // 2, d_out), jnp.uint8))
-        a = GAIN[name] / (_RMS_PER_A * d_in ** 0.5)
-        sc = jax.random.uniform(ks, (*lead, d_in // 32, d_out), jnp.float32)
-        sc = (sc * 10.0 + 1.0) * a
-        if name == "wcls" and d_out > config.vocab_size:
-            # zero scales make the pad columns dequantize to exact zeros
-            sc = jnp.where(jnp.arange(d_out) < config.vocab_size, sc, 0.0)
-        out[name] = PackedQ40(packed=pk, scales=sc.astype(jnp.float16))
+        out[name] = q40_plane(kp, ks, lead, d_in, d_out, GAIN[name],
+                              live_out=config.vocab_size if name == "wcls" else None)
     key, ke, k1, k2, k3, kb = jax.random.split(key, 6)
     out["embedding"] = (
         jax.random.normal(ke, (config.vocab_size, d), jnp.float32)
@@ -104,15 +115,17 @@ def _generate(config, key, dtype):
     return out
 
 
-def device_weights(config, seed: int, dtype=jnp.bfloat16) -> dict:
-    """name -> device array (or PackedQ40 of two), all from one program.
-
-    ``seed`` may exceed 32 bits: it is folded into the key in two halves."""
+def seed_key(seed: int):
+    """The key of ``--seed``, which may exceed 31 bits: folded in two halves."""
     seed = int(seed)
-    key = jax.random.fold_in(
+    return jax.random.fold_in(
         jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF
     )
-    t = jax.jit(lambda k: _generate(config, k, dtype))(key)
+
+
+def device_weights(config, seed: int, dtype=jnp.bfloat16) -> dict:
+    """name -> device array (or PackedQ40 of two), all from one program."""
+    t = jax.jit(lambda k: _generate(config, k, dtype))(seed_key(seed))
     jax.block_until_ready(t)
     return t
 

@@ -21,7 +21,6 @@ T_PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
 import faulthandler  # noqa: E402
-import importlib.util  # noqa: E402
 import io  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -108,9 +107,10 @@ def check_devices(cell: dict, rehearse: bool):
     return devs
 
 
-def build_stack(cfg: dict, seed: int, traffic):
+def build_stack(family, cfg: dict, seed: int, traffic):
     """Weights, engine, tokenizer and scheduler as dllama-api's load_stack and
-    make_scheduler put them together, apart from where the weights come from."""
+    make_scheduler put them together, apart from where the weights come from:
+    the configuration's family module (`cells.load_family`) makes them."""
     import jax.numpy as jnp
 
     from distributed_llama_multiusers_tpu.runtime.engine import (
@@ -122,15 +122,14 @@ def build_stack(cfg: dict, seed: int, traffic):
         ContinuousBatchingScheduler,
     )
     from distributed_llama_multiusers_tpu.serving import QosQueue
-    from harness import weights
     from harness.tokenizer import BenchTokenizer
 
     serving = cfg["serving"]
-    config = cells.llama_config(cfg)
+    config = family.program_config(cfg)
     dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
     t0 = time.monotonic()
-    tensors = weights.device_weights(config, seed, dtypes[serving["activations"]])
-    params = weights.assemble_params(config, tensors)
+    tensors = family.device_weights(config, seed, dtypes[serving["activations"]])
+    params = family.assemble_params(config, tensors)
     t1 = time.monotonic()
     buckets = serving.get("prefill_buckets", "default")
     engine = InferenceEngine(
@@ -165,10 +164,7 @@ def build_stack(cfg: dict, seed: int, traffic):
 
 def load_metric(name: str):
     path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return cells.load_module(path, "bench_metric_" + name).read
 
 
 class StallWatch(threading.Thread):
@@ -246,6 +242,7 @@ def main(argv=None) -> int:
     cell = cells.find_cell(bench, args.workload)
     cfg = cells.load_config_file(bench, cell["config"])
     traffic_params = cells.load_traffic_file(cell["traffic"], bench.get("traffic_dir"))
+    family = cells.load_family(cfg, bench.get("families_dir"))
 
     import jax
 
@@ -263,7 +260,7 @@ def main(argv=None) -> int:
 
     peaks = None if dev.platform == "cpu" else chip_peaks(dev.device_kind)
     traffic = Traffic(traffic_params, lanes=int(cfg["serving"]["lanes"]))
-    config, tensors, engine, sched, prompts = build_stack(cfg, args.seed, traffic)
+    config, tensors, engine, sched, prompts = build_stack(family, cfg, args.seed, traffic)
 
     annotate = None
     consume_returns: list[float] = []
@@ -354,9 +351,7 @@ def main(argv=None) -> int:
                    for t in marks]
         log(f"requests due and still without a first token at each 5 s mark: {waiting}")
 
-    compared = correct.compare(cfg, tensors, engine, args.seed)
-    log(f"compared with the plain reference: {correct.describe(compared)}; "
-        f"tokens per finished request == max_tokens: {not wrong}")
+    compared = correct.compare(family, cfg, tensors, engine, args.seed)
 
     reduced = None
     if trace_file:
@@ -388,7 +383,6 @@ def main(argv=None) -> int:
         "correct": bool(compared["ok"] and failed == 0),
         "attempted": attempted, "failed": failed,
         "metrics": metrics, "device": device,
-        "compared": compared,
         "compile_cache": cache_counts.counts,
         # the first requests as the traffic file fixes them, and how many were
         # issued: the same for every seed
@@ -416,6 +410,11 @@ def main(argv=None) -> int:
         for key in ("busy_s", "window_s"):
             device[key] = "not measured"
         device["memory_peak_bytes"] = "not measured"
+    # each number compared beside its limit: the last lines of the log and the
+    # last key of the result's line, where the driver's record keeps them
+    result["compared"] = compared
+    log(f"correct {result['correct']}, compared with the plain reference: "
+        f"{correct.describe(compared)}; requests failed {failed} (limit 0)")
     print(json.dumps(result), flush=True)
     return 0
 

@@ -90,12 +90,13 @@ def main() -> int:
     cell = cells.find_cell(bench, args.workload)
     cfg = cells.load_config_file(bench, cell["config"])
     traffic_params = cells.load_traffic_file(cell["traffic"], bench.get("traffic_dir"))
+    family = cells.load_family(cfg, bench.get("families_dir"))
     run.setup_compile_cache()
     run.check_devices(cell, rehearse=args.rehearse)
     from harness.traffic import Traffic
 
     traffic = Traffic(traffic_params, lanes=int(cfg["serving"]["lanes"]))
-    config, _tensors, _engine, sched, prompts = run.build_stack(cfg, args.seed, traffic)
+    config, _tensors, _engine, sched, prompts = run.build_stack(family, cfg, args.seed, traffic)
     sched.start()
     rows = []
     try:

@@ -12,7 +12,7 @@ import pytest
 
 import control
 from harness import correct
-from harness.cells import BENCH_DIR
+from harness.cells import BENCH_DIR, load_family
 
 LOGITS = ("prefill_rel_err", "decode_rel_err")
 ROUTES = ("route_greedy_gap", "route_nucleus_excess", "route_kv_rel_err")
@@ -28,8 +28,13 @@ def cfg():
 
 
 @pytest.fixture(scope="module")
-def sound(cfg):
-    return control.readings(cfg, "as_configured", [11, 12, 13, 14], log=lambda s: None)
+def family(cfg):
+    return load_family(cfg)
+
+
+@pytest.fixture(scope="module")
+def sound(family, cfg):
+    return control.readings(family, cfg, "as_configured", [11, 12, 13, 14], log=lambda s: None)
 
 
 def test_engine_as_configured_is_correct(sound):
@@ -40,16 +45,16 @@ def test_engine_as_configured_is_correct(sound):
 
 
 @pytest.mark.parametrize("variant", ["f8_kv_cache", "q80_activations", "reference_in_f8"])
-def test_lower_precision_is_not_correct(cfg, sound, variant):
-    runs = control.readings(cfg, variant, [11, 12, 13], log=lambda s: None)
+def test_lower_precision_is_not_correct(family, cfg, sound, variant):
+    runs = control.readings(family, cfg, variant, [11, 12, 13], log=lambda s: None)
     assert not any(r["ok"] for r in runs), runs
     for key in LOGITS:
         largest_sound = max(r[key] for r in sound)
         assert min(r[key] for r in runs) > 3 * largest_sound
 
 
-def test_admission_into_the_wrong_lane_is_not_correct(cfg, sound):
-    runs = control.readings(cfg, "admits_swapped", [11, 12, 13], log=lambda s: None)
+def test_admission_into_the_wrong_lane_is_not_correct(family, cfg, sound):
+    runs = control.readings(family, cfg, "admits_swapped", [11, 12, 13], log=lambda s: None)
     assert not any(r["ok"] for r in runs), runs
     limits = cfg["correctness"]["limits"]
     for key in ROUTES:
