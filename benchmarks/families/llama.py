@@ -44,9 +44,10 @@ def program_config(cfg: dict):
 
 
 def lane_state_rel_err(engine, lane_x: int, lane_y: int, n: int):
-    """Largest difference between two lanes' first n positions of keys and of
-    values, the family's only per-lane state, over the largest magnitude
-    there; None for a cache that is not the contiguous
+    """Both lanes have absorbed the same n tokens. Largest difference
+    between their rows ``[0, n)`` of keys and of values, over the largest
+    magnitude there: the family's only per-lane state, all of it kept by
+    position. None for a cache that is not the contiguous
     ``[layers, lanes, positions, heads, head size]`` pair."""
     import jax.numpy as jnp
 

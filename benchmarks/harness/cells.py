@@ -98,9 +98,10 @@ def load_family(cfg: dict, families_dir: str | None = None):
     - ``reference_logits(cfg, tensors, tokens, row_positions, lossy=None)``:
       float32 logits ``[B, R, vocab]`` of the plain reference, which imports
       nothing of the program; ``lossy`` names a type the control rounds to;
-    - ``lane_state_rel_err(engine, lane_x, lane_y, n)``: the largest relative
-      difference between what two lanes hold for their first ``n``
-      positions, over every kind of per-lane state, or None."""
+    - ``lane_state_rel_err(engine, lane_x, lane_y, n)``: both lanes have
+      absorbed the same ``n`` tokens; the largest relative difference
+      between them over every kind of per-lane state, rows ``[0, n)`` of
+      what is kept by position and the whole of what is not, or None."""
     name = cfg.get("family", DEFAULT_FAMILY)
     dirs = [os.path.join(BENCH_DIR, "families")]
     if families_dir:

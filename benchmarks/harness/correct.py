@@ -23,17 +23,24 @@ activations, reads only 1.3 times the engine.
 and the fused prefill + decode step, which share the core and differ in the
 feed (the device's carry of token and position), the splice of the admitted
 lane and the sampler. So a short chain is dispatched as the scheduler
-dispatches it (a reseeded pipelined step, two fused steps that admit sample
-prompts into spare lanes, a chained step that decodes those lanes from the
-carry), with half the lanes greedy and half sampled, and then replayed step by
-step through `engine.decode` with the tokens the chain chose. Compared: each
-greedy token of the chain against the replay's logits (how far under the row's
-largest logit it lies, in standard deviations of the row: 0 unless two logits
-tie); each sampled token against the nucleus computed here on the host from
-those logits (how far past ``top_p`` the probability before it reaches: 0
-inside the nucleus); the boundary token of each fused admission likewise
-against the logits `engine.prefill` gave for the same prompt; and the per-lane
-state the fused step wrote against what `engine.prefill` wrote.
+dispatches it, two deep: a reseeded pipelined step; a fused step that admits
+one sample prompt whole into a spare lane; two fused steps that admit another
+in two chunks, between which the lane parks as an admitting lane does; a
+chained step that decodes both from the carry. Half the lanes are greedy,
+half sampled. No lane is ever rewound: the chain runs on one set of lanes and
+the synchronous programs it is held against on their *twins*, lanes that
+`engine.prefill` filled with the same prompts before the chain, that stood
+parked while it ran, and that `engine.decode` then steps with the tokens the
+chain chose, the chain's lanes parked in their turn. Compared: each greedy
+token of the chain against the twin's logits (how far under the row's largest
+logit it lies, in standard deviations of the row: 0 unless two logits tie);
+each sampled token against the nucleus computed here on the host from those
+logits (how far past ``top_p`` the probability before it reaches: 0 inside
+the nucleus); the boundary token of each admission likewise against the
+logits `engine.prefill` gave its twin; and, pair by pair, the whole per-lane
+state of a chain lane against its twin's, which has absorbed the same tokens:
+whatever the state is made of, rows that a step could write again or a
+running sum that it could not.
 
 The model is reached through the configuration's family module alone
 (`cells.load_family`): its plain reference and its comparison of two lanes'
@@ -41,6 +48,8 @@ state. Nothing here knows an architecture.
 """
 
 from __future__ import annotations
+
+import time
 
 import numpy as np
 
@@ -158,13 +167,22 @@ def nucleus_excess(row: np.ndarray, token: int, temp: float, topp: float):
     return max(0.0, float(ahead[rank] - topp)), float(np.mean(ahead < topp))
 
 
-def route_check(family, cfg: dict, engine, prompts, forced, prompt_rows, seed: int,
+def split_admission(prompt, buckets):
+    """The two chunks a prompt is admitted in: cut at the largest prefill
+    bucket not above half of it (at half of it where none is)."""
+    half = len(prompt) // 2
+    cut = max([b for b in buckets if b <= half], default=max(1, half))
+    return prompt[:cut], prompt[cut:]
+
+
+def route_check(family, cfg: dict, engine, prompts, seed: int,
                 fault: str | None = None) -> dict:
-    """The pipelined and the fused programs against the synchronous ones, on
-    the lanes `engine_logits` left filled (module docstring, **Routes**).
-    ``prompt_rows[i]``: the logits `engine.prefill` gave at prompt i's last
-    position. ``fault`` (the control only) = "swap_admits": the two fused
-    steps admit each other's prompt, as a splice into the wrong lane would."""
+    """The pipelined and the fused programs against the synchronous ones
+    (module docstring, **Routes**), on lanes of its own filling: the logits'
+    part is done and on the host. No lane is stepped twice over one position.
+    ``fault`` (the control only) = "swap_admits": the chain admits each
+    admission's prompt into the other's lane, as a splice into the wrong lane
+    would; the twins keep their own."""
     spec = cfg["correctness"]
     temp = float(spec["sampler"]["temperature"])
     topp = float(spec["sampler"]["top_p"])
@@ -172,19 +190,28 @@ def route_check(family, cfg: dict, engine, prompts, forced, prompt_rows, seed: i
     n, seq_len = engine.n_lanes, engine.config.seq_len
     if engine.pipeline_depth < 2:
         raise ValueError("the route check dispatches two steps deep")
-    steps = len(forced[0])
-    live = list(range(len(prompts)))
-    lane_a, lane_b = n - 2, n - 1
+    if fault not in (None, "swap_admits"):
+        raise ValueError(f"unknown fault {fault!r}")
+    k = min(len(prompts), (n - 4) // 2)
+    if k < 2:
+        raise ValueError(
+            "the route check needs two chain lanes, two admitted lanes and a "
+            f"twin of each: 8 lanes, have {n}")
+    chain = list(range(k))
+    lane_a, lane_b, twin_a, twin_b = range(2 * k, 2 * k + 4)
+    # chain lane -> the twin that replays it
+    twin = dict(zip(chain + [lane_a, lane_b], list(range(k, 2 * k)) + [twin_a, twin_b]))
     rng = np.random.default_rng([int(seed), 5])
     seeds = rng.integers(1, 2**31 - 1, size=n).astype(np.uint32)
     topps = np.full(n, topp, np.float32)
     lane_temp = np.zeros(n, np.float32)
-    lane_temp[[i for i in live if i % 2]] = temp  # odd sample lanes sample
-    lane_temp[lane_b] = temp                      # as does the second admission
+    lane_temp[chain[1::2]] = temp  # odd chain lanes sample
+    lane_temp[lane_b] = temp       # as does the second admission
     feed0 = np.zeros(n, np.int32)
-    feed0[live] = rng.integers(2, cfg["vocab_size"], size=len(live))
-    pos0 = np.full(n, seq_len, np.int32)
-    pos0[live] = [len(p) + steps for p in prompts]
+    feed0[chain] = rng.integers(2, cfg["vocab_size"], size=k)
+    for x, y in twin.items():
+        # a twin draws as its chain lane does: by sampler seed and position
+        seeds[y], lane_temp[y] = seeds[x], lane_temp[x]
 
     def temps_of(lanes):
         t = np.zeros(n, np.float32)
@@ -196,26 +223,45 @@ def route_check(family, cfg: dict, engine, prompts, forced, prompt_rows, seed: i
         p[lanes] = -1
         return p
 
+    # both lanes of a pair are filled before the chain, so that a step that
+    # disturbs a parked lane shows in the replay. b's twin is filled in the
+    # chunks its prompt is admitted in: another bucket is another program,
+    # which rounds other bits (on the chip a prompt prefilled whole and in
+    # two chunks differ by 6-8 % of the largest key in the last layers)
+    for i, x in enumerate(chain):
+        engine.prefill(x, prompts[i])
+        engine.prefill(twin[x], prompts[i])
+    row_a = np.asarray(engine.prefill(twin_a, prompts[ia])[0], np.float32)
+    first, rest = split_admission(prompts[ib], engine.prefill_buckets)
+    engine.prefill(twin_b, first)
+    row_b = np.asarray(engine.prefill(twin_b, rest, start_pos=len(first))[0], np.float32)
+
     chunk_a, chunk_b = prompts[ia], prompts[ib]
     if fault == "swap_admits":
         chunk_a, chunk_b = chunk_b, chunk_a
-    elif fault is not None:
-        raise ValueError(f"unknown fault {fault!r}")
-    with_a, with_ab = live + [lane_a], live + [lane_a, lane_b]
-    # the chain, two steps deep as the scheduler keeps it
-    engine.decode_pipelined(pos0, temps_of(live), topps, seeds, tokens=feed0)
-    engine.decode_prefill_fused(
-        carried(live), temps_of(live), topps, seeds, p_lane=lane_a,
-        chunk=chunk_a, p_start=0, p_temp=0.0, p_topp=topp, p_seed=int(seeds[lane_a]))
-    out_a = engine.pipeline_consume()
-    engine.decode_prefill_fused(
-        carried(with_a), temps_of(with_a), topps, seeds, p_lane=lane_b,
-        chunk=chunk_b, p_start=0, p_temp=temp, p_topp=topp, p_seed=int(seeds[lane_b]))
-    out_b = engine.pipeline_consume()
+    first, rest = split_admission(chunk_b, engine.prefill_buckets)
+    with_a, with_ab = chain + [lane_a], chain + [lane_a, lane_b]
+
+    def admit(live, lane, chunk, start):  # the admitting lane parks in the decode half
+        engine.decode_prefill_fused(
+            carried(live), temps_of(live), topps, seeds, p_lane=lane, chunk=chunk,
+            p_start=start, p_temp=float(lane_temp[lane]), p_topp=topp, p_seed=int(seeds[lane]))
+
+    # the chain, two steps deep as the scheduler keeps it: a reseeded step, an
+    # admission whole, one in two chunks, a chained step from the carry
+    pos0 = np.full(n, seq_len, np.int32)
+    pos0[chain] = [len(prompts[i]) for i in range(k)]
+    engine.decode_pipelined(pos0, temps_of(chain), topps, seeds, tokens=feed0)
+    admit(chain, lane_a, chunk_a, 0)
+    outs = [engine.pipeline_consume()]
+    admit(with_a, lane_b, first, 0)
+    outs.append(engine.pipeline_consume())
+    admit(with_a, lane_b, rest, len(first))
+    outs.append(engine.pipeline_consume())
     engine.decode_pipelined(carried(with_ab), temps_of(with_ab), topps, seeds)
-    out_c = engine.pipeline_consume()
-    out_d = engine.pipeline_consume()
+    outs += [engine.pipeline_consume(), engine.pipeline_consume()]
     engine.pipeline_flush()
+    stepped = [chain, chain, with_a, with_a, with_ab]  # the lanes each step decoded
 
     gaps, excesses, shares, mismatches = [0.0], [0.0], [], 0
 
@@ -226,38 +272,47 @@ def route_check(family, cfg: dict, engine, prompts, forced, prompt_rows, seed: i
             excesses.append(excess)
             shares.append(share)
 
-    def chosen(out, lanes):
-        greedy, sampled = out
-        return np.where(lane_temp[lanes] == 0.0, greedy[lanes], sampled[lanes])
+    def chosen(out, lane, column=None):  # column n: a fused step's boundary pair
+        return out[int(lane_temp[lane] != 0.0)][lane if column is None else column]
 
-    # the replay: one synchronous step for each step of the chain, fed with
-    # the tokens the chain chose, at the positions the carry has to hold
-    feed, pos = feed0.copy(), pos0.copy()
-    for out, lanes in ((out_a, live), (out_b, live), (out_c, with_a), (out_d, with_ab)):
-        temps = temps_of(lanes)
+    # the replay, on the twins alone: one synchronous step for each step of
+    # the chain, fed with the tokens the chain chose, at the positions the
+    # carry has to hold; the chain's lanes park
+    feed = np.zeros(n, np.int32)
+    pos = np.full(n, seq_len, np.int32)
+    for x in chain:
+        feed[twin[x]], pos[twin[x]] = feed0[x], pos0[x]
+    for out, lanes in zip(outs, stepped):
+        temps = temps_of([twin[x] for x in lanes])
         logits, greedy, sampled = engine.decode(
             feed, pos, temps, topps, seeds, want_logits=True)
         logits = np.asarray(logits, np.float32)
-        for i in lanes:
-            check_token(logits[i], out[0][i], out[1][i], float(temps[i]))
-            mismatches += int(out[0][i] != greedy[i]) + int(out[1][i] != sampled[i])
-        feed[lanes] = chosen(out, lanes)
-        pos[lanes] += 1
-        if out is out_b:    # lane_a joins with its boundary token (greedy)
-            feed[lane_a], pos[lane_a] = out[0][n], len(prompts[ia])
-        elif out is out_c:  # lane_b with its own (sampled)
-            feed[lane_b], pos[lane_b] = out[1][n], len(prompts[ib])
+        for x in lanes:
+            y = twin[x]
+            check_token(logits[y], out[0][x], out[1][x], float(temps[y]))
+            mismatches += int(out[0][x] != greedy[y]) + int(out[1][x] != sampled[y])
+            feed[y] = chosen(out, x)
+            pos[y] += 1
+        if out is outs[1]:    # a's twin joins with a's boundary token
+            feed[twin_a], pos[twin_a] = chosen(out, lane_a, n), len(prompts[ia])
+        elif out is outs[3]:  # b's with the token b's last chunk ended on;
+            # outs[2] carries the first chunk's boundary slot, which is no token
+            feed[twin_b], pos[twin_b] = chosen(out, lane_b, n), len(prompts[ib])
     # the admissions: boundary tokens against engine.prefill's logits for the
-    # same prompt, and the per-lane state written (reported under the name it
-    # had when keys and values were the only kind)
-    check_token(prompt_rows[ia], out_b[0][n], out_b[1][n], 0.0)
-    check_token(prompt_rows[ib], out_c[0][n], out_c[1][n], temp)
-    kv = [family.lane_state_rel_err(engine, lane_a, ia, len(prompts[ia])),
-          family.lane_state_rel_err(engine, lane_b, ib, len(prompts[ib]))]
+    # same prompt
+    check_token(row_a, outs[1][0][n], outs[1][1][n], float(lane_temp[lane_a]))
+    check_token(row_b, outs[3][0][n], outs[3][1][n], float(lane_temp[lane_b]))
+    # every pair has absorbed the same tokens, pos[twin] of them: the whole of
+    # their state is compared (reported under the name it had when keys and
+    # values were the only kind)
+    state = [family.lane_state_rel_err(engine, x, y, int(pos[y])) for x, y in twin.items()]
     return {
         "route_greedy_gap": max(gaps),
         "route_nucleus_excess": max(excesses),
-        "route_kv_rel_err": None if None in kv else max(kv),
+        "route_kv_rel_err": None if None in state else max(state),
+        # pair by pair: the chain's lanes, then the admission whole, then the
+        # one in two chunks
+        "route_state_rel_errs": state,
         "route_tokens": len(gaps) + len(excesses) - 2,
         "route_token_mismatches": mismatches,
         "nucleus_share_of_vocab": float(np.mean(shares)) if shares else None,
@@ -272,13 +327,17 @@ def compare(family, cfg: dict, weights: dict, engine, seed: int, fault: str | No
     prompts, forced = sample_sequences(cfg, seed)
     prefixes = [prefix_lengths(cfg, len(p)) for p in prompts]
     n_pre = len(prefixes[0]) + 1
-    routes = {}
+    t0 = time.monotonic()
     if isinstance(engine, str):
         got = plain_logits(family, cfg, weights, prompts, forced, prefixes, lossy=engine)
     else:
         got = engine_logits(engine, prompts, forced, prefixes)
-        routes = route_check(family, cfg, engine, prompts, forced, got[:, n_pre - 1], seed, fault)
+    t1 = time.monotonic()
+    routes = ({} if isinstance(engine, str)
+              else route_check(family, cfg, engine, prompts, seed, fault))
+    t2 = time.monotonic()
     want = plain_logits(family, cfg, weights, prompts, forced, prefixes)
+    t3 = time.monotonic()
     err = relative_errors(got, want)
     out = {
         "prefill_rel_err": _rms(err[:, :n_pre]),
@@ -286,6 +345,9 @@ def compare(family, cfg: dict, weights: dict, engine, seed: int, fault: str | No
         **routes,
         "rows": int(err.size),
         "largest_row": float(err.max()),
+        # what the comparison cost
+        "compare_seconds": {"logits": round(t1 - t0, 3), "routes": round(t2 - t1, 3),
+                            "reference": round(t3 - t2, 3)},
     }
     if keep_rows:
         out["row_errors"] = [[float(x) for x in r] for r in err]

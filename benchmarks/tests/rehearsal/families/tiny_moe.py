@@ -34,7 +34,8 @@ from harness.weights import GAIN, q40_plane, seed_key
 _ATT = ("wq", "wk", "wv", "wo")
 _FFN = ("w1", "w2", "w3")
 
-# the per-lane state is the Llama family's contiguous K/V pair
+# the per-lane state is the Llama family's contiguous K/V pair, all of it kept
+# by position: rows [0, n) of two lanes that have absorbed the same n tokens
 lane_state_rel_err = cells.load_family({}).lane_state_rel_err
 
 

@@ -39,9 +39,12 @@ def sound(family, cfg):
 
 def test_engine_as_configured_is_correct(sound):
     assert all(r["ok"] for r in sound), sound
-    # the sibling programs agree with the synchronous ones to the token
+    # the sibling programs agree with the synchronous ones to the token and to
+    # the bit of what they leave in a lane: 2 chain lanes x 5 steps, a's 3,
+    # b's 1 and two boundary tokens, of which 7 sampled; four pairs of lanes
     assert all(r[k] == 0.0 for r in sound for k in ROUTES), sound
-    assert all(r["route_tokens"] >= 16 for r in sound)
+    assert all((r["route_tokens"], r["route_token_mismatches"]) == (23, 0) for r in sound)
+    assert all(r["route_state_rel_errs"] == [0.0] * 4 for r in sound)
 
 
 @pytest.mark.parametrize("variant", ["f8_kv_cache", "q80_activations", "reference_in_f8"])
