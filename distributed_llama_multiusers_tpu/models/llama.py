@@ -16,7 +16,8 @@ matmuls run in the params' dtype (bf16 on TPU) with f32 accumulation.
 
 How the cache moves. The stacked K and V arrays (``[L, ...]``) ride the
 layer scan as its CARRY, next to the residual stream; the scanned input is
-the layers' weights and the layer index ``l``. Layer ``l`` scatters its fresh
+the layer index ``l`` and what of the layers' weights is not read by that
+index (below). Layer ``l`` scatters its fresh
 rows straight into the stack (``k_all.at[l, lane, position]``; paged:
 ``k_all.at[l, page, slot]``) and then reads its plane back out of the carry,
 rows included. Nothing of the cache's size is a scanned input or a stacked
@@ -27,6 +28,20 @@ a one-row append cost two whole-cache copies plus a slice and a write-back of
 every layer's plane (two thirds of a 7B decode step on a v5e, PERF.md
 section 6, PR 27). The scan runs over one run of identical layers whose cache
 is addressed by layer index (ROADMAP D5's shape).
+
+How the weights move. On one device with the Pallas kernel on, the scan's body
+closes over the stacked Q40 planes (``PackedQ40`` leaves ``[L, d_in/2, d_out]``
+at widths the kernel tiles: loop invariants) and hands ``matmul`` a
+``Q40Layer(stack, l)``: the kernel's weight blocks are addressed
+``(l, k, j)`` inside the stack (ops/pallas_q40.py), so the nibbles go from
+HBM into the kernel once. A Pallas call is opaque to XLA and gets its operands
+materialised: scanned, each layer's planes were first copied into a buffer
+of their own and then read again by the kernels, a sixth of a 7B decode step
+on a v5e (PERF.md section 6, PR 30). Everything else is a scanned input as
+ever, the scan slicing layer ``l`` out: the norms and biases, dense weights,
+Q40 planes that XLA dequantizes (the kernel off), that a mesh's shard-local
+paths take, or that carry an expert axis (``[L, E, ...]``). What a leaf is
+decides it (``ops.linear.reads_q40_stack``); nothing selects a path by name.
 
 Optional ``emulate_q80_activations`` reproduces the reference's lossy
 activation quantization (cast to Q80 before each quantized matmul and at the
@@ -44,7 +59,12 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import HiddenAct
 from ..ops.activations import gelu, silu
-from ..ops.linear import matmul, pallas_kernel_active, shared_q80_acts
+from ..ops.linear import (
+    matmul,
+    pallas_kernel_active,
+    reads_q40_stack,
+    shared_q80_acts,
+)
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope
 from ..telemetry.names import (
@@ -429,7 +449,7 @@ def llama_forward(
 
         # shared predicate with the runtime_setup startup log
         use_q80_sync = q80_sync_engages(h_cfg, dict(mesh.shape))
-    from ..quants.packed import PackedQ40
+    from ..quants.packed import PackedQ40, Q40Layer
 
     use_ring_sync = False
     # pure-TP mesh + Q40 planes + the kernel: every matmul runs the kernel
@@ -530,11 +550,23 @@ def llama_forward(
 
     row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
 
+    # the Q40 stacks the kernel reads by layer index (module header, "How
+    # the weights move"): closed over by the scan's body, not scanned. What a
+    # leaf is decides it, and a mesh's shard-local paths keep their planes
+    layers = params.layers
+    in_stack = tuple(
+        f for f in ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
+        if mesh is None and reads_q40_stack(getattr(layers, f))
+    )
+    scanned_layers = layers._replace(**dict.fromkeys(in_stack))
+
     def layer_step(carry, layer_in):
         # the stacked cache rides the carry ([L, ...]; module header, "How
-        # the cache moves"); ``l`` is this layer's index into it
+        # the cache moves"); ``l`` is this layer's index into it, and into
+        # the weight stacks the kernel reads
         x, k_all, v_all = carry
         lp, l = layer_in
+        lp = lp._replace(**{f: Q40Layer(getattr(layers, f), l) for f in in_stack})
         dtype = x.dtype
 
         with jax.named_scope(SCOPE_QKV):
@@ -636,7 +668,7 @@ def llama_forward(
     with jax.named_scope(SCOPE_LAYERS):
         layer_index = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
         (x, new_k, new_v), _ = jax.lax.scan(
-            layer_step, (x, cache.k, cache.v), (params.layers, layer_index)
+            layer_step, (x, cache.k, cache.v), (scanned_layers, layer_index)
         )
 
     with jax.named_scope(SCOPE_HEAD):

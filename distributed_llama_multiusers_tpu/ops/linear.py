@@ -15,7 +15,7 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 
-from ..quants.packed import PackedQ40, q40_matmul_xla
+from ..quants.packed import PackedQ40, Q40Layer, q40_matmul_xla
 
 # The kernel carries its own GSPMD partitioning rule
 # (ops/pallas_q40.q40_matmul_partitioned), so it stays on under meshes:
@@ -99,6 +99,19 @@ def shared_q80_acts(x: jnp.ndarray):
     return make_q80_acts(x, shared=True)
 
 
+def reads_q40_stack(w) -> bool:
+    """Whether ``matmul`` multiplies by one layer of ``w`` without the plane
+    being sliced out first: a PackedQ40 whose planes carry exactly one
+    leading axis, at widths the Pallas kernel tiles, with the kernel on. A
+    layer scan on one device then closes over such a stack and hands
+    ``matmul`` a ``Q40Layer``; every other leaf it scans as ever."""
+    if not (isinstance(w, PackedQ40) and pallas_kernel_active()):
+        return False
+    from .pallas_q40 import pallas_supports_stack
+
+    return pallas_supports_stack(w)
+
+
 def _raw_x(x):
     """Unwrap a Q80Acts bundle to its original activation for every
     non-kernel path (dense weights, XLA fallback)."""
@@ -111,7 +124,17 @@ def matmul(x, w) -> jnp.ndarray:
     """y = x @ w for dense [.., d_in, d_out] arrays or PackedQ40 weights.
     ``x`` may be a Q80Acts bundle from ``shared_q80_acts``: the Pallas
     path consumes the prebuilt operands directly; every other path falls
-    back to the bundle's original activation."""
+    back to the bundle's original activation. ``w`` may be a ``Q40Layer``, a
+    stack and a layer index, which a layer scan builds where
+    ``reads_q40_stack`` holds: the kernel reads that layer's tiles out of the
+    stack."""
+    if isinstance(w, Q40Layer):
+        from .pallas_q40 import q40_matmul_pallas
+
+        kw = {} if _pallas_w_dtype is None else {"w_dtype": _pallas_w_dtype}
+        return q40_matmul_pallas(
+            x, w.stack, interpret=_pallas_interpret, layer=w.layer, **kw
+        )
     if isinstance(w, PackedQ40):
         if w.packed.ndim == 2 and pallas_kernel_active():
             from .pallas_q40 import (

@@ -124,6 +124,7 @@ TRACE_STATS = {
     "acts_builds": 0,      # make_q80_acts executions (any caller)
     "shared_builds": 0,    # ... with shared=True (the models/llama.py hoist)
     "shared_consumes": 0,  # q40_matmul_pallas calls fed a prebuilt Q80Acts
+    "stacked_consumes": 0,  # ... that read their layer's tiles out of a stack
     "impl_traces": 0,      # kernel-body traces (one per compiled family)
 }
 
@@ -442,6 +443,14 @@ def pallas_supports(w: PackedQ40) -> bool:
     return _plan_blocks(w.d_in, w.d_out) is not None
 
 
+def pallas_supports_stack(w: PackedQ40) -> bool:
+    """True when ``w`` is a stack of planes the slab kernel handles (exactly
+    one leading axis, ``[L, d_in//2, d_out]``): the kernel then reads layer
+    ``l``'s tiles out of the stack itself (``q40_matmul_pallas(layer=l)``)
+    and nobody has to slice the plane out first."""
+    return w.packed.ndim == 3 and _plan_blocks(w.d_in, w.d_out) is not None
+
+
 def _resolve_w_dtype(w_dtype, interpret: bool):
     """None -> exact f32 in interpret mode (CPU parity tests), bf16 on TPU.
     w_dtype is the dot's COMPUTE dtype: the dequantized planes and the x
@@ -548,10 +557,20 @@ def make_q80_acts(x: jnp.ndarray, shared: bool = False) -> Q80Acts:
 
 
 def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
-                      w_dtype=None) -> jnp.ndarray:
+                      w_dtype=None, layer=None) -> jnp.ndarray:
     """y = x @ dequant(w). x: [..., d_in] array OR a prebuilt ``Q80Acts``
     bundle (operand sharing across matmuls); returns [..., d_out] in the
     input's dtype.
+
+    ``layer``: with a STACKED weight (planes ``[L, d_in//2, d_out]`` and
+    ``[L, d_in//32, d_out]``) the int32 index of the layer to multiply by,
+    traced or not. The kernel's weight blocks are then addressed
+    ``(layer, k, j)`` inside the stack, so a layer scan hands the whole
+    stack over as a loop invariant and no copy of the plane is made for
+    the call (a Pallas call is opaque to XLA and gets its operands
+    materialised: sliced first, a 7B decode step copied all 4 GB of planes
+    before the kernels read them again, PERF.md section 6, PR 30). A 2-D
+    weight takes no ``layer``.
 
     ``w_dtype``: the dot's compute dtype — applied to the dequantized
     weight planes AND the x operand. None (the default) resolves to exact
@@ -579,34 +598,58 @@ def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
         mode = resolve_mode(w.d_in, w.d_out, m)
     if mode in ("blockdot", "i8blockdot") and m > BLOCKDOT_MAX_M:
         mode = "bf16chain"
+    at = ()  # a plain plane keeps the entries' five-argument call
+    if layer is not None:
+        TRACE_STATS["stacked_consumes"] += 1
+        at = (jnp.asarray(layer, jnp.int32),)
     if acts is not None:
         TRACE_STATS["shared_consumes"] += 1
-        return _q40_matmul_acts_impl(acts, w, interpret, w_dtype_r, mode)
-    return _q40_matmul_pallas_impl(x, w, interpret, w_dtype_r, mode)
+        return _q40_matmul_acts_impl(acts, w, interpret, w_dtype_r, mode, *at)
+    return _q40_matmul_pallas_impl(x, w, interpret, w_dtype_r, mode, *at)
 
 
 @partial(jax.jit, static_argnames=("interpret", "w_dtype", "mode"))
 def _q40_matmul_pallas_impl(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
-                            mode) -> jnp.ndarray:
+                            mode, layer=None) -> jnp.ndarray:
     """Raw-x entry: builds the operand bundle inside the same trace (XLA
     DCEs the layouts `mode` does not touch), then runs the kernel."""
-    return _q40_matmul_core(make_q80_acts(x), w, interpret, w_dtype, mode)
+    return _q40_matmul_core(make_q80_acts(x), w, interpret, w_dtype, mode,
+                            layer)
 
 
 @partial(jax.jit, static_argnames=("interpret", "w_dtype", "mode"))
 def _q40_matmul_acts_impl(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
-                          mode) -> jnp.ndarray:
+                          mode, layer=None) -> jnp.ndarray:
     """Prebuilt-operand entry. Q80Acts is a NamedTuple pytree, so inside an
     outer trace the bundle stays symbolic and one build feeds every
     consumer without re-tracing the prep."""
-    return _q40_matmul_core(acts, w, interpret, w_dtype, mode)
+    return _q40_matmul_core(acts, w, interpret, w_dtype, mode, layer)
+
+
+def _q40_matmul_kernel(layer_ref, *refs, body):
+    """Every mode's kernel behind the scalar-prefetch operand: the layer
+    index is spent in the weight BlockSpecs' index maps, before the body."""
+    del layer_ref
+    body(*refs)
 
 
 def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
-                     mode) -> jnp.ndarray:
+                     mode, layer=None) -> jnp.ndarray:
     TRACE_STATS["impl_traces"] += 1
-    if w.packed.ndim != 2:
-        raise ValueError(f"expected 2D packed weight, got {w.packed.shape}")
+    packed, scales = w.packed, w.scales
+    if packed.ndim == 2 and layer is None:
+        # one plane is a stack of one, read at layer 0: a free reshape, and
+        # the same pallas_call as a layer of a real stack
+        packed = packed[None]
+        layer = jnp.zeros((), jnp.int32)
+    elif packed.ndim == 3 and layer is not None:
+        # the scales (a ninth of the bytes) are still sliced out: see below
+        scales = jax.lax.dynamic_index_in_dim(scales, layer, 0, keepdims=False)
+    else:
+        raise ValueError(
+            f"expected a 2D packed weight, or a [L, ...] stack and its layer "
+            f"index; got {packed.shape} and layer={layer!r}"
+        )
     d_in, d_out = w.d_in, w.d_out
     half = d_in // 2
     if acts.d_in != d_in:
@@ -627,16 +670,23 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
 
     grid = (m_pad // m_tile, d_out // w_tile, n_k)
 
-    scale_bits = jax.lax.bitcast_convert_type(w.scales, jnp.int16)
+    # Mosaic has no f16 type, so the kernel takes the scales' bit patterns.
+    # For XLA:TPU f16 -> s16 is not a relabelling but a pass over the data:
+    # on a whole stack it is hoisted out of the layer loop and costs the
+    # program the stack's size in temporaries and its read and write every
+    # step (441 MB at 7B widths, compiled for a v5e, PR 30). Fused with the
+    # slice of ONE layer's scale plane it costs that plane's read, as ever.
+    scale_bits = jax.lax.bitcast_convert_type(scales, jnp.int16)
 
-    aux_spec = pl.BlockSpec((rows // 16, m_tile), lambda i, j, k: (k, i))
+    # index maps take the grid position and then the scalar-prefetch ref
+    aux_spec = pl.BlockSpec((rows // 16, m_tile), lambda i, j, k, l: (k, i))
     if mode == "blockdot":
         # x TRANSPOSED [rows, m]: the kernel slices 16-row (one quant
         # block) ranges, which must land on the sublane axis — sub-128
         # lane slices would relayout
         xa, xb_ = acts.x_lo_t, acts.x_hi_t
         aux = acts.bsum_t
-        x_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k: (k, i))
+        x_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k, l: (k, i))
         kernel = partial(_q40_blockdot_kernel, sub_tiles=sub, n_k=n_k)
     elif mode == "i8blockdot":
         # Q80-quantized activations from the bundle; x TRANSPOSED like
@@ -645,33 +695,41 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         xa, xb_ = acts.xq_lo_t, acts.xq_hi_t
         aux = acts.aux_t
         aux_spec = pl.BlockSpec(
-            ((rows // 16) * 2, m_tile), lambda i, j, k: (k, i)
+            ((rows // 16) * 2, m_tile), lambda i, j, k, l: (k, i)
         )
-        x_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k: (k, i))
+        x_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k, l: (k, i))
         kernel = partial(_q40_i8blockdot_kernel, sub_tiles=sub, n_k=n_k)
     else:
         xa, xb_ = acts.x_lo, acts.x_hi
         aux = acts.bsum_t
-        x_spec = pl.BlockSpec((m_tile, rows), lambda i, j, k: (i, k))
+        x_spec = pl.BlockSpec((m_tile, rows), lambda i, j, k, l: (i, k))
         kernel = partial(_q40_slab_kernel, w_dtype=w_dtype, sub_tiles=sub,
                          n_k=n_k, mode=mode)
 
     out_dtype = acts.x.dtype
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            x_spec,
-            x_spec,
-            aux_spec,
-            pl.BlockSpec((rows, w_tile), lambda i, j, k: (k, j)),
-            pl.BlockSpec((rows // 16, w_tile), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((m_tile, w_tile), lambda i, j, k: (i, j)),
+        partial(_q40_matmul_kernel, body=kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # the layer index, read by the index maps
+            grid=grid,
+            in_specs=[
+                x_spec,
+                x_spec,
+                aux_spec,
+                # layer l's nibble tiles, addressed inside the stack; the
+                # leading block dimension is squeezed: the body sees [rows, W]
+                pl.BlockSpec((None, rows, w_tile),
+                             lambda i, j, k, l: (l[0], k, j)),
+                pl.BlockSpec((rows // 16, w_tile), lambda i, j, k, l: (k, j)),
+            ],
+            out_specs=pl.BlockSpec((m_tile, w_tile),
+                                   lambda i, j, k, l: (i, j)),
+            scratch_shapes=[
+                pltpu.VMEM((m_tile, w_tile if n_k > 1 else SUB_TILE),
+                           jnp.float32)
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((m_pad, d_out), out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((m_tile, w_tile if n_k > 1 else SUB_TILE), jnp.float32)
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
@@ -683,7 +741,7 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(xa, xb_, aux, w.packed, scale_bits)
+    )(layer.reshape(1), xa, xb_, aux, packed, scale_bits)
 
     return out[:m].reshape(*lead, d_out)
 

@@ -57,6 +57,16 @@ class PackedQ40(NamedTuple):
         return self.packed.shape[-1]
 
 
+class Q40Layer(NamedTuple):
+    """Layer ``layer`` of a stacked Q40 weight, still inside its stack: what
+    a layer scan hands ``ops.linear.matmul`` where the Pallas kernel reads
+    that layer's tiles out of the stack itself (``models/llama.py``), so that
+    the plane is never sliced into a buffer of its own."""
+
+    stack: PackedQ40  # planes [L, d_in//2, d_out] and [L, d_in//32, d_out]
+    layer: jnp.ndarray  # int32 scalar, traced in a scan
+
+
 def pack_q40_planar(values: np.ndarray, scales: np.ndarray):
     """Host-side repack: planar int8 values [..., d_out, d_in] (centered at 0,
     file orientation) + f16-exact scales [..., d_out, d_in//32] -> the device

@@ -74,6 +74,17 @@ def v5e(v5e_devices):
     return SingleDeviceSharding(v5e_devices[0])
 
 
+def _routed_mode(mode: str, d_in: int, d_out: int, m: int) -> str:
+    """The kernel mode q40_matmul_pallas would hand its jitted entry."""
+    if mode == "auto":
+        mode = dequant_select.DequantTable().resolve(
+            d_in, d_out, dequant_select.m_class_of(m)
+        )
+    if mode in ("blockdot", "i8blockdot") and m > pq.BLOCKDOT_MAX_M:
+        mode = "bf16chain"
+    return mode
+
+
 def _compile(sharding, mode: str, d_in: int, d_out: int, m: int) -> str:
     """Compile the bf16-dot kernel the way q40_matmul_pallas routes it and
     return the optimized HLO text."""
@@ -84,14 +95,9 @@ def _compile(sharding, mode: str, d_in: int, d_out: int, m: int) -> str:
         scales=jax.ShapeDtypeStruct((d_in // 32, d_out), jnp.float16,
                                     sharding=sharding),
     )
-    if mode == "auto":
-        mode = dequant_select.DequantTable().resolve(
-            d_in, d_out, dequant_select.m_class_of(m)
-        )
-    if mode in ("blockdot", "i8blockdot") and m > pq.BLOCKDOT_MAX_M:
-        mode = "bf16chain"
     return pq._q40_matmul_pallas_impl.lower(
-        x, w, interpret=False, w_dtype=jnp.bfloat16, mode=mode
+        x, w, interpret=False, w_dtype=jnp.bfloat16,
+        mode=_routed_mode(mode, d_in, d_out, m),
     ).compile().as_text()
 
 
@@ -154,6 +160,108 @@ def test_pure_tp_kernel_paths_compile_for_a_v5e_mesh(
     hlo = jax.jit(lambda x, w: fn(x, w, mesh)).lower(x, w).compile().as_text()
     assert "tpu_custom_call" in hlo and "CustomSPMDPartitioning" not in hlo
     assert collective is None or collective in hlo
+
+
+# Stacked weights (PR 30): the kernel reads layer l's tiles out of a [L, ...]
+# stack by a scalar-prefetch index. (d_in, d_out, decode m) of the seven
+# planes of a layer at the benchmark's two configurations: Mistral-7B
+# (16 lanes) wq/wo, wk/wv, w1/w3, w2; Qwen2.5-7B (32 lanes) the same
+STACK_SHAPES = [
+    (4096, 4096, 16), (4096, 1024, 16), (4096, 14336, 16), (14336, 4096, 16),
+    (3584, 3584, 32), (3584, 512, 32), (3584, 18944, 32), (18944, 3584, 32),
+]
+STACK_LAYERS = 4
+
+
+def _compile_stacked(sharding, mode: str, d_in: int, d_out: int, m: int) -> str:
+    """As `_compile`, the weight a stack and the layer a traced scalar."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    x = sds((m, d_in), jnp.bfloat16)
+    w = PackedQ40(
+        packed=sds((STACK_LAYERS, d_in // 2, d_out), jnp.uint8),
+        scales=sds((STACK_LAYERS, d_in // 32, d_out), jnp.float16),
+    )
+    return pq._q40_matmul_pallas_impl.lower(
+        x, w, interpret=False, w_dtype=jnp.bfloat16,
+        mode=_routed_mode(mode, d_in, d_out, m), layer=sds((), jnp.int32),
+    ).compile().as_text()
+
+
+def _scales_stack_converted_whole(hlo: str, d_in: int, d_out: int) -> bool:
+    """Whether the program makes the WHOLE stack's scale bit patterns: for
+    XLA:TPU f16 -> s16 is a pass over the data, so the kernel converts one
+    layer's slice (ops/pallas_q40.py); hoisted out of a layer loop, the
+    stack's conversion was 441 MB of temporaries at 7B widths."""
+    return f"= s16[{STACK_LAYERS},{d_in // 32},{d_out}]" in hlo
+
+
+@pytest.mark.parametrize("prefill", [False, True], ids=["decode", "prefill1024"])
+@pytest.mark.parametrize("d_in,d_out,m", STACK_SHAPES)
+def test_stacked_weight_default_mode_compiles_for_v5e(v5e, d_in, d_out, m, prefill):
+    hlo = _compile_stacked(v5e, DEFAULT_MODE, d_in, d_out, 1024 if prefill else m)
+    assert "tpu_custom_call" in hlo
+    assert not _scales_stack_converted_whole(hlo, d_in, d_out)
+
+
+@pytest.mark.parametrize("d_in,d_out,m", [(4096, 14336, 16), (3584, 512, 32)])
+@pytest.mark.parametrize("mode", OTHER_MODES)
+def test_stacked_weight_every_selectable_mode_compiles_for_v5e(
+        v5e, mode, d_in, d_out, m):
+    """Every mode `--dequant` offers and `auto` can resolve to, at a
+    multi-chunk two-wide-tile plan and at a single-slab plan."""
+    assert "tpu_custom_call" in _compile_stacked(v5e, mode, d_in, d_out, m)
+
+
+@pytest.mark.parametrize("reads_stack", [True, False],
+                         ids=["kernel_reads_stack", "control_scanned_planes"])
+def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
+    """The optimized HLO of a three-layer decode forward at (4096, 14336) and
+    the other planes of that width: no slice, fusion or copy has a nibble
+    plane's shape as its result. The control scans the planes as the program
+    did before PR 30, and shows the slices this check looks for. (Stacks as
+    small as three layers XLA may stage WHOLE in fast memory ahead of the
+    loop, by `slice-start`s of its own; that is not what is looked for.)"""
+    import re
+
+    from distributed_llama_multiusers_tpu.models import llama
+    from distributed_llama_multiusers_tpu.models.config import LlamaConfig
+
+    monkeypatch.setattr(
+        linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas
+    )
+    if not reads_stack:
+        monkeypatch.setattr(llama, "reads_q40_stack", lambda w: False)
+    L, d, h, kv, vocab, lanes, seq = 3, 4096, 14336, 1024, 8192, 16, 256
+    cfg = LlamaConfig(dim=d, hidden_dim=h, n_layers=L, n_heads=32, n_kv_heads=8,
+                      vocab_size=vocab, seq_len=seq)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    q40 = lambda d_in, d_out, lead=(L,): PackedQ40(
+        packed=sds(lead + (d_in // 2, d_out), jnp.uint8),
+        scales=sds(lead + (d_in // 32, d_out), jnp.float16))
+    params = llama.LlamaParams(
+        embedding=sds((vocab, d), jnp.bfloat16),
+        layers=llama.LlamaLayerParams(
+            wq=q40(d, d), wk=q40(d, kv), wv=q40(d, kv), wo=q40(d, d),
+            w1=q40(d, h), w2=q40(h, d), w3=q40(d, h),
+            rms_att=sds((L, d), jnp.float32), rms_ffn=sds((L, d), jnp.float32)),
+        rms_final=sds((d,), jnp.float32), wcls=q40(d, vocab, ()),
+        rope_cos=sds((seq, 64), jnp.float32), rope_sin=sds((seq, 64), jnp.float32))
+    cache = llama.KVCache(*(sds((L, lanes, seq, 8, 128), jnp.bfloat16),) * 2)
+    tok = sds((lanes, 1), jnp.int32)
+    hlo = jax.jit(
+        lambda p, t, c: llama.llama_forward(cfg, p, t, t, c), donate_argnums=(2,)
+    ).lower(params, tok, cache).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 8  # seven a layer body, and the head
+    # a plane sliced out for a kernel call: the result of a slice fusion of
+    # its own (`[1, d_in/2, d_out]`: the kernel takes a plane as a stack of one)
+    planes = {(a // 2, b) for a, b in ((d, d), (d, kv), (d, h), (h, d))}
+    sliced = {(int(r), int(w)) for r, w in re.findall(
+        r"= u8\[(?:1,)?(\d+),(\d+)\]\S* (?:fusion|dynamic-slice|copy)\(", hlo)}
+    sliced &= planes
+    if reads_stack:
+        assert sliced == set(), sliced
+    else:
+        assert sliced == planes, sliced
 
 
 def test_selection_table_resolves_only_to_compile_tested_modes():

@@ -32,11 +32,12 @@ from distributed_llama_multiusers_tpu.models.llama import (
     KVCache,
     PagedKVCache,
     _dense_attention,
+    _maybe_bias,
     _to_cache_dtype,
     init_paged_kv_cache,
 )
 from distributed_llama_multiusers_tpu.ops.activations import silu
-from distributed_llama_multiusers_tpu.ops.linear import matmul
+from distributed_llama_multiusers_tpu.ops.linear import matmul, shared_q80_acts
 from distributed_llama_multiusers_tpu.ops.norm import rms_norm
 from distributed_llama_multiusers_tpu.ops.rope import apply_rope
 from distributed_llama_multiusers_tpu.runtime import InferenceEngine
@@ -69,8 +70,12 @@ def _cache(config, layout: str, dtype=jnp.float32):
 
 
 def _layer_loop_forward(config, params, tokens, positions, cache):
-    """llama_forward for a dense model on one device as a Python loop over
-    the layers, the cache handled plane by plane (the scan's old form)."""
+    """llama_forward for a dense-FFN model on one device as a Python loop over
+    the layers: every leaf of layer ``l`` sliced out of its stack (Q40 planes
+    too, tests/test_weight_residency.py) and the cache handled plane by plane
+    (the scan's old form). Biases and shared operand builds as the scan has
+    them: both are identities for a model without biases or with the kernel
+    off."""
     b, t = tokens.shape
     n_heads, n_kv, hd = config.n_heads, config.n_kv_heads, config.head_size
     paged = isinstance(cache, PagedKVCache)
@@ -91,10 +96,10 @@ def _layer_loop_forward(config, params, tokens, positions, cache):
     planes_k, planes_v = [], []
     for l in range(config.n_layers):
         lp = jax.tree.map(lambda a: a[l], params.layers)
-        y = rms_norm(x, lp.rms_att, config.norm_epsilon)
-        q = matmul(y, lp.wq).reshape(b, t, n_heads, hd)
-        k = matmul(y, lp.wk).reshape(b, t, n_kv, hd)
-        v = matmul(y, lp.wv).reshape(b, t, n_kv, hd)
+        y = shared_q80_acts(rms_norm(x, lp.rms_att, config.norm_epsilon))
+        q = _maybe_bias(matmul(y, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
+        k = _maybe_bias(matmul(y, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
+        v = _maybe_bias(matmul(y, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
         q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
         k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
         k_plane = cache.k[l].at[at].set(_to_cache_dtype(k, cache.k.dtype), mode="drop")
@@ -109,7 +114,7 @@ def _layer_loop_forward(config, params, tokens, positions, cache):
                                 v_plane.astype(jnp.float32), mask,
                                 1.0 / float(hd) ** 0.5)
         x = x + matmul(attn.reshape(b, t, n_heads * hd).astype(x.dtype), lp.wo)
-        y = rms_norm(x, lp.rms_ffn, config.norm_epsilon)
+        y = shared_q80_acts(rms_norm(x, lp.rms_ffn, config.norm_epsilon))
         x = x + matmul(silu(matmul(y, lp.w1)) * matmul(y, lp.w3), lp.w2)
     y = rms_norm(x, params.rms_final, config.norm_epsilon)
     logits = matmul(y, params.wcls).astype(jnp.float32)[..., : config.vocab_size]

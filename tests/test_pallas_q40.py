@@ -575,3 +575,126 @@ def test_env_dequant_rejects_unknown_on_import():
     )
     assert proc.returncode != 0
     assert "not a known dequant mode" in proc.stderr
+
+
+# ---------------------------------------------------------------------------
+# Stacked weights: the kernel reads layer ``l``'s tiles out of a [L, ...]
+# stack itself (q40_matmul_pallas(layer=l)), so a layer scan never slices the
+# plane into a buffer of its own. Same arithmetic, same bits: only where the
+# weight blocks are fetched from differs.
+# ---------------------------------------------------------------------------
+
+STACK_L = 3
+
+
+def _stack(rng, d_out, d_in, n=STACK_L):
+    planes = [_pack(rng, d_out, d_in) for _ in range(n)]
+    return PackedQ40(packed=jnp.stack([p.packed for p in planes]),
+                     scales=jnp.stack([p.scales for p in planes]))
+
+
+def _plane(stack, l):
+    return PackedQ40(packed=stack.packed[l], scales=stack.scales[l])
+
+
+@pytest.mark.parametrize("how", ["jit", "scan"])
+@pytest.mark.parametrize("entry", ["raw_x", "shared_acts"])
+@pytest.mark.parametrize("mode", DEQUANT_MODES)
+def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
+    """Layer ``l`` read out of the stack equals the 2-D kernel on plane ``l``
+    to the bit, in every dequant mode and through both jitted entries, with
+    ``l`` a traced scalar (an argument of a jit; the counter of a lax.scan)
+    at the stack's first and last layer. Both sides are computed inside one
+    traced program, so the operand builds are the same operations."""
+    rng = np.random.default_rng(30)
+    stack = _stack(rng, 256, 128)
+    x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
+    kw = dict(interpret=True, w_dtype=jnp.bfloat16)
+
+    def both(x, stack, l):
+        xin = make_q80_acts(x) if entry == "shared_acts" else x
+        return (q40_matmul_pallas(xin, stack, layer=l, **kw),
+                q40_matmul_pallas(xin, _plane(stack, l), **kw))
+
+    set_dequant_mode(mode)
+    try:
+        reset_trace_stats()
+        if how == "jit":
+            fn = jax.jit(both)
+            pairs = {l: fn(x, stack, jnp.int32(l)) for l in (0, STACK_L - 1)}
+        else:
+            _, (got, want) = jax.lax.scan(
+                lambda c, l: (c, both(x, stack, l)), 0,
+                jnp.arange(STACK_L, dtype=jnp.int32))
+            pairs = {l: (got[l], want[l]) for l in (0, STACK_L - 1)}
+        # one trace of ``both``: one kernel call that indexes a stack
+        assert TRACE_STATS["stacked_consumes"] == 1, TRACE_STATS
+    finally:
+        set_dequant_mode(None)
+    for l, (got, want) in pairs.items():
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=f"layer {l}")
+    assert not np.array_equal(np.asarray(pairs[0][0]),
+                              np.asarray(pairs[STACK_L - 1][0]))
+
+
+@pytest.mark.parametrize("m,d_in,d_out", [
+    (4, 4096, 2048),   # n_k > 1: the k axis walks chunks of layer l's plane
+    (2, 512, 16384),   # two wide tiles: the j axis
+    (300, 64, 256),    # two m tiles
+])
+def test_stacked_weight_on_every_grid_axis(m, d_in, d_out):
+    """The layer offset composes with each axis of the grid."""
+    rng = np.random.default_rng(d_in + d_out)
+    stack = _stack(rng, d_out, d_in, n=2)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+    for l in (0, 1):
+        got = q40_matmul_pallas(x, stack, interpret=True, layer=l)
+        want = q40_matmul_pallas(x, _plane(stack, l), interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(q40_matmul_xla(x, _plane(stack, l))),
+            atol=2e-4, rtol=2e-4)
+
+
+# sha256[:16] of the output bytes the PARENT of PR 30 gives for the seeded
+# call below (its kernel took 2-D planes only): a 2-D weight goes through the
+# same pallas_call as a stack now, as the stack of one read at layer 0
+PARENT_2D_DIGESTS = {
+    "f32": "a00e1bb2e19dc500", "v4": "152c5bc3acc7695f",
+    "bf16chain": "8cd3d3f237e607c8", "repeat": "8cd3d3f237e607c8",
+    "u8chain": "8cd3d3f237e607c8", "blockdot": "2e41ab0e772529f9",
+    "i8blockdot": "e537117c72b1fdf8",
+}
+
+
+@pytest.mark.parametrize("mode", list(PARENT_2D_DIGESTS))
+def test_plain_weight_gives_what_it_gave_before_stacks(mode):
+    import hashlib
+
+    rng = np.random.default_rng(30)
+    pw = _pack(rng, 384, 256)
+    x = jnp.asarray(rng.standard_normal((5, 256), dtype=np.float32))
+    kw = {} if mode == "f32" else {"w_dtype": jnp.bfloat16}
+    set_dequant_mode(None if mode == "f32" else mode)
+    try:
+        got = np.asarray(q40_matmul_pallas(x, pw, interpret=True, **kw))
+        as_stack = np.asarray(q40_matmul_pallas(
+            x, PackedQ40(pw.packed[None], pw.scales[None]), interpret=True,
+            layer=0, **kw))
+    finally:
+        set_dequant_mode(None)
+    assert hashlib.sha256(got.tobytes()).hexdigest()[:16] == PARENT_2D_DIGESTS[mode]
+    np.testing.assert_array_equal(as_stack, got)
+
+
+def test_stack_and_layer_go_together():
+    """A stack without a layer, or a layer with a 2-D plane, is an error and
+    not a guess."""
+    rng = np.random.default_rng(3)
+    stack = _stack(rng, 128, 64, n=2)
+    x = jnp.asarray(rng.standard_normal((2, 64), dtype=np.float32))
+    with pytest.raises(ValueError, match="stack and its layer"):
+        q40_matmul_pallas(x, stack, interpret=True)
+    with pytest.raises(ValueError, match="stack and its layer"):
+        q40_matmul_pallas(x, _plane(stack, 0), interpret=True, layer=0)
