@@ -1,7 +1,7 @@
 """host-sync: device->host transfers in the decode path must be explicit.
 
-The serving invariant (ROADMAP north star, VERDICT Weak #3): one small
-host transfer per decode step. A stray ``np.asarray(logits)`` / ``.item()``
+The serving invariant: one small host transfer per decode step (a token
+id a lane, never a row of logits). A stray ``np.asarray(logits)`` / ``.item()``
 in the engine step functions or the scheduler loop silently serializes the
 pipeline on a full [n_lanes, vocab] f32 row every token — the classic
 silent throughput killer on an accelerator behind a high-latency link.

@@ -14,8 +14,8 @@ real compile and never on an executable-cache hit):
 - ``arm(stats)`` — called by ``warmup_engine`` as its last act: from
   here on, every backend compile bumps the engine's
   ``EngineStats.jit_compiles_after_warmup`` counter (under the stats
-  lock — surfaced on ``/stats``, bridged to ``/metrics``, banked by the
-  bench phases as ``*_compiles_after_warmup``), and with the witness
+  lock — surfaced on ``/stats``, bridged to ``/metrics``, reported by
+  the benchmark as ``jit_compiles_after_warmup``), and with the witness
   ENABLED (``DLLAMA_JITCHECK=1`` or :func:`force`) additionally raises
   :class:`RecompileAfterWarmup` out of the guilty dispatch — a stack
   trace at the exact call that changed an aval or hit an unwarmed

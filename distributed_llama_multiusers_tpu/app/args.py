@@ -216,8 +216,8 @@ def build_parser(prog: str, api: bool = False) -> argparse.ArgumentParser:
                         "equivalent; default v4). 'auto' resolves the mode "
                         "per (d_in, d_out, m-class) matmul site from the "
                         "persisted selection table "
-                        "(ops/dequant_table.json, refreshed by the bench "
-                        "sweeps) BEFORE warmup, so every program still "
+                        "(ops/dequant_table.json, written only by "
+                        "scripts/kernel_lab3.py --adopt) BEFORE warmup, so every program still "
                         "compiles exactly once; interpret/CPU always runs "
                         "the exact-f32 v4 chain")
     p.add_argument("--step-deadline", type=float, default=None,

@@ -51,10 +51,10 @@ def enable_compilation_cache() -> str | None:
     returns the directory in use. Where JAX_COMPILATION_CACHE_DIR is set JAX
     already caches there and nothing else is configured; otherwise the cache
     lives at DEFAULT_COMPILE_CACHE_DIR. Repeat builds of the same programs
-    (server restarts, bench phase children, pod workers replaying identical
-    programs) then load instead of compiling. Kernel-geometry env knobs are
-    safe: they change the serialized Mosaic kernel inside the HLO, so the
-    cache key differs."""
+    (server restarts, benchmark runs of one checkout, pod workers replaying
+    identical programs) then load instead of compiling. A change of the
+    kernel's block geometry changes the serialized Mosaic kernel inside
+    the HLO, so the cache key differs."""
     if os.environ.get("DLLAMA_NO_COMPILE_CACHE") == "1":
         return None
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -278,7 +278,7 @@ class FleetRouter:
 
     def scrape_once(self) -> None:
         """One scrape pass over every replica (the loop's body; also the
-        test/bench lever for deterministic state). Replicas are scraped
+        tests' lever for deterministic state). Replicas are scraped
         CONCURRENTLY: a blackholed host (no RST — each attempt eats the
         full 2s timeout) must not stall the healthy replicas' load and
         draining freshness behind it, so a pass costs max(one probe),

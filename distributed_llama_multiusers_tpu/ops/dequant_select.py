@@ -6,9 +6,8 @@ touched it. So "auto" cannot mean "measure and switch live" — it means
 resolve each matmul site's mode ONCE, deterministically, from a small
 persisted selection table keyed by (d_in, d_out, m-class), before
 ``warmup_engine`` compiles the step families. The table is checked in
-(ops/dequant_table.json) and refreshed out-of-band by the measurement
-loops (bench.py's in-bench micro-A/B, scripts/kernel_sweep.py
---update-table, scripts/kernel_lab3.py --adopt) through ``record_win``.
+(ops/dequant_table.json) and has ONE writer: ``scripts/kernel_lab3.py
+--adopt``, through ``record_win``.
 
 Everything in this module is HOST state: rules are plain python dicts and
 strings. No device arrays may ever be constructed into the table or the
@@ -119,7 +118,7 @@ def _get_table() -> DequantTable:
 def resolve_mode(d_in: int, d_out: int, m: int) -> str:
     """The auto-mode hook q40_matmul_pallas calls at trace time: the
     table's answer for this site, recorded into the site map surfaced on
-    /stats and stamped into bench artifacts."""
+    /stats."""
     cls = m_class_of(m)
     mode = _get_table().resolve(d_in, d_out, cls)
     with _lock:
@@ -174,9 +173,9 @@ def _reset_for_tests() -> None:
 
 
 def dequant_stats() -> dict:
-    """The dequant attribution payload for /stats and bench artifacts:
-    the configured mode knob, the per-site resolutions (auto), and the
-    selection-table provenance when a table is loaded."""
+    """The dequant attribution payload for /stats: the configured mode
+    knob, the per-site resolutions (auto), and the selection-table
+    provenance when a table is loaded."""
     from . import pallas_q40 as pq
 
     out = {"dequant_mode": pq.DEQUANT_MODE}
@@ -188,31 +187,13 @@ def dequant_stats() -> dict:
     return out
 
 
-def bench_stamp(prefix: str) -> dict:
-    """Phase-prefixed dequant attribution for bench.py: every phase
-    result records the resolved mode (and table provenance) next to its
-    tok/s number so kernel A/B rows stay attributable after the fact."""
-    s = dequant_stats()
-    out = {f"{prefix}_dequant_mode": s["dequant_mode"]}
-    if s.get("dequant_sites"):
-        out[f"{prefix}_dequant_sites"] = s["dequant_sites"]
-    if s.get("dequant_table"):
-        t = s["dequant_table"]
-        out[f"{prefix}_dequant_table"] = (
-            f"v{t.get('version')}:{t.get('rows')} rows "
-            f"({os.path.basename(t.get('path') or '?')}, "
-            f"updated {t.get('updated')})"
-        )
-    return out
-
-
 def record_win(d_in, d_out, m_class: str, mode: str, source: str,
                path: str | None = None) -> str:
     """Feed a measured (shape -> mode) winner back into the persisted
-    table (scripts/kernel_sweep.py --update-table, bench.py in-bench A/B,
-    kernel_lab3 --adopt). Upserts the matching rule and rewrites the file
-    atomically. Writes the FILE only: a live process's resolution stays
-    whatever it froze at — the next serving start picks the row up."""
+    table (its one caller: scripts/kernel_lab3.py --adopt). Upserts the
+    matching rule and rewrites the file atomically. Writes the FILE only:
+    a live process's resolution stays whatever it froze at — the next
+    serving start picks the row up."""
     from .pallas_q40 import DEQUANT_MODES
 
     if mode not in DEQUANT_MODES:

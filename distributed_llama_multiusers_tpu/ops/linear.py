@@ -30,28 +30,13 @@ _pallas_interpret = False
 # x operand). None -> kernel default: bf16 on TPU (single-pass MXU, the
 # reference's Q80-activation precision class), exact f32 under interpret/
 # CPU tests. Explicit jnp.float32 restores ~f32-accurate multi-pass MXU
-# dots on TPU (the bench ablation knob).
+# dots on TPU.
 _pallas_w_dtype = None
-
-# Operand sharing (ops/pallas_q40.Q80Acts): llama_forward builds the
-# activation-quant/relayout operands once per distinct input and feeds
-# every matmul sharing it. Off switch for A/B and bisection only — the
-# shared and per-call bundles are the same traced graph.
-_shared_acts_enabled = os.environ.get("DLLAMA_SHARED_ACTS", "on") != "off"
 
 
 def set_pallas_enabled(enabled: bool) -> None:
     global _pallas_enabled
     _pallas_enabled = enabled
-
-
-def set_shared_acts(enabled: bool) -> None:
-    global _shared_acts_enabled
-    _shared_acts_enabled = enabled
-
-
-def shared_acts_enabled() -> bool:
-    return _shared_acts_enabled
 
 
 def set_pallas_interpret(enabled: bool) -> None:
@@ -86,11 +71,12 @@ def pallas_kernel_active() -> bool:
 
 
 def shared_q80_acts(x: jnp.ndarray):
-    """Build the shared Q80/relayout operand bundle for ``x``, or return x
-    unchanged when sharing cannot engage (kernel off, sharing disabled, or
-    a d_in that does not cover whole quant blocks). Callers pass the
-    result to ``matmul`` exactly like a raw activation."""
-    if not (_shared_acts_enabled and pallas_kernel_active()):
+    """Build the shared Q80/relayout operand bundle for ``x`` (llama_forward
+    builds it once per distinct input and feeds every matmul sharing it),
+    or return x unchanged when sharing cannot engage (kernel off, or a d_in
+    that does not cover whole quant blocks). Callers pass the result to
+    ``matmul`` exactly like a raw activation."""
+    if not pallas_kernel_active():
         return x
     if x.shape[-1] % 32 != 0:
         return x

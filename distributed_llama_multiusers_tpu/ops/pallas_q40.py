@@ -20,8 +20,8 @@ nibble offset is folded into one small correction dot against per-block x
 sums instead of a per-weight subtract. Per packed byte the VPU does one
 shift+mask+scale-mul, the rest is MXU work.
 
-Block layout (round-4 rework, driven by stage_probe.py measurements on a
-real v5e): blocks span the FULL output width (or a wide 512-multiple tile
+Block layout (round-4 rework, from pure-read measurements on a real v5e):
+blocks span the FULL output width (or a wide 512-multiple tile
 for very wide matmuls), so each DMA fetches one contiguous multi-hundred-KB
 slab instead of the 512-BYTE strided rows of the old (chunk, 512) blocks —
 which measured at 47 GB/s of the chip's 819 GB/s on pure reads. Dequant
@@ -41,6 +41,7 @@ on TPU.
 
 from __future__ import annotations
 
+import os as _os
 from functools import partial
 from typing import NamedTuple
 
@@ -56,15 +57,10 @@ from ..quants.packed import (
     pallas_wide_tile as _pick_w,
 )
 
-import os as _os
-
-# tuning knobs, env-overridable for hardware sweeps (scripts/kernel_sweep.py)
-SINGLE_SLAB_BYTES = int(
-    _os.environ.get("DLLAMA_SINGLE_SLAB", 1 << 20)
-)  # planes up to this: one DMA, no k axis
-TARGET_BLOCK_BYTES = int(
-    _os.environ.get("DLLAMA_TARGET_BLOCK", 1 << 20)
-)  # k-chunk size target (DMA/compute overlap)
+# block geometry: every cell, test and server runs these values (ROADMAP
+# S2(b)(ii) is where a chip measurement may change them)
+SINGLE_SLAB_BYTES = 1 << 20  # planes up to this: one DMA, no k axis
+TARGET_BLOCK_BYTES = 1 << 20  # k-chunk size target (DMA/compute overlap)
 
 # Dequant arithmetic variant for the bf16 dot path (round-5 finding: the
 # kernel is VPU-bound on the per-weight dequant chain — hbm_util ~0.26 on
@@ -133,24 +129,6 @@ def reset_trace_stats() -> None:
     for k in TRACE_STATS:
         TRACE_STATS[k] = 0
 
-# The one shared DMA-geometry sweep table: (single-slab ceiling, k-chunk
-# target) in bytes, keyed by a stable name. scripts/kernel_sweep.py runs
-# all of them; bench.py's in-bench sweep runs the non-default entries in
-# this order under its remaining deadline. Ordered best-candidates-first
-# (round-4 stage_probe pointed at larger contiguous DMAs).
-SWEEP_COMBOS = {
-    "slab1M_blk1M": (1 << 20, 1 << 20),  # the compiled-in default above
-    "slab2M_blk2M": (2 << 20, 2 << 20),
-    "slab4M_blk2M": (4 << 20, 2 << 20),
-    "slab4M_blk4M": (4 << 20, 4 << 20),
-    # whole-plane single DMA for every 1B plane (w1/w3 are 8 MB packed):
-    # trades k-loop double-buffer overlap for zero chunking overhead.
-    # (A 512k combo was dropped: blocks under 1 MB cannot tile the
-    # 8192-wide FFN planes at all — rows would have to be <128 — so it
-    # silently measured the XLA fallback, not the kernel.)
-    "slab8M_blk8M": (8 << 20, 8 << 20),
-}
-DEFAULT_COMBO = "slab1M_blk1M"
 M_TILE = 256
 ROW_ALIGN = 8  # x rows padded to this multiple
 # Mosaic's default scoped-VMEM limit (16 MiB) refuses the prefill-shaped

@@ -116,20 +116,11 @@ def pack_q40_host(w: np.ndarray):
 # pure-math helpers so the loader can pad without importing Pallas.
 # ---------------------------------------------------------------------------
 
-import os as _os
-
-# widest output block of the slab kernel. Env-overridable for hardware
-# geometry A/Bs (bench sweep "r02_narrow512": the round-2 kernel's
-# 512-lane tiles measured hbm_util 0.438 where the full-width slab
-# measured 0.259 — the sweep reproduces that layout via DLLAMA_W_MAX=512)
-PALLAS_W_MAX = int(_os.environ.get("DLLAMA_W_MAX", 8192))
-if PALLAS_W_MAX <= 0 or PALLAS_W_MAX % 128 != 0:
-    # a non-128-multiple makes every plane silently take the XLA fallback
-    # (no tile candidate divides the planes), which would mislabel a sweep
-    # datapoint as kernel geometry — fail loudly instead
-    raise ValueError(
-        f"DLLAMA_W_MAX must be a positive multiple of 128, got {PALLAS_W_MAX}"
-    )
+# widest output block of the slab kernel: a positive multiple of 128 (a
+# plane's tile candidates are its 128-multiple divisors up to this; any
+# other value would send every plane to the XLA fallback), pinned against
+# the kernel's own block plan in tests/test_dequant_select.py
+PALLAS_W_MAX = 8192
 PALLAS_SUB = 512  # in-kernel dequant sub-tile (lanes)
 
 

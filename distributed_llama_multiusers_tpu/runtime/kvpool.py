@@ -267,7 +267,7 @@ class HostTier:
                 self._bytes -= len(entry[0])
 
     def clear(self) -> int:
-        """Drop every entry (containment / the bench's rebuild lever —
+        """Drop every entry (containment / the tests' rebuild lever —
         without this, drop_parked would still reactivate via the tier).
         Returns how many entries were dropped."""
         with self._lock:
@@ -914,7 +914,7 @@ class KVPagePool:
             return n
 
     def swap_out_parked(self) -> int:
-        """Evict every parked session WITH swap-out staging (the bench's
+        """Evict every parked session WITH swap-out staging (the tests'
         swap-tier lever; pressure eviction does the same organically).
         Returns how many sessions were evicted; the caller must drain
         the staged pages through the engine (``drain_kv_swapouts``)."""

@@ -156,7 +156,7 @@ class SpecStream:
         Accounting: a spec verify whose lookahead is only PARTIALLY
         consumed is RETRACTED from the acceptance counters
         (``spec_lane_steps`` / ``spec_emitted``), not left dangling — the
-        bench/stats acceptance ratio (emitted per drafted lane-step, class
+        /stats acceptance ratio (emitted per drafted lane-step, class
         [1, K+1]) aggregates only fully realized steps, so a turn ending
         mid-lookahead can neither deflate it nor strand a lane-step whose
         emitted count no longer means anything. Counters never go below 0

@@ -152,7 +152,7 @@ class JournalEntry:
     queue_timeout_s: float | None = None
     budget_s: float | None = None
     stream: bool = False
-    kind: str | None = None  # "chat" | "completion" | None (CLI/bench)
+    kind: str | None = None  # "chat" | "completion" | None (CLI)
     response_format: dict | None = None  # structured output (grammar/)
     trace: str | None = None  # fleet trace context, "tid-sid" wire form
     watermark: int = 0  # tokens already delivered to the client transport

@@ -25,7 +25,7 @@ happened, which lane stalled. This package is the three missing layers:
 Pure stdlib (no numpy/jax): importable anywhere dlint runs, and
 registered under dlint's ``clock``, ``host-sync``, and ``guarded-by``
 checks. Entry points: ``Telemetry`` (the hub the scheduler, HTTP server,
-and bench share), ``GET /metrics`` / ``GET /trace`` (server/http.py),
+and benchmark share), ``GET /metrics`` / ``GET /trace`` (server/http.py),
 ``--trace-path`` (dumped on drain). docs/OBSERVABILITY.md is the guide.
 """
 

@@ -1,4 +1,4 @@
-"""Telemetry hub: the one object the scheduler/server/bench share.
+"""Telemetry hub: the one object the scheduler/server/benchmark share.
 
 Bundles the span tracer (spans.py), the metrics registry (metrics.py)
 with the standard serving instruments pre-registered, and the JSON

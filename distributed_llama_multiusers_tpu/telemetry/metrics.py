@@ -4,9 +4,8 @@ The serving path's latency distributions (TTFT, inter-token gap, queue
 wait, step duration) are heavy-tailed over four-plus decades — from
 sub-millisecond mock steps to multi-second cold prefills — so the
 histograms use FIXED geometric bucket edges (``log_buckets``): every
-process, every restart, every bench child bins identically, which is what
-lets bench percentiles and a scraped ``/metrics`` series be compared
-without re-bucketing. Rendering follows the Prometheus text exposition
+process and every restart bins identically, which is what lets two
+scraped ``/metrics`` series be compared without re-bucketing. Rendering follows the Prometheus text exposition
 format (``*_bucket{le=...}`` cumulative counts + ``_sum``/``_count``;
 counters end in ``_total``), so any Prometheus-compatible scraper ingests
 ``GET /metrics`` directly.
@@ -138,8 +137,8 @@ class Histogram:
     values past the last edge land in the implicit +Inf bucket).
     ``quantile(q)`` interpolates linearly inside the winning bucket —
     a bucketed estimate, which is the point: the server's ``/metrics``
-    and the bench's reported percentiles come from the SAME counts, so
-    they cannot drift."""
+    and a percentile reported from the registry come from the SAME
+    counts, so they cannot drift."""
 
     _dlint_guarded_by = {("_m_lock",): ("_hist_counts", "_hist_sum", "_hist_n")}
 
@@ -315,8 +314,8 @@ class LabelledHistogram:
 
 class MetricsRegistry:
     """Name -> metric map with idempotent constructors and one-call text
-    exposition. Re-registering a name returns the existing instance (the
-    bench and the server share instruments by construction)."""
+    exposition. Re-registering a name returns the existing instance (two
+    callers of one name share the instrument by construction)."""
 
     _dlint_guarded_by = {("_reg_lock",): ("_reg_metrics",)}
 

@@ -167,7 +167,7 @@ def merge_chrome_traces(parts: list) -> dict:
 
 def dump_chrome_trace(tracer: SpanTracer, path: str) -> dict:
     """Write the tracer's current window to ``path`` and return the
-    rendered document (the bench reports slice counts from it)."""
+    rendered document (tests count slices in it)."""
     doc = tracer_chrome_trace(tracer)
     with open(path, "w", encoding="utf-8") as f:
         json.dump(doc, f)

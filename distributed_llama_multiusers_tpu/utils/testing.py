@@ -97,10 +97,10 @@ class CharStreamTokenizer(StubStreamTokenizer):
     harnesses: shared text prefixes become shared token prefixes exactly
     as long as they are (the base stub maps every prompt to the same
     tokens, which would make any prefix probe a trivial full-prompt
-    hit). One home shared by tests/test_prefix_cache.py and bench.py's
-    serving_prefix phase, so the encoding the byte-identity tests pin
-    and the encoding the bench measures cannot drift. ``max_chars``
-    caps the prompt length in tokens (None = unbounded)."""
+    hit). One home for tests/test_prefix_cache.py, tests/test_fleet.py
+    and tests/test_disagg.py, so the byte-identity tests pin one
+    encoding. ``max_chars`` caps the prompt length in tokens (None =
+    unbounded)."""
 
     def __init__(self, vocab_size: int = 64, max_chars: int | None = None):
         super().__init__(vocab_size)
@@ -113,14 +113,13 @@ class CharStreamTokenizer(StubStreamTokenizer):
 
 
 class MockAsyncEngine:
-    """Engine stub modelling an ASYNC device for scheduler pipeline tests
-    and the bench microbench: dispatch is free and advances a simulated
+    """Engine stub modelling an ASYNC device for scheduler pipeline
+    tests: dispatch is free and advances a simulated
     device busy-until timeline, consume blocks until the simulated step
     completes. The scheduler's pipelined loop runs against it unmodified,
     so the ``events`` log proves the lag structure (consume of step k runs
     while step k+1 is already dispatched) without accelerator timing noise.
-    One implementation, imported by both tests/test_pipelined_decode.py and
-    bench.py, so the pinned test and the bench evidence cannot drift.
+    (tests/test_pipelined_decode.py holds that lag to it).
 
     Tokens are a pure function of (lane, position) — NOT of global step
     order — so the synchronous scheduler and the pipelined/fused one emit
@@ -455,7 +454,7 @@ class MockAsyncEngine:
             self.swap_in_bytes += len(payload)
 
     def swap_out_parked(self):
-        """Evict every parked chain into the host tier (bench lever)."""
+        """Evict every parked chain into the host tier (tests' lever)."""
         if self.kvpool is None:
             return 0
         n = self.kvpool.swap_out_parked()

@@ -162,10 +162,10 @@ def test_freeze_under_fixed_mode_skips_table_load(tmp_path, monkeypatch):
         pq.set_dequant_mode(None)
 
 
-# -- stats + bench stamps -----------------------------------------------------
+# -- stats ---------------------------------------------------------------------
 
 
-def test_dequant_stats_and_bench_stamp_keys(tmp_path, monkeypatch):
+def test_dequant_stats_keys(tmp_path, monkeypatch):
     path = _write_table(tmp_path, [
         {"d_in": "*", "d_out": "*", "m_class": "*", "mode": "bf16chain"},
     ], updated="2026-08-07")
@@ -177,20 +177,49 @@ def test_dequant_stats_and_bench_stamp_keys(tmp_path, monkeypatch):
         assert stats["dequant_mode"] == "auto"
         assert stats["dequant_sites"] == {"256x512/decode": "bf16chain"}
         assert stats["dequant_table"]["rows"] == 1
-        stamp = ds.bench_stamp("primary")
-        assert stamp["primary_dequant_mode"] == "auto"
-        assert stamp["primary_dequant_sites"] == stats["dequant_sites"]
-        assert "1 rows" in stamp["primary_dequant_table"]
-        assert "2026-08-07" in stamp["primary_dequant_table"]
+        assert stats["dequant_table"]["updated"] == "2026-08-07"
     finally:
         pq.set_dequant_mode(None)
 
 
-def test_bench_stamp_minimal_under_fixed_mode():
-    stamp = ds.bench_stamp("serving")
-    assert stamp["serving_dequant_mode"] == pq.DEQUANT_MODE
-    assert "serving_dequant_sites" not in stamp
-    assert "serving_dequant_table" not in stamp
+# -- block geometry -------------------------------------------------------------
+
+
+def test_block_geometry_constants_are_usable():
+    """The kernel's block geometry is three constants (a chip measurement
+    may move them, ROADMAP S2(b)(ii)). The widest block must be a positive
+    multiple of 128, or no tile candidate divides any plane and every
+    matmul silently takes the XLA fallback; and the block a plan aims for
+    fits the VMEM bound."""
+    from distributed_llama_multiusers_tpu.quants import packed
+
+    assert packed.PALLAS_W_MAX > 0 and packed.PALLAS_W_MAX % 128 == 0
+    assert 0 < pq.SINGLE_SLAB_BYTES <= pq.MAX_BLOCK_BYTES
+    assert 0 < pq.TARGET_BLOCK_BYTES <= pq.MAX_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("d_in,d_out", [
+    (4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096),  # Mistral 7B
+    (4096, 32768),  # its head
+    (3584, 3584), (3584, 512), (3584, 18944), (18944, 3584),  # Qwen2.5 7B
+    (3584, 152064),  # its head, which the loader pads
+])
+def test_packed_layout_and_block_plan_agree(d_in, d_out):
+    """The two modules that share the geometry agree at the benchmark
+    cells' widths: the width the packed layout pads a plane to is a width
+    the kernel plans blocks for, inside its VMEM bound."""
+    from distributed_llama_multiusers_tpu.quants import packed
+
+    padded = packed.padded_d_out(d_out)
+    w_tile = packed.pallas_wide_tile(padded)
+    assert w_tile is not None and w_tile <= packed.PALLAS_W_MAX
+    assert padded % w_tile == 0
+    assert sum(packed.pallas_sub_tiles(w_tile)) == w_tile
+    plan = pq._plan_blocks(d_in, padded)
+    assert plan is not None and plan[0] == w_tile
+    rows = plan[1]
+    assert (d_in // 2) % rows == 0
+    assert rows * w_tile <= pq.MAX_BLOCK_BYTES
 
 
 # -- CLI pairing --------------------------------------------------------------

@@ -555,9 +555,26 @@ def test_disagg_handoff_mid_stream_byte_identical():
     ref = _oracle_text(LONG_BODY)
     p = _paged_replica("p0", role="prefill")
     d = _paged_replica("d0", role="decode")
+    # short sessions co-resident with the hand-off: they must not notice
+    shorts = [{"prompt": f"coresident question {i}", "max_tokens": 16,
+               "stream": True} for i in range(3)]
+    short_refs = [_oracle_text(b) for b in shorts]
     router, rhttpd, rbase = _router([p, d], long_prompt_chars=120)
     try:
+        got = {}
+
+        def short_client(i):
+            got[i] = _stream_via_router(rbase, shorts[i])
+
+        threads = [threading.Thread(target=short_client, args=(i,))
+                   for i in range(len(shorts))]
+        for t in threads:
+            t.start()
         text, term, served, ids = _stream_via_router(rbase, LONG_BODY)
+        for t in threads:
+            t.join(timeout=120)
+        assert [got[i][0] for i in range(len(shorts))] == short_refs
+        assert all("error" not in got[i][1] for i in range(len(shorts)))
         assert served == "p0"  # long -> the prefill-role replica
         assert text == ref
         assert term is not None and "error" not in term
@@ -574,31 +591,53 @@ def test_disagg_handoff_mid_stream_byte_identical():
         stats = router.handle_stats()
         assert stats["router_disagg_handoffs_ok"] == 1
         assert stats["router_long_prompt_chars"] == 120
+        # export, import and adoption dispatched no new device program
+        for r in (p, d):
+            assert r["engine"].stats.snapshot()[
+                "jit_compiles_after_warmup"] == 0, r["rid"]
     finally:
         router.close()
         rhttpd.shutdown()
         _stop_replica(p)
         _stop_replica(d)
+    # adopted pages park or free with their session like native ones:
+    # after stop() neither side holds a page, a mirror or a pending op
+    for r in (p, d):
+        assert all(v == 0 for v in r["sched"].leak_counts().values()), (
+            r["rid"], r["sched"].leak_counts())
 
 
-def test_no_decode_target_falls_back_monolithic():
-    """A fleet with ONLY the prefill replica: the hand-off has nowhere
-    to go, so it falls back typed and the original stream finishes
-    byte-identical — the monolithic path, never a hang."""
+@pytest.mark.parametrize("fleet", ["prefill_only", "prefill_dead"])
+def test_no_decode_target_falls_back_monolithic(fleet):
+    """``prefill_only``: a fleet with ONLY the prefill replica: the
+    hand-off has nowhere to go, so it falls back typed and the original
+    stream finishes byte-identical — the monolithic path, never a hang.
+    ``prefill_dead``: the prefill replica is gone before the long prompt
+    arrives; with no prefill-role replica eligible the router serves it
+    whole on the decode replica, byte-identical, with no hand-off tried."""
     ref = _oracle_text(LONG_BODY)
     p = _paged_replica("solo", role="prefill")
-    router, rhttpd, rbase = _router([p], long_prompt_chars=120)
+    alive = [p]
+    if fleet == "prefill_dead":
+        alive.append(_paged_replica("d0", role="decode"))
+    router, rhttpd, rbase = _router(alive, long_prompt_chars=120)
     try:
+        if fleet == "prefill_dead":
+            alive.remove(p)
+            _stop_replica(p)
+            p["httpd"].server_close()  # connects refused: a dead process
+            router.scrape_once()
         text, term, served, _ = _stream_via_router(rbase, LONG_BODY)
-        assert served == "solo"
+        assert served == ("solo" if fleet == "prefill_only" else "d0")
         assert text == ref
         assert term["choices"][0]["finish_reason"] == "length"
         assert router.disagg_handoffs_ok == 0
-        assert router.disagg_fallbacks == 1
+        assert router.disagg_fallbacks == (1 if fleet == "prefill_only" else 0)
     finally:
         router.close()
         rhttpd.shutdown()
-        _stop_replica(p)
+        for r in alive:
+            _stop_replica(r)
 
 
 def test_prefill_death_mid_transfer_migrates_not_hangs(monkeypatch):

@@ -163,6 +163,13 @@ def test_engine_fault_mid_churn_contained():
             "fault-free run"
         )
     assert len(ok) == n - len(failed)
+    # hang-free: no future was left unresolved (the failure mode before
+    # containment was a dead loop thread with every client blocked)
+    assert all(r.future.done() for r in reqs)
+    # and containment released what the failed lanes held
+    assert all(v == 0 for v in sched.leak_counts().values()), (
+        sched.leak_counts()
+    )
     # ring drained, loop survived long enough to serve everything after
     # the fault and to stop cleanly (sched.stop() in _drive did not raise)
     assert engine.pipeline_inflight() == 0
@@ -286,6 +293,10 @@ def test_breaker_over_health_and_stats_http():
     try:
         status, body = _get(base + "/health")
         assert status == 200 and body["status"] == "ok"
+        # the first /stats of a process starts the JAX backend (about a
+        # second where no earlier test has): read it once before the
+        # 0.3 s cooldown below is running
+        assert _get(base + "/stats")[0] == 200
 
         # one engine fault trips the threshold-1 breaker
         faults.arm("engine.dispatch:@1:n=1")

@@ -646,15 +646,18 @@ def _stream_via_router(rbase, body, on_delta=None, timeout=120):
     return "".join(texts), term, served, ids
 
 
-def test_live_migration_mid_stream_byte_identical():
+@pytest.mark.parametrize("how", ["kill", "drain_timeout"])
+def test_live_migration_mid_stream_byte_identical(how):
     """THE pin (acceptance criterion): a streaming session moved off a
     dying replica resumes on another replica byte-identical to the
     uninterrupted run — zero lost, zero duplicated output — and the
-    router's SSE ids stay gapless across the splice. The kill is the
+    router's SSE ids stay gapless across the splice. ``kill`` is the
     orderly-death shape (accept loop down + scheduler stopped with the
-    stream mid-flight -> the force-cancel path a drain timeout or
-    SIGTERM-then-die takes); transport-level breaks land in the same
-    migrate branch via the socket-error path."""
+    stream mid-flight); transport-level breaks land in the same migrate
+    branch via the socket-error path. ``drain_timeout`` is a rolling
+    restart that runs out of patience: the replica stays reachable,
+    flips to draining, and force-cancels what is still streaming when
+    its short window closes — that stream must migrate, not die."""
     a, b = _replica(rid="m1"), _replica(rid="m2")
     router, rhttpd, rbase = _router([a, b])
     killed = []
@@ -672,8 +675,14 @@ def test_live_migration_mid_stream_byte_identical():
             if n_deltas == 5 and not killed:
                 victim = a if source == "m1" else b
                 killed.append(victim)
-                victim["httpd"].shutdown()
-                victim["sched"].stop()
+                if how == "kill":
+                    victim["httpd"].shutdown()
+                    victim["sched"].stop()
+                else:
+                    threading.Thread(
+                        target=lambda: victim["sched"].drain(timeout=0.03),
+                        daemon=True,
+                    ).start()
 
         text, term, served, ids = _stream_via_router(
             rbase, body, on_delta=kill_source
@@ -693,7 +702,7 @@ def test_live_migration_mid_stream_byte_identical():
         router.close()
         rhttpd.shutdown()
         for r in (a, b):
-            if r not in killed:
+            if how != "kill" or r not in killed:
                 _stop_replica(r)
 
 

@@ -147,21 +147,33 @@ def test_arm_is_idempotent_and_sinks_are_weak(counter_only):
 # -- the serving pin ----------------------------------------------------------
 
 
-def _stack(tiny_model, n_lanes=2):
+def _stack(tiny_model, n_lanes=2, tp=None):
     h = load_model_header(tiny_model["model"])
     config, params = load_params_from_m(
         tiny_model["model"], h, dtype=jnp.float32
     )
     tok = Tokenizer(tiny_model["tokenizer"])
+    mesh = None
+    if tp is not None:
+        from distributed_llama_multiusers_tpu.parallel import (
+            MeshPlan,
+            make_mesh,
+        )
+        from distributed_llama_multiusers_tpu.parallel.sharding import (
+            shard_params,
+        )
+
+        mesh = make_mesh(MeshPlan(tp=tp))
+        params = shard_params(params, mesh)
     engine = InferenceEngine(
-        config, params, n_lanes=n_lanes, prefill_buckets=(8, 16)
+        config, params, n_lanes=n_lanes, prefill_buckets=(8, 16), mesh=mesh
     )
     return engine, tok
 
 
-def _churn(engine, tok, n=4, max_tokens=6):
-    sched = ContinuousBatchingScheduler(engine, tok)
-    warmup_engine(engine, spec=True, multi_step=sched.multi_step)
+def _churn(engine, tok, n=4, max_tokens=6, speculative=True):
+    sched = ContinuousBatchingScheduler(engine, tok, speculative=speculative)
+    warmup_engine(engine, spec=speculative, multi_step=sched.multi_step)
     sched.start()
     try:
         # mixed traffic over a SHARED prompt: greedy + device-sampled
@@ -183,13 +195,25 @@ def _churn(engine, tok, n=4, max_tokens=6):
     finally:
         sched.stop()
     assert all(r.error is None for r in reqs), [r.error for r in reqs]
+    # and after stop() nothing is still held: session mirrors, pending
+    # device ops, open journal marks, lane-held KV pages
+    assert all(v == 0 for v in sched.leak_counts().values()), (
+        sched.leak_counts()
+    )
     return sched
 
 
-def test_serving_churn_is_compile_stable_under_witness(tiny_model, witness_on):
+@pytest.mark.parametrize("plane", ["one_device", "tp2_mesh"])
+def test_serving_churn_is_compile_stable_under_witness(
+    tiny_model, witness_on, plane
+):
     """THE pin: a real serving churn after warmup compiles NOTHING —
     strict mode would have raised at the guilty dispatch, and the
-    counter the bench phases bank reads 0.
+    counter /stats and the benchmark report reads 0. ``tp2_mesh`` is the
+    pod plane's form of it (speculation off, as a pod serves): a
+    recompile on a mesh stalls every chip of it, and the sharded step
+    families, the replicated token carry and the cache shardings must
+    all come out of warmup with the avals the churn dispatches.
 
     Runs under ``DLLAMA_DEQUANT=auto`` (ISSUE 18): with f32 params the
     resolved arithmetic is identical to the default, so the baseline pin
@@ -203,8 +227,12 @@ def test_serving_churn_is_compile_stable_under_witness(tiny_model, witness_on):
     dequant_select._reset_for_tests()
     pallas_q40.set_dequant_mode("auto")
     try:
-        engine, tok = _stack(tiny_model)
-        _churn(engine, tok)
+        if plane == "tp2_mesh":
+            engine, tok = _stack(tiny_model, tp=2)
+            _churn(engine, tok, speculative=False)
+        else:
+            engine, tok = _stack(tiny_model)
+            _churn(engine, tok)
         assert engine.stats.snapshot()["jit_compiles_after_warmup"] == 0
         with pytest.raises(RuntimeError, match="frozen"):
             dequant_select.reload_table()

@@ -226,9 +226,10 @@ def test_corrupt_swap_entry_fails_request_never_poisons_tree():
 
 
 def test_drop_parked_stays_drop_no_tier_deposit():
-    """drop_parked() is the REBUILD lever (the bench's third rung): it
-    must not stage swap-outs even with the tier enabled, or the
-    'rebuild' measurement would quietly serve from host RAM."""
+    """drop_parked() is the REBUILD lever (the third rung of
+    test_paged_three_tier_residency_byte_identical): it must not stage
+    swap-outs even with the tier enabled, or a 'rebuild' would quietly
+    serve from host RAM."""
     eng = _paged_engine()
     _park_chain(eng, 0, list(range(2, 22)))
     assert eng.kvpool.drop_parked() == 1

@@ -114,7 +114,14 @@ def test_stats_surface_shape(witness_off):
 # -- the serving pin: a real churn drains clean ------------------------------
 
 
-def test_scheduler_stop_drains_clean(witness_on):
+@pytest.mark.parametrize("fault", [None, "engine.dispatch:@6:n=1"])
+def test_scheduler_stop_drains_clean(witness_on, fault):
+    """``fault``: one injected dispatch fault mid-churn. Containment
+    fails the lanes it held and must release every mirror, page and
+    pending op they held; every future still resolves (no client hangs)
+    and the loop serves on."""
+    from distributed_llama_multiusers_tpu.utils import faults
+
     engine = MockAsyncEngine(n_lanes=2)
     sched = ContinuousBatchingScheduler(
         engine, StubStreamTokenizer(engine.config.vocab_size),
@@ -124,15 +131,29 @@ def test_scheduler_stop_drains_clean(witness_on):
         Request(prompt=f"drain pin {i}", max_tokens=8, temperature=0.0)
         for i in range(4)
     ]
+    if fault:
+        faults.arm(fault)
     sched.start()
     try:
         for r in reqs:
             sched.submit(r)
         for r in reqs:
-            r.future.result(timeout=60)
+            try:
+                r.future.result(timeout=60)
+            except Exception:  # noqa: BLE001 - the contained lanes' failure
+                assert fault and r.finish_reason == "error", r.error
     finally:
+        faults.disarm()
         sched.stop()  # raises ResourceLeak if anything is still held
-    assert all(r.error is None for r in reqs)
+    assert all(r.future.done() for r in reqs)
+    failed = [r for r in reqs if r.finish_reason == "error"]
+    if fault:
+        assert 1 <= len(failed) <= 2  # the lanes held at the fault, no more
+        assert sched.qos_stats()["engine_failure_rounds"] == 1
+        assert engine.pipeline_inflight() == 0
+    else:
+        assert not failed
+    assert all(r.error is None for r in reqs if r not in failed)
     assert all(v == 0 for v in sched.leak_counts().values())
     assert leakcheck.leaks_total() == 0
 

@@ -189,7 +189,7 @@ def test_sharded_forward_takes_pallas_path(monkeypatch, tmp_path):
 def test_pallas_bf16_weight_tiles_close():
     """w_dtype=bf16 (the VMEM-bandwidth ablation knob) stays within bf16
     rounding of the exact f32 kernel — reachable via
-    linear.set_pallas_w_dtype and the bench ablation."""
+    linear.set_pallas_w_dtype."""
     rng = np.random.default_rng(3)
     pw = _pack(rng, 256, 128)
     x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))

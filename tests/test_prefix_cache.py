@@ -24,8 +24,7 @@ from distributed_llama_multiusers_tpu.runtime import (
 from distributed_llama_multiusers_tpu.tokenizer import Tokenizer
 
 # char-level prompt-DEPENDENT tokenizer (shared text prefixes become
-# shared token prefixes): one home in utils/testing.py, shared with the
-# bench's serving_prefix phase so the two encodings cannot drift
+# shared token prefixes): one home in utils/testing.py
 from distributed_llama_multiusers_tpu.utils.testing import (
     CharStreamTokenizer as _CharTokenizer,
 )
@@ -542,8 +541,8 @@ def test_kvpool_duplicate_content_pages_freed_not_parked():
 def test_kvpool_parked_pages_count_distinct_pages():
     """pool_parked_pages is real pool occupancy: N parked sessions
     sharing the same physical prefix page pin it ONCE, not once per
-    holder — otherwise the pages-per-resident-session bench metric
-    could never show overlap."""
+    holder — otherwise pages per resident session could never show
+    overlap."""
     from distributed_llama_multiusers_tpu.runtime.kvpool import KVPagePool
 
     pool = KVPagePool(n_pages=8, page_size=4, n_lanes=2)
@@ -851,14 +850,24 @@ def test_paged_park_drop_journal_rebuild_byte_identical(loaded, tmp_path):
         assert e.finished
 
 
-def test_paged_three_tier_residency_byte_identical(loaded):
+@pytest.mark.parametrize("warmed", [False, True])
+def test_paged_three_tier_residency_byte_identical(loaded, warmed):
     """Tiered-residency determinism pin: one seeded request replayed
     with its prefix served from each residency tier — resident-parked
     (refcount bump), host-RAM swapped (batched host->device copy behind
     a sha256 re-verify), and dropped (re-prefill rebuild) — produces
     byte-identical streams, all equal to a contiguous engine that never
     paged at all. This is what makes the swap tier safe to enable: the
-    tier only moves WHERE bytes live, never what they are."""
+    tier only moves WHERE bytes live, never what they are.
+
+    ``warmed``: the server's form of it. After ``warmup_engine`` the
+    whole walk compiles NOTHING (the swap gather/scatter programs and
+    the COW page copy are warm-up's to cover, or the first swapped
+    admission stalls every lane behind a compile), and after ``stop()``
+    nothing is still held: the lanes' pages are back at their count from
+    before the first request, parked pages apart."""
+    from distributed_llama_multiusers_tpu.runtime.engine import warmup_engine
+
     config, params, tok = loaded
     prompt = "aa bb cc dd ee ff gg hh 11"
     seed = 1234
@@ -882,7 +891,10 @@ def test_paged_three_tier_residency_byte_identical(loaded):
     eng = InferenceEngine(config, params, n_lanes=2, prefill_buckets=(8,),
                           paged_kv=True, kv_page_size=16,
                           kv_host_bytes=64 << 20)
+    pre_pages = eng.pool_stats().get("pool_pages_in_use", 0)
     sched = ContinuousBatchingScheduler(eng, tok)
+    if warmed:
+        warmup_engine(eng, spec=True, multi_step=sched.multi_step)
     sched.start()
     try:
         assert one(sched) == ref  # cold prefill; the session parks
@@ -901,6 +913,11 @@ def test_paged_three_tier_residency_byte_identical(loaded):
         assert eng.stats.pipeline_flushes == 0
     finally:
         sched.stop()
+    if warmed:
+        assert eng.stats.snapshot()["jit_compiles_after_warmup"] == 0
+        held = sched.leak_counts()
+        assert held.pop("kv_lane_pages") == pre_pages
+        assert all(v == 0 for v in held.values()), held
 
 
 @pytest.mark.slow  # tier-2: heavy; a faster sibling keeps this class covered in tier-1 (see pyproject markers)

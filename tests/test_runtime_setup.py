@@ -136,14 +136,3 @@ def test_opted_in_rdma_hop_raises_instead_of_falling_back(monkeypatch):
     monkeypatch.setattr(ring_collective, "_rdma_shift", broken)
     with pytest.raises(NotImplementedError, match="Mosaic gap"):
         ring_collective._shift(jnp.zeros((8,)), "tp", 2, rdma_ok=True)
-
-
-def test_bench_refuses_an_unknown_device_kind():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    assert bench._chip_spec("TPU v5 lite") == (197e12, 819e9)
-    with pytest.raises(ValueError, match="peaks table"):
-        bench._chip_spec("TPU v9 imaginary")

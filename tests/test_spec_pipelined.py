@@ -658,7 +658,7 @@ def test_spec_accepted_counter_survives_retraction():
 
 def test_specstream_discard_pending_retracts_partial_step(loaded):
     """A turn ending with unconsumed lookahead RETRACTS the partially
-    consumed verify step from the acceptance counters: the bench ratio
+    consumed verify step from the acceptance counters: the /stats ratio
     (emitted per drafted lane-step, class [1, K+1]) aggregates only
     fully realized steps — a discard can neither deflate it nor strand
     a dangling lane-step."""

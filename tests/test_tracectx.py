@@ -340,7 +340,14 @@ def test_replica_honours_trace_header_and_filters_ring():
         assert {e["args"]["trace_id"] for e in events} == {ctx.trace_id}
         assert {e["args"]["replica"] for e in events} == {"tr1"}
         assert "generate" in {e["name"] for e in events}
-        # incremental poll: pass the cursor back, get nothing twice
+        # incremental poll: pass the cursor back, get nothing twice. The
+        # response above came back at the request's last token, with the
+        # pipelined chain's overshoot steps still to consume: their
+        # loop.wait / loop.stream spans land after it. A device op runs
+        # at the serving loop's step boundary only, outside the chain, so
+        # once this one has run the chain has recorded all it will and
+        # the loop is parked on its queue: the ring stands still
+        r["sched"].run_device_op(lambda: None)
         full, _ = _get_json(f"http://{r['base']}/trace")
         inc, _ = _get_json(f"http://{r['base']}/trace?since={full['cursor']}")
         assert [e for e in inc["traceEvents"] if e["ph"] != "M"] == []

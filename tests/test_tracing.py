@@ -154,12 +154,13 @@ class FakeAnnotation:
         return False
 
 
-def run_scheduler(reqs, stagger=True, annotate=None, prompt_tokens=8, **kw):
+def run_scheduler(reqs, stagger=True, annotate=None, prompt_tokens=8,
+                  engine=None, tel=None, **kw):
     """A few requests through the real scheduler loop over the mock engine:
     the first is admitted synchronously (idle scheduler), the others ride the
     live chain as fused admissions."""
-    engine = MockAsyncEngine(n_lanes=4, step_s=0.002, max_chunk=16)
-    tel = Telemetry()
+    engine = engine or MockAsyncEngine(n_lanes=4, step_s=0.002, max_chunk=16)
+    tel = tel or Telemetry()
     if annotate is not None:
         tel.annotation_factory = annotate
     sched = ContinuousBatchingScheduler(
@@ -331,11 +332,61 @@ ADMISSIONS = {
 }
 
 
+class StepStamps(Telemetry):
+    """Telemetry that also notes, per request, WHICH consumed step read back
+    its prompt's last chunk and which one emitted its first token: the
+    scheduler reports a consumed step (``on_pipelined_step``) before it
+    streams that step's tokens and adopts its boundary token."""
+
+    def __init__(self):
+        super().__init__()
+        self.consuming = None
+        self.prefill_done_step = {}
+        self.first_token_step = {}
+
+    def on_pipelined_step(self, t_dispatch, fused_info=None, kind="pipelined",
+                          step=None):
+        self.consuming = step
+        super().on_pipelined_step(t_dispatch, fused_info, kind=kind, step=step)
+
+    def on_prefill_done(self, req, now):
+        self.prefill_done_step[req.id] = self.consuming
+        super().on_prefill_done(req, now)
+
+    def on_token(self, req, now=None):
+        self.first_token_step.setdefault(req.id, self.consuming)
+        super().on_token(req, now)
+
+
+def engine_holding_the_chain_for(reqs):
+    """A mock engine whose device, once the first request has three tokens,
+    returns no further step until the LAST request's submit has begun, by
+    which time every request before it is in the queue: the chain is live
+    at their admission whatever the machine does to the submitting thread
+    meanwhile."""
+    engine = MockAsyncEngine(n_lanes=4, step_s=0.002, max_chunk=16)
+    device_returns = engine.pipeline_consume
+
+    def held_consume():
+        deadline = time.monotonic() + 60
+        while len(reqs[0].generated_tokens) >= 3 and reqs[-1].submitted_at is None:
+            assert time.monotonic() < deadline
+            time.sleep(0.0005)
+        return device_returns()
+
+    engine.pipeline_consume = held_consume
+    return engine
+
+
 @pytest.mark.parametrize("kind", sorted(ADMISSIONS))
 def test_first_token_phases_add_up_to_ttft(kind):
     kw, prompt_tokens, idx = ADMISSIONS[kind]
     reqs = some_requests(4, max_tokens=12, prompt="p" * 64)
-    run_scheduler(reqs, prompt_tokens=prompt_tokens, **kw)
+    assert idx < len(reqs) - 1  # the request looked at is not the last one
+    stamps = StepStamps()
+    # a fused admission needs a live chain to ride
+    engine = engine_holding_the_chain_for(reqs) if kw["pipelined"] else None
+    run_scheduler(reqs, prompt_tokens=prompt_tokens, engine=engine, tel=stamps, **kw)
     for r in reqs:
         ph = r.summary["phases"]
         assert set(ph) == set(PHASE_KEYS)
@@ -351,8 +402,16 @@ def test_first_token_phases_add_up_to_ttft(kind):
         # (the mock's synchronous prefill takes no device time)
         n_chunks = -(-prompt_tokens // 16)
         assert ph["prefill_ms"] >= 2.0 * n_chunks * 0.9
-    # and the first token waited for the next consumed step
-    assert ph["first_token_hold_ms"] >= 1.0
+        # and the first token waited for the next consumed step: the one
+        # after the step whose readback ended the prefill emitted it. (In
+        # time that is a device step, 2 ms, when the host keeps up; a host
+        # that was late to the first readback finds the second one ready,
+        # so no bound on first_token_hold_ms holds by construction.)
+        assert stamps.first_token_step[r.id] == stamps.prefill_done_step[r.id] + 1
+    else:
+        # the prompt was prefilled before the step that emits its first
+        # token was dispatched: that step's 2 ms lie between the two stamps
+        assert ph["first_token_hold_ms"] >= 1.0
 
 
 def test_phases_without_a_first_token_are_zero_not_missing():
