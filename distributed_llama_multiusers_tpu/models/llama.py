@@ -29,6 +29,24 @@ every layer's plane (two thirds of a 7B decode step on a v5e, PERF.md
 section 6, PR 27). The scan runs over one run of identical layers whose cache
 is addressed by layer index (ROADMAP D5's shape).
 
+How the cache is read at decode width. A step of one row a lane (``t == 1``:
+the pipelined decode step, the decode half of every fused step, the
+synchronous and multi-step decodes) on one device, with a contiguous bf16
+cache and Pallas on, slices no plane out of the carry: a Pallas kernel
+(ops/pallas_attention.py) is handed the stack, the layer index and the lanes'
+positions, and fetches for each lane the row blocks up to its position, of
+layer ``l``'s K and V. A parked lane (position ``seq_len``: idle, or admitting
+through a fused step's prefill half) fetches nothing and yields zeros. Read
+whole, the two planes were 4.3 GB a 7B decode step whatever the lanes held,
+two thirds of attention's time (PERF.md section 6, PR 32). The stack goes in
+as the carry holds it, ``(S, n_kv)`` merged into rows by a reshape that moves
+no byte. Everything else takes ``_dense_attention`` over the plane read out
+of the carry, as before: a prefill chunk or a verify step (a ``[T, S]`` score
+tile is the right shape there), the paged pool's gather, any mesh, a cache
+the kernel does not tile, the CPU. What the inputs are decides it
+(``decode_attention_engages``); the two paths share no logic, the dense one
+being the plain form the kernel is tested against.
+
 How the weights move. On one device with the Pallas kernel on, the scan's body
 closes over the stacked Q40 planes (``PackedQ40`` leaves ``[L, d_in/2, d_out]``
 at widths the kernel tiles: loop invariants) and hands ``matmul`` a
@@ -58,9 +76,11 @@ from jax import shard_map
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import HiddenAct
+from ..ops import pallas_attention
 from ..ops.activations import gelu, silu
 from ..ops.linear import (
     matmul,
+    pallas_interpret,
     pallas_kernel_active,
     reads_q40_stack,
     shared_q80_acts,
@@ -382,11 +402,32 @@ def _moe_ffn(y, yq, lp, act_fn, n_active: int, maybe_qdq, ep_sharded: bool = Fal
 def _dense_attention(qf, kf, vf, mask, scale):
     """Single-device GQA attention with materialized scores (reference
     multiheadAtt_F32, src/nn/nn-cpu-ops.cpp:749-784). qf: [B,T,K,G,H] f32;
-    kf/vf: [B,S,K,H] f32; mask: [B,T,S]."""
+    kf/vf: [B,S,K,H] f32; mask: [B,T,S].
+
+    Who still takes it: ``llama_forward`` wherever the in-place decode kernel
+    does not engage (a prefill chunk, the verify programs' ``K + 1`` rows,
+    the paged pool's gathered view, a mesh without sp, a cache dtype or head
+    size the kernel does not tile, the CPU), and training
+    (``train_layer_step_fn``). It is also what tests/test_pallas_attention.py
+    holds the kernel to."""
     scores = jnp.einsum("btkgh,bskh->btkgs", qf * scale, kf)
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("btkgs,bskh->btkgh", probs, vf)
+
+
+def decode_attention_engages(cache, mesh, n_heads: int) -> bool:
+    """Whether a step of one row a lane (``t == 1``) attends this cache in
+    place through ``ops/pallas_attention.py``: a contiguous ``KVCache`` the
+    kernel tiles, on one device, where Pallas compiles (a TPU, or interpret
+    mode). What the inputs are decides it, as ``reads_q40_stack`` does for
+    the weights; the engine asks the same question for its counters."""
+    return (
+        isinstance(cache, KVCache)
+        and mesh is None
+        and pallas_kernel_active()
+        and pallas_attention.supports(cache.k, n_heads)
+    )
 
 
 def llama_forward(
@@ -409,7 +450,9 @@ def llama_forward(
     same buffers back with ``B * T`` rows a layer written, and no copy of a
     plane or of the stack is part of the program's dataflow. Attention reads
     layer ``l``'s plane AFTER that layer's append, so a query sees its own
-    fresh key, as it did when each plane was updated on its own.
+    fresh key, as it did when each plane was updated on its own. At ``T = 1``
+    the read is each lane's rows up to its position, in place (module header,
+    "How the cache is read at decode width").
 
     ``cache`` may be a :class:`PagedKVCache` (paged attention): K/V are
     gathered per lane through the page table into the same ``[B, S, ...]``
@@ -521,10 +564,15 @@ def llama_forward(
         x = params.embedding[tokens]  # [B, T, dim]
     lane_idx = jnp.arange(b)[:, None]  # [B, 1]
 
+    # one row a lane on one device: the cache is attended in place (module
+    # header, "How the cache is read at decode width")
+    in_place = t == 1 and decode_attention_engages(cache, mesh, n_heads)
     with jax.named_scope(SCOPE_ATTENTION):
         # cache index validity: query at position p attends to cache slots s <= p
         s_idx = jnp.arange(h_cfg.seq_len)  # [S]
         attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
+        if in_place:
+            attn_plan = pallas_attention.lane_blocks(positions, h_cfg.seq_len)
 
     if paged:
         # page indirection, computed ONCE (the table is layer-invariant):
@@ -559,6 +607,36 @@ def llama_forward(
         if mesh is None and reads_q40_stack(getattr(layers, f))
     )
     scanned_layers = layers._replace(**dict.fromkeys(in_stack))
+
+    def plane_attention(q, k_all, v_all, l, scale):
+        """Attention over layer ``l``'s whole plane, sliced out of the carry:
+        every width and layout the in-place kernel does not take."""
+        group = n_heads // n_kv
+        qf = q.astype(jnp.float32).reshape(b, t, n_kv, group, hd)
+        # layer l's plane, read out of the carry AFTER the append: the
+        # fresh rows are in it (contiguous: [B, S, n_kv, hd]; paged:
+        # [n_pages, page_size, n_kv, hd])
+        k_cache = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
+        v_cache = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
+        if paged:
+            # gather each lane's logical [S] view through the page table:
+            # the same values a contiguous lane plane would hold, in the
+            # same order, so the f32 attention below is byte-identical to
+            # the contiguous path (pinned by tests/test_prefix_cache.py)
+            kf = k_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
+            vf = v_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
+            return _dense_attention(
+                qf, kf.astype(jnp.float32), vf.astype(jnp.float32),
+                attn_mask, scale,
+            )
+        if use_sp:
+            from ..parallel.ring_attention import sp_attention
+
+            return sp_attention(qf, k_cache, v_cache, positions, mesh, scale)
+        return _dense_attention(
+            qf, k_cache.astype(jnp.float32), v_cache.astype(jnp.float32),
+            attn_mask, scale,
+        )
 
     def layer_step(carry, layer_in):
         # the stacked cache rides the carry ([L, ...]; module header, "How
@@ -611,34 +689,16 @@ def llama_forward(
 
         # GQA attention in f32 (reference multiheadAtt_F32, nn-cpu-ops.cpp:749-784)
         with jax.named_scope(SCOPE_ATTENTION):
-            group = n_heads // n_kv
-            qf = q.astype(jnp.float32).reshape(b, t, n_kv, group, hd)
             scale = 1.0 / float(hd) ** 0.5
-            # layer l's plane, read out of the carry AFTER the append: the
-            # fresh rows are in it (contiguous: [B, S, n_kv, hd]; paged:
-            # [n_pages, page_size, n_kv, hd])
-            k_cache = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
-            v_cache = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
-            if paged:
-                # gather each lane's logical [S] view through the page table:
-                # the same values a contiguous lane plane would hold, in the
-                # same order, so the f32 attention below is byte-identical to
-                # the contiguous path (pinned by tests/test_prefix_cache.py)
-                kf = k_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
-                vf = v_cache.reshape(n_pages * page, n_kv, hd)[gather_idx]
-                attn = _dense_attention(
-                    qf, kf.astype(jnp.float32), vf.astype(jnp.float32),
-                    attn_mask, scale,
+            if in_place:
+                # the kernel fetches each lane's rows [0, pos] of layer l out
+                # of the carry, AFTER the append: no plane is sliced out
+                attn = pallas_attention.decode_attention(
+                    q.reshape(b, n_heads, hd), k_all, v_all, l, attn_plan,
+                    scale, interpret=pallas_interpret(),
                 )
-            elif use_sp:
-                from ..parallel.ring_attention import sp_attention
-
-                attn = sp_attention(qf, k_cache, v_cache, positions, mesh, scale)
             else:
-                attn = _dense_attention(
-                    qf, k_cache.astype(jnp.float32), v_cache.astype(jnp.float32),
-                    attn_mask, scale,
-                )
+                attn = plane_attention(q, k_all, v_all, l, scale)
             attn = attn.reshape(b, t, n_heads * hd).astype(dtype)
 
         # sync-boundary cast (ZQ pipe) + merge_add; with a compressed wire

@@ -70,6 +70,11 @@ def pallas_kernel_active() -> bool:
     return _pallas_enabled and (_pallas_interpret or _pallas_q40_matmul() is not None)
 
 
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode (the tests' hook)."""
+    return _pallas_interpret
+
+
 def shared_q80_acts(x: jnp.ndarray):
     """Build the shared Q80/relayout operand bundle for ``x`` (llama_forward
     builds it once per distinct input and feeds every matmul sharing it),

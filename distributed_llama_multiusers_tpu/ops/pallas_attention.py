@@ -1,0 +1,218 @@
+"""Decode-width attention over the stacked contiguous KV cache, read in place.
+
+At one query row a lane (``t == 1``) attention is a read of the lane's keys
+and values and little else. The dense path (``models/llama.py``
+``_dense_attention``) slices layer ``l``'s whole K and V planes out of the
+stacked cache, ``lanes x seq_len`` rows each whatever the lanes hold, and
+masks afterwards. This kernel is handed the stack itself, the layer index and
+the lanes' positions as scalar-prefetch operands, and fetches for lane ``b``
+only the row blocks ``[0, ceil((pos_b + 1) / BLOCK_ROWS))`` of layer ``l``.
+
+How the stack goes in. The carry holds ``[L, lanes, S, n_kv, hd]`` with
+``(n_kv, hd)`` tiled; merging ``(S, n_kv)`` into one axis of ``S * n_kv``
+rows of ``hd`` leaves every byte where it is (a bitcast for XLA, checked in
+tests/test_chip_compile.py), and gives the kernel a plain ``[rows, hd]``
+matrix a block: row ``s * n_kv + h`` is key head ``h`` of position ``s``.
+
+What a block computes. All query heads of the lane against all rows of the
+block in one product, ``[heads, hd] x [rows, hd]^T``: a row belongs to ONE kv
+head, so the columns of the other heads' rows are masked out by a constant
+bias (``-inf`` where ``col % n_kv != head // group``). The MXU has the width
+to spare, and no head's rows have to be picked out of the tiles. Online
+softmax over the lane's blocks (running maximum, sum and value accumulator in
+f32 scratch); both products take the operands the dense path's einsums give
+the MXU at the TPU's default precision (bf16, accumulated in f32), with the
+scale applied to the f32 scores instead of to the queries, which stay exact.
+
+What is fetched. The grid is one axis over a work list built from the
+positions (``lane_blocks``): one item a block a lane holds, the lanes in
+order, and as many steps as there are items. A grid over lanes x the most
+blocks a lane can have would spend a step on every block a lane does NOT
+have: 128 steps a call at 16 lanes where the chat mixes' lanes hold 37 blocks,
+0.8 ms of a 2.7 ms step on a v5e (PERF.md section 6, PR 32). The K and V block
+index maps read the item's lane and block from the list, so the pipeline
+fetches the next item's block while this one is computed, across lanes. A
+parked lane (position ``>= seq_len``: idle, or admitting through the fused
+step's prefill half) is one item that computes nothing and whose index stays
+on the block the pipeline already holds (the lane before it, or the first
+block the next live lane needs): no fetch is issued for an index that did not
+change, so it reads nothing, and it writes zeros.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# cache rows (positions) a block; chosen on a v5e from the two 7B head shapes
+# (n_kv 8 / group 4 at 16 lanes, n_kv 4 / group 7 at 32): PERF.md section 6
+BLOCK_ROWS = 256
+HEAD_SIZE = 128  # one lane tile: the scratch statistics are [heads, 128]
+# an item's code in the work list (``lane_blocks``)
+FULL, LAST, FIRST, FINAL = 1, 2, 4, 8
+
+
+def supports(k_all, n_heads: int) -> bool:
+    """Whether the kernel takes this cache: a bf16 stack of ``HEAD_SIZE``-wide
+    heads whose context is whole blocks, query heads a multiple of kv heads."""
+    if k_all.ndim != 5 or k_all.dtype != jnp.bfloat16:
+        return False
+    _, _, seq_len, n_kv, hd = k_all.shape
+    return hd == HEAD_SIZE and seq_len % BLOCK_ROWS == 0 and n_heads % n_kv == 0
+
+
+def rows_read(positions, seq_len: int, block: int = BLOCK_ROWS) -> int:
+    """Cache rows a layer's K (or V) fetch brings in for these lane positions:
+    whole blocks up to each live lane's row, nothing for a parked lane. Host
+    side (numpy), for the scheduler's counter."""
+    pos = np.asarray(positions, np.int64)
+    live = pos[(pos >= 0) & (pos < seq_len)]
+    return int((block * (live // block + 1)).sum())
+
+
+def lane_blocks(positions: jnp.ndarray, seq_len: int):
+    """The work list of a step, layer invariant and built once outside the
+    layer scan: ``(n_items, plan)``. Item ``w < n_items`` is one block of one
+    lane, the lanes in order and each lane's blocks in order; a parked lane is
+    one item that computes nothing. ``plan`` is ``int32 [5, lanes * blocks]``:
+    the item's lane, the lane and block it fetches, the lane's position, and
+    a code (``FULL`` or ``LAST`` block, ``FIRST`` / ``FINAL`` item of its
+    lane). The grid has ``n_items`` steps: nothing is spent on the blocks a
+    lane does not have."""
+    pos = positions.reshape(-1).astype(jnp.int32)
+    n_lanes = pos.shape[0]
+    lanes = jnp.arange(n_lanes, dtype=jnp.int32)
+    n = jnp.where((pos >= 0) & (pos < seq_len), pos // BLOCK_ROWS + 1, 0)
+    live = n > 0
+    items = jnp.maximum(n, 1)
+    end = jnp.cumsum(items)
+    w = jnp.arange(n_lanes * (seq_len // BLOCK_ROWS), dtype=jnp.int32)
+    lane = jnp.minimum(jnp.searchsorted(end, w, side="right", method="compare_all"), n_lanes - 1)
+    lane = lane.astype(jnp.int32)
+    j = w - (end - items)[lane]
+    # a parked lane stays on what the pipeline holds: the last block of the
+    # nearest live lane before it, else the first block of the first live lane
+    prev = jax.lax.cummax(jnp.where(live, lanes, -1))
+    first = jnp.argmax(live).astype(jnp.int32)  # 0 when every lane is parked
+    held_lane = jnp.where(prev >= 0, prev, first)
+    held_block = jnp.where(prev >= 0, jnp.maximum(n[held_lane] - 1, 0), 0)
+    src = jnp.where(live, lanes, held_lane)[lane]
+    block = jnp.where(live[lane], j, held_block[lane])
+    code = (
+        jnp.where(live[lane], jnp.where(j == n[lane] - 1, LAST, FULL), 0)
+        + jnp.where(j == 0, FIRST, 0)
+        + jnp.where(j == items[lane] - 1, FINAL, 0)
+    )
+    return end[-1], jnp.stack([lane, src, block, pos[lane], code])
+
+
+def _head_bias(n_heads: int, heads_pad: int, n_kv: int, rows: int) -> np.ndarray:
+    """0 where a block's row (``col % n_kv`` is its kv head) belongs to the
+    query head's group, ``-inf`` elsewhere and on the padding heads."""
+    head = np.arange(heads_pad)[:, None]
+    col = np.arange(rows)[None, :]
+    own = (col % n_kv == head // (n_heads // n_kv)) & (head < n_heads)
+    return np.where(own, 0.0, -np.inf).astype(np.float32)
+
+
+def _decode_attention_kernel(layer_ref, plan_ref, q_ref, bias_ref, k_ref, v_ref,
+                             o_ref, m_ref, l_ref, acc_ref, *, scale, n_kv):
+    del layer_ref  # spent in the index maps
+    w = pl.program_id(0)
+    block_index, pos, code = plan_ref[2, w], plan_ref[3, w], plan_ref[4, w]
+
+    @pl.when(code & FIRST != 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(last: bool):
+        k, v = k_ref[...], v_ref[...]  # [rows, hd]: row s * n_kv + h
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + bias_ref[...]  # [heads, rows]
+        if last:
+            # rows above the lane's position: out of the scores, and out of
+            # the values (0 x NaN is NaN: a stale row must not reach the sum)
+            limit = (pos - block_index * BLOCK_ROWS + 1) * n_kv
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < limit, s, -jnp.inf)
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < limit, v, jnp.zeros_like(v))
+        m_prev = m_ref[...]  # [heads, 128], every lane of a row the same
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a padding head has no row of its own in any block: exp(-inf - 0)
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        alpha = jnp.exp(m_prev - m_safe)
+        p = jnp.exp(s - m_safe[:, :1])
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_ref[...] = m_new
+
+    pl.when(code & FULL != 0)(partial(block, False))
+    pl.when(code & LAST != 0)(partial(block, True))
+
+    @pl.when(code & FINAL != 0)
+    def _():
+        l = l_ref[...]
+        # a parked lane summed nothing: zeros, and no division by its sum
+        o_ref[...] = jnp.where(l > 0.0, acc_ref[...] / l, 0.0)
+
+
+def decode_attention(q, k_all, v_all, layer, work, scale: float,
+                     interpret: bool = False) -> jnp.ndarray:
+    """One query row a lane against layer ``layer`` of the stacked cache.
+
+    q ``[lanes, n_heads, hd]`` (head ``h * group + g`` reads kv head ``h``);
+    ``k_all`` / ``v_all`` ``[L, lanes, S, n_kv, hd]`` as the layer scan
+    carries them, the lanes' fresh rows already appended; ``work`` from
+    ``lane_blocks``. Returns ``[lanes, n_heads, hd]`` float32; a lane's
+    result depends on that lane's rows ``[0, pos]`` alone."""
+    n_layers, lanes, seq_len, n_kv, hd = k_all.shape
+    n_heads = q.shape[1]
+    n_items, plan = work
+    heads_pad = -(-n_heads // 16) * 16  # whole bf16 sublane tiles
+    rows = BLOCK_ROWS * n_kv
+    q = jnp.pad(q.astype(k_all.dtype), ((0, 0), (0, heads_pad - n_heads), (0, 0)))
+    flat = (n_layers, lanes, seq_len * n_kv, hd)  # (S, n_kv) merged: a bitcast
+
+    kv_spec = pl.BlockSpec(
+        (None, None, rows, hd),
+        lambda w, layer_ref, plan_ref: (layer_ref[0], plan_ref[1, w], plan_ref[2, w], 0),
+    )
+    lane_spec = pl.BlockSpec(
+        (None, heads_pad, hd), lambda w, layer_ref, plan_ref: (plan_ref[0, w], 0, 0)
+    )
+    out = pl.pallas_call(
+        partial(_decode_attention_kernel, scale=scale, n_kv=n_kv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # the layer index and the work list
+            grid=(n_items,),
+            in_specs=[
+                lane_spec,
+                pl.BlockSpec((heads_pad, rows), lambda w, *_: (0, 0)),
+                kv_spec,
+                kv_spec,
+            ],
+            out_specs=lane_spec,
+            scratch_shapes=[pltpu.VMEM((heads_pad, hd), jnp.float32)] * 3,
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        name="decode_attention",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan, q,
+      _head_bias(n_heads, heads_pad, n_kv, rows),
+      k_all.reshape(flat), v_all.reshape(flat))
+    return out[:, :n_heads]

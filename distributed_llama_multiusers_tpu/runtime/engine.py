@@ -43,10 +43,12 @@ from ..models.llama import (
     KVCache,
     LlamaParams,
     PagedKVCache,
+    decode_attention_engages,
     init_kv_cache,
     init_paged_kv_cache,
     llama_forward,
 )
+from ..ops import pallas_attention
 from ..telemetry.logs import log_event
 from ..telemetry.names import SCOPE_CARRY, SCOPE_HEAD, SCOPE_SAMPLER
 from ..utils import faults
@@ -149,6 +151,17 @@ class EngineStats:
     # the ones where it actually bit)
     grammar_lanes: int = 0
     grammar_masked_steps: int = 0
+    # decode attention's reads of the KV cache, in rows of one layer's K (or
+    # V) plane, summed over dispatched decode steps (fused steps' decode
+    # halves included; verify steps, which are wider than one row, not).
+    # Kept by the scheduler from the positions the host tracks: `read` is
+    # what the step fetches (whole blocks up to each live lane's row where
+    # the in-place kernel engages, ops/pallas_attention.py; the whole plane
+    # where it does not), `whole` what reading whole planes fetches
+    # (lanes x seq_len a step). Their ratio says how much of a gain is the
+    # traffic's (short lanes) and how much the kernel's
+    attn_kv_rows_read: int = 0
+    attn_kv_rows_whole: int = 0
     # compile stability (analysis/jitcheck.py, ISSUE 15): XLA backend
     # compiles observed AFTER warmup_engine armed the recompile witness —
     # the machine-checked form of "one compiled program per (family,
@@ -186,6 +199,7 @@ class EngineStats:
             "sync_bytes_per_decode", "sync_collectives_per_decode",
             "sync_bytes_total", "worker_restarts", "worker_replay_errors",
             "grammar_lanes", "grammar_masked_steps",
+            "attn_kv_rows_read", "attn_kv_rows_whole",
             "jit_compiles_after_warmup",
         ),
     }
@@ -222,6 +236,7 @@ class EngineStats:
             self.sync_bytes_total = 0
             self.worker_restarts = self.worker_replay_errors = 0
             self.grammar_lanes = self.grammar_masked_steps = 0
+            self.attn_kv_rows_read = self.attn_kv_rows_whole = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
             # compile stability since warmup, and a window reset hiding a
@@ -406,6 +421,14 @@ class InferenceEngine:
             self.kvpool = None
             self.cache = init_kv_cache(config, n_lanes, dtype=cache_dtype)
         self.stats = EngineStats()
+        # cache rows a block of the in-place decode attention fetches; None
+        # where decode steps read whole planes (the scheduler's
+        # attn_kv_rows_* counters ask)
+        self.decode_attention_block = (
+            pallas_attention.BLOCK_ROWS
+            if decode_attention_engages(self.cache, mesh, config.n_heads)
+            else None
+        )
         # async decode pipeline: bounded ring of dispatched-but-unconsumed
         # steps plus the on-device token carry feeding the next dispatch
         self.pipeline_depth = (

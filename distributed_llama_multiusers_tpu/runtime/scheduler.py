@@ -51,6 +51,7 @@ from ..serving import (
 )
 from ..analysis import jitcheck, leakcheck
 from ..lockcheck import make_lock
+from ..ops.pallas_attention import rows_read
 from ..serving.watchdog import deadline_from_env
 from ..telemetry import Telemetry
 from ..telemetry.names import (
@@ -864,6 +865,35 @@ class ContinuousBatchingScheduler:
     def _count_masked_step(self) -> None:
         with self.engine.stats.lock:
             self.engine.stats.grammar_masked_steps += 1
+
+    def _device_rows(self, live: dict, meta) -> np.ndarray:
+        """Where each live lane's row is on the DEVICE at a pipelined
+        dispatch: what the host has consumed (``lane.pos``) plus the lane's
+        steps still in flight (``meta``: one entry a dispatched step, its
+        first field that step's live lanes); every other lane parked."""
+        at = np.full(self.engine.n_lanes, self.engine.config.seq_len, np.int32)
+        for i, lane in live.items():
+            at[i] = lane.pos
+        for step_lanes, *_ in meta:
+            for i, lane in step_lanes:
+                if live.get(i) is lane:
+                    at[i] += 1
+        return at
+
+    def _count_attention_rows(self, positions, steps: int = 1) -> None:
+        """Bump the attn_kv_rows_* counters for a dispatch of ``steps`` decode
+        steps whose lanes stand at ``positions`` (parked: ``seq_len``) and
+        advance one row a step: host integers only, no device value."""
+        engine = self.engine
+        seq_len = engine.config.seq_len
+        block = getattr(engine, "decode_attention_block", None)
+        whole = len(positions) * seq_len * steps
+        read = whole if block is None else sum(
+            rows_read(positions + s, seq_len, block) for s in range(steps)
+        )
+        with engine.stats.lock:
+            engine.stats.attn_kv_rows_read += read
+            engine.stats.attn_kv_rows_whole += whole
 
     def occupancy(self) -> tuple[int, int]:
         """(busy lanes, total lanes) — public surface for /stats."""
@@ -1988,6 +2018,8 @@ class ContinuousBatchingScheduler:
                     fused_info, spec_drafted = self._pipeline_dispatch(
                         live, admitting, feed if host_feed else None, spec_ok
                     )
+                if spec_drafted is None:
+                    self._count_attention_rows(self._device_rows(live, meta))
                 host_feed = False
                 dispatched_any = True
                 meta.append(
@@ -2364,6 +2396,7 @@ class ContinuousBatchingScheduler:
                     )
                 elif h > 1:
                     logits = None  # host-exact lanes are excluded by the gate
+                    self._count_attention_rows(positions, h)
                     chosen = self.engine.decode_multi(
                         tokens, positions, temps, topps, seeds, h,
                         g_states=g_states,
@@ -2372,6 +2405,7 @@ class ContinuousBatchingScheduler:
                     # logits materialize only when a host-exact lane will
                     # read them: the common all-device-sampling step keeps
                     # no [n_lanes, vocab] buffer alive
+                    self._count_attention_rows(positions)
                     logits, greedy, sampled = self.engine.decode(
                         tokens, positions, temps, topps, seeds,
                         want_logits=host_exact_active,

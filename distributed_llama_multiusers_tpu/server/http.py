@@ -410,6 +410,12 @@ class ApiServer:
             # (schemas installed/live, state occupancy) ride qos_stats
             "grammar_lanes": stats["grammar_lanes"],
             "grammar_masked_steps": stats["grammar_masked_steps"],
+            # decode attention's cache reads in rows of one layer's plane:
+            # what the decode steps fetched (whole blocks up to each live
+            # lane's row where the in-place kernel engages) against reading
+            # every lane's whole plane
+            "attn_kv_rows_read": stats["attn_kv_rows_read"],
+            "attn_kv_rows_whole": stats["attn_kv_rows_whole"],
             # failure containment (multihost.worker_serve): supervised
             # restarts + classified protocol errors on THIS process —
             # non-zero only on pod processes that actually restarted

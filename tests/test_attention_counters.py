@@ -1,0 +1,104 @@
+"""The scheduler's attn_kv_rows_* counters (EngineStats) against a hand count.
+
+The host never reads a position back from a chain: a live lane's row on the
+device is what the host has consumed plus its steps still in flight. The mock
+engine simulates the device's position carry, so every dispatch's effective
+positions can be recorded there and the counters recomputed from them.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+from distributed_llama_multiusers_tpu.runtime.scheduler import (
+    ContinuousBatchingScheduler,
+    Request,
+)
+from distributed_llama_multiusers_tpu.utils.testing import (
+    MockAsyncEngine,
+    StubStreamTokenizer,
+)
+
+BLOCK, SEQ, LANES = 256, 1024, 4
+
+
+def _hand_count(positions, block):
+    """Rows a whole-block fetch up to each live lane's row brings in; a lane
+    at `SEQ` (parked, idle, admitting) brings in nothing."""
+    return sum(block * (p // block + 1) for p in positions if 0 <= p < SEQ)
+
+
+def _run(block, pipeline_depth=2):
+    engine = MockAsyncEngine(n_lanes=LANES, seq_len=SEQ, max_chunk=64,
+                             pipeline_depth=pipeline_depth)
+    engine.decode_attention_block = block
+    seen = []  # one entry a decode step: every lane's row on the "device"
+
+    def spy(name, positions_arg):
+        real = getattr(engine, name)
+
+        def wrapped(*a, **kw):
+            seen.append(np.asarray(engine._eff_positions(a[positions_arg])))
+            return real(*a, **kw)
+
+        setattr(engine, name, wrapped)
+
+    spy("decode_pipelined", 0)
+    spy("decode_prefill_fused", 0)
+    spy("decode", 1)
+    sched = ContinuousBatchingScheduler(
+        engine, StubStreamTokenizer(engine.config.vocab_size, prompt_tokens=512),
+        speculative=False, prefix_min_tokens=0, multi_step=0,
+    )
+    # three requests on four lanes: one lane stays parked throughout, and the
+    # long prompt's lane crosses a block boundary while it generates
+    reqs = [Request(prompt="a" * n, max_tokens=m, temperature=0.0)
+            for n, m in ((250, 24), (40, 30), (300, 12))]
+    sched.start()
+    try:
+        sched.submit(reqs[0])
+        deadline = time.monotonic() + 60
+        while len(reqs[0].generated_tokens) < 2:
+            assert time.monotonic() < deadline
+            time.sleep(0.002)
+        for r in reqs[1:]:
+            sched.submit(r)
+        for r in reqs:
+            r.future.result(timeout=60)
+    finally:
+        sched.stop()
+    return engine.stats.snapshot(), seen
+
+
+@pytest.mark.parametrize("pipeline_depth", [2, 0],
+                         ids=["pipelined_and_fused", "synchronous"])
+def test_counters_equal_the_hand_count_over_the_devices_positions(pipeline_depth):
+    stats, seen = _run(BLOCK, pipeline_depth)
+    assert len(seen) > 30
+    assert any((at >= SEQ).any() and (at < SEQ).any() for at in seen)  # a parked lane
+    assert any(BLOCK <= p < SEQ for at in seen for p in at)  # a second block
+    assert stats["attn_kv_rows_whole"] == len(seen) * LANES * SEQ
+    assert stats["attn_kv_rows_read"] == sum(_hand_count(at, BLOCK) for at in seen)
+    assert 0 < stats["attn_kv_rows_read"] < stats["attn_kv_rows_whole"] // 4
+
+
+def test_an_engine_that_reads_whole_planes_counts_whole_planes():
+    """No in-place kernel (the CPU, a paged pool, a mesh): every lane's plane
+    is read whole, parked or not, and the share reads 100 %."""
+    stats, seen = _run(None)
+    assert stats["attn_kv_rows_read"] == stats["attn_kv_rows_whole"] > 0
+    assert stats["attn_kv_rows_whole"] == len(seen) * LANES * SEQ
+
+
+def test_a_multi_step_dispatch_counts_each_of_its_steps():
+    """`decode_multi` advances every live lane a row a step: three steps from
+    rows 255 and 10, one lane parked."""
+    engine = MockAsyncEngine(n_lanes=3, seq_len=SEQ)
+    engine.decode_attention_block = BLOCK
+    sched = ContinuousBatchingScheduler(
+        engine, StubStreamTokenizer(engine.config.vocab_size))
+    sched._count_attention_rows(np.array([255, SEQ, 10], np.int32), steps=3)
+    stats = engine.stats.snapshot()
+    assert stats["attn_kv_rows_whole"] == 3 * 3 * SEQ
+    assert stats["attn_kv_rows_read"] == (BLOCK + 2 * BLOCK + 2 * BLOCK) + 3 * BLOCK

@@ -212,28 +212,20 @@ def test_stacked_weight_every_selectable_mode_compiles_for_v5e(
     assert "tpu_custom_call" in _compile_stacked(v5e, mode, d_in, d_out, m)
 
 
-@pytest.mark.parametrize("reads_stack", [True, False],
-                         ids=["kernel_reads_stack", "control_scanned_planes"])
-def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
-    """The optimized HLO of a three-layer decode forward at (4096, 14336) and
-    the other planes of that width: no slice, fusion or copy has a nibble
-    plane's shape as its result. The control scans the planes as the program
-    did before PR 30, and shows the slices this check looks for. (Stacks as
-    small as three layers XLA may stage WHOLE in fast memory ahead of the
-    loop, by `slice-start`s of its own; that is not what is looked for.)"""
-    import re
-
+def _three_layer_decode_hlo(v5e, monkeypatch, lanes=16, n_heads=32, n_kv=8):
+    """The optimized HLO of a three-layer decode forward (one row a lane, the
+    cache donated) for a described v5e, and its dimensions: Mistral-7B's
+    widths, or Qwen2.5-7B's at 28 heads."""
     from distributed_llama_multiusers_tpu.models import llama
     from distributed_llama_multiusers_tpu.models.config import LlamaConfig
 
     monkeypatch.setattr(
         linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas
     )
-    if not reads_stack:
-        monkeypatch.setattr(llama, "reads_q40_stack", lambda w: False)
-    L, d, h, kv, vocab, lanes, seq = 3, 4096, 14336, 1024, 8192, 16, 256
-    cfg = LlamaConfig(dim=d, hidden_dim=h, n_layers=L, n_heads=32, n_kv_heads=8,
-                      vocab_size=vocab, seq_len=seq)
+    L, d, kv, vocab, seq = 3, n_heads * 128, n_kv * 128, 8192, 256
+    h = {4096: 14336, 3584: 18944}[d]
+    cfg = LlamaConfig(dim=d, hidden_dim=h, n_layers=L, n_heads=n_heads,
+                      n_kv_heads=n_kv, vocab_size=vocab, seq_len=seq)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
     q40 = lambda d_in, d_out, lead=(L,): PackedQ40(
         packed=sds(lead + (d_in // 2, d_out), jnp.uint8),
@@ -246,12 +238,34 @@ def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
             rms_att=sds((L, d), jnp.float32), rms_ffn=sds((L, d), jnp.float32)),
         rms_final=sds((d,), jnp.float32), wcls=q40(d, vocab, ()),
         rope_cos=sds((seq, 64), jnp.float32), rope_sin=sds((seq, 64), jnp.float32))
-    cache = llama.KVCache(*(sds((L, lanes, seq, 8, 128), jnp.bfloat16),) * 2)
+    cache = llama.KVCache(*(sds((L, lanes, seq, n_kv, 128), jnp.bfloat16),) * 2)
     tok = sds((lanes, 1), jnp.int32)
     hlo = jax.jit(
         lambda p, t, c: llama.llama_forward(cfg, p, t, t, c), donate_argnums=(2,)
     ).lower(params, tok, cache).compile().as_text()
-    assert hlo.count("tpu_custom_call") == 8  # seven a layer body, and the head
+    return hlo, dict(L=L, d=d, h=h, kv=kv, lanes=lanes, seq=seq, n_kv=n_kv)
+
+
+@pytest.mark.parametrize("reads_stack", [True, False],
+                         ids=["kernel_reads_stack", "control_scanned_planes"])
+def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
+    """The optimized HLO of a three-layer decode forward at (4096, 14336) and
+    the other planes of that width: no slice, fusion or copy has a nibble
+    plane's shape as its result. The control scans the planes as the program
+    did before PR 30, and shows the slices this check looks for. (Stacks as
+    small as three layers XLA may stage WHOLE in fast memory ahead of the
+    loop, by `slice-start`s of its own; that is not what is looked for.)
+    Nine kernel calls: seven Q40 matmuls a layer body and the head, and since
+    PR 32 the decode attention that reads the cache in place."""
+    import re
+
+    from distributed_llama_multiusers_tpu.models import llama
+
+    if not reads_stack:
+        monkeypatch.setattr(llama, "reads_q40_stack", lambda w: False)
+    hlo, dims = _three_layer_decode_hlo(v5e, monkeypatch)
+    d, h, kv = dims["d"], dims["h"], dims["kv"]
+    assert hlo.count("tpu_custom_call") == 9
     # a plane sliced out for a kernel call: the result of a slice fusion of
     # its own (`[1, d_in/2, d_out]`: the kernel takes a plane as a stack of one)
     planes = {(a // 2, b) for a, b in ((d, d), (d, kv), (d, h), (h, d))}
@@ -262,6 +276,79 @@ def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
         assert sliced == set(), sliced
     else:
         assert sliced == planes, sliced
+
+
+def _cache_sized_results(hlo: str, L, lanes, seq, n_kv) -> list[str]:
+    """Instructions that MAKE an array of the size of a K or V plane or of
+    the stack, in the carry's shape or with (S, n_kv) merged: a slice, a
+    fusion, a copy or a conversion, in any layout. The in-place appends (a
+    scatter fusion whose operand is the stack it returns) are what a decode
+    step is allowed; parameters, tuple elements and bitcasts move nothing."""
+    import re
+
+    lead = rf"(?:{L},|1,)?{lanes},"
+    shape = rf"(?:bf16|f32)\[{lead}(?:{seq},{n_kv}|{seq * n_kv}),128\]"
+    made = re.findall(
+        rf"^\s*(?:ROOT )?(\S+) = {shape}\S* "
+        r"(fusion|dynamic-slice|slice|copy|convert|transpose|copy-start)\((.*)$",
+        hlo, flags=re.M)
+    return [f"{name} = {op}" for name, op, rest in made
+            if not (op == "fusion" and "dl.kv_write" in rest)]
+
+
+# Decode attention in place (PR 32, ops/pallas_attention.py): (lanes, n_heads,
+# n_kv) of the benchmark's two configurations at their cells' lanes, bf16,
+# 2048 positions
+ATTENTION_SHAPES = [(16, 32, 8), (32, 28, 4)]
+
+
+@pytest.mark.parametrize("lanes,n_heads,n_kv", ATTENTION_SHAPES,
+                         ids=["mistral7b", "qwen25_7b"])
+def test_decode_attention_compiles_for_v5e(v5e, lanes, n_heads, n_kv):
+    """Mosaic takes the kernel at both head shapes, the stack of a few layers
+    as the carry holds it, the layer and the work list traced."""
+    from distributed_llama_multiusers_tpu.ops import pallas_attention as pa
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    stack = sds((STACK_LAYERS, lanes, 2048, n_kv, 128), jnp.bfloat16)
+
+    def attend(q, k, v, layer, positions):
+        return pa.decode_attention(
+            q, k, v, layer, pa.lane_blocks(positions, 2048), 128 ** -0.5)
+
+    hlo = jax.jit(attend).lower(
+        sds((lanes, n_heads, 128), jnp.bfloat16), stack, stack,
+        sds((), jnp.int32), sds((lanes,), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo and "decode_attention" in hlo
+    # merging (S, n_kv) for the kernel moved no byte of either stack
+    assert not _cache_sized_results(hlo, STACK_LAYERS, lanes, 2048, n_kv)
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel_reads_in_place", "control_plane_reads"])
+@pytest.mark.parametrize("lanes,n_heads,n_kv", ATTENTION_SHAPES,
+                         ids=["mistral7b", "qwen25_7b"])
+def test_decode_forward_reads_no_kv_plane_for_v5e(
+        v5e, monkeypatch, lanes, n_heads, n_kv, in_place):
+    """The optimized HLO of a three-layer decode forward: nothing has a K or V
+    plane, or the stack, as its result but the two in-place appends: the
+    kernel is handed the carry. The control patches the kernel's predicate
+    off, as the program was before PR 32, and shows what the check looks
+    for: each plane read out of the stack (and, at 4 kv heads, copied)."""
+    from distributed_llama_multiusers_tpu.models import llama
+
+    if not in_place:
+        monkeypatch.setattr(llama, "decode_attention_engages",
+                            lambda cache, mesh, n_heads: False)
+    hlo, dims = _three_layer_decode_hlo(v5e, monkeypatch, lanes, n_heads, n_kv)
+    assert hlo.count("decode_attention") >= int(in_place)
+    made = _cache_sized_results(hlo, dims["L"], lanes, dims["seq"], n_kv)
+    if in_place:
+        assert made == [], made
+    else:
+        reads = [m for m in made if "dynamic-slice" in m or "fusion" in m]
+        assert len(reads) >= 2, made  # K's plane and V's
 
 
 def test_selection_table_resolves_only_to_compile_tested_modes():
