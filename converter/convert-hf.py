@@ -22,7 +22,13 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from distributed_llama_multiusers_tpu.formats.model_file import ArchType, HiddenAct, ModelHeader, RopeType
+from distributed_llama_multiusers_tpu.formats.model_file import (
+    ArchType,
+    HiddenAct,
+    ModelHeader,
+    MoeScore,
+    RopeType,
+)
 from distributed_llama_multiusers_tpu.quants.codec import FloatType
 from writer import parse_float_type, write_header, write_tensor
 
@@ -76,6 +82,9 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         "mistral": ArchType.LLAMA,
         "mixtral": ArchType.LLAMA,
         "qwen2": ArchType.LLAMA,
+        # latent attention + routed FFN: the header's KEY_KV_LORA_RANK block
+        # says so; the arch word stays the one every reader accepts
+        "deepseek_v3": ArchType.LLAMA,
     }.get(cfg["model_type"])
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
@@ -97,6 +106,8 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         vocab_size=cfg["vocab_size"],
         rope_theta=float(cfg.get("rope_theta", 10000.0)),
     )
+    if cfg["model_type"] == "deepseek_v3":
+        set_latent_header(h, cfg)
     n_experts = cfg.get("num_local_experts")
     if n_experts:
         h.n_experts = int(n_experts)
@@ -115,8 +126,77 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
     return h, cfg
 
 
-def convert(folder: str, weight_type: int, out_path: str) -> None:
+def set_latent_header(h: ModelHeader, cfg: dict) -> None:
+    """The header keys of ``model_type: deepseek_v3`` (formats/model_file.py
+    KEY_KV_LORA_RANK ...). What the runtime does not compute is refused here,
+    not converted wrongly."""
+    for key, want in (("q_lora_rank", None), ("n_group", 1), ("topk_group", 1),
+                      ("moe_layer_freq", 1), ("rope_scaling", None)):
+        if cfg.get(key, want) != want:
+            raise ValueError(f"Unsupported deepseek_v3 setting: {key} = {cfg[key]!r}")
+    if not cfg.get("rope_interleave", True):
+        raise ValueError("Unsupported deepseek_v3 setting: rope_interleave false")
+    score = {"sigmoid": MoeScore.SIGMOID, "softmax": MoeScore.SOFTMAX}.get(cfg["scoring_func"])
+    if score is None:
+        raise ValueError(f"Unsupported scoring_func: {cfg['scoring_func']}")
+    h.kv_lora_rank = cfg["kv_lora_rank"]
+    h.qk_nope_head_dim = cfg["qk_nope_head_dim"]
+    h.qk_rope_head_dim = cfg["qk_rope_head_dim"]
+    h.v_head_dim = cfg["v_head_dim"]
+    h.norm_epsilon = float(cfg["rms_norm_eps"])
+    h.n_experts = int(cfg.get("n_routed_experts") or 0)
+    if h.n_experts:
+        h.n_active_experts = int(cfg["num_experts_per_tok"])
+        h.moe_hidden_dim = cfg["moe_intermediate_size"]
+        h.shared_hidden_dim = int(cfg.get("n_shared_experts") or 0) * cfg["moe_intermediate_size"]
+        h.n_dense_layers = int(cfg.get("first_k_dense_replace", 0))
+        h.moe_score_func = score
+        h.moe_select_bias = int(cfg.get("topk_method") == "noaux_tc")
+        h.moe_norm_topk = int(bool(cfg.get("norm_topk_prob", True)))
+        h.moe_routed_scale = float(cfg.get("routed_scaling_factor", 1.0))
+
+
+def write_latent_layers(out, index, header: ModelHeader, wt: int) -> None:
+    """The layers of a deepseek_v3 checkpoint in the order of
+    formats/model_file._latent_block_specs. ``kv_a_proj_with_mqa`` and
+    ``kv_b_proj`` are kept whole; no row is permuted: ``rope_interleave``
+    checkpoints hold the rotary part in adjacent pairs, the runtime's own
+    convention. The router and its selection bias stay F32."""
+    for l in range(header.n_layers):
+        pre = f"model.layers.{l}"
+        write_tensor(out, index.get(f"{pre}.self_attn.q_proj.weight"), wt)
+        write_tensor(out, index.get(f"{pre}.self_attn.kv_a_proj_with_mqa.weight"), wt)
+        write_tensor(out, index.get(f"{pre}.self_attn.kv_a_layernorm.weight"), FloatType.F32)
+        write_tensor(out, index.get(f"{pre}.self_attn.kv_b_proj.weight"), wt)
+        write_tensor(out, index.get(f"{pre}.self_attn.o_proj.weight"), wt)
+        if l < header.n_dense_layers or header.n_experts == 0:
+            write_tensor(out, index.get(f"{pre}.mlp.gate_proj.weight"), wt)  # w1
+            write_tensor(out, index.get(f"{pre}.mlp.down_proj.weight"), wt)  # w2
+            write_tensor(out, index.get(f"{pre}.mlp.up_proj.weight"), wt)  # w3
+        else:
+            write_tensor(out, index.get(f"{pre}.mlp.gate.weight"), FloatType.F32)
+            if header.moe_select_bias:
+                write_tensor(out, index.get(f"{pre}.mlp.gate.e_score_correction_bias"), FloatType.F32)
+            for e in range(header.n_experts):
+                epre = f"{pre}.mlp.experts.{e}"
+                write_tensor(out, index.get(f"{epre}.up_proj.weight"), wt)  # w3
+                write_tensor(out, index.get(f"{epre}.gate_proj.weight"), wt)  # w1
+                write_tensor(out, index.get(f"{epre}.down_proj.weight"), wt)  # w2
+            if header.shared_hidden_dim:
+                spre = f"{pre}.mlp.shared_experts"
+                write_tensor(out, index.get(f"{spre}.gate_proj.weight"), wt)  # w1
+                write_tensor(out, index.get(f"{spre}.down_proj.weight"), wt)  # w2
+                write_tensor(out, index.get(f"{spre}.up_proj.weight"), wt)  # w3
+        write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
+        write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
+
+
+def convert(folder: str, weight_type: int, out_path: str, index=None) -> None:
+    """``index`` (tests): anything with ``get(key)`` and ``in`` over the
+    checkpoint's tensor names, in place of the folder's safetensors."""
     header, cfg = load_config(folder, weight_type)
+    if index is not None:
+        return write_model(header, index, weight_type, out_path)
     files = sorted(
         os.path.join(folder, f)
         for f in os.listdir(folder)
@@ -124,7 +204,10 @@ def convert(folder: str, weight_type: int, out_path: str) -> None:
     )
     if not files:
         raise FileNotFoundError("No .safetensors files found")
-    index = SafetensorsIndex(files)
+    write_model(header, SafetensorsIndex(files), weight_type, out_path)
+
+
+def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> None:
     wt = weight_type
     n_heads, n_kv = header.n_heads, header.n_kv_heads
     # Qwen2-family checkpoints (and llama-arch configs with
@@ -138,7 +221,9 @@ def convert(folder: str, weight_type: int, out_path: str) -> None:
     with open(out_path, "wb") as out:
         write_header(out, header)
         write_tensor(out, index.get("model.embed_tokens.weight"), FloatType.F32)
-        for l in range(header.n_layers):
+        if header.kv_lora_rank:
+            write_latent_layers(out, index, header, wt)
+        for l in range(0 if header.kv_lora_rank else header.n_layers):  # a Llama block's layers
             pre = f"model.layers.{l}"
             write_tensor(out, permute_rotary(index.get(f"{pre}.self_attn.q_proj.weight"), n_heads), wt)
             if header.qkv_bias:

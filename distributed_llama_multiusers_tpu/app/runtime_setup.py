@@ -69,6 +69,19 @@ def enable_compilation_cache() -> str | None:
     return path
 
 
+def _build_engine(config, params, **kw) -> InferenceEngine:
+    """``InferenceEngine(...)``; what a latent-attention model does not serve
+    (the paged pool, the host tier, a mesh) ends the start-up with the
+    engine's own sentence instead of a traceback."""
+    try:
+        return InferenceEngine(config, params, **kw)
+    except ValueError as e:
+        if not config.latent_attention:
+            raise
+        print(f"error: {e}", file=sys.stderr)
+        raise SystemExit(2) from e
+
+
 def load_stack(args, n_lanes: int | None = None):
     """Returns (config, params, tokenizer, engine).
 
@@ -104,7 +117,8 @@ def load_stack(args, n_lanes: int | None = None):
         config, params = load_params_from_m_quantized(args.model, header, dtype=config_dtype)
         from ..quants.packed import PackedQ40
 
-        if any(isinstance(x, PackedQ40) for x in [params.wcls, params.layers.wq]):
+        block = params.attn if config.latent_attention else params.layers
+        if any(isinstance(x, PackedQ40) for x in [params.wcls, block.wq]):
             log("🔷", "Q40 weights resident in HBM (dequant-in-matmul)")
         else:
             weights_mode = "dense"
@@ -225,7 +239,7 @@ def load_stack(args, n_lanes: int | None = None):
             file=sys.stderr,
         )
         raise SystemExit(2)
-    engine = InferenceEngine(
+    engine = _build_engine(
         config,
         params,
         # every process must compile identical programs: lane count comes
@@ -310,6 +324,8 @@ def load_stack(args, n_lanes: int | None = None):
             weights_mode == "packed" and pallas_kernel_active()
         ),
         "ring_sync": ring_sync,
+        # which attention and which expert path the decode steps run
+        **engine.path_facts(),
         # weights + KV as placed, per local device (None: the backend does
         # not report it) — a mesh that left everything on device 0 shows
         "device_bytes_in_use": (

@@ -56,6 +56,24 @@ KEY_ROPE_TYPE = 18
 # (Qwen2-family checkpoints). Readers of bias-free files never see the key,
 # so every pre-extension .m stays byte-identical.
 KEY_QKV_BIAS = 19
+# framework extension: the latent-attention block with a routed FFN
+# (``model_type: deepseek_v3``; models/deepseek.py). Written only where
+# KEY_KV_LORA_RANK is nonzero, so every file without them reads, and is
+# written, as before. Two values are not whole numbers and are stored scaled:
+# the routed experts' factor in millionths, the norm's epsilon in billionths
+# (absent: the 1e-5 every Llama file has always been read with).
+KEY_KV_LORA_RANK = 20
+KEY_QK_NOPE_HEAD_DIM = 21
+KEY_QK_ROPE_HEAD_DIM = 22
+KEY_V_HEAD_DIM = 23
+KEY_MOE_HIDDEN_DIM = 24
+KEY_SHARED_HIDDEN_DIM = 25
+KEY_N_DENSE_LAYERS = 26
+KEY_MOE_SCORE_FUNC = 27
+KEY_MOE_SELECT_BIAS = 28
+KEY_MOE_NORM_TOPK = 29
+KEY_MOE_ROUTED_SCALE_E6 = 30
+KEY_NORM_EPSILON_E9 = 31
 
 
 class ArchType:
@@ -65,6 +83,13 @@ class ArchType:
 class HiddenAct:
     GELU = 0
     SILU = 1
+
+
+class MoeScore:
+    """How a router turns its logits into scores over all experts."""
+
+    SOFTMAX = 0  # Mixtral: softmax, the chosen renormalised
+    SIGMOID = 1  # DeepSeek-V3: independent sigmoids
 
 
 class RopeType:
@@ -99,6 +124,18 @@ class ModelHeader:
     rope_type: int = RopeType.LLAMA
     qkv_bias: int = 0  # Qwen2-family q/k/v bias vectors (KEY_QKV_BIAS)
     norm_epsilon: float = 1e-5
+    # the latent-attention block (KEY_KV_LORA_RANK ...); all zero elsewhere
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_hidden_dim: int = 0
+    shared_hidden_dim: int = 0
+    n_dense_layers: int = 0
+    moe_score_func: int = MoeScore.SOFTMAX
+    moe_select_bias: int = 0
+    moe_norm_topk: int = 1
+    moe_routed_scale: float = 1.0
     header_size: int = 0
     file_size: int = 0
 
@@ -132,7 +169,28 @@ class ModelHeader:
             (KEY_ROPE_SCALING_HIGH_FREQ_FACTORY, int(self.rope_scaling_high_freq_factor)),
             (KEY_ROPE_SCALING_ORIG_MAX_SEQ_LEN, self.rope_scaling_orig_max_seq_len),
             (KEY_ROPE_TYPE, self.rope_type),
-        ] + ([(KEY_QKV_BIAS, self.qkv_bias)] if self.qkv_bias else [])
+        ] + ([(KEY_QKV_BIAS, self.qkv_bias)] if self.qkv_bias else []) + (
+            [(key, getattr(self, name)) for key, name in _LATENT_INT_KEYS.items()]
+            + [(KEY_MOE_ROUTED_SCALE_E6, int(round(self.moe_routed_scale * 1e6))),
+               (KEY_NORM_EPSILON_E9, int(round(self.norm_epsilon * 1e9)))]
+            if self.kv_lora_rank else []
+        )
+
+
+_LATENT_INT_KEYS = {
+    KEY_KV_LORA_RANK: "kv_lora_rank",
+    KEY_QK_NOPE_HEAD_DIM: "qk_nope_head_dim",
+    KEY_QK_ROPE_HEAD_DIM: "qk_rope_head_dim",
+    KEY_V_HEAD_DIM: "v_head_dim",
+    KEY_MOE_HIDDEN_DIM: "moe_hidden_dim",
+    KEY_SHARED_HIDDEN_DIM: "shared_hidden_dim",
+    KEY_N_DENSE_LAYERS: "n_dense_layers",
+    KEY_MOE_SCORE_FUNC: "moe_score_func",
+    KEY_MOE_SELECT_BIAS: "moe_select_bias",
+    KEY_MOE_NORM_TOPK: "moe_norm_topk",
+}
+# every header field of the latent-attention block, as models/config.py takes them
+LATENT_FIELDS = (*_LATENT_INT_KEYS.values(), "moe_routed_scale")
 
 
 def write_model_header(f: BinaryIO, header: ModelHeader) -> int:
@@ -200,6 +258,12 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 h.rope_type = value
             elif key == KEY_QKV_BIAS:
                 h.qkv_bias = value
+            elif key in _LATENT_INT_KEYS:
+                setattr(h, _LATENT_INT_KEYS[key], value)
+            elif key == KEY_MOE_ROUTED_SCALE_E6:
+                h.moe_routed_scale = value / 1e6
+            elif key == KEY_NORM_EPSILON_E9:
+                h.norm_epsilon = value / 1e9
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
@@ -247,7 +311,9 @@ def model_tensor_specs(h: ModelHeader) -> list[TensorSpec]:
     wt = h.weight_type
     dim, hidden, kv_dim, vocab = h.dim, h.hidden_dim, h.kv_dim, h.vocab_size
     add("embedding", 0, FloatType.F32, (vocab, dim))
-    for l in range(h.n_layers):
+    if h.kv_lora_rank:
+        _latent_block_specs(h, add)
+    for l in range(0 if h.kv_lora_rank else h.n_layers):  # a Llama block's layers
         add("block_matmul_q", l, wt, (dim, dim))
         if h.qkv_bias:
             add("block_bias_q", l, FloatType.F32, (1, dim))
@@ -273,6 +339,45 @@ def model_tensor_specs(h: ModelHeader) -> list[TensorSpec]:
     add("final_rms_norm", 0, FloatType.F32, (1, dim))
     add("final_matmul_logits", 0, wt, (vocab, dim))
     return specs
+
+
+def _latent_block_specs(h: ModelHeader, add) -> None:
+    """The layers of a latent-attention file (``model_type: deepseek_v3``), a
+    framework extension like the router above. A layer: ``q`` (heads x
+    (nope + rope) rows), ``kv_a`` (latent + rope rows, kept whole), the
+    latent's norm, ``kv_b`` (heads x (nope + v) rows, kept whole), ``wo``;
+    then a dense FFN in the first ``n_dense_layers`` layers, and in the
+    others the router (F32), its selection bias (F32, where the header says
+    so), every expert's w3, w1, w2, and the shared experts as one gated FFN;
+    then the two norms. The rotary part of ``q`` and ``kv_a`` is in the
+    interleaved-pair layout as published (``rope_interleave``)."""
+    wt, dim = h.weight_type, h.dim
+    qk = h.qk_nope_head_dim + h.qk_rope_head_dim
+    for l in range(h.n_layers):
+        add("block_matmul_q", l, wt, (h.n_heads * qk, dim))
+        add("block_matmul_kv_a", l, wt, (h.kv_lora_rank + h.qk_rope_head_dim, dim))
+        add("block_rms_norm_kv", l, FloatType.F32, (1, h.kv_lora_rank))
+        add("block_matmul_kv_b", l, wt,
+            (h.n_heads * (h.qk_nope_head_dim + h.v_head_dim), h.kv_lora_rank))
+        add("block_matmul_wo", l, wt, (dim, h.n_heads * h.v_head_dim))
+        if l < h.n_dense_layers or h.n_experts == 0:
+            add("block_matmul_w1", l, wt, (h.hidden_dim, dim))
+            add("block_matmul_w2", l, wt, (dim, h.hidden_dim))
+            add("block_matmul_w3", l, wt, (h.hidden_dim, dim))
+        else:
+            add("block_moe_gate", l, FloatType.F32, (h.n_experts, dim))
+            if h.moe_select_bias:
+                add("block_moe_bias", l, FloatType.F32, (1, h.n_experts))
+            for e in range(h.n_experts):
+                add("block_matmul_w3", l, wt, (h.moe_hidden_dim, dim), e)
+                add("block_matmul_w1", l, wt, (h.moe_hidden_dim, dim), e)
+                add("block_matmul_w2", l, wt, (dim, h.moe_hidden_dim), e)
+            if h.shared_hidden_dim:
+                add("block_matmul_shared_w1", l, wt, (h.shared_hidden_dim, dim))
+                add("block_matmul_shared_w2", l, wt, (dim, h.shared_hidden_dim))
+                add("block_matmul_shared_w3", l, wt, (h.shared_hidden_dim, dim))
+        add("block_rms_norm_0", l, FloatType.F32, (1, dim))
+        add("block_rms_norm_1", l, FloatType.F32, (1, dim))
 
 
 def iter_model_tensors(path: str, header: ModelHeader) -> Iterator[tuple[TensorSpec, np.ndarray]]:

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..formats.model_file import HiddenAct, ModelHeader, RopeType
+from ..formats.model_file import LATENT_FIELDS, HiddenAct, ModelHeader, MoeScore, RopeType
 
 
 @dataclass(frozen=True)
@@ -27,6 +27,26 @@ class LlamaConfig:
     n_experts: int = 0
     n_active_experts: int = 0
     qkv_bias: int = 0  # Qwen2-family: add per-layer q/k/v projection biases
+    # Latent attention (the DeepSeek-V2/V3 block; models/deepseek.py):
+    # kv_lora_rank > 0 selects it. Every head has its own sizes there (a
+    # query/key head is qk_nope + qk_rope wide, a value head v_head_dim), none
+    # of them dim // n_heads; the cache keeps one row of kv_lora_rank +
+    # qk_rope_head_dim numbers a token a layer, shared by all heads.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The routed FFN of that block: the first n_dense_layers layers keep a
+    # dense gated FFN of hidden_dim; the others route n_active_experts of
+    # n_experts experts of moe_hidden_dim each, beside one always-on gated FFN
+    # of shared_hidden_dim (the shared experts, merged; 0: none).
+    moe_hidden_dim: int = 0
+    shared_hidden_dim: int = 0
+    n_dense_layers: int = 0
+    moe_score_func: int = MoeScore.SOFTMAX  # scores over all experts
+    moe_select_bias: int = 0  # a per-expert bias added to choose, not to weigh
+    moe_norm_topk: int = 1  # chosen scores renormalised to sum 1
+    moe_routed_scale: float = 1.0  # factor on the routed experts' sum
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -35,9 +55,35 @@ class LlamaConfig:
                 f"n_active_experts={self.n_active_experts}, n_experts={self.n_experts}"
             )
 
+        if self.kv_lora_rank > 0:
+            if min(self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim) <= 0:
+                raise ValueError(
+                    "latent attention needs qk_nope_head_dim, qk_rope_head_dim "
+                    "and v_head_dim"
+                )
+            if self.n_experts > 0 and not (
+                self.moe_hidden_dim > 0 and 0 <= self.n_dense_layers <= self.n_layers
+            ):
+                raise ValueError(
+                    "a routed latent-attention model needs moe_hidden_dim and "
+                    "0 <= n_dense_layers <= n_layers"
+                )
+
+    @property
+    def latent_attention(self) -> bool:
+        """Whether the block is models/deepseek.py's (latent attention, a
+        one-row-a-token cache) and not models/llama.py's."""
+        return self.kv_lora_rank > 0
+
     @property
     def head_size(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Width the rotary embedding turns: the whole head of a Llama block,
+        the ``qk_rope_head_dim`` part of a latent-attention head."""
+        return self.qk_rope_head_dim if self.latent_attention else self.head_size
 
     @property
     def kv_dim(self) -> int:
@@ -64,4 +110,5 @@ class LlamaConfig:
             n_experts=h.n_experts,
             n_active_experts=h.n_active_experts,
             qkv_bias=h.qkv_bias,
+            **{name: getattr(h, name) for name in LATENT_FIELDS},
         )

@@ -114,7 +114,7 @@ def read_m_tensors(path: str, header: ModelHeader) -> dict:
 def _rope_cache(config: LlamaConfig):
     return build_rope_cache(
         config.seq_len,
-        config.head_size,
+        config.rope_dim,
         config.rope_theta,
         config.rope_scaling_factor,
         config.rope_scaling_low_freq_factor,
@@ -134,6 +134,93 @@ def _cast_fn(dtype):
     return cast
 
 
+# the latent-attention walk (formats/model_file._latent_block_specs): tensor
+# name -> the key models/deepseek.latent_params takes it by. w1/w2/w3 are the
+# leading dense layers' (``dense_`` + key) where the tensor is no expert's
+_LATENT_NAME_MAP = {
+    "block_matmul_q": "wq",
+    "block_matmul_kv_a": "wkva",
+    "block_matmul_kv_b": "wkvb",
+    "block_matmul_wo": "wo",
+    "block_matmul_w1": "w1",
+    "block_matmul_w2": "w2",
+    "block_matmul_w3": "w3",
+    "block_matmul_shared_w1": "shared_w1",
+    "block_matmul_shared_w2": "shared_w2",
+    "block_matmul_shared_w3": "shared_w3",
+    "block_moe_gate": "moe_gate",
+    "block_moe_bias": "moe_bias",
+    "block_rms_norm_kv": "rms_kv",
+    "block_rms_norm_0": "rms_att",
+    "block_rms_norm_1": "rms_ffn",
+}
+_LATENT_VECTORS = {"moe_bias", "rms_kv", "rms_att", "rms_ffn"}
+
+
+def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
+                              device_put_fn=None, quantized: bool = False):
+    """A latent-attention ``.m`` (``header.kv_lora_rank``) as the tree
+    models/deepseek.py runs: tensors stacked by layer (the experts by routed
+    layer and expert), matmul weights ``[d_in, d_out]``. ``quantized`` keeps
+    Q40 matmul tensors packed (``PackedQ40``; the experts ``Q40Experts``);
+    the router, its bias and the norms are float32 either way."""
+    from .deepseek import latent_params
+
+    config = LlamaConfig.from_header(header)
+    put = device_put_fn or (lambda name, x: jnp.asarray(x))
+    cast = _cast_fn(dtype)
+    n_dense = config.n_dense_layers if config.n_experts else config.n_layers
+    groups: dict = {}  # key -> {(layer[, expert]): array or (packed, scales)}
+    top: dict = {}
+    for spec, raw in iter_model_tensors(path, header):
+        matmul = "matmul" in spec.name
+        if matmul and quantized and spec.float_type == FloatType.Q40:
+            x = pack_q40_from_blocks(raw, spec.shape)
+            if spec.name == "final_matmul_logits":
+                x = pad_packed_d_out(*x)
+        else:
+            x = _decode_tensor(raw, spec.float_type, spec.shape)
+            x = x.T if matmul or spec.name == "block_moe_gate" else x
+        if not spec.name.startswith("block_"):
+            top[spec.name] = x
+            continue
+        key = _LATENT_NAME_MAP[spec.name]
+        if key in _LATENT_VECTORS:
+            x = x.reshape(-1)
+        index = (spec.layer,)
+        if key in ("w1", "w2", "w3", "rms_ffn") and spec.expert < 0 and spec.layer < n_dense:
+            key = "dense_" + key
+        elif key not in ("wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv"):
+            index = (spec.layer - n_dense,) + ((spec.expert,) if spec.expert >= 0 else ())
+        groups.setdefault(key, {})[index] = x
+
+    def stack(name, entries: dict):
+        shape = tuple(max(i[d] for i in entries) + 1 for d in range(len(next(iter(entries)))))
+        picks = [entries[i] for i in np.ndindex(*shape)]
+        if isinstance(picks[0], tuple):  # Q40, kept packed
+            planes = [np.stack([p[j] for p in picks]) for j in (0, 1)]
+            return PackedQ40(
+                packed=put(name, planes[0].reshape(*shape, *planes[0].shape[1:])),
+                scales=put(name + ".scales", planes[1].reshape(*shape, *planes[1].shape[1:])),
+            )
+        x = np.stack(picks)
+        x = x.reshape(*shape, *x.shape[1:])
+        if name in _LATENT_VECTORS | {"moe_gate", "dense_rms_ffn"}:
+            return put(name, x).astype(jnp.float32)
+        return put(name, cast(x)).astype(dtype)
+
+    t = {name: stack(name, entries) for name, entries in groups.items()}
+    t["embedding"] = put("embedding", cast(top["embedding"])).astype(dtype)
+    t["rms_final"] = put("rms_final", top["final_rms_norm"].reshape(-1)).astype(jnp.float32)
+    wcls = top["final_matmul_logits"]
+    t["wcls"] = (
+        PackedQ40(packed=put("wcls", wcls[0]), scales=put("wcls.scales", wcls[1]))
+        if isinstance(wcls, tuple) else put("wcls", cast(wcls)).astype(dtype)
+    )
+    cos, sin = _rope_cache(config)
+    return config, latent_params(t, put("rope_cos", cos), put("rope_sin", sin), dtype, config)
+
+
 def load_params_from_m(
     path: str,
     header: ModelHeader,
@@ -147,6 +234,8 @@ def load_params_from_m(
     ``device_put_fn(name, np_array) -> jax.Array`` lets callers control
     placement/sharding; defaults to plain jnp.asarray.
     """
+    if header.kv_lora_rank:
+        return load_latent_params_from_m(path, header, dtype, device_put_fn)
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
 
@@ -217,6 +306,8 @@ def load_params_from_m_quantized(
     the reference running Q40 weights at rest (src/nn/nn-cpu-ops.cpp:222-440).
     Non-Q40 matmul tensors (f32/f16 models) are loaded dense; embedding and
     norms are always dense (gather/elementwise ops want plain arrays)."""
+    if header.kv_lora_rank:
+        return load_latent_params_from_m(path, header, dtype, device_put_fn, quantized=True)
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
     L, E = config.n_layers, config.n_experts

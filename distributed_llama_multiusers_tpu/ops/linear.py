@@ -50,6 +50,12 @@ def set_pallas_w_dtype(dtype) -> None:
     _pallas_w_dtype = dtype
 
 
+def pallas_w_dtype_kw() -> dict:
+    """The ``w_dtype`` keyword a Pallas Q40 call takes: empty for the
+    kernel's own default."""
+    return {} if _pallas_w_dtype is None else {"w_dtype": _pallas_w_dtype}
+
+
 @lru_cache(maxsize=1)
 def _pallas_q40_matmul():
     """The Pallas kernel entry on a TPU; None on any other platform and

@@ -1,0 +1,228 @@
+"""Grouped Q40 matmul for a routed FFN: every row by ITS expert's weights,
+the slabs read in place by layer index and expert id.
+
+A routed layer multiplies each token's row by the few experts its router
+chose. The weights of all experts of all routed layers are one stack
+``[L, E, d_in/2, d_out]`` a matrix (``quants.packed.Q40Experts``). Slicing a
+layer out for a kernel call copies every expert's planes, chosen or not (a
+Pallas call gets its operands materialised: PERF.md section 6, PR 30), and a
+loop over experts reads them all. Here the (token, expert) assignments are
+sorted by expert and laid out in tiles of ``tm`` rows, each group starting on
+a tile (``route_plan``); the grid is one axis over tiles, and the weight
+blocks' index maps read the layer and the tile's expert id from scalar
+prefetch: block ``(l, e, 0, 0)`` of the stack, a whole slab. Tiles of one
+expert follow each other, and the pipeline issues no fetch for an index that
+did not change, so a slab is read once whatever its row count, and a slab no
+row chose is never addressed. Tiles past the last used one repeat its expert
+(nothing is fetched) and compute nothing.
+
+Shapes are static: ``n_tiles`` is the most tiles any routing of ``n_assign``
+assignments over ``n_experts`` experts can need, so no token is ever dropped.
+A parked row (an idle lane) is given the expert id ``n_experts``: it sorts
+past every group, takes no tile row and fetches nothing.
+
+The dequant chain is ops/pallas_q40.py's ``v4`` (two dots on the nibble
+halves, the -8 offset folded into a correction dot against per-block sums of
+``x``); the per-block sums go in untransposed, ``[tm, n_blk]``, since a tile of
+8 rows cannot be a lane dimension.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..quants.packed import Q40Experts, unpack_q40_slabs
+from .pallas_q40 import (
+    VMEM_LIMIT_BYTES,
+    _f16_bits_to_f32,
+    _plan_blocks,
+    _resolve_w_dtype,
+    _sub_tiles,
+)
+
+ROWS_NARROW = 8  # rows a tile where an expert seldom has more (decode, verify)
+ROWS_WIDE = 128  # and where it has many (prefill chunks)
+
+
+def tile_rows(n_assign: int, n_experts: int) -> int:
+    """Rows a tile, chosen by the assignments at trace time: a slab is
+    dequantized once a tile, so tiles are as tall as an expert's group is
+    likely to be."""
+    return ROWS_NARROW if n_assign <= ROWS_NARROW * n_experts else ROWS_WIDE
+
+
+def max_tiles(n_assign: int, n_experts: int, tm: int) -> int:
+    """The most tiles a routing can need: every group ends in at most one
+    partial tile, and a tile holds at least one assignment."""
+    return min(n_assign, n_assign // tm + min(n_experts, n_assign))
+
+
+class RoutePlan(NamedTuple):
+    """Where every (token, expert) assignment of a step goes. ``P`` padded
+    rows in ``n_tiles`` tiles of ``tm`` (``P = n_tiles * tm``)."""
+
+    tile_expert: jnp.ndarray  # [n_tiles] int32: the expert a tile multiplies by
+    n_used: jnp.ndarray  # [] int32: tiles that hold rows; the rest compute nothing
+    src: jnp.ndarray  # [P] int32: the token row a padded row takes; n for none
+    pos: jnp.ndarray  # [n, k] int32: the padded row of each assignment; P if parked
+    slabs: jnp.ndarray  # [] int32: distinct experts chosen (slabs a matrix read)
+    assignments: jnp.ndarray  # [] int32: live rows x k
+
+
+def route_plan(topi: jnp.ndarray, live: jnp.ndarray, n_experts: int) -> RoutePlan:
+    """Sort a step's assignments by expert. ``topi``: ``[n, k]`` expert ids;
+    ``live``: ``[n]`` bool, False for a parked row, which routes nowhere."""
+    n, k = topi.shape
+    a = n * k
+    tm = tile_rows(a, n_experts)
+    n_tiles = max_tiles(a, n_experts, tm)
+    p = n_tiles * tm
+    flat = jnp.where(live[:, None], topi, n_experts).reshape(a).astype(jnp.int32)
+    sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[flat].add(1)
+    g = sizes[:n_experts]
+    tiles_e = (g + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles_e)
+    n_used = tile_end[-1]
+    # rank of an assignment inside its group: stable sort by expert
+    order = jnp.argsort(flat, stable=True)
+    sorted_e = flat[order]
+    group_start = jnp.cumsum(sizes) - sizes  # [E + 1], the parked group last
+    rank = jnp.arange(a, dtype=jnp.int32) - group_start[sorted_e]
+    tile_start = jnp.concatenate([tile_end - tiles_e, jnp.zeros((1,), jnp.int32)])
+    pos_sorted = jnp.where(sorted_e < n_experts, tile_start[sorted_e] * tm + rank, p)
+    pos = jnp.zeros((a,), jnp.int32).at[order].set(pos_sorted)
+    token = jnp.arange(a, dtype=jnp.int32) // k
+    src = jnp.full((p + 1,), n, jnp.int32).at[pos].set(token)[:p]
+    # tile i belongs to the first expert whose tiles end past i; tiles past
+    # the last used one repeat its expert, so their index does not change
+    tile_idx = jnp.arange(n_tiles, dtype=jnp.int32)
+    owner = jnp.searchsorted(tile_end, tile_idx, side="right").astype(jnp.int32)
+    last = jnp.max(jnp.where(g > 0, jnp.arange(n_experts, dtype=jnp.int32), 0))
+    tile_expert = jnp.where(tile_idx < n_used, jnp.minimum(owner, n_experts - 1), last)
+    return RoutePlan(
+        tile_expert=tile_expert, n_used=n_used, src=src, pos=pos.reshape(n, k),
+        slabs=jnp.sum(g > 0).astype(jnp.int32),
+        assignments=jnp.sum(live).astype(jnp.int32) * k,
+    )
+
+
+def grouped_supports(w) -> bool:
+    """Whether the kernel takes this stack: ``Q40Experts`` whose slab is one
+    block (the whole reduction and the whole output width), so that a tile
+    is one grid step and a slab one fetch."""
+    if not isinstance(w, Q40Experts) or w.packed.ndim != 4:
+        return False
+    plan = _plan_blocks(w.d_in, w.d_out)
+    return plan is not None and plan == (w.d_out, w.d_in // 2)
+
+
+def _grouped_kernel(meta_ref, x_lo_ref, x_hi_ref, bsum_ref, packed_ref,
+                    scales_ref, out_ref, *, w_dtype, sub_tiles):
+    """One tile: ``[tm, d_in]`` rows (as nibble halves) by one expert's slab.
+    meta: ``[layer, n_used, tile_expert...]``; layer and expert are spent in
+    the index maps."""
+    rows = packed_ref.shape[0]
+    n_blk = rows // 16
+
+    @pl.when(pl.program_id(0) < meta_ref[1])
+    def _():
+        x_lo = x_lo_ref[...].astype(w_dtype)
+        x_hi = x_hi_ref[...].astype(w_dtype)
+        bsum = bsum_ref[...]
+        off = 0
+        for t in sub_tiles:
+            s = _f16_bits_to_f32(scales_ref[:, off:off + t])  # [n_blk, t]
+            p = packed_ref[:, off:off + t].astype(jnp.int32)
+            s3 = s[:, None, :]
+            w_lo = ((p & 0x0F).astype(jnp.float32).reshape(n_blk, 16, t) * s3)
+            w_hi = ((p >> 4).astype(jnp.float32).reshape(n_blk, 16, t) * s3)
+            w_lo = w_lo.reshape(rows, t).astype(w_dtype)
+            w_hi = w_hi.reshape(rows, t).astype(w_dtype)
+            # folded -8 offset, as in ops/pallas_q40.py's slab kernel
+            corr = jnp.dot(bsum, s, preferred_element_type=jnp.float32)
+            out_ref[:, off:off + t] = (
+                jnp.dot(x_lo, w_lo, preferred_element_type=jnp.float32)
+                + jnp.dot(x_hi, w_hi, preferred_element_type=jnp.float32)
+                - 8.0 * corr
+            )
+            off += t
+
+
+def tile_block_index(i, meta):
+    """The weight block of tile ``i``: ``(layer, expert, 0, 0)`` of the stack.
+    The tests read which slabs a plan addresses through this."""
+    return (meta[0], meta[2 + i], 0, 0)
+
+
+@partial(jax.jit, static_argnames=("interpret", "w_dtype"))
+def _grouped_impl(x_rows, w: Q40Experts, layer, tile_expert, n_used,
+                  interpret, w_dtype):
+    p_rows, d_in = x_rows.shape
+    n_tiles = tile_expert.shape[0]
+    tm = p_rows // n_tiles
+    half, n_blk, d_out = d_in // 2, d_in // 32, w.d_out
+    xb = x_rows.astype(jnp.float32).reshape(p_rows, n_blk, 2, 16)
+    x_lo = xb[:, :, 0, :].reshape(p_rows, half)
+    x_hi = xb[:, :, 1, :].reshape(p_rows, half)
+    bsum = xb.sum(axis=(2, 3))  # [P, n_blk], exact f32
+    meta = jnp.concatenate([
+        jnp.stack([jnp.asarray(layer, jnp.int32), jnp.asarray(n_used, jnp.int32)]),
+        tile_expert.astype(jnp.int32),
+    ])
+    row_spec = pl.BlockSpec((tm, half), lambda i, m: (i, 0))
+    return pl.pallas_call(
+        partial(_grouped_kernel, w_dtype=w_dtype, sub_tiles=_sub_tiles(d_out)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_tiles,),
+            in_specs=[
+                row_spec,
+                row_spec,
+                pl.BlockSpec((tm, n_blk), lambda i, m: (i, 0)),
+                # the tile's expert's slab, addressed inside the stack; the
+                # two leading block dimensions are squeezed
+                pl.BlockSpec((None, None, half, d_out), tile_block_index),
+                pl.BlockSpec((None, None, n_blk, d_out), tile_block_index),
+            ],
+            out_specs=pl.BlockSpec((tm, d_out), lambda i, m: (i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((p_rows, d_out), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name="q40_grouped_matmul",
+    )(meta, x_lo, x_hi, bsum, w.packed, w.scale_bits)
+
+
+def q40_grouped_pallas(x_rows, w: Q40Experts, layer, plan: RoutePlan,
+                       interpret: bool = False, w_dtype=None) -> jnp.ndarray:
+    """``[P, d_in]`` rows in the plan's padded order -> ``[P, d_out]`` f32,
+    row ``r`` by the slab ``(layer, plan.tile_expert[r // tm])``. Rows of
+    unused tiles are not written."""
+    return _grouped_impl(
+        x_rows, w, layer, plan.tile_expert, plan.n_used,
+        interpret, _resolve_w_dtype(w_dtype, interpret),
+    )
+
+
+def grouped_matmul_xla(x_rows, w, layer, plan: RoutePlan) -> jnp.ndarray:
+    """The same product without the kernel (the CPU; what the kernel is held
+    to): the slabs the tiles name are gathered and dequantized, no others.
+    ``w``: ``Q40Experts`` and a layer index, or one layer's dense
+    ``[E, d_in, d_out]`` (``layer`` unused)."""
+    n_tiles = plan.tile_expert.shape[0]
+    xt = x_rows.reshape(n_tiles, -1, x_rows.shape[-1])
+    if isinstance(w, Q40Experts):
+        slabs = unpack_q40_slabs(w, layer, plan.tile_expert, jnp.float32)
+    else:
+        slabs = w[plan.tile_expert].astype(jnp.float32)
+    y = jnp.einsum("ptd,pdo->pto", xt.astype(jnp.float32), slabs)
+    return y.reshape(x_rows.shape[0], -1)

@@ -220,3 +220,47 @@ def q40_matmul_xla(x: jnp.ndarray, w: PackedQ40, compute_dtype=None) -> jnp.ndar
     dtype = compute_dtype or x.dtype
     wd = unpack_q40(w, dtype)
     return jnp.matmul(x, wd, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+class Q40Experts(NamedTuple):
+    """The Q40 weights of every expert of every routed layer, one stack
+    ``[L, E, ...]`` a matrix, as the grouped kernel reads them
+    (ops/pallas_q40_grouped.py): by layer index and expert id, in place. The
+    scales are held as their float16 BIT PATTERNS. Mosaic has no f16 type, and
+    for XLA:TPU f16 -> s16 is a pass over the data: ``PackedQ40`` pays it on
+    one layer's scale plane a call, which here would be every expert's,
+    chosen or not. ``from_packed`` pays it once, at load."""
+
+    packed: jnp.ndarray  # uint8 [L, E, d_in//2, d_out]
+    scale_bits: jnp.ndarray  # int16 [L, E, d_in//32, d_out]: float16 bits
+
+    @property
+    def d_in(self) -> int:
+        return self.packed.shape[-2] * 2
+
+    @property
+    def d_out(self) -> int:
+        return self.packed.shape[-1]
+
+    @property
+    def n_experts(self) -> int:
+        return self.packed.shape[1]
+
+    @staticmethod
+    def from_packed(w: PackedQ40) -> "Q40Experts":
+        import jax
+
+        if w.packed.ndim != 4:
+            raise ValueError(f"expected [L, E, d_in//2, d_out] planes, got {w.packed.shape}")
+        return Q40Experts(w.packed, jax.lax.bitcast_convert_type(w.scales, jnp.int16))
+
+
+def unpack_q40_slabs(w: Q40Experts, layer, experts, dtype=jnp.float32) -> jnp.ndarray:
+    """Dequantize the slabs ``(layer, experts[i])`` to ``[n, d_in, d_out]``:
+    the XLA form of the grouped kernel's read (the CPU, and what the kernel
+    is tested against). Only the slabs named are touched."""
+    import jax
+
+    packed = w.packed[layer, experts]  # [n, d_in//2, d_out]
+    scales = jax.lax.bitcast_convert_type(w.scale_bits[layer, experts], jnp.float16)
+    return unpack_q40(PackedQ40(packed, scales), dtype)

@@ -46,8 +46,8 @@ from ..models.llama import (
     decode_attention_engages,
     init_kv_cache,
     init_paged_kv_cache,
-    llama_forward,
 )
+from ..models.deepseek import forward_counted, init_latent_cache
 from ..ops import pallas_attention
 from ..telemetry.logs import log_event
 from ..telemetry.names import SCOPE_CARRY, SCOPE_HEAD, SCOPE_SAMPLER
@@ -162,6 +162,17 @@ class EngineStats:
     # traffic's (short lanes) and how much the kernel's
     attn_kv_rows_read: int = 0
     attn_kv_rows_whole: int = 0
+    # a routed FFN's reads of its expert stacks (models/deepseek.py), summed
+    # over the consumed decode steps (fused steps' decode halves included).
+    # Counted ON THE DEVICE from the expert ids and brought back in the
+    # step's packed token readback, so no sync is added: `read` is distinct
+    # (layer, expert) slabs a step fetched of ONE expert matrix (each of w1,
+    # w3, w2 reads that many), `whole` routed layers x experts a step (what a
+    # sweep of every expert reads), `assignments` live rows x experts a token
+    # x routed layers. All 0 for a model without routed layers
+    moe_slabs_read: int = 0
+    moe_slabs_whole: int = 0
+    moe_assignments: int = 0
     # compile stability (analysis/jitcheck.py, ISSUE 15): XLA backend
     # compiles observed AFTER warmup_engine armed the recompile witness —
     # the machine-checked form of "one compiled program per (family,
@@ -200,6 +211,7 @@ class EngineStats:
             "sync_bytes_total", "worker_restarts", "worker_replay_errors",
             "grammar_lanes", "grammar_masked_steps",
             "attn_kv_rows_read", "attn_kv_rows_whole",
+            "moe_slabs_read", "moe_slabs_whole", "moe_assignments",
             "jit_compiles_after_warmup",
         ),
     }
@@ -237,6 +249,7 @@ class EngineStats:
             self.worker_restarts = self.worker_replay_errors = 0
             self.grammar_lanes = self.grammar_masked_steps = 0
             self.attn_kv_rows_read = self.attn_kv_rows_whole = 0
+            self.moe_slabs_read = self.moe_slabs_whole = self.moe_assignments = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
             # compile stability since warmup, and a window reset hiding a
@@ -342,6 +355,28 @@ class InferenceEngine:
                 jnp.bfloat16 if jax.devices()[0].platform == "tpu" else jnp.float32
             )
         self.cache_dtype = cache_dtype
+        if config.latent_attention:
+            # models/deepseek.py: one latent row a token, on one device. What
+            # is framed as a K/V pair of heads, or partitions by head, would
+            # run wrongly: refused by name, here, not in the middle of a step
+            refused = [
+                (paged_kv, "the paged KV pool (--paged-kv on), and with it "
+                           "prefix page sharing, KV-page transfer and a "
+                           "prefill/decode role: a page is framed as a K/V "
+                           "pair of heads"),
+                (kv_host_bytes > 0, "the host KV tier (--kv-host-bytes): it "
+                                    "swaps the pool's pages"),
+                (mesh is not None, "a mesh (--workers): the block's latent "
+                                   "cache and expert stacks have no sharding"),
+            ]
+            for hit, what in refused:
+                if hit:
+                    raise ValueError(
+                        "a latent-attention model (kv_lora_rank "
+                        f"{config.kv_lora_rank}) keeps one latent cache row a "
+                        f"token on one device and does not serve {what}"
+                    )
+        init_contiguous = init_latent_cache if config.latent_attention else init_kv_cache
         if paged_kv:
             if mesh is not None and (
                 dict(mesh.shape).get("dp", 1) > 1
@@ -419,8 +454,14 @@ class InferenceEngine:
             )()
         else:
             self.kvpool = None
-            self.cache = init_kv_cache(config, n_lanes, dtype=cache_dtype)
+            self.cache = init_contiguous(config, n_lanes, dtype=cache_dtype)
         self.stats = EngineStats()
+        # routed layers x experts: what a decode step adds to moe_slabs_whole
+        # (0: no routed layers, and no counts ride the token readback)
+        self.moe_slabs_per_step = (
+            (config.n_layers - config.n_dense_layers) * config.n_experts
+            if config.latent_attention else 0
+        )
         # cache rows a block of the in-place decode attention fetches; None
         # where decode steps read whole planes (the scheduler's
         # attn_kv_rows_* counters ask)
@@ -478,6 +519,24 @@ class InferenceEngine:
             self._g_sharding = None
 
         cfg = config
+        # the configuration's block (models/llama.py, or models/deepseek.py
+        # for latent attention): one forward for every step family. The
+        # decode steps of the pipelined chain also take its counts (a routed
+        # FFN's slab reads; None for a Llama block, whose programs are then
+        # what they always were)
+        forward_c = forward_counted(cfg)
+
+        def forward(*a, **kw):
+            return forward_c(*a, **kw)[:2]
+
+        def _token_rows(greedy, sampled, counts):
+            # [2, n]: the step's packed token readback; a routed model's
+            # counts ride it as two more rows (each count in every column)
+            rows = [greedy, sampled]
+            if counts is not None:
+                rows += [jnp.broadcast_to(c, greedy.shape) for c in counts]
+            return jnp.stack(rows)
+
         q80 = emulate_q80_activations
         # Q80-compressed wo/w2 sync (the reference's default transport);
         # meaningful on DCN-spanning meshes where payload bytes matter
@@ -646,7 +705,7 @@ class InferenceEngine:
         def _decode_core(params, cache, tokens, positions, temps, topps,
                          seeds, gtab, gs):
             # tokens/positions: [n_lanes] -> [n_lanes, 1]
-            logits, cache = llama_forward(
+            logits, cache, counts = forward_c(
                 cfg, params, tokens[:, None], positions[:, None], cache,
                 emulate_q80_activations=q80, mesh=sp_mesh, q80_sync=q80s,
             )
@@ -663,12 +722,12 @@ class InferenceEngine:
             with jax.named_scope(SCOPE_CARRY):
                 chosen = jnp.where(temps == 0.0, greedy, sampled)
                 new_g = _g_next(gtab, gs, chosen)
-            return step, greedy, sampled, new_g, cache
+            return step, greedy, sampled, new_g, cache, counts
 
         @partial(jax.jit, donate_argnums=(1,))
         def _decode(params, cache, tokens, positions, temps, topps, seeds,
                     gtab, gs):
-            step, greedy, sampled, _, cache = _decode_core(
+            step, greedy, sampled, _, cache, _ = _decode_core(
                 params, cache, tokens, positions, temps, topps, seeds,
                 gtab, gs,
             )
@@ -688,7 +747,7 @@ class InferenceEngine:
             # alive (the row is still computed for argmax, but never
             # materialized as a program output, so it pins no HBM and — in
             # the pipelined path — can never force a sync)
-            _, greedy, sampled, _, cache = _decode_core(
+            _, greedy, sampled, _, cache, _ = _decode_core(
                 params, cache, tokens, positions, temps, topps, seeds,
                 gtab, gs,
             )
@@ -719,7 +778,7 @@ class InferenceEngine:
             # state rides it identically.
             pos = _eff_positions(carry_pos, positions)
             gs = _eff_g(carry_g, gs_host)
-            _, greedy, sampled, new_g, cache = _decode_core(
+            _, greedy, sampled, new_g, cache, counts = _decode_core(
                 params, cache, tokens, pos, temps, topps, seeds, gtab, gs
             )
             with jax.named_scope(SCOPE_CARRY):
@@ -729,7 +788,7 @@ class InferenceEngine:
                     rep_tokens(nxt),
                     rep_tokens(new_pos),
                     rep_tokens(new_g),
-                    rep_tokens(jnp.stack([greedy, sampled])),
+                    rep_tokens(_token_rows(greedy, sampled, counts)),
                     cache,
                 )
 
@@ -792,7 +851,7 @@ class InferenceEngine:
                 full = jnp.concatenate([feed[:, None], drafts[:, 1:]], axis=1)
                 k_spec = full.shape[1]  # SPEC_DRAFT + 1
                 pos2d = pos[:, None] + jnp.arange(k_spec, dtype=jnp.int32)
-            logits, cache = llama_forward(
+            logits, cache = forward(
                 cfg, params, full, pos2d, cache,
                 emulate_q80_activations=q80, mesh=sp_mesh, q80_sync=q80s,
             )
@@ -918,7 +977,7 @@ class InferenceEngine:
                 full = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [n, K]
                 k_spec = full.shape[1]
                 pos2d = positions[:, None] + jnp.arange(k_spec, dtype=jnp.int32)
-            logits, cache = llama_forward(
+            logits, cache = forward(
                 cfg, params, full, pos2d, cache,
                 emulate_q80_activations=q80, mesh=sp_mesh, q80_sync=q80s,
             )
@@ -972,7 +1031,7 @@ class InferenceEngine:
                 # its mapped blocks hit sentinel entries and drop)
                 with jax.named_scope(SCOPE_CARRY):
                     row = jax.lax.dynamic_slice_in_dim(cache.table, lane, 1, axis=0)
-                logits, lane_cache = llama_forward(
+                logits, lane_cache = forward(
                     cfg,
                     params,
                     tokens[None, :],
@@ -991,7 +1050,7 @@ class InferenceEngine:
                 with jax.named_scope(SCOPE_CARRY):
                     k_lane = jax.lax.dynamic_slice_in_dim(cache.k, lane, 1, axis=1)
                     v_lane = jax.lax.dynamic_slice_in_dim(cache.v, lane, 1, axis=1)
-                logits, lane_cache = llama_forward(
+                logits, lane_cache = forward(
                     cfg,
                     params,
                     tokens[None, :],
@@ -1073,7 +1132,7 @@ class InferenceEngine:
             )
             pos = _eff_positions(carry_pos, positions)
             gs = _eff_g(carry_g, gs_host)
-            _, greedy, sampled, new_g, cache = _decode_core(
+            _, greedy, sampled, new_g, cache, counts = _decode_core(
                 params, cache, feed, pos, temps, topps, seeds, gtab, gs
             )
             with jax.named_scope(SCOPE_CARRY):
@@ -1090,10 +1149,12 @@ class InferenceEngine:
                 # its grammar carry joins the same way: start state advanced
                 # by the boundary token (junk mid-prompt; final chunk wins)
                 new_g = new_g.at[p_lane].set(_g_next1(gtab, p_g, p_first))
+                # the boundary column counts nothing
+                p_counts = counts and tuple(jnp.zeros_like(c) for c in counts)
                 packed = jnp.concatenate(
                     [
-                        jnp.stack([greedy, sampled]),
-                        jnp.stack([p_greedy, p_sampled])[:, None],
+                        _token_rows(greedy, sampled, counts),
+                        _token_rows(p_greedy, p_sampled, p_counts)[:, None],
                     ],
                     axis=1,
                 )
@@ -1197,7 +1258,7 @@ class InferenceEngine:
                 state threads the scan carry like the position does."""
                 def body(carry, _):
                     tok, pos, g, cache = carry
-                    logits, cache = llama_forward(
+                    logits, cache = forward(
                         cfg, params, tok[:, None], pos[:, None], cache,
                         emulate_q80_activations=q80, mesh=sp_mesh,
                         q80_sync=q80s,
@@ -1237,6 +1298,31 @@ class InferenceEngine:
         self._decode_exec = None
 
     # -- grammar-constrained decoding (grammar/) ----------------------------
+
+    def path_facts(self) -> dict:
+        """Which attention and which expert path this engine's decode steps
+        run, by the predicates the forward itself asks: said once at
+        start-up (the ``runtime_device`` line, ``/stats``), so that a
+        fallback is never silent."""
+        from ..ops.linear import pallas_kernel_active
+        from ..ops.pallas_q40_grouped import grouped_supports
+
+        cfg = self.config
+        if self.decode_attention_block is not None:
+            attention = "pallas_in_place"
+        elif cfg.latent_attention:
+            attention = "xla_dense_latent_absorbed"
+        else:
+            attention = "xla_dense"
+        if cfg.n_experts == 0:
+            experts = None
+        elif not cfg.latent_attention:
+            experts = "mixtral_moe_ffn"
+        elif grouped_supports(self.params.routed.w1) and pallas_kernel_active():
+            experts = "q40_grouped_kernel"
+        else:
+            experts = "xla_gathered_slabs"
+        return {"attention_path": attention, "expert_path": experts}
 
     # the scheduler gates response_format requests on this; pod roots
     # broadcast attach/detach as OP_GRAMMAR packets (RootControlEngine)
@@ -1796,6 +1882,13 @@ class InferenceEngine:
             self.stats.overlap_s += max(0.0, t0 - dispatched_at)
         if kind == "spec":
             return toks_np[:, :-1], toks_np[:, -1]
+        if toks_np.shape[0] > 2:
+            # a routed model's decode step: its slab counts, made on the
+            # device, came with the tokens (``_token_rows``)
+            with self.stats.lock:
+                self.stats.moe_slabs_read += int(toks_np[2, 0])
+                self.stats.moe_assignments += int(toks_np[3, 0])
+                self.stats.moe_slabs_whole += self.moe_slabs_per_step
         return toks_np[0], toks_np[1]
 
     def pipeline_flush(self, count: bool = True) -> int:

@@ -416,6 +416,13 @@ class ApiServer:
             # every lane's whole plane
             "attn_kv_rows_read": stats["attn_kv_rows_read"],
             "attn_kv_rows_whole": stats["attn_kv_rows_whole"],
+            # a routed FFN's reads of its expert stacks over the decode
+            # steps: distinct (layer, expert) slabs fetched, what a sweep of
+            # every expert fetches, and (row, expert) pairs routed; all 0
+            # for a model without routed layers
+            "moe_slabs_read": stats["moe_slabs_read"],
+            "moe_slabs_whole": stats["moe_slabs_whole"],
+            "moe_assignments": stats["moe_assignments"],
             # failure containment (multihost.worker_serve): supervised
             # restarts + classified protocol errors on THIS process —
             # non-zero only on pod processes that actually restarted

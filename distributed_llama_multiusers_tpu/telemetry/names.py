@@ -40,6 +40,16 @@ SCOPE_HEAD = "dl.head"            # final norm, wcls
 SCOPE_SAMPLER = "dl.sampler"      # grammar mask, argmax, full-vocab nucleus sample
 SCOPE_CARRY = "dl.carry"          # token/position/grammar carry, admitted-lane splice, packs
 
+# the latent-attention block with a routed FFN (models/deepseek.py) nests
+# these under the scopes above, in every step family; an operation belongs to
+# the deepest, so the parents keep what is left (dl.qkv: the norm and the
+# query's matmul and rotation; dl.ffn: the norm and the residual add)
+SCOPE_KV_LATENT = "dl.kv_latent"  # under dl.qkv: down projection, its norm, the key's rotation
+SCOPE_ROUTER = "dl.router"        # under dl.ffn: router logits, scores, top-k, weights
+SCOPE_EXPERTS = "dl.experts"      # under dl.ffn: sort by expert, grouped kernel, combine
+SCOPE_SHARED_EXPERT = "dl.shared_expert"  # under dl.ffn: the always-on gated FFN
+LATENT_BLOCK_SCOPES = (SCOPE_KV_LATENT, SCOPE_ROUTER, SCOPE_EXPERTS, SCOPE_SHARED_EXPERT)
+
 # scopes inside the layer scan, in program order
 LAYER_SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_ATTN_OUT, SCOPE_FFN)
 # every scope whose time is the model's own arithmetic (no children)

@@ -1,0 +1,41 @@
+"""A toy latent-attention model with a routed FFN for the tier-1 tests: the
+benchmark's deepseek_v3 family (its generator and its plain reference) at the
+rehearsal's size, loaded by path as ``tests/test_bench_family_seam.py`` loads
+the seam's cases."""
+
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmarks")
+
+
+def load():
+    """(cfg, family, correct): the toy configuration file's keys, the family
+    module, and the harness's comparison."""
+    path = list(sys.path)
+    sys.path[:0] = [p for p in (BENCH_DIR, ROOT) if p not in sys.path]
+    try:
+        from harness import cells, correct
+
+        with open(os.path.join(BENCH_DIR, "tests", "rehearsal", "configs", "tiny_latent.json")) as f:
+            cfg = json.load(f)
+        return cfg, cells.load_family(cfg), correct
+    finally:
+        sys.path[:] = path
+
+
+def engine(family, cfg, seed=11, dtype=None, lanes=8, **kw):
+    """(engine, tensors) as the benchmark builds them, at ``dtype``
+    (activations and cache; float32 by default)."""
+    import jax.numpy as jnp
+
+    from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
+
+    dtype = dtype or jnp.float32
+    config = family.program_config(cfg)
+    tensors = family.device_weights(config, seed, dtype)
+    eng = InferenceEngine(config, family.assemble_params(config, tensors), n_lanes=lanes,
+                          cache_dtype=dtype, **kw)
+    return eng, tensors

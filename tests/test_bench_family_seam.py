@@ -35,3 +35,26 @@ finally:
 globals().update(
     {name: obj for name, obj in vars(_cases).items() if name.startswith("test_")}
 )
+
+
+def test_both_configurations_load_the_llama_family_by_default():
+    """The case of that name, over the configurations it was written for. It
+    walks every entry of BENCHMARK.json and asserts that none names a family;
+    since PR 33 one does (``kanana-2-30b-a3b``: ``deepseek_v3``), and a file
+    under ``benchmarks/`` is a benchmark PR's to edit (PERF.md, open
+    questions). Held here: a file that names no family loads the Llama one,
+    and every entry loads a module that exports the seam."""
+    cells = _cases.cells
+    bench = cells.load_benchmark()
+    default = 0
+    for entry in bench["configs"]:
+        cfg = cells.load_config_file(bench, entry["name"])
+        family = cells.load_family(cfg)
+        assert family.__file__ == os.path.join(
+            BENCH_DIR, "families", cfg.get("family", "llama") + ".py")
+        assert all(callable(getattr(family, f)) for f in _cases.FAMILY_EXPORTS)
+        config = family.program_config(cfg)
+        assert (config.vocab_size, config.seq_len) == (
+            cfg["vocab_size"], cfg["max_position_embeddings"])
+        default += "family" not in cfg
+    assert default == 2  # mistral-7b-v0.3 and qwen2.5-7b, as they were

@@ -351,6 +351,100 @@ def test_decode_forward_reads_no_kv_plane_for_v5e(
         assert len(reads) >= 2, made  # K's plane and V's
 
 
+# -- the latent-attention block with a routed FFN (models/deepseek.py) --------
+
+# one expert's w1 / w3 and w2 at the benchmark's routed width, 128 experts
+EXPERT_SHAPES = [(2048, 768), (768, 2048)]
+
+
+@pytest.mark.parametrize("rows", [32, 1024], ids=["decode", "prefill1024"])
+@pytest.mark.parametrize("d_in,d_out", EXPERT_SHAPES)
+def test_grouped_expert_kernel_compiles_for_v5e(v5e, d_in, d_out, rows):
+    """The grouped Q40 kernel at 6 experts a token of 128, at decode width (8
+    rows a tile) and at the widest prefill bucket (128 rows a tile)."""
+    from distributed_llama_multiusers_tpu.ops import pallas_q40_grouped as pg
+    from distributed_llama_multiusers_tpu.quants.packed import Q40Experts
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    L, E, a = 3, 128, rows * 6
+    tm = pg.tile_rows(a, E)
+    n_tiles = pg.max_tiles(a, E, tm)
+    w = Q40Experts(sds((L, E, d_in // 2, d_out), jnp.uint8),
+                   sds((L, E, d_in // 32, d_out), jnp.int16))
+    assert pg.grouped_supports(w)
+    hlo = pg._grouped_impl.lower(
+        sds((n_tiles * tm, d_in), jnp.bfloat16), w, sds((), jnp.int32),
+        sds((n_tiles,), jnp.int32), sds((), jnp.int32),
+        interpret=False, w_dtype=jnp.bfloat16,
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    # the stack goes in whole and is read by id: no slab, layer or stack of it
+    # is the result of a slice, a copy or a fusion
+    assert f"= u8[{E},{d_in // 2},{d_out}]" not in hlo
+    assert f"= u8[{L},{E},{d_in // 2},{d_out}]" not in hlo.split("ENTRY")[0]
+
+
+def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, monkeypatch):
+    """Three layers (one dense, two routed) of the benchmark's latent block at
+    its published widths, one row a lane, the cache donated: the kernels are
+    there (wq, wkva, wo and the dense FFN or the grouped and shared experts,
+    the head), the latent stack is the result of its in-place scatters alone
+    (no copy, no relayout: a size-one head axis cost four whole-stack copies,
+    PR 33), and no expert plane leaves its stack."""
+    import re
+
+    from distributed_llama_multiusers_tpu.models import deepseek
+    from distributed_llama_multiusers_tpu.models.config import LlamaConfig
+    from distributed_llama_multiusers_tpu.models.llama import KVCache
+    from distributed_llama_multiusers_tpu.quants.packed import Q40Experts
+
+    monkeypatch.setattr(linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas)
+    L, Lm, E, d, lanes, seq, vocab = 3, 2, 128, 2048, 32, 512, 8192
+    cfg = LlamaConfig(
+        dim=d, hidden_dim=6144, n_layers=L, n_heads=32, n_kv_heads=32, vocab_size=vocab,
+        seq_len=seq, norm_epsilon=1e-6, n_experts=E, n_active_experts=6, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, moe_hidden_dim=768,
+        shared_hidden_dim=1536, n_dense_layers=1, moe_score_func=1, moe_select_bias=1,
+        moe_routed_scale=2.448)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    q40 = lambda d_in, d_out, lead: PackedQ40(
+        packed=sds(lead + (d_in // 2, d_out), jnp.uint8),
+        scales=sds(lead + (d_in // 32, d_out), jnp.float16))
+    experts = lambda d_in, d_out: Q40Experts(
+        sds((Lm, E, d_in // 2, d_out), jnp.uint8), sds((Lm, E, d_in // 32, d_out), jnp.int16))
+    params = deepseek.DeepseekParams(
+        embedding=sds((vocab, d), jnp.bfloat16),
+        attn=deepseek.LatentAttnParams(
+            wq=q40(d, 32 * 192, (L,)), wkva=q40(d, 576, (L,)),
+            wuk=sds((L, 32, 128, 512), jnp.bfloat16), wuv=sds((L, 32, 512, 128), jnp.bfloat16),
+            wo=q40(32 * 128, d, (L,)),
+            rms_att=sds((L, d), jnp.float32), rms_kv=sds((L, 512), jnp.float32)),
+        dense=deepseek.DenseFfnParams(
+            w1=q40(d, 6144, (1,)), w2=q40(6144, d, (1,)), w3=q40(d, 6144, (1,)),
+            rms_ffn=sds((1, d), jnp.float32)),
+        routed=deepseek.RoutedFfnParams(
+            gate=sds((Lm, d, E), jnp.float32), bias=sds((Lm, E), jnp.float32),
+            w1=experts(d, 768), w2=experts(768, d), w3=experts(d, 768),
+            s1=q40(d, 1536, (Lm,)), s2=q40(1536, d, (Lm,)), s3=q40(d, 1536, (Lm,)),
+            rms_ffn=sds((Lm, d), jnp.float32)),
+        rms_final=sds((d,), jnp.float32), wcls=q40(d, vocab, ()),
+        rope_cos=sds((seq, 32), jnp.float32), rope_sin=sds((seq, 32), jnp.float32))
+    cache = KVCache(sds((L, lanes, seq, 512), jnp.bfloat16), sds((L, lanes, seq, 128), jnp.bfloat16))
+    tok = sds((lanes, 1), jnp.int32)
+    hlo = jax.jit(
+        lambda p, t, c: deepseek.deepseek_forward(cfg, p, t, t, c), donate_argnums=(2,)
+    ).lower(params, tok, cache).compile().as_text()
+    # layer 0: wq, wkva, wo, w1, w3, w2; the scan's body: wq, wkva, wo, three
+    # grouped products, the shared experts' three; the head
+    assert hlo.count("tpu_custom_call") == 16
+    stack = rf"bf16\[{L},{lanes},{seq},512\]"
+    # (a stack this small XLA may stage whole in fast memory by copy-start /
+    # copy-done of its own, as the Llama block's test above notes; a plain
+    # copy of it is what is looked for)
+    assert not re.search(rf"= {stack}\S* copy\(", hlo)
+    assert f"= u8[{E},1024,768]" not in hlo and f"= u8[{E},384,2048]" not in hlo
+
+
 def test_selection_table_resolves_only_to_compile_tested_modes():
     """`auto` may only land on a mode the grid above compiles."""
     modes = {r["mode"] for r in dequant_select.DequantTable().rules}

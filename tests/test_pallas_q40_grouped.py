@@ -1,0 +1,122 @@
+"""The grouped Q40 kernel (ops/pallas_q40_grouped.py) in interpret mode: every
+row by its expert's dequantized weights, a slab no row chose never addressed,
+parked rows routed nowhere, static shapes that hold any routing."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_llama_multiusers_tpu.ops import pallas_q40_grouped as g
+from distributed_llama_multiusers_tpu.quants.packed import (
+    PackedQ40,
+    Q40Experts,
+    unpack_q40,
+    unpack_q40_slabs,
+)
+
+L, E, D_IN, D_OUT = 2, 8, 256, 128
+
+
+@pytest.fixture(scope="module")
+def stack():
+    pk = jax.random.bits(jax.random.PRNGKey(0), (L, E, D_IN // 2, D_OUT), jnp.uint8)
+    sc = jax.random.uniform(jax.random.PRNGKey(1), (L, E, D_IN // 32, D_OUT)) * 0.01 + 0.001
+    packed = PackedQ40(pk, sc.astype(jnp.float16))
+    return packed, Q40Experts.from_packed(packed)
+
+
+def _routing(seed, n, k, n_experts=E, parked=()):
+    rng = np.random.default_rng(seed)
+    topi = np.stack([rng.choice(n_experts, size=k, replace=False) for _ in range(n)])
+    live = np.ones(n, bool)
+    live[list(parked)] = False
+    return jnp.asarray(topi, jnp.int32), jnp.asarray(live)
+
+
+def _addressed(plan):
+    """(layer, expert) blocks the index map names over the whole grid."""
+    meta = np.concatenate([[1, int(plan.n_used)], np.asarray(plan.tile_expert)])
+    return [g.tile_block_index(i, meta) for i in range(plan.tile_expert.shape[0])]
+
+
+def test_scale_bits_round_trip(stack):
+    packed, experts = stack
+    got = unpack_q40_slabs(experts, 1, jnp.arange(E))
+    np.testing.assert_array_equal(got, unpack_q40(PackedQ40(packed.packed[1], packed.scales[1])))
+
+
+@pytest.mark.parametrize("n,k,parked", [(5, 2, (2,)), (32, 3, ()), (32, 3, (0, 7, 31)), (70, 2, (3,))])
+def test_every_row_is_multiplied_by_its_experts_dequantized_weights(stack, n, k, parked):
+    packed, experts = stack
+    topi, live = _routing(n + k, n, k, parked=parked)
+    plan = g.route_plan(topi, live, E)
+    x = jax.random.normal(jax.random.PRNGKey(2), (n, D_IN), jnp.float32)
+    rows = jnp.concatenate([x, jnp.zeros((1, D_IN))])[plan.src]
+    got = g.q40_grouped_pallas(rows, experts, 1, plan, interpret=True)
+    xla = g.grouped_matmul_xla(rows, experts, 1, plan)
+    dense = np.asarray(unpack_q40(PackedQ40(packed.packed[1], packed.scales[1])))
+    p_rows = rows.shape[0]
+    for i in range(n):
+        for j in range(k):
+            pos = int(plan.pos[i, j])
+            if not live[i]:
+                assert pos == p_rows  # routed nowhere: past the last row
+                continue
+            want = np.asarray(x[i]) @ dense[int(topi[i, j])]
+            np.testing.assert_allclose(got[pos], want, rtol=2e-5, atol=2e-5)
+            np.testing.assert_allclose(xla[pos], want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_slab_no_row_chose_is_never_addressed(seed):
+    n, k = 6, 2
+    topi, live = _routing(seed, n, k, parked=(seed % n,))
+    plan = g.route_plan(topi, live, E)
+    chosen = {int(e) for i in range(n) if live[i] for e in topi[i]}
+    blocks = _addressed(plan)
+    assert {b[1] for b in blocks} == chosen
+    assert {b[0] for b in blocks} == {1} and all(b[2:] == (0, 0) for b in blocks)
+    experts = [b[1] for b in blocks]
+    # groups follow each other in expert order, so a slab is fetched once; the
+    # tiles past the last used one repeat its expert and fetch nothing
+    used = experts[: int(plan.n_used)]
+    assert used == sorted(used) and set(experts[int(plan.n_used):]) <= {used[-1]}
+    assert int(plan.slabs) == len(chosen)
+    assert int(plan.assignments) == int(live.sum()) * k
+
+
+def test_parked_rows_take_no_tile_row_and_all_parked_computes_nothing(stack):
+    _, experts = stack
+    topi, _ = _routing(3, 4, 2)
+    plan = g.route_plan(topi, jnp.zeros(4, bool), E)
+    assert int(plan.n_used) == 0 and int(plan.slabs) == 0 and int(plan.assignments) == 0
+    assert np.all(np.asarray(plan.src) == 4) and np.all(np.asarray(plan.pos) == plan.src.shape[0])
+
+
+@pytest.mark.parametrize("n,k,n_experts", [(1, 2, 8), (32, 6, 128), (160, 6, 128), (1024, 6, 128), (64, 2, 4)])
+def test_the_static_tile_count_holds_the_worst_routing(n, k, n_experts):
+    a = n * k
+    tm = g.tile_rows(a, n_experts)
+    n_tiles = g.max_tiles(a, n_experts, tm)
+    # the routings that need the most tiles: as many groups of one row past a
+    # whole tile as there are experts, and everything on one expert
+    for topi in (
+        np.stack([(np.arange(k) + i) % n_experts for i in range(n)]),
+        np.tile(np.arange(k), (n, 1)),
+    ):
+        plan = g.route_plan(jnp.asarray(topi, jnp.int32), jnp.ones(n, bool), n_experts)
+        assert plan.tile_expert.shape[0] == n_tiles
+        assert int(plan.n_used) <= n_tiles
+        pos = np.asarray(plan.pos).ravel()
+        assert len(set(pos)) == a and pos.max() < n_tiles * tm  # no token dropped
+        owner = np.asarray(plan.tile_expert)[pos // tm]
+        np.testing.assert_array_equal(owner, topi.ravel())
+
+
+def test_supports_says_which_stacks_the_kernel_takes(stack):
+    packed, experts = stack
+    assert g.grouped_supports(experts)
+    assert not g.grouped_supports(packed)  # float16 scales: the XLA form
+    wide = Q40Experts(jnp.zeros((1, 2, 4096, 4096), jnp.uint8), jnp.zeros((1, 2, 256, 4096), jnp.int16))
+    assert not g.grouped_supports(wide)  # a slab of several blocks
