@@ -77,6 +77,66 @@ DEFAULT_TOPP = 0.9
 DEFAULT_PIPELINE_DEPTH = 2
 
 
+def _ordered_key(z):
+    """float32 -> uint32 with the floats' order (-0.0 and 0.0 one key): the
+    bit pattern with the sign bit set for z >= 0 and all bits flipped for
+    z < 0, so -inf (a grammar-masked token) is the least key of a row."""
+    b = jax.lax.bitcast_convert_type(z, jnp.uint32)
+    k = jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+    return jnp.where(z == 0.0, jnp.uint32(0x80000000), k)
+
+
+def _count_before(mask):
+    """[V] bool -> [V] float32: how many True entries lie strictly before
+    each position. Rows of 128 (one MXU tile): within a row one product
+    with a strictly triangular 0/1 matrix (exact: 0/1 operands, sums under
+    128), across rows a running sum of V/128 row totals. (`jnp.cumsum` over
+    the whole vocabulary is 0.6 ms at 32 x 152064 on a v5e, this form under
+    0.1.)"""
+    v, w = mask.shape[0], 128
+    rows = -(-v // w)
+    m = jnp.pad(mask, (0, rows * w - v)).reshape(rows, w).astype(jnp.float32)
+    before_in_row = jnp.triu(jnp.ones((w, w), jnp.float32), 1)
+    totals = jnp.sum(m, axis=1)
+    rows_before = jnp.cumsum(totals) - totals
+    return (m @ before_in_row + rows_before[:, None]).reshape(-1)[:v]
+
+
+def nucleus_keep(z, topp):
+    """The top-p nucleus of one row WITHOUT an order: ``z`` [V] float32 the
+    logits over the temperature, p = softmax(z); returns the [V] bool
+    kept set a stable descending sort, cumulative sum and
+    ``(csum - p) < topp`` cut-off give, for a nucleus of any width.
+
+    A token is kept iff the mass strictly ahead of it is under ``topp``.
+    G(v) = the mass of tokens with z > v falls as v rises, so the kept set
+    is ``z >= v*`` for the least value v* of the row with G(v*) < topp:
+    found exactly by bisection over the 32 bits of an order-preserving key
+    (32 passes of one compare, select and row sum; a fixed summation tree
+    with monotone rounding keeps G monotone, so the search ends on a value
+    of the row and not on a tolerance). Ties at v* as the stable sort has
+    them: the tie of rank j by token id is kept iff G(v*) + j * p(v*) <
+    topp. ``topp`` <= 0 or >= 1 keeps every token with p > 0, by that test
+    and not by comparing a rounded sum with 1.0."""
+    key, p = _ordered_key(z), jax.nn.softmax(z)
+
+    def mass_above(v):
+        return jnp.sum(jnp.where(key > v, p, 0.0))
+
+    def _bit(i, v):
+        bit = jnp.uint32(0x80000000) >> i.astype(jnp.uint32)
+        # the largest key with v's leading bits and a 0 here: if the mass
+        # above it is already under topp, v* is at or below it
+        low = mass_above(v | (bit - jnp.uint32(1))) < topp
+        return jnp.where(low, v, v | bit)
+
+    v = jax.lax.fori_loop(0, 32, _bit, jnp.uint32(0))
+    tie = key == v
+    ahead = mass_above(v) + _count_before(tie) * jnp.max(jnp.where(tie, p, 0.0))
+    keep = (key > v) | (tie & (ahead < topp))
+    return jnp.where((topp <= 0.0) | (topp >= 1.0), p > 0.0, keep)
+
+
 @dataclass
 class EngineStats:
     """Per-call timing + transfer counters — the analogue of the reference's
@@ -592,7 +652,8 @@ class InferenceEngine:
 
         def _g_mask_row(gtab, g, row):
             # -inf outside the state's legal set: the masked row feeds the
-            # SAME argmax + full-vocab sort/cumsum/categorical as before
+            # SAME argmax + full-vocab nucleus search/categorical as a free
+            # row (an order-preserving key and p = 0: never kept)
             return jnp.where(_g_bits(gtab, g), row, -jnp.inf)
 
         _g_mask_rows = jax.vmap(_g_mask_row, in_axes=(None, 0, 0))
@@ -638,37 +699,45 @@ class InferenceEngine:
             )
             return mgreedy.T, gstates.T
 
-        # EXACT on-device top-p: the nucleus is computed over the FULL
-        # vocab (top_k with k == vocab_size is a total descending sort), so
-        # no truncation class exists and wide-nucleus / high-temperature
-        # requests sample on device like everyone else — the host Sampler
-        # survives only as the host_sampling=True escape hatch. (PR 9's
-        # dead `device_topk` knob is gone: a knob that selects no program
-        # is exactly what the warmup-coverage lint would mis-model.)
-        nucleus_k = cfg.vocab_size
-
+        # EXACT on-device top-p, with no order. The rule, one function for
+        # every step family (decode, pipelined, fused, verify, multi-step,
+        # the prefill boundary token, `sample_token`):
+        #   1. z = row / max(temp, 1e-6) in float32, p = softmax(z) over the
+        #      WHOLE vocabulary (a grammar-masked token has p = 0);
+        #   2. a token is kept iff the mass strictly ahead of it is under
+        #      top_p: `nucleus_keep` (above the class) finds the edge value
+        #      by a threshold search over the unsorted row, ties at the edge
+        #      by token id as a stable sort has them;
+        #   3. top_p <= 0 or >= 1 keeps every token with p > 0 (the sorted
+        #      form compared a rounded running sum with 1.0 there and lost
+        #      80-320 tokens of a 152064-token tail);
+        #   4. fold_in(PRNGKey(seed), pos), then a categorical draw over the
+        #      kept tokens in vocabulary order; temp == 0 returns `greedy`.
+        # No truncation class exists, wide-nucleus / high-temperature
+        # requests sample on device like everyone else, and no step program
+        # holds a sort of the vocabulary (tests/test_sampler_no_sort.py).
+        # The host Sampler survives only as the host_sampling=True escape
+        # hatch.
         def _sample_lane(row, temp, topp, seed, pos, greedy):
-            """Exact nucleus sample for one lane, on device: full-vocab
-            sort → cumulative sum → nucleus mask → categorical draw.
+            """Exact nucleus sample for one lane, on device: softmax over
+            the whole row, the kept set by `nucleus_keep`, a categorical
+            draw over the kept tokens in vocabulary order.
 
-            Reproduces the reference Sampler's sort→cumsum→cutoff shape
-            (src/tokenizer.cpp:416-457) over the WHOLE vocab, so the kept
-            set equals the host Sampler's exact nucleus for any (temp,
-            topp); only the RNG differs (fold_in(seed, pos) + categorical
-            here vs xorshift64* there — pinned by
-            tests/test_sampler_parity.py). Deterministic per (seed,
-            position): seeded runs reproduce."""
-            vals, idx = jax.lax.top_k(row, nucleus_k)
-            t = jnp.maximum(temp, 1e-6)
-            p = jax.nn.softmax(vals.astype(jnp.float32) / t)
-            csum = jnp.cumsum(p)
-            topp_eff = jnp.where((topp <= 0.0) | (topp >= 1.0), 1.0, topp)
-            # keep every token up to and including the one crossing topp
-            keep = (csum - p) < topp_eff
-            p = jnp.where(keep, p, 0.0)
+            The kept set is the reference Sampler's sort→cumsum→cutoff set
+            (src/tokenizer.cpp:416-457) for any (temp, topp); only the RNG
+            differs (fold_in(seed, pos) + categorical here vs xorshift64*
+            there — pinned by tests/test_sampler_parity.py). Deterministic
+            per (seed, position): seeded runs reproduce. The Gumbel noise
+            is attached to a token's ID; builds that sorted the row first
+            attached it to the token's RANK, so a (seed, position) yields
+            another, equally valid, token than it did there (a journal
+            written by such a build replays to other tokens)."""
+            z = row.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
+            keep = nucleus_keep(z, topp)
             key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
-            choice = jax.random.categorical(key, jnp.log(p))
-            return jnp.where(temp == 0.0, greedy, idx[choice].astype(jnp.int32))
+            # z is log p plus a constant of the row: the same distribution
+            choice = jax.random.categorical(key, jnp.where(keep, z, -jnp.inf))
+            return jnp.where(temp == 0.0, greedy, choice.astype(jnp.int32))
 
         self._sample_lanes = jax.vmap(_sample_lane)
         self._sample_one = jax.jit(
@@ -680,7 +749,7 @@ class InferenceEngine:
         @jax.named_scope(SCOPE_SAMPLER)
         def _sample_lanes_or_greedy(step, temps, topps, seeds, positions,
                                     greedy):
-            # the full-vocab sort is only worth paying when some lane
+            # the full-vocab sampler is only worth paying when some lane
             # actually samples: an XLA Conditional (ONE branch executes at
             # runtime, unlike a select) skips the whole sampler for
             # all-greedy batches — the common serving case — with a single
@@ -696,7 +765,7 @@ class InferenceEngine:
 
         @jax.named_scope(SCOPE_SAMPLER)
         def _masked_greedy(gtab, gs, step):
-            # grammar mask BEFORE both the argmax and the exact top-p sort:
+            # grammar mask BEFORE both the argmax and the exact top-p search:
             # constrained lanes' greedy continuation IS the masked argmax.
             # FREE lanes (gs == 0) see an all-ones mask — identity.
             mstep = _g_mask_rows(gtab, gs, step)
@@ -1073,7 +1142,7 @@ class InferenceEngine:
                 mlast = _g_mask_row(gtab, p_g, last)
                 greedy = jnp.argmax(mlast).astype(jnp.int32)
                 # same runtime gate as the decode families: a greedy admission
-                # (temp 0) skips the full-vocab sampler sort entirely
+                # (temp 0) skips the full-vocab sampler entirely
                 sampled = jax.lax.cond(
                     temp > 0.0,
                     lambda: _sample_lane(

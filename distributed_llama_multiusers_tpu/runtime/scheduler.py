@@ -285,9 +285,9 @@ class _Lane:
 # the docs: requests at/above these used to fall back to the host Sampler
 # because the old on-device sampler truncated to top-`device_topk` logits,
 # which a near-1.0 top-p or a very high temperature defeats. The device
-# sampler is now EXACT (full-vocab sort → cumsum → nucleus mask,
-# engine.py _sample_lane), so no request routes host-exact on numerics
-# grounds anymore — `host_sampling=True` (bit-exact reference xorshift
+# sampler is now EXACT (full-vocab softmax → threshold search for the
+# nucleus, engine.py nucleus_keep), so no request routes host-exact on
+# numerics grounds anymore — `host_sampling=True` (bit-exact reference xorshift
 # semantics, one [vocab] f32 transfer per token) is the only remaining
 # host-exact path, and steady-state serving never reads logits back.
 HOST_EXACT_TOPP = 0.99

@@ -17,7 +17,11 @@ Three properties make this a latency blip instead of data loss:
   written-but-never-received deltas in the dead process's socket buffer
   (the journaled watermark trails transport writes, not client receipt,
   so it can sit AHEAD of the client's true position and is never used
-  to discard replayed deltas).
+  to discard replayed deltas). Byte-identical on ONE build: the token a
+  (seed, position) yields belongs to the sampler's form, which changed
+  with PR 34 (the draw runs over tokens by id, no longer by rank), so a
+  SAMPLED request journaled by an older build regenerates to other,
+  equally valid, tokens after the upgrade; greedy requests do not move.
 - **no recovery stampede** — re-admission is PACED (one request at a
   time, a small gap between submits) and goes through ``submit()``,
   which is gated by the circuit breaker: on a restart into a still-sick

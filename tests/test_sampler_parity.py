@@ -3,20 +3,29 @@
 
 PINNED NUMERICS CLASS (the contract this file enforces):
 
-- SUPPORT-EXACT: the device sampler's nucleus — full-vocab descending
-  sort, cumulative sum, keep while (csum - p) < top_p including the
-  crossing token — equals the host Sampler's exact nucleus for every
-  (temp, topp) in the grid, including topp <= 0 / >= 1 (both samplers
-  define those as full-vocab multinomial) and the old HOST_EXACT_TOPP /
-  HOST_EXACT_TEMP routing boundaries, which no longer route anywhere:
-  every draw from either sampler lands inside that set.
+- SUPPORT-EXACT: the device sampler's nucleus — p = softmax(row / temp)
+  over the whole vocabulary; a token is kept iff the mass strictly ahead
+  of it (larger logits, and equal logits of a lower token id) is under
+  top_p, i.e. up to and including the crossing token. The device finds
+  that set WITHOUT an order (``nucleus_keep``: the kept set is
+  ``z >= v*`` for the least value v* of the row whose mass above is under
+  top_p, by bisection over the floats' bit patterns; ties at v* by token
+  id) — and it equals the host Sampler's exact nucleus, and the set the
+  full stable descending sort + cumulative sum of builds before PR 34
+  gave (``_sorted_keep`` below is that code), for every (temp, topp) in
+  the grid, including topp <= 0 / >= 1 (both samplers define those as
+  full-vocab multinomial: every token with p > 0) and the old
+  HOST_EXACT_TOPP / HOST_EXACT_TEMP routing boundaries, which no longer
+  route anywhere: every draw from either sampler lands inside that set.
 - DISTRIBUTION: probabilities are the same f32 softmax on both sides;
   empirical frequencies agree with the analytic distribution (loose
   total-variation bound — this is a smoke bound, not a statistical
   proof).
 - RNG STREAMS DIFFER BY CONSTRUCTION: fold_in(seed, pos) + categorical
   on device vs xorshift64* on host — token-for-token equality between
-  the two samplers is NOT part of the class and is never asserted.
+  the two samplers is NOT part of the class and is never asserted (nor
+  between builds: since PR 34 the device's noise belongs to a token's id,
+  before it to the token's rank in the sort).
   What IS asserted: the device draw is deterministic per (seed, pos),
   so seeded serving runs reproduce, and the device sampler equals
   itself across the sync/pipelined scheduler paths (pinned by the
@@ -26,11 +35,13 @@ PINNED NUMERICS CLASS (the contract this file enforces):
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from distributed_llama_multiusers_tpu.formats import load_model_header
 from distributed_llama_multiusers_tpu.models import load_params_from_m
 from distributed_llama_multiusers_tpu.runtime import InferenceEngine
+from distributed_llama_multiusers_tpu.runtime.engine import nucleus_keep
 from distributed_llama_multiusers_tpu.runtime.scheduler import (
     HOST_EXACT_TEMP,
     HOST_EXACT_TOPP,
@@ -169,3 +180,156 @@ def test_wide_nucleus_tail_actually_reachable(engine):
         for seed in range(200)
     )
     assert hit_tail, "no draw ever reached past the old top-64 truncation"
+
+
+# ---------------------------------------------------------------------------
+# the kept set of the threshold search against the sort it replaced
+# ---------------------------------------------------------------------------
+
+QWEN_VOCAB = 152064
+
+
+@jax.jit
+def _sorted_keep(row, temp, topp):
+    """The kept set as builds before PR 34 computed it (the deleted body of
+    ``_sample_lane``): a total stable descending sort of the row with its
+    indices, softmax, cumulative sum, ``(csum - p) < topp``. Its ``topp``
+    <= 0 / >= 1 branch compared a rounded float32 sum with 1.0 and dropped
+    tokens of the tail, so those points are held to the contract instead
+    (``test_full_vocab_topp_keeps_every_token_with_mass``)."""
+    vals, idx = jax.lax.top_k(row, row.shape[0])
+    p = jax.nn.softmax(vals.astype(jnp.float32) / jnp.maximum(temp, 1e-6))
+    keep = (jnp.cumsum(p) - p) < topp
+    return jnp.zeros(row.shape, bool).at[idx].set(keep)
+
+
+@jax.jit
+def _searched_keep(row, temp, topp):
+    z = row.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
+    return nucleus_keep(z, topp)
+
+
+def _bf16_row(vocab, seed):
+    """Logits as the head's kernel writes them: normal at the benchmark's
+    gain, rounded to bfloat16, so values repeat and a tie group of tens of
+    tokens straddles the nucleus's edge."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal(vocab) * 1.78, jnp.float32)
+    return np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))
+
+
+def _masked_row(vocab, seed):
+    """A grammar-masked row: most tokens -inf, the legal ones spread."""
+    rng = np.random.default_rng(seed)
+    row = np.full(vocab, -np.inf, np.float32)
+    legal = rng.choice(vocab, size=max(3, vocab // 7), replace=False)
+    row[legal] = rng.standard_normal(len(legal)).astype(np.float32) * 2.0
+    return row
+
+
+ROWS = {
+    "separated": lambda: _logits(4096),
+    "bf16_ties": lambda: _bf16_row(32768, 3),
+    "grammar_masked": lambda: _masked_row(4096, 5),
+}
+PARTIAL_GRID = [g for g in GRID if 0.0 < g[1] < 1.0]
+
+
+@pytest.mark.parametrize("temp,topp", PARTIAL_GRID)
+@pytest.mark.parametrize("kind", sorted(ROWS))
+def test_searched_nucleus_equals_the_sorted_one(kind, temp, topp):
+    """(a), (b), (c): the search's kept set IS the stable sort's, token
+    for token, on a well-separated row, on a row of bfloat16 values whose
+    tie group straddles the edge, and on a row with -inf entries."""
+    row = ROWS[kind]()
+    want = np.asarray(_sorted_keep(row, np.float32(temp), np.float32(topp)))
+    got = np.asarray(_searched_keep(row, np.float32(temp), np.float32(topp)))
+    assert got.sum() >= 1 and not got[~np.isfinite(row)].any()
+    assert np.array_equal(got, want), (
+        f"{kind} temp={temp} topp={topp}: search keeps {got.sum()}, sort "
+        f"{want.sum()}, {np.sum(got != want)} tokens differ"
+    )
+    host, _ = _host_nucleus(row, temp, topp)
+    assert len(host ^ set(np.nonzero(got)[0].tolist())) <= 2  # f32 vs f64 sums
+
+
+@pytest.mark.parametrize("temp,topp", [(0.7, 0.9), (0.8, 0.95), (2.0, 0.5)])
+def test_tie_group_at_the_edge_keeps_its_lowest_ids(temp, topp):
+    """(b) spelled out: the edge of the nucleus falls INSIDE a group of equal
+    logits; of that group exactly the lowest token ids are kept, as many as
+    the stable sort keeps, and every token above the group is."""
+    row = _bf16_row(32768, 3)
+    got = np.asarray(_searched_keep(row, np.float32(temp), np.float32(topp)))
+    edge = row[got].min()
+    group = np.nonzero(row == edge)[0]
+    kept = got[group]
+    assert 0 < kept.sum() < len(group), "pick a row whose tie group straddles"
+    assert kept[: kept.sum()].all() and not kept[kept.sum():].any()
+    assert got[row > edge].all() and not got[row < edge].any()
+    want = np.asarray(_sorted_keep(row, np.float32(temp), np.float32(topp)))
+    assert want[group].sum() == kept.sum()
+
+
+@pytest.mark.parametrize("topp", [1.0, 0.0, -0.5])
+@pytest.mark.parametrize("kind", ["normal", "bf16", "grammar_masked"])
+def test_full_vocab_topp_keeps_every_token_with_mass(kind, topp):
+    """(d): topp >= 1 or <= 0 is the full-vocabulary multinomial at Qwen's
+    152064 tokens: EVERY token with p > 0 is kept. (The sorted form compared
+    an f32 running sum with 1.0 and lost 80-320 tokens of the tail in half
+    the rows: the control below shows the reference failing where it does.)"""
+    rng = np.random.default_rng(17)
+    row = {
+        "normal": lambda: (rng.standard_normal(QWEN_VOCAB) * 1.78).astype(np.float32),
+        "bf16": lambda: _bf16_row(QWEN_VOCAB, 17),
+        "grammar_masked": lambda: _masked_row(QWEN_VOCAB, 17),
+    }[kind]()
+    got = np.asarray(_searched_keep(row, np.float32(0.7), np.float32(topp)))
+    nucleus, p = _host_nucleus(row, 0.7, topp)
+    assert set(np.nonzero(got)[0].tolist()) == nucleus
+    assert got.sum() == np.sum(p > 0) >= QWEN_VOCAB // 8
+
+
+def test_sorted_form_dropped_tail_tokens_at_topp_one():
+    """The control of (d): on some row of 152064 tokens the old form, fed
+    the 1.0 it substituted for topp >= 1, keeps fewer tokens than have
+    mass; the search keeps them all on the same row."""
+    lost = 0
+    for seed in range(4):
+        row = (np.random.default_rng(seed).standard_normal(QWEN_VOCAB)
+               * 1.78).astype(np.float32)
+        old = np.asarray(_sorted_keep(row, np.float32(0.7), np.float32(1.0)))
+        new = np.asarray(_searched_keep(row, np.float32(0.7), np.float32(1.0)))
+        assert new.all()
+        lost += int(QWEN_VOCAB - old.sum())
+    assert lost > 0
+
+
+@pytest.mark.parametrize("vocab", [96, 4096, QWEN_VOCAB])
+def test_one_token_nucleus(vocab):
+    """(e): a cold temperature and a small topp keep the argmax alone."""
+    row = _logits(vocab)
+    row[np.argmax(row)] += 2.0   # one token holds over a third of the mass
+    got = np.asarray(_searched_keep(row, np.float32(0.2), np.float32(0.3)))
+    want = np.asarray(_sorted_keep(row, np.float32(0.2), np.float32(0.3)))
+    assert np.array_equal(got, want)
+    assert got.sum() == 1 and got[np.argmax(row)]
+
+
+def test_all_equal_row_is_cut_by_token_id():
+    """Every logit equal: one tie group holds the whole row, and the nucleus
+    is its first ceil(topp * V) ids (the running count, not the values,
+    decides)."""
+    got = np.asarray(_searched_keep(np.zeros(1000, np.float32),
+                                    np.float32(1.0), np.float32(0.25)))
+    assert got[:250].all() and not got[251:].any()
+
+
+def test_device_draws_cover_a_tie_group_only_up_to_the_edge(engine):
+    """End to end through ``engine.sample_token``: with a row of two values
+    the draws reach exactly the tokens the sorted nucleus holds."""
+    vocab = engine.config.vocab_size
+    row = np.where(np.arange(vocab) % 2 == 0, 1.0, 0.0).astype(np.float32)
+    want = np.asarray(_sorted_keep(row, np.float32(1.0), np.float32(0.8)))
+    draws = {engine.sample_token(row, 1.0, 0.8, seed, 0) for seed in range(400)}
+    assert draws <= set(np.nonzero(want)[0].tolist())
+    assert len(draws) > want.sum() // 2
