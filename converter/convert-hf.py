@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 from distributed_llama_multiusers_tpu.formats.model_file import (
     ArchType,
     HiddenAct,
+    LayerKind,
     ModelHeader,
     MoeScore,
     RopeType,
@@ -85,12 +86,16 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         # latent attention + routed FFN: the header's KEY_KV_LORA_RANK block
         # says so; the arch word stays the one every reader accepts
         "deepseek_v3": ArchType.LLAMA,
+        # a per-layer pattern of conv and attention mixers: KEY_LAYER_KIND
+        "lfm2_moe": ArchType.LLAMA,
     }.get(cfg["model_type"])
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
-    act = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}.get(cfg["hidden_act"])
+    # lfm2_moe publishes no hidden_act: its FFNs are SwiGLU
+    hidden_act = cfg.get("hidden_act", "silu" if cfg["model_type"] == "lfm2_moe" else None)
+    act = {"gelu": HiddenAct.GELU, "silu": HiddenAct.SILU}.get(hidden_act)
     if act is None:
-        raise ValueError(f"Unsupported hidden act: {cfg['hidden_act']}")
+        raise ValueError(f"Unsupported hidden act: {hidden_act}")
     h = ModelHeader(
         version=0,
         arch_type=arch,
@@ -104,10 +109,13 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         seq_len=cfg["max_position_embeddings"],
         orig_seq_len=cfg["max_position_embeddings"],
         vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rope_theta=float(
+            (cfg.get("rope_parameters") or {}).get("rope_theta", cfg.get("rope_theta", 10000.0))),
     )
     if cfg["model_type"] == "deepseek_v3":
         set_latent_header(h, cfg)
+    if cfg["model_type"] == "lfm2_moe":
+        set_pattern_header(h, cfg)
     n_experts = cfg.get("num_local_experts")
     if n_experts:
         h.n_experts = int(n_experts)
@@ -154,6 +162,78 @@ def set_latent_header(h: ModelHeader, cfg: dict) -> None:
         h.moe_select_bias = int(cfg.get("topk_method") == "noaux_tc")
         h.moe_norm_topk = int(bool(cfg.get("norm_topk_prob", True)))
         h.moe_routed_scale = float(cfg.get("routed_scaling_factor", 1.0))
+
+
+_LAYER_KINDS = {"conv": LayerKind.CONV, "full_attention": LayerKind.ATTENTION}
+
+
+def set_pattern_header(h: ModelHeader, cfg: dict) -> None:
+    """The header keys of ``model_type: lfm2_moe`` (formats/model_file.py
+    KEY_LAYER_KIND ...): the layer kinds as published, the conv's taps, the
+    per-head norm of queries and keys, and the routed FFN's keys. What the
+    runtime does not compute is refused here, not converted wrongly."""
+    unknown = sorted(set(cfg["layer_types"]) - set(_LAYER_KINDS))
+    if unknown:
+        raise ValueError(f"Unsupported lfm2_moe layer types: {unknown}")
+    if cfg.get("conv_bias"):
+        raise ValueError("Unsupported lfm2_moe setting: conv_bias true")
+    if (cfg.get("rope_parameters") or {}).get("rope_type", "default") != "default":
+        raise ValueError(f"Unsupported rope parameters: {cfg['rope_parameters']}")
+    h.layer_kinds = [_LAYER_KINDS[k] for k in cfg["layer_types"]]
+    h.conv_kernel = int(cfg["conv_L_cache"])
+    h.qk_norm = 1
+    h.norm_epsilon = float(cfg["norm_eps"])
+    h.n_experts = int(cfg.get("num_experts") or 0)
+    if h.n_experts:
+        h.n_active_experts = int(cfg["num_experts_per_tok"])
+        h.moe_hidden_dim = cfg["moe_intermediate_size"]
+        h.n_dense_layers = int(cfg.get("num_dense_layers", 0))
+        h.moe_score_func = MoeScore.SIGMOID
+        h.moe_select_bias = int(bool(cfg.get("use_expert_bias")))
+        h.moe_norm_topk = int(bool(cfg.get("norm_topk_prob", True)))
+        h.moe_routed_scale = float(cfg.get("routed_scaling_factor", 1.0))
+
+
+def write_pattern_layers(out, index, header: ModelHeader, wt: int) -> None:
+    """The layers of an lfm2_moe checkpoint in the order of
+    formats/model_file._pattern_block_specs. ``conv.in_proj`` is kept whole
+    (its thirds are B, C, x); the depthwise taps ``[dim, 1, K]`` are written
+    ``[dim, K]``; q and k rows are permuted to the interleaved-pair layout as
+    a Llama file's, and the per-head norm gains with them: the gain is
+    applied per dimension BEFORE the rotation, so it must follow its row."""
+    n_heads, n_kv = header.n_heads, header.n_kv_heads
+    for l, kind in enumerate(header.layer_kinds):
+        pre = f"model.layers.{l}"
+        if kind == LayerKind.CONV:
+            write_tensor(out, index.get(f"{pre}.conv.in_proj.weight"), wt)
+            taps = index.get(f"{pre}.conv.conv.weight")
+            write_tensor(out, taps.reshape(header.dim, header.conv_kernel), FloatType.F32)
+            write_tensor(out, index.get(f"{pre}.conv.out_proj.weight"), wt)
+        else:
+            att = f"{pre}.self_attn"
+            write_tensor(out, permute_rotary(index.get(f"{att}.q_proj.weight"), n_heads), wt)
+            write_tensor(out, permute_rotary(index.get(f"{att}.k_proj.weight"), n_kv), wt)
+            write_tensor(out, index.get(f"{att}.v_proj.weight"), wt)
+            for name in ("q_layernorm", "k_layernorm"):
+                gain = index.get(f"{att}.{name}.weight").reshape(-1, 1)
+                write_tensor(out, permute_rotary(gain, 1).reshape(-1), FloatType.F32)
+            write_tensor(out, index.get(f"{att}.out_proj.weight"), wt)
+        ffn = f"{pre}.feed_forward"
+        if l < header.n_dense_layers or header.n_experts == 0:
+            write_tensor(out, index.get(f"{ffn}.w1.weight"), wt)  # gate
+            write_tensor(out, index.get(f"{ffn}.w2.weight"), wt)  # down
+            write_tensor(out, index.get(f"{ffn}.w3.weight"), wt)  # up
+        else:
+            write_tensor(out, index.get(f"{ffn}.gate.weight"), FloatType.F32)
+            if header.moe_select_bias:
+                write_tensor(out, index.get(f"{ffn}.expert_bias"), FloatType.F32)
+            for e in range(header.n_experts):
+                epre = f"{ffn}.experts.{e}"
+                write_tensor(out, index.get(f"{epre}.w3.weight"), wt)  # up
+                write_tensor(out, index.get(f"{epre}.w1.weight"), wt)  # gate
+                write_tensor(out, index.get(f"{epre}.w2.weight"), wt)  # down
+        write_tensor(out, index.get(f"{pre}.operator_norm.weight"), FloatType.F32)
+        write_tensor(out, index.get(f"{pre}.ffn_norm.weight"), FloatType.F32)
 
 
 def write_latent_layers(out, index, header: ModelHeader, wt: int) -> None:
@@ -223,7 +303,9 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
         write_tensor(out, index.get("model.embed_tokens.weight"), FloatType.F32)
         if header.kv_lora_rank:
             write_latent_layers(out, index, header, wt)
-        for l in range(0 if header.kv_lora_rank else header.n_layers):  # a Llama block's layers
+        elif header.layer_kinds:
+            write_pattern_layers(out, index, header, wt)
+        for l in range(0 if header.kv_lora_rank or header.layer_kinds else header.n_layers):  # a Llama block's layers
             pre = f"model.layers.{l}"
             write_tensor(out, permute_rotary(index.get(f"{pre}.self_attn.q_proj.weight"), n_heads), wt)
             if header.qkv_bias:
@@ -254,7 +336,9 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
                 write_tensor(out, index.get(f"{pre}.mlp.up_proj.weight"), wt)  # w3
             write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
             write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
-        write_tensor(out, index.get("model.norm.weight"), FloatType.F32)
+        # lfm2_moe names its final norm after the embedding
+        norm_key = "model.embedding_norm.weight" if header.layer_kinds else "model.norm.weight"
+        write_tensor(out, index.get(norm_key), FloatType.F32)
         head_key = "lm_head.weight" if "lm_head.weight" in index else "model.embed_tokens.weight"
         write_tensor(out, index.get(head_key), wt)
     print(f"✅ {out_path} created successfully")

@@ -70,13 +70,13 @@ def enable_compilation_cache() -> str | None:
 
 
 def _build_engine(config, params, **kw) -> InferenceEngine:
-    """``InferenceEngine(...)``; what a latent-attention model does not serve
-    (the paged pool, the host tier, a mesh) ends the start-up with the
-    engine's own sentence instead of a traceback."""
+    """``InferenceEngine(...)``; what a latent-attention model or one with a
+    layer pattern does not serve (the paged pool, the host tier, a mesh) ends
+    the start-up with the engine's own sentence instead of a traceback."""
     try:
         return InferenceEngine(config, params, **kw)
     except ValueError as e:
-        if not config.latent_attention:
+        if not (config.latent_attention or config.layer_kinds):
             raise
         print(f"error: {e}", file=sys.stderr)
         raise SystemExit(2) from e
@@ -117,8 +117,11 @@ def load_stack(args, n_lanes: int | None = None):
         config, params = load_params_from_m_quantized(args.model, header, dtype=config_dtype)
         from ..quants.packed import PackedQ40
 
-        block = params.attn if config.latent_attention else params.layers
-        if any(isinstance(x, PackedQ40) for x in [params.wcls, block.wq]):
+        if config.layer_kinds:
+            first = params.conv.w_in if params.conv is not None else params.attn.wq
+        else:
+            first = (params.attn if config.latent_attention else params.layers).wq
+        if any(isinstance(x, PackedQ40) for x in [params.wcls, first]):
             log("🔷", "Q40 weights resident in HBM (dequant-in-matmul)")
         else:
             weights_mode = "dense"

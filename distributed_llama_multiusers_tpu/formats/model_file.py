@@ -74,6 +74,16 @@ KEY_MOE_SELECT_BIAS = 28
 KEY_MOE_NORM_TOPK = 29
 KEY_MOE_ROUTED_SCALE_E6 = 30
 KEY_NORM_EPSILON_E9 = 31
+# framework extension: a block whose layers differ in their mixer
+# (``model_type: lfm2_moe``; models/hybrid.py). KEY_LAYER_KIND is written once
+# a layer, in layer order (a list, as published: no period is guessed from
+# it); KEY_CONV_KERNEL is the short convolution's taps, KEY_QK_NORM says that
+# an attention layer norms its queries and keys per head. Such a file also
+# carries the routed FFN's keys above (kv_lora_rank 0). A file without a layer
+# kind reads, and is written, as before.
+KEY_LAYER_KIND = 32
+KEY_CONV_KERNEL = 33
+KEY_QK_NORM = 34
 
 
 class ArchType:
@@ -90,6 +100,13 @@ class MoeScore:
 
     SOFTMAX = 0  # Mixtral: softmax, the chosen renormalised
     SIGMOID = 1  # DeepSeek-V3: independent sigmoids
+
+
+class LayerKind:
+    """A layer's mixer (KEY_LAYER_KIND)."""
+
+    ATTENTION = 0  # GQA over the KV cache ("full_attention")
+    CONV = 1  # gated short convolution over a window of inputs ("conv")
 
 
 class RopeType:
@@ -136,6 +153,10 @@ class ModelHeader:
     moe_select_bias: int = 0
     moe_norm_topk: int = 1
     moe_routed_scale: float = 1.0
+    # a block of mixed layers (KEY_LAYER_KIND ...); empty / zero elsewhere
+    layer_kinds: list = field(default_factory=list)  # LayerKind a layer
+    conv_kernel: int = 0
+    qk_norm: int = 0
     header_size: int = 0
     file_size: int = 0
 
@@ -173,7 +194,11 @@ class ModelHeader:
             [(key, getattr(self, name)) for key, name in _LATENT_INT_KEYS.items()]
             + [(KEY_MOE_ROUTED_SCALE_E6, int(round(self.moe_routed_scale * 1e6))),
                (KEY_NORM_EPSILON_E9, int(round(self.norm_epsilon * 1e9)))]
-            if self.kv_lora_rank else []
+            if self.kv_lora_rank or self.layer_kinds else []
+        ) + (
+            [(KEY_LAYER_KIND, kind) for kind in self.layer_kinds]
+            + [(KEY_CONV_KERNEL, self.conv_kernel), (KEY_QK_NORM, self.qk_norm)]
+            if self.layer_kinds else []
         )
 
 
@@ -264,10 +289,19 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 h.moe_routed_scale = value / 1e6
             elif key == KEY_NORM_EPSILON_E9:
                 h.norm_epsilon = value / 1e9
+            elif key == KEY_LAYER_KIND:
+                h.layer_kinds.append(value)
+            elif key == KEY_CONV_KERNEL:
+                h.conv_kernel = value
+            elif key == KEY_QK_NORM:
+                h.qk_norm = value
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
             raise ValueError("Model does not specify weight type")
+        if h.layer_kinds and len(h.layer_kinds) != h.n_layers:
+            raise ValueError(
+                f"{len(h.layer_kinds)} layer kinds for {h.n_layers} layers")
         h.header_size = header_size
         h.orig_seq_len = h.seq_len
         if max_seq_len > 0 and h.seq_len > max_seq_len:
@@ -313,7 +347,9 @@ def model_tensor_specs(h: ModelHeader) -> list[TensorSpec]:
     add("embedding", 0, FloatType.F32, (vocab, dim))
     if h.kv_lora_rank:
         _latent_block_specs(h, add)
-    for l in range(0 if h.kv_lora_rank else h.n_layers):  # a Llama block's layers
+    elif h.layer_kinds:
+        _pattern_block_specs(h, add)
+    for l in range(0 if h.kv_lora_rank or h.layer_kinds else h.n_layers):  # a Llama block's layers
         add("block_matmul_q", l, wt, (dim, dim))
         if h.qkv_bias:
             add("block_bias_q", l, FloatType.F32, (1, dim))
@@ -365,17 +401,59 @@ def _latent_block_specs(h: ModelHeader, add) -> None:
             add("block_matmul_w2", l, wt, (dim, h.hidden_dim))
             add("block_matmul_w3", l, wt, (h.hidden_dim, dim))
         else:
-            add("block_moe_gate", l, FloatType.F32, (h.n_experts, dim))
-            if h.moe_select_bias:
-                add("block_moe_bias", l, FloatType.F32, (1, h.n_experts))
-            for e in range(h.n_experts):
-                add("block_matmul_w3", l, wt, (h.moe_hidden_dim, dim), e)
-                add("block_matmul_w1", l, wt, (h.moe_hidden_dim, dim), e)
-                add("block_matmul_w2", l, wt, (dim, h.moe_hidden_dim), e)
+            _routed_ffn_specs(h, add, l)
             if h.shared_hidden_dim:
                 add("block_matmul_shared_w1", l, wt, (h.shared_hidden_dim, dim))
                 add("block_matmul_shared_w2", l, wt, (dim, h.shared_hidden_dim))
                 add("block_matmul_shared_w3", l, wt, (h.shared_hidden_dim, dim))
+        add("block_rms_norm_0", l, FloatType.F32, (1, dim))
+        add("block_rms_norm_1", l, FloatType.F32, (1, dim))
+
+
+def _routed_ffn_specs(h: ModelHeader, add, l: int) -> None:
+    """A routed layer's FFN: the router (F32), its selection bias (F32, where
+    the header says so), then every expert's w3, w1, w2."""
+    wt, dim = h.weight_type, h.dim
+    add("block_moe_gate", l, FloatType.F32, (h.n_experts, dim))
+    if h.moe_select_bias:
+        add("block_moe_bias", l, FloatType.F32, (1, h.n_experts))
+    for e in range(h.n_experts):
+        add("block_matmul_w3", l, wt, (h.moe_hidden_dim, dim), e)
+        add("block_matmul_w1", l, wt, (h.moe_hidden_dim, dim), e)
+        add("block_matmul_w2", l, wt, (dim, h.moe_hidden_dim), e)
+
+
+def _pattern_block_specs(h: ModelHeader, add) -> None:
+    """The layers of a file whose layers differ in their mixer
+    (``model_type: lfm2_moe``), a framework extension. A conv layer:
+    ``conv_in`` (3 x dim rows: the gate B, the gate C, the input x, in that
+    order), the taps (F32, ``[dim, conv_kernel]``), ``conv_out``. An attention
+    layer: q, k, v (rows permuted to the interleaved-pair layout, as a Llama
+    file's), their per-head norm gains (F32, permuted alike, where
+    ``qk_norm``), wo. Then a dense FFN in the first ``n_dense_layers`` layers
+    and a routed one in the others; then the two norms."""
+    wt, dim, kv_dim = h.weight_type, h.dim, h.kv_dim
+    for l, kind in enumerate(h.layer_kinds):
+        if kind == LayerKind.CONV:
+            add("block_matmul_conv_in", l, wt, (3 * dim, dim))
+            add("block_conv_taps", l, FloatType.F32, (dim, h.conv_kernel))
+            add("block_matmul_conv_out", l, wt, (dim, dim))
+        elif kind == LayerKind.ATTENTION:
+            add("block_matmul_q", l, wt, (dim, dim))
+            add("block_matmul_k", l, wt, (kv_dim, dim))
+            add("block_matmul_v", l, wt, (kv_dim, dim))
+            if h.qk_norm:
+                add("block_q_norm", l, FloatType.F32, (1, h.head_size))
+                add("block_k_norm", l, FloatType.F32, (1, h.head_size))
+            add("block_matmul_wo", l, wt, (dim, dim))
+        else:
+            raise ValueError(f"layer {l}: unknown layer kind {kind}")
+        if l < h.n_dense_layers or h.n_experts == 0:
+            add("block_matmul_w1", l, wt, (h.hidden_dim, dim))
+            add("block_matmul_w2", l, wt, (dim, h.hidden_dim))
+            add("block_matmul_w3", l, wt, (h.hidden_dim, dim))
+        else:
+            _routed_ffn_specs(h, add, l)
         add("block_rms_norm_0", l, FloatType.F32, (1, dim))
         add("block_rms_norm_1", l, FloatType.F32, (1, dim))
 

@@ -10,7 +10,16 @@ import numpy as np
 
 from .. import native
 from ..quants.codec import FloatType, quantize_q40, quantize_q80
-from .model_file import ArchType, HiddenAct, ModelHeader, RopeType, write_model_header
+from .model_file import (
+    ArchType,
+    HiddenAct,
+    LayerKind,
+    ModelHeader,
+    MoeScore,
+    RopeType,
+    model_tensor_specs,
+    write_model_header,
+)
 from .tokenizer_file import TokenizerData, write_tokenizer_file
 
 
@@ -56,6 +65,28 @@ def tiny_header(
     return h
 
 
+def tiny_pattern_header(
+    pattern: str = "ccAcccAc",
+    n_dense_layers: int = 2,
+    n_experts: int = 8,
+    n_active_experts: int = 2,
+    moe_hidden_dim: int = 64,
+    conv_kernel: int = 3,
+    **kw,
+) -> ModelHeader:
+    """A toy of a block whose layers differ in their mixer (models/hybrid.py):
+    ``pattern`` a letter a layer, ``c`` a gated short convolution and ``A``
+    GQA attention with normed queries and keys; ``n_dense_layers`` dense FFNs,
+    then routed ones (sigmoid scores, a selection bias)."""
+    h = tiny_header(n_layers=len(pattern), **kw)
+    h.layer_kinds = [LayerKind.CONV if c == "c" else LayerKind.ATTENTION for c in pattern]
+    h.conv_kernel, h.qk_norm = conv_kernel, 1
+    h.n_experts, h.n_active_experts = n_experts, n_active_experts
+    h.moe_hidden_dim, h.n_dense_layers = moe_hidden_dim, n_dense_layers
+    h.moe_score_func, h.moe_select_bias = MoeScore.SIGMOID, 1
+    return h
+
+
 def _write_tensor(f, x: np.ndarray, float_type: int) -> None:
     x = np.ascontiguousarray(x, dtype=np.float32).reshape(-1)
     if float_type == FloatType.F32:
@@ -81,6 +112,15 @@ def write_synthetic_model(path: str, header: ModelHeader, seed: int = 0, scale: 
 
     def rand(shape):
         return rng.standard_normal(shape, dtype=np.float32) * scale
+
+    if header.layer_kinds:
+        # a layer pattern's file: the walk itself says what to write
+        with open(path, "wb") as f:
+            header.header_size = write_model_header(f, header)
+            for spec in model_tensor_specs(header):
+                # a norm's gains sit about one
+                _write_tensor(f, ("norm" in spec.name) + rand(spec.shape), spec.float_type)
+        return
 
     with open(path, "wb") as f:
         write_model_header(f, header)

@@ -4,7 +4,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..formats.model_file import LATENT_FIELDS, HiddenAct, ModelHeader, MoeScore, RopeType
+from ..formats.model_file import (
+    LATENT_FIELDS,
+    HiddenAct,
+    LayerKind,
+    ModelHeader,
+    MoeScore,
+    RopeType,
+)
 
 
 @dataclass(frozen=True)
@@ -47,6 +54,16 @@ class LlamaConfig:
     moe_select_bias: int = 0  # a per-expert bias added to choose, not to weigh
     moe_norm_topk: int = 1  # chosen scores renormalised to sum 1
     moe_routed_scale: float = 1.0  # factor on the routed experts' sum
+    # A block whose layers differ in their mixer (models/hybrid.py): the kind
+    # of every layer as published (formats.model_file.LayerKind; a list, no
+    # period is guessed from it), empty for a block of one kind. A conv layer
+    # is a gated short convolution of conv_kernel taps, whose per-lane state
+    # is its last conv_kernel - 1 inputs; qk_norm: an attention layer norms
+    # its queries and keys per head before the rotation. The FFN is the routed
+    # block's above (n_dense_layers, moe_*).
+    layer_kinds: tuple = ()
+    conv_kernel: int = 0
+    qk_norm: int = 0
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -68,6 +85,46 @@ class LlamaConfig:
                     "a routed latent-attention model needs moe_hidden_dim and "
                     "0 <= n_dense_layers <= n_layers"
                 )
+
+        if self.layer_kinds:
+            if len(self.layer_kinds) != self.n_layers:
+                raise ValueError(
+                    f"{len(self.layer_kinds)} layer kinds for {self.n_layers} layers")
+            if self.latent_attention:
+                raise ValueError("a layer pattern's attention layers are GQA, not latent")
+            if self.n_conv_layers and self.conv_kernel < 2:
+                raise ValueError("a conv layer needs conv_kernel >= 2")
+            if self.n_experts > 0 and not (
+                self.moe_hidden_dim > 0 and 0 <= self.n_dense_layers <= self.n_layers
+            ):
+                raise ValueError(
+                    "a routed layer pattern needs moe_hidden_dim and "
+                    "0 <= n_dense_layers <= n_layers"
+                )
+
+    @property
+    def n_conv_layers(self) -> int:
+        return sum(k == LayerKind.CONV for k in self.layer_kinds)
+
+    @property
+    def n_attention_layers(self) -> int:
+        """Layers that keep keys and values: what the KV stack holds."""
+        return self.n_layers - self.n_conv_layers
+
+    @property
+    def recurrent_state(self) -> bool:
+        """Whether a lane carries state that is overwritten in place, beside
+        what the cache keeps by position: nothing that rewinds a lane or
+        copies one at another position than its last holds for it."""
+        return self.n_conv_layers > 0
+
+    @property
+    def n_routed_layers(self) -> int:
+        """Layers whose FFN routes rows to experts read by id (the grouped
+        kernel's; 0 for a dense model and for models/llama.py's ``_moe_ffn``)."""
+        if self.n_experts == 0 or self.moe_hidden_dim == 0:
+            return 0
+        return self.n_layers - self.n_dense_layers
 
     @property
     def latent_attention(self) -> bool:
@@ -111,4 +168,7 @@ class LlamaConfig:
             n_active_experts=h.n_active_experts,
             qkv_bias=h.qkv_bias,
             **{name: getattr(h, name) for name in LATENT_FIELDS},
+            layer_kinds=tuple(h.layer_kinds),
+            conv_kernel=h.conv_kernel,
+            qk_norm=h.qk_norm,
         )

@@ -61,7 +61,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Layout, with_layout_constraint
+from jax.experimental.layout import Layout
 
 from ..formats.model_file import HiddenAct, MoeScore
 from ..ops.activations import gelu, silu
@@ -97,7 +97,7 @@ from ..telemetry.names import (
     SCOPE_SHARED_EXPERT,
 )
 from .config import LlamaConfig
-from .llama import KVCache, _qdq_q80, _to_cache_dtype
+from .llama import KVCache, _qdq_q80, kv_append
 
 
 class LatentAttnParams(NamedTuple):
@@ -270,6 +270,68 @@ def absorbed_attention(q_nope, q_pe, wuk, wuv, c_plane, r_plane, mask, scale):
     return jnp.einsum("bthc,hcv->bthv", o_lat.astype(wuv.dtype), wuv).astype(jnp.float32)
 
 
+class FfnOps(NamedTuple):
+    """What a block's FFNs share across its layers: the activation, the Q80
+    emulation's cast (identity unless asked for) and the shared operand build
+    of two matmuls on one input (identity unless the weights are Q40)."""
+
+    act_fn: object
+    maybe_qdq: object
+    share_q80: object
+
+
+def ffn_ops(cfg: LlamaConfig, emulate_q80_activations: bool, quantized: bool) -> FfnOps:
+    return FfnOps(
+        act_fn=silu if cfg.hidden_act == HiddenAct.SILU else gelu,
+        maybe_qdq=_qdq_q80 if emulate_q80_activations else (lambda y: y),
+        share_q80=shared_q80_acts if quantized else (lambda y: y),
+    )
+
+
+def gated_ffn(ops: FfnOps, yq, w1, w2, w3):
+    """``W2 (act(W1 y) * W3 y)``: a dense layer's FFN, or the shared experts."""
+    yqs = ops.share_q80(yq)  # one operand build for the gate and the up matmul
+    return matmul(ops.maybe_qdq(ops.act_fn(matmul(yqs, w1)) * matmul(yqs, w3)), w2)
+
+
+def dense_ffn(cfg: LlamaConfig, ops: FfnOps, x, dp: "DenseFfnParams"):
+    """A leading dense layer's FFN half: norm, gated FFN, residual add."""
+    with jax.named_scope(SCOPE_FFN):
+        y = rms_norm(x, dp.rms_ffn, cfg.norm_epsilon)
+        return x + ops.maybe_qdq(gated_ffn(ops, ops.maybe_qdq(y), dp.w1, dp.w2, dp.w3))
+
+
+def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live):
+    """A routed layer's FFN half. ``rp``: the layer's parameters, the expert
+    stacks whole; ``lm`` its index into them; ``live`` ``[B * T]``: False for
+    a parked row, which routes nowhere. Returns (x, slabs, assignments)."""
+    b, t, _ = x.shape
+    n = b * t
+    dtype = x.dtype
+    with jax.named_scope(SCOPE_FFN):
+        y = rms_norm(x, rp.rms_ffn, cfg.norm_epsilon)
+        yq = ops.maybe_qdq(y)
+        with jax.named_scope(SCOPE_ROUTER):
+            topw, topi = moe_router(cfg, y.reshape(n, -1), rp.gate, rp.bias)
+        with jax.named_scope(SCOPE_EXPERTS):
+            plan = route_plan(topi, live, cfg.n_experts)
+            rows = jnp.concatenate(
+                [yq.reshape(n, -1), jnp.zeros((1, yq.shape[-1]), yq.dtype)]
+            )[plan.src]  # [P, dim], sorted by expert, a zero row where none
+            g = grouped_matmul(rows, rp.w1, lm, plan)
+            u = grouped_matmul(rows, rp.w3, lm, plan)
+            ys = grouped_matmul(ops.maybe_qdq(ops.act_fn(g) * u), rp.w2, lm, plan)
+            # a parked row's assignments point past the last row: zeros
+            ys = jnp.concatenate([ys, jnp.zeros((1, ys.shape[-1]), ys.dtype)])
+            routed = jnp.einsum("nk,nkd->nd", topw, ys[plan.pos])
+            out = routed.reshape(b, t, -1)
+        if rp.s1 is not None:
+            with jax.named_scope(SCOPE_SHARED_EXPERT):
+                out = out + gated_ffn(ops, yq, rp.s1, rp.s2, rp.s3)
+        x = x + ops.maybe_qdq(out.astype(dtype))
+    return x, plan.slabs, plan.assignments
+
+
 def _pick(leaf, index):
     """Layer ``index`` of a stacked leaf: a ``Q40Layer`` where the kernel
     reads the stack itself, the expert stacks as they are (the grouped kernel
@@ -306,10 +368,8 @@ def deepseek_forward_counted(
     n_heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     eps = cfg.norm_epsilon
-    act_fn = silu if cfg.hidden_act == HiddenAct.SILU else gelu
-    maybe_qdq = _qdq_q80 if emulate_q80_activations else (lambda y: y)
-    quantized = isinstance(params.attn.wq, PackedQ40)
-    share_q80 = shared_q80_acts if quantized else (lambda y: y)
+    ops = ffn_ops(cfg, emulate_q80_activations, isinstance(params.attn.wq, PackedQ40))
+    maybe_qdq, share_q80 = ops.maybe_qdq, ops.share_q80
     scale = 1.0 / float(nope + rope) ** 0.5
 
     with jax.named_scope(SCOPE_EMBED):
@@ -344,10 +404,7 @@ def deepseek_forward_counted(
             q, q_pe, c, k_pe = jax.lax.optimization_barrier((q, q_pe, c, k_pe))
         with jax.named_scope(SCOPE_KV_WRITE):
             at = (l, lane_idx, positions)
-            c_all = c_all.at[at].set(_to_cache_dtype(c, c_all.dtype), mode="drop")
-            r_all = r_all.at[at].set(_to_cache_dtype(k_pe, r_all.dtype), mode="drop")
-            c_all = with_layout_constraint(c_all, row_major)
-            r_all = with_layout_constraint(r_all, row_major)
+            c_all, r_all = kv_append(c_all, r_all, at, c, k_pe, row_major)
         with jax.named_scope(SCOPE_ATTENTION):
             # the layer's latent plane, read out of the carry AFTER the append
             c_plane = jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
@@ -360,37 +417,6 @@ def deepseek_forward_counted(
             x = x + maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
         return x, c_all, r_all
 
-    def gated_ffn(yq, w1, w2, w3):
-        yqs = share_q80(yq)  # one operand build for the gate and the up matmul
-        return matmul(maybe_qdq(act_fn(matmul(yqs, w1)) * matmul(yqs, w3)), w2)
-
-    def routed_ffn(x, rp, lm):
-        """``rp``: the routed layer's parameters, the expert stacks whole;
-        ``lm`` its index into them."""
-        n, k = b * t, cfg.n_active_experts
-        with jax.named_scope(SCOPE_FFN):
-            y = rms_norm(x, rp.rms_ffn, eps)
-            yq = maybe_qdq(y)
-            with jax.named_scope(SCOPE_ROUTER):
-                topw, topi = moe_router(cfg, y.reshape(n, -1), rp.gate, rp.bias)
-            with jax.named_scope(SCOPE_EXPERTS):
-                plan = route_plan(topi, live, cfg.n_experts)
-                rows = jnp.concatenate(
-                    [yq.reshape(n, -1), jnp.zeros((1, yq.shape[-1]), yq.dtype)]
-                )[plan.src]  # [P, dim], sorted by expert, a zero row where none
-                g = grouped_matmul(rows, rp.w1, lm, plan)
-                u = grouped_matmul(rows, rp.w3, lm, plan)
-                ys = grouped_matmul(maybe_qdq(act_fn(g) * u), rp.w2, lm, plan)
-                # a parked row's assignments point past the last row: zeros
-                ys = jnp.concatenate([ys, jnp.zeros((1, ys.shape[-1]), ys.dtype)])
-                routed = jnp.einsum("nk,nkd->nd", topw, ys[plan.pos])
-                out = routed.reshape(b, t, -1)
-            if rp.s1 is not None:
-                with jax.named_scope(SCOPE_SHARED_EXPERT):
-                    out = out + gated_ffn(yq, rp.s1, rp.s2, rp.s3)
-            x = x + maybe_qdq(out.astype(dtype))
-        return x, plan.slabs, plan.assignments
-
     n_dense = cfg.n_dense_layers if params.routed is not None else cfg.n_layers
     with jax.named_scope(SCOPE_LAYERS):
         c_all, r_all = cache.k, cache.v
@@ -399,9 +425,7 @@ def deepseek_forward_counted(
             ap = LatentAttnParams(*(_pick(leaf, l) for leaf in params.attn))
             x, c_all, r_all = attention(x, ap, l, c_all, r_all)
             dp = DenseFfnParams(*(_pick(leaf, l) for leaf in params.dense))
-            with jax.named_scope(SCOPE_FFN):
-                y = rms_norm(x, dp.rms_ffn, eps)
-                x = x + maybe_qdq(gated_ffn(maybe_qdq(y), dp.w1, dp.w2, dp.w3))
+            x = dense_ffn(cfg, ops, x, dp)
 
         counts = None
         if params.routed is not None:
@@ -413,7 +437,7 @@ def deepseek_forward_counted(
                 ap = LatentAttnParams(*(_pick(leaf, l) for leaf in params.attn))
                 x, c_all, r_all = attention(x, ap, l, c_all, r_all)
                 rp = RoutedFfnParams(*(_pick(leaf, lm) for leaf in params.routed))
-                x, s, a = routed_ffn(x, rp, lm)
+                x, s, a = routed_ffn(cfg, ops, x, rp, lm, live)
                 return (x, c_all, r_all, slabs + s, assigned + a), None
 
             zero = jnp.zeros((), jnp.int32)
@@ -441,7 +465,13 @@ def forward_counted(config: LlamaConfig):
     """The forward function of a configuration's block, every step family's
     one entry: ``f(config, params, tokens, positions, cache, **kw) ->
     (logits, cache, counts)``. What the configuration is decides it; a Llama
-    block counts nothing (None)."""
+    block counts nothing (None). A block with a recurrent state
+    (models/hybrid.py) also takes ``n_valid``: how many leading rows of each
+    lane are real."""
+    if config.layer_kinds:
+        from .hybrid import hybrid_forward_counted
+
+        return hybrid_forward_counted
     if config.latent_attention:
         return deepseek_forward_counted
     from .llama import llama_forward

@@ -416,6 +416,66 @@ def _dense_attention(qf, kf, vf, mask, scale):
     return jnp.einsum("btkgs,bskh->btkgh", probs, vf)
 
 
+def gqa_project(cfg: LlamaConfig, yq, wq, wk, wv, positions, rope_cos, rope_sin,
+                biases=(None, None, None), norms=None, project=matmul):
+    """A GQA layer's queries, keys and values from its normed input: the
+    three projections (``project``: ``matmul``, or a mesh's sliced matmul),
+    their biases where the family has them, the per-head norm of queries and
+    keys where it has that (``norms``: the two gains, applied over a head
+    BEFORE the rotation), the rotation, and the barrier that holds the cache
+    back until all three are done. One form for models/llama.py's scan and
+    models/hybrid.py's attention layers."""
+    b, t = positions.shape
+    n_heads, n_kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    q = _maybe_bias(project(yq, wq), biases[0]).reshape(b, t, n_heads, hd)
+    k = _maybe_bias(project(yq, wk), biases[1]).reshape(b, t, n_kv, hd)
+    v = _maybe_bias(project(yq, wv), biases[2]).reshape(b, t, n_kv, hd)
+    if norms is not None:
+        q = rms_norm(q, norms[0], cfg.norm_epsilon)
+        k = rms_norm(k, norms[1], cfg.norm_epsilon)
+
+    q = apply_rope(q, rope_cos, rope_sin, positions)
+    k = apply_rope(k, rope_cos, rope_sin, positions)
+    # all three projections finish before the cache is touched. Left
+    # to itself XLA schedules the wq kernel between the K plane's read
+    # and the scores that use it, the kernel claims the fast memory
+    # the plane could sit in, and the plane is parked in HBM instead:
+    # written and read once more, 6.5 ms against 2.9 a 7B decode step
+    # on a v5e (PERF.md section 6, PR 27). An identity on the values.
+    return jax.lax.optimization_barrier((q, k, v))
+
+
+def kv_append(k_all, v_all, at, k, v, row_major=None):
+    """Fresh rows scattered into the stacked cache at ``at`` (layer, lane or
+    page, position or slot), in place on the carry; ``mode="drop"``: a row
+    whose position lies past the context is written nowhere. ``row_major``
+    (one device only: GSPMD cannot partition the constraint and would gather
+    a sharded cache to apply it) keeps the stack in the row-major layout it
+    arrives and leaves in. Left free, XLA gives the carry of a loop whose
+    attention is wide (a 1024-token prefill chunk at 4 kv heads) a
+    kv-head-major layout and converts the WHOLE carry to the scatter's layout
+    and back in every layer (PERF.md section 6, PR 27)."""
+    k_all = k_all.at[at].set(_to_cache_dtype(k, k_all.dtype), mode="drop")
+    v_all = v_all.at[at].set(_to_cache_dtype(v, v_all.dtype), mode="drop")
+    if row_major is not None:
+        k_all = with_layout_constraint(k_all, row_major)
+        v_all = with_layout_constraint(v_all, row_major)
+    return k_all, v_all
+
+
+def dense_plane_attention(q, k_all, v_all, l, attn_mask, scale, n_kv: int):
+    """GQA attention over layer ``l``'s whole contiguous plane
+    (``[B, S, n_kv, hd]``, or with the two last axes merged, as
+    models/hybrid.py keeps 64-wide heads), read out of the carry AFTER the
+    append: the fresh rows are in it. q: ``[B, T, n_heads, hd]``."""
+    b, t, n_heads, hd = q.shape
+    qf = q.astype(jnp.float32).reshape(b, t, n_kv, n_heads // n_kv, hd)
+    k_cache = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
+    v_cache = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
+    planes = (c.astype(jnp.float32).reshape(b, -1, n_kv, hd) for c in (k_cache, v_cache))
+    return _dense_attention(qf, *planes, attn_mask, scale)
+
+
 def decode_attention_engages(cache, mesh, n_heads: int) -> bool:
     """Whether a step of one row a lane (``t == 1``) attends this cache in
     place through ``ops/pallas_attention.py``: a contiguous ``KVCache`` the
@@ -611,6 +671,8 @@ def llama_forward(
     def plane_attention(q, k_all, v_all, l, scale):
         """Attention over layer ``l``'s whole plane, sliced out of the carry:
         every width and layout the in-place kernel does not take."""
+        if not (paged or use_sp):
+            return dense_plane_attention(q, k_all, v_all, l, attn_mask, scale, n_kv)
         group = n_heads // n_kv
         qf = q.astype(jnp.float32).reshape(b, t, n_kv, group, hd)
         # layer l's plane, read out of the carry AFTER the append: the
@@ -629,14 +691,9 @@ def llama_forward(
                 qf, kf.astype(jnp.float32), vf.astype(jnp.float32),
                 attn_mask, scale,
             )
-        if use_sp:
-            from ..parallel.ring_attention import sp_attention
+        from ..parallel.ring_attention import sp_attention
 
-            return sp_attention(qf, k_cache, v_cache, positions, mesh, scale)
-        return _dense_attention(
-            qf, k_cache.astype(jnp.float32), v_cache.astype(jnp.float32),
-            attn_mask, scale,
-        )
+        return sp_attention(qf, k_cache, v_cache, positions, mesh, scale)
 
     def layer_step(carry, layer_in):
         # the stacked cache rides the carry ([L, ...]; module header, "How
@@ -650,19 +707,10 @@ def llama_forward(
         with jax.named_scope(SCOPE_QKV):
             y = rms_norm(x, lp.rms_att, eps)
             yq = share_q80(maybe_qdq(y))  # one operand build for wq/wk/wv
-            q = _maybe_bias(sliced_matmul(yq, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
-            k = _maybe_bias(sliced_matmul(yq, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
-            v = _maybe_bias(sliced_matmul(yq, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
-
-            q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
-            k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
-            # all three projections finish before the cache is touched. Left
-            # to itself XLA schedules the wq kernel between the K plane's read
-            # and the scores that use it, the kernel claims the fast memory
-            # the plane could sit in, and the plane is parked in HBM instead:
-            # written and read once more, 6.5 ms against 2.9 a 7B decode step
-            # on a v5e (PERF.md section 6, PR 27). An identity on the values.
-            q, k, v = jax.lax.optimization_barrier((q, k, v))
+            q, k, v = gqa_project(
+                h_cfg, yq, lp.wq, lp.wk, lp.wv, positions, params.rope_cos,
+                params.rope_sin, biases=(lp.bq, lp.bk, lp.bv), project=sliced_matmul,
+            )
 
         # KV append at per-lane positions (reference OP_SHIFT, scatter on
         # TPU). mode="drop" pins JAX's default out-of-bounds scatter
@@ -674,18 +722,8 @@ def llama_forward(
         # sentinel entries drop the write too.
         with jax.named_scope(SCOPE_KV_WRITE):
             at = (l, w_page, w_slot) if paged else (l, lane_idx, positions)
-            k_all = k_all.at[at].set(_to_cache_dtype(k, k_all.dtype), mode="drop")
-            v_all = v_all.at[at].set(_to_cache_dtype(v, v_all.dtype), mode="drop")
-            # the stack keeps the row-major layout it arrives and leaves in.
-            # Left free, XLA gives the carry of a loop whose attention is wide
-            # (a 1024-token prefill chunk at 4 kv heads) a kv-head-major
-            # layout and converts the WHOLE carry to the scatter's layout
-            # and back in every layer (PERF.md section 6, PR 27). One device
-            # only: GSPMD cannot partition the constraint and would gather
-            # a sharded cache to apply it
-            if mesh is None:
-                k_all = with_layout_constraint(k_all, row_major)
-                v_all = with_layout_constraint(v_all, row_major)
+            k_all, v_all = kv_append(
+                k_all, v_all, at, k, v, row_major if mesh is None else None)
 
         # GQA attention in f32 (reference multiheadAtt_F32, nn-cpu-ops.cpp:749-784)
         with jax.named_scope(SCOPE_ATTENTION):

@@ -157,19 +157,14 @@ _LATENT_NAME_MAP = {
 _LATENT_VECTORS = {"moe_bias", "rms_kv", "rms_att", "rms_ffn"}
 
 
-def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
-                              device_put_fn=None, quantized: bool = False):
-    """A latent-attention ``.m`` (``header.kv_lora_rank``) as the tree
-    models/deepseek.py runs: tensors stacked by layer (the experts by routed
-    layer and expert), matmul weights ``[d_in, d_out]``. ``quantized`` keeps
-    Q40 matmul tensors packed (``PackedQ40``; the experts ``Q40Experts``);
-    the router, its bias and the norms are float32 either way."""
-    from .deepseek import latent_params
-
-    config = LlamaConfig.from_header(header)
-    put = device_put_fn or (lambda name, x: jnp.asarray(x))
+def _load_stacked(path: str, header: ModelHeader, dtype, put, quantized: bool,
+                  place, f32_names) -> dict:
+    """A ``.m`` whose block tensors ``place(spec)`` files under ``(key,
+    index)`` (a layer, or a layer and an expert, counted as the block counts
+    them), stacked by index: key -> array, matmul weights ``[d_in, d_out]``.
+    ``quantized`` keeps Q40 matmul tensors packed (``PackedQ40``); the keys in
+    ``f32_names`` stay float32. With ``embedding``, ``rms_final``, ``wcls``."""
     cast = _cast_fn(dtype)
-    n_dense = config.n_dense_layers if config.n_experts else config.n_layers
     groups: dict = {}  # key -> {(layer[, expert]): array or (packed, scales)}
     top: dict = {}
     for spec, raw in iter_model_tensors(path, header):
@@ -180,18 +175,13 @@ def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16
                 x = pad_packed_d_out(*x)
         else:
             x = _decode_tensor(raw, spec.float_type, spec.shape)
-            x = x.T if matmul or spec.name == "block_moe_gate" else x
+            x = x.T if matmul or spec.name in ("block_moe_gate", "block_conv_taps") else x
         if not spec.name.startswith("block_"):
             top[spec.name] = x
             continue
-        key = _LATENT_NAME_MAP[spec.name]
-        if key in _LATENT_VECTORS:
+        key, index = place(spec)
+        if not matmul and spec.shape[0] == 1:  # a vector, stored [1, n]
             x = x.reshape(-1)
-        index = (spec.layer,)
-        if key in ("w1", "w2", "w3", "rms_ffn") and spec.expert < 0 and spec.layer < n_dense:
-            key = "dense_" + key
-        elif key not in ("wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv"):
-            index = (spec.layer - n_dense,) + ((spec.expert,) if spec.expert >= 0 else ())
         groups.setdefault(key, {})[index] = x
 
     def stack(name, entries: dict):
@@ -205,7 +195,7 @@ def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16
             )
         x = np.stack(picks)
         x = x.reshape(*shape, *x.shape[1:])
-        if name in _LATENT_VECTORS | {"moe_gate", "dense_rms_ffn"}:
+        if name in f32_names:
             return put(name, x).astype(jnp.float32)
         return put(name, cast(x)).astype(dtype)
 
@@ -217,8 +207,91 @@ def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16
         PackedQ40(packed=put("wcls", wcls[0]), scales=put("wcls.scales", wcls[1]))
         if isinstance(wcls, tuple) else put("wcls", cast(wcls)).astype(dtype)
     )
+    return t
+
+
+def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
+                              device_put_fn=None, quantized: bool = False):
+    """A latent-attention ``.m`` (``header.kv_lora_rank``) as the tree
+    models/deepseek.py runs: tensors stacked by layer (the experts by routed
+    layer and expert), matmul weights ``[d_in, d_out]``. ``quantized`` keeps
+    Q40 matmul tensors packed (``PackedQ40``; the experts ``Q40Experts``);
+    the router, its bias and the norms are float32 either way."""
+    from .deepseek import latent_params
+
+    config = LlamaConfig.from_header(header)
+    put = device_put_fn or (lambda name, x: jnp.asarray(x))
+    n_dense = config.n_dense_layers if config.n_experts else config.n_layers
+
+    def place(spec):
+        key = _LATENT_NAME_MAP[spec.name]
+        index = (spec.layer,)
+        if key in ("w1", "w2", "w3", "rms_ffn") and spec.expert < 0 and spec.layer < n_dense:
+            key = "dense_" + key
+        elif key not in ("wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv"):
+            index = (spec.layer - n_dense,) + ((spec.expert,) if spec.expert >= 0 else ())
+        return key, index
+
+    t = _load_stacked(path, header, dtype, put, quantized, place,
+                      _LATENT_VECTORS | {"moe_gate", "dense_rms_ffn"})
     cos, sin = _rope_cache(config)
     return config, latent_params(t, put("rope_cos", cos), put("rope_sin", sin), dtype, config)
+
+
+# the layer-pattern walk (formats/model_file._pattern_block_specs): tensor
+# name -> the key models/hybrid.hybrid_params takes it by
+_PATTERN_NAME_MAP = {
+    "block_matmul_conv_in": "conv_in",
+    "block_conv_taps": "conv_taps",
+    "block_matmul_conv_out": "conv_out",
+    "block_matmul_q": "wq",
+    "block_matmul_k": "wk",
+    "block_matmul_v": "wv",
+    "block_q_norm": "q_norm",
+    "block_k_norm": "k_norm",
+    "block_matmul_wo": "wo",
+    "block_matmul_w1": "w1",
+    "block_matmul_w2": "w2",
+    "block_matmul_w3": "w3",
+    "block_moe_gate": "moe_gate",
+    "block_moe_bias": "moe_bias",
+    "block_rms_norm_1": "rms_ffn",
+}
+_PATTERN_F32 = {"conv_taps", "q_norm", "k_norm", "moe_gate", "moe_bias", "rms_ffn",
+                "dense_rms_ffn", "attn_rms", "conv_rms"}
+
+
+def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
+                               device_put_fn=None, quantized: bool = False):
+    """A ``.m`` with a layer-kind list (``header.layer_kinds``) as the tree
+    models/hybrid.py runs: each kind's tensors stacked by the count of that
+    kind, the dense FFNs by layer, the routed ones by routed layer (and
+    expert). The taps, the per-head norm gains, the router, its bias and the
+    norms are float32 whatever ``dtype`` is."""
+    from ..formats.model_file import LayerKind
+    from .hybrid import hybrid_params
+
+    config = LlamaConfig.from_header(header)
+    put = device_put_fn or (lambda name, x: jnp.asarray(x))
+    n_dense = config.n_dense_layers if config.n_experts else config.n_layers
+    kinds = config.layer_kinds
+    # a layer's index into its kind's stack
+    nth = [sum(k == kinds[l] for k in kinds[:l]) for l in range(len(kinds))]
+
+    def place(spec):
+        l = spec.layer
+        if spec.name == "block_rms_norm_0":  # the mixer's norm, filed by kind
+            return ("conv_rms" if kinds[l] == LayerKind.CONV else "attn_rms"), (nth[l],)
+        key = _PATTERN_NAME_MAP[spec.name]
+        if key in ("w1", "w2", "w3", "rms_ffn", "moe_gate", "moe_bias"):
+            if spec.expert < 0 and l < n_dense:
+                return "dense_" + key, (l,)
+            return key, (l - n_dense,) + ((spec.expert,) if spec.expert >= 0 else ())
+        return key, (nth[l],)
+
+    t = _load_stacked(path, header, dtype, put, quantized, place, _PATTERN_F32)
+    cos, sin = _rope_cache(config)
+    return config, hybrid_params(t, put("rope_cos", cos), put("rope_sin", sin))
 
 
 def load_params_from_m(
@@ -236,6 +309,8 @@ def load_params_from_m(
     """
     if header.kv_lora_rank:
         return load_latent_params_from_m(path, header, dtype, device_put_fn)
+    if header.layer_kinds:
+        return load_pattern_params_from_m(path, header, dtype, device_put_fn)
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
 
@@ -308,6 +383,8 @@ def load_params_from_m_quantized(
     norms are always dense (gather/elementwise ops want plain arrays)."""
     if header.kv_lora_rank:
         return load_latent_params_from_m(path, header, dtype, device_put_fn, quantized=True)
+    if header.layer_kinds:
+        return load_pattern_params_from_m(path, header, dtype, device_put_fn, quantized=True)
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
     L, E = config.n_layers, config.n_experts

@@ -10,11 +10,19 @@ loop over experts reads them all. Here the (token, expert) assignments are
 sorted by expert and laid out in tiles of ``tm`` rows, each group starting on
 a tile (``route_plan``); the grid is one axis over tiles, and the weight
 blocks' index maps read the layer and the tile's expert id from scalar
-prefetch: block ``(l, e, 0, 0)`` of the stack, a whole slab. Tiles of one
-expert follow each other, and the pipeline issues no fetch for an index that
-did not change, so a slab is read once whatever its row count, and a slab no
-row chose is never addressed. Tiles past the last used one repeat its expert
+prefetch: block ``(l, e, 0, 0)`` of the stack, a whole slab, where a slab is
+one block of ``_plan_blocks`` (0.75 MiB at 2048 x 768). Tiles of one expert
+follow each other, and the pipeline issues no fetch for an index that did not
+change, so such a slab is read once whatever its row count, and a slab no row
+chose is never addressed. Tiles past the last used one repeat its expert
 (nothing is fetched) and compute nothing.
+
+A wider slab (1.5 MiB at 2048 x 1536) is walked in the reduction blocks
+``_plan_blocks`` gives: a second, inner grid axis ``k`` over them, the weight
+block ``(l, e, k, 0)``, the partial products summed in an f32 scratch
+(``_acc_epilogue``). A tile then fetches its slab once, block by block; a
+second tile of the same expert fetches it again (at eight rows a tile few
+experts have one), and the unused tiles stay on the last block fetched.
 
 Shapes are static: ``n_tiles`` is the most tiles any routing of ``n_assign``
 assignments over ``n_experts`` experts can need, so no token is ever dropped.
@@ -40,7 +48,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..quants.packed import Q40Experts, unpack_q40_slabs
 from .pallas_q40 import (
     VMEM_LIMIT_BYTES,
+    _acc_epilogue,
     _f16_bits_to_f32,
+    _final_writeback,
     _plan_blocks,
     _resolve_w_dtype,
     _sub_tiles,
@@ -112,23 +122,35 @@ def route_plan(topi: jnp.ndarray, live: jnp.ndarray, n_experts: int) -> RoutePla
     )
 
 
+def slab_blocks(d_in: int, d_out: int) -> int | None:
+    """Reduction blocks the kernel walks a ``d_in x d_out`` slab in (1: the
+    slab is one block and one fetch); None where it does not tile the shape:
+    a block spans the whole output width."""
+    plan = _plan_blocks(d_in, d_out)
+    if plan is None or plan[0] != d_out or (d_in // 2) % plan[1]:
+        return None
+    return (d_in // 2) // plan[1]
+
+
 def grouped_supports(w) -> bool:
-    """Whether the kernel takes this stack: ``Q40Experts`` whose slab is one
-    block (the whole reduction and the whole output width), so that a tile
-    is one grid step and a slab one fetch."""
+    """Whether the kernel takes this stack: ``Q40Experts`` whose slab is whole
+    blocks of ``_plan_blocks`` over the reduction, each the whole output
+    width."""
     if not isinstance(w, Q40Experts) or w.packed.ndim != 4:
         return False
-    plan = _plan_blocks(w.d_in, w.d_out)
-    return plan is not None and plan == (w.d_out, w.d_in // 2)
+    return slab_blocks(w.d_in, w.d_out) is not None
 
 
 def _grouped_kernel(meta_ref, x_lo_ref, x_hi_ref, bsum_ref, packed_ref,
-                    scales_ref, out_ref, *, w_dtype, sub_tiles):
-    """One tile: ``[tm, d_in]`` rows (as nibble halves) by one expert's slab.
-    meta: ``[layer, n_used, tile_expert...]``; layer and expert are spent in
-    the index maps."""
+                    scales_ref, out_ref, *acc_ref, w_dtype, sub_tiles, n_k):
+    """One tile by one reduction block: ``[tm, rows * 2]`` of the rows (as
+    nibble halves) by ``rows`` packed rows of one expert's slab (the whole
+    slab where ``n_k`` is 1). meta: ``[layer, n_used, tile_expert...]``;
+    layer and expert are spent in the index maps."""
     rows = packed_ref.shape[0]
     n_blk = rows // 16
+    k = pl.program_id(1) if n_k > 1 else 0
+    acc = acc_ref[0] if acc_ref else None
 
     @pl.when(pl.program_id(0) < meta_ref[1])
     def _():
@@ -146,18 +168,24 @@ def _grouped_kernel(meta_ref, x_lo_ref, x_hi_ref, bsum_ref, packed_ref,
             w_hi = w_hi.reshape(rows, t).astype(w_dtype)
             # folded -8 offset, as in ops/pallas_q40.py's slab kernel
             corr = jnp.dot(bsum, s, preferred_element_type=jnp.float32)
-            out_ref[:, off:off + t] = (
+            part = (
                 jnp.dot(x_lo, w_lo, preferred_element_type=jnp.float32)
                 + jnp.dot(x_hi, w_hi, preferred_element_type=jnp.float32)
                 - 8.0 * corr
             )
+            _acc_epilogue(part, off, t, k, n_k, out_ref, acc)
             off += t
+        _final_writeback(k, n_k, out_ref, acc)
 
 
-def tile_block_index(i, meta):
-    """The weight block of tile ``i``: ``(layer, expert, 0, 0)`` of the stack.
-    The tests read which slabs a plan addresses through this."""
-    return (meta[0], meta[2 + i], 0, 0)
+def tile_block_index(i, *k_meta, n_k: int = 1):
+    """The weight block of tile ``i`` (and reduction block ``k`` where a slab
+    has more than one): ``(layer, expert, k, 0)`` of the stack. An unused tile
+    stays on the last block, so nothing is fetched for it. The tests read
+    which slabs a plan addresses through this."""
+    meta = k_meta[-1]
+    k = jnp.where(i < meta[1], k_meta[0], n_k - 1) if len(k_meta) == 2 else 0
+    return (meta[0], meta[2 + i], k, 0)
 
 
 @partial(jax.jit, static_argnames=("interpret", "w_dtype"))
@@ -167,6 +195,8 @@ def _grouped_impl(x_rows, w: Q40Experts, layer, tile_expert, n_used,
     n_tiles = tile_expert.shape[0]
     tm = p_rows // n_tiles
     half, n_blk, d_out = d_in // 2, d_in // 32, w.d_out
+    n_k = slab_blocks(d_in, d_out)
+    rows, k_blk = half // n_k, n_blk // n_k  # packed rows, quant blocks a block
     xb = x_rows.astype(jnp.float32).reshape(p_rows, n_blk, 2, 16)
     x_lo = xb[:, :, 0, :].reshape(p_rows, half)
     x_hi = xb[:, :, 1, :].reshape(p_rows, half)
@@ -175,26 +205,41 @@ def _grouped_impl(x_rows, w: Q40Experts, layer, tile_expert, n_used,
         jnp.stack([jnp.asarray(layer, jnp.int32), jnp.asarray(n_used, jnp.int32)]),
         tile_expert.astype(jnp.int32),
     ])
-    row_spec = pl.BlockSpec((tm, half), lambda i, m: (i, 0))
+    if n_k == 1:
+        grid, scratch = (n_tiles,), []
+        row_spec = pl.BlockSpec((tm, half), lambda i, m: (i, 0))
+        bsum_spec = pl.BlockSpec((tm, n_blk), lambda i, m: (i, 0))
+        out_spec = pl.BlockSpec((tm, d_out), lambda i, m: (i, 0))
+        w_index = tile_block_index
+    else:
+        grid, scratch = (n_tiles, n_k), [pltpu.VMEM((tm, d_out), jnp.float32)]
+        row_spec = pl.BlockSpec((tm, rows), lambda i, k, m: (i, k))
+        # a block of the sums is narrower than a tile of lanes: laid out
+        # [n_k, P, k_blk], so that a block's last axis is the array's
+        bsum = jnp.transpose(bsum.reshape(p_rows, n_k, k_blk), (1, 0, 2))
+        bsum_spec = pl.BlockSpec((None, tm, k_blk), lambda i, k, m: (k, i, 0))
+        out_spec = pl.BlockSpec((tm, d_out), lambda i, k, m: (i, 0))
+        w_index = partial(tile_block_index, n_k=n_k)
     return pl.pallas_call(
-        partial(_grouped_kernel, w_dtype=w_dtype, sub_tiles=_sub_tiles(d_out)),
+        partial(_grouped_kernel, w_dtype=w_dtype, sub_tiles=_sub_tiles(d_out), n_k=n_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_tiles,),
+            grid=grid,
             in_specs=[
                 row_spec,
                 row_spec,
-                pl.BlockSpec((tm, n_blk), lambda i, m: (i, 0)),
-                # the tile's expert's slab, addressed inside the stack; the
-                # two leading block dimensions are squeezed
-                pl.BlockSpec((None, None, half, d_out), tile_block_index),
-                pl.BlockSpec((None, None, n_blk, d_out), tile_block_index),
+                bsum_spec,
+                # a block of the tile's expert's slab, addressed inside the
+                # stack; the two leading block dimensions are squeezed
+                pl.BlockSpec((None, None, rows, d_out), w_index),
+                pl.BlockSpec((None, None, k_blk, d_out), w_index),
             ],
-            out_specs=pl.BlockSpec((tm, d_out), lambda i, m: (i, 0)),
+            out_specs=out_spec,
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((p_rows, d_out), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,

@@ -48,6 +48,7 @@ from ..models.llama import (
     init_paged_kv_cache,
 )
 from ..models.deepseek import forward_counted, init_latent_cache
+from ..models.hybrid import init_hybrid_cache
 from ..ops import pallas_attention
 from ..telemetry.logs import log_event
 from ..telemetry.names import SCOPE_CARRY, SCOPE_HEAD, SCOPE_SAMPLER
@@ -233,6 +234,17 @@ class EngineStats:
     moe_slabs_read: int = 0
     moe_slabs_whole: int = 0
     moe_assignments: int = 0
+    # a model whose lanes carry a state overwritten in place beside the KV
+    # cache (models/hybrid.py; all 0 for every other model): the bytes of
+    # that state over all lanes (fixed at start-up, kept by reset());
+    # prompt chunks dispatched at position 0, which read zero state whatever
+    # the lane held (one an admission: nothing is cleared on the device);
+    # admissions that shared a long enough prefix with a resident lane and
+    # were prefilled whole all the same, because a copied lane would carry
+    # the source's state at ITS last position
+    recurrent_state_bytes: int = 0
+    state_zero_starts: int = 0
+    prefix_reuse_declined: int = 0
     # compile stability (analysis/jitcheck.py, ISSUE 15): XLA backend
     # compiles observed AFTER warmup_engine armed the recompile witness —
     # the machine-checked form of "one compiled program per (family,
@@ -272,6 +284,7 @@ class EngineStats:
             "grammar_lanes", "grammar_masked_steps",
             "attn_kv_rows_read", "attn_kv_rows_whole",
             "moe_slabs_read", "moe_slabs_whole", "moe_assignments",
+            "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
             "jit_compiles_after_warmup",
         ),
     }
@@ -310,6 +323,7 @@ class EngineStats:
             self.grammar_lanes = self.grammar_masked_steps = 0
             self.attn_kv_rows_read = self.attn_kv_rows_whole = 0
             self.moe_slabs_read = self.moe_slabs_whole = self.moe_assignments = 0
+            self.state_zero_starts = self.prefix_reuse_declined = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
             # compile stability since warmup, and a window reset hiding a
@@ -415,28 +429,46 @@ class InferenceEngine:
                 jnp.bfloat16 if jax.devices()[0].platform == "tpu" else jnp.float32
             )
         self.cache_dtype = cache_dtype
+        # What frames a lane as pages of a K/V pair of heads a layer, moves
+        # such pages between tiers or replicas, or partitions by head would
+        # run wrongly for a block that keeps another state on one device:
+        # refused by name, here, not in the middle of a step
+        unserved = None
         if config.latent_attention:
-            # models/deepseek.py: one latent row a token, on one device. What
-            # is framed as a K/V pair of heads, or partitions by head, would
-            # run wrongly: refused by name, here, not in the middle of a step
+            # models/deepseek.py: one latent row a token
+            unserved = (
+                f"a latent-attention model (kv_lora_rank {config.kv_lora_rank}) "
+                "keeps one latent cache row a token on one device",
+                "a page is framed as a K/V pair of heads",
+                "the block's latent cache and expert stacks have no sharding",
+            )
+        elif config.layer_kinds:
+            # models/hybrid.py: a stack a kind of layer, and (with a conv
+            # layer) a state overwritten in place
+            unserved = (
+                "a model with a per-layer pattern of mixers "
+                f"({config.n_conv_layers} conv, {config.n_attention_layers} "
+                "attention layers) keeps a stack a kind on one device",
+                "a lane's state is not pages of a K/V pair a layer",
+                "the block's state and expert stacks have no sharding",
+            )
+        if unserved is not None:
+            subject, why_pages, why_mesh = unserved
             refused = [
                 (paged_kv, "the paged KV pool (--paged-kv on), and with it "
-                           "prefix page sharing, KV-page transfer and a "
-                           "prefill/decode role: a page is framed as a K/V "
-                           "pair of heads"),
+                           "prefix page sharing, KV-page transfer, migration "
+                           f"tickets and a prefill/decode role: {why_pages}"),
                 (kv_host_bytes > 0, "the host KV tier (--kv-host-bytes): it "
                                     "swaps the pool's pages"),
-                (mesh is not None, "a mesh (--workers): the block's latent "
-                                   "cache and expert stacks have no sharding"),
+                (mesh is not None, f"a mesh (--workers): {why_mesh}"),
             ]
             for hit, what in refused:
                 if hit:
-                    raise ValueError(
-                        "a latent-attention model (kv_lora_rank "
-                        f"{config.kv_lora_rank}) keeps one latent cache row a "
-                        f"token on one device and does not serve {what}"
-                    )
-        init_contiguous = init_latent_cache if config.latent_attention else init_kv_cache
+                    raise ValueError(f"{subject} and does not serve {what}")
+        init_contiguous = (
+            init_hybrid_cache if config.layer_kinds
+            else init_latent_cache if config.latent_attention else init_kv_cache
+        )
         if paged_kv:
             if mesh is not None and (
                 dict(mesh.shape).get("dp", 1) > 1
@@ -516,12 +548,18 @@ class InferenceEngine:
             self.kvpool = None
             self.cache = init_contiguous(config, n_lanes, dtype=cache_dtype)
         self.stats = EngineStats()
+        # bytes of per-lane state overwritten in place (0: none)
+        self.lane_state_bytes = self.cache.conv.nbytes if config.recurrent_state else 0
+        self.stats.recurrent_state_bytes = self.lane_state_bytes
         # routed layers x experts: what a decode step adds to moe_slabs_whole
         # (0: no routed layers, and no counts ride the token readback)
-        self.moe_slabs_per_step = (
-            (config.n_layers - config.n_dense_layers) * config.n_experts
-            if config.latent_attention else 0
-        )
+        self.moe_slabs_per_step = config.n_routed_layers * config.n_experts
+        # a verify step advances a lane by rows it may reject, and a state
+        # overwritten in place cannot give them back: a model with one is
+        # served without speculation (the scheduler and warmup_engine ask;
+        # the verify entry points refuse)
+        if config.recurrent_state:
+            self.supports_speculative = self.supports_spec_pipelined = False
         # cache rows a block of the in-place decode attention fetches; None
         # where decode steps read whole planes (the scheduler's
         # attn_kv_rows_* counters ask)
@@ -586,7 +624,12 @@ class InferenceEngine:
         # what they always were)
         forward_c = forward_counted(cfg)
 
-        def forward(*a, **kw):
+        def forward(*a, n_valid=None, **kw):
+            # n_valid (a prefill chunk's real tokens) reaches a block whose
+            # lanes carry a state overwritten in place; no other has a use
+            # for it, and their programs are what they always were
+            if cfg.recurrent_state:
+                kw["n_valid"] = n_valid
             return forward_c(*a, **kw)[:2]
 
         def _token_rows(greedy, sampled, counts):
@@ -1115,24 +1158,29 @@ class InferenceEngine:
                 )
             else:
                 # slice this lane's cache to batch-of-1 (the splice of an
-                # admitted lane: out here, and back in below)
+                # admitted lane: out here, and back in below). Every leaf of
+                # a contiguous cache has its lanes on axis 1: K and V, the
+                # latent block's two leaves, a layer pattern's conv state
                 with jax.named_scope(SCOPE_CARRY):
-                    k_lane = jax.lax.dynamic_slice_in_dim(cache.k, lane, 1, axis=1)
-                    v_lane = jax.lax.dynamic_slice_in_dim(cache.v, lane, 1, axis=1)
+                    lane_in = jax.tree_util.tree_map(
+                        lambda a: jax.lax.dynamic_slice_in_dim(a, lane, 1, axis=1), cache)
                 logits, lane_cache = forward(
                     cfg,
                     params,
                     tokens[None, :],
                     positions[None, :],
-                    KVCache(k=k_lane, v=v_lane),
+                    lane_in,
+                    # the chunk's real tokens: a state that is overwritten in
+                    # place must not absorb the bucket's padded tail
+                    n_valid=n_tokens[None],
                     emulate_q80_activations=q80,
                     mesh=sp_mesh,
                     q80_sync=q80s,
                 )
                 with jax.named_scope(SCOPE_CARRY):
-                    k = jax.lax.dynamic_update_slice_in_dim(cache.k, lane_cache.k, lane, axis=1)
-                    v = jax.lax.dynamic_update_slice_in_dim(cache.v, lane_cache.v, lane, axis=1)
-                out_cache = KVCache(k=k, v=v)
+                    out_cache = jax.tree_util.tree_map(
+                        lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, lane, axis=1),
+                        cache, lane_cache)
             with jax.named_scope(SCOPE_HEAD):
                 last = jax.lax.dynamic_index_in_dim(logits[0], n_tokens - 1, axis=0, keepdims=False)
             # grammar: the boundary token — the request's FIRST generated
@@ -1243,11 +1291,10 @@ class InferenceEngine:
             # any query can read them (the chunked-prefill invariant). The
             # copy is an HBM-to-HBM move (~cache-lane bytes), orders of
             # magnitude cheaper than re-prefilling the prefix.
-            k_src = jax.lax.dynamic_index_in_dim(cache.k, src, axis=1, keepdims=False)
-            v_src = jax.lax.dynamic_index_in_dim(cache.v, src, axis=1, keepdims=False)
-            return KVCache(
-                k=cache.k.at[:, dst].set(k_src),
-                v=cache.v.at[:, dst].set(v_src),
+            return jax.tree_util.tree_map(
+                lambda a: a.at[:, dst].set(
+                    jax.lax.dynamic_index_in_dim(a, src, axis=1, keepdims=False)),
+                cache,
             )
 
         @partial(jax.jit, donate_argnums=(0,))
@@ -1385,13 +1432,21 @@ class InferenceEngine:
             attention = "xla_dense"
         if cfg.n_experts == 0:
             experts = None
-        elif not cfg.latent_attention:
+        elif not cfg.n_routed_layers:
             experts = "mixtral_moe_ffn"
         elif grouped_supports(self.params.routed.w1) and pallas_kernel_active():
             experts = "q40_grouped_kernel"
         else:
             experts = "xla_gathered_slabs"
-        return {"attention_path": attention, "expert_path": experts}
+        facts = {"attention_path": attention, "expert_path": experts}
+        if cfg.recurrent_state:
+            # what is declined for a state overwritten in place, said where
+            # the paths are said
+            facts.update(
+                recurrent_state_bytes=self.lane_state_bytes,
+                declined_for_recurrent_state=["prefix_reuse", "speculation"],
+            )
+        return facts
 
     # the scheduler gates response_format requests on this; pod roots
     # broadcast attach/detach as OP_GRAMMAR packets (RootControlEngine)
@@ -1530,6 +1585,7 @@ class InferenceEngine:
             self.stats.host_bytes_in += toks_np.nbytes
             self.stats.prefill_s += time.perf_counter() - t0
             self.stats.prefill_tokens += len(chunk)
+            self.stats.state_zero_starts += int(self.config.recurrent_state and start_pos == 0)
         return last, greedy, sampled
 
     def prefill(
@@ -1910,6 +1966,7 @@ class InferenceEngine:
             self.stats.fused_steps += 1
             self.stats.sync_bytes_total += self.stats.sync_bytes_per_decode
             self.stats.prefill_tokens += len(chunk)
+            self.stats.state_zero_starts += int(self.config.recurrent_state and p_start == 0)
             self.stats.fused_bucket_hist[bucket] = (
                 self.stats.fused_bucket_hist.get(bucket, 0) + 1
             )
@@ -2005,8 +2062,17 @@ class InferenceEngine:
     # drafts per speculative step (K = SPEC_DRAFT + 1 verified tokens)
     SPEC_DRAFT = SPEC_DRAFT
     # pod roots forward this via RootControlEngine.__getattr__ and broadcast
-    # verify steps as OP_DECODE_SPEC control packets
+    # verify steps as OP_DECODE_SPEC control packets (False on an engine
+    # whose model has a recurrent state: __init__)
     supports_speculative = True
+
+    def _check_speculative(self) -> None:
+        if not self.supports_speculative:
+            raise ValueError(
+                "a model with a recurrent per-lane state is served without "
+                "speculation: a verify step advances the state by rows it "
+                "may reject"
+            )
 
     def decode_spec(
         self,
@@ -2031,6 +2097,7 @@ class InferenceEngine:
         overshooting draft-slot KV writes are dropped by the cache scatter.
         Returns (step_logits [n, vocab] device array, emitted np[n, K],
         n_emit np[n])."""
+        self._check_speculative()
         n = self.n_lanes
         if temps is None:
             temps = np.zeros(n, np.float32)
@@ -2075,6 +2142,7 @@ class InferenceEngine:
         entry point (engine dispatch, fused variant, and the pod root's
         pre-broadcast validation) calls this, so a future layout change
         cannot silently diverge one copy from the others."""
+        self._check_speculative()
         shape = getattr(drafts, "shape", None)
         want = (self.n_lanes, self.SPEC_DRAFT + 1)
         if shape != want:
@@ -2371,6 +2439,12 @@ class InferenceEngine:
             raise RuntimeError(
                 "copy_lane is the contiguous layout's primitive; a paged "
                 "engine shares prefix pages by refcount via paged_admit"
+            )
+        if self.config.recurrent_state and src != dst:
+            raise RuntimeError(
+                "copy_lane would give the lane the source's recurrent state "
+                "at ITS last position, not at the shared prefix: a model "
+                "with such a state prefills every prompt whole"
             )
         if src == dst or prefix_len == 0:
             return  # nothing would move: skip the whole-cache rebuild
@@ -2689,7 +2763,9 @@ class InferenceEngine:
 
     def reset_lane(self, lane: int) -> None:
         """Nothing to clear on device: a fresh request's prefill rewrites the
-        lane's cache from position 0, and reads are masked to s <= pos."""
+        lane's cache from position 0, and reads are masked to s <= pos. A
+        state that is overwritten in place (models/hybrid.py) is not cleared
+        either: a step whose first position is 0 reads zeros in its place."""
 
 
 @contextlib.contextmanager
@@ -2882,8 +2958,10 @@ def warmup_engine(
                 reset_swap = getattr(engine, "reset_swap_stats", None)
                 if callable(reset_swap):
                     reset_swap()
-        if pool is None and n > 1:
-            # the contiguous prefix-reuse primitive (found by dlint's
+        recurrent = getattr(getattr(engine, "config", None), "recurrent_state", False)
+        if pool is None and n > 1 and not recurrent:
+            # the contiguous prefix-reuse primitive (never taken, and refused,
+            # for a model whose lanes carry a state overwritten in place) (found by dlint's
             # warmup-coverage at adoption): the first shared-prefix
             # admission used to pay the whole-lane-copy compile
             # mid-serving. Traced src/dst scalars: ONE program for any
@@ -2961,6 +3039,10 @@ def warmup_engine(
         # means DLLAMA_JITCHECK=1 will raise on any post-warmup compile
         jitcheck_strict=jitcheck.enabled(),
         seq_len=engine.config.seq_len,
+        # which attention and which expert path the warmed decode steps run
+        # (and what is declined for a recurrent state): said here too, so
+        # that a start without load_stack's runtime_device line says it
+        **(engine.path_facts() if callable(getattr(engine, "path_facts", None)) else {}),
         # the dequant path every warmed program baked in: the configured
         # knob plus (under auto) the per-site table resolutions recorded
         # while the families above traced

@@ -458,6 +458,11 @@ class ContinuousBatchingScheduler:
         self.host_sampling = host_sampling
         self.speculative = speculative
         self.prefix_min_tokens = prefix_min_tokens
+        # a per-lane state overwritten in place (models/hybrid.py): prefix
+        # reuse by lane copy and speculation are declined for such a model,
+        # by what its configuration is (the scheduler_start line says so)
+        self._recurrent_state = bool(
+            getattr(getattr(engine, "config", None), "recurrent_state", False))
         self.multi_step = multi_step
         self.pipelined = pipelined
         self.fused_prefill = fused_prefill
@@ -554,6 +559,12 @@ class ContinuousBatchingScheduler:
                 and getattr(self.engine, "pipeline_depth", 0) == 2
             ),
             prefix_min_tokens=self.prefix_min_tokens,
+            # a model whose lanes carry a state overwritten in place: a
+            # lane copy would carry the source's state at its last
+            # position and a verify step advance it by rejected rows, so
+            # both are declined whatever the two settings above say
+            **({"recurrent_state": True, "prefix_reuse": "declined",
+                "speculation": "declined"} if self._recurrent_state else {}),
             # compile stability: True once warmup_engine armed the
             # recompile witness (analysis/jitcheck.py) — the normal
             # make_scheduler order warms before start(), so a False here
@@ -1195,9 +1206,16 @@ class ContinuousBatchingScheduler:
                     best_lane, best_lcp = j, lcp
             best_lcp = min(best_lcp, len(tokens) - 1)  # >= 1 token to prefill
             if best_lcp >= self.prefix_min_tokens:
-                self.engine.copy_lane(best_lane, lane_idx,
-                                      prefix_len=best_lcp)
-                start = best_lcp
+                if self._recurrent_state:
+                    # a copied lane would carry the source's state at ITS
+                    # last position, not at the shared prefix: the prompt
+                    # is prefilled whole, and the decline is counted
+                    with self.engine.stats.lock:
+                        self.engine.stats.prefix_reuse_declined += 1
+                else:
+                    self.engine.copy_lane(best_lane, lane_idx,
+                                          prefix_len=best_lcp)
+                    start = best_lcp
         if start > 0:  # one accounting site for both layouts
             self.telemetry.on_prefix_hit(req, start)
             with self.engine.stats.lock:

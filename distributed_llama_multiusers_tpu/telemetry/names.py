@@ -50,6 +50,13 @@ SCOPE_EXPERTS = "dl.experts"      # under dl.ffn: sort by expert, grouped kernel
 SCOPE_SHARED_EXPERT = "dl.shared_expert"  # under dl.ffn: the always-on gated FFN
 LATENT_BLOCK_SCOPES = (SCOPE_KV_LATENT, SCOPE_ROUTER, SCOPE_EXPERTS, SCOPE_SHARED_EXPERT)
 
+# a block whose layers differ in their mixer (models/hybrid.py): a conv layer
+# takes dl.conv in the place of the four attention scopes; the FFN scopes are
+# the routed block's above
+SCOPE_CONV = "dl.conv"              # the short-conv mixer: norm, in-projection, gates, taps, out-projection
+SCOPE_CONV_STATE = "dl.conv_state"  # under dl.conv: the lane state's read and its commit
+CONV_MIXER_SCOPES = (SCOPE_CONV, SCOPE_CONV_STATE)
+
 # scopes inside the layer scan, in program order
 LAYER_SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_ATTN_OUT, SCOPE_FFN)
 # every scope whose time is the model's own arithmetic (no children)

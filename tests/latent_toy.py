@@ -1,7 +1,8 @@
 """A toy latent-attention model with a routed FFN for the tier-1 tests: the
 benchmark's deepseek_v3 family (its generator and its plain reference) at the
 rehearsal's size, loaded by path as ``tests/test_bench_family_seam.py`` loads
-the seam's cases."""
+the seam's cases. ``load("tiny_lfm2.json")`` is the lfm2_moe family's toy (conv
+and attention mixers in a pattern) the same way."""
 
 import json
 import os
@@ -11,15 +12,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "benchmarks")
 
 
-def load():
-    """(cfg, family, correct): the toy configuration file's keys, the family
-    module, and the harness's comparison."""
+def load(name: str = "tiny_latent.json"):
+    """(cfg, family, correct): the toy configuration file's keys (``name``
+    under the rehearsal's configs), its family module, and the harness's
+    comparison."""
     path = list(sys.path)
     sys.path[:0] = [p for p in (BENCH_DIR, ROOT) if p not in sys.path]
     try:
         from harness import cells, correct
 
-        with open(os.path.join(BENCH_DIR, "tests", "rehearsal", "configs", "tiny_latent.json")) as f:
+        with open(os.path.join(BENCH_DIR, "tests", "rehearsal", "configs", name)) as f:
             cfg = json.load(f)
         return cfg, cells.load_family(cfg), correct
     finally:

@@ -384,6 +384,33 @@ def test_grouped_expert_kernel_compiles_for_v5e(v5e, d_in, d_out, rows):
     assert f"= u8[{L},{E},{d_in // 2},{d_out}]" not in hlo.split("ENTRY")[0]
 
 
+@pytest.mark.parametrize("rows", [64, 1024], ids=["decode", "prefill1024"])
+@pytest.mark.parametrize("d_in,d_out", [(2048, 1536), (1536, 2048)])
+def test_grouped_expert_kernel_walks_a_wide_slab_for_v5e(v5e, d_in, d_out, rows):
+    """The grouped kernel at LFM2's expert width (4 experts a token of 64):
+    a 1.5 MiB slab walked in two reduction blocks, at 8 and at 128 rows a
+    tile; no slab, layer or stack leaves the stack."""
+    from distributed_llama_multiusers_tpu.ops import pallas_q40_grouped as pg
+    from distributed_llama_multiusers_tpu.quants.packed import Q40Experts
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    L, E, a = 3, 64, rows * 4
+    tm = pg.tile_rows(a, E)
+    assert tm == (8 if rows == 64 else 128)
+    n_tiles = pg.max_tiles(a, E, tm)
+    w = Q40Experts(sds((L, E, d_in // 2, d_out), jnp.uint8),
+                   sds((L, E, d_in // 32, d_out), jnp.int16))
+    assert pg.slab_blocks(d_in, d_out) == 2 and pg.grouped_supports(w)
+    hlo = pg._grouped_impl.lower(
+        sds((n_tiles * tm, d_in), jnp.bfloat16), w, sds((), jnp.int32),
+        sds((n_tiles,), jnp.int32), sds((), jnp.int32),
+        interpret=False, w_dtype=jnp.bfloat16,
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert f"= u8[{E},{d_in // 2},{d_out}]" not in hlo
+    assert f"= u8[{L},{E},{d_in // 2},{d_out}]" not in hlo.split("ENTRY")[0]
+
+
 def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, monkeypatch):
     """Three layers (one dense, two routed) of the benchmark's latent block at
     its published widths, one row a lane, the cache donated: the kernels are
@@ -443,6 +470,72 @@ def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, 
     # copy of it is what is looked for)
     assert not re.search(rf"= {stack}\S* copy\(", hlo)
     assert f"= u8[{E},1024,768]" not in hlo and f"= u8[{E},384,2048]" not in hlo
+
+
+def test_pattern_decode_forward_copies_no_cache_no_state_and_no_expert_stack_for_v5e(v5e, monkeypatch):
+    """Eight layers of the benchmark's layer-pattern block at its published
+    widths (two dense, then one whole period of routed layers and an odd tail
+    of two; six conv and two attention layers), one row a lane, the cache
+    donated: the kernels are there, the K/V stack and the conv state stack are
+    the results of their in-place writes alone, and no expert plane leaves its
+    stack. With the head's 64 as the K/V stack's last axis XLA gave the stack
+    another layout inside the loop and copied it whole, in and out (PR 35):
+    the stack keeps ``n_kv * head`` merged."""
+    import re
+
+    from distributed_llama_multiusers_tpu.models import hybrid
+    from distributed_llama_multiusers_tpu.models.config import LlamaConfig
+    from distributed_llama_multiusers_tpu.models.deepseek import DenseFfnParams, RoutedFfnParams
+    from distributed_llama_multiusers_tpu.quants.packed import Q40Experts
+
+    monkeypatch.setattr(linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas)
+    kinds = (1, 1, 0, 1, 1, 1, 0, 1)  # c c A c | c c A c
+    L, Ld, Lm, La, Lc, E, d, lanes, seq, vocab = 8, 2, 6, 2, 6, 64, 2048, 64, 512, 8192
+    cfg = LlamaConfig(
+        dim=d, hidden_dim=11776, n_layers=L, n_heads=32, n_kv_heads=8, vocab_size=vocab,
+        seq_len=seq, rope_theta=1e6, n_experts=E, n_active_experts=4, moe_hidden_dim=1536,
+        n_dense_layers=Ld, moe_score_func=1, moe_select_bias=1, layer_kinds=kinds,
+        conv_kernel=3, qk_norm=1)
+    assert hybrid.layer_periods(kinds[Ld:]) == (4, 1)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    q40 = lambda d_in, d_out, lead: PackedQ40(
+        packed=sds(lead + (d_in // 2, d_out), jnp.uint8),
+        scales=sds(lead + (d_in // 32, d_out), jnp.float16))
+    experts = lambda d_in, d_out: Q40Experts(
+        sds((Lm, E, d_in // 2, d_out), jnp.uint8), sds((Lm, E, d_in // 32, d_out), jnp.int16))
+    params = hybrid.HybridParams(
+        embedding=sds((vocab, d), jnp.bfloat16),
+        attn=hybrid.GqaParams(
+            wq=q40(d, d, (La,)), wk=q40(d, 512, (La,)), wv=q40(d, 512, (La,)), wo=q40(d, d, (La,)),
+            q_norm=sds((La, 64), jnp.float32), k_norm=sds((La, 64), jnp.float32),
+            rms=sds((La, d), jnp.float32)),
+        conv=hybrid.ConvParams(
+            w_in=q40(d, 3 * d, (Lc,)), taps=sds((Lc, 3, d), jnp.float32),
+            w_out=q40(d, d, (Lc,)), rms=sds((Lc, d), jnp.float32)),
+        dense=DenseFfnParams(
+            w1=q40(d, 11776, (Ld,)), w2=q40(11776, d, (Ld,)), w3=q40(d, 11776, (Ld,)),
+            rms_ffn=sds((Ld, d), jnp.float32)),
+        routed=RoutedFfnParams(
+            gate=sds((Lm, d, E), jnp.float32), bias=sds((Lm, E), jnp.float32),
+            w1=experts(d, 1536), w2=experts(1536, d), w3=experts(d, 1536),
+            s1=None, s2=None, s3=None, rms_ffn=sds((Lm, d), jnp.float32)),
+        rms_final=sds((d,), jnp.float32), wcls=q40(d, vocab, ()),
+        rope_cos=sds((seq, 32), jnp.float32), rope_sin=sds((seq, 32), jnp.float32))
+    cache = hybrid.HybridCache(
+        sds((La, lanes, seq, 512), jnp.bfloat16), sds((La, lanes, seq, 512), jnp.bfloat16),
+        sds((Lc, lanes, 2 * d), jnp.bfloat16))
+    tok = sds((lanes, 1), jnp.int32)
+    hlo = jax.jit(
+        lambda p, t, c: hybrid.hybrid_forward_counted(cfg, p, t, t, c)[:2], donate_argnums=(2,)
+    ).lower(params, tok, cache).compile().as_text()
+    # two dense layers: conv_in, conv_out, w1, w3, w2 each; the scan's body, one
+    # period: 3 conv layers of 2 + 1 attention layer of 4, and 4 x 3 grouped
+    # products; the tail: an attention and a conv layer, 2 x 3 grouped; the head
+    assert hlo.count("tpu_custom_call") == 10 + (6 + 4 + 12) + (4 + 2 + 6) + 1
+    for stack in (rf"bf16\[{La},{lanes},{seq},512\]", rf"bf16\[{Lc},{lanes},{2 * d}\]"):
+        assert not re.search(rf"= {stack}\S* copy\(", hlo), stack
+    assert f"= u8[{E},1024,1536]" not in hlo and f"= u8[{E},768,2048]" not in hlo
+    assert f"= u8[{Lm},{E},1024,1536]" not in hlo.split("ENTRY")[0]
 
 
 @pytest.mark.parametrize("lanes,vocab", [(32, 152064), (32, 128256), (16, 32768)])

@@ -118,5 +118,47 @@ def test_supports_says_which_stacks_the_kernel_takes(stack):
     packed, experts = stack
     assert g.grouped_supports(experts)
     assert not g.grouped_supports(packed)  # float16 scales: the XLA form
-    wide = Q40Experts(jnp.zeros((1, 2, 4096, 4096), jnp.uint8), jnp.zeros((1, 2, 256, 4096), jnp.int16))
-    assert not g.grouped_supports(wide)  # a slab of several blocks
+    wide = Q40Experts(jnp.zeros((1, 2, 1024, 16384), jnp.uint8), jnp.zeros((1, 2, 64, 16384), jnp.int16))
+    assert not g.grouped_supports(wide)  # a block narrower than the output width
+
+
+@pytest.mark.parametrize("d_in,d_out,blocks", [
+    (2048, 768, 1), (768, 2048, 1),    # Kanana's slabs: one block, one fetch, as before
+    (2048, 1536, 2), (1536, 2048, 2),  # LFM2's: 1.5 MiB, walked in two
+])
+def test_a_slab_is_planned_in_whole_reduction_blocks(d_in, d_out, blocks):
+    assert g.slab_blocks(d_in, d_out) == blocks
+    w = Q40Experts(jnp.zeros((1, 2, d_in // 2, d_out), jnp.uint8),
+                   jnp.zeros((1, 2, d_in // 32, d_out), jnp.int16))
+    assert g.grouped_supports(w)
+
+
+@pytest.mark.parametrize("d_in,d_out", [(2048, 1536), (1536, 2048)])
+@pytest.mark.parametrize("n,k", [(6, 2), (80, 4)])  # tiles of 8 rows, and of 128
+def test_a_slab_of_several_blocks_against_the_gathered_product(d_in, d_out, n, k):
+    n_experts = 4
+    pk = jax.random.bits(jax.random.PRNGKey(3), (2, n_experts, d_in // 2, d_out), jnp.uint8)
+    sc = jax.random.uniform(jax.random.PRNGKey(4), (2, n_experts, d_in // 32, d_out)) * 0.01 + 0.001
+    experts = Q40Experts.from_packed(PackedQ40(pk, sc.astype(jnp.float16)))
+    topi, live = _routing(n, n, k, n_experts=n_experts, parked=(1,))
+    plan = g.route_plan(topi, live, n_experts)
+    assert plan.src.shape[0] // plan.tile_expert.shape[0] == (8 if n == 6 else 128)
+    x = jax.random.normal(jax.random.PRNGKey(5), (n, d_in), jnp.float32)
+    rows = jnp.concatenate([x, jnp.zeros((1, d_in))])[plan.src]
+    got = np.asarray(g.q40_grouped_pallas(rows, experts, 1, plan, interpret=True))
+    want = np.asarray(g.grouped_matmul_xla(rows, experts, 1, plan))
+    tm = rows.shape[0] // plan.tile_expert.shape[0]
+    used = int(plan.n_used) * tm  # rows of unused tiles are not written
+    np.testing.assert_allclose(got[:used], want[:used], rtol=2e-5, atol=2e-4)
+
+
+def test_unused_tiles_of_a_walked_slab_stay_on_the_last_block():
+    topi, live = _routing(0, 6, 2)
+    plan = g.route_plan(topi, live, E)
+    meta = jnp.concatenate([jnp.asarray([1, plan.n_used], jnp.int32), plan.tile_expert])
+    n_used, n_tiles = int(plan.n_used), plan.tile_expert.shape[0]
+    assert n_used < n_tiles
+    blocks = [tuple(int(v) for v in g.tile_block_index(i, k, meta, n_k=2))
+              for i in range(n_tiles) for k in range(2)]
+    assert [b[2] for b in blocks[: 2 * n_used]] == [0, 1] * n_used
+    assert set(blocks[2 * n_used:]) == {blocks[2 * n_used - 1]}  # nothing more is fetched
