@@ -33,7 +33,9 @@ in bf16 at rank 512, not 1152. Both ride the layer scan's
 carry and are appended in place, then read, exactly as K and V are
 (``models/llama.py``, "How the cache moves"). Attention is XLA's dense path
 over the layer's latent plane sliced out of the carry; the in-place decode
-kernel (ops/pallas_attention.py) takes 128-wide heads and does not engage.
+kernel (ops/pallas_attention.py) takes K and V stacks of heads (128-wide on
+an axis of their own, or narrower ones merged into rows of whole tiles), not
+a latent row and its rope part, and does not engage.
 
 The FFN. The first ``n_dense_layers`` layers run a dense gated FFN, before
 the scan; the others are the scan: a router in float32 (sigmoid or softmax

@@ -46,8 +46,17 @@ run as one ``lax.scan`` over whole periods of their kinds (the shortest
 period the published list repeats with; its layers unrolled inside the body,
 each computing only its own mixer) and the odd tail unrolled after it. Every
 weight stack is closed over and read at its index (``deepseek._pick``).
-Attention is XLA's dense path over the layer's plane: the in-place decode
-kernel takes a ``KVCache`` of 128-wide heads.
+Attention at one row a lane (``t == 1``: every decode step and the decode half
+of every fused step) reads the K/V stack in place, as ``models/llama.py``'s
+block does ("How the cache is read at decode width"): the decode kernel
+(ops/pallas_attention.py) is handed the merged stack as it sits, the count of
+attention layers before this one and the lanes' positions, and fetches for
+each lane the row blocks up to its position. Wider steps, the CPU, a float32
+or f8 cache and a context that is not whole blocks take XLA's dense path over
+the layer's whole plane (``llama.decode_attention_engages`` decides, from the
+inputs). Dense, the five planes of a 64-lane step were 1.34 GB read and
+converted whatever the lanes held: 10.2 of a 28 ms decode step on a v5e
+(PERF.md section 6, PR 36).
 """
 
 from __future__ import annotations
@@ -59,7 +68,8 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout
 
 from ..formats.model_file import LayerKind
-from ..ops.linear import matmul
+from ..ops import pallas_attention
+from ..ops.linear import matmul, pallas_interpret
 from ..ops.norm import rms_norm
 from ..quants.packed import PackedQ40, Q40Experts
 from ..telemetry.names import (
@@ -82,7 +92,13 @@ from .deepseek import (
     ffn_ops,
     routed_ffn,
 )
-from .llama import _to_cache_dtype, dense_plane_attention, gqa_project, kv_append
+from .llama import (
+    _to_cache_dtype,
+    decode_attention_engages,
+    dense_plane_attention,
+    gqa_project,
+    kv_append,
+)
 
 
 class GqaParams(NamedTuple):
@@ -234,9 +250,13 @@ def hybrid_forward_counted(
     live = in_context.reshape(b * t)
     if n_valid is None:
         n_valid = jnp.sum(in_context, axis=1).astype(jnp.int32)
+    # one row a lane: the K/V stack is attended in place (module header)
+    in_place = t == 1 and decode_attention_engages(cache, mesh, cfg.n_heads, cfg.n_kv_heads)
     with jax.named_scope(SCOPE_ATTENTION):
         s_idx = jnp.arange(cfg.seq_len)
         attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
+        if in_place:
+            attn_plan = pallas_attention.lane_blocks(positions, cfg.seq_len)
     row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
     scale = 1.0 / float(cfg.head_size) ** 0.5
     from_zero = (positions[:, :1] == 0)[:, :, None]  # [B, 1, 1]
@@ -255,7 +275,15 @@ def hybrid_forward_counted(
                 k_all, v_all, (ai, lane_idx, positions),
                 k.reshape(b, t, cfg.kv_dim), v.reshape(b, t, cfg.kv_dim), row_major)
         with jax.named_scope(SCOPE_ATTENTION):
-            attn = dense_plane_attention(q, k_all, v_all, ai, attn_mask, scale, cfg.n_kv_heads)
+            if in_place:
+                # the kernel fetches each lane's rows [0, pos] of attention
+                # layer ai out of the carry, AFTER the append
+                attn = pallas_attention.decode_attention(
+                    q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, ai,
+                    attn_plan, scale, interpret=pallas_interpret())
+            else:
+                attn = dense_plane_attention(
+                    q, k_all, v_all, ai, attn_mask, scale, cfg.n_kv_heads)
             attn = attn.reshape(b, t, cfg.dim).astype(dtype)
         with jax.named_scope(SCOPE_ATTN_OUT):
             x = x + maybe_qdq(matmul(maybe_qdq(attn), ap.wo))

@@ -40,10 +40,12 @@ through a fused step's prefill half) fetches nothing and yields zeros. Read
 whole, the two planes were 4.3 GB a 7B decode step whatever the lanes held,
 two thirds of attention's time (PERF.md section 6, PR 32). The stack goes in
 as the carry holds it, ``(S, n_kv)`` merged into rows by a reshape that moves
-no byte. Everything else takes ``_dense_attention`` over the plane read out
-of the carry, as before: a prefill chunk or a verify step (a ``[T, S]`` score
-tile is the right shape there), the paged pool's gather, any mesh, a cache
-the kernel does not tile, the CPU. What the inputs are decides it
+no byte (``models/hybrid.py``'s stack of narrower heads, kept as rows of
+``n_kv * head``, goes in as it sits, with block-diagonal queries). Everything
+else takes ``_dense_attention`` over the plane read out of the carry, as
+before: a prefill chunk or a verify step (a ``[T, S]`` score tile is the right
+shape there), the paged pool's gather, any mesh, a cache the kernel does not
+tile, the CPU. What the inputs are decides it
 (``decode_attention_engages``); the two paths share no logic, the dense one
 being the plain form the kernel is tested against.
 
@@ -404,12 +406,14 @@ def _dense_attention(qf, kf, vf, mask, scale):
     multiheadAtt_F32, src/nn/nn-cpu-ops.cpp:749-784). qf: [B,T,K,G,H] f32;
     kf/vf: [B,S,K,H] f32; mask: [B,T,S].
 
-    Who still takes it: ``llama_forward`` wherever the in-place decode kernel
-    does not engage (a prefill chunk, the verify programs' ``K + 1`` rows,
-    the paged pool's gathered view, a mesh without sp, a cache dtype or head
-    size the kernel does not tile, the CPU), and training
-    (``train_layer_step_fn``). It is also what tests/test_pallas_attention.py
-    holds the kernel to."""
+    Who still takes it: ``llama_forward`` and ``models/hybrid.py``'s
+    attention layers wherever the in-place decode kernel does not engage (a
+    prefill chunk, the verify programs' ``K + 1`` rows, the paged pool's
+    gathered view, a mesh without sp, a float32 or f8 cache, a head size or
+    row width the kernel does not tile, a context that is not whole blocks,
+    the CPU), and training (``train_layer_step_fn``). It is also what
+    tests/test_pallas_attention.py holds the kernel to, at both forms of
+    stack."""
     scores = jnp.einsum("btkgh,bskh->btkgs", qf * scale, kf)
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -476,17 +480,22 @@ def dense_plane_attention(q, k_all, v_all, l, attn_mask, scale, n_kv: int):
     return _dense_attention(qf, *planes, attn_mask, scale)
 
 
-def decode_attention_engages(cache, mesh, n_heads: int) -> bool:
+def decode_attention_engages(cache, mesh, n_heads: int, n_kv: int | None = None) -> bool:
     """Whether a step of one row a lane (``t == 1``) attends this cache in
-    place through ``ops/pallas_attention.py``: a contiguous ``KVCache`` the
-    kernel tiles, on one device, where Pallas compiles (a TPU, or interpret
-    mode). What the inputs are decides it, as ``reads_q40_stack`` does for
-    the weights; the engine asks the same question for its counters."""
+    place through ``ops/pallas_attention.py``: contiguous K and V stacks of
+    one shape that the kernel tiles (a ``KVCache``'s, or a
+    ``models/hybrid.py`` ``HybridCache``'s merged ones; not the paged pool,
+    not a latent cache's two unlike leaves), on one device, where Pallas
+    compiles (a TPU, or interpret mode). ``n_kv``: the kv heads of a merged
+    row, which its shape does not say. What the inputs are decides it, as
+    ``reads_q40_stack`` does for the weights; both blocks' forwards and the
+    engine's counters ask this one question."""
     return (
-        isinstance(cache, KVCache)
+        not isinstance(cache, PagedKVCache)
+        and cache.k.shape == cache.v.shape
         and mesh is None
         and pallas_kernel_active()
-        and pallas_attention.supports(cache.k, n_heads)
+        and pallas_attention.supports(cache.k, n_heads, n_kv)
     )
 
 

@@ -8,21 +8,42 @@ masks afterwards. This kernel is handed the stack itself, the layer index and
 the lanes' positions as scalar-prefetch operands, and fetches for lane ``b``
 only the row blocks ``[0, ceil((pos_b + 1) / BLOCK_ROWS))`` of layer ``l``.
 
-How the stack goes in. The carry holds ``[L, lanes, S, n_kv, hd]`` with
-``(n_kv, hd)`` tiled; merging ``(S, n_kv)`` into one axis of ``S * n_kv``
-rows of ``hd`` leaves every byte where it is (a bitcast for XLA, checked in
-tests/test_chip_compile.py), and gives the kernel a plain ``[rows, hd]``
-matrix a block: row ``s * n_kv + h`` is key head ``h`` of position ``s``.
+How the stack goes in. Two forms of stack, told apart by rank, and neither
+is moved:
+
+- ``[L, lanes, S, n_kv, hd]`` with ``hd == 128`` (models/llama.py), ``(n_kv,
+  hd)`` tiled. Merging ``(S, n_kv)`` into one axis of ``S * n_kv`` rows of
+  ``hd`` leaves every byte where it is (a bitcast for XLA, checked in
+  tests/test_chip_compile.py), and gives the kernel a plain ``[rows, hd]``
+  matrix a block: row ``s * n_kv + h`` is key head ``h`` of position ``s``,
+  ``n_kv`` rows a position.
+- ``[A, lanes, S, n_kv * hd]`` (models/hybrid.py: heads narrower than a
+  128-lane tile, kept merged so that the last axis is whole tiles) goes in as
+  it sits: one row a POSITION, ``n_kv * hd`` wide, every kv head's values side
+  by side. A reshape to ``[S * n_kv, hd]`` rows is no bitcast under the chip's
+  tiling, and the kernel does not need it.
 
 What a block computes. All query heads of the lane against all rows of the
-block in one product, ``[heads, hd] x [rows, hd]^T``: a row belongs to ONE kv
-head, so the columns of the other heads' rows are masked out by a constant
-bias (``-inf`` where ``col % n_kv != head // group``). The MXU has the width
-to spare, and no head's rows have to be picked out of the tiles. Online
-softmax over the lane's blocks (running maximum, sum and value accumulator in
-f32 scratch); both products take the operands the dense path's einsums give
-the MXU at the TPU's default precision (bf16, accumulated in f32), with the
-scale applied to the f32 scores instead of to the queries, which stay exact.
+block in one product, ``[heads, width] x [rows, width]^T``, and each head
+meets its own kv head only:
+
+- 128-wide heads: a row belongs to ONE kv head, so the columns of the other
+  heads' rows are masked out by a constant bias (``-inf`` where ``col % n_kv
+  != head // group``).
+- merged rows: a row belongs to EVERY head, and the QUERIES are block
+  diagonal: head ``h``'s ``hd`` values sit in the columns of its kv head,
+  ``[(h // group) * hd, (h // group + 1) * hd)``, zeros elsewhere, so the
+  other heads' columns add exact zeros to the f32 sum. The value product gives
+  every head all ``n_kv * hd`` columns, of which it keeps its kv head's
+  (``_own_columns``: a select, no product); the softmax runs over ``[heads,
+  rows]`` scores, one column a position.
+
+The MXU has the width to spare, and no head's rows or columns have to be
+picked out of the tiles. Online softmax over the lane's blocks (running
+maximum, sum and value accumulator in f32 scratch); both products take the
+operands the dense path's einsums give the MXU at the TPU's default precision
+(bf16, accumulated in f32), with the scale applied to the f32 scores instead
+of to the queries, which stay exact.
 
 What is fetched. The grid is one axis over a work list built from the
 positions (``lane_blocks``): one item a block a lane holds, the lanes in
@@ -53,17 +74,29 @@ from jax.experimental.pallas import tpu as pltpu
 # (n_kv 8 / group 4 at 16 lanes, n_kv 4 / group 7 at 32): PERF.md section 6
 BLOCK_ROWS = 256
 HEAD_SIZE = 128  # one lane tile: the scratch statistics are [heads, 128]
+# the widest merged row taken: a K block of it is the 128-wide form's largest
+# compiled one (8 kv heads a position, 512 KB)
+MAX_ROW_WIDTH = 1024
 # an item's code in the work list (``lane_blocks``)
 FULL, LAST, FIRST, FINAL = 1, 2, 4, 8
 
 
-def supports(k_all, n_heads: int) -> bool:
-    """Whether the kernel takes this cache: a bf16 stack of ``HEAD_SIZE``-wide
-    heads whose context is whole blocks, query heads a multiple of kv heads."""
-    if k_all.ndim != 5 or k_all.dtype != jnp.bfloat16:
+def supports(k_all, n_heads: int, n_kv: int | None = None) -> bool:
+    """Whether the kernel takes this cache: a bf16 stack whose context is
+    whole blocks, query heads a multiple of kv heads, and either form of the
+    module header: ``[L, lanes, S, n_kv, HEAD_SIZE]``, or ``[A, lanes, S,
+    n_kv * hd]`` whose rows are whole 128-lane tiles of the caller's ``n_kv``
+    heads (a merged row does not say how many heads it holds)."""
+    if k_all.dtype != jnp.bfloat16 or k_all.ndim not in (4, 5):
         return False
-    _, _, seq_len, n_kv, hd = k_all.shape
-    return hd == HEAD_SIZE and seq_len % BLOCK_ROWS == 0 and n_heads % n_kv == 0
+    if k_all.ndim == 5:
+        n_kv = k_all.shape[3]
+        tiled = k_all.shape[4] == HEAD_SIZE
+    else:
+        width = k_all.shape[3]
+        tiled = bool(n_kv) and (
+            width % HEAD_SIZE == 0 and width % n_kv == 0 and width <= MAX_ROW_WIDTH)
+    return tiled and k_all.shape[2] % BLOCK_ROWS == 0 and n_heads % n_kv == 0
 
 
 def rows_read(positions, seq_len: int, block: int = BLOCK_ROWS) -> int:
@@ -120,11 +153,25 @@ def _head_bias(n_heads: int, heads_pad: int, n_kv: int, rows: int) -> np.ndarray
     return np.where(own, 0.0, -np.inf).astype(np.float32)
 
 
-def _decode_attention_kernel(layer_ref, plan_ref, q_ref, bias_ref, k_ref, v_ref,
-                             o_ref, m_ref, l_ref, acc_ref, *, scale, n_kv):
+def _own_columns(n_heads: int, heads_pad: int, n_kv: int) -> np.ndarray:
+    """``[heads_pad, n_kv, 1]``: whether kv head ``j``'s columns of a merged
+    row are query head ``h``'s; nowhere on the padding heads."""
+    head = np.arange(heads_pad)[:, None]
+    own = (np.arange(n_kv)[None, :] == head // (n_heads // n_kv)) & (head < n_heads)
+    return own[:, :, None]
+
+
+def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_pos):
     del layer_ref  # spent in the index maps
+    # the head bias goes in with 128-wide heads only (module header)
+    bias_ref = refs[0] if len(refs) == 7 else None
+    k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-6:]
     w = pl.program_id(0)
     block_index, pos, code = plan_ref[2, w], plan_ref[3, w], plan_ref[4, w]
+
+    def across(stat):
+        """A ``[heads, 128]`` statistic beside the ``[heads, width]`` values."""
+        return stat if stat.shape == acc_ref.shape else stat[:, :1]
 
     @pl.when(code & FIRST != 0)
     def _():
@@ -133,15 +180,17 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, bias_ref, k_ref, v_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def block(last: bool):
-        k, v = k_ref[...], v_ref[...]  # [rows, hd]: row s * n_kv + h
+        k, v = k_ref[...], v_ref[...]  # [rows, width]
         s = jax.lax.dot_general(
             q_ref[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale + bias_ref[...]  # [heads, rows]
+        ) * scale  # [heads, rows]
+        if bias_ref is not None:
+            s = s + bias_ref[...]
         if last:
             # rows above the lane's position: out of the scores, and out of
             # the values (0 x NaN is NaN: a stale row must not reach the sum)
-            limit = (pos - block_index * BLOCK_ROWS + 1) * n_kv
+            limit = (pos - block_index * BLOCK_ROWS + 1) * rows_per_pos
             col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col < limit, s, -jnp.inf)
             row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
@@ -153,7 +202,7 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, bias_ref, k_ref, v_ref,
         alpha = jnp.exp(m_prev - m_safe)
         p = jnp.exp(s - m_safe[:, :1])
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+        acc_ref[...] = across(alpha) * acc_ref[...] + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
@@ -163,7 +212,7 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, bias_ref, k_ref, v_ref,
 
     @pl.when(code & FINAL != 0)
     def _():
-        l = l_ref[...]
+        l = across(l_ref[...])
         # a parked lane summed nothing: zeros, and no division by its sum
         o_ref[...] = jnp.where(l > 0.0, acc_ref[...] / l, 0.0)
 
@@ -173,46 +222,57 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
     """One query row a lane against layer ``layer`` of the stacked cache.
 
     q ``[lanes, n_heads, hd]`` (head ``h * group + g`` reads kv head ``h``);
-    ``k_all`` / ``v_all`` ``[L, lanes, S, n_kv, hd]`` as the layer scan
-    carries them, the lanes' fresh rows already appended; ``work`` from
-    ``lane_blocks``. Returns ``[lanes, n_heads, hd]`` float32; a lane's
-    result depends on that lane's rows ``[0, pos]`` alone."""
-    n_layers, lanes, seq_len, n_kv, hd = k_all.shape
-    n_heads = q.shape[1]
+    ``k_all`` / ``v_all`` ``[L, lanes, S, n_kv, hd]`` or ``[A, lanes, S, n_kv *
+    hd]`` (``supports``) as the layer loop carries them, the lanes' fresh rows
+    already appended; ``work`` from ``lane_blocks``. Returns ``[lanes,
+    n_heads, hd]`` float32; a lane's result depends on that lane's rows
+    ``[0, pos]`` alone."""
+    n_heads, hd = q.shape[1:]
+    n_layers, lanes, seq_len = k_all.shape[:3]
+    merged = k_all.ndim == 4  # one row a position, every kv head in it
+    width = k_all.shape[-1]
+    n_kv = width // hd if merged else k_all.shape[3]
+    rows_per_pos = 1 if merged else n_kv
     n_items, plan = work
     heads_pad = -(-n_heads // 16) * 16  # whole bf16 sublane tiles
-    rows = BLOCK_ROWS * n_kv
+    rows = BLOCK_ROWS * rows_per_pos
     q = jnp.pad(q.astype(k_all.dtype), ((0, 0), (0, heads_pad - n_heads), (0, 0)))
-    flat = (n_layers, lanes, seq_len * n_kv, hd)  # (S, n_kv) merged: a bitcast
+    if merged:
+        # block-diagonal queries: a head's values in its kv head's columns
+        own = _own_columns(n_heads, heads_pad, n_kv)
+        q = jnp.where(own, q[:, :, None, :], 0).reshape(lanes, heads_pad, width)
+    # (S, n_kv) of 128-wide heads merged: a bitcast; a merged stack as it is
+    flat = (n_layers, lanes, seq_len * rows_per_pos, width)
 
     kv_spec = pl.BlockSpec(
-        (None, None, rows, hd),
+        (None, None, rows, width),
         lambda w, layer_ref, plan_ref: (layer_ref[0], plan_ref[1, w], plan_ref[2, w], 0),
     )
     lane_spec = pl.BlockSpec(
-        (None, heads_pad, hd), lambda w, layer_ref, plan_ref: (plan_ref[0, w], 0, 0)
+        (None, heads_pad, width), lambda w, layer_ref, plan_ref: (plan_ref[0, w], 0, 0)
     )
+    bias = () if merged else (_head_bias(n_heads, heads_pad, n_kv, rows),)
+    bias_spec = [pl.BlockSpec((heads_pad, rows), lambda w, *_: (0, 0))] * len(bias)
     out = pl.pallas_call(
-        partial(_decode_attention_kernel, scale=scale, n_kv=n_kv),
+        partial(_decode_attention_kernel, scale=scale, rows_per_pos=rows_per_pos),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # the layer index and the work list
             grid=(n_items,),
-            in_specs=[
-                lane_spec,
-                pl.BlockSpec((heads_pad, rows), lambda w, *_: (0, 0)),
-                kv_spec,
-                kv_spec,
-            ],
+            in_specs=[lane_spec, *bias_spec, kv_spec, kv_spec],
             out_specs=lane_spec,
-            scratch_shapes=[pltpu.VMEM((heads_pad, hd), jnp.float32)] * 3,
+            scratch_shapes=[pltpu.VMEM((heads_pad, HEAD_SIZE), jnp.float32)] * 2
+            + [pltpu.VMEM((heads_pad, width), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, width), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         name="decode_attention",
         interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), plan, q,
-      _head_bias(n_heads, heads_pad, n_kv, rows),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan, q, *bias,
       k_all.reshape(flat), v_all.reshape(flat))
+    if merged:
+        # a head keeps its kv head's columns of the value product: a select
+        # and a sum with exact zeros, no product
+        out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, hd), 0.0).sum(axis=2)
     return out[:, :n_heads]

@@ -565,7 +565,9 @@ class InferenceEngine:
         # attn_kv_rows_* counters ask)
         self.decode_attention_block = (
             pallas_attention.BLOCK_ROWS
-            if decode_attention_engages(self.cache, mesh, config.n_heads)
+            # (latent rows are no K/V heads: that block's forward never asks)
+            if not config.latent_attention and decode_attention_engages(
+                self.cache, mesh, config.n_heads, config.n_kv_heads)
             else None
         )
         # async decode pipeline: bounded ring of dispatched-but-unconsumed

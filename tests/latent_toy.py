@@ -28,6 +28,21 @@ def load(name: str = "tiny_latent.json"):
         sys.path[:] = path
 
 
+def wide_lfm2():
+    """(cfg, family, correct) of the lfm2_moe toy at a shape the in-place
+    decode attention takes (ops/pallas_attention.py ``supports``): 4 heads of
+    64 on 2 kv heads, so a merged K/V row is one whole 128-lane tile, and a
+    context of two 256-row blocks, with sample prompts on both sides of the
+    block's edge. Served in bfloat16 (``engine(..., dtype=jnp.bfloat16)``)."""
+    import copy
+
+    cfg, family, correct = load("tiny_lfm2.json")
+    cfg = copy.deepcopy(cfg)
+    cfg.update(hidden_size=256, max_position_embeddings=512)
+    cfg["correctness"]["prompt_tokens"] = [20, 250, 300]
+    return cfg, family, correct
+
+
 def engine(family, cfg, seed=11, dtype=None, lanes=8, **kw):
     """(engine, tensors) as the benchmark builds them, at ``dtype``
     (activations and cache; float32 by default)."""

@@ -102,3 +102,56 @@ def test_a_multi_step_dispatch_counts_each_of_its_steps():
     stats = engine.stats.snapshot()
     assert stats["attn_kv_rows_whole"] == 3 * 3 * SEQ
     assert stats["attn_kv_rows_read"] == (BLOCK + 2 * BLOCK + 2 * BLOCK) + 3 * BLOCK
+
+
+@pytest.mark.parametrize("pallas", [True, False], ids=["in_place", "pallas_off"])
+def test_a_layer_pattern_engine_counts_what_its_decode_attention_fetches(pallas):
+    """A real engine on the lfm2_moe toy at a shape the kernel takes (bf16
+    rows of whole tiles, two blocks of context), served synchronously so that
+    every step's positions are the host's own: with Pallas on (interpret mode)
+    the engine says `pallas_in_place` and the counter is the hand count over
+    those positions, under the whole planes; with Pallas off it says
+    `xla_dense` and counts whole planes."""
+    import jax.numpy as jnp
+
+    import latent_toy
+    from distributed_llama_multiusers_tpu.ops import linear
+
+    cfg, family, _ = latent_toy.wide_lfm2()
+    seq, lanes = cfg["max_position_embeddings"], 4
+    linear.set_pallas_interpret(pallas)
+    try:
+        engine, _ = latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16, lanes=lanes,
+                                      pipeline_depth=0, prefill_buckets=(64,))
+        facts = engine.path_facts()
+        assert facts["attention_path"] == ("pallas_in_place" if pallas else "xla_dense")
+        assert engine.decode_attention_block == (BLOCK if pallas else None)
+        seen, real = [], engine.decode
+        engine.decode = lambda tokens, positions, *a, **kw: (
+            seen.append(np.asarray(positions).copy()) or real(tokens, positions, *a, **kw))
+        sched = ContinuousBatchingScheduler(
+            engine, StubStreamTokenizer(cfg["vocab_size"], prompt_tokens=512),
+            speculative=False, prefix_min_tokens=0, multi_step=0)
+        # one lane crosses the block's edge while it generates, one stays low,
+        # two stay parked
+        reqs = [Request(prompt="a" * n, max_tokens=m, temperature=0.0)
+                for n, m in ((250, 10), (30, 6))]
+        sched.start()
+        try:
+            for r in reqs:
+                sched.submit(r)
+            for r in reqs:
+                r.future.result(timeout=300)
+                assert r.error is None, r.error
+        finally:
+            sched.stop()
+    finally:
+        linear.set_pallas_interpret(False)
+    stats = engine.stats.snapshot()
+    assert len(seen) >= 9 and any(BLOCK <= p < seq for at in seen for p in at)
+    assert stats["attn_kv_rows_whole"] == len(seen) * lanes * seq
+    hand = sum(BLOCK * (p // BLOCK + 1) for at in seen for p in at if 0 <= p < seq)
+    if pallas:
+        assert stats["attn_kv_rows_read"] == hand < stats["attn_kv_rows_whole"] // 2
+    else:
+        assert stats["attn_kv_rows_read"] == stats["attn_kv_rows_whole"]

@@ -278,22 +278,27 @@ def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
         assert sliced == planes, sliced
 
 
-def _cache_sized_results(hlo: str, L, lanes, seq, n_kv) -> list[str]:
-    """Instructions that MAKE an array of the size of a K or V plane or of
-    the stack, in the carry's shape or with (S, n_kv) merged: a slice, a
+def _results_of_shape(hlo: str, shape: str) -> list[str]:
+    """Instructions that MAKE an array of ``shape`` (a regex): a slice, a
     fusion, a copy or a conversion, in any layout. The in-place appends (a
     scatter fusion whose operand is the stack it returns) are what a decode
     step is allowed; parameters, tuple elements and bitcasts move nothing."""
     import re
 
-    lead = rf"(?:{L},|1,)?{lanes},"
-    shape = rf"(?:bf16|f32)\[{lead}(?:{seq},{n_kv}|{seq * n_kv}),128\]"
     made = re.findall(
         rf"^\s*(?:ROOT )?(\S+) = {shape}\S* "
         r"(fusion|dynamic-slice|slice|copy|convert|transpose|copy-start)\((.*)$",
         hlo, flags=re.M)
     return [f"{name} = {op}" for name, op, rest in made
             if not (op == "fusion" and "dl.kv_write" in rest)]
+
+
+def _cache_sized_results(hlo: str, L, lanes, seq, n_kv) -> list[str]:
+    """What makes an array of the size of a K or V plane or of the stack, in
+    the carry's shape or with (S, n_kv) merged (``_results_of_shape``)."""
+    lead = rf"(?:{L},|1,)?{lanes},"
+    return _results_of_shape(
+        hlo, rf"(?:bf16|f32)\[{lead}(?:{seq},{n_kv}|{seq * n_kv}),128\]")
 
 
 # Decode attention in place (PR 32, ops/pallas_attention.py): (lanes, n_heads,
@@ -472,31 +477,27 @@ def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, 
     assert f"= u8[{E},1024,768]" not in hlo and f"= u8[{E},384,2048]" not in hlo
 
 
-def test_pattern_decode_forward_copies_no_cache_no_state_and_no_expert_stack_for_v5e(v5e, monkeypatch):
-    """Eight layers of the benchmark's layer-pattern block at its published
-    widths (two dense, then one whole period of routed layers and an odd tail
-    of two; six conv and two attention layers), one row a lane, the cache
-    donated: the kernels are there, the K/V stack and the conv state stack are
-    the results of their in-place writes alone, and no expert plane leaves its
-    stack. With the head's 64 as the K/V stack's last axis XLA gave the stack
-    another layout inside the loop and copied it whole, in and out (PR 35):
-    the stack keeps ``n_kv * head`` merged."""
-    import re
-
+def _pattern_decode_hlo(v5e, monkeypatch, periods: int, seq: int):
+    """The optimized HLO of the benchmark's layer-pattern block at its
+    published widths, ``periods`` times ``c c A c`` (two dense layers, then
+    whole periods ``A c c c`` of routed layers in the scan and an odd tail
+    ``A c``), one row a lane at 64 lanes, the cache donated; and its
+    dimensions."""
     from distributed_llama_multiusers_tpu.models import hybrid
     from distributed_llama_multiusers_tpu.models.config import LlamaConfig
     from distributed_llama_multiusers_tpu.models.deepseek import DenseFfnParams, RoutedFfnParams
     from distributed_llama_multiusers_tpu.quants.packed import Q40Experts
 
     monkeypatch.setattr(linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas)
-    kinds = (1, 1, 0, 1, 1, 1, 0, 1)  # c c A c | c c A c
-    L, Ld, Lm, La, Lc, E, d, lanes, seq, vocab = 8, 2, 6, 2, 6, 64, 2048, 64, 512, 8192
+    kinds = (1, 1, 0, 1) * periods
+    L, Ld, La, E, d, lanes, vocab = 4 * periods, 2, periods, 64, 2048, 64, 8192
+    Lm, Lc = L - Ld, L - La
     cfg = LlamaConfig(
         dim=d, hidden_dim=11776, n_layers=L, n_heads=32, n_kv_heads=8, vocab_size=vocab,
         seq_len=seq, rope_theta=1e6, n_experts=E, n_active_experts=4, moe_hidden_dim=1536,
         n_dense_layers=Ld, moe_score_func=1, moe_select_bias=1, layer_kinds=kinds,
         conv_kernel=3, qk_norm=1)
-    assert hybrid.layer_periods(kinds[Ld:]) == (4, 1)
+    assert hybrid.layer_periods(kinds[Ld:]) == (4, periods - 1)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
     q40 = lambda d_in, d_out, lead: PackedQ40(
         packed=sds(lead + (d_in // 2, d_out), jnp.uint8),
@@ -528,14 +529,69 @@ def test_pattern_decode_forward_copies_no_cache_no_state_and_no_expert_stack_for
     hlo = jax.jit(
         lambda p, t, c: hybrid.hybrid_forward_counted(cfg, p, t, t, c)[:2], donate_argnums=(2,)
     ).lower(params, tok, cache).compile().as_text()
+    return hlo, dict(La=La, Lc=Lc, Lm=Lm, E=E, d=d, lanes=lanes, seq=seq)
+
+
+def test_pattern_decode_forward_copies_no_cache_no_state_and_no_expert_stack_for_v5e(v5e, monkeypatch):
+    """Eight layers of the benchmark's layer-pattern block at its published
+    widths (two dense, then one whole period of routed layers and an odd tail
+    of two; six conv and two attention layers), one row a lane, the cache
+    donated: the kernels are there, the K/V stack and the conv state stack are
+    the results of their in-place writes alone, and no expert plane leaves its
+    stack. With the head's 64 as the K/V stack's last axis XLA gave the stack
+    another layout inside the loop and copied it whole, in and out (PR 35):
+    the stack keeps ``n_kv * head`` merged."""
+    import re
+
+    hlo, dims = _pattern_decode_hlo(v5e, monkeypatch, periods=2, seq=512)
+    La, Lc, Lm, E, d, lanes, seq = (dims[k] for k in ("La", "Lc", "Lm", "E", "d", "lanes", "seq"))
     # two dense layers: conv_in, conv_out, w1, w3, w2 each; the scan's body, one
-    # period: 3 conv layers of 2 + 1 attention layer of 4, and 4 x 3 grouped
-    # products; the tail: an attention and a conv layer, 2 x 3 grouped; the head
-    assert hlo.count("tpu_custom_call") == 10 + (6 + 4 + 12) + (4 + 2 + 6) + 1
+    # period: 3 conv layers of 2 + 1 attention layer of 4 and its decode
+    # attention (PR 36), and 4 x 3 grouped products; the tail: an attention (4
+    # and its decode attention) and a conv layer, 2 x 3 grouped; the head
+    assert hlo.count("tpu_custom_call") == 10 + (6 + 5 + 12) + (5 + 2 + 6) + 1
     for stack in (rf"bf16\[{La},{lanes},{seq},512\]", rf"bf16\[{Lc},{lanes},{2 * d}\]"):
         assert not re.search(rf"= {stack}\S* copy\(", hlo), stack
     assert f"= u8[{E},1024,1536]" not in hlo and f"= u8[{E},768,2048]" not in hlo
     assert f"= u8[{Lm},{E},1024,1536]" not in hlo.split("ENTRY")[0]
+
+
+def _merged_plane_results(hlo: str, La: int, lanes: int, seq: int, n_kv: int, hd: int) -> list[str]:
+    """What makes an array of the size of one K or V plane of the merged
+    stack (in the carry's shape or split by head, bf16 or float32) or of the
+    stack itself (``_results_of_shape``)."""
+    lead = rf"(?:{La},|1,)?{lanes},{seq},"
+    return _results_of_shape(
+        hlo, rf"(?:bf16|f32)\[{lead}(?:{n_kv * hd}|{n_kv},{hd}|{n_kv},1,{hd})\]")
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel_reads_in_place", "control_plane_reads"])
+def test_pattern_decode_forward_reads_no_kv_plane_for_v5e(v5e, monkeypatch, in_place):
+    """The layer-pattern block at the benchmark's depth and cache (20 layers,
+    five of them attention; 64 lanes x 2048 positions x 8 heads of 64, merged
+    to rows of 512): the optimized decode forward holds a ``decode_attention``
+    kernel for each attention instance (the scan's period body and the odd
+    tail) and nothing has a ``[64, 2048, 512]`` plane, a float32 plane or the
+    ``[5, 64, 2048, 512]`` stack as its result but the in-place appends. The
+    control patches the predicate off, as the program was before PR 36, and
+    shows what the check looks for: each instance's K and V planes read out
+    of the stack and converted."""
+    import re
+
+    from distributed_llama_multiusers_tpu.models import hybrid
+
+    if not in_place:
+        monkeypatch.setattr(hybrid, "decode_attention_engages", lambda *a: False)
+    hlo, dims = _pattern_decode_hlo(v5e, monkeypatch, periods=5, seq=2048)
+    kernels = len(re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call".*decode_attention', hlo))
+    made = _merged_plane_results(hlo, dims["La"], dims["lanes"], dims["seq"], 8, 64)
+    if in_place:
+        assert kernels == 2 and made == [], (kernels, made)
+    else:
+        assert kernels == 0
+        reads = [m for m in made if "dynamic-slice" in m or "fusion" in m or "convert" in m]
+        assert len(reads) >= 4, made  # K's plane and V's, in the body and in the tail
 
 
 @pytest.mark.parametrize("lanes,vocab", [(32, 152064), (32, 128256), (16, 32768)])

@@ -172,6 +172,45 @@ def test_kernels_in_interpret_mode_agree_with_the_reference():
     assert (r["route_greedy_gap"], r["route_nucleus_excess"], r["route_kv_rel_err"]) == (0, 0, 0)
 
 
+def test_decode_steps_attend_the_merged_stack_in_place_where_the_kernel_takes_it():
+    """A bfloat16 cache whose rows are whole 128-lane tiles and whose context
+    is whole blocks, Pallas on (interpret mode): the engine says so, and the
+    tokens of a pipelined chain, of a fused admission's decode half and of the
+    synchronous replay are the same, lanes on both sides of a block's edge."""
+    from distributed_llama_multiusers_tpu.ops import pallas_attention
+
+    cfg, family, correct = latent_toy.wide_lfm2()
+    linear.set_pallas_interpret(True)
+    try:
+        e, tensors = latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16)
+        assert e.cache.k.shape == (2, 8, 512, 128) and e.cache.k.dtype == jnp.bfloat16
+        assert e.path_facts()["attention_path"] == "pallas_in_place"
+        assert e.decode_attention_block == pallas_attention.BLOCK_ROWS
+        r = correct.compare(family, cfg, tensors, e, 5)
+    finally:
+        linear.set_pallas_interpret(False)
+    assert (r["route_greedy_gap"], r["route_nucleus_excess"], r["route_kv_rel_err"]) == (0, 0, 0)
+    assert r["route_tokens"] >= 20 and r["route_token_mismatches"] == 0
+    # bfloat16 against the float32 reference, as the dense path reads (0.012)
+    assert r["prefill_rel_err"] < 0.02 and r["decode_rel_err"] < 0.02
+
+
+@pytest.mark.parametrize("dtype,seq,why", [
+    (jnp.bfloat16, 512, "Pallas off"), (jnp.float32, 512, "a float32 cache"),
+    (jnp.bfloat16, 384, "a context that is not whole blocks"),
+])
+def test_the_dense_path_is_said_where_the_kernel_does_not_take_the_cache(dtype, seq, why):
+    cfg, family, _ = latent_toy.wide_lfm2()
+    cfg["max_position_embeddings"] = seq
+    linear.set_pallas_interpret(why != "Pallas off")
+    try:
+        e, _ = latent_toy.engine(family, cfg, 5, dtype=dtype)
+        assert e.path_facts()["attention_path"] == "xla_dense", why
+        assert e.decode_attention_block is None
+    finally:
+        linear.set_pallas_interpret(False)
+
+
 def test_bfloat16_where_float32_is_stated_is_told_apart():
     e, tensors = latent_toy.engine(FAMILY, CFG, 5, dtype=jnp.bfloat16)
     r = CORRECT.compare(FAMILY, CFG, tensors, e, 5)
