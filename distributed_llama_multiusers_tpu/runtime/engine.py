@@ -25,7 +25,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 
 import numpy as np
 import jax
@@ -51,6 +51,7 @@ from ..models.deepseek import forward_counted, init_latent_cache
 from ..models.hybrid import init_hybrid_cache
 from ..ops import pallas_attention
 from ..telemetry.logs import log_event
+from ..telemetry import names
 from ..telemetry.names import SCOPE_CARRY, SCOPE_HEAD, SCOPE_SAMPLER
 from ..utils import faults
 from .kvpool import DEFAULT_MAX_PARKED, DEFAULT_PAGE_SIZE, KVPagePool
@@ -76,6 +77,29 @@ DEFAULT_TOPP = 0.9
 # at most this many dispatched-but-unconsumed steps. 2 = classic one-step
 # lag (consume step k while step k+1 runs); 0/1 disables pipelining.
 DEFAULT_PIPELINE_DEPTH = 2
+
+
+def _step_program(width_arg: str | None = None, width: int | None = None):
+    """Wraps a step program's whole body in its ``dlstep.*`` class
+    (telemetry/names.py ``STEP_PROGRAMS``, by the function's name), under
+    ``jax.jit``: every operation of the program then says in its ``op_name``
+    which program it is, and for which static width it was compiled
+    (``width``, or the leading axis of the argument named ``width_arg``: a
+    shape, so no operand and no compile of its own)."""
+
+    def deco(fn):
+        family = names.STEP_PROGRAMS[fn.__name__]
+        i = None if width_arg is None else fn.__code__.co_varnames.index(width_arg)
+
+        @wraps(fn)
+        def classed(*args):
+            w = width if i is None else args[i].shape[0]
+            with jax.named_scope(names.step_class(family, w)):
+                return fn(*args)
+
+        return classed
+
+    return deco
 
 
 def _ordered_key(z):
@@ -147,6 +171,11 @@ class EngineStats:
     prefill_s: float = 0.0
     decode_s: float = 0.0
     prefill_tokens: int = 0
+    # rows the prefill halves COMPUTED: a chunk rides the smallest bucket
+    # that holds it (bucket_for) and the program computes every row of the
+    # bucket, so this grows by the bucket where prefill_tokens grows by the
+    # chunk; 1 - prefill_tokens / prefill_bucket_rows is the padding
+    prefill_bucket_rows: int = 0
     decode_steps: int = 0
     host_bytes_in: int = 0  # device->host logits/token traffic
     spec_steps: int = 0  # speculative verify steps (one per batched call)
@@ -272,7 +301,8 @@ class EngineStats:
     # so the dataclass does not treat it as a field.
     _dlint_guarded_by = {
         ("lock",): (
-            "prefill_s", "decode_s", "prefill_tokens", "decode_steps",
+            "prefill_s", "decode_s", "prefill_tokens", "prefill_bucket_rows",
+            "decode_steps",
             "host_bytes_in", "spec_steps", "spec_emitted", "spec_lane_steps",
             "prefix_hits", "prefix_tokens_saved", "multi_dispatches",
             "spec_pipelined_steps", "spec_accept_hist", "host_exact_lanes",
@@ -307,7 +337,8 @@ class EngineStats:
         with self.lock:
             snap = EngineStats(**self._counters())
             self.prefill_s = self.decode_s = self.overlap_s = 0.0
-            self.prefill_tokens = self.decode_steps = self.host_bytes_in = 0
+            self.prefill_tokens = self.prefill_bucket_rows = 0
+            self.decode_steps = self.host_bytes_in = 0
             self.spec_steps = self.spec_emitted = self.spec_lane_steps = 0
             self.prefix_hits = self.prefix_tokens_saved = 0
             self.multi_dispatches = 0
@@ -839,12 +870,14 @@ class InferenceEngine:
             return step, greedy, sampled, new_g, cache, counts
 
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program()
         def _decode(params, cache, tokens, positions, temps, topps, seeds,
                     gtab, gs):
-            step, greedy, sampled, _, cache, _ = _decode_core(
-                params, cache, tokens, positions, temps, topps, seeds,
-                gtab, gs,
-            )
+            with jax.named_scope(names.HALF_DECODE):
+                step, greedy, sampled, _, cache, _ = _decode_core(
+                    params, cache, tokens, positions, temps, topps, seeds,
+                    gtab, gs,
+                )
             # greedy+sampled stacked into ONE [2, n] array: a decode step
             # costs a single device->host round trip, not two (the transfer
             # is latency-bound — 8 bytes/lane payload)
@@ -855,16 +888,18 @@ class InferenceEngine:
             return step, pair, cache
 
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program()
         def _decode_nologits(params, cache, tokens, positions, temps, topps,
                              seeds, gtab, gs):
             # the common all-device-sampling step: no [n, vocab] output kept
             # alive (the row is still computed for argmax, but never
             # materialized as a program output, so it pins no HBM and — in
             # the pipelined path — can never force a sync)
-            _, greedy, sampled, _, cache, _ = _decode_core(
-                params, cache, tokens, positions, temps, topps, seeds,
-                gtab, gs,
-            )
+            with jax.named_scope(names.HALF_DECODE):
+                _, greedy, sampled, _, cache, _ = _decode_core(
+                    params, cache, tokens, positions, temps, topps, seeds,
+                    gtab, gs,
+                )
             with jax.named_scope(SCOPE_CARRY):
                 return rep_tokens(jnp.stack([greedy, sampled])), cache
 
@@ -879,7 +914,22 @@ class InferenceEngine:
         # the grammar-state select is the identical rule (-1 = carry)
         _eff_g = _eff_positions
 
+        @jax.named_scope(names.HALF_DECODE)
+        def _decode_half(params, cache, feed, carry_pos, positions, temps,
+                         topps, seeds, gtab, carry_g, gs_host):
+            # the decode batch's half of a pipelined step, alone
+            # (_decode_pl) or beside an admitted chunk (_decode_prefill):
+            # the effective positions and grammar states, then one token a
+            # lane. What follows it in either program (the carry and the
+            # packed readback) joins the halves and sits under neither.
+            pos = _eff_positions(carry_pos, positions)
+            gs = _eff_g(carry_g, gs_host)
+            return pos, _decode_core(
+                params, cache, feed, pos, temps, topps, seeds, gtab, gs
+            )
+
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program()
         def _decode_pl(params, cache, tokens, carry_pos, positions, temps,
                        topps, seeds, gtab, carry_g, gs_host):
             # pipelined step: the per-lane feed rule (greedy lanes continue
@@ -890,10 +940,9 @@ class InferenceEngine:
             # carry too (clamped at seq_len, where the KV scatter drops
             # writes — the same park rule the host applies); the grammar
             # state rides it identically.
-            pos = _eff_positions(carry_pos, positions)
-            gs = _eff_g(carry_g, gs_host)
-            _, greedy, sampled, new_g, cache, counts = _decode_core(
-                params, cache, tokens, pos, temps, topps, seeds, gtab, gs
+            pos, (_, greedy, sampled, new_g, cache, counts) = _decode_half(
+                params, cache, tokens, carry_pos, positions, temps, topps,
+                seeds, gtab, carry_g, gs_host,
             )
             with jax.named_scope(SCOPE_CARRY):
                 nxt = jnp.where(temps == 0.0, greedy, sampled)
@@ -999,15 +1048,26 @@ class InferenceEngine:
                 packed = jnp.concatenate([emitted, n_emit[:, None]], axis=1)
             return nxt, new_pos, new_g, packed, cache
 
+        @jax.named_scope(names.HALF_DECODE)
+        def _spec_half(params, cache, feed, carry_pos, positions, drafts,
+                       draft_len, temps, topps, seeds, gtab, carry_g, gs_host):
+            # _decode_half's twin for the verify steps: the decode batch's
+            # half is the verify window
+            pos = _eff_positions(carry_pos, positions)
+            gs = _eff_g(carry_g, gs_host)
+            return _spec_verify_core(
+                params, cache, feed, pos, drafts, draft_len, temps, topps,
+                seeds, gtab, gs,
+            )
+
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program()
         def _decode_spec_pl(params, cache, tokens, carry_pos, positions,
                             drafts, draft_len, temps, topps, seeds, gtab,
                             carry_g, gs_host):
-            pos = _eff_positions(carry_pos, positions)
-            gs = _eff_g(carry_g, gs_host)
-            nxt, new_pos, new_g, packed, cache = _spec_verify_core(
-                params, cache, tokens, pos, drafts, draft_len, temps,
-                topps, seeds, gtab, gs,
+            nxt, new_pos, new_g, packed, cache = _spec_half(
+                params, cache, tokens, carry_pos, positions, drafts,
+                draft_len, temps, topps, seeds, gtab, carry_g, gs_host,
             )
             with jax.named_scope(SCOPE_CARRY):
                 return (
@@ -1019,6 +1079,7 @@ class InferenceEngine:
                 )
 
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program("p_tokens")
         def _decode_spec_prefill(params, cache, tokens, carry_pos,
                                  positions, drafts, draft_len, temps, topps,
                                  seeds, p_lane, p_tokens, p_start, p_n,
@@ -1038,11 +1099,9 @@ class InferenceEngine:
                 params, cache, p_lane, p_tokens, p_start, p_n,
                 p_temp, p_topp, p_seed, gtab, p_g,
             )
-            pos = _eff_positions(carry_pos, positions)
-            gs = _eff_g(carry_g, gs_host)
-            nxt, new_pos, new_g, packed, cache = _spec_verify_core(
-                params, cache, tokens, pos, drafts, draft_len, temps,
-                topps, seeds, gtab, gs,
+            nxt, new_pos, new_g, packed, cache = _spec_half(
+                params, cache, tokens, carry_pos, positions, drafts,
+                draft_len, temps, topps, seeds, gtab, carry_g, gs_host,
             )
             with jax.named_scope(SCOPE_CARRY):
                 p_first = jnp.where(p_temp == 0.0, p_greedy, p_sampled)
@@ -1064,6 +1123,8 @@ class InferenceEngine:
                 )
 
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program()
+        @jax.named_scope(names.HALF_DECODE)
         def _decode_spec(params, cache, tokens, drafts, draft_len, positions,
                          temps, topps, seeds, gtab, gs):
             """Speculative decode: verify K = 1 + n_draft tokens per lane in
@@ -1119,6 +1180,7 @@ class InferenceEngine:
 
         self._decode_spec_fn = _decode_spec
 
+        @jax.named_scope(names.HALF_PREFILL)
         def _prefill_half(params, cache, lane, tokens, start_pos, n_tokens,
                           temp, topp, seed, gtab, p_g):
             """The prompt-chunk math shared by ``_prefill`` and the fused
@@ -1203,6 +1265,7 @@ class InferenceEngine:
             return last, greedy, sampled, out_cache
 
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program("tokens")
         def _prefill(params, cache, lane, tokens, start_pos, n_tokens,
                      temp, topp, seed, gtab, p_g):
             last, greedy, sampled, cache = _prefill_half(
@@ -1216,6 +1279,7 @@ class InferenceEngine:
             return last, pair, cache
 
         @partial(jax.jit, donate_argnums=(1,))
+        @_step_program("p_tokens")
         def _decode_prefill(params, cache, feed, carry_pos, positions,
                             temps, topps, seeds, p_lane, p_tokens, p_start,
                             p_n, p_temp, p_topp, p_seed, gtab, carry_g,
@@ -1249,10 +1313,9 @@ class InferenceEngine:
                 params, cache, p_lane, p_tokens, p_start, p_n,
                 p_temp, p_topp, p_seed, gtab, p_g,
             )
-            pos = _eff_positions(carry_pos, positions)
-            gs = _eff_g(carry_g, gs_host)
-            _, greedy, sampled, new_g, cache, counts = _decode_core(
-                params, cache, feed, pos, temps, topps, seeds, gtab, gs
+            pos, (_, greedy, sampled, new_g, cache, counts) = _decode_half(
+                params, cache, feed, carry_pos, positions, temps, topps,
+                seeds, gtab, carry_g, gs_host,
             )
             with jax.named_scope(SCOPE_CARRY):
                 nxt = jnp.where(temps == 0.0, greedy, sampled)
@@ -1361,6 +1424,8 @@ class InferenceEngine:
 
         def _make_decode_multi(h):
             @partial(jax.jit, donate_argnums=(1,))
+            @_step_program(width=h)
+            @jax.named_scope(names.HALF_DECODE)
             def _decode_multi(params, cache, tokens, positions, temps, topps,
                               seeds, gtab, gs):
                 """h chained decode steps in ONE device program (lax.scan):
@@ -1587,6 +1652,7 @@ class InferenceEngine:
             self.stats.host_bytes_in += toks_np.nbytes
             self.stats.prefill_s += time.perf_counter() - t0
             self.stats.prefill_tokens += len(chunk)
+            self.stats.prefill_bucket_rows += bucket
             self.stats.state_zero_starts += int(self.config.recurrent_state and start_pos == 0)
         return last, greedy, sampled
 
@@ -1968,6 +2034,7 @@ class InferenceEngine:
             self.stats.fused_steps += 1
             self.stats.sync_bytes_total += self.stats.sync_bytes_per_decode
             self.stats.prefill_tokens += len(chunk)
+            self.stats.prefill_bucket_rows += bucket
             self.stats.state_zero_starts += int(self.config.recurrent_state and p_start == 0)
             self.stats.fused_bucket_hist[bucket] = (
                 self.stats.fused_bucket_hist.get(bucket, 0) + 1
@@ -2316,6 +2383,7 @@ class InferenceEngine:
             self.stats.spec_pipelined_steps += 1
             self.stats.sync_bytes_total += self.stats.sync_bytes_per_decode
             self.stats.prefill_tokens += len(chunk)
+            self.stats.prefill_bucket_rows += bucket
             self.stats.fused_bucket_hist[bucket] = (
                 self.stats.fused_bucket_hist.get(bucket, 0) + 1
             )

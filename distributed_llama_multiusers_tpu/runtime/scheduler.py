@@ -1341,7 +1341,9 @@ class ContinuousBatchingScheduler:
             if wd is not None:
                 wd.step_done()
         self.breaker.record_success()
-        self.telemetry.on_prefill_chunk(req, lane_idx, t_chunk, len(chunk))
+        self.telemetry.on_prefill_chunk(
+            req, lane_idx, t_chunk, len(chunk),
+            bucket=self.engine.bucket_for(len(chunk)))
         lane.pos += len(chunk)
         lane.pending = lane.pending[len(chunk):]
         self._lane_kv[lane_idx].extend(chunk)  # committed: prefix-cacheable
@@ -1794,6 +1796,7 @@ class ContinuousBatchingScheduler:
         self.telemetry.on_pipelined_step(
             t_dispatch, fused,
             kind="spec_pipelined" if is_spec else "pipelined", step=step,
+            bucket=None if fused is None else self.engine.bucket_for(fused[3]),
         )
         if is_spec:
             emitted, n_emit = out_a, out_b

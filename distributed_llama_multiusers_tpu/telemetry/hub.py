@@ -324,9 +324,14 @@ class Telemetry:
         self.trace_of(req).prefill_done_at = now
 
     def on_prefill_chunk(self, req, lane: int, t0: float, n_tokens: int,
-                         fused: bool = False, step: int | None = None) -> None:
+                         fused: bool = False, step: int | None = None,
+                         bucket: int | None = None) -> None:
+        """``bucket`` is the prefill bucket the chunk rode
+        (``engine.bucket_for(n_tokens)``): the rows the program computed."""
         now_pc = self.tracer.now()
         extra = {"tokens": n_tokens}
+        if bucket is not None:
+            extra["bucket"] = bucket
         if step is not None:
             extra["step"] = step
         self.tracer.slice(
@@ -365,7 +370,8 @@ class Telemetry:
 
     def on_pipelined_step(self, t_dispatch: float, fused_info=None,
                           kind: str = "pipelined",
-                          step: int | None = None) -> None:
+                          step: int | None = None,
+                          bucket: int | None = None) -> None:
         """One pipelined step, recorded at CONSUME time (one step behind):
         the slice spans dispatch -> lagged readback completion. ``kind``
         distinguishes the in-chain spec verify steps
@@ -374,7 +380,9 @@ class Telemetry:
         step, ``fused_info`` is the scheduler's
         ``(lane_idx, lane, final, n_chunk)`` and the admitting lane also
         gets a ``prefill.fused`` slice on its own track. ``step`` is the
-        dispatch's sequence number, the one its ``loop.*`` spans carry."""
+        dispatch's sequence number, the one its ``loop.*`` spans carry;
+        ``bucket`` the prefill bucket a fused step's chunk rode, which is
+        the class of step program the lanes waited through."""
         now_pc = self.tracer.now()
         step_args = {} if step is None else {"step": step}
         if fused_info is None:
@@ -382,6 +390,8 @@ class Telemetry:
                               now_pc, args=self.span_args(extra=step_args))
         else:
             lane_idx, lane, final, n_chunk = fused_info
+            if bucket is not None:
+                step_args["bucket"] = bucket
             req = lane.request
             req_id = getattr(req, "id", None)
             # a verify step that ALSO carries a chunk keeps its spec
@@ -397,7 +407,7 @@ class Telemetry:
             )
             if req is not None:
                 self.on_prefill_chunk(req, lane_idx, t_dispatch, n_chunk,
-                                      fused=True, step=step)
+                                      fused=True, step=step, bucket=bucket)
         self.step_duration.observe(max(0.0, now_pc - t_dispatch))
 
     def on_flush(self, live: int, admitting: int) -> None:

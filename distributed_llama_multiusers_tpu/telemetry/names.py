@@ -3,9 +3,9 @@
 Three users: the program (``models/llama.py`` and ``runtime/engine.py`` wrap
 the parts of every step family in ``jax.named_scope(SCOPE_*)``;
 ``runtime/scheduler.py`` opens the ``LOOP_*`` spans), the readers
-(``benchmarks/harness/progtrace.py``) and the tests. Strings only: this
-module imports nothing but ``re``, so the benchmark's readers can import it without
-pulling in jax.
+(``benchmarks/harness/progtrace.py``, ``stepclass.py``) and the tests. Strings
+only: this module imports nothing but ``re``, so the benchmark's readers can
+import it without pulling in jax.
 
 **Device scopes.** A scope is HLO metadata (the ``op_name`` of every
 instruction traced inside it): it costs the device nothing, and JAX's
@@ -16,6 +16,17 @@ self time (what runs inside the scan under none of the five layer scopes)
 plus the operations that carry no scope at all is what XLA adds around the
 model's own arithmetic: whole-cache carry copies, per-layer slices and
 updates of the stacked cache and of the stacked weight planes.
+
+**Step classes and halves.** A second kind of name, which ``_SCOPE_RE`` does
+not match (``scope_path`` and ``scope_of`` return the same with and without
+it): every jitted step program wraps its whole body in ONE
+``dlstep.<family>[.b<width>]`` (``step_class``; the width is the static shape
+it was compiled for: its chunk's bucket, a multi-step program's horizon), and
+inside it the admitted chunk's operations sit under ``dlhalf.prefill`` and the
+decode batch's under ``dlhalf.decode``. What joins the two (the closing ``dl.carry`` block) sits
+under neither. A device trace then says which program an execution was and
+whose operations it ran, which its module name (``jit__decode_prefill(<hash>)``,
+the same for every bucket) does not.
 
 **Host spans.** ``Telemetry.span(name, track)`` records a ring slice named
 ``name`` and holds a profiler annotation named ``ANNOTATION_PREFIX + name``
@@ -63,6 +74,26 @@ LAYER_SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_ATTN_OUT, SCOP
 LEAF_SCOPES = (SCOPE_EMBED, *LAYER_SCOPES, SCOPE_HEAD, SCOPE_SAMPLER, SCOPE_CARRY)
 ALL_SCOPES = (SCOPE_EMBED, SCOPE_LAYERS, *LEAF_SCOPES[1:])
 
+STEP_PREFIX = "dlstep."
+# jitted step program (the name a trace's ``XLA Modules`` line gives its
+# executions, ``jit_<name>(<hash>)``) -> family of its class
+STEP_PROGRAMS = {
+    "_decode_pl": "decode",                 # the pipelined decode step
+    "_decode_prefill": "fused",             # + a prompt chunk; by the chunk's bucket
+    "_prefill": "prefill",                  # the synchronous chunk; by its bucket
+    "_decode": "decode_sync",               # the synchronous step that returns its logits
+    "_decode_nologits": "decode_sync_nologits",
+    "_decode_spec": "spec",                 # the synchronous verify step
+    "_decode_spec_pl": "spec_pl",           # the verify step inside the chain
+    "_decode_spec_prefill": "spec_fused",   # + a prompt chunk; by the chunk's bucket
+    "_decode_multi": "decode_multi",        # h chained steps; by h
+}
+
+HALF_PREFIX = "dlhalf."
+HALF_PREFILL = "dlhalf.prefill"  # _prefill_half: the admitted chunk, every row of its bucket
+HALF_DECODE = "dlhalf.decode"    # the decode batch: positions, _decode_core or the verify core
+HALVES = (HALF_PREFILL, HALF_DECODE)
+
 ANNOTATION_PREFIX = "dl."
 
 LOOP_TRACK = "loop"
@@ -91,3 +122,27 @@ def scope_of(op_name: str) -> str | None:
     its ``op_name``; None for an operation under no scope."""
     found = scope_path(op_name)
     return found[-1] if found else None
+
+
+_STEP_RE = re.compile(r"(?<![\w.])dlstep\.[a-z_]+(?:\.b\d+)?")
+_HALF_RE = re.compile(r"(?<![\w.])dlhalf\.[a-z_]+")
+
+
+def step_class(family: str, width: int | None = None) -> str:
+    """``dlstep.<family>`` or, for a program compiled once a static width
+    (a prefill bucket, a multi-step horizon), ``dlstep.<family>.b<width>``."""
+    return f"{STEP_PREFIX}{family}" if width is None else f"{STEP_PREFIX}{family}.b{int(width)}"
+
+
+def step_class_of(op_name: str) -> str | None:
+    """The ``dlstep.*`` class in an ``op_name`` (the outermost, should a step
+    program ever be traced inside another); None where there is none."""
+    m = _STEP_RE.search(op_name)
+    return m.group(0) if m else None
+
+
+def half_of(op_name: str) -> str | None:
+    """``dlhalf.prefill`` or ``dlhalf.decode``; None for an operation of the
+    join, or of a program from before the halves."""
+    m = _HALF_RE.search(op_name)
+    return m.group(0) if m else None

@@ -249,6 +249,10 @@ class MockAsyncEngine:
     def max_chunk(self):
         return self._max_chunk
 
+    def bucket_for(self, n):
+        # one bucket: every chunk rides the largest (fused_bucket_hist's key)
+        return self._max_chunk
+
     # -- grammar-constrained decoding (grammar/; REAL slab + compiler) -----
 
     @property
@@ -550,6 +554,7 @@ class MockAsyncEngine:
         t = self._tok_g(lane, start_pos + len(chunk) - 1, g_state)
         with self.stats.lock:
             self.stats.prefill_tokens += len(chunk)
+            self.stats.prefill_bucket_rows += self._max_chunk
         return None, t, t
 
     def _toks_at(self, positions, g_states=None):
@@ -721,6 +726,7 @@ class MockAsyncEngine:
         with self.stats.lock:
             self.stats.fused_steps += 1
             self.stats.prefill_tokens += len(chunk)
+            self.stats.prefill_bucket_rows += self._max_chunk
             self.stats.fused_bucket_hist[self._max_chunk] = (
                 self.stats.fused_bucket_hist.get(self._max_chunk, 0) + 1
             )
@@ -811,6 +817,7 @@ class MockAsyncEngine:
             self.stats.spec_pipelined_steps += 1
             self.stats.fused_steps += 1
             self.stats.prefill_tokens += len(chunk)
+            self.stats.prefill_bucket_rows += self._max_chunk
             self.stats.fused_bucket_hist[self._max_chunk] = (
                 self.stats.fused_bucket_hist.get(self._max_chunk, 0) + 1
             )

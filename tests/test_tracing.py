@@ -345,9 +345,9 @@ class StepStamps(Telemetry):
         self.first_token_step = {}
 
     def on_pipelined_step(self, t_dispatch, fused_info=None, kind="pipelined",
-                          step=None):
+                          step=None, **kw):
         self.consuming = step
-        super().on_pipelined_step(t_dispatch, fused_info, kind=kind, step=step)
+        super().on_pipelined_step(t_dispatch, fused_info, kind=kind, step=step, **kw)
 
     def on_prefill_done(self, req, now):
         self.prefill_done_step[req.id] = self.consuming
