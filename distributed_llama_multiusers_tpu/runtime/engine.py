@@ -57,7 +57,8 @@ from ..utils import faults
 from .kvpool import DEFAULT_MAX_PARKED, DEFAULT_PAGE_SIZE, KVPagePool
 from .spec import SPEC_DRAFT
 
-DEFAULT_PREFILL_BUCKETS = (16, 64, 256, 1024)
+# rungs by what a prefill half costs: flat under ~100 rows (none below 64), by the row above (gaps of 2-4x)
+DEFAULT_PREFILL_BUCKETS = (64, 256, 512, 1024)
 
 # host-swap transfer batch: pages moved per device dispatch by the
 # gather/scatter swap programs (fixed operand shape = ONE compile each;

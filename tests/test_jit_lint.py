@@ -351,13 +351,13 @@ BUCKETED = """
         return tokens, cache
 
     class Engine:
-        prefill_buckets = (16, 64)
+        prefill_buckets = (64, 256)
 
         def __init__(self):
             self._prefill_fn = _prefill
 
         def bucket_for(self, n):
-            return 16
+            return 64
 
         def prefill_chunk(self, chunk):
             bucket = self.bucket_for(len(chunk))
@@ -372,7 +372,7 @@ BUCKETED = """
 def test_warmup_coverage_flags_bucketed_family_warmed_once(tmp_path):
     findings = run_on(tmp_path, {"runtime/engine.py": BUCKETED + """
     def warmup_engine(engine):
-        engine.prefill_chunk([0] * 16)
+        engine.prefill_chunk([0] * 64)
     """})
     assert checks_of(findings) == ["warmup-coverage"]
     assert "prefill_buckets` loop" in findings[0].message

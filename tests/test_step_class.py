@@ -217,7 +217,7 @@ OP_NAMES = [
      "jit(_decode_prefill)/dl.carry/concatenate"),
     ("jit(_decode_pl)/dlstep.decode/dlhalf.decode/vmap(dl.sampler)/sort",
      "jit(_decode_pl)/vmap(dl.sampler)/sort"),
-    ("jit(_prefill)/dlstep.prefill.b16/dlhalf.prefill/dl.layers/while/body/dynamic_update_slice",
+    ("jit(_prefill)/dlstep.prefill.b512/dlhalf.prefill/dl.layers/while/body/dynamic_update_slice",
      "jit(_prefill)/dl.layers/while/body/dynamic_update_slice"),
     ("jit(_decode_pl)/dlstep.decode/convert_element_type", "jit(_decode_pl)/convert_element_type"),
     ("jit(model.embed)/gather", "jit(model.embed)/gather"),
