@@ -86,6 +86,8 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         # latent attention + routed FFN: the header's KEY_KV_LORA_RANK block
         # says so; the arch word stays the one every reader accepts
         "deepseek_v3": ArchType.LLAMA,
+        # the same block with a query latent, an indexer, expert groups, YaRN
+        "deepseek_v32": ArchType.LLAMA,
         # a per-layer pattern of conv and attention mixers: KEY_LAYER_KIND
         "lfm2_moe": ArchType.LLAMA,
     }.get(cfg["model_type"])
@@ -112,7 +114,7 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         rope_theta=float(
             (cfg.get("rope_parameters") or {}).get("rope_theta", cfg.get("rope_theta", 10000.0))),
     )
-    if cfg["model_type"] == "deepseek_v3":
+    if cfg["model_type"] in ("deepseek_v3", "deepseek_v32"):
         set_latent_header(h, cfg)
     if cfg["model_type"] == "lfm2_moe":
         set_pattern_header(h, cfg)
@@ -123,7 +125,19 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
             cfg.get("num_active_local_experts") or cfg.get("num_experts_per_tok")
         )
     scaling = cfg.get("rope_scaling")
-    if scaling is not None and scaling.get("rope_type") in ("llama3",):
+    if scaling is not None and scaling.get("type", scaling.get("rope_type")) == "yarn":
+        # YaRN's factor, beta_slow, beta_fast and original context ride the
+        # four scaling keys (formats/model_file.py RopeType.YARN)
+        h.rope_type = RopeType.YARN
+        h.rope_scaling_factor = float(scaling["factor"])
+        h.rope_scaling_low_freq_factor = float(scaling.get("beta_slow", 1))
+        h.rope_scaling_high_freq_factor = float(scaling.get("beta_fast", 32))
+        h.rope_scaling_orig_max_seq_len = int(scaling["original_max_position_embeddings"])
+        h.rope_yarn_mscale_all_dim = float(scaling.get("mscale_all_dim", 0.0))
+        if float(scaling.get("mscale", h.rope_yarn_mscale_all_dim)) != h.rope_yarn_mscale_all_dim:
+            # the runtime scales the softmax and not the rotation
+            raise ValueError(f"Unsupported rope scaling: mscale differs from mscale_all_dim: {scaling}")
+    elif scaling is not None and scaling.get("rope_type") in ("llama3",):
         h.rope_type = RopeType.LLAMA3_1
         h.rope_scaling_factor = float(scaling["factor"])
         h.rope_scaling_low_freq_factor = float(scaling["low_freq_factor"])
@@ -138,10 +152,20 @@ def set_latent_header(h: ModelHeader, cfg: dict) -> None:
     """The header keys of ``model_type: deepseek_v3`` (formats/model_file.py
     KEY_KV_LORA_RANK ...). What the runtime does not compute is refused here,
     not converted wrongly."""
-    for key, want in (("q_lora_rank", None), ("n_group", 1), ("topk_group", 1),
-                      ("moe_layer_freq", 1), ("rope_scaling", None)):
-        if cfg.get(key, want) != want:
-            raise ValueError(f"Unsupported deepseek_v3 setting: {key} = {cfg[key]!r}")
+    if cfg.get("moe_layer_freq", 1) != 1:
+        raise ValueError(f"Unsupported deepseek_v3 setting: moe_layer_freq = {cfg['moe_layer_freq']!r}")
+    scaling = cfg.get("rope_scaling")
+    if scaling is not None and scaling.get("type", scaling.get("rope_type")) != "yarn":
+        raise ValueError(f"Unsupported deepseek_v3 setting: rope_scaling = {scaling!r}")
+    # each mechanism by its own key, whatever the model's name
+    h.q_lora_rank = int(cfg.get("q_lora_rank") or 0)
+    h.moe_n_group = int(cfg.get("n_group") or 1)
+    h.moe_topk_group = int(cfg.get("topk_group") or 1)
+    h.index_topk = int(cfg.get("index_topk") or 0)
+    if h.index_topk:
+        h.index_n_heads, h.index_head_dim = int(cfg["index_n_heads"]), int(cfg["index_head_dim"])
+    if "router_norm_floor" in cfg:  # no published key: a caller's own
+        h.moe_norm_floor = float(cfg["router_norm_floor"])
     if not cfg.get("rope_interleave", True):
         raise ValueError("Unsupported deepseek_v3 setting: rope_interleave false")
     score = {"sigmoid": MoeScore.SIGMOID, "softmax": MoeScore.SOFTMAX}.get(cfg["scoring_func"])
@@ -242,9 +266,23 @@ def write_latent_layers(out, index, header: ModelHeader, wt: int) -> None:
     ``kv_b_proj`` are kept whole; no row is permuted: ``rope_interleave``
     checkpoints hold the rotary part in adjacent pairs, the runtime's own
     convention. The router and its selection bias stay F32."""
+    held = range(header.experts_held_first,
+                 header.experts_held_first + (header.experts_held_count or header.n_experts))
     for l in range(header.n_layers):
         pre = f"model.layers.{l}"
-        write_tensor(out, index.get(f"{pre}.self_attn.q_proj.weight"), wt)
+        if header.q_lora_rank:
+            write_tensor(out, index.get(f"{pre}.self_attn.q_a_proj.weight"), wt)
+            write_tensor(out, index.get(f"{pre}.self_attn.q_a_layernorm.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{pre}.self_attn.q_b_proj.weight"), wt)
+        else:
+            write_tensor(out, index.get(f"{pre}.self_attn.q_proj.weight"), wt)
+        if header.index_topk:
+            ipre = f"{pre}.self_attn.indexer"
+            write_tensor(out, index.get(f"{ipre}.wq_b.weight"), wt)
+            write_tensor(out, index.get(f"{ipre}.wk.weight"), wt)
+            write_tensor(out, index.get(f"{ipre}.k_norm.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{ipre}.k_norm.bias"), FloatType.F32)
+            write_tensor(out, index.get(f"{ipre}.weights_proj.weight"), FloatType.F32)
         write_tensor(out, index.get(f"{pre}.self_attn.kv_a_proj_with_mqa.weight"), wt)
         write_tensor(out, index.get(f"{pre}.self_attn.kv_a_layernorm.weight"), FloatType.F32)
         write_tensor(out, index.get(f"{pre}.self_attn.kv_b_proj.weight"), wt)
@@ -257,7 +295,7 @@ def write_latent_layers(out, index, header: ModelHeader, wt: int) -> None:
             write_tensor(out, index.get(f"{pre}.mlp.gate.weight"), FloatType.F32)
             if header.moe_select_bias:
                 write_tensor(out, index.get(f"{pre}.mlp.gate.e_score_correction_bias"), FloatType.F32)
-            for e in range(header.n_experts):
+            for e in held:  # the experts this file holds (all of them unless told)
                 epre = f"{pre}.mlp.experts.{e}"
                 write_tensor(out, index.get(f"{epre}.up_proj.weight"), wt)  # w3
                 write_tensor(out, index.get(f"{epre}.gate_proj.weight"), wt)  # w1
@@ -271,10 +309,15 @@ def write_latent_layers(out, index, header: ModelHeader, wt: int) -> None:
         write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
 
 
-def convert(folder: str, weight_type: int, out_path: str, index=None) -> None:
+def convert(folder: str, weight_type: int, out_path: str, index=None,
+            experts_held: tuple | None = None) -> None:
     """``index`` (tests): anything with ``get(key)`` and ``in`` over the
-    checkpoint's tensor names, in place of the folder's safetensors."""
+    checkpoint's tensor names, in place of the folder's safetensors.
+    ``experts_held`` ``(first id, count)``: write one chip's share of the
+    routed experts (the router keeps every output)."""
     header, cfg = load_config(folder, weight_type)
+    if experts_held is not None:
+        header.experts_held_first, header.experts_held_count = (int(x) for x in experts_held)
     if index is not None:
         return write_model(header, index, weight_type, out_path)
     files = sorted(

@@ -19,6 +19,7 @@ pre-permuted to the interleaved-rotary layout (converter/convert-hf.py:11-14).
 
 from __future__ import annotations
 
+import math
 import mmap
 import os
 import struct
@@ -84,6 +85,30 @@ KEY_NORM_EPSILON_E9 = 31
 KEY_LAYER_KIND = 32
 KEY_CONV_KERNEL = 33
 KEY_QK_NORM = 34
+# framework extension: what ``model_type: deepseek_v32`` adds to the latent
+# block (models/deepseek.py), each written only where it is set, so every
+# file without them reads, and is written, as before. A query latent
+# (KEY_Q_LORA_RANK: ``q_a``, its norm, ``q_b`` in the place of ``q``); the
+# lightning indexer (its heads, their width and how many positions it keeps;
+# three matrices and a layer norm's gain and bias a layer); the router's
+# expert groups; the share of the routed experts this file holds (first id
+# and count: the router keeps every output, the file only those experts'
+# tensors; count 0: all of them); YaRN's scale on the softmax
+# (``mscale_all_dim``, in millionths; its factor, beta_slow, beta_fast and
+# original context ride the four KEY_ROPE_SCALING_* keys under
+# RopeType.YARN); and the floor under the
+# router's renormalising sum as a power of ten (20: 1e-20, what a file without
+# the key is read with; -1: no floor).
+KEY_Q_LORA_RANK = 35
+KEY_INDEX_N_HEADS = 36
+KEY_INDEX_HEAD_DIM = 37
+KEY_INDEX_TOPK = 38
+KEY_MOE_N_GROUP = 39
+KEY_MOE_TOPK_GROUP = 40
+KEY_EXPERTS_HELD_FIRST = 41
+KEY_EXPERTS_HELD_COUNT = 42
+KEY_ROPE_YARN_MSCALE_ALL_DIM_E6 = 43
+KEY_MOE_NORM_FLOOR_EXP10 = 44
 
 
 class ArchType:
@@ -113,6 +138,7 @@ class RopeType:
     LLAMA = 0
     FALCON = 1  # reserved in reference enum; unused
     LLAMA3_1 = 2
+    YARN = 3  # frequencies blended over a correction range (ops/rope.py)
 
 
 @dataclass
@@ -153,6 +179,17 @@ class ModelHeader:
     moe_select_bias: int = 0
     moe_norm_topk: int = 1
     moe_routed_scale: float = 1.0
+    # what deepseek_v32 adds (KEY_Q_LORA_RANK ...); unset elsewhere
+    q_lora_rank: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    experts_held_first: int = 0
+    experts_held_count: int = 0  # 0: every expert
+    rope_yarn_mscale_all_dim: float = 0.0
+    moe_norm_floor: float = 1e-20
     # a block of mixed layers (KEY_LAYER_KIND ...); empty / zero elsewhere
     layer_kinds: list = field(default_factory=list)  # LayerKind a layer
     conv_kernel: int = 0
@@ -195,6 +232,15 @@ class ModelHeader:
             + [(KEY_MOE_ROUTED_SCALE_E6, int(round(self.moe_routed_scale * 1e6))),
                (KEY_NORM_EPSILON_E9, int(round(self.norm_epsilon * 1e9)))]
             if self.kv_lora_rank or self.layer_kinds else []
+        ) + [
+            (key, getattr(self, name)) for key, name in _SPARSE_INT_KEYS.items()
+            if getattr(self, name) != _SPARSE_DEFAULTS.get(name, 0)
+        ] + [
+            (key, int(round(getattr(self, name) * 1e6)))
+            for key, name in _YARN_E6_KEYS.items() if getattr(self, name)
+        ] + (
+            [(KEY_MOE_NORM_FLOOR_EXP10, _floor_to_exp10(self.moe_norm_floor))]
+            if self.moe_norm_floor != 1e-20 else []
         ) + (
             [(KEY_LAYER_KIND, kind) for kind in self.layer_kinds]
             + [(KEY_CONV_KERNEL, self.conv_kernel), (KEY_QK_NORM, self.qk_norm)]
@@ -214,8 +260,30 @@ _LATENT_INT_KEYS = {
     KEY_MOE_SELECT_BIAS: "moe_select_bias",
     KEY_MOE_NORM_TOPK: "moe_norm_topk",
 }
+_SPARSE_INT_KEYS = {
+    KEY_Q_LORA_RANK: "q_lora_rank",
+    KEY_INDEX_N_HEADS: "index_n_heads",
+    KEY_INDEX_HEAD_DIM: "index_head_dim",
+    KEY_INDEX_TOPK: "index_topk",
+    KEY_MOE_N_GROUP: "moe_n_group",
+    KEY_MOE_TOPK_GROUP: "moe_topk_group",
+    KEY_EXPERTS_HELD_FIRST: "experts_held_first",
+    KEY_EXPERTS_HELD_COUNT: "experts_held_count",
+}
+_SPARSE_DEFAULTS = {"moe_n_group": 1, "moe_topk_group": 1}
+_YARN_E6_KEYS = {KEY_ROPE_YARN_MSCALE_ALL_DIM_E6: "rope_yarn_mscale_all_dim"}
+
+
+def _floor_to_exp10(floor: float) -> int:
+    """The renormalising floor as the header holds it: 1e-n as n, none as -1."""
+    return -1 if floor <= 0.0 else int(round(-math.log10(floor)))
+
+
 # every header field of the latent-attention block, as models/config.py takes them
-LATENT_FIELDS = (*_LATENT_INT_KEYS.values(), "moe_routed_scale")
+LATENT_FIELDS = (
+    *_LATENT_INT_KEYS.values(), "moe_routed_scale",
+    *_SPARSE_INT_KEYS.values(), *_YARN_E6_KEYS.values(), "moe_norm_floor",
+)
 
 
 def write_model_header(f: BinaryIO, header: ModelHeader) -> int:
@@ -289,6 +357,12 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 h.moe_routed_scale = value / 1e6
             elif key == KEY_NORM_EPSILON_E9:
                 h.norm_epsilon = value / 1e9
+            elif key in _SPARSE_INT_KEYS:
+                setattr(h, _SPARSE_INT_KEYS[key], value)
+            elif key in _YARN_E6_KEYS:
+                setattr(h, _YARN_E6_KEYS[key], value / 1e6)
+            elif key == KEY_MOE_NORM_FLOOR_EXP10:
+                h.moe_norm_floor = 0.0 if value < 0 else 10.0 ** -value
             elif key == KEY_LAYER_KIND:
                 h.layer_kinds.append(value)
             elif key == KEY_CONV_KERNEL:
@@ -390,7 +464,20 @@ def _latent_block_specs(h: ModelHeader, add) -> None:
     wt, dim = h.weight_type, h.dim
     qk = h.qk_nope_head_dim + h.qk_rope_head_dim
     for l in range(h.n_layers):
-        add("block_matmul_q", l, wt, (h.n_heads * qk, dim))
+        if h.q_lora_rank:  # the query latent: q_a, its norm, q_b
+            add("block_matmul_q_a", l, wt, (h.q_lora_rank, dim))
+            add("block_rms_norm_q", l, FloatType.F32, (1, h.q_lora_rank))
+        add("block_matmul_q", l, wt, (h.n_heads * qk, h.q_lora_rank or dim))
+        if h.index_topk:
+            # the indexer: queries from the query latent, one key a token
+            # with a layer norm's gain and bias, the heads' weights (F32, as
+            # the router is: 64 outputs are no Q40 plane)
+            add("block_matmul_idx_q", l, wt,
+                (h.index_n_heads * h.index_head_dim, h.q_lora_rank or dim))
+            add("block_matmul_idx_k", l, wt, (h.index_head_dim, dim))
+            add("block_idx_k_norm_gain", l, FloatType.F32, (1, h.index_head_dim))
+            add("block_idx_k_norm_bias", l, FloatType.F32, (1, h.index_head_dim))
+            add("block_idx_weights", l, FloatType.F32, (h.index_n_heads, dim))
         add("block_matmul_kv_a", l, wt, (h.kv_lora_rank + h.qk_rope_head_dim, dim))
         add("block_rms_norm_kv", l, FloatType.F32, (1, h.kv_lora_rank))
         add("block_matmul_kv_b", l, wt,
@@ -417,7 +504,7 @@ def _routed_ffn_specs(h: ModelHeader, add, l: int) -> None:
     add("block_moe_gate", l, FloatType.F32, (h.n_experts, dim))
     if h.moe_select_bias:
         add("block_moe_bias", l, FloatType.F32, (1, h.n_experts))
-    for e in range(h.n_experts):
+    for e in range(h.experts_held_count or h.n_experts):  # the experts the file holds
         add("block_matmul_w3", l, wt, (h.moe_hidden_dim, dim), e)
         add("block_matmul_w1", l, wt, (h.moe_hidden_dim, dim), e)
         add("block_matmul_w2", l, wt, (dim, h.moe_hidden_dim), e)

@@ -87,6 +87,34 @@ def tiny_pattern_header(
     return h
 
 
+def tiny_sparse_latent_header(
+    n_layers: int = 3,
+    n_experts: int = 16,
+    experts_held: tuple = (0, 8),
+    index_topk: int = 16,
+    **kw,
+) -> ModelHeader:
+    """A toy of the latent-attention block with what ``deepseek_v32`` adds
+    (models/deepseek.py): a query latent, an indexer that keeps ``index_topk``
+    positions, expert groups, a held share of the routed experts, YaRN."""
+    h = tiny_header(dim=128, hidden_dim=256, n_layers=n_layers, n_heads=4, n_kv_heads=4,
+                    vocab_size=256, seq_len=128, **kw)
+    h.kv_lora_rank, h.qk_nope_head_dim, h.qk_rope_head_dim, h.v_head_dim = 64, 32, 16, 32
+    h.q_lora_rank = 64
+    h.index_n_heads, h.index_head_dim, h.index_topk = 4, 32, index_topk
+    h.n_experts, h.n_active_experts, h.moe_hidden_dim = n_experts, 3, 64
+    h.shared_hidden_dim, h.n_dense_layers = 64, 1
+    h.moe_score_func, h.moe_select_bias, h.moe_routed_scale = MoeScore.SIGMOID, 1, 2.5
+    h.moe_n_group, h.moe_topk_group = 4, 2
+    h.experts_held_first, h.experts_held_count = experts_held
+    h.norm_epsilon, h.moe_norm_floor = 1e-6, 0.0
+    h.rope_type = RopeType.YARN
+    h.rope_scaling_factor, h.rope_scaling_orig_max_seq_len = 4.0, 32
+    h.rope_scaling_low_freq_factor, h.rope_scaling_high_freq_factor = 1.0, 32.0
+    h.rope_yarn_mscale_all_dim = 1.0
+    return h
+
+
 def _write_tensor(f, x: np.ndarray, float_type: int) -> None:
     x = np.ascontiguousarray(x, dtype=np.float32).reshape(-1)
     if float_type == FloatType.F32:
@@ -113,13 +141,15 @@ def write_synthetic_model(path: str, header: ModelHeader, seed: int = 0, scale: 
     def rand(shape):
         return rng.standard_normal(shape, dtype=np.float32) * scale
 
-    if header.layer_kinds:
-        # a layer pattern's file: the walk itself says what to write
+    if header.layer_kinds or header.kv_lora_rank:
+        # a layer pattern's or a latent block's file: the walk itself says
+        # what to write
         with open(path, "wb") as f:
             header.header_size = write_model_header(f, header)
             for spec in model_tensor_specs(header):
                 # a norm's gains sit about one
-                _write_tensor(f, ("norm" in spec.name) + rand(spec.shape), spec.float_type)
+                gain = "norm" in spec.name and "bias" not in spec.name
+                _write_tensor(f, gain + rand(spec.shape), spec.float_type)
         return
 
     with open(path, "wb") as f:

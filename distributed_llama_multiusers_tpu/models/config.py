@@ -54,6 +54,34 @@ class LlamaConfig:
     moe_select_bias: int = 0  # a per-expert bias added to choose, not to weigh
     moe_norm_topk: int = 1  # chosen scores renormalised to sum 1
     moe_routed_scale: float = 1.0  # factor on the routed experts' sum
+    moe_norm_floor: float = 1e-20  # added to the chosen scores' sum before dividing
+    # Expert groups in that router (moe_n_group > 1): the experts lie in
+    # moe_n_group equal groups, a group scores the sum of its two largest
+    # score + bias, and only the moe_topk_group best groups' experts can be
+    # chosen.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # The share of the routed experts this process holds (count 0: all): ids
+    # [first, first + count). The router keeps all n_experts outputs and
+    # chooses among them; a row's chosen expert outside the range fetches
+    # nothing and adds nothing here (another chip's), the renormalisation is
+    # over every chosen expert. The expert stacks hold `count` slabs a layer.
+    experts_held_first: int = 0
+    experts_held_count: int = 0
+    # A query latent (q_lora_rank > 0): q = Wqb rmsnorm(Wqa n), and the
+    # indexer's queries are made from the same normed latent.
+    q_lora_rank: int = 0
+    # Learned sparse attention (index_topk > 0; models/deepseek.py): an
+    # indexer of index_n_heads heads of index_head_dim scores every cached
+    # position for a query, and attention reads the index_topk best only.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # YaRN (rope_type YARN: the factor, beta_slow, beta_fast and original
+    # context are the four rope_scaling_* fields above): mscale_all_dim
+    # scales the softmax (the rotation is unscaled: a checkpoint whose mscale
+    # differs from it is refused by the converter).
+    rope_yarn_mscale_all_dim: float = 0.0
     # A block whose layers differ in their mixer (models/hybrid.py): the kind
     # of every layer as published (formats.model_file.LayerKind; a list, no
     # period is guessed from it), empty for a block of one kind. A conv layer
@@ -85,6 +113,27 @@ class LlamaConfig:
                     "a routed latent-attention model needs moe_hidden_dim and "
                     "0 <= n_dense_layers <= n_layers"
                 )
+
+        if self.index_topk > 0 and not (
+            self.latent_attention and self.index_n_heads > 0
+            and self.index_head_dim >= self.qk_rope_head_dim
+        ):
+            raise ValueError(
+                "an indexer (index_topk) belongs to latent attention and needs "
+                "index_n_heads and index_head_dim >= qk_rope_head_dim")
+        if self.moe_n_group > 1 and (
+            self.n_experts % self.moe_n_group
+            or not 1 <= self.moe_topk_group <= self.moe_n_group
+            or self.n_experts // self.moe_n_group < 2
+        ):
+            raise ValueError(
+                "expert groups need n_experts a multiple of moe_n_group, two "
+                "experts a group and 1 <= moe_topk_group <= moe_n_group")
+        if self.experts_held_count and not (
+            0 <= self.experts_held_first
+            and self.experts_held_first + self.experts_held_count <= self.n_experts
+        ):
+            raise ValueError("the held experts lie outside [0, n_experts)")
 
         if self.layer_kinds:
             if len(self.layer_kinds) != self.n_layers:
@@ -125,6 +174,26 @@ class LlamaConfig:
         if self.n_experts == 0 or self.moe_hidden_dim == 0:
             return 0
         return self.n_layers - self.n_dense_layers
+
+    @property
+    def experts_held(self) -> tuple:
+        """(first id, count) of the routed experts held: every one unless a
+        share is named."""
+        return (self.experts_held_first, self.experts_held_count or self.n_experts)
+
+    @property
+    def sparse_attention(self) -> bool:
+        """Whether attention reads only the positions an indexer selects."""
+        return self.index_topk > 0
+
+    @property
+    def softmax_scale_factor(self) -> float:
+        """YaRN's factor on the attention scores' scale (1 elsewhere)."""
+        from ..ops.rope import yarn_mscale
+
+        if self.rope_type != RopeType.YARN or not self.rope_yarn_mscale_all_dim:
+            return 1.0
+        return yarn_mscale(self.rope_scaling_factor, self.rope_yarn_mscale_all_dim) ** 2
 
     @property
     def latent_attention(self) -> bool:

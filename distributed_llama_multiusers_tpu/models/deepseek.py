@@ -52,6 +52,39 @@ orientations, so the parameter tree holds its dequantized values in the
 activation dtype (``latent_params``: 2 x rank x heads x (nope + v) bytes a
 layer in bf16).
 
+What ``model_type: deepseek_v32`` adds, each engaged by its own field and by
+no model's name. A QUERY LATENT (``q_lora_rank > 0``): ``cq = rmsnorm(Wqa n)``,
+``q = Wqb cq``. EXPERT GROUPS (``moe_n_group > 1``; ``moe_router``). A HELD
+SHARE of the routed experts (``experts_held_count``; ``routed_ffn``): the
+router keeps every output, the stacks hold the share's slabs, a chosen expert
+outside it is another chip's and adds nothing here. YaRN (``ops/rope.py``) and
+its factor on the softmax scale. And LEARNED SPARSE ATTENTION
+(``index_topk > 0``), the lightning indexer:
+
+    qI_j = WIq cq                     index_n_heads heads of index_head_dim
+    kI   = layernorm(WIk n)           ONE row a token, the indexer's own cache
+    rotary embedding on the first qk_rope_head_dim numbers of every qI_j and of kI
+    w    = WIw n / sqrt(index_n_heads * index_head_dim)
+    I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s)),  s <= t
+    S_t  = the index_topk positions of largest I(t, .), ties to the lower position
+
+and attention's softmax runs over ``s in S_t`` only. ``kI`` is a third leaf of
+the cache tree (``IndexedLatentCache.ik``, ``[L, lanes, S, index_head_dim]``,
+whole 128-lane tiles), appended in place through the carry beside the two
+latent leaves. The selection is EXACT at every width and one code path
+(``sparse_attention``): the score pass over the key blocks the step's
+positions reach and no further, ``jax.lax.top_k`` (a sort; equal scores to the
+lower position) in segments and then over the segments' winners, the chosen
+rows GATHERED out of the stacks by (layer, lane, position), a block of queries
+at a time, and the absorbed form over those rows alone: attention reads
+``index_topk`` rows a query, not the plane. (A threshold search as the
+sampler's finds the ``index_topk``-th score of a 1024-row chunk in a third of
+the sort's time, but leaves a mask: attending a mask costs operations by the
+context's length, 580 ms of an 856 ms chunk on a v5e, where gathering costs
+by ``index_topk``: PERF.md section 6, PR 41.) No path attends an unchosen
+row, and none skips the indexer: below ``index_topk`` positions every row is
+chosen because the top-k of fewer rows is all of them.
+
 Not served by this block, and refused by ``InferenceEngine`` at start-up: the
 paged pool (and with it prefix page sharing, the host tier and KV-page
 transfer: pages are framed as a K/V pair of heads), and any mesh.
@@ -63,7 +96,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Layout
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import HiddenAct, MoeScore
 from ..ops.activations import gelu, silu
@@ -91,22 +124,24 @@ from ..telemetry.names import (
     SCOPE_EXPERTS,
     SCOPE_FFN,
     SCOPE_HEAD,
+    SCOPE_INDEXER,
     SCOPE_KV_LATENT,
     SCOPE_KV_WRITE,
     SCOPE_LAYERS,
     SCOPE_QKV,
     SCOPE_ROUTER,
     SCOPE_SHARED_EXPERT,
+    SCOPE_SPARSE_SELECT,
 )
 from .config import LlamaConfig
-from .llama import KVCache, _qdq_q80, kv_append
+from .llama import KVCache, _qdq_q80, _to_cache_dtype, kv_append
 
 
 class LatentAttnParams(NamedTuple):
     """Attention weights of every layer, stacked ``[L, ...]``; matmul weights
     ``[d_in, d_out]``, dense or ``PackedQ40``."""
 
-    wq: jnp.ndarray  # [L, dim, H * (nope + rope)]
+    wq: jnp.ndarray  # [L, dim, H * (nope + rope)]; from the query latent: [L, q_rank, ...]
     wkva: jnp.ndarray  # [L, dim, rank + rope]
     # Wkvb, DENSE, a head at a time in the two orientations the absorbed
     # form multiplies by (see latent_params)
@@ -115,6 +150,15 @@ class LatentAttnParams(NamedTuple):
     wo: jnp.ndarray  # [L, H * v, dim]
     rms_att: jnp.ndarray  # [L, dim]
     rms_kv: jnp.ndarray  # [L, rank]
+    # a query latent (config.q_lora_rank)
+    wqa: jnp.ndarray | None = None  # [L, dim, q_rank]
+    rms_q: jnp.ndarray | None = None  # [L, q_rank]
+    # the indexer (config.index_topk)
+    idx_wq: jnp.ndarray | None = None  # [L, q_rank or dim, Hi * Di]
+    idx_wk: jnp.ndarray | None = None  # [L, dim, Di]
+    idx_ww: jnp.ndarray | None = None  # [L, dim, Hi] f32: the heads' weights
+    idx_k_gain: jnp.ndarray | None = None  # [L, Di] f32: the key's layer norm
+    idx_k_bias: jnp.ndarray | None = None  # [L, Di] f32
 
 
 class DenseFfnParams(NamedTuple):
@@ -158,14 +202,25 @@ def rope_leaf_width(config: LlamaConfig) -> int:
     return -(-config.qk_rope_head_dim // ROPE_LEAF_ALIGN) * ROPE_LEAF_ALIGN
 
 
-def init_latent_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32) -> KVCache:
+class IndexedLatentCache(NamedTuple):
+    """The latent cache of a model with an indexer: the two latent leaves and
+    the index keys, one row a token a layer each, the lanes on axis 1."""
+
+    k: jnp.ndarray  # [L, lanes, S, kv_lora_rank]: the normed latent
+    v: jnp.ndarray  # [L, lanes, S, rope_leaf_width]: the rotated k_pe
+    ik: jnp.ndarray  # [L, lanes, S, index_head_dim]: the indexer's keys
+
+
+def init_latent_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32):
     """One row a token a layer: ``k`` the normed latent ``c'``, ``v`` the
-    rotated ``k_pe`` (module header, "The cache")."""
+    rotated ``k_pe`` (module header, "The cache"); with an indexer, ``ik``
+    its keys."""
     lead = (config.n_layers, n_lanes, config.seq_len)
-    return KVCache(
-        k=jnp.zeros((*lead, config.kv_lora_rank), dtype),
-        v=jnp.zeros((*lead, rope_leaf_width(config)), dtype),
-    )
+    k = jnp.zeros((*lead, config.kv_lora_rank), dtype)
+    v = jnp.zeros((*lead, rope_leaf_width(config)), dtype)
+    if config.sparse_attention:
+        return IndexedLatentCache(k=k, v=v, ik=jnp.zeros((*lead, config.index_head_dim), dtype))
+    return KVCache(k=k, v=v)
 
 
 def latent_params(t: dict, rope_cos, rope_sin, dtype, config: LlamaConfig) -> DeepseekParams:
@@ -189,6 +244,8 @@ def latent_params(t: dict, rope_cos, rope_sin, dtype, config: LlamaConfig) -> De
         wuk=jnp.transpose(wkvb[..., :nope], (0, 2, 3, 1)),
         wuv=jnp.transpose(wkvb[..., nope:], (0, 2, 1, 3)),
         rms_att=t["rms_att"], rms_kv=t["rms_kv"],
+        # the query latent's and the indexer's, where the model has them
+        **{name: t[name] for name in LatentAttnParams._field_defaults if name in t},
     )
     dense = routed = None
     if "dense_w1" in t:
@@ -215,7 +272,7 @@ def moe_router(config: LlamaConfig, y: jnp.ndarray, gate: jnp.ndarray,
     """(weights ``[..., k]`` f32, expert ids ``[..., k]`` int32). Float32
     throughout, the logits at the highest matmul precision: a choice between
     two experts is no place for a bf16 pass. The bias chooses and does not
-    weigh."""
+    weigh; with expert groups (``moe_n_group > 1``) it also ranks the groups."""
     logits = jnp.einsum(
         "...d,de->...e", y.astype(jnp.float32), gate.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -225,10 +282,18 @@ def moe_router(config: LlamaConfig, y: jnp.ndarray, gate: jnp.ndarray,
     else:
         scores = jax.nn.softmax(logits, axis=-1)
     choose = scores if bias is None else scores + bias.astype(jnp.float32)
+    if config.moe_n_group > 1:
+        # a group scores the sum of its two largest; outside the
+        # moe_topk_group best groups nothing can be chosen
+        groups = choose.reshape(*choose.shape[:-1], config.moe_n_group, -1)
+        group_score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, config.moe_topk_group)
+        kept = jnp.any(best[..., None] == jnp.arange(config.moe_n_group), axis=-2)
+        choose = jnp.where(kept[..., None], groups, -jnp.inf).reshape(choose.shape)
     _, idx = jax.lax.top_k(choose, config.n_active_experts)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if config.moe_norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + config.moe_norm_floor)
     return w * config.moe_routed_scale, idx.astype(jnp.int32)
 
 
@@ -243,6 +308,12 @@ def grouped_matmul(x_rows, w, layer, plan):
     return grouped_matmul_xla(x_rows, w, layer, plan)
 
 
+def _product_dtype(cached):
+    """The dtype cached rows are multiplied in: their own (bf16, f32), float32
+    for a narrower cache."""
+    return cached.dtype if cached.dtype in (jnp.bfloat16, jnp.float32) else jnp.float32
+
+
 def absorbed_attention(q_nope, q_pe, wuk, wuv, c_plane, r_plane, mask, scale):
     """All heads against the one latent row a token. q_nope ``[B,T,H,nope]``,
     q_pe ``[B,T,H,rope]`` (rotated), wuk ``[H, nope, rank]``, wuv
@@ -251,7 +322,7 @@ def absorbed_attention(q_nope, q_pe, wuk, wuv, c_plane, r_plane, mask, scale):
     padded with zeros alike), mask ``[B,T,S]``.
     Returns ``[B,T,H,v]`` f32. The planes are multiplied in the dtype they are
     cached in (bf16, f32), accumulated in f32."""
-    plane_dtype = c_plane.dtype if c_plane.dtype in (jnp.bfloat16, jnp.float32) else jnp.float32
+    plane_dtype = _product_dtype(c_plane)
     # (the two products by head take no float32 result type: a bf16 pair is
     # rounded once either way, to the plane's dtype here and to the stream's
     # after the second, and XLA:CPU has no bf16 x bf16 -> f32 batched dot)
@@ -270,6 +341,167 @@ def absorbed_attention(q_nope, q_pe, wuk, wuv, c_plane, r_plane, mask, scale):
         preferred_element_type=jnp.float32,
     )
     return jnp.einsum("bthc,hcv->bthv", o_lat.astype(wuv.dtype), wuv).astype(jnp.float32)
+
+# -- learned sparse attention -------------------------------------------------
+
+# positions a segment of the exact top-k (one sort a segment, then one of the
+# segments' winners): a sort of 8192 is a ninth of a quarter of a sort of
+# 32768 on a v5e (PERF.md section 6, PR 41)
+TOPK_SEGMENT = 8192
+# queries whose chosen rows are gathered and attended at a time (a block's
+# rows: QUERY_BLOCK x index_topk x (rank + rope leaf) in the cache's dtype)
+QUERY_BLOCK = 256
+# index scores made at a time, all heads: rows x heads x keys float32
+SCORE_BLOCK_ELEMENTS = 1 << 26
+
+
+def _layer_norm(x, gain, bias, eps):
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps) * gain + bias).astype(x.dtype)
+
+
+def _rope_first(x, n: int, cos, sin, positions):
+    """The rotary embedding on the first ``n`` numbers of every head of ``x``
+    ``[B, T, H, D]``, the rest as they are."""
+    return jnp.concatenate([apply_rope(x[..., :n], cos, sin, positions), x[..., n:]], axis=-1)
+
+
+def index_scores_block(qi, w, ik_block):
+    """``I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s))`` for a block of keys:
+    qi ``[B, T, Hi, Di]``, w ``[B, T, Hi]`` f32, ik_block ``[B, K, Di]`` ->
+    ``[B, T, K]`` f32. The products are made in the dtype the keys are cached
+    in and accumulated in float32; the weighted sum over heads is float32
+    arithmetic, not a second matmul pass."""
+    pd = _product_dtype(ik_block)
+    s = jnp.einsum("btjd,bkd->btjk", qi.astype(pd), ik_block.astype(pd),
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(w[..., None] * jax.nn.relu(s), axis=2)
+
+
+def exact_topk(scores, k: int, last):
+    """The positions of the ``k`` largest of every row of ``scores``
+    ``[B, T, S]``, equal scores to the lower position: a sort (never
+    ``approx_max_k``), over segments of ``TOPK_SEGMENT`` positions and then
+    over the segments' winners, which hold the row's ``k`` largest whatever
+    the split. A segment that starts past ``last`` (the last position any row
+    may choose) is not sorted: its places are filled with ``-inf`` at its own
+    positions, which no row can choose; while ``last`` lies in the first
+    segment its winners are the answer and nothing is merged. Ties: a
+    segment's winners come by falling score and, equal, by rising position
+    (``jax.lax.top_k``: the lower index first), the segments in order, and the
+    merge is a STABLE sort of (score, position) pairs, so equal scores keep
+    that order: the lower position first."""
+    b, t, seq = scores.shape
+    seg = min(TOPK_SEGMENT, seq)
+    if seq % seg:
+        raise ValueError(f"a context of {seq} is not whole segments of {seg}")
+    if seq == seg or t == 1:
+        # one segment, or one row a lane: a handful of rows sort whole in a
+        # third of the time their four segments and the merge take (0.31
+        # against 1.1 ms for 8 rows of 32768 on a v5e)
+        return jax.lax.top_k(scores, k)[1]
+    ks = min(k, seg)
+
+    def winners(j):
+        def sort():
+            v, i = jax.lax.top_k(jax.lax.dynamic_slice_in_dim(scores, j * seg, seg, axis=2), ks)
+            return v, i + j * seg
+
+        def skip():
+            return (jnp.full((b, t, ks), -jnp.inf, scores.dtype),
+                    jnp.broadcast_to(j * seg + jnp.arange(ks, dtype=jnp.int32), (b, t, ks)))
+
+        return jax.lax.cond(j * seg <= last, sort, skip)
+
+    vals, idx = jax.lax.map(winners, jnp.arange(seq // seg, dtype=jnp.int32))
+
+    def merge():
+        # the pairs sorted together: no gather of positions by rank afterwards
+        v = jnp.moveaxis(vals, 0, 2).reshape(b, t, -1)
+        i = jnp.moveaxis(idx, 0, 2).reshape(b, t, -1)
+        return jax.lax.sort((-v, i), dimension=2, is_stable=True, num_keys=1)[1][..., :k]
+
+    if ks < k:  # a segment holds fewer than k positions: always merged
+        return merge()
+    return jax.lax.cond(last < seg, lambda: idx[0][..., :k], merge)
+
+
+def sparse_attention(cfg, q_nope, q_pe, qi, w, wuk, wuv, c_all, r_all, ik_all, l,
+                     positions, scale):
+    """Attention over the rows the indexer chooses, at any width (module
+    header): every query's scores over the key blocks the step's positions
+    reach, its ``index_topk`` best positions (exact), their latent rows
+    GATHERED out of the stacks as the carry holds them, by (layer, lane,
+    position), a block of queries at a time, and the absorbed form over those
+    rows alone. Returns (``[B, T, H, v]`` f32, index keys scored, rows
+    attended), the counts over live rows."""
+    b, t = positions.shape
+    seq = c_all.shape[2]
+    k = min(cfg.index_topk, seq)
+    live = positions < seq
+    last = jnp.max(jnp.where(live, positions, -1))  # the last row any query may read
+    with jax.named_scope(SCOPE_INDEXER):
+        # the score pass, a block of keys at a time up to ``last``
+        kb = seq
+        while kb > 1024 and b * t * cfg.index_n_heads * kb > SCORE_BLOCK_ELEMENTS and kb % 2 == 0:
+            kb //= 2
+        s_in_block = jnp.arange(kb)
+
+        def score_block(j, out):
+            ik_block = jax.lax.dynamic_slice(
+                ik_all, (l, 0, j * kb, 0), (1, b, kb, ik_all.shape[-1]))[0]
+            sc = index_scores_block(qi, w, ik_block)
+            held = (j * kb + s_in_block)[None, None, :] <= positions[..., None]
+            return jax.lax.dynamic_update_slice(
+                out, jnp.where(held, sc, -jnp.inf), (0, 0, j * kb))
+
+        scores = jax.lax.fori_loop(
+            0, (last + kb) // kb, score_block, jnp.full((b, t, seq), -jnp.inf, jnp.float32))
+    with jax.named_scope(SCOPE_SPARSE_SELECT):
+        idx = exact_topk(scores, k, last)  # [B, T, k]
+        chosen = idx <= positions[..., None]  # fewer than k rows held: the -inf fill drops out
+    qb = min(QUERY_BLOCK, t)
+    if t % qb:
+        raise ValueError(f"a step of {t} rows is not whole blocks of {qb} queries")
+    pd = _product_dtype(c_all)
+    with jax.named_scope(SCOPE_ATTENTION):
+        q_abs = jnp.einsum("bthn,hnc->bthc", q_nope.astype(wuk.dtype), wuk).astype(pd)
+        q_pe = q_pe.astype(pd)
+    # a chosen row's place in a stack seen as rows (layer, lane, position): a
+    # view that moves no byte, gathered by ONE index a row (XLA's general
+    # gather by (layer, lane, position) triples ran at a third of the speed on
+    # a v5e: PERF.md section 6, PR 41)
+    row = (l * c_all.shape[1] + jnp.arange(b, dtype=jnp.int32)[:, None, None]) * seq + idx
+    c_flat = c_all.reshape(-1, c_all.shape[-1])
+    r_flat = r_all.reshape(-1, r_all.shape[-1])
+
+    def attend(q0):
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, q0, qb, axis=1)
+        with jax.named_scope(SCOPE_SPARSE_SELECT):  # the gather of the chosen rows
+            at = cut(row)
+            c_rows, r_rows = c_flat[at].astype(pd), r_flat[at].astype(pd)  # [B, qb, k, width]
+        with jax.named_scope(SCOPE_ATTENTION):
+            sc = jnp.einsum("bthc,btkc->bthk", cut(q_abs), c_rows,
+                            preferred_element_type=jnp.float32)
+            sc = sc + jnp.einsum("bthr,btkr->bthk", cut(q_pe), r_rows,
+                                 preferred_element_type=jnp.float32)
+            sc = jnp.where(cut(chosen)[:, :, None, :], sc * scale, -jnp.inf)
+            probs = jax.nn.softmax(sc, axis=-1)
+            return jnp.einsum("bthk,btkc->bthc", probs.astype(pd), c_rows,
+                              preferred_element_type=jnp.float32)
+
+    if t == qb:
+        o_lat = attend(0)
+    else:  # a block of queries at a time: one block's rows live at once
+        o_lat = jax.lax.map(attend, jnp.arange(0, t, qb))  # [t / qb, B, qb, H, rank]
+        o_lat = jnp.moveaxis(o_lat, 0, 1).reshape(b, t, *o_lat.shape[3:])
+    with jax.named_scope(SCOPE_ATTENTION):
+        o = jnp.einsum("bthc,hcv->bthv", o_lat.astype(wuv.dtype), wuv).astype(jnp.float32)
+    scored = jnp.sum(jnp.where(live, positions + 1, 0)).astype(jnp.int32)
+    picked = jnp.sum(chosen & live[..., None]).astype(jnp.int32)
+    return o, scored, picked
 
 
 class FfnOps(NamedTuple):
@@ -306,7 +538,11 @@ def dense_ffn(cfg: LlamaConfig, ops: FfnOps, x, dp: "DenseFfnParams"):
 def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live):
     """A routed layer's FFN half. ``rp``: the layer's parameters, the expert
     stacks whole; ``lm`` its index into them; ``live`` ``[B * T]``: False for
-    a parked row, which routes nowhere. Returns (x, slabs, assignments)."""
+    a parked row, which routes nowhere. Returns (x, slabs, assignments,
+    unheld): distinct slabs fetched, (live row, expert) pairs that fetched
+    one, and pairs whose expert lies outside the held share
+    (``cfg.experts_held``): those fetch nothing and add nothing, exactly as a
+    parked row's do, and their weight stays in the renormalising sum."""
     b, t, _ = x.shape
     n = b * t
     dtype = x.dtype
@@ -316,7 +552,17 @@ def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live
         with jax.named_scope(SCOPE_ROUTER):
             topw, topi = moe_router(cfg, y.reshape(n, -1), rp.gate, rp.bias)
         with jax.named_scope(SCOPE_EXPERTS):
-            plan = route_plan(topi, live, cfg.n_experts)
+            if cfg.experts_held_count:
+                first, held = cfg.experts_held
+                local = topi - first  # ids into the stacks, which hold the share
+                here = (local >= 0) & (local < held)
+                # an expert of another chip's share sorts with the parked rows
+                plan = route_plan(jnp.where(here, local, held), live, held)
+                unheld = jnp.sum(live[:, None] & ~here).astype(jnp.int32)
+                fetched = plan.assignments - unheld
+            else:
+                plan = route_plan(topi, live, cfg.n_experts)
+                unheld, fetched = jnp.zeros((), jnp.int32), plan.assignments
             rows = jnp.concatenate(
                 [yq.reshape(n, -1), jnp.zeros((1, yq.shape[-1]), yq.dtype)]
             )[plan.src]  # [P, dim], sorted by expert, a zero row where none
@@ -331,7 +577,7 @@ def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live
             with jax.named_scope(SCOPE_SHARED_EXPERT):
                 out = out + gated_ffn(ops, yq, rp.s1, rp.s2, rp.s3)
         x = x + ops.maybe_qdq(out.astype(dtype))
-    return x, plan.slabs, plan.assignments
+    return x, plan.slabs, fetched, unheld
 
 
 def _pick(leaf, index):
@@ -357,13 +603,17 @@ def deepseek_forward_counted(
     mesh=None,
     q80_sync: bool = False,
 ):
-    """(logits ``[B, T, vocab]`` f32, updated cache, counts). ``counts`` is
-    ``(slabs, assignments)``, int32 scalars summed over the routed layers:
-    distinct (layer, expert) slabs one expert matrix read, and (row, expert)
-    pairs routed; None for a model without routed layers."""
+    """(logits ``[B, T, vocab]`` f32, updated cache, counts). ``counts`` is a
+    tuple of int32 scalars summed over the layers, named by
+    ``count_names(config)``: ``(slabs, assignments)`` of the routed layers
+    (distinct (layer, expert) slabs one expert matrix read, and (row, expert)
+    pairs that read one), then ``unheld`` where a share of the experts is held
+    (pairs whose expert is another chip's), then ``(scored, selected)`` where
+    an indexer chooses (index keys scored, rows attended); None for a model
+    with none of them."""
     if mesh is not None or q80_sync:
         raise ValueError("the latent-attention block runs on one device: no mesh")
-    if not isinstance(cache, KVCache):
+    if not isinstance(cache, (KVCache, IndexedLatentCache)):
         raise ValueError("the latent-attention block keeps a contiguous latent cache")
     cfg = config
     b, t = tokens.shape
@@ -372,60 +622,102 @@ def deepseek_forward_counted(
     eps = cfg.norm_epsilon
     ops = ffn_ops(cfg, emulate_q80_activations, isinstance(params.attn.wq, PackedQ40))
     maybe_qdq, share_q80 = ops.maybe_qdq, ops.share_q80
-    scale = 1.0 / float(nope + rope) ** 0.5
+    scale = cfg.softmax_scale_factor / float(nope + rope) ** 0.5
+    sparse = cfg.sparse_attention
+    if sparse != isinstance(cache, IndexedLatentCache):
+        raise ValueError("an indexer's keys are a third leaf of the cache, and only its")
 
     with jax.named_scope(SCOPE_EMBED):
         x = params.embedding[tokens]
     dtype = x.dtype
     lane_idx = jnp.arange(b)[:, None]
     live = (positions < cfg.seq_len).reshape(b * t)
-    with jax.named_scope(SCOPE_ATTENTION):
-        s_idx = jnp.arange(cfg.seq_len)
-        attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
+    if not sparse:
+        with jax.named_scope(SCOPE_ATTENTION):
+            s_idx = jnp.arange(cfg.seq_len)
+            attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
     row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
+    cos, sin = params.rope_cos, params.rope_sin
 
-    def attention(x, ap, l, c_all, r_all):
+    def attention(x, ap, l, leaves):
+        """One layer's attention half. ``leaves``: the cache's stacks as the
+        carry holds them; returns (x, leaves, (rows scored, rows chosen))."""
+        c_all, r_all = leaves[:2]
         with jax.named_scope(SCOPE_QKV):
             y = rms_norm(x, ap.rms_att, eps)
-            yq = share_q80(maybe_qdq(y))  # one operand build for wq and wkva
-            q = matmul(yq, ap.wq).reshape(b, t, n_heads, nope + rope)
+            yq = share_q80(maybe_qdq(y))  # one operand build for the projections of n
+            if ap.wqa is not None:  # the query latent
+                cq = maybe_qdq(rms_norm(matmul(yq, ap.wqa), ap.rms_q, eps))
+                if sparse:
+                    cq = share_q80(cq)  # wq and the indexer's queries
+            else:
+                cq = yq
+            q = matmul(cq, ap.wq).reshape(b, t, n_heads, nope + rope)
             with jax.named_scope(SCOPE_KV_LATENT):
                 kva = matmul(yq, ap.wkva)  # [B, T, rank + rope]
                 c = rms_norm(kva[..., :rank], ap.rms_kv, eps)
-                k_pe = apply_rope(
-                    kva[..., None, rank:], params.rope_cos, params.rope_sin, positions
-                )
-            q_pe = apply_rope(q[..., nope:], params.rope_cos, params.rope_sin, positions)
+                k_pe = apply_rope(kva[..., None, rank:], cos, sin, positions)
+            q_pe = apply_rope(q[..., nope:], cos, sin, positions)
             # both rotated parts at the rope leaf's width, zeros past the end
             k_pe, q_pe = (
                 jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, r_all.shape[-1] - rope)])
                 for a in (k_pe[:, :, 0], q_pe)
             )
+            fresh = (q, q_pe, c, k_pe)
+            if sparse:
+                with jax.named_scope(SCOPE_INDEXER):
+                    qi = matmul(cq, ap.idx_wq).reshape(
+                        b, t, cfg.index_n_heads, cfg.index_head_dim)
+                    qi = _rope_first(qi, rope, cos, sin, positions)
+                    ki = _layer_norm(matmul(yq, ap.idx_wk), ap.idx_k_gain, ap.idx_k_bias, eps)
+                    ki = _rope_first(ki[:, :, None], rope, cos, sin, positions)[:, :, 0]
+                    # float32 like the router: the weights rank positions
+                    w = jnp.einsum(
+                        "btd,dj->btj", y.astype(jnp.float32), ap.idx_ww.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST,
+                    ) * float(cfg.index_n_heads * cfg.index_head_dim) ** -0.5
+                fresh += (qi, ki, w)
             # the projections finish before the cache is touched, as in
             # models/llama.py's layer_step
-            q, q_pe, c, k_pe = jax.lax.optimization_barrier((q, q_pe, c, k_pe))
+            fresh = jax.lax.optimization_barrier(fresh)
+            q, q_pe, c, k_pe = fresh[:4]
         with jax.named_scope(SCOPE_KV_WRITE):
             at = (l, lane_idx, positions)
             c_all, r_all = kv_append(c_all, r_all, at, c, k_pe, row_major)
-        with jax.named_scope(SCOPE_ATTENTION):
-            # the layer's latent plane, read out of the carry AFTER the append
-            c_plane = jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
-            r_plane = jax.lax.dynamic_index_in_dim(r_all, l, 0, keepdims=False)
-            o = absorbed_attention(
-                q[..., :nope], q_pe, ap.wuk, ap.wuv, c_plane, r_plane, attn_mask, scale,
-            )
-            attn = o.reshape(b, t, n_heads * vd).astype(dtype)
+        if not sparse:
+            with jax.named_scope(SCOPE_ATTENTION):
+                # the layer's latent plane, read out of the carry AFTER the append
+                c_plane = jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
+                r_plane = jax.lax.dynamic_index_in_dim(r_all, l, 0, keepdims=False)
+                o = absorbed_attention(
+                    q[..., :nope], q_pe, ap.wuk, ap.wuv, c_plane, r_plane, attn_mask, scale,
+                )
+            leaves, seen = (c_all, r_all), ()
+        else:
+            qi, ki, w = fresh[4:]
+            with jax.named_scope(SCOPE_INDEXER):  # the index key's append
+                ik_all = leaves[2].at[at].set(
+                    _to_cache_dtype(ki, leaves[2].dtype), mode="drop")
+                ik_all = with_layout_constraint(ik_all, row_major)
+            o, scored, picked = sparse_attention(
+                cfg, q[..., :nope], q_pe, qi, w, ap.wuk, ap.wuv, c_all, r_all, ik_all, l,
+                positions, scale)
+            leaves, seen = (c_all, r_all, ik_all), (scored, picked)
+        attn = o.reshape(b, t, n_heads * vd).astype(dtype)
         with jax.named_scope(SCOPE_ATTN_OUT):
             x = x + maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
-        return x, c_all, r_all
+        return x, leaves, seen
 
     n_dense = cfg.n_dense_layers if params.routed is not None else cfg.n_layers
+    zero = jnp.zeros((), jnp.int32)
     with jax.named_scope(SCOPE_LAYERS):
-        c_all, r_all = cache.k, cache.v
+        leaves = tuple(cache)
+        seen = (zero, zero) if sparse else ()
         for i in range(n_dense):  # the leading dense layers, before the scan
             l = jnp.int32(i)
             ap = LatentAttnParams(*(_pick(leaf, l) for leaf in params.attn))
-            x, c_all, r_all = attention(x, ap, l, c_all, r_all)
+            x, leaves, more = attention(x, ap, l, leaves)
+            seen = tuple(a + m for a, m in zip(seen, more))
             dp = DenseFfnParams(*(_pick(leaf, l) for leaf in params.dense))
             x = dense_ffn(cfg, ops, x, dp)
 
@@ -434,26 +726,41 @@ def deepseek_forward_counted(
             def layer_step(carry, lm):
                 # every stack is closed over and read at its layer index: a
                 # Q40 stack by the kernels, the rest by a slice of one layer
-                x, c_all, r_all, slabs, assigned = carry
+                x, leaves, routed, seen = carry
                 l = lm + n_dense
                 ap = LatentAttnParams(*(_pick(leaf, l) for leaf in params.attn))
-                x, c_all, r_all = attention(x, ap, l, c_all, r_all)
+                x, leaves, more = attention(x, ap, l, leaves)
                 rp = RoutedFfnParams(*(_pick(leaf, lm) for leaf in params.routed))
-                x, s, a = routed_ffn(cfg, ops, x, rp, lm, live)
-                return (x, c_all, r_all, slabs + s, assigned + a), None
+                x, *more_routed = routed_ffn(cfg, ops, x, rp, lm, live)
+                return (x, leaves, tuple(a + m for a, m in zip(routed, more_routed)),
+                        tuple(a + m for a, m in zip(seen, more))), None
 
-            zero = jnp.zeros((), jnp.int32)
-            (x, c_all, r_all, slabs, assigned), _ = jax.lax.scan(
-                layer_step, (x, c_all, r_all, zero, zero),
+            # (slabs, assignments) and, where a share of the experts is held,
+            # the pairs that fell outside it
+            routed0 = (zero,) * (3 if cfg.experts_held_count else 2)
+            (x, leaves, routed, seen), _ = jax.lax.scan(
+                layer_step, (x, leaves, routed0, seen),
                 jnp.arange(cfg.n_layers - n_dense, dtype=jnp.int32),
             )
-            counts = (slabs, assigned)
+            counts = routed + seen
+        elif sparse:
+            counts = seen
 
     with jax.named_scope(SCOPE_HEAD):
         y = rms_norm(x, params.rms_final, eps)
         logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)
         logits = logits[..., : cfg.vocab_size]
-    return logits, KVCache(k=c_all, v=r_all), counts
+    return logits, type(cache)(*leaves), counts
+
+
+def count_names(config: LlamaConfig) -> tuple:
+    """The names of ``deepseek_forward_counted``'s counts, in their order."""
+    names = ()
+    if config.n_routed_layers:
+        names += ("slabs", "assignments") + (("unheld",) if config.experts_held_count else ())
+    if config.sparse_attention:
+        names += ("scored", "selected")
+    return names
 
 
 def deepseek_forward(config, params, tokens, positions, cache, **kw):

@@ -324,7 +324,7 @@ def hybrid_forward_counted(
         x, k_all, v_all, s_all, slabs, assigned = carry
         x, k_all, v_all, s_all = mixer(kind, x, ai, ci, k_all, v_all, s_all)
         rp = RoutedFfnParams(*(_pick(leaf, lm) for leaf in params.routed))
-        x, s, a = routed_ffn(cfg, ops, x, rp, lm, live)
+        x, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live)
         return (x, k_all, v_all, s_all, slabs + s, assigned + a)
 
     n_dense = cfg.n_dense_layers if params.routed is not None else cfg.n_layers

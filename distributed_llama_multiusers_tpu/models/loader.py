@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 _BF16_NP = np.dtype(ml_dtypes.bfloat16)
 
-from ..formats.model_file import ModelHeader, iter_model_tensors
+from ..formats.model_file import ModelHeader, RopeType, iter_model_tensors
 from ..ops.rope import build_rope_cache
 from ..quants.codec import FloatType, dequantize_q40, dequantize_q80
 from ..quants.packed import (
@@ -120,6 +120,7 @@ def _rope_cache(config: LlamaConfig):
         config.rope_scaling_low_freq_factor,
         config.rope_scaling_high_freq_factor,
         config.rope_scaling_orig_max_seq_len,
+        yarn=config.rope_type == RopeType.YARN,
     )
 
 
@@ -153,8 +154,20 @@ _LATENT_NAME_MAP = {
     "block_rms_norm_kv": "rms_kv",
     "block_rms_norm_0": "rms_att",
     "block_rms_norm_1": "rms_ffn",
+    # a query latent and the indexer (header.q_lora_rank, header.index_topk)
+    "block_matmul_q_a": "wqa",
+    "block_rms_norm_q": "rms_q",
+    "block_matmul_idx_q": "idx_wq",
+    "block_matmul_idx_k": "idx_wk",
+    "block_idx_k_norm_gain": "idx_k_gain",
+    "block_idx_k_norm_bias": "idx_k_bias",
+    "block_idx_weights": "idx_ww",
 }
-_LATENT_VECTORS = {"moe_bias", "rms_kv", "rms_att", "rms_ffn"}
+_LATENT_VECTORS = {"moe_bias", "rms_kv", "rms_att", "rms_ffn", "rms_q",
+                   "idx_k_gain", "idx_k_bias"}
+# keyed by layer, whatever the layer's FFN is
+_LATENT_BY_LAYER = {"wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv", "wqa", "rms_q",
+                    "idx_wq", "idx_wk", "idx_k_gain", "idx_k_bias", "idx_ww"}
 
 
 def _load_stacked(path: str, header: ModelHeader, dtype, put, quantized: bool,
@@ -175,7 +188,8 @@ def _load_stacked(path: str, header: ModelHeader, dtype, put, quantized: bool,
                 x = pad_packed_d_out(*x)
         else:
             x = _decode_tensor(raw, spec.float_type, spec.shape)
-            x = x.T if matmul or spec.name in ("block_moe_gate", "block_conv_taps") else x
+            x = x.T if matmul or spec.name in (
+                "block_moe_gate", "block_conv_taps", "block_idx_weights") else x
         if not spec.name.startswith("block_"):
             top[spec.name] = x
             continue
@@ -228,12 +242,12 @@ def load_latent_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16
         index = (spec.layer,)
         if key in ("w1", "w2", "w3", "rms_ffn") and spec.expert < 0 and spec.layer < n_dense:
             key = "dense_" + key
-        elif key not in ("wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv"):
+        elif key not in _LATENT_BY_LAYER:
             index = (spec.layer - n_dense,) + ((spec.expert,) if spec.expert >= 0 else ())
         return key, index
 
     t = _load_stacked(path, header, dtype, put, quantized, place,
-                      _LATENT_VECTORS | {"moe_gate", "dense_rms_ffn"})
+                      _LATENT_VECTORS | {"moe_gate", "dense_rms_ffn", "idx_ww"})
     cos, sin = _rope_cache(config)
     return config, latent_params(t, put("rope_cos", cos), put("rope_sin", sin), dtype, config)
 

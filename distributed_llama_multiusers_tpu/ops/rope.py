@@ -38,6 +38,34 @@ def _scale_frequency_llama3(
     return (1 - smooth) * freq / scaling_factor + smooth * freq
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor for a context stretched ``factor`` times."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_size: int, rope_theta: float, factor: float,
+                     beta_fast: float, beta_slow: float, orig_max_seq_len: int) -> np.ndarray:
+    """YaRN's frequencies of the ``head_size // 2`` pairs: the plain ones
+    ``theta^(-2p/head)`` where a pair turns more than ``beta_fast`` times over
+    the original context, those over ``factor`` where it turns fewer than
+    ``beta_slow`` times, a linear blend over the pairs between (the
+    "correction range", floor and ceiling of the pair at which a turn count
+    is reached, clamped to the head)."""
+    half = head_size // 2
+    base = 1.0 / (rope_theta ** (2.0 * np.arange(half, dtype=np.float64) / head_size))
+
+    def pair_at(turns: float) -> float:
+        return head_size * math.log(orig_max_seq_len / (turns * 2.0 * math.pi)) / (
+            2.0 * math.log(rope_theta))
+
+    low = max(math.floor(pair_at(beta_fast)), 0)
+    high = min(math.ceil(pair_at(beta_slow)), head_size - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return base * (1.0 - ramp) + (base / factor) * ramp
+
+
 def build_rope_cache(
     seq_len: int,
     head_size: int,
@@ -47,12 +75,20 @@ def build_rope_cache(
     high_freq_factor: float = 0.0,
     orig_max_seq_len: int = 0,
     dtype=np.float32,
+    yarn: bool = False,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Returns (cos, sin), each [seq_len, head_size // 2], float32.
 
     Frequencies follow the reference: pair p (elements 2p, 2p+1 of a head)
     uses theta^(-2p/head_size) (src/nn/nn-core.cpp:328-333).
     """
+    if yarn:
+        # the four scaling numbers are YaRN's factor, beta_slow, beta_fast and
+        # original context; the rotation itself is not scaled
+        freqs = yarn_frequencies(head_size, rope_theta, scaling_factor,
+                                 high_freq_factor, low_freq_factor, orig_max_seq_len)
+        t = np.arange(seq_len, dtype=np.float64)[:, None] * freqs[None, :]
+        return np.cos(t).astype(dtype), np.sin(t).astype(dtype)
     half = head_size // 2
     freqs = np.empty(half, dtype=np.float64)
     apply_scaling = scaling_factor != 1.0

@@ -47,7 +47,11 @@ from ..models.llama import (
     init_kv_cache,
     init_paged_kv_cache,
 )
-from ..models.deepseek import forward_counted, init_latent_cache
+from ..models.deepseek import (
+    count_names,
+    forward_counted,
+    init_latent_cache,
+)
 from ..models.hybrid import init_hybrid_cache
 from ..ops import pallas_attention
 from ..telemetry.logs import log_event
@@ -264,6 +268,22 @@ class EngineStats:
     moe_slabs_read: int = 0
     moe_slabs_whole: int = 0
     moe_assignments: int = 0
+    # a held share of the routed experts (config.experts_held_count; 0 and 0
+    # where every expert is held): the experts a layer holds here (fixed at
+    # start-up, kept by reset()), and the (row, expert) pairs of the decode
+    # steps whose expert is another chip's: they fetch nothing and add
+    # nothing, and moe_assignments counts only the pairs that did fetch
+    moe_experts_held: int = 0
+    moe_rows_unheld: int = 0
+    # learned sparse attention (config.index_topk; all 0 elsewhere), counted
+    # on the device and brought back like the routed counts: index keys the
+    # decode steps scored (lanes x layers x rows held) and latent rows their
+    # attention then read (at most index_topk a lane a layer). With an
+    # indexer the scheduler's attn_kv_rows_read counts the rows CHOSEN (of
+    # one layer, as ever) and attn_kv_rows_whole the rows HELD by the live
+    # lanes, so their ratio is what the selection leaves of the context
+    indexer_rows_scored: int = 0
+    sparse_rows_selected: int = 0
     # a model whose lanes carry a state overwritten in place beside the KV
     # cache (models/hybrid.py; all 0 for every other model): the bytes of
     # that state over all lanes (fixed at start-up, kept by reset());
@@ -315,6 +335,8 @@ class EngineStats:
             "grammar_lanes", "grammar_masked_steps",
             "attn_kv_rows_read", "attn_kv_rows_whole",
             "moe_slabs_read", "moe_slabs_whole", "moe_assignments",
+            "moe_experts_held", "moe_rows_unheld",
+            "indexer_rows_scored", "sparse_rows_selected",
             "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
             "jit_compiles_after_warmup",
         ),
@@ -355,6 +377,7 @@ class EngineStats:
             self.grammar_lanes = self.grammar_masked_steps = 0
             self.attn_kv_rows_read = self.attn_kv_rows_whole = 0
             self.moe_slabs_read = self.moe_slabs_whole = self.moe_assignments = 0
+            self.moe_rows_unheld = self.indexer_rows_scored = self.sparse_rows_selected = 0
             self.state_zero_starts = self.prefix_reuse_declined = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
@@ -586,6 +609,16 @@ class InferenceEngine:
         # routed layers x experts: what a decode step adds to moe_slabs_whole
         # (0: no routed layers, and no counts ride the token readback)
         self.moe_slabs_per_step = config.n_routed_layers * config.n_experts
+        self.stats.moe_experts_held = config.experts_held_count
+        # what the counts that ride a decode step's token readback are, in
+        # their order (models/deepseek.count_names); () where none ride it
+        self._count_names = count_names(config) if config.latent_attention else (
+            ("slabs", "assignments") if config.n_routed_layers else ())
+        # an indexer's selection is made for one new row a lane or for a
+        # prompt chunk; a verify step's rows are not served (declined by
+        # name: path_facts, the scheduler's start-up line)
+        if config.sparse_attention:
+            self.supports_speculative = self.supports_spec_pipelined = False
         # a verify step advances a lane by rows it may reject, and a state
         # overwritten in place cannot give them back: a model with one is
         # served without speculation (the scheduler and warmup_engine ask;
@@ -1494,6 +1527,8 @@ class InferenceEngine:
         cfg = self.config
         if self.decode_attention_block is not None:
             attention = "pallas_in_place"
+        elif cfg.sparse_attention:
+            attention = "sparse_topk"
         elif cfg.latent_attention:
             attention = "xla_dense_latent_absorbed"
         else:
@@ -1507,6 +1542,16 @@ class InferenceEngine:
         else:
             experts = "xla_gathered_slabs"
         facts = {"attention_path": attention, "expert_path": experts}
+        if cfg.sparse_attention:
+            # how the chosen rows are read (models/deepseek.py: gathered,
+            # at every width), and what an indexer's rows are not computed for
+            facts.update(
+                index_topk=cfg.index_topk,
+                sparse_rows="gathered",
+                declined_for_sparse_attention=["speculation"],
+            )
+        if cfg.experts_held_count:
+            facts["experts_held"] = f"{cfg.experts_held_count}/{cfg.n_experts}"
         if cfg.recurrent_state:
             # what is declined for a state overwritten in place, said where
             # the paths are said
@@ -2079,12 +2124,18 @@ class InferenceEngine:
         if kind == "spec":
             return toks_np[:, :-1], toks_np[:, -1]
         if toks_np.shape[0] > 2:
-            # a routed model's decode step: its slab counts, made on the
-            # device, came with the tokens (``_token_rows``)
+            # a decode step's counts (a routed FFN's slab reads, an indexer's
+            # rows), made on the device, came with the tokens (``_token_rows``)
+            got = {name: int(toks_np[2 + i, 0]) for i, name in enumerate(self._count_names)}
             with self.stats.lock:
-                self.stats.moe_slabs_read += int(toks_np[2, 0])
-                self.stats.moe_assignments += int(toks_np[3, 0])
-                self.stats.moe_slabs_whole += self.moe_slabs_per_step
+                if "slabs" in got:
+                    self.stats.moe_slabs_read += got["slabs"]
+                    self.stats.moe_assignments += got["assignments"]
+                    self.stats.moe_slabs_whole += self.moe_slabs_per_step
+                    self.stats.moe_rows_unheld += got.get("unheld", 0)
+                if "scored" in got:
+                    self.stats.indexer_rows_scored += got["scored"]
+                    self.stats.sparse_rows_selected += got["selected"]
         return toks_np[0], toks_np[1]
 
     def pipeline_flush(self, count: bool = True) -> int:
@@ -2138,6 +2189,12 @@ class InferenceEngine:
 
     def _check_speculative(self) -> None:
         if not self.supports_speculative:
+            if self.config.sparse_attention:
+                raise ValueError(
+                    "a model with an indexer (index_topk) is served without "
+                    "speculation: its selection is computed for one new row "
+                    "a lane or for a prompt chunk, not for a verify step's rows"
+                )
             raise ValueError(
                 "a model with a recurrent per-lane state is served without "
                 "speculation: a verify step advances the state by rows it "

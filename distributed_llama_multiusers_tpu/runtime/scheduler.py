@@ -463,6 +463,14 @@ class ContinuousBatchingScheduler:
         # by what its configuration is (the scheduler_start line says so)
         self._recurrent_state = bool(
             getattr(getattr(engine, "config", None), "recurrent_state", False))
+        # learned sparse attention (models/deepseek.py): rows a lane's decode
+        # step attends at most (0: every row held); speculation is declined
+        # for such a model (the scheduler_start line says so)
+        cfg = getattr(engine, "config", None)
+        self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
+        # a held share of the routed experts, "16/256" (None: every expert)
+        held = int(getattr(cfg, "experts_held_count", 0) or 0)
+        self._experts_held = f"{held}/{cfg.n_experts}" if held else None
         self.multi_step = multi_step
         self.pipelined = pipelined
         self.fused_prefill = fused_prefill
@@ -565,6 +573,11 @@ class ContinuousBatchingScheduler:
             # both are declined whatever the two settings above say
             **({"recurrent_state": True, "prefix_reuse": "declined",
                 "speculation": "declined"} if self._recurrent_state else {}),
+            # an indexer chooses the rows attention reads: said with what is
+            # declined for it (a verify step's rows have no selection)
+            **({"attention_path": "sparse_topk", "index_topk": self._index_topk,
+                "speculation": "declined"} if self._index_topk else {}),
+            **({"experts_held": self._experts_held} if self._experts_held else {}),
             # compile stability: True once warmup_engine armed the
             # recompile witness (analysis/jitcheck.py) — the normal
             # make_scheduler order warms before start(), so a False here
@@ -898,10 +911,19 @@ class ContinuousBatchingScheduler:
         engine = self.engine
         seq_len = engine.config.seq_len
         block = getattr(engine, "decode_attention_block", None)
-        whole = len(positions) * seq_len * steps
-        read = whole if block is None else sum(
-            rows_read(positions + s, seq_len, block) for s in range(steps)
-        )
+        if self._index_topk:
+            # an indexer chose: a live lane's step attends min(rows held,
+            # index_topk) rows of the rows it holds, a parked lane none
+            # dlint: ok[host-sync] the host's own lane positions (numpy ints), no device value
+            pos = np.asarray(positions, np.int64)
+            held = [pos[pos < seq_len] + s + 1 for s in range(steps)]
+            whole = int(sum(h.sum() for h in held))
+            read = int(sum(np.minimum(h, self._index_topk).sum() for h in held))
+        else:
+            whole = len(positions) * seq_len * steps
+            read = whole if block is None else sum(
+                rows_read(positions + s, seq_len, block) for s in range(steps)
+            )
         with engine.stats.lock:
             engine.stats.attn_kv_rows_read += read
             engine.stats.attn_kv_rows_whole += whole

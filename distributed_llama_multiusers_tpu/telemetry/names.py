@@ -61,6 +61,13 @@ SCOPE_EXPERTS = "dl.experts"      # under dl.ffn: sort by expert, grouped kernel
 SCOPE_SHARED_EXPERT = "dl.shared_expert"  # under dl.ffn: the always-on gated FFN
 LATENT_BLOCK_SCOPES = (SCOPE_KV_LATENT, SCOPE_ROUTER, SCOPE_EXPERTS, SCOPE_SHARED_EXPERT)
 
+# learned sparse attention in that block (config.index_topk): two scopes
+# beside dl.attention, under its parent, so dl.attention keeps what attends
+# the chosen rows and nothing else
+SCOPE_INDEXER = "dl.indexer"  # the indexer's projections, norm, rotation, its key's append, the score pass
+SCOPE_SPARSE_SELECT = "dl.sparse_select"  # the top-k of the scores, and the gather of the chosen rows or their mask
+SPARSE_ATTENTION_SCOPES = (SCOPE_INDEXER, SCOPE_SPARSE_SELECT)
+
 # a block whose layers differ in their mixer (models/hybrid.py): a conv layer
 # takes dl.conv in the place of the four attention scopes; the FFN scopes are
 # the routed block's above

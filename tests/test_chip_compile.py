@@ -620,3 +620,79 @@ def test_selection_table_resolves_only_to_compile_tested_modes():
     modes = {r["mode"] for r in dequant_select.DequantTable().rules}
     assert modes <= set(OTHER_MODES) | {DEFAULT_MODE}
     assert dequant_select.FALLBACK_MODE in pq.DEQUANT_MODES
+
+
+def _deepseek_v32_cell_program(v5e, monkeypatch, b: int, t: int):
+    """The optimized HLO of the benchmark's deepseek-v3.2 configuration at the
+    cell's own depth, widths and cache (9 layers, 16 of 256 experts held, 8
+    lanes of 32768 positions), ``b`` lanes of ``t`` rows, the cache donated;
+    and its configuration. The arrays are the family generator's shapes."""
+    import latent_toy
+    from distributed_llama_multiusers_tpu.models import deepseek
+    from distributed_llama_multiusers_tpu.quants.packed import padded_d_out
+
+    path = list(__import__("sys").path)
+    __import__("sys").path[:0] = [latent_toy.BENCH_DIR, latent_toy.ROOT]
+    try:
+        from harness import cells
+
+        bench = cells.load_benchmark()
+        cfg = cells.load_config_file(bench, "deepseek-v3.2")
+        family = cells.load_family(cfg)
+    finally:
+        __import__("sys").path[:] = path
+    config = family.program_config(cfg)
+    monkeypatch.setattr(linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas)
+    monkeypatch.setattr(linear, "pallas_kernel_active", lambda: True)
+    monkeypatch.setattr(deepseek, "pallas_kernel_active", lambda: True)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
+    arrays = jax.eval_shape(
+        lambda k: family._generate(config, k, jnp.bfloat16, padded_d_out(config.vocab_size)),
+        jax.random.PRNGKey(0))
+    params = on_chip(jax.eval_shape(lambda a: family.assemble_params(config, a), arrays))
+    cache = on_chip(jax.eval_shape(lambda: deepseek.init_latent_cache(config, b, jnp.bfloat16)))
+    tok = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=v5e)
+    hlo = jax.jit(
+        lambda p, tk, c: deepseek.deepseek_forward_counted(config, p, tk, tk, c),
+        donate_argnums=(2,),
+    ).lower(params, tok, cache).compile().as_text()
+    return hlo, config
+
+
+def test_sparse_latent_decode_copies_no_cache_stack_and_reads_no_whole_plane_for_v5e(v5e, monkeypatch):
+    """One row a lane at the cell's 8 lanes: none of the three cache stacks
+    (latent, rope, index keys) is copied or re-laid (each is the result of its
+    in-place scatters alone), attention gathers the chosen rows out of the
+    stacks as they sit (no ``[lanes, S, 512]`` latent plane is sliced out to
+    gather from, no ``[lanes, S, 640]`` float32 plane is made to attend), and
+    the kernels are there."""
+    import re
+
+    hlo, c = _deepseek_v32_cell_program(v5e, monkeypatch, 8, 1)
+    L, lanes, S = c.n_layers, 8, c.seq_len
+    for width in (c.kv_lora_rank, 128, c.index_head_dim):
+        assert not re.search(rf"= bf16\[{L},{lanes},{S},{width}\]\S* copy\(", hlo), width
+    assert not re.search(rf"f32\[{lanes},{S},(640|512|576)\]", hlo)
+    assert not re.search(rf"= bf16\[{lanes},{S},{c.kv_lora_rank}\]\S* (fusion|copy)\(", hlo)
+    # the dense layer and the scan's body: q_a, q_b, kv_a, the indexer's two,
+    # wo, and a dense or a routed-and-shared FFN; the head
+    assert hlo.count("tpu_custom_call") == 22
+    assert "approx" not in hlo.lower()
+
+
+def test_sparse_latent_chunk_compiles_for_v5e_and_gathers_in_blocks(v5e, monkeypatch):
+    """A 1024-row chunk against the cell's 32768-position lane: the chip's
+    compiler takes it, the lane's three stacks are copied nowhere, no
+    ``[1024, index_topk, 512]`` block of every query's rows exists at once (a
+    block of queries at a time), and the selection is a sort, never the
+    approximate top-k."""
+    import re
+
+    hlo, c = _deepseek_v32_cell_program(v5e, monkeypatch, 1, 1024)
+    L, S = c.n_layers, c.seq_len
+    for width in (c.kv_lora_rank, 128, c.index_head_dim):
+        assert not re.search(rf"= bf16\[{L},1,{S},{width}\]\S* copy\(", hlo), width
+    assert not re.search(rf"\[(1,)?1024,{c.index_topk},(512|128|640)\]", hlo)
+    assert re.search(rf"\[(1,)?256,{c.index_topk},512\]", hlo)  # one block's gathered rows
+    assert " sort(" in hlo and "approx" not in hlo.lower()

@@ -54,6 +54,9 @@ JOIN_SCOPES = {names.SCOPE_CARRY, names.SCOPE_HEAD}
 TOYS = {
     "latent": ("tiny_latent.json", names.LATENT_BLOCK_SCOPES),
     "hybrid": ("tiny_lfm2.json", names.CONV_MIXER_SCOPES + (names.SCOPE_ROUTER, names.SCOPE_EXPERTS)),
+    # an indexer beside the latent block's attention: indexer_step_ms and
+    # sparse_select_step_ms read these two
+    "sparse": ("tiny_deepseek_v32.json", names.LATENT_BLOCK_SCOPES + names.SPARSE_ATTENTION_SCOPES),
 }
 
 
