@@ -616,9 +616,10 @@ def llama_forward(
 
     # Shared Q80 activation operands (ops/pallas_q40.Q80Acts): wq/wk/wv
     # consume one normed x and w1/w3 another, so each site builds its
-    # activation-quant + relayout operands ONCE instead of once per
-    # matmul (one build feeds three dots at the attention site, two at
-    # the FFN site). Single-chip only: under a mesh the matmuls take raw
+    # operand bundle ONCE instead of once per matmul (one build feeds
+    # three dots at the attention site, two at the FFN site). Since the
+    # kernel takes x as it is the bundle is x with its rows padded, no
+    # more. Single-chip only: under a mesh the matmuls take raw
     # activations. shared_q80_acts itself no-ops when the Pallas kernel
     # is off, so every other path sees the plain activation.
     share = mesh is None and isinstance(

@@ -82,8 +82,10 @@ def pallas_interpret() -> bool:
 
 
 def shared_q80_acts(x: jnp.ndarray):
-    """Build the shared Q80/relayout operand bundle for ``x`` (llama_forward
-    builds it once per distinct input and feeds every matmul sharing it),
+    """Build the shared operand bundle for ``x`` (x itself, its leading axes
+    merged and its rows padded: the kernel takes the columns as they are;
+    llama_forward builds it once per distinct input and feeds every matmul
+    sharing it),
     or return x unchanged when sharing cannot engage (kernel off, or a d_in
     that does not cover whole quant blocks). Callers pass the result to
     ``matmul`` exactly like a raw activation."""

@@ -13,12 +13,18 @@ block is 16 consecutive packed rows (low nibble = block inputs [0,16), high
 nibble = [16,32)) + 1 scale row, so a chunk of whole blocks covers the same
 contiguous input range in `packed`, `scales`, and `x`.
 
-Kernel formulation (round-3 kernel-lab "v1", landed round 4): TWO dots —
-the low/high nibble planes each multiply a pre-split half of x, so the
-kernel never concatenates/relayouts the dequantized tile — and the -8
-nibble offset is folded into one small correction dot against per-block x
-sums instead of a per-weight subtract. Per packed byte the VPU does one
-shift+mask+scale-mul, the rest is MXU work.
+Kernel formulation: the low/high nibble planes are split off the packed
+bytes, interleaved by whole 16-row pieces into the input's own column order
+and dequantised as one plane, so ONE dot against x as it is replaces the two
+dots against pre-split halves of x that rounds 4 to PR 41 ran: splitting
+x's lane axis into [n_blk, 2, 16] outside the kernel was five XLA
+relayouts a layer, 3.65 ms of an 18.8 ms Mistral decode step (PERF.md
+section 6, PR 42). The -8 nibble offset is folded into one small
+correction dot against per-block x sums instead of a per-weight subtract,
+and the kernel sums x's blocks itself, by a dot (``_block_sums``). Per
+packed byte the VPU does one shift+mask+scale-mul, the rest is MXU work.
+The two block-dot modes keep the transposed (and Q80-quantised) operands,
+built where their kernels are called (``_block_dot_operands``).
 
 Block layout (round-4 rework, from pure-read measurements on a real v5e):
 blocks span the FULL output width (or a wide 512-multiple tile
@@ -91,6 +97,9 @@ DEQUANT_MODES = ("v4", "bf16chain", "repeat", "u8chain", "blockdot",
 # m-class) from the persisted selection table (ops/dequant_select.py) inside
 # q40_matmul_pallas, at trace time, so every family still compiles once.
 SELECTABLE_MODES = DEQUANT_MODES + ("auto",)
+# the two modes whose kernels take pre-split, transposed operands; the other
+# four (the slab chains) take x as it is
+BLOCK_DOT_MODES = ("blockdot", "i8blockdot")
 
 
 def _env_dequant_default() -> str:
@@ -114,13 +123,17 @@ BLOCKDOT_MAX_M = 32  # above this, the post-scale FMA outweighs the savings
 # are the operand-sharing and compile-churn witnesses: `shared_builds` /
 # `shared_consumes` pin that one Q80Acts build feeds every matmul sharing
 # its input (llama_forward: wq/wk/wv = 1 build, w1/w3 = 1 build per step),
-# and `impl_traces` holding still across repeated calls is the
-# no-recompile signal tests assert across the BLOCKDOT_MAX_M boundary.
+# `impl_traces` holding still across repeated calls is the no-recompile
+# signal tests assert across the BLOCKDOT_MAX_M boundary, and
+# `natural_x_consumes` is the engagement witness of the slab chains'
+# operand (PR 42): every kernel body traced in a slab chain was handed x in
+# its own column order and dtype; there is no other form to fall back to.
 TRACE_STATS = {
     "acts_builds": 0,      # make_q80_acts executions (any caller)
     "shared_builds": 0,    # ... with shared=True (the models/llama.py hoist)
     "shared_consumes": 0,  # q40_matmul_pallas calls fed a prebuilt Q80Acts
     "stacked_consumes": 0,  # ... that read their layer's tiles out of a stack
+    "natural_x_consumes": 0,  # kernel-body traces handed x itself (slab chains)
     "impl_traces": 0,      # kernel-body traces (one per compiled family)
 }
 
@@ -130,7 +143,7 @@ def reset_trace_stats() -> None:
         TRACE_STATS[k] = 0
 
 M_TILE = 256
-ROW_ALIGN = 8  # x rows padded to this multiple
+ROW_ALIGN = 8  # x rows padded to whole sublane tiles: 8 rows of 4-byte words
 # Mosaic's default scoped-VMEM limit (16 MiB) refuses the prefill-shaped
 # plans: a 256-row m tile against an 8192-wide slab needs 18.5 MiB (f32
 # accumulator + double-buffered output block), i.e. every prefill bucket
@@ -230,30 +243,93 @@ def set_dequant_mode(mode: str | None) -> None:
     DEQUANT_MODE = mode or _env_dequant_default()
 
 
-def _q40_slab_kernel(x_lo_ref, x_hi_ref, bsum_t_ref, packed_ref, scales_ref,
-                     out_ref, acc_ref, *, w_dtype, sub_tiles, n_k, mode):
-    """One (m tile, d_out wide-tile, d_in chunk) step — two-dot formulation
-    over a contiguous weight slab:
+def _natural_order(lo, hi, n_blk, t):
+    """The low and the high nibble plane ``[16 * n_blk, t]`` as one
+    ``[n_blk, 32, t]`` in the input's own column order: block b's 16 low
+    rows, then its 16 high rows. Done on the nibbles BEFORE they are
+    converted and scaled, where the 16-row pieces are whole (8, 128) tiles
+    of 4-byte words and the concatenation places registers: joining the
+    dequantised bf16 planes instead cost the kernel a tenth of its time at
+    decode width (PERF.md section 6, PR 42)."""
+    return jnp.concatenate(
+        [lo.reshape(n_blk, 16, t), hi.reshape(n_blk, 16, t)], axis=1
+    )
 
-    - NO nibble concat: the low/high nibble planes each feed their own MXU
-      dot against a matching pre-split half of x, so the dequantized tile
-      never needs the [n_blk, 32, tile] relayout of the round-1 kernel.
+
+# columns of x one block-sum dot takes: the 0/1 matrix it meets is built in
+# the kernel every grid step and grows with the square of its width, so a
+# chunk wider than this (a narrow d_out keeps the whole half as one slab:
+# 7168 columns against the DeepSeek indexer's 128-wide planes) is summed in
+# slices against one matrix of a slice's width
+BSUM_SLICE = 2048
+
+
+def _sum_slice(n: int) -> int:
+    """Columns a block-sum dot takes of a chunk of ``n``: all of them up to
+    BSUM_SLICE, else the largest divisor of n under it that is whole
+    128-lane tiles of x and whole 8-row tiles of the scales it meets (a
+    multiple of 256); n itself where there is none."""
+    if n <= BSUM_SLICE:
+        return n
+    for c in range(BSUM_SLICE, 0, -256):
+        if n % c == 0:
+            return c
+    return n
+
+
+def _block_sums(x):
+    """Per-quant-block sums of x's columns, ``[m, n]`` -> ``n / 32`` f32 sums
+    a row, as dots: x against the 0/1 matrix that says which block a column
+    is in, ``_sum_slice(n)`` columns a dot (the blocks repeat, so one matrix
+    serves every slice). Returned a slice a piece, ``[m, c / 32]`` each, in
+    column order: the caller meets them with the matching rows of the
+    scales and never joins them on the lane axis. No lane of x is split.
+
+    The products are exact and the accumulation is f32 in either dtype the
+    kernel computes in: bf16 x against 0/1 in one MXU pass, f32 x at
+    ``Precision.HIGHEST`` (asked for, not left to a default that may round
+    the operand to bf16)."""
+    n = x.shape[-1]
+    c = _sum_slice(n)
+    shape = (c, c // 32)
+    col_blk = jax.lax.broadcasted_iota(jnp.int32, shape, 0) >> 5
+    blk = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    ind = (col_blk == blk).astype(x.dtype)
+    exact = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    return [
+        jnp.dot(x[:, j:j + c], ind, preferred_element_type=jnp.float32,
+                precision=exact)
+        for j in range(0, n, c)
+    ]
+
+
+def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
+                     w_dtype, sub_tiles, n_k, mode):
+    """One (m tile, d_out wide-tile, d_in chunk) step over a contiguous
+    weight slab. ``x`` arrives as it is: this chunk's ``2 * rows`` columns in
+    their own order and their own dtype.
+
+    - the low/high nibble planes are interleaved by whole 16-row pieces
+      into the input's order (``_natural_order``), dequantised as one plane
+      and meet x in ONE dot of depth ``2 * rows``;
     - NO per-weight -8 subtract: folded into one small correction dot,
-      8 * (per-block x sums) @ scales, subtracted from the partial sum.
-    - Dequant walks the slab in `sub_tiles`-lane slices to bound the VMEM
+      8 * (per-block x sums) @ scales, subtracted from the partial sum. The
+      block sums are dots too, once a grid step, BSUM_SLICE columns of x at
+      most to a dot (``_block_sums``);
+    - dequant walks the slab in `sub_tiles`-lane slices to bound the VMEM
       transient (the slab itself can be megabytes wide).
 
-    x_lo/x_hi: [mt, rows] (block-interleaved halves of x's columns for this
-    d_in chunk). bsum_t: [rows/16, mt] f32 per-quant-block x sums,
-    transposed so the lane dim is m. packed: [rows, W] uint8 slab. scales:
-    [rows/16, W] int16 (f16 bits). acc: [mt, W] f32 scratch (elided when
-    n_k == 1: the block writes out_ref directly)."""
+    x: [mt, 2*rows]. packed: [rows, W] uint8 slab. scales: [rows/16, W]
+    int16 (f16 bits). acc: [mt, W] f32 scratch (elided when n_k == 1: the
+    block writes out_ref directly)."""
     rows, _ = packed_ref.shape
     n_blk = rows // 16
     k = pl.program_id(2)
-    x_lo = x_lo_ref[...].astype(w_dtype)
-    x_hi = x_hi_ref[...].astype(w_dtype)
-    bsum_t = bsum_t_ref[...]
+    # the dot's compute dtype first (a no-op in every cell: x is bf16), so
+    # both terms of the folded -8 see the same rounded x
+    x = x_ref[...].astype(w_dtype)
+    bsum = _block_sums(x)  # [mt, n_blk] f32, in slices of blk_c blocks
+    blk_c = n_blk // len(bsum)
 
     off = 0
     for t in sub_tiles:
@@ -262,51 +338,39 @@ def _q40_slab_kernel(x_lo_ref, x_hi_ref, bsum_t_ref, packed_ref, scales_ref,
             # low-nibble mask on native 8-bit lanes BEFORE any widening
             # (the other chains pay a uint8->int32 expansion up front);
             # the high nibble shifts AFTER widening — Mosaic cannot
-            # legalize arith.shrui on 8-bit lanes for the v5e
+            # legalize arith.shrui on 8-bit lanes for the v5e. A 16-row
+            # piece is half an 8-bit tile, so the planes are joined as bf16
             p8 = packed_ref[:, off:off + t]
-            s3 = s.astype(jnp.bfloat16)[:, None, :]
             lo8 = (p8 & jnp.uint8(0x0F)).astype(jnp.int8)
             hi8 = (p8.astype(jnp.int32) >> 4).astype(jnp.int8)
-            w_lo = (lo8.astype(jnp.bfloat16).reshape(n_blk, 16, t) * s3)
-            w_hi = (hi8.astype(jnp.bfloat16).reshape(n_blk, 16, t) * s3)
-            w_lo = w_lo.reshape(rows, t)
-            w_hi = w_hi.reshape(rows, t)
-        elif mode == "bf16chain":
+            nib = _natural_order(lo8.astype(jnp.bfloat16),
+                                 hi8.astype(jnp.bfloat16), n_blk, t)
+        else:
+            p = packed_ref[:, off:off + t].astype(jnp.int32)
+            nib = _natural_order(p & 0x0F, p >> 4, n_blk, t)
+        if mode in ("bf16chain", "u8chain"):
             # dequant stays in bf16: nibbles (0..15, exact in bf16) cast
             # once, scales rounded to bf16 once per block (amortized /32),
             # ONE bf16 mul per weight — drops the f32 round-trip + downcast
-            p = packed_ref[:, off:off + t].astype(jnp.int32)
-            s3 = s.astype(jnp.bfloat16)[:, None, :]
-            w_lo = ((p & 0x0F).astype(jnp.bfloat16).reshape(n_blk, 16, t) * s3)
-            w_hi = ((p >> 4).astype(jnp.bfloat16).reshape(n_blk, 16, t) * s3)
-            w_lo = w_lo.reshape(rows, t)
-            w_hi = w_hi.reshape(rows, t)
+            w = (nib.astype(jnp.bfloat16)
+                 * s.astype(jnp.bfloat16)[:, None, :]).reshape(2 * rows, t)
         elif mode == "repeat":
             # bf16 chain with the scale broadcast as an explicit row repeat
-            # (each block's scale row 16x consecutive) instead of the
+            # (each block's scale row 32x consecutive) instead of the
             # reshape->broadcast->reshape dance — a relayout-cost A/B
-            p = packed_ref[:, off:off + t].astype(jnp.int32)
-            s_rep = jnp.repeat(s.astype(jnp.bfloat16), 16, axis=0)
-            w_lo = (p & 0x0F).astype(jnp.bfloat16) * s_rep
-            w_hi = (p >> 4).astype(jnp.bfloat16) * s_rep
+            w = (nib.reshape(2 * rows, t).astype(jnp.bfloat16)
+                 * jnp.repeat(s.astype(jnp.bfloat16), 32, axis=0))
         else:  # v4: f32 dequant, cast to the dot dtype at the end
-            p = packed_ref[:, off:off + t].astype(jnp.int32)
-            s3 = s[:, None, :]
-            w_lo = ((p & 0x0F).astype(jnp.float32).reshape(n_blk, 16, t) * s3)
-            w_hi = ((p >> 4).astype(jnp.float32).reshape(n_blk, 16, t) * s3)
-            w_lo = w_lo.reshape(rows, t).astype(w_dtype)
-            w_hi = w_hi.reshape(rows, t).astype(w_dtype)
+            w = (nib.astype(jnp.float32) * s[:, None, :]).reshape(
+                2 * rows, t).astype(w_dtype)
 
         # folded -8 offset: 8 * bsum_b @ s == sum_i x_i * 8 * s_block(i)
-        corr = jax.lax.dot_general(
-            bsum_t, s, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        part = (
-            jnp.dot(x_lo, w_lo, preferred_element_type=jnp.float32)
-            + jnp.dot(x_hi, w_hi, preferred_element_type=jnp.float32)
-            - 8.0 * corr
-        )
+        corr = None
+        for j, b in enumerate(bsum):
+            c = jnp.dot(b, s[j * blk_c:(j + 1) * blk_c],
+                        preferred_element_type=jnp.float32)
+            corr = c if corr is None else corr + c
+        part = jnp.dot(x, w, preferred_element_type=jnp.float32) - 8.0 * corr
         _acc_epilogue(part, off, t, k, n_k, out_ref, acc_ref)
         off += t
     _final_writeback(k, n_k, out_ref, acc_ref)
@@ -439,9 +503,12 @@ def _resolve_w_dtype(w_dtype, interpret: bool):
     return jnp.float32 if interpret else jnp.bfloat16
 
 
-def _m_geometry(m: int) -> tuple[int, int]:
-    """(m_pad, m_tile): x rows padded to ROW_ALIGN, tiled at M_TILE."""
-    m_pad = max(ROW_ALIGN, ((m + ROW_ALIGN - 1) // ROW_ALIGN) * ROW_ALIGN)
+def _m_geometry(m: int, dtype) -> tuple[int, int]:
+    """(m_pad, m_tile): x rows padded to whole sublane tiles of ``dtype``
+    (8 rows of f32, 16 of bf16: a narrower type packs two rows a sublane),
+    tiled at M_TILE."""
+    align = ROW_ALIGN * max(1, 4 // jnp.dtype(dtype).itemsize)
+    m_pad = max(align, ((m + align - 1) // align) * align)
     m_tile = min(M_TILE, m_pad)
     if m_pad % m_tile != 0:
         m_pad = ((m_pad + m_tile - 1) // m_tile) * m_tile
@@ -449,29 +516,21 @@ def _m_geometry(m: int) -> tuple[int, int]:
 
 
 class Q80Acts(NamedTuple):
-    """Shared activation operands for the Q40 matmul: built ONCE per
-    distinct input and consumed by every matmul sharing it — llama_forward's
-    wq/wk/wv share one normed x and w1/w3 another, so the per-step
-    activation-quant + relayout VPU work drops to one build per site
-    instead of one per call.
+    """The activation operand of the Q40 matmul, made ONCE per distinct
+    input and consumed by every matmul sharing it — llama_forward's
+    wq/wk/wv share one normed x and w1/w3 another.
 
-    Every kernel layout is materialized eagerly — the f32 nibble halves
-    (slab chains), their transposes (blockdot), the Q80 per-block int8
-    quantization with interleaved bsum/sx aux (i8blockdot) — because under
-    jit the layouts the resolved mode does not touch are dead code XLA
-    eliminates per compiled program. `x` keeps the ORIGINAL [..., d_in]
-    input: it is the shape/dtype source and the operand for the XLA
-    fallback when a consumer's weight has no supported tiling."""
+    ``x_rows`` is x itself with its leading axes merged and its rows padded:
+    no column moves and the dtype stays. The slab chains (v4, bf16chain,
+    repeat, u8chain: every cell) take it as it is; the two block-dot modes
+    have their pre-split operands built from it where the kernel is called
+    (``_block_dot_operands``), and consumers that share an input inside one
+    program have the identical builds merged by XLA. `x` keeps the ORIGINAL
+    [..., d_in] input: it is the shape/dtype source and the operand for the
+    XLA fallback when a consumer's weight has no supported tiling."""
 
     x: jnp.ndarray        # original input, [..., d_in]
-    x_lo: jnp.ndarray     # [m_pad, half] f32 block-local low-nibble half
-    x_hi: jnp.ndarray     # [m_pad, half] f32 high half
-    x_lo_t: jnp.ndarray   # [half, m_pad] f32 (blockdot: block rows on sublanes)
-    x_hi_t: jnp.ndarray
-    bsum_t: jnp.ndarray   # [n_blk, m_pad] f32 per-block sums (folded -8)
-    xq_lo_t: jnp.ndarray  # [half, m_pad] int8 Q80-quantized halves
-    xq_hi_t: jnp.ndarray
-    aux_t: jnp.ndarray    # [2*n_blk, m_pad] f32; aux[2b]=bsum[b], aux[2b+1]=sx[b]
+    x_rows: jnp.ndarray   # [m_pad, d_in], x's dtype and column order
 
     @property
     def d_in(self) -> int:
@@ -486,14 +545,10 @@ class Q80Acts(NamedTuple):
 
 
 def make_q80_acts(x: jnp.ndarray, shared: bool = False) -> Q80Acts:
-    """Build the Q40-matmul activation operand bundle for `x` (idempotent
-    on an existing bundle). O(m*d_in) VPU work, negligible next to the
-    weight read — but when one input feeds several matmuls the per-call
-    prep (f32 cast + pad, nibble split, transposes, Q80 quantization +
-    aux interleave) used to be traced into EVERY call; hoisting it here
-    runs it once per distinct input. bsum stays TRANSPOSED [n_blk, m] so
-    its lane dim is m — Pallas lane-dim blocks must be multiples of 128
-    or the full extent, and m tiles are either all of m_pad or 256-wide."""
+    """The Q40-matmul activation operand for `x` (idempotent on an existing
+    bundle): a merge of the leading axes and a row pad to whole tiles of
+    x's dtype, whatever the mode. The kernel takes x as it is and sums its
+    blocks itself; nothing of x is converted, split or transposed here."""
     if isinstance(x, Q80Acts):
         return x
     d_in = x.shape[-1]
@@ -502,36 +557,45 @@ def make_q80_acts(x: jnp.ndarray, shared: bool = False) -> Q80Acts:
     TRACE_STATS["acts_builds"] += 1
     if shared:
         TRACE_STATS["shared_builds"] += 1
-    half = d_in // 2
-    n_blk = d_in // 32
     m = 1
     for s in x.shape[:-1]:
         m *= s
-    xf = x.reshape(m, d_in).astype(jnp.float32)
-    m_pad, _ = _m_geometry(m)
+    x_rows = x.reshape(m, d_in)
+    m_pad, _ = _m_geometry(m, x.dtype)
     if m_pad != m:
-        xf = jnp.pad(xf, ((0, m_pad - m), (0, 0)))
+        x_rows = jnp.pad(x_rows, ((0, m_pad - m), (0, 0)))
+    return Q80Acts(x=x, x_rows=x_rows)
 
-    xb = xf.reshape(m_pad, n_blk, 2, 16)
-    x_lo = xb[:, :, 0, :].reshape(m_pad, half)
-    x_hi = xb[:, :, 1, :].reshape(m_pad, half)
 
-    xq3 = xf.reshape(m_pad, n_blk, 32)
+def _block_dot_operands(x_rows: jnp.ndarray, mode: str):
+    """The three operands a block-dot kernel takes, from ``[m_pad, d_in]``
+    rows: the nibble halves of x's columns TRANSPOSED ``[half, m_pad]`` and
+    an aux plane whose lane dim is m (Pallas lane-dim blocks must be
+    multiples of 128 or the full extent, and m tiles are either all of
+    m_pad or 256-wide). blockdot: f32 halves and the block sums
+    ``[n_blk, m_pad]``; i8blockdot: the Q80 per-block int8 quantization and
+    ``[2 * n_blk, m_pad]`` with aux[2b] = bsum[b], aux[2b+1] = sx[b].
+
+    The split sees x's lane axis as [n_blk, 2, 16], which XLA:TPU does by a
+    physical relayout: it cost 3.65 ms of an 18.8 ms Mistral decode step
+    while the slab chains took their operands from it too (PERF.md section
+    6, PR 42)."""
+    m_pad, d_in = x_rows.shape
+    half = d_in // 2
+    n_blk = d_in // 32
+    xq3 = x_rows.astype(jnp.float32).reshape(m_pad, n_blk, 32)
     bsum = xq3.sum(axis=2)  # EXACT f32 sums: the folded -8 stays exact
-    sx = jnp.maximum(jnp.abs(xq3).max(axis=2), 1e-8) / 127.0
-    xq = jnp.clip(jnp.round(xq3 / sx[:, :, None]), -127, 127).astype(jnp.int8)
-
-    return Q80Acts(
-        x=x,
-        x_lo=x_lo,
-        x_hi=x_hi,
-        x_lo_t=x_lo.T,
-        x_hi_t=x_hi.T,
-        bsum_t=bsum.T,
-        xq_lo_t=xq[:, :, :16].reshape(m_pad, half).T,
-        xq_hi_t=xq[:, :, 16:].reshape(m_pad, half).T,
-        aux_t=jnp.stack([bsum, sx], axis=2).reshape(m_pad, n_blk * 2).T,
-    )
+    if mode == "blockdot":
+        halves, aux = xq3, bsum
+    else:
+        sx = jnp.maximum(jnp.abs(xq3).max(axis=2), 1e-8) / 127.0
+        halves = jnp.clip(
+            jnp.round(xq3 / sx[:, :, None]), -127, 127).astype(jnp.int8)
+        aux = jnp.stack([bsum, sx], axis=2).reshape(m_pad, n_blk * 2)
+    halves = halves.reshape(m_pad, n_blk, 2, 16)
+    return (halves[:, :, 0, :].reshape(m_pad, half).T,
+            halves[:, :, 1, :].reshape(m_pad, half).T,
+            aux.T)
 
 
 def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
@@ -574,7 +638,7 @@ def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
         from .dequant_select import resolve_mode
 
         mode = resolve_mode(w.d_in, w.d_out, m)
-    if mode in ("blockdot", "i8blockdot") and m > BLOCKDOT_MAX_M:
+    if mode in BLOCK_DOT_MODES and m > BLOCKDOT_MAX_M:
         mode = "bf16chain"
     at = ()  # a plain plane keeps the entries' five-argument call
     if layer is not None:
@@ -589,8 +653,8 @@ def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
 @partial(jax.jit, static_argnames=("interpret", "w_dtype", "mode"))
 def _q40_matmul_pallas_impl(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
                             mode, layer=None) -> jnp.ndarray:
-    """Raw-x entry: builds the operand bundle inside the same trace (XLA
-    DCEs the layouts `mode` does not touch), then runs the kernel."""
+    """Raw-x entry: builds the operand bundle inside the same trace, then
+    runs the kernel."""
     return _q40_matmul_core(make_q80_acts(x), w, interpret, w_dtype, mode,
                             layer)
 
@@ -643,7 +707,7 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
 
     lead = acts.x.shape[:-1]
     m = acts.m
-    m_pad = acts.x_lo.shape[0]
+    m_pad = acts.x_rows.shape[0]
     m_tile = min(M_TILE, m_pad)
 
     grid = (m_pad // m_tile, d_out // w_tile, n_k)
@@ -657,30 +721,24 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
     scale_bits = jax.lax.bitcast_convert_type(scales, jnp.int16)
 
     # index maps take the grid position and then the scalar-prefetch ref
-    aux_spec = pl.BlockSpec((rows // 16, m_tile), lambda i, j, k, l: (k, i))
-    if mode == "blockdot":
-        # x TRANSPOSED [rows, m]: the kernel slices 16-row (one quant
+    if mode in BLOCK_DOT_MODES:
+        # x TRANSPOSED [rows, m]: the kernels slice 16-row (one quant
         # block) ranges, which must land on the sublane axis — sub-128
         # lane slices would relayout
-        xa, xb_ = acts.x_lo_t, acts.x_hi_t
-        aux = acts.bsum_t
-        x_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k, l: (k, i))
-        kernel = partial(_q40_blockdot_kernel, sub_tiles=sub, n_k=n_k)
-    elif mode == "i8blockdot":
-        # Q80-quantized activations from the bundle; x TRANSPOSED like
-        # blockdot; bsum (EXACT f32 sums) and the activation scales
-        # interleave on the sublane axis
-        xa, xb_ = acts.xq_lo_t, acts.xq_hi_t
-        aux = acts.aux_t
-        aux_spec = pl.BlockSpec(
-            ((rows // 16) * 2, m_tile), lambda i, j, k, l: (k, i)
-        )
-        x_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k, l: (k, i))
-        kernel = partial(_q40_i8blockdot_kernel, sub_tiles=sub, n_k=n_k)
+        x_ops = _block_dot_operands(acts.x_rows, mode)
+        blockdot = mode == "blockdot"
+        kernel = partial(
+            _q40_blockdot_kernel if blockdot else _q40_i8blockdot_kernel,
+            sub_tiles=sub, n_k=n_k)
+        x_t_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k, l: (k, i))
+        x_specs = [x_t_spec, x_t_spec, pl.BlockSpec(
+            ((rows // 16) * (1 if blockdot else 2), m_tile),
+            lambda i, j, k, l: (k, i))]
     else:
-        xa, xb_ = acts.x_lo, acts.x_hi
-        aux = acts.bsum_t
-        x_spec = pl.BlockSpec((m_tile, rows), lambda i, j, k, l: (i, k))
+        # x as it is: chunk k's 2 * rows columns, in its own dtype
+        TRACE_STATS["natural_x_consumes"] += 1
+        x_ops = (acts.x_rows,)
+        x_specs = [pl.BlockSpec((m_tile, 2 * rows), lambda i, j, k, l: (i, k))]
         kernel = partial(_q40_slab_kernel, w_dtype=w_dtype, sub_tiles=sub,
                          n_k=n_k, mode=mode)
 
@@ -691,9 +749,7 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
             num_scalar_prefetch=1,  # the layer index, read by the index maps
             grid=grid,
             in_specs=[
-                x_spec,
-                x_spec,
-                aux_spec,
+                *x_specs,
                 # layer l's nibble tiles, addressed inside the stack; the
                 # leading block dimension is squeezed: the body sees [rows, W]
                 pl.BlockSpec((None, rows, w_tile),
@@ -715,11 +771,12 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         cost_estimate=pl.CostEstimate(
             flops=2 * m_pad * d_in * d_out,
             bytes_accessed=d_in * d_out // 2 + (d_in // 32) * d_out * 2
-            + m_pad * d_in * 4 + m_pad * d_out * out_dtype.itemsize,
+            + m_pad * d_in * out_dtype.itemsize
+            + m_pad * d_out * out_dtype.itemsize,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(layer.reshape(1), xa, xb_, aux, packed, scale_bits)
+    )(layer.reshape(1), *x_ops, packed, scale_bits)
 
     return out[:m].reshape(*lead, d_out)
 

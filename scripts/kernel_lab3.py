@@ -59,8 +59,9 @@ _INTERPRET = False
 
 
 # ---------------------------------------------------------------------------
-# kernel bodies. Shared operand layout (all pre-split outside the kernel,
-# matching the product kernel's convention):
+# kernel bodies. Shared operand layout (all pre-split outside the kernel, as
+# the product's block-dot modes still take them; its slab chains have taken
+# x as it is since PR 42, and a variant that wins here is ported to that):
 #   xl/xh  [M, half]        block-local nibble halves of x's columns
 #   xlt/xht[half, M]        the same, transposed (blockdot wants sublane
 #                           slicing at 16-row granularity)

@@ -85,10 +85,11 @@ def _routed_mode(mode: str, d_in: int, d_out: int, m: int) -> str:
     return mode
 
 
-def _compile(sharding, mode: str, d_in: int, d_out: int, m: int) -> str:
+def _compile(sharding, mode: str, d_in: int, d_out: int, m: int,
+             x_dtype=jnp.bfloat16, w_dtype=jnp.bfloat16) -> str:
     """Compile the bf16-dot kernel the way q40_matmul_pallas routes it and
     return the optimized HLO text."""
-    x = jax.ShapeDtypeStruct((m, d_in), jnp.bfloat16, sharding=sharding)
+    x = jax.ShapeDtypeStruct((m, d_in), x_dtype, sharding=sharding)
     w = PackedQ40(
         packed=jax.ShapeDtypeStruct((d_in // 2, d_out), jnp.uint8,
                                     sharding=sharding),
@@ -96,9 +97,23 @@ def _compile(sharding, mode: str, d_in: int, d_out: int, m: int) -> str:
                                     sharding=sharding),
     )
     return pq._q40_matmul_pallas_impl.lower(
-        x, w, interpret=False, w_dtype=jnp.bfloat16,
+        x, w, interpret=False, w_dtype=w_dtype,
         mode=_routed_mode(mode, d_in, d_out, m),
     ).compile().as_text()
+
+
+def _lane_splits(hlo: str) -> list[str]:
+    """Arrays `[rows, n_blk, 16]` / `[rows, n_blk, 2, 16]` in the compiled
+    program: an activation's lane axis split into quant-block halves, which
+    XLA:TPU does by a physical relayout. Since PR 42 the slab chains take x
+    as it is and no program of theirs makes one."""
+    import re
+
+    return sorted(set(re.findall(r"(?:f32|bf16)\[\d+,\d+,(?:2,)?16\]", hlo)))
+
+
+def _is_slab_chain(mode: str, d_in: int, d_out: int, m: int) -> bool:
+    return _routed_mode(mode, d_in, d_out, m) not in pq.BLOCK_DOT_MODES
 
 
 def test_default_mode_is_what_this_file_calls_default(monkeypatch):
@@ -109,16 +124,54 @@ def test_default_mode_is_what_this_file_calls_default(monkeypatch):
 # m = 1: decode. m = 1024: the widest default prefill bucket — four 256-row
 # m tiles, the plan with the largest VMEM footprint (m = 128 is one smaller
 # tile of the same plan).
-@pytest.mark.parametrize("m", [1, 1024])
+# m = 16: a decode batch whose bf16 rows are one whole tile, handed over as
+# they are (PR 42).
+@pytest.mark.parametrize("m", [1, 16, 1024])
 @pytest.mark.parametrize("d_in,d_out", SHAPES)
 def test_default_mode_compiles_for_v5e(v5e, d_in, d_out, m):
-    assert "tpu_custom_call" in _compile(v5e, DEFAULT_MODE, d_in, d_out, m)
+    hlo = _compile(v5e, DEFAULT_MODE, d_in, d_out, m)
+    assert "tpu_custom_call" in hlo
+    assert _lane_splits(hlo) == []
 
 
+@pytest.mark.parametrize("m", [1, 16, 1024])
 @pytest.mark.parametrize("d_in,d_out", TWO_SHAPES)
 @pytest.mark.parametrize("mode", OTHER_MODES)
-def test_every_selectable_mode_compiles_for_v5e(v5e, mode, d_in, d_out):
-    assert "tpu_custom_call" in _compile(v5e, mode, d_in, d_out, 1)
+def test_every_selectable_mode_compiles_for_v5e(v5e, mode, d_in, d_out, m):
+    """At 1024 rows the block-dot modes route to bf16chain, as they are
+    served. A slab chain's program splits no lane of x."""
+    hlo = _compile(v5e, mode, d_in, d_out, m)
+    assert "tpu_custom_call" in hlo
+    assert not (_is_slab_chain(mode, d_in, d_out, m) and _lane_splits(hlo))
+
+
+# a narrow d_out keeps the whole half as one slab, so the kernel's chunk of x
+# is all d_in columns: the DeepSeek indexer's 7168 x 128, Qwen2.5's wk / wv,
+# and one four times as deep. The block sums are then taken in slices against
+# one 0/1 matrix of at most BSUM_SLICE columns (whole, the matrix of 16384
+# columns is 8M elements a grid step).
+@pytest.mark.parametrize("mode,d_in,d_out,m", [
+    (DEFAULT_MODE, 7168, 128, 16), (DEFAULT_MODE, 7168, 128, 512),
+    (DEFAULT_MODE, 3584, 512, 32), (DEFAULT_MODE, 3584, 512, 1024),
+    (DEFAULT_MODE, 16384, 128, 16), (DEFAULT_MODE, 16384, 128, 512),
+    ("bf16chain", 7168, 128, 16), ("repeat", 7168, 128, 16),
+    ("u8chain", 7168, 128, 16),
+])
+def test_whole_half_narrow_plans_compile_for_v5e(v5e, mode, d_in, d_out, m):
+    assert pq._plan_blocks(d_in, d_out) == (d_out, d_in // 2)
+    assert d_in // pq._sum_slice(d_in) > 1 and pq._sum_slice(d_in) <= pq.BSUM_SLICE
+    hlo = _compile(v5e, mode, d_in, d_out, m)
+    assert "tpu_custom_call" in hlo and _lane_splits(hlo) == []
+
+
+# an f32 x (no cell hands one over): rounded to the bf16 dot's dtype before
+# its blocks are summed, or under an f32 dot summed at Precision.HIGHEST
+@pytest.mark.parametrize("w_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16_dot", "f32_dot"])
+@pytest.mark.parametrize("d_in,d_out", TWO_SHAPES + [(7168, 128)])
+def test_f32_operand_compiles_for_v5e(v5e, d_in, d_out, w_dtype):
+    hlo = _compile(v5e, DEFAULT_MODE, d_in, d_out, 8, jnp.float32, w_dtype)
+    assert "tpu_custom_call" in hlo and _lane_splits(hlo) == []
 
 
 @pytest.mark.parametrize("d_in,d_out", [(2048, 131072), (4096, 14336)])
@@ -201,15 +254,21 @@ def test_stacked_weight_default_mode_compiles_for_v5e(v5e, d_in, d_out, m, prefi
     hlo = _compile_stacked(v5e, DEFAULT_MODE, d_in, d_out, 1024 if prefill else m)
     assert "tpu_custom_call" in hlo
     assert not _scales_stack_converted_whole(hlo, d_in, d_out)
+    assert _lane_splits(hlo) == []
 
 
+@pytest.mark.parametrize("prefill", [False, True], ids=["decode", "prefill1024"])
 @pytest.mark.parametrize("d_in,d_out,m", [(4096, 14336, 16), (3584, 512, 32)])
 @pytest.mark.parametrize("mode", OTHER_MODES)
 def test_stacked_weight_every_selectable_mode_compiles_for_v5e(
-        v5e, mode, d_in, d_out, m):
+        v5e, mode, d_in, d_out, m, prefill):
     """Every mode `--dequant` offers and `auto` can resolve to, at a
-    multi-chunk two-wide-tile plan and at a single-slab plan."""
-    assert "tpu_custom_call" in _compile_stacked(v5e, mode, d_in, d_out, m)
+    multi-chunk two-wide-tile plan and at a single-slab plan, at decode
+    width and at 1024 rows (where a block-dot mode is served by bf16chain)."""
+    m = 1024 if prefill else m
+    hlo = _compile_stacked(v5e, mode, d_in, d_out, m)
+    assert "tpu_custom_call" in hlo
+    assert not (_is_slab_chain(mode, d_in, d_out, m) and _lane_splits(hlo))
 
 
 def _three_layer_decode_hlo(v5e, monkeypatch, lanes=16, n_heads=32, n_kv=8):
@@ -276,6 +335,36 @@ def test_layer_loop_slices_no_q40_plane_for_v5e(v5e, monkeypatch, reads_stack):
         assert sliced == set(), sliced
     else:
         assert sliced == planes, sliced
+
+
+@pytest.mark.parametrize("mode,splits", [(DEFAULT_MODE, False), ("blockdot", True)],
+                         ids=["x_as_it_is", "control_block_dot_operands"])
+def test_decode_forward_splits_no_activation_lane_for_v5e(v5e, monkeypatch, mode, splits):
+    """The compiled three-layer decode forward at Mistral-7B's widths holds
+    no `[16, 128, 16]` / `[16, 448, 16]` / `[16, 448, 2, 16]` array: the
+    operations that were 3.65 ms of an 18.8 ms decode step (PERF.md section
+    6, PR 42). The control compiles the same forward in the mode that still
+    takes pre-split operands and finds them."""
+    import re
+
+    monkeypatch.setattr(pq, "DEQUANT_MODE", mode)
+    hlo, dims = _three_layer_decode_hlo(v5e, monkeypatch)
+    assert hlo.count("tpu_custom_call") == 9
+    if not splits:
+        # x reaches each of the eight Q40 kernels as the bf16 the model made
+        # (its second operand, after the layer index): rounded once, for the
+        # dot and for the block sums alike
+        made = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", hlo))
+        x_ops = [made[ops.split(",")[1].strip()] for ops in re.findall(
+            r"%_q40_matmul_\w+\.\d+ = \S+ custom-call\(([^)]*)\)", hlo)]
+        widths = {dims["d"], dims["h"]}
+        assert len(x_ops) == 8 and all(
+            re.fullmatch(rf"bf16\[{dims['lanes']},(\d+)\]", x)
+            and int(x.split(",")[1][:-1]) in widths for x in x_ops), x_ops
+    blocks = {dims["d"] // 32, dims["h"] // 32}
+    found = [s for s in _lane_splits(hlo)
+             if int(s.split(",")[1]) in blocks and s.split("[")[1].startswith(f"{dims['lanes']},")]
+    assert bool(found) == splits, found
 
 
 def _results_of_shape(hlo: str, shape: str) -> list[str]:

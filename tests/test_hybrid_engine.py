@@ -186,13 +186,60 @@ def test_decode_steps_attend_the_merged_stack_in_place_where_the_kernel_takes_it
         assert e.cache.k.shape == (2, 8, 512, 128) and e.cache.k.dtype == jnp.bfloat16
         assert e.path_facts()["attention_path"] == "pallas_in_place"
         assert e.decode_attention_block == pallas_attention.BLOCK_ROWS
-        r = correct.compare(family, cfg, tensors, e, 5)
+        r = correct.compare(family, cfg, tensors, e, 5, keep_rows=True)
     finally:
         linear.set_pallas_interpret(False)
     assert (r["route_greedy_gap"], r["route_nucleus_excess"], r["route_kv_rel_err"]) == (0, 0, 0)
     assert r["route_tokens"] >= 20 and r["route_token_mismatches"] == 0
-    # bfloat16 against the float32 reference, as the dense path reads (0.012)
-    assert r["prefill_rel_err"] < 0.02 and r["decode_rel_err"] < 0.02
+    # bfloat16 against the float32 reference: a row reads 0.008-0.014, as the
+    # dense path does (0.012), unless the router's choice at its position is a
+    # near tie: the reference's margin between the last expert chosen and the
+    # first left out is under one bfloat16 step of a score (2**-8 in [0.5, 1)),
+    # so rounding decides the set, and which way it falls changes with the
+    # order of any sum before it (PR 42's kernel sums the same products in
+    # another order: rows 3 of sequences 0 and 1, margins 0.0032 and 0.0015,
+    # read 0.009 before it and 0.082 / 0.063 since; seeds 6-8 flip other rows
+    # on either side of that PR). Such a row, and the decode rows after it in
+    # its lane, are the only ones allowed over the 0.02 every row was held to
+    rows = np.asarray(r["row_errors"])
+    near_tie = _router_margins(family, cfg, tensors, 5) < 2.0 ** -8
+    prompt_row = rows.shape[1] - 1 - int(cfg["correctness"]["decode_steps"])
+    after_tie = np.zeros_like(near_tie)  # a prefix row is prefilled alone
+    after_tie[:, prompt_row:] = np.cumsum(near_tie[:, prompt_row:], axis=1) > 0
+    clear = ~(near_tie | after_tie)
+    assert rows.shape == near_tie.shape and clear.sum() >= 6, near_tie
+    assert rows[clear].max() < 0.02, (rows, near_tie)
+    assert r["prefill_rel_err"] < 0.02 and r["decode_rel_err"] < 0.04, r
+
+
+def _router_margins(family, cfg, tensors, seed):
+    """For each compared row ``[sequence, row]``, the least margin over the
+    routed layers, in the float32 reference, between the last expert the
+    router chooses at that row's position and the first it leaves out."""
+    import jax
+
+    prompts, forced = CORRECT.sample_sequences(cfg, seed)
+    k = int(cfg["num_experts_per_tok"])
+    real, seen = family._route, []
+
+    def spy(m, gate, bias, **kw):
+        scores = jnp.sort(jax.nn.sigmoid(m @ gate) + bias, axis=-1)
+        seen.append(np.asarray(scores[0, :, -k] - scores[0, :, -k - 1]))
+        return real(m, gate, bias, **kw)
+
+    family._route = spy
+    try:
+        out = []
+        for p, f in zip(prompts, forced):
+            seen.clear()
+            with jax.default_matmul_precision("highest"):
+                family.reference_forward(cfg, tensors, np.asarray([p + f], np.int32))
+            at = ([n - 1 for n in CORRECT.prefix_lengths(cfg, len(p))]
+                  + list(range(len(p) - 1, len(p) + len(f))))
+            out.append(np.stack(seen).min(axis=0)[at])
+    finally:
+        family._route = real
+    return np.stack(out)
 
 
 @pytest.mark.parametrize("dtype,seq,why", [

@@ -6,6 +6,8 @@ q40_matmul_xla dequantize identically, so results must agree to float
 rounding, not a quantization tolerance.
 """
 
+from functools import partial
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -329,15 +331,15 @@ from distributed_llama_multiusers_tpu.ops.pallas_q40 import (  # noqa: E402
 )
 
 
-@pytest.mark.parametrize("mode", ["v4", "blockdot", "i8blockdot"])
+@pytest.mark.parametrize(
+    "mode", ["v4", "blockdot", "i8blockdot", "bf16chain", "repeat", "u8chain"])
 def test_q80_acts_shared_vs_raw_parity(mode):
     """A prebuilt Q80Acts bundle and a raw activation run the SAME traced
     math per mode — only XLA fusion boundaries differ between the eager
     build and the in-jit build, so i8blockdot (the one mode with a
-    reduction in operand prep) sits at ~1e-7 reduction-order wiggle.
-    Covers the two acts-consuming modes plus the v4 chain standing in for
-    the bf16-chain family (all chains unwrap the bundle via _raw_x on the
-    same line, so one representative pins the passthrough)."""
+    reduction in operand prep) sits at ~1e-7 reduction-order wiggle. The
+    slab chains (PR 42) are handed x itself either way, and nothing is
+    prepared that a fusion boundary could move: to the bit."""
     rng = np.random.default_rng(5)
     pw = _pack(rng, 256, 128)
     x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
@@ -353,7 +355,10 @@ def test_q80_acts_shared_vs_raw_parity(mode):
         )
     finally:
         set_dequant_mode(None)
-    np.testing.assert_allclose(shared, raw, rtol=1e-5, atol=1e-5)
+    if mode in ("blockdot", "i8blockdot"):
+        np.testing.assert_allclose(shared, raw, rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_array_equal(shared, raw)
 
 
 def test_q80_acts_build_and_consume_counters():
@@ -362,6 +367,8 @@ def test_q80_acts_build_and_consume_counters():
     rng = np.random.default_rng(6)
     weights = [_pack(rng, d_out, 128) for d_out in (128, 256, 384)]
     x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
+    # the kernel bodies are traced here, whatever ran before in this process
+    pq._q40_matmul_acts_impl.clear_cache()
     reset_trace_stats()
     acts = make_q80_acts(x, shared=True)
     for pw in weights:
@@ -369,6 +376,20 @@ def test_q80_acts_build_and_consume_counters():
     assert TRACE_STATS["acts_builds"] == 1, TRACE_STATS
     assert TRACE_STATS["shared_builds"] == 1, TRACE_STATS
     assert TRACE_STATS["shared_consumes"] == 3, TRACE_STATS
+    # each traced kernel body (the f32 chain) was handed x as it is
+    assert TRACE_STATS["impl_traces"] == 3, TRACE_STATS
+    assert TRACE_STATS["natural_x_consumes"] == 3, TRACE_STATS
+    # the block-dot modes take the operands built for them, not x itself
+    set_dequant_mode("blockdot")
+    try:
+        pq._q40_matmul_acts_impl.clear_cache()
+        reset_trace_stats()
+        q40_matmul_pallas(acts, weights[0], interpret=True,
+                          w_dtype=jnp.bfloat16)
+    finally:
+        set_dequant_mode(None)
+    assert TRACE_STATS["impl_traces"] == 1, TRACE_STATS
+    assert TRACE_STATS["natural_x_consumes"] == 0, TRACE_STATS
 
 
 def test_shared_acts_build_counts_model_scale(tiny_model):
@@ -402,6 +423,10 @@ def test_shared_acts_build_counts_model_scale(tiny_model):
         # at most once per kernel-family trace (0 on a warm jit cache) —
         # never one-per-consumer like the pre-sharing layout
         assert TRACE_STATS["acts_builds"] - 2 <= 3, TRACE_STATS
+        # whatever kernel body this trace made (none on a warm jit cache)
+        # took x in its own order: the model has no other form to run
+        assert (TRACE_STATS["natural_x_consumes"]
+                == TRACE_STATS["impl_traces"]), TRACE_STATS
     finally:
         linear.set_pallas_interpret(False)
 
@@ -629,6 +654,11 @@ def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
             pairs = {l: (got[l], want[l]) for l in (0, STACK_L - 1)}
         # one trace of ``both``: one kernel call that indexes a stack
         assert TRACE_STATS["stacked_consumes"] == 1, TRACE_STATS
+        # stack and plane alike: x itself in a slab chain, never in a
+        # block-dot mode
+        natural = mode not in ("blockdot", "i8blockdot")
+        assert TRACE_STATS["natural_x_consumes"] == (
+            TRACE_STATS["impl_traces"] if natural else 0), TRACE_STATS
     finally:
         set_dequant_mode(None)
     for l, (got, want) in pairs.items():
@@ -657,13 +687,21 @@ def test_stacked_weight_on_every_grid_axis(m, d_in, d_out):
             atol=2e-4, rtol=2e-4)
 
 
-# sha256[:16] of the output bytes the PARENT of PR 30 gives for the seeded
-# call below (its kernel took 2-D planes only): a 2-D weight goes through the
-# same pallas_call as a stack now, as the stack of one read at layer 0
+# sha256[:16] of the output bytes of the seeded call below. The two block-dot
+# modes: what the PARENT of PR 30 gave (its kernel took 2-D planes only; a 2-D
+# weight goes through the same pallas_call as a stack now, as the stack of one
+# read at layer 0), untouched since. The slab chains: what PR 42 gives, whose
+# one dot of depth 2 * rows sums the same products in another order than the
+# two dots of depth rows it replaced, and whose block sums are of x as the
+# dot sees it (this call hands an f32 x to a bf16 dot: the parent summed the
+# unrounded f32 there; a bf16 x, as every cell's, reads the same either
+# way). PR 30's parent gave 152c5bc3acc7695f for v4, 8cd3d3f237e607c8 for
+# the bf16 chains, a00e1bb2e19dc500 in f32; how far the order moves a result
+# is held against ``_two_dot_form`` below.
 PARENT_2D_DIGESTS = {
-    "f32": "a00e1bb2e19dc500", "v4": "152c5bc3acc7695f",
-    "bf16chain": "8cd3d3f237e607c8", "repeat": "8cd3d3f237e607c8",
-    "u8chain": "8cd3d3f237e607c8", "blockdot": "2e41ab0e772529f9",
+    "f32": "4405722f4a618a94", "v4": "aab6fc6aa5b9fec8",
+    "bf16chain": "b84f88086d153cd2", "repeat": "b84f88086d153cd2",
+    "u8chain": "b84f88086d153cd2", "blockdot": "2e41ab0e772529f9",
     "i8blockdot": "e537117c72b1fdf8",
 }
 
@@ -686,6 +724,12 @@ def test_plain_weight_gives_what_it_gave_before_stacks(mode):
         set_dequant_mode(None)
     assert hashlib.sha256(got.tobytes()).hexdigest()[:16] == PARENT_2D_DIGESTS[mode]
     np.testing.assert_array_equal(as_stack, got)
+    if mode in ("f32", "v4"):
+        # the same products as the two-dot form, summed in another order
+        want = np.asarray(_two_dot_form(
+            x, pw, jnp.float32 if mode == "f32" else jnp.bfloat16))
+        np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max(),
+                                   rtol=0)
 
 
 def test_stack_and_layer_go_together():
@@ -698,3 +742,334 @@ def test_stack_and_layer_go_together():
         q40_matmul_pallas(x, stack, interpret=True)
     with pytest.raises(ValueError, match="stack and its layer"):
         q40_matmul_pallas(x, _plane(stack, 0), interpret=True, layer=0)
+
+
+# ---------------------------------------------------------------------------
+# The slab chains take x as it is (PR 42): its own column order, its own
+# dtype, one BlockSpec. The kernel puts the dequantised nibble planes back in
+# the input's order by whole 16-row tiles, multiplies in one dot and sums x's
+# quant blocks itself. Before, ``make_q80_acts`` split x's lane axis into
+# [n_blk, 2, 16] in XLA ahead of every distinct input.
+# ---------------------------------------------------------------------------
+
+from distributed_llama_multiusers_tpu.ops import pallas_q40 as pq  # noqa: E402
+
+
+def _two_dot_form(x, pw, w_dtype, sums_of=None):
+    """What the kernel computed before PR 42, in plain jax.numpy on a whole
+    plane: the pre-split halves of x against the low and the high nibble
+    plane in two dots, the folded -8 against exact f32 block sums.
+    ``sums_of``: the array whose blocks are summed. By default x as the dots
+    see it, rounded to ``w_dtype``: what a bf16 x gave then and gives now.
+    The parent summed the f32 it was handed (``sums_of=x`` for an f32 x,
+    or the f32 a fused convert pair let through: see the norm-then-cast
+    test)."""
+    m, d_in = x.shape
+    n_blk, half = d_in // 32, d_in // 2
+    xf = x.astype(jnp.float32)
+    xb = xf.reshape(m, n_blk, 2, 16)
+    x_lo = xb[:, :, 0, :].reshape(m, half).astype(w_dtype)
+    x_hi = xb[:, :, 1, :].reshape(m, half).astype(w_dtype)
+    if sums_of is None:
+        sums_of = x.astype(w_dtype)
+    bsum = sums_of.astype(jnp.float32).reshape(m, n_blk, 32).sum(axis=2)
+    p = pw.packed.astype(jnp.int32)
+    s = pw.scales.astype(jnp.float32)
+    planes = [
+        (nib.astype(jnp.float32).reshape(n_blk, 16, -1) * s[:, None, :])
+        .reshape(half, -1).astype(w_dtype)
+        for nib in (p & 0x0F, p >> 4)
+    ]
+    dot = partial(jnp.dot, preferred_element_type=jnp.float32,
+                  precision="highest")
+    y = dot(x_lo, planes[0]) + dot(x_hi, planes[1]) - 8.0 * dot(bsum, s)
+    return y.astype(x.dtype)
+
+
+# every plan the cells' shapes take, at sizes interpret mode can carry
+NATURAL_SHAPES = [
+    # m = 8 (DeepSeek's decode width), 112 blocks (3584 / 32), one slab
+    (8, 3584, 256),
+    # m = 16, 112 blocks in two reduction chunks of 56 (rows 896)
+    (16, 3584, 1024),
+    # m = 32, 128 blocks in chunks, the f32 accumulator
+    (32, 4096, 2048),
+    # m = 64, two wide tiles
+    (64, 512, 16384),
+    # one whole m tile; 43 blocks
+    (256, 1376, 128),
+    # above M_TILE: two m tiles, and rows that need padding
+    (300, 64, 256),
+    # rows that need padding under either dtype; 448 blocks (14336 / 32)
+    (5, 14336, 128),
+]
+
+
+@pytest.mark.parametrize("weight", ["plane", "stack"])
+@pytest.mark.parametrize("entry", ["raw_x", "shared_acts"])
+@pytest.mark.parametrize("m,d_in,d_out", NATURAL_SHAPES)
+def test_natural_operand_matches_xla_and_the_two_dot_form(m, d_in, d_out,
+                                                          entry, weight):
+    """The kernel handed x itself, in exact f32: against the XLA dequant to
+    the tolerance this file has always had, against the two-dot form (the
+    same products in another order) closer, and the four ways in (raw x or
+    the shared bundle, a plane or a layer of a stack under a traced index)
+    equal to the bit."""
+    rng = np.random.default_rng(d_in + d_out + m)
+    stack = _stack(rng, d_out, d_in, n=2)
+    pw = _plane(stack, 1)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+
+    @jax.jit
+    def run(x, stack, l):
+        xin = make_q80_acts(x) if entry == "shared_acts" else x
+        if weight == "stack":
+            return q40_matmul_pallas(xin, stack, interpret=True, layer=l)
+        return q40_matmul_pallas(xin, _plane(stack, 1), interpret=True)
+
+    got = np.asarray(run(x, stack, jnp.int32(1)))
+    assert got.shape == (m, d_out)
+    np.testing.assert_allclose(
+        got, np.asarray(q40_matmul_xla(x, pw)), atol=2e-4, rtol=2e-4)
+    want = np.asarray(_two_dot_form(x, pw, jnp.float32))
+    np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max(),
+                               rtol=0)
+    base = np.asarray(q40_matmul_pallas(x, pw, interpret=True))
+    np.testing.assert_array_equal(got, base)
+
+
+@pytest.mark.parametrize("mode", ["v4", "bf16chain", "repeat", "u8chain"])
+@pytest.mark.parametrize("m,d_in,d_out", [
+    (8, 3584, 256), (16, 3584, 1024), (32, 512, 1024), (300, 64, 256)])
+def test_natural_operand_in_bf16_as_the_cells_run_it(m, d_in, d_out, mode):
+    """x in bf16 under the bf16 dot, every slab chain: rows padded to whole
+    16-row tiles, the block sums a bf16 dot with f32 accumulation (every
+    product exact). Against the two-dot form in the same precision the
+    result differs by the summation order and the output's one rounding to
+    bf16; v4 dequantises exactly as that form does, the bf16 chains also
+    round the scale."""
+    rng = np.random.default_rng(d_in + d_out + m)
+    pw = _pack(rng, d_out, d_in)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32)
+                    ).astype(jnp.bfloat16)
+    set_dequant_mode(mode)
+    try:
+        got = q40_matmul_pallas(x, pw, interpret=True, w_dtype=jnp.bfloat16)
+    finally:
+        set_dequant_mode(None)
+    assert got.dtype == jnp.bfloat16 and got.shape == (m, d_out)
+    got = np.asarray(got, np.float32)
+    want = np.asarray(_two_dot_form(x, pw, jnp.bfloat16), np.float32)
+    top = np.abs(want).max()
+    # one bf16 rounding of the output is 2**-8 of a value; the chains that
+    # round the scale to bf16 as well stay inside this file's 2e-2 of max
+    bound = 2 ** -7 if mode == "v4" else 2e-2
+    assert np.abs(got - want).max() <= bound * top, (
+        mode, np.abs(got - want).max() / top)
+
+
+@pytest.mark.parametrize("made_by", ["norm", "gated_product"])
+def test_x_rounded_once_feeds_both_terms_of_the_folded_minus_8(made_by):
+    """x as the model makes it in f32 and casts to bf16: an RMS norm, and the
+    FFN's gated product silu(a) * b of two bf16 arrays. The kernel handed the
+    bf16 x agrees with the two-dot form on that x to the order of a sum and
+    the output's one rounding. The parent's compiled decode step summed the
+    blocks of the UNROUNDED product at w2's input (XLA removed the f32 ->
+    bf16 -> f32 pair after the multiply: allow_excess_precision; the four
+    other inputs of a layer were rounded, PERF.md section 6, PR 42), while
+    its dots saw the rounded one; that form (``sums_of`` the f32) is the
+    farther of the two from the f32 result, by the 8 * s * sum(x - bf16(x))
+    the two terms then disagree by."""
+    rng = np.random.default_rng(42)
+    m, d_in, d_out = 16, 3584, 1024
+    pw = _pack(rng, d_out, d_in)
+    as_bf16 = lambda a: jnp.asarray(a).astype(jnp.bfloat16).astype(jnp.float32)
+    a = as_bf16(rng.standard_normal((m, d_in), dtype=np.float32))
+    if made_by == "norm":
+        g = jnp.asarray(1 + 0.1 * rng.standard_normal(d_in, dtype=np.float32))
+        xf = a * jax.lax.rsqrt((a * a).mean(-1, keepdims=True) + 1e-5) * g
+    else:
+        b = as_bf16(rng.standard_normal((m, d_in), dtype=np.float32))
+        xf = as_bf16(jax.nn.silu(a)) * b
+    xb = xf.astype(jnp.bfloat16)
+    exact = np.asarray(q40_matmul_xla(xf, pw), np.float32)
+    rel = lambda y: (np.linalg.norm(np.asarray(y, np.float32) - exact)
+                     / np.linalg.norm(exact))
+
+    got = q40_matmul_pallas(xb, pw, interpret=True, w_dtype=jnp.bfloat16)
+    same_x = _two_dot_form(xb, pw, jnp.bfloat16)
+    sums_unrounded = _two_dot_form(xb, pw, jnp.bfloat16, sums_of=xf)
+    gap = np.abs(np.asarray(got, np.float32) - np.asarray(same_x, np.float32))
+    assert gap.max() <= 2 ** -7 * np.abs(exact).max()
+    # 0.0048 against 0.0062 on this seed, either way x was made
+    assert rel(got) < 0.9 * rel(sums_unrounded), (rel(got), rel(sums_unrounded))
+    assert abs(rel(got) - rel(same_x)) < 0.01 * rel(same_x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m,n", [(8, 512), (16, 3584), (5, 14336), (256, 64),
+                                 (16, 7168), (8, 2048)])
+def test_block_sums_equal_the_reshaped_sum(m, n, dtype):
+    """The kernel's block sums (a dot against a 0/1 matrix: no lane of x is
+    split) against ``x.reshape(m, n_blk, 32).sum(-1)`` in f32: the same 32
+    numbers summed in f32 either way, so equal to the order of a sum."""
+    rng = np.random.default_rng(m + n)
+    x = jnp.asarray(rng.standard_normal((m, n), dtype=np.float32)).astype(dtype)
+    pieces = pq._block_sums(x)
+    # one 0/1 matrix of at most BSUM_SLICE columns, whatever the chunk's width
+    assert len(pieces) == n // pq._sum_slice(n) and pq._sum_slice(n) <= 2048
+    got = np.concatenate([np.asarray(p) for p in pieces], axis=1)
+    assert got.dtype == np.float32 and got.shape == (m, n // 32)
+    want = np.asarray(x.astype(jnp.float32).reshape(m, n // 32, 32).sum(-1))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("m,dtype,want", [
+    (1, jnp.float32, (8, 8)), (8, jnp.float32, (8, 8)),
+    (8, jnp.bfloat16, (16, 16)), (16, jnp.bfloat16, (16, 16)),
+    (20, jnp.bfloat16, (32, 32)), (64, jnp.bfloat16, (64, 64)),
+    (300, jnp.float32, (512, 256)), (300, jnp.bfloat16, (512, 256)),
+    (1024, jnp.bfloat16, (1024, 256)),
+])
+def test_rows_pad_to_whole_tiles_of_their_dtype(m, dtype, want):
+    """A bf16 block wants whole 16-row tiles (two rows a sublane), an f32
+    one 8: decided by the input's dtype and static row count, nothing else."""
+    assert pq._m_geometry(m, dtype) == want
+    x = jnp.zeros((m, 64), dtype)
+    assert make_q80_acts(x).x_rows.shape == (want[0], 64)
+    assert make_q80_acts(x).x_rows.dtype == dtype
+
+
+# --- the lowered-program witness -------------------------------------------
+
+import re  # noqa: E402
+
+WITNESS_SCOPES = ("dl.ffn", "dl.qkv", "dl.attn_out")
+
+
+def _arrays_under(jaxpr, scopes, prefix=""):
+    """(scope path, shape) of every array an equation makes under one of
+    ``scopes``, through scans, jits and conditionals, NOT into a kernel: what
+    a Pallas kernel does inside is not an XLA operation."""
+    found = []
+    for eqn in jaxpr.eqns:
+        path = f"{prefix}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if any(s in path for s in scopes):
+            found += [(path, tuple(v.aval.shape)) for v in eqn.outvars
+                      if hasattr(v.aval, "shape")]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _arrays_under(sub, scopes, path)
+    return found
+
+
+def _lane_splits(found):
+    """Arrays of rank 3 and up whose last axis is 16: a quant block's half,
+    split off the lane axis."""
+    return sorted({shape for _, shape in found
+                   if len(shape) >= 3 and shape[-1] == 16})
+
+
+@pytest.fixture(scope="module")
+def witness_engine(tmp_path_factory):
+    """A two-layer quantised model no dimension of which is 16 (64-wide
+    heads, 128 / 256-wide matmul inputs: 4 and 8 quant blocks), served by a
+    real engine with the kernel in interpret mode."""
+    from distributed_llama_multiusers_tpu.formats.model_file import load_model_header
+    from distributed_llama_multiusers_tpu.formats.synthetic import (
+        tiny_header,
+        write_synthetic_model,
+    )
+    from distributed_llama_multiusers_tpu.models.loader import (
+        load_params_from_m_quantized,
+    )
+    from distributed_llama_multiusers_tpu.ops import linear
+    from distributed_llama_multiusers_tpu.runtime import InferenceEngine
+
+    path = str(tmp_path_factory.mktemp("witness") / "w.m")
+    write_synthetic_model(path, tiny_header(
+        dim=128, hidden_dim=256, n_heads=2, n_kv_heads=1, seq_len=32), seed=5)
+    h = load_model_header(path)
+    config, qparams = load_params_from_m_quantized(path, h, dtype=jnp.bfloat16)
+    linear.set_pallas_interpret(True)
+    linear.set_pallas_w_dtype(jnp.bfloat16)
+    try:
+        yield InferenceEngine(config, qparams, n_lanes=2, prefill_buckets=(8,))
+    finally:
+        linear.set_pallas_w_dtype(None)
+        linear.set_pallas_interpret(False)
+
+
+def _decode_step_forms(engine):
+    """(StableHLO text, jaxpr) of the pipelined decode step program as the
+    engine's own entry point dispatches it."""
+    fn, seen = engine._decode_pl_fn, []
+
+    def spy(*args, **kw):
+        seen.append((fn.lower(*args, **kw).as_text(),
+                     fn.trace(*args, **kw).jaxpr))
+        return fn(*args, **kw)
+
+    engine._decode_pl_fn = spy
+    try:
+        z = np.zeros(engine.n_lanes, np.int32)
+        engine.decode_pipelined(z, tokens=z)
+        engine.pipeline_flush()
+    finally:
+        engine._decode_pl_fn = fn
+    assert seen, "the decode step program was not dispatched"
+    return seen[0]
+
+
+# an activation [rows, d_in] seen as [rows, d_in / 32, 2, 16]
+SPLIT_RESHAPE = re.compile(r"tensor<\d+x\d+x2x16x(?:f32|bf16)>")
+
+
+@pytest.mark.parametrize("mode,splits", [
+    ("v4", False), ("bf16chain", False), ("repeat", False), ("u8chain", False),
+    ("blockdot", True),
+])
+def test_decode_step_program_splits_no_activation_lane(witness_engine, mode,
+                                                       splits):
+    """There is no fallback whose hits could be counted, so the witness is
+    the program: the lowered decode step of a quantised model holds no
+    reshape of an activation to [.., d_in / 32, 2, 16] and, outside the
+    kernels, no array whose last axis is 16 under the three scopes the dense
+    Q40 matmuls run in. The control is the mode that still takes pre-split
+    operands (blockdot), in which the same search finds both."""
+    set_dequant_mode(mode)
+    try:
+        reset_trace_stats()
+        jax.clear_caches()  # the step program is traced anew under this mode
+        text, jaxpr = _decode_step_forms(witness_engine)
+    finally:
+        set_dequant_mode(None)
+        jax.clear_caches()
+    found = _arrays_under(jaxpr, WITNESS_SCOPES)
+    assert any("dl.ffn" in p for p, _ in found), "no dl.ffn scope in the program"
+    if splits:
+        assert SPLIT_RESHAPE.search(text)
+        assert _lane_splits(found), found
+        assert TRACE_STATS["natural_x_consumes"] == 0, TRACE_STATS
+    else:
+        assert not SPLIT_RESHAPE.search(text), SPLIT_RESHAPE.findall(text)
+        assert _lane_splits(found) == [], _lane_splits(found)
+        assert TRACE_STATS["natural_x_consumes"] == TRACE_STATS["impl_traces"] > 0
+
+
+def test_the_witness_finds_the_split_the_kernel_took_before():
+    """Control on the preparation itself: the operands every chain took
+    before PR 42, and the block-dot modes still take, are built by that
+    split; lowered alone under a scope it shows what both searches look for,
+    and ``make_q80_acts`` builds none of it, under any mode."""
+    def prep(x):
+        with jax.named_scope("dl.ffn"):
+            return pq._block_dot_operands(make_q80_acts(x).x_rows, "blockdot")
+
+    assert pq.Q80Acts._fields == ("x", "x_rows")  # x and its padded rows
+
+    x = jax.ShapeDtypeStruct((16, 256), jnp.bfloat16)
+    assert SPLIT_RESHAPE.search(jax.jit(prep).lower(x).as_text())
+    found = _arrays_under(jax.make_jaxpr(prep)(x).jaxpr, WITNESS_SCOPES)
+    assert (16, 8, 16) in _lane_splits(found), found
