@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""Convert a HuggingFace Llama/Mistral checkpoint folder to the `.m` format.
+"""Convert a HuggingFace checkpoint folder to the `.m` format (model types
+llama, mistral, mixtral, qwen2, deepseek_v3, deepseek_v32, lfm2_moe, jamba).
 
 Usage: python convert-hf.py <sourceFolderPath> <weightsFloatType> <name>
 
@@ -90,6 +91,9 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         "deepseek_v32": ArchType.LLAMA,
         # a per-layer pattern of conv and attention mixers: KEY_LAYER_KIND
         "lfm2_moe": ArchType.LLAMA,
+        # selective state-space mixers beside attention without rotation:
+        # KEY_LAYER_KIND with LayerKind.SSM, the KEY_SSM_* keys, RopeType.NONE
+        "jamba": ArchType.LLAMA,
     }.get(cfg["model_type"])
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
@@ -118,6 +122,8 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         set_latent_header(h, cfg)
     if cfg["model_type"] == "lfm2_moe":
         set_pattern_header(h, cfg)
+    if cfg["model_type"] == "jamba":
+        set_ssm_header(h, cfg)
     n_experts = cfg.get("num_local_experts")
     if n_experts:
         h.n_experts = int(n_experts)
@@ -216,6 +222,69 @@ def set_pattern_header(h: ModelHeader, cfg: dict) -> None:
         h.moe_select_bias = int(bool(cfg.get("use_expert_bias")))
         h.moe_norm_topk = int(bool(cfg.get("norm_topk_prob", True)))
         h.moe_routed_scale = float(cfg.get("routed_scaling_factor", 1.0))
+
+
+def set_ssm_header(h: ModelHeader, cfg: dict) -> None:
+    """The header keys of ``model_type: jamba`` (formats/model_file.py
+    KEY_SSM_D_INNER ...): a layer is attention where ``l % attn_layer_period
+    == attn_layer_offset`` and a state-space mixer elsewhere, nothing is
+    rotated, and every layer's FFN is a plain MLP. What the runtime does not
+    compute is refused here, by name, not converted wrongly."""
+    if int(cfg.get("num_experts", 1)) > 1:
+        raise ValueError(
+            f"Unsupported jamba setting: num_experts = {cfg['num_experts']} (the routed "
+            "variant, an expert layer every expert_layer_period, is not converted)")
+    for key in ("mamba_proj_bias", "sliding_window"):
+        if cfg.get(key):
+            raise ValueError(f"Unsupported jamba setting: {key} = {cfg[key]!r}")
+    period, offset = int(cfg["attn_layer_period"]), int(cfg["attn_layer_offset"])
+    h.layer_kinds = [LayerKind.ATTENTION if l % period == offset else LayerKind.SSM
+                     for l in range(h.n_layers)]
+    h.rope_type = RopeType.NONE
+    h.norm_epsilon = float(cfg["rms_norm_eps"])
+    rank = cfg.get("mamba_dt_rank", "auto")
+    h.ssm_dt_rank = -(-h.dim // 16) if rank == "auto" else int(rank)
+    h.ssm_d_inner = int(cfg["mamba_expand"]) * h.dim
+    h.ssm_d_state = int(cfg["mamba_d_state"])
+    h.ssm_conv_kernel = int(cfg["mamba_d_conv"])
+    h.ssm_conv_bias = int(bool(cfg.get("mamba_conv_bias", True)))
+    h.ssm_inner_norms = 1  # dt_layernorm, b_layernorm, c_layernorm: the family's
+
+
+def write_ssm_layers(out, index, header: ModelHeader, wt: int) -> None:
+    """The layers of a jamba checkpoint in the order of
+    formats/model_file._pattern_block_specs. ``mamba.in_proj`` (x, z) and
+    ``mamba.x_proj`` (dt, B, C) are kept whole; the depthwise taps ``[E, 1,
+    K]`` are written ``[E, K]``; what steers the state's exponential
+    (``dt_proj`` and its bias, ``A_log``, ``D``), the conv's taps and bias and
+    the three inner norms' gains stay F32. No row of q or k is permuted:
+    nothing is rotated."""
+    for l, kind in enumerate(header.layer_kinds):
+        pre = f"model.layers.{l}"
+        if kind == LayerKind.SSM:
+            m = f"{pre}.mamba"
+            write_tensor(out, index.get(f"{m}.in_proj.weight"), wt)
+            taps = index.get(f"{m}.conv1d.weight")
+            write_tensor(out, taps.reshape(header.ssm_d_inner, header.ssm_conv_kernel), FloatType.F32)
+            if header.ssm_conv_bias:
+                write_tensor(out, index.get(f"{m}.conv1d.bias"), FloatType.F32)
+            write_tensor(out, index.get(f"{m}.x_proj.weight"), wt)
+            for name in ("dt_layernorm", "b_layernorm", "c_layernorm"):
+                write_tensor(out, index.get(f"{m}.{name}.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{m}.dt_proj.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{m}.dt_proj.bias"), FloatType.F32)
+            write_tensor(out, index.get(f"{m}.A_log"), FloatType.F32)
+            write_tensor(out, index.get(f"{m}.D"), FloatType.F32)
+            write_tensor(out, index.get(f"{m}.out_proj.weight"), wt)
+        else:
+            for name in ("q_proj", "k_proj", "v_proj", "o_proj"):
+                write_tensor(out, index.get(f"{pre}.self_attn.{name}.weight"), wt)
+        ffn = f"{pre}.feed_forward"
+        write_tensor(out, index.get(f"{ffn}.gate_proj.weight"), wt)  # w1
+        write_tensor(out, index.get(f"{ffn}.down_proj.weight"), wt)  # w2
+        write_tensor(out, index.get(f"{ffn}.up_proj.weight"), wt)  # w3
+        write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
+        write_tensor(out, index.get(f"{pre}.pre_ff_layernorm.weight"), FloatType.F32)
 
 
 def write_pattern_layers(out, index, header: ModelHeader, wt: int) -> None:
@@ -346,6 +415,8 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
         write_tensor(out, index.get("model.embed_tokens.weight"), FloatType.F32)
         if header.kv_lora_rank:
             write_latent_layers(out, index, header, wt)
+        elif header.ssm_d_inner:
+            write_ssm_layers(out, index, header, wt)
         elif header.layer_kinds:
             write_pattern_layers(out, index, header, wt)
         for l in range(0 if header.kv_lora_rank or header.layer_kinds else header.n_layers):  # a Llama block's layers
@@ -379,8 +450,10 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
                 write_tensor(out, index.get(f"{pre}.mlp.up_proj.weight"), wt)  # w3
             write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
             write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
-        # lfm2_moe names its final norm after the embedding
-        norm_key = "model.embedding_norm.weight" if header.layer_kinds else "model.norm.weight"
+        # lfm2_moe names its final norm after the embedding, jamba after its place
+        norm_key = ("model.final_layernorm.weight" if header.ssm_d_inner
+                    else "model.embedding_norm.weight" if header.layer_kinds
+                    else "model.norm.weight")
         write_tensor(out, index.get(norm_key), FloatType.F32)
         head_key = "lm_head.weight" if "lm_head.weight" in index else "model.embed_tokens.weight"
         write_tensor(out, index.get(head_key), wt)

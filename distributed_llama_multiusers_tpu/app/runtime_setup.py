@@ -118,7 +118,7 @@ def load_stack(args, n_lanes: int | None = None):
         from ..quants.packed import PackedQ40
 
         if config.layer_kinds:
-            first = params.conv.w_in if params.conv is not None else params.attn.wq
+            first = next(m for m in (params.conv, params.ssm, params.attn) if m is not None)[0]
         else:
             first = (params.attn if config.latent_attention else params.layers).wq
         if any(isinstance(x, PackedQ40) for x in [params.wcls, first]):

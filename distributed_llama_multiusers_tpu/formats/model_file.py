@@ -109,6 +109,19 @@ KEY_EXPERTS_HELD_FIRST = 41
 KEY_EXPERTS_HELD_COUNT = 42
 KEY_ROPE_YARN_MSCALE_ALL_DIM_E6 = 43
 KEY_MOE_NORM_FLOOR_EXP10 = 44
+# framework extension: a selective state-space mixer (``LayerKind.SSM``;
+# ``model_type: jamba``; models/hybrid.py, ops/ssm_scan.py), each key written
+# only where the file has such a layer, so every file without one reads, and
+# is written, as before. The mixer's inner width (expand x dim), the state a
+# channel, the rank of the step size's projection, the taps of its causal
+# depthwise conv, whether that conv has a bias, and whether dt, B and C are
+# normed before use (the family's three inner norms).
+KEY_SSM_D_INNER = 45
+KEY_SSM_D_STATE = 46
+KEY_SSM_DT_RANK = 47
+KEY_SSM_CONV_KERNEL = 48
+KEY_SSM_CONV_BIAS = 49
+KEY_SSM_INNER_NORMS = 50
 
 
 class ArchType:
@@ -132,6 +145,7 @@ class LayerKind:
 
     ATTENTION = 0  # GQA over the KV cache ("full_attention")
     CONV = 1  # gated short convolution over a window of inputs ("conv")
+    SSM = 2  # selective state-space mixer: a running sum a channel ("mamba")
 
 
 class RopeType:
@@ -139,6 +153,7 @@ class RopeType:
     FALCON = 1  # reserved in reference enum; unused
     LLAMA3_1 = 2
     YARN = 3  # frequencies blended over a correction range (ops/rope.py)
+    NONE = 4  # no rotation and no other positional term (models/hybrid.py)
 
 
 @dataclass
@@ -194,6 +209,13 @@ class ModelHeader:
     layer_kinds: list = field(default_factory=list)  # LayerKind a layer
     conv_kernel: int = 0
     qk_norm: int = 0
+    # a selective state-space mixer (KEY_SSM_D_INNER ...); zero elsewhere
+    ssm_d_inner: int = 0
+    ssm_d_state: int = 0
+    ssm_dt_rank: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_conv_bias: int = 0
+    ssm_inner_norms: int = 0
     header_size: int = 0
     file_size: int = 0
 
@@ -245,6 +267,9 @@ class ModelHeader:
             [(KEY_LAYER_KIND, kind) for kind in self.layer_kinds]
             + [(KEY_CONV_KERNEL, self.conv_kernel), (KEY_QK_NORM, self.qk_norm)]
             if self.layer_kinds else []
+        ) + (
+            [(key, getattr(self, name)) for key, name in _SSM_INT_KEYS.items()]
+            if self.ssm_d_inner else []
         )
 
 
@@ -271,6 +296,14 @@ _SPARSE_INT_KEYS = {
     KEY_EXPERTS_HELD_COUNT: "experts_held_count",
 }
 _SPARSE_DEFAULTS = {"moe_n_group": 1, "moe_topk_group": 1}
+_SSM_INT_KEYS = {
+    KEY_SSM_D_INNER: "ssm_d_inner",
+    KEY_SSM_D_STATE: "ssm_d_state",
+    KEY_SSM_DT_RANK: "ssm_dt_rank",
+    KEY_SSM_CONV_KERNEL: "ssm_conv_kernel",
+    KEY_SSM_CONV_BIAS: "ssm_conv_bias",
+    KEY_SSM_INNER_NORMS: "ssm_inner_norms",
+}
 _YARN_E6_KEYS = {KEY_ROPE_YARN_MSCALE_ALL_DIM_E6: "rope_yarn_mscale_all_dim"}
 
 
@@ -284,6 +317,8 @@ LATENT_FIELDS = (
     *_LATENT_INT_KEYS.values(), "moe_routed_scale",
     *_SPARSE_INT_KEYS.values(), *_YARN_E6_KEYS.values(), "moe_norm_floor",
 )
+# every header field of a selective state-space mixer, as models/config.py takes them
+SSM_FIELDS = tuple(_SSM_INT_KEYS.values())
 
 
 def write_model_header(f: BinaryIO, header: ModelHeader) -> int:
@@ -369,6 +404,8 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 h.conv_kernel = value
             elif key == KEY_QK_NORM:
                 h.qk_norm = value
+            elif key in _SSM_INT_KEYS:
+                setattr(h, _SSM_INT_KEYS[key], value)
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
@@ -376,6 +413,11 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
         if h.layer_kinds and len(h.layer_kinds) != h.n_layers:
             raise ValueError(
                 f"{len(h.layer_kinds)} layer kinds for {h.n_layers} layers")
+        if LayerKind.SSM in h.layer_kinds and not (
+                h.ssm_d_inner and h.ssm_d_state and h.ssm_dt_rank and h.ssm_conv_kernel >= 2):
+            raise ValueError(
+                "a state-space layer needs ssm_d_inner, ssm_d_state, ssm_dt_rank "
+                "and ssm_conv_kernel >= 2")
         h.header_size = header_size
         h.orig_seq_len = h.seq_len
         if max_seq_len > 0 and h.seq_len > max_seq_len:
@@ -517,14 +559,39 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
     order), the taps (F32, ``[dim, conv_kernel]``), ``conv_out``. An attention
     layer: q, k, v (rows permuted to the interleaved-pair layout, as a Llama
     file's), their per-head norm gains (F32, permuted alike, where
-    ``qk_norm``), wo. Then a dense FFN in the first ``n_dense_layers`` layers
-    and a routed one in the others; then the two norms."""
+    ``qk_norm``), wo. A state-space layer (``model_type: jamba``): ``ssm_in``
+    (2 x ``ssm_d_inner`` rows: the input x, the gate z, in that order), the
+    conv's taps (F32, ``[ssm_d_inner, ssm_conv_kernel]``) and its bias (F32,
+    where the header says so), ``ssm_x`` (``ssm_dt_rank`` + 2 x
+    ``ssm_d_state`` rows: dt, B, C, in that order), the gains of their three
+    norms (F32, where the header says so), then what steers the state's
+    exponential, all F32: ``dt_proj`` ``[ssm_d_inner, ssm_dt_rank]`` and its
+    bias, ``A_log`` ``[ssm_d_inner, ssm_d_state]``, ``D``; then ``ssm_out``.
+    Then a dense FFN in the first ``n_dense_layers`` layers (every layer
+    where there are no experts) and a routed one in the others; then the two
+    norms."""
     wt, dim, kv_dim = h.weight_type, h.dim, h.kv_dim
+    e, n, r = h.ssm_d_inner, h.ssm_d_state, h.ssm_dt_rank
     for l, kind in enumerate(h.layer_kinds):
         if kind == LayerKind.CONV:
             add("block_matmul_conv_in", l, wt, (3 * dim, dim))
             add("block_conv_taps", l, FloatType.F32, (dim, h.conv_kernel))
             add("block_matmul_conv_out", l, wt, (dim, dim))
+        elif kind == LayerKind.SSM:
+            add("block_matmul_ssm_in", l, wt, (2 * e, dim))
+            add("block_ssm_conv_taps", l, FloatType.F32, (e, h.ssm_conv_kernel))
+            if h.ssm_conv_bias:
+                add("block_ssm_conv_bias", l, FloatType.F32, (1, e))
+            add("block_matmul_ssm_x", l, wt, (r + 2 * n, e))
+            if h.ssm_inner_norms:
+                add("block_ssm_dt_norm", l, FloatType.F32, (1, r))
+                add("block_ssm_b_norm", l, FloatType.F32, (1, n))
+                add("block_ssm_c_norm", l, FloatType.F32, (1, n))
+            add("block_ssm_dt_proj", l, FloatType.F32, (e, r))
+            add("block_ssm_dt_bias", l, FloatType.F32, (1, e))
+            add("block_ssm_a_log", l, FloatType.F32, (e, n))
+            add("block_ssm_d", l, FloatType.F32, (1, e))
+            add("block_matmul_ssm_out", l, wt, (dim, e))
         elif kind == LayerKind.ATTENTION:
             add("block_matmul_q", l, wt, (dim, dim))
             add("block_matmul_k", l, wt, (kv_dim, dim))

@@ -87,6 +87,42 @@ def tiny_pattern_header(
     return h
 
 
+def tiny_ssm_header(
+    pattern: str = "MMAM",
+    ssm_d_state: int = 8,
+    ssm_dt_rank: int = 32,
+    ssm_conv_kernel: int = 4,
+    **kw,
+) -> ModelHeader:
+    """A toy of a block with selective state-space mixers (models/hybrid.py,
+    ops/ssm_scan.py): ``pattern`` a letter a layer, ``M`` a state-space mixer
+    of twice the stream's width, ``A`` GQA attention without rotation; every
+    FFN dense."""
+    kw.setdefault("n_kv_heads", 1)
+    h = tiny_header(n_layers=len(pattern), rope_type=RopeType.NONE, **kw)
+    h.layer_kinds = [LayerKind.SSM if c == "M" else LayerKind.ATTENTION for c in pattern]
+    h.ssm_d_inner, h.ssm_d_state, h.ssm_dt_rank = 2 * h.dim, ssm_d_state, ssm_dt_rank
+    h.ssm_conv_kernel, h.ssm_conv_bias, h.ssm_inner_norms = ssm_conv_kernel, 1, 1
+    h.norm_epsilon = 1e-6
+    return h
+
+
+def ssm_steering_init(name: str, shape, rng) -> np.ndarray | None:
+    """What steers a state-space layer's exponential, as the mixer's own
+    published initialisation draws it (it decides how long the state
+    remembers): ``A_log = log(1..N)`` a channel, the step's bias such that
+    its softplus is log-uniform in ``[1e-3, 1e-1]``, ``D = 1``. None for any
+    other tensor."""
+    if name == "block_ssm_a_log":
+        return np.broadcast_to(np.log(np.arange(1, shape[1] + 1, dtype=np.float32)), shape)
+    if name == "block_ssm_dt_bias":
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape)).astype(np.float32)
+        return dt + np.log(-np.expm1(-dt))  # softplus^-1
+    if name == "block_ssm_d":
+        return np.ones(shape, np.float32)
+    return None
+
+
 def tiny_sparse_latent_header(
     n_layers: int = 3,
     n_experts: int = 16,
@@ -149,7 +185,8 @@ def write_synthetic_model(path: str, header: ModelHeader, seed: int = 0, scale: 
             for spec in model_tensor_specs(header):
                 # a norm's gains sit about one
                 gain = "norm" in spec.name and "bias" not in spec.name
-                _write_tensor(f, gain + rand(spec.shape), spec.float_type)
+                x = ssm_steering_init(spec.name, spec.shape, rng)
+                _write_tensor(f, gain + rand(spec.shape) if x is None else x, spec.float_type)
         return
 
     with open(path, "wb") as f:

@@ -6,6 +6,7 @@ from dataclasses import dataclass
 
 from ..formats.model_file import (
     LATENT_FIELDS,
+    SSM_FIELDS,
     HiddenAct,
     LayerKind,
     ModelHeader,
@@ -92,6 +93,20 @@ class LlamaConfig:
     layer_kinds: tuple = ()
     conv_kernel: int = 0
     qk_norm: int = 0
+    # A selective state-space mixer among those kinds (LayerKind.SSM;
+    # ops/ssm_scan.py): ssm_d_inner channels, each a running sum of
+    # ssm_d_state numbers (float32 whatever the cache's type) fed through a
+    # causal depthwise conv of ssm_conv_kernel taps (with a bias where
+    # ssm_conv_bias), the step size projected from ssm_dt_rank numbers;
+    # ssm_inner_norms: dt, B and C are normed before use. Its per-lane state
+    # is the sum and the conv's last ssm_conv_kernel - 1 inputs. With
+    # rope_type NONE the attention layers rotate nothing.
+    ssm_d_inner: int = 0
+    ssm_d_state: int = 0
+    ssm_dt_rank: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_conv_bias: int = 0
+    ssm_inner_norms: int = 0
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -143,6 +158,13 @@ class LlamaConfig:
                 raise ValueError("a layer pattern's attention layers are GQA, not latent")
             if self.n_conv_layers and self.conv_kernel < 2:
                 raise ValueError("a conv layer needs conv_kernel >= 2")
+            if self.n_ssm_layers and not (
+                self.ssm_d_inner > 0 and self.ssm_d_state > 0 and self.ssm_dt_rank > 0
+                and self.ssm_conv_kernel >= 2
+            ):
+                raise ValueError(
+                    "a state-space layer needs ssm_d_inner, ssm_d_state, "
+                    "ssm_dt_rank and ssm_conv_kernel >= 2")
             if self.n_experts > 0 and not (
                 self.moe_hidden_dim > 0 and 0 <= self.n_dense_layers <= self.n_layers
             ):
@@ -156,16 +178,20 @@ class LlamaConfig:
         return sum(k == LayerKind.CONV for k in self.layer_kinds)
 
     @property
+    def n_ssm_layers(self) -> int:
+        return sum(k == LayerKind.SSM for k in self.layer_kinds)
+
+    @property
     def n_attention_layers(self) -> int:
         """Layers that keep keys and values: what the KV stack holds."""
-        return self.n_layers - self.n_conv_layers
+        return self.n_layers - self.n_conv_layers - self.n_ssm_layers
 
     @property
     def recurrent_state(self) -> bool:
         """Whether a lane carries state that is overwritten in place, beside
         what the cache keeps by position: nothing that rewinds a lane or
         copies one at another position than its last holds for it."""
-        return self.n_conv_layers > 0
+        return self.n_conv_layers > 0 or self.n_ssm_layers > 0
 
     @property
     def n_routed_layers(self) -> int:
@@ -240,4 +266,5 @@ class LlamaConfig:
             layer_kinds=tuple(h.layer_kinds),
             conv_kernel=h.conv_kernel,
             qk_norm=h.qk_norm,
+            **{name: getattr(h, name) for name in SSM_FIELDS},
         )

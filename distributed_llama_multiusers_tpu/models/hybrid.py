@@ -1,10 +1,12 @@
-"""A block whose layers differ in their mixer (``model_type: lfm2_moe``):
-gated short convolutions and GQA attention in a published per-layer pattern,
-leading dense FFNs, then routed ones. Assembled from the parts of the other
-two blocks, with ``llama_forward``'s signature: the GQA projection, append and
+"""A block whose layers differ in their mixer (``model_type: lfm2_moe``,
+``jamba``): gated short convolutions, selective state-space mixers and GQA
+attention in a published per-layer pattern, leading dense FFNs, then routed
+ones (or dense ones throughout). Assembled from the parts of the other two
+blocks, with ``llama_forward``'s signature: the GQA projection, append and
 plane attention are ``models/llama.py``'s, the router, the route plan, the
-grouped kernel and the gated FFN ``models/deepseek.py``'s. New here are the
-conv mixer, its state, and a forward over a list of layer kinds.
+grouped kernel and the gated FFN ``models/deepseek.py``'s, the state-space
+recurrence ``ops/ssm_scan.py``'s. New here are the conv and state-space
+mixers, their state, and a forward over a list of layer kinds.
 
 The layer (``h`` the stream, ``K = conv_kernel``):
 
@@ -13,19 +15,30 @@ The layer (``h`` the stream, ``K = conv_kernel``):
                 u_t = B_t * X_t
                 v_t = sum_{j<K} w[j] * u_{t-(K-1)+j}      depthwise, causal, u_{<0} = 0
                 h' = h + W_out (C_t * v_t)
-    attention:  q, k normed per head (where ``qk_norm``), rotated, GQA over the cache
+    state-space: [x; z] = W_in n         two parts of ``E = ssm_d_inner``, in that order
+                c_t = b_c + sum_{j<K'} w[j] * x_{t-(K'-1)+j}   depthwise, causal, x_{<0} = 0
+                u_t = silu(c_t);  [dt; B; C] = W_x u_t      R + N + N numbers, each normed
+                D_t = softplus(W_dt dt + b_dt),  A = -exp(A_log)        float32
+                S_t = exp(D_t (x) A) * S_{t-1} + (D_t * u_t) (x) B_t    S_{-1} = 0
+                h' = h + W_out ((S_t C_t + D * u_t) * silu(z_t))
+    attention:  q, k normed per head (where ``qk_norm``), rotated (unless
+                ``rope_type`` NONE), GQA over the cache
                 h' = h + Wo o
     FFN:        dense in the first ``n_dense_layers`` layers, routed in the others
 
 The state. Each kind of layer keeps its own stack, indexed by the count of
 that kind: ``k`` and ``v`` ``[attention layers, lanes, S, n_kv * head]`` (no
 plane for a conv layer), and the conv layers' window of inputs
-``[conv layers, lanes, (K-1) * dim]``: a lane's last ``K - 1`` rows of ``u``.
-Both are flat in their last axis, so that it is whole tiles of a TPU's 128
+``[conv layers, lanes, (K-1) * dim]``: a lane's last ``K - 1`` rows of ``u``;
+and, where the block has state-space layers, their running sum ``ssm``
+``[SSM layers, lanes, N * E]``, FLOAT32 whatever the cache's type, and their
+conv's window ``ssm_conv`` ``[SSM layers, lanes, (K'-1) * E]`` (both None in a
+block without such layers: its programs are what they were).
+All are flat in their last axis, so that it is whole tiles of a TPU's 128
 lanes: with a 64-wide head as the last axis XLA gave the K/V stack another
 layout inside the layer loop and copied it whole, in and out, every step
 (compiled for a described v5e, PR 35), and a size-one axis before the last
-cost whole-stack copies before (PERF.md section 6, PR 33). All three ride the carry and are written in place;
+cost whole-stack copies before (PERF.md section 6, PR 33). All ride the carry and are written in place;
 the lane axis is axis 1 of each, so the engine's lane splice, slice and copy
 treat them alike.
 
@@ -41,8 +54,16 @@ is overwritten, not kept by position: a lane cannot be rewound, and a copy of
 a lane is its state at its LAST position (runtime/engine.py refuses what
 would need either).
 
-The layers. The leading dense layers run first, unrolled; the routed layers
-run as one ``lax.scan`` over whole periods of their kinds (the shortest
+The running sum has the window's rule in its own terms (``ops/ssm_scan.py``):
+rows at or past ``a`` take ``D_t = 0`` and no input, so ``S`` passes through
+them unchanged and the step leaves the state AFTER ROW ``a - 1`` (the state it
+read where ``a = 0``); a step whose first position is 0 reads ``S = 0`` and a
+zero window; a second chunk continues the first exactly.
+
+The layers. In a routed model the leading dense layers run first, unrolled;
+the others (every layer of a model without routed ones, its dense FFNs read
+by layer) run as one ``lax.scan`` over whole periods of their kinds (a run of
+``RUN_SCAN_MIN`` or more layers of one kind inside a period a scan of its own; the shortest
 period the published list repeats with; its layers unrolled inside the body,
 each computing only its own mixer) and the odd tail unrolled after it. Every
 weight stack is closed over and read at its index (``deepseek._pick``).
@@ -71,6 +92,7 @@ from ..formats.model_file import LayerKind
 from ..ops import pallas_attention
 from ..ops.linear import matmul, pallas_interpret
 from ..ops.norm import rms_norm
+from ..ops.ssm_scan import state_step
 from ..quants.packed import PackedQ40, Q40Experts
 from ..telemetry.names import (
     SCOPE_ATTENTION,
@@ -82,6 +104,7 @@ from ..telemetry.names import (
     SCOPE_KV_WRITE,
     SCOPE_LAYERS,
     SCOPE_QKV,
+    SCOPE_SSM,
 )
 from .config import LlamaConfig
 from .deepseek import (
@@ -122,6 +145,25 @@ class ConvParams(NamedTuple):
     rms: jnp.ndarray  # [Lc, dim]: the layer's operator norm
 
 
+class SsmParams(NamedTuple):
+    """The state-space layers' weights, stacked ``[SSM layers, ...]``. What
+    steers the state's exponential is float32 whatever the activations are."""
+
+    w_in: jnp.ndarray  # [Ls, dim, 2 * E]: x, z
+    taps: jnp.ndarray  # [Ls, K, E] f32: tap j multiplies x_{t-(K-1)+j}
+    conv_bias: jnp.ndarray | None  # [Ls, E] f32 (config.ssm_conv_bias)
+    w_x: jnp.ndarray  # [Ls, E, R + 2 * N]: dt, B, C
+    dt_norm: jnp.ndarray | None  # [Ls, R] f32 (config.ssm_inner_norms)
+    b_norm: jnp.ndarray | None  # [Ls, N]
+    c_norm: jnp.ndarray | None
+    w_dt: jnp.ndarray  # [Ls, R, E] f32
+    dt_bias: jnp.ndarray  # [Ls, E] f32
+    a_log: jnp.ndarray  # [Ls, N, E] f32: A = -exp(a_log)
+    d: jnp.ndarray  # [Ls, E] f32: the skip term
+    w_out: jnp.ndarray  # [Ls, E, dim]
+    rms: jnp.ndarray  # [Ls, dim]: the layer's input norm
+
+
 class HybridParams(NamedTuple):
     embedding: jnp.ndarray  # [vocab, dim]
     attn: GqaParams | None
@@ -130,8 +172,9 @@ class HybridParams(NamedTuple):
     routed: RoutedFfnParams | None
     rms_final: jnp.ndarray
     wcls: jnp.ndarray
-    rope_cos: jnp.ndarray  # [seq_len, head_size // 2] f32
-    rope_sin: jnp.ndarray
+    rope_cos: jnp.ndarray | None  # [seq_len, head_size // 2] f32; None: no rotation
+    rope_sin: jnp.ndarray | None
+    ssm: SsmParams | None = None
 
 
 class HybridCache(NamedTuple):
@@ -140,15 +183,33 @@ class HybridCache(NamedTuple):
     k: jnp.ndarray  # [La, lanes, S, n_kv * head]
     v: jnp.ndarray
     conv: jnp.ndarray  # [Lc, lanes, (K-1) * dim]: the last K-1 rows of u
+    # state-space layers only (None elsewhere): the running sum, float32
+    # whatever the cache's type, and the conv's last K'-1 inputs
+    ssm: jnp.ndarray | None = None  # [Ls, lanes, N * E]
+    ssm_conv: jnp.ndarray | None = None  # [Ls, lanes, (K'-1) * E]
 
 
 def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32) -> HybridCache:
     kv = (config.n_attention_layers, n_lanes, config.seq_len, config.kv_dim)
+    ssm = ssm_conv = None
+    if config.n_ssm_layers:
+        ls, e = config.n_ssm_layers, config.ssm_d_inner
+        ssm = jnp.zeros((ls, n_lanes, config.ssm_d_state * e), jnp.float32)
+        ssm_conv = jnp.zeros((ls, n_lanes, (config.ssm_conv_kernel - 1) * e), dtype)
     return HybridCache(
         k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
         conv=jnp.zeros(
-            (config.n_conv_layers, n_lanes, (config.conv_kernel - 1) * config.dim), dtype),
+            (config.n_conv_layers, n_lanes, max(config.conv_kernel - 1, 0) * config.dim), dtype),
+        ssm=ssm, ssm_conv=ssm_conv,
     )
+
+
+def state_leaves(cache) -> tuple:
+    """The leaves of a cache that are overwritten in place and not kept by
+    position: a lane's recurrent state (none for any other cache)."""
+    if not isinstance(cache, HybridCache):
+        return ()
+    return tuple(x for x in (cache.conv, cache.ssm, cache.ssm_conv) if x is not None)
 
 
 def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
@@ -159,7 +220,7 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     def experts(w):
         return Q40Experts.from_packed(w) if isinstance(w, PackedQ40) else w
 
-    attn = conv = dense = routed = None
+    attn = conv = dense = routed = ssm = None
     if "wq" in t:
         attn = GqaParams(
             wq=t["wq"], wk=t["wk"], wv=t["wv"], wo=t["wo"],
@@ -168,6 +229,12 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     if "conv_in" in t:
         conv = ConvParams(
             w_in=t["conv_in"], taps=t["conv_taps"], w_out=t["conv_out"], rms=t["conv_rms"])
+    if "ssm_in" in t:
+        ssm = SsmParams(
+            w_in=t["ssm_in"], taps=t["ssm_taps"], conv_bias=t.get("ssm_conv_bias"),
+            w_x=t["ssm_x"], dt_norm=t.get("ssm_dt_norm"), b_norm=t.get("ssm_b_norm"),
+            c_norm=t.get("ssm_c_norm"), w_dt=t["ssm_dt_proj"], dt_bias=t["ssm_dt_bias"],
+            a_log=t["ssm_a_log"], d=t["ssm_d"], w_out=t["ssm_out"], rms=t["ssm_rms"])
     if "dense_w1" in t:
         dense = DenseFfnParams(
             w1=t["dense_w1"], w2=t["dense_w2"], w3=t["dense_w3"], rms_ffn=t["dense_rms_ffn"])
@@ -180,6 +247,7 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     return HybridParams(
         embedding=t["embedding"], attn=attn, conv=conv, dense=dense, routed=routed,
         rms_final=t["rms_final"], wcls=t["wcls"], rope_cos=rope_cos, rope_sin=rope_sin,
+        ssm=ssm,
     )
 
 
@@ -203,6 +271,30 @@ def short_conv(window, taps, t: int):
     return sum(
         taps[j].astype(jnp.float32) * wf[:, j:j + t] for j in range(taps.shape[0])
     )
+
+
+# layers of one kind in a row, inside a period, that run as a scan of their
+# own and not unrolled: a period of thirteen state-space layers and one
+# attention layer unrolled is fourteen layers compiled into every one of a
+# dozen step programs (Jamba2-3B: 252 s of warm-up and executables that
+# outgrew the compile cache, PR 43); LFM2's runs of one and two stay unrolled
+RUN_SCAN_MIN = 4
+
+
+def kind_runs(kinds: tuple) -> list:
+    """``(start, length)`` of every run of equal neighbours in ``kinds``."""
+    runs, start = [], 0
+    for j in range(1, len(kinds) + 1):
+        if j == len(kinds) or kinds[j] != kinds[start]:
+            runs.append((start, j - start))
+            start = j
+    return runs
+
+
+def kinds_after(kind, nth: tuple) -> tuple:
+    """The (attention, conv, state-space) counts after one more layer of ``kind``."""
+    slot = {LayerKind.ATTENTION: 0, LayerKind.CONV: 1, LayerKind.SSM: 2}[kind]
+    return tuple(n + (k == slot) for k, n in enumerate(nth))
 
 
 def layer_periods(kinds: tuple) -> tuple[int, int]:
@@ -237,8 +329,8 @@ def hybrid_forward_counted(
     b, t = tokens.shape
     eps, kinds = cfg.norm_epsilon, cfg.layer_kinds
     k_taps = cfg.conv_kernel
-    quantized = isinstance(
-        params.conv.w_in if params.conv is not None else params.attn.wq, PackedQ40)
+    first = next(m for m in (params.conv, params.ssm, params.attn) if m is not None)
+    quantized = isinstance(first[0], PackedQ40)
     ops = ffn_ops(cfg, emulate_q80_activations, quantized)
     maybe_qdq, share_q80 = ops.maybe_qdq, ops.share_q80
 
@@ -260,6 +352,8 @@ def hybrid_forward_counted(
     row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
     scale = 1.0 / float(cfg.head_size) ** 0.5
     from_zero = (positions[:, :1] == 0)[:, :, None]  # [B, 1, 1]
+    # rows a state may absorb: the first n_valid of a lane (module header)
+    real_row = (jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None])[:, :, None]
 
     def attention(x, ai, k_all, v_all):
         ap = GqaParams(*(_pick(leaf, ai) for leaf in params.attn))
@@ -289,6 +383,17 @@ def hybrid_forward_counted(
             x = x + maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
         return x, k_all, v_all
 
+    def window_step(w_all, wi, u, taps_k: int):
+        """Layer ``wi``'s window of inputs read (zeros where the step starts
+        a sequence: module header), joined with this step's rows and
+        committed; returns ``(window, the stack)``."""
+        state = jax.lax.dynamic_index_in_dim(w_all, wi, 0, keepdims=False)
+        state = state.reshape(b, taps_k - 1, u.shape[-1])
+        state = jnp.where(from_zero, jnp.zeros_like(state), state)
+        window, new_state = window_state(state, u, n_valid)
+        return window, w_all.at[wi].set(
+            _to_cache_dtype(new_state, w_all.dtype).reshape(b, -1))
+
     def conv(x, ci, s_all):
         cp = ConvParams(*(_pick(leaf, ci) for leaf in params.conv))
         with jax.named_scope(SCOPE_CONV):
@@ -297,75 +402,126 @@ def hybrid_forward_counted(
             gate_b, gate_c, xin = jnp.split(bcx, 3, axis=-1)
             u = gate_b * xin
             with jax.named_scope(SCOPE_CONV_STATE):
-                state = jax.lax.dynamic_index_in_dim(s_all, ci, 0, keepdims=False)
-                state = state.reshape(b, k_taps - 1, cfg.dim)
-                # a step that starts a sequence reads zeros, whatever the
-                # lane held (module header)
-                state = jnp.where(from_zero, jnp.zeros_like(state), state)
-                window, new_state = window_state(state, u, n_valid)
-                s_all = s_all.at[ci].set(
-                    _to_cache_dtype(new_state, s_all.dtype).reshape(b, -1))
+                window, s_all = window_step(s_all, ci, u, k_taps)
             v = short_conv(window, cp.taps, t).astype(dtype)
             x = x + maybe_qdq(matmul(maybe_qdq(gate_c * v), cp.w_out))
         return x, s_all
 
-    def mixer(kind, x, ai, ci, k_all, v_all, s_all):
+    def ssm(x, si, ssm_all, win_all):
+        sp = SsmParams(*(_pick(leaf, si) for leaf in params.ssm))
+        n_state, rank = cfg.ssm_d_state, cfg.ssm_dt_rank
+        f32 = jnp.float32
+        with jax.named_scope(SCOPE_SSM):
+            y = rms_norm(x, sp.rms, eps)
+            xin, z = jnp.split(matmul(maybe_qdq(y), sp.w_in), 2, axis=-1)  # [B, T, E] each
+            window, win_all = window_step(win_all, si, xin, cfg.ssm_conv_kernel)
+            c = short_conv(window, sp.taps, t)
+            if sp.conv_bias is not None:
+                c = c + sp.conv_bias
+            u = jax.nn.silu(c)  # float32: the recurrence's input
+            dbc = matmul(maybe_qdq(u.astype(dtype)), sp.w_x).astype(f32)
+            dt, bm, cm = jnp.split(dbc, (rank, rank + n_state), axis=-1)
+            if cfg.ssm_inner_norms:
+                dt = rms_norm(dt, sp.dt_norm, eps)
+                bm = rms_norm(bm, sp.b_norm, eps)
+                cm = rms_norm(cm, sp.c_norm, eps)
+            # the step size steers an exponential: a float32 product
+            delta = jax.nn.softplus(
+                jnp.einsum("btr,re->bte", dt, sp.w_dt,
+                           precision=jax.lax.Precision.HIGHEST) + sp.dt_bias)
+            # a row past the lane's real ones moves nothing (module header)
+            delta = jnp.where(real_row, delta, 0.0)
+            out, ssm_all = state_step(
+                ssm_all, si, from_zero, delta, u, bm, cm, sp.a_log, sp.d)
+            gated = (out * jax.nn.silu(z.astype(f32))).astype(dtype)
+            x = x + maybe_qdq(matmul(maybe_qdq(gated), sp.w_out))
+        return x, ssm_all, win_all
+
+    # the carry: the stream, then every state stack (a kind the block lacks
+    # is None and no leaf), then a routed model's two counts
+    def mixer(kind, carry, ai, ci, si):
+        x, k_all, v_all, s_all, ssm_all, win_all, *counts = carry
         if kind == LayerKind.CONV:
             x, s_all = conv(x, ci, s_all)
+        elif kind == LayerKind.SSM:
+            x, ssm_all, win_all = ssm(x, si, ssm_all, win_all)
         else:
             x, k_all, v_all = attention(x, ai, k_all, v_all)
-        return x, k_all, v_all, s_all
+        return (x, k_all, v_all, s_all, ssm_all, win_all, *counts)
 
     def kinds_before(lo, hi):
+        """(attention, conv, state-space) layers among ``kinds[lo:hi]``."""
         n_conv = sum(k == LayerKind.CONV for k in kinds[lo:hi])
-        return (hi - lo) - n_conv, n_conv
+        n_ssm = sum(k == LayerKind.SSM for k in kinds[lo:hi])
+        return (hi - lo) - n_conv - n_ssm, n_conv, n_ssm
 
-    def routed_layer(kind, carry, ai, ci, lm):
-        x, k_all, v_all, s_all, slabs, assigned = carry
-        x, k_all, v_all, s_all = mixer(kind, x, ai, ci, k_all, v_all, s_all)
+    def dense_layer(kind, carry, nth, l):
+        x, *rest = mixer(kind, carry, *nth)
+        dp = DenseFfnParams(*(_pick(leaf, l) for leaf in params.dense))
+        return (dense_ffn(cfg, ops, x, dp), *rest)
+
+    def routed_layer(kind, carry, nth, lm):
+        x, *stacks, slabs, assigned = mixer(kind, carry, *nth)
         rp = RoutedFfnParams(*(_pick(leaf, lm) for leaf in params.routed))
         x, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live)
-        return (x, k_all, v_all, s_all, slabs + s, assigned + a)
+        return (x, *stacks, slabs + s, assigned + a)
 
-    n_dense = cfg.n_dense_layers if params.routed is not None else cfg.n_layers
+    routed = params.routed is not None
+    n_lead = cfg.n_dense_layers if routed else 0
     with jax.named_scope(SCOPE_LAYERS):
-        k_all, v_all, s_all = cache
-        for l in range(n_dense):  # the leading dense layers, before the scan
-            ai, ci = (jnp.int32(n) for n in kinds_before(0, l))
-            x, k_all, v_all, s_all = mixer(kinds[l], x, ai, ci, k_all, v_all, s_all)
-            dp = DenseFfnParams(*(_pick(leaf, jnp.int32(l)) for leaf in params.dense))
-            x = dense_ffn(cfg, ops, x, dp)
+        carry = (x, *cache)
+        for l in range(n_lead):  # a routed model's leading dense layers, before the scan
+            nth = tuple(jnp.int32(n) for n in kinds_before(0, l))
+            carry = dense_layer(kinds[l], carry, nth, jnp.int32(l))
 
-        counts = None
-        if params.routed is not None:
-            routed_kinds = kinds[n_dense:]
-            period, whole = layer_periods(routed_kinds)
-            a0, c0 = kinds_before(0, n_dense)
-            a_per, c_per = kinds_before(n_dense, n_dense + period)
+        # the others: whole periods of their kinds in one scan, then the odd
+        # tail; the FFN routed where the model routes, else dense by layer
+        body_kinds = kinds[n_lead:]
+        period, whole = layer_periods(body_kinds)
+        before = kinds_before(0, n_lead)
+        per = kinds_before(n_lead, n_lead + period)
+        if routed:
             zero = jnp.zeros((), jnp.int32)
-            carry = (x, k_all, v_all, s_all, zero, zero)
+            carry = (*carry, zero, zero)
+        # a layer's FFN is read at its count after the leading layers (none
+        # lead where every layer is dense: the dense stacks count from 0)
+        layer = routed_layer if routed else dense_layer
 
-            def period_step(carry, i):
-                # a whole period of layers, each reading its kind's stacks at
-                # the count of that kind before it
-                for j in range(period):
-                    a_in, c_in = kinds_before(n_dense, n_dense + j)
-                    carry = routed_layer(
-                        routed_kinds[j], carry, a0 + i * a_per + a_in,
-                        c0 + i * c_per + c_in, i * period + j)
-                return carry, None
+        def period_step(carry, i):
+            # a whole period of layers, each reading its kind's stacks at
+            # the count of that kind before it; a long run of one kind is a
+            # scan of its own (kind_runs)
+            for j, n_run in kind_runs(body_kinds[:period]):
+                within = kinds_before(n_lead, n_lead + j)
+                nth = tuple(n0 + i * n_per + n_in
+                            for n0, n_per, n_in in zip(before, per, within))
+                if n_run < RUN_SCAN_MIN:
+                    for r in range(n_run):  # every kind's count but the run's own stands still
+                        carry = layer(body_kinds[j], carry, nth, i * period + j)
+                        j, nth = j + 1, kinds_after(body_kinds[j], nth)
+                    continue
 
-            if whole:
-                carry, _ = jax.lax.scan(
-                    period_step, carry, jnp.arange(whole, dtype=jnp.int32))
-            for l in range(n_dense + whole * period, cfg.n_layers):  # the odd tail
-                ai, ci = (jnp.int32(n) for n in kinds_before(0, l))
-                carry = routed_layer(kinds[l], carry, ai, ci, jnp.int32(l - n_dense))
-            x, k_all, v_all, s_all, slabs, assigned = carry
+                def run_step(carry, r, j=j, nth=nth):
+                    at = tuple(n + r * (m - n) for n, m in zip(nth, kinds_after(body_kinds[j], nth)))
+                    return layer(body_kinds[j], carry, at, i * period + j + r), None
+
+                carry, _ = jax.lax.scan(run_step, carry, jnp.arange(n_run, dtype=jnp.int32))
+            return carry, None
+
+        if whole:
+            carry, _ = jax.lax.scan(
+                period_step, carry, jnp.arange(whole, dtype=jnp.int32))
+        for l in range(n_lead + whole * period, cfg.n_layers):  # the odd tail
+            nth = tuple(jnp.int32(n) for n in kinds_before(0, l))
+            carry = layer(kinds[l], carry, nth, jnp.int32(l - n_lead))
+        x, *stacks = carry
+        counts = None
+        if routed:
+            *stacks, slabs, assigned = stacks
             counts = (slabs, assigned)
 
     with jax.named_scope(SCOPE_HEAD):
         y = rms_norm(x, params.rms_final, eps)
         logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)
         logits = logits[..., : cfg.vocab_size]
-    return logits, HybridCache(k=k_all, v=v_all, conv=s_all), counts
+    return logits, HybridCache(*stacks), counts

@@ -426,7 +426,8 @@ def gqa_project(cfg: LlamaConfig, yq, wq, wk, wv, positions, rope_cos, rope_sin,
     three projections (``project``: ``matmul``, or a mesh's sliced matmul),
     their biases where the family has them, the per-head norm of queries and
     keys where it has that (``norms``: the two gains, applied over a head
-    BEFORE the rotation), the rotation, and the barrier that holds the cache
+    BEFORE the rotation), the rotation (none where ``rope_cos`` is None: a
+    model without a positional term), and the barrier that holds the cache
     back until all three are done. One form for models/llama.py's scan and
     models/hybrid.py's attention layers."""
     b, t = positions.shape
@@ -438,8 +439,9 @@ def gqa_project(cfg: LlamaConfig, yq, wq, wk, wv, positions, rope_cos, rope_sin,
         q = rms_norm(q, norms[0], cfg.norm_epsilon)
         k = rms_norm(k, norms[1], cfg.norm_epsilon)
 
-    q = apply_rope(q, rope_cos, rope_sin, positions)
-    k = apply_rope(k, rope_cos, rope_sin, positions)
+    if rope_cos is not None:
+        q = apply_rope(q, rope_cos, rope_sin, positions)
+        k = apply_rope(k, rope_cos, rope_sin, positions)
     # all three projections finish before the cache is touched. Left
     # to itself XLA schedules the wq kernel between the K plane's read
     # and the scores that use it, the kernel claims the fast memory

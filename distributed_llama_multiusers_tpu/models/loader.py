@@ -170,6 +170,12 @@ _LATENT_BY_LAYER = {"wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv", "wqa", "rms
                     "idx_wq", "idx_wk", "idx_k_gain", "idx_k_bias", "idx_ww"}
 
 
+# float32 tensors the walk stores [d_out, d_in] (or [channels, taps]) and the
+# blocks read the other way round
+_TRANSPOSED_F32 = {"block_moe_gate", "block_conv_taps", "block_idx_weights",
+                   "block_ssm_conv_taps", "block_ssm_dt_proj", "block_ssm_a_log"}
+
+
 def _load_stacked(path: str, header: ModelHeader, dtype, put, quantized: bool,
                   place, f32_names) -> dict:
     """A ``.m`` whose block tensors ``place(spec)`` files under ``(key,
@@ -188,8 +194,7 @@ def _load_stacked(path: str, header: ModelHeader, dtype, put, quantized: bool,
                 x = pad_packed_d_out(*x)
         else:
             x = _decode_tensor(raw, spec.float_type, spec.shape)
-            x = x.T if matmul or spec.name in (
-                "block_moe_gate", "block_conv_taps", "block_idx_weights") else x
+            x = x.T if matmul or spec.name in _TRANSPOSED_F32 else x
         if not spec.name.startswith("block_"):
             top[spec.name] = x
             continue
@@ -270,9 +275,24 @@ _PATTERN_NAME_MAP = {
     "block_moe_gate": "moe_gate",
     "block_moe_bias": "moe_bias",
     "block_rms_norm_1": "rms_ffn",
+    # a state-space layer (LayerKind.SSM)
+    "block_matmul_ssm_in": "ssm_in",
+    "block_ssm_conv_taps": "ssm_taps",
+    "block_ssm_conv_bias": "ssm_conv_bias",
+    "block_matmul_ssm_x": "ssm_x",
+    "block_ssm_dt_norm": "ssm_dt_norm",
+    "block_ssm_b_norm": "ssm_b_norm",
+    "block_ssm_c_norm": "ssm_c_norm",
+    "block_ssm_dt_proj": "ssm_dt_proj",
+    "block_ssm_dt_bias": "ssm_dt_bias",
+    "block_ssm_a_log": "ssm_a_log",
+    "block_ssm_d": "ssm_d",
+    "block_matmul_ssm_out": "ssm_out",
 }
 _PATTERN_F32 = {"conv_taps", "q_norm", "k_norm", "moe_gate", "moe_bias", "rms_ffn",
-                "dense_rms_ffn", "attn_rms", "conv_rms"}
+                "dense_rms_ffn", "attn_rms", "conv_rms", "ssm_rms", "ssm_taps",
+                "ssm_conv_bias", "ssm_dt_norm", "ssm_b_norm", "ssm_c_norm",
+                "ssm_dt_proj", "ssm_dt_bias", "ssm_a_log", "ssm_d"}
 
 
 def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
@@ -280,11 +300,14 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
     """A ``.m`` with a layer-kind list (``header.layer_kinds``) as the tree
     models/hybrid.py runs: each kind's tensors stacked by the count of that
     kind, the dense FFNs by layer, the routed ones by routed layer (and
-    expert). The taps, the per-head norm gains, the router, its bias and the
-    norms are float32 whatever ``dtype`` is."""
+    expert). The taps, the per-head norm gains, the router, its bias, the
+    norms and what steers a state-space layer's exponential are float32
+    whatever ``dtype`` is."""
     from ..formats.model_file import LayerKind
     from .hybrid import hybrid_params
 
+    mixer_rms = {LayerKind.CONV: "conv_rms", LayerKind.SSM: "ssm_rms",
+                 LayerKind.ATTENTION: "attn_rms"}
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
     n_dense = config.n_dense_layers if config.n_experts else config.n_layers
@@ -295,7 +318,7 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
     def place(spec):
         l = spec.layer
         if spec.name == "block_rms_norm_0":  # the mixer's norm, filed by kind
-            return ("conv_rms" if kinds[l] == LayerKind.CONV else "attn_rms"), (nth[l],)
+            return mixer_rms[kinds[l]], (nth[l],)
         key = _PATTERN_NAME_MAP[spec.name]
         if key in ("w1", "w2", "w3", "rms_ffn", "moe_gate", "moe_bias"):
             if spec.expert < 0 and l < n_dense:
@@ -304,6 +327,8 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
         return key, (nth[l],)
 
     t = _load_stacked(path, header, dtype, put, quantized, place, _PATTERN_F32)
+    if config.rope_type == RopeType.NONE:  # nothing is rotated: no tables
+        return config, hybrid_params(t, None, None)
     cos, sin = _rope_cache(config)
     return config, hybrid_params(t, put("rope_cos", cos), put("rope_sin", sin))
 

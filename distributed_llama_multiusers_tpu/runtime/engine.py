@@ -52,7 +52,7 @@ from ..models.deepseek import (
     forward_counted,
     init_latent_cache,
 )
-from ..models.hybrid import init_hybrid_cache
+from ..models.hybrid import init_hybrid_cache, state_leaves
 from ..ops import pallas_attention
 from ..telemetry.logs import log_event
 from ..telemetry import names
@@ -295,6 +295,14 @@ class EngineStats:
     recurrent_state_bytes: int = 0
     state_zero_starts: int = 0
     prefix_reuse_declined: int = 0
+    # selective state-space layers (config.n_ssm_layers; all 0 elsewhere):
+    # (live lane, layer) pairs whose running sum the decode steps advanced
+    # (counted by the scheduler from its own lane positions), and prompt rows
+    # through the chunked scan, summed over those layers: the real ones, and
+    # every row of the bucket the chunk rode (the program computes them all)
+    ssm_lane_steps: int = 0
+    ssm_rows_scanned: int = 0
+    ssm_rows_computed: int = 0
     # compile stability (analysis/jitcheck.py, ISSUE 15): XLA backend
     # compiles observed AFTER warmup_engine armed the recompile witness —
     # the machine-checked form of "one compiled program per (family,
@@ -338,6 +346,7 @@ class EngineStats:
             "moe_experts_held", "moe_rows_unheld",
             "indexer_rows_scored", "sparse_rows_selected",
             "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
+            "ssm_lane_steps", "ssm_rows_scanned", "ssm_rows_computed",
             "jit_compiles_after_warmup",
         ),
     }
@@ -379,6 +388,7 @@ class EngineStats:
             self.moe_slabs_read = self.moe_slabs_whole = self.moe_assignments = 0
             self.moe_rows_unheld = self.indexer_rows_scored = self.sparse_rows_selected = 0
             self.state_zero_starts = self.prefix_reuse_declined = 0
+            self.ssm_lane_steps = self.ssm_rows_scanned = self.ssm_rows_computed = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
             # compile stability since warmup, and a window reset hiding a
@@ -502,8 +512,9 @@ class InferenceEngine:
             # layer) a state overwritten in place
             unserved = (
                 "a model with a per-layer pattern of mixers "
-                f"({config.n_conv_layers} conv, {config.n_attention_layers} "
-                "attention layers) keeps a stack a kind on one device",
+                f"({config.n_conv_layers} conv, {config.n_ssm_layers} state-space, "
+                f"{config.n_attention_layers} attention layers) keeps a stack a kind "
+                "on one device",
                 "a lane's state is not pages of a K/V pair a layer",
                 "the block's state and expert stacks have no sharding",
             )
@@ -604,7 +615,7 @@ class InferenceEngine:
             self.cache = init_contiguous(config, n_lanes, dtype=cache_dtype)
         self.stats = EngineStats()
         # bytes of per-lane state overwritten in place (0: none)
-        self.lane_state_bytes = self.cache.conv.nbytes if config.recurrent_state else 0
+        self.lane_state_bytes = sum(leaf.nbytes for leaf in state_leaves(self.cache))
         self.stats.recurrent_state_bytes = self.lane_state_bytes
         # routed layers x experts: what a decode step adds to moe_slabs_whole
         # (0: no routed layers, and no counts ride the token readback)
@@ -1558,6 +1569,10 @@ class InferenceEngine:
             facts.update(
                 recurrent_state_bytes=self.lane_state_bytes,
                 declined_for_recurrent_state=["prefix_reuse", "speculation"],
+                # refused at construction, by name (__init__), for any block
+                # that keeps a stack a kind of layer on one device
+                refused_for_recurrent_state=[
+                    "paged_kv", "kv_host_tier", "kv_page_transfer", "migration", "mesh"],
             )
         return facts
 
@@ -1700,6 +1715,8 @@ class InferenceEngine:
             self.stats.prefill_tokens += len(chunk)
             self.stats.prefill_bucket_rows += bucket
             self.stats.state_zero_starts += int(self.config.recurrent_state and start_pos == 0)
+            self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
+            self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
         return last, greedy, sampled
 
     def prefill(
@@ -2082,6 +2099,8 @@ class InferenceEngine:
             self.stats.prefill_tokens += len(chunk)
             self.stats.prefill_bucket_rows += bucket
             self.stats.state_zero_starts += int(self.config.recurrent_state and p_start == 0)
+            self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
+            self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
             self.stats.fused_bucket_hist[bucket] = (
                 self.stats.fused_bucket_hist.get(bucket, 0) + 1
             )

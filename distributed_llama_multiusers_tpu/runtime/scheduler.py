@@ -468,6 +468,8 @@ class ContinuousBatchingScheduler:
         # for such a model (the scheduler_start line says so)
         cfg = getattr(engine, "config", None)
         self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
+        # selective state-space layers: the decode steps' ssm_lane_steps
+        self._n_ssm_layers = int(getattr(cfg, "n_ssm_layers", 0) or 0)
         # a held share of the routed experts, "16/256" (None: every expert)
         held = int(getattr(cfg, "experts_held_count", 0) or 0)
         self._experts_held = f"{held}/{cfg.n_experts}" if held else None
@@ -924,9 +926,13 @@ class ContinuousBatchingScheduler:
             read = whole if block is None else sum(
                 rows_read(positions + s, seq_len, block) for s in range(steps)
             )
+        # a state-space layer advances every live lane's running sum a step
+        ssm = self._n_ssm_layers and self._n_ssm_layers * steps * sum(
+            1 for p in positions if p < seq_len)
         with engine.stats.lock:
             engine.stats.attn_kv_rows_read += read
             engine.stats.attn_kv_rows_whole += whole
+            engine.stats.ssm_lane_steps += ssm
 
     def occupancy(self) -> tuple[int, int]:
         """(busy lanes, total lanes) — public surface for /stats."""
@@ -2353,13 +2359,18 @@ class ContinuousBatchingScheduler:
             # lane's cache, which prefix caching may still reuse
             # (round-5 code-review finding). Lanes mid-prefill point at
             # their next unwritten slot, which the next prefill chunk
-            # rewrites before any query can read it.
+            # rewrites before any query can read it; where a lane carries a
+            # state overwritten in place they stand parked like the idle
+            # ones, as they do in the chain: a running sum or a window of
+            # inputs would absorb the junk row and keep it (PR 43: the
+            # second of two requests admitted together chose other tokens
+            # than it chose alone).
             positions = np.full(n_lanes, cfg.seq_len, np.int32)
             temps = np.zeros(n_lanes, np.float32)
             topps = np.full(n_lanes, DEFAULT_TOPP, np.float32)
             seeds = np.zeros(n_lanes, np.uint32)
             for i, lane in enumerate(self._lanes):
-                if lane.request is not None and lane.pending:
+                if lane.request is not None and lane.pending and not self._recurrent_state:
                     positions[i] = lane.pos
             for i, lane in active:
                 tokens[i] = lane.next_token

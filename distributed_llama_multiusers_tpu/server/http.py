@@ -416,6 +416,13 @@ class ApiServer:
             # every lane's whole plane
             "attn_kv_rows_read": stats["attn_kv_rows_read"],
             "attn_kv_rows_whole": stats["attn_kv_rows_whole"],
+            # selective state-space layers: (live lane, layer) running sums
+            # the decode steps advanced, and prompt rows through the chunked
+            # scan (real, and with their buckets' padding); 0 for a model
+            # without such layers
+            "ssm_lane_steps": stats["ssm_lane_steps"],
+            "ssm_rows_scanned": stats["ssm_rows_scanned"],
+            "ssm_rows_computed": stats["ssm_rows_computed"],
             # a routed FFN's reads of its expert stacks over the decode
             # steps: distinct (layer, expert) slabs fetched, what a sweep of
             # every expert fetches, and (row, expert) pairs routed; all 0

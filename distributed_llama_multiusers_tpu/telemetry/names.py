@@ -74,6 +74,11 @@ SPARSE_ATTENTION_SCOPES = (SCOPE_INDEXER, SCOPE_SPARSE_SELECT)
 SCOPE_CONV = "dl.conv"              # the short-conv mixer: norm, in-projection, gates, taps, out-projection
 SCOPE_CONV_STATE = "dl.conv_state"  # under dl.conv: the lane state's read and its commit
 CONV_MIXER_SCOPES = (SCOPE_CONV, SCOPE_CONV_STATE)
+# a selective state-space layer in such a block (ops/ssm_scan.py) takes dl.ssm
+# in the place of the four attention scopes
+SCOPE_SSM = "dl.ssm"            # the state-space mixer: norm, projections, conv, gates, out-projection
+SCOPE_SSM_SCAN = "dl.ssm_scan"  # under dl.ssm: the running sum's read, the recurrence and its commit
+SSM_MIXER_SCOPES = (SCOPE_SSM, SCOPE_SSM_SCAN)
 
 # scopes inside the layer scan, in program order
 LAYER_SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_ATTN_OUT, SCOPE_FFN)

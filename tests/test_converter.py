@@ -178,3 +178,47 @@ def test_convert_tokenizer_llama3(tmp_path):
     assert len(t.eos_token_ids) == 2
     ids = t.encode("ab", add_bos=False)
     assert ids == [2]  # merged via rank-descending scores
+
+
+def test_convert_hf_jamba_folder_serves(tmp_path):
+    """A tiny ``model_type: jamba`` folder (config.json + safetensors under the
+    published tensor names) through the command's own path: the header says
+    what the layers are, the tied head is the embedding, and the file loads
+    and serves a prompt through the engine."""
+    torch = pytest.importorskip("torch")
+    from safetensors.torch import save_file
+
+    import jax.numpy as jnp
+
+    from test_hybrid_model_file import JCFG, _jamba_state_dict
+
+    cfg = {k: v for k, v in JCFG.items() if k not in ("serving", "correctness", "family", "source")}
+    (tmp_path / "config.json").write_text(json.dumps(cfg))
+    sd = _jamba_state_dict(JCFG, seed=2)
+    save_file({k: torch.from_numpy(np.ascontiguousarray(v)) for k, v in sd.items()},
+              str(tmp_path / "model.safetensors"))
+    mod = _load("convert_hf", "convert-hf.py")
+    out = str(tmp_path / "jamba.m")
+    mod.convert(str(tmp_path), 2, out)  # q40
+
+    from distributed_llama_multiusers_tpu.formats import load_model_header
+    from distributed_llama_multiusers_tpu.formats.model_file import LayerKind, RopeType
+    from distributed_llama_multiusers_tpu.models.loader import load_params_from_m
+    from distributed_llama_multiusers_tpu.quants.codec import dequantize_q40, quantize_q40
+    from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
+
+    h = load_model_header(out)
+    assert h.layer_kinds.count(LayerKind.SSM) == 6 and h.layer_kinds.count(LayerKind.ATTENTION) == 2
+    assert h.rope_type == RopeType.NONE and h.ssm_d_inner == 2 * cfg["hidden_size"]
+    config, params = load_params_from_m(out, h, dtype=jnp.float32)
+    emb = sd["model.embed_tokens.weight"]
+    np.testing.assert_allclose(  # no lm_head in the folder: the tied embedding, quantized
+        np.asarray(params.wcls).T, dequantize_q40(quantize_q40(emb.reshape(-1))).reshape(emb.shape))
+    a_log = sd["model.layers.0.mamba.A_log"]
+    np.testing.assert_array_equal(np.asarray(params.ssm.a_log[0]), a_log.T)  # float32, untouched
+    engine = InferenceEngine(config, params, n_lanes=2, prefill_buckets=(8, 16))
+    last, greedy, pos = engine.prefill(0, list(range(2, 40)))
+    assert pos == 38 and bool(np.isfinite(np.asarray(last)).all())
+    with pytest.raises(ValueError, match="Unsupported arch type"):
+        (tmp_path / "config.json").write_text(json.dumps(dict(cfg, model_type="bamba")))
+        mod.load_config(str(tmp_path), 2)
