@@ -167,6 +167,80 @@ def nucleus_keep(z, topp):
     return jnp.where((topp <= 0.0) | (topp >= 1.0), p > 0.0, keep)
 
 
+# EXACT on-device top-p, with no order. The rule, one function for
+# every step family (decode, pipelined, fused, verify, multi-step,
+# the prefill boundary token, `sample_token`):
+#   1. z = row / max(temp, 1e-6) in float32, p = softmax(z) over the
+#      WHOLE vocabulary (a grammar-masked token has p = 0);
+#   2. a token is kept iff the mass strictly ahead of it is under
+#      top_p: `nucleus_keep` (above) finds the edge value
+#      by a threshold search over the unsorted row, ties at the edge
+#      by token id as a stable sort has them;
+#   3. top_p <= 0 or >= 1 keeps every token with p > 0 (the sorted
+#      form compared a rounded running sum with 1.0 there and lost
+#      80-320 tokens of a 152064-token tail);
+#   4. fold_in(PRNGKey(seed), pos), then a categorical draw over the
+#      kept tokens in vocabulary order; temp == 0 returns `greedy`.
+# No truncation class exists, wide-nucleus / high-temperature
+# requests sample on device like everyone else, and no step program
+# holds a sort of the vocabulary (tests/test_sampler_no_sort.py).
+# The host Sampler survives only as the host_sampling=True escape
+# hatch.
+def _sample_lane(row, temp, topp, seed, pos, greedy):
+    """Exact nucleus sample for one lane, on device: softmax over
+    the whole row, the kept set by `nucleus_keep`, a categorical
+    draw over the kept tokens in vocabulary order.
+
+    The kept set is the reference Sampler's sort→cumsum→cutoff set
+    (src/tokenizer.cpp:416-457) for any (temp, topp); only the RNG
+    differs (fold_in(seed, pos) + categorical here vs xorshift64*
+    there — pinned by tests/test_sampler_parity.py). Deterministic
+    per (seed, position): seeded runs reproduce. The Gumbel noise
+    is attached to a token's ID; builds that sorted the row first
+    attached it to the token's RANK, so a (seed, position) yields
+    another, equally valid, token than it did there (a journal
+    written by such a build replays to other tokens)."""
+    z = row.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
+    keep = nucleus_keep(z, topp)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
+    # z is log p plus a constant of the row: the same distribution
+    choice = jax.random.categorical(key, jnp.where(keep, z, -jnp.inf))
+    return jnp.where(temp == 0.0, greedy, choice.astype(jnp.int32))
+
+
+# The rows the search walks 33 times have to stay in fast memory: a row's
+# key (uint32) and p (float32) are 8 bytes a token, and on a v5e XLA holds
+# 32 x 152064 of them (38.9 MB) or 64 x 65536 (33.6 MB: 3.5 us a pass) where
+# 256 x 65536 at once (134 MB) streams the keys from HBM on every pass
+# (178 us). Groups of 128 x 65536 stay resident too and are no faster.
+SAMPLER_GROUP_BYTES = 40 << 20
+
+
+def sampler_group(rows: int, vocab: int) -> int:
+    """How many rows the sampler takes at once: the largest divisor of
+    ``rows`` whose keys and probabilities fit ``SAMPLER_GROUP_BYTES`` (one
+    row where even one does not). Static shapes only, so every step program
+    of an engine decides alike."""
+    fit = max(1, SAMPLER_GROUP_BYTES // (8 * vocab))
+    return max(g for g in range(1, min(rows, fit) + 1) if rows % g == 0)
+
+
+def sample_lanes(rows, temps, topps, seeds, positions, greedy):
+    """``_sample_lane`` over ``rows`` [n, V] and the lanes' [n] operands, in
+    groups of ``sampler_group`` rows, one group after another (``lax.map``
+    is sequential: two groups' rows are never live together). A lane's
+    arithmetic is the same expression over the same row whatever the group;
+    with one group this IS ``jax.vmap(_sample_lane)``."""
+    lanes = jax.vmap(_sample_lane)
+    operands = (rows, temps, topps, seeds, positions, greedy)
+    n, vocab = rows.shape
+    group = sampler_group(n, vocab)
+    if group == n:
+        return lanes(*operands)
+    grouped = tuple(a.reshape(n // group, group, *a.shape[1:]) for a in operands)
+    return jax.lax.map(lambda xs: lanes(*xs), grouped).reshape(n)
+
+
 @dataclass
 class EngineStats:
     """Per-call timing + transfer counters — the analogue of the reference's
@@ -820,47 +894,8 @@ class InferenceEngine:
             )
             return mgreedy.T, gstates.T
 
-        # EXACT on-device top-p, with no order. The rule, one function for
-        # every step family (decode, pipelined, fused, verify, multi-step,
-        # the prefill boundary token, `sample_token`):
-        #   1. z = row / max(temp, 1e-6) in float32, p = softmax(z) over the
-        #      WHOLE vocabulary (a grammar-masked token has p = 0);
-        #   2. a token is kept iff the mass strictly ahead of it is under
-        #      top_p: `nucleus_keep` (above the class) finds the edge value
-        #      by a threshold search over the unsorted row, ties at the edge
-        #      by token id as a stable sort has them;
-        #   3. top_p <= 0 or >= 1 keeps every token with p > 0 (the sorted
-        #      form compared a rounded running sum with 1.0 there and lost
-        #      80-320 tokens of a 152064-token tail);
-        #   4. fold_in(PRNGKey(seed), pos), then a categorical draw over the
-        #      kept tokens in vocabulary order; temp == 0 returns `greedy`.
-        # No truncation class exists, wide-nucleus / high-temperature
-        # requests sample on device like everyone else, and no step program
-        # holds a sort of the vocabulary (tests/test_sampler_no_sort.py).
-        # The host Sampler survives only as the host_sampling=True escape
-        # hatch.
-        def _sample_lane(row, temp, topp, seed, pos, greedy):
-            """Exact nucleus sample for one lane, on device: softmax over
-            the whole row, the kept set by `nucleus_keep`, a categorical
-            draw over the kept tokens in vocabulary order.
-
-            The kept set is the reference Sampler's sort→cumsum→cutoff set
-            (src/tokenizer.cpp:416-457) for any (temp, topp); only the RNG
-            differs (fold_in(seed, pos) + categorical here vs xorshift64*
-            there — pinned by tests/test_sampler_parity.py). Deterministic
-            per (seed, position): seeded runs reproduce. The Gumbel noise
-            is attached to a token's ID; builds that sorted the row first
-            attached it to the token's RANK, so a (seed, position) yields
-            another, equally valid, token than it did there (a journal
-            written by such a build replays to other tokens)."""
-            z = row.astype(jnp.float32) / jnp.maximum(temp, 1e-6)
-            keep = nucleus_keep(z, topp)
-            key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
-            # z is log p plus a constant of the row: the same distribution
-            choice = jax.random.categorical(key, jnp.where(keep, z, -jnp.inf))
-            return jnp.where(temp == 0.0, greedy, choice.astype(jnp.int32))
-
-        self._sample_lanes = jax.vmap(_sample_lane)
+        # groups a step's rows are sampled in (1: all lanes at once)
+        self.sampler_groups = n_lanes // sampler_group(n_lanes, cfg.vocab_size)
         self._sample_one = jax.jit(
             lambda row, temp, topp, seed, pos: _sample_lane(
                 row, temp, topp, seed, pos, jnp.argmax(row).astype(jnp.int32)
@@ -878,7 +913,7 @@ class InferenceEngine:
             # the pod control packets
             return jax.lax.cond(
                 jnp.any(temps > 0.0),
-                lambda: self._sample_lanes(
+                lambda: sample_lanes(
                     step, temps, topps, seeds, positions, greedy
                 ),
                 lambda: greedy,
@@ -1552,7 +1587,8 @@ class InferenceEngine:
             experts = "q40_grouped_kernel"
         else:
             experts = "xla_gathered_slabs"
-        facts = {"attention_path": attention, "expert_path": experts}
+        facts = {"attention_path": attention, "expert_path": experts,
+                 "sampler_groups": self.sampler_groups}
         if cfg.sparse_attention:
             # how the chosen rows are read (models/deepseek.py: gathered,
             # at every width), and what an indexer's rows are not computed for

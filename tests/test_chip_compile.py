@@ -704,6 +704,37 @@ def test_nucleus_search_compiles_with_no_sort(v5e, lanes, vocab):
     assert not re.search(r"\bsort[.(]|TopK|top_k|topk", hlo)
 
 
+def test_grouped_sampler_holds_a_groups_rows_in_fast_memory(v5e):
+    """Jamba's 256 lanes x 65536 through the sampler's entry (PR 44): four
+    groups of 64, and the chip's compiler keeps BOTH operands of the 32 passes
+    (the keys and the probabilities of a group) in memory space 1 across the
+    inner `while`, where all 256 rows at once leave the keys in HBM and every
+    pass streams them (5.5 ms of that cell's decode half). No sort either."""
+    import re
+
+    from distributed_llama_multiusers_tpu.runtime.engine import (
+        _sample_lane, sample_lanes, sampler_group)
+
+    lanes, vocab = 256, 65536
+    assert sampler_group(lanes, vocab) == 64
+    operands = [jax.ShapeDtypeStruct((lanes, vocab), jnp.float32, sharding=v5e)] + [
+        jax.ShapeDtypeStruct((lanes,), d, sharding=v5e)
+        for d in (jnp.float32, jnp.float32, jnp.int32, jnp.int32, jnp.int32)]
+
+    def searches(fn):
+        hlo = jax.jit(fn).lower(*operands).compile().as_text()
+        assert not re.search(r"\bsort[.(]|TopK|top_k|topk", hlo)
+        # the loops that carry a [rows, vocab] key: the 32 passes
+        return [line for line in hlo.splitlines()
+                if " while(" in line and re.search(r"u32\[\d+,65536\]", line)]
+
+    (search,) = searches(sample_lanes)
+    resident = r"\[64,65536\]\{1,0:T\(8,128\)S\(1\)\}"
+    assert re.search("u32" + resident, search) and re.search("f32" + resident, search)
+    (search,) = searches(jax.vmap(_sample_lane))   # the control: ungrouped
+    assert re.search(r"u32\[256,65536\]\{1,0:T\(8,128\)\}", search)
+
+
 def test_selection_table_resolves_only_to_compile_tested_modes():
     """`auto` may only land on a mode the grid above compiles."""
     modes = {r["mode"] for r in dequant_select.DequantTable().rules}

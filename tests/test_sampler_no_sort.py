@@ -5,7 +5,9 @@ The sampler decides the nucleus by a threshold search over the unsorted row
 fallback whose hits could be counted, so the witness that the mechanism is
 the one that runs is that the lowered and the compiled form of every step
 family that draws a token hold no ``sort`` and no ``top_k`` / ``TopK``. The
-control lowers the sorted form the search replaced and finds both.
+control lowers the sorted form the search replaced and finds both. Every
+family is read twice: over an engine whose lanes the sampler takes at once,
+and over one whose lanes it takes in two groups (PR 44, ``sample_lanes``).
 """
 
 import re
@@ -18,13 +20,14 @@ import jax.numpy as jnp
 from distributed_llama_multiusers_tpu.formats import load_model_header
 from distributed_llama_multiusers_tpu.models import load_params_from_m
 from distributed_llama_multiusers_tpu.runtime import InferenceEngine
+from distributed_llama_multiusers_tpu.runtime import engine as engine_mod
 
 # StableHLO / CHLO operations and HLO instructions or custom-call targets
 SORTS = re.compile(
     r"stablehlo\.sort|chlo\.top_k|mhlo\.topk|\bsort\(|\bsort\.\d+|TopK|top_k|topk",
 )
 
-LANES = 2
+LANES = 4
 
 
 def _z(e):
@@ -68,12 +71,19 @@ PROGRAMS = {
 }
 
 
-@pytest.fixture(scope="module")
-def engine(tiny_model):
+@pytest.fixture(scope="module", params=[1, 2], ids=["one_group", "two_groups"])
+def engine(tiny_model, request):
     h = load_model_header(tiny_model["model"])
     config, params = load_params_from_m(tiny_model["model"], h,
                                         dtype=jnp.float32)
-    return InferenceEngine(config, params, n_lanes=LANES, prefill_buckets=(4,))
+    # the budget is read when a step program traces, so it stays as set for
+    # as long as this engine is the module's
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "SAMPLER_GROUP_BYTES",
+                   8 * config.vocab_size * LANES // request.param)
+        e = InferenceEngine(config, params, n_lanes=LANES, prefill_buckets=(4,))
+        assert e.sampler_groups == request.param == e.path_facts()["sampler_groups"]
+        yield e
 
 
 def _spy(fn, seen):
@@ -106,6 +116,11 @@ def test_step_program_holds_no_sort(engine, attr):
     _assert_no_sort(attr, seen)
     # the sampler is IN the program that was read, not beside it
     assert all("dl.sampler" in hlo for _, hlo in seen) or attr == "_sample_one"
+    # and so is the loop over its groups: the lanes' rows as [groups, group,
+    # V], a shape nothing else in a step program has (one row is never grouped)
+    if attr not in ("_sample_one", "_prefill_fn"):
+        rows = f"tensor<2x{LANES // 2}x{engine.config.vocab_size}xf32>"
+        assert all((rows in shlo) == (engine.sampler_groups == 2) for shlo, _ in seen)
 
 
 @pytest.mark.parametrize("h", [2, 4])

@@ -33,6 +33,9 @@ PINNED NUMERICS CLASS (the contract this file enforces):
   test_spec_pipelined.py).
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 import jax
@@ -41,7 +44,13 @@ import jax.numpy as jnp
 from distributed_llama_multiusers_tpu.formats import load_model_header
 from distributed_llama_multiusers_tpu.models import load_params_from_m
 from distributed_llama_multiusers_tpu.runtime import InferenceEngine
-from distributed_llama_multiusers_tpu.runtime.engine import nucleus_keep
+from distributed_llama_multiusers_tpu.runtime import engine as engine_mod
+from distributed_llama_multiusers_tpu.runtime.engine import (
+    _sample_lane,
+    nucleus_keep,
+    sample_lanes,
+    sampler_group,
+)
 from distributed_llama_multiusers_tpu.runtime.scheduler import (
     HOST_EXACT_TEMP,
     HOST_EXACT_TOPP,
@@ -333,3 +342,90 @@ def test_device_draws_cover_a_tie_group_only_up_to_the_edge(engine):
     draws = {engine.sample_token(row, 1.0, 0.8, seed, 0) for seed in range(400)}
     assert draws <= set(np.nonzero(want)[0].tolist())
     assert len(draws) > want.sum() // 2
+
+
+# ---------------------------------------------------------------------------
+# the sampler over groups of lanes (PR 44): same tokens as all lanes at once
+# ---------------------------------------------------------------------------
+
+
+def _lane_operands(kind, n):
+    """``n`` lanes over rolled copies of one of ``ROWS`` (the tie groups and
+    the masked tokens move, the values stay), the grid's temperatures and
+    top_ps by turns, every fourth lane greedy."""
+    base = ROWS[kind]()
+    rows = np.stack([np.roll(base, 37 * i) for i in range(n)])
+    temps = np.array([GRID[i % len(GRID)][0] for i in range(n)], np.float32)
+    temps[::4] = 0.0
+    topps = np.array([GRID[i % len(GRID)][1] for i in range(n)], np.float32)
+    lanes = np.arange(n, dtype=np.int32)
+    return (rows, temps, topps, 1000 + lanes, 7 * lanes + 3,
+            np.argmax(rows, axis=-1).astype(np.int32))
+
+
+# (rows, rows that fit the budget) -> the group: 1, 2 and 4 groups of 8 rows,
+# and 12 rows where 5 fit but only 4 divide, or 3
+GROUPINGS = {(8, 8): 8, (8, 5): 4, (8, 2): 2, (12, 5): 4, (12, 3): 3}
+
+
+@pytest.mark.parametrize("kind", sorted(ROWS))
+@pytest.mark.parametrize("n,fit", sorted(GROUPINGS))
+def test_grouped_entry_draws_the_ungrouped_vmaps_tokens(monkeypatch, kind, n, fit):
+    operands = _lane_operands(kind, n)
+    vocab = operands[0].shape[1]
+    want = np.asarray(jax.jit(jax.vmap(_sample_lane))(*operands))
+    # (+ 7: a budget need not be a whole number of rows)
+    monkeypatch.setattr(engine_mod, "SAMPLER_GROUP_BYTES", 8 * vocab * fit + 7)
+    group = sampler_group(n, vocab)
+    assert group == GROUPINGS[n, fit]
+    # the mechanism engaged: the 32 passes are one loop, the groups' another
+    # (a function of its own a case: JAX keys a trace by the function and the
+    # shapes, and the budget is neither)
+    def entry(*a):
+        return sample_lanes(*a)
+
+    traced = str(jax.make_jaxpr(entry)(*operands))
+    assert traced.count("scan[") == 1 + (group < n)
+    if group == n:   # one group IS the vmap: a cell under the budget traces as it did
+        assert traced == str(jax.make_jaxpr(jax.vmap(_sample_lane))(*operands))
+    got = np.asarray(jax.jit(entry)(*operands))
+    assert np.array_equal(got, want), (group, np.nonzero(got != want)[0])
+    greedy = operands[1] == 0.0
+    assert np.array_equal(got[greedy], operands[5][greedy])
+    assert (got[~greedy] != operands[5][~greedy]).any()
+
+
+def _cells():
+    """Every cell of BENCHMARK.json with its (lanes, vocabulary)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    files = {c["name"]: os.path.join(root, c["file"]) for c in bench["configs"]}
+    for w in bench["workloads"]:
+        with open(files[w["config"]]) as f:
+            cfg = json.load(f)
+        yield w["config"], w["name"], cfg["serving"]["lanes"], cfg["vocab_size"]
+
+
+def test_the_rule_over_the_benchmarks_cells():
+    """Every cell the benchmark had before PR 43 is ONE group (its step
+    programs trace as they did); jamba2-3b's 256 lanes are four groups of
+    64, LFM2's own shape."""
+    cells = list(_cells())
+    assert len(cells) >= 7
+    for config, cell, lanes, vocab in cells:
+        group = sampler_group(lanes, vocab)
+        assert lanes % group == 0 and 8 * group * vocab <= engine_mod.SAMPLER_GROUP_BYTES
+        if config == "jamba2-3b":
+            assert (lanes, vocab, group) == (256, 65536, 64), cell
+        else:
+            assert group == lanes, cell
+
+
+@pytest.mark.parametrize("rows,vocab,group", [
+    (1, 152064, 1), (256, 65536, 64), (512, 65536, 64), (96, 65536, 48),
+    (7, 1 << 20, 1),      # a prime count: one row at a time
+    (4, 1 << 23, 1),      # not even one row fits: one row at a time all the same
+])
+def test_the_rule_takes_the_largest_divisor_that_fits(rows, vocab, group):
+    assert sampler_group(rows, vocab) == group
