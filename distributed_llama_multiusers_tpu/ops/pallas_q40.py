@@ -32,8 +32,16 @@ for very wide matmuls), so each DMA fetches one contiguous multi-hundred-KB
 slab instead of the 512-BYTE strided rows of the old (chunk, 512) blocks —
 which measured at 47 GB/s of the chip's 819 GB/s on pure reads. Dequant
 happens in 512-lane sub-tiles INSIDE the kernel to bound VMEM transients.
-Grid: (m tiles, d_out wide-tiles, d_in chunks); the d_in axis accumulates
-into an f32 VMEM scratch.
+Grid: (m blocks, d_out wide-tiles, d_in chunks); the d_in axis accumulates
+into an f32 VMEM scratch. The m axis is outermost and the weight blocks'
+index maps ignore it, so every m block fetches and dequantises the whole
+plane again: the block of rows is therefore the CALL's rows, up to
+M_BLOCK_MAX = 1024 (``_row_plan``, PR 45: the x block ``[m_block, 2 * rows]``,
+the accumulator and the output block ``[m_block, w_tile]``), and a prefill
+chunk of any bucket pays the VPU's pass over the weights once, where 256-row
+blocks paid it four times at 1024 rows. A row's result does not depend on
+the rows that share its block. Calls of 256 rows or fewer have the grid,
+the blocks and the VMEM ceiling they always had.
 
 On TPU the dot runs in bf16 by default: BOTH the dequantized weight planes
 and the x operand are cast to bf16 (``w_dtype`` is the dot's compute
@@ -124,10 +132,13 @@ BLOCKDOT_MAX_M = 32  # above this, the post-scale FMA outweighs the savings
 # `shared_consumes` pin that one Q80Acts build feeds every matmul sharing
 # its input (llama_forward: wq/wk/wv = 1 build, w1/w3 = 1 build per step),
 # `impl_traces` holding still across repeated calls is the no-recompile
-# signal tests assert across the BLOCKDOT_MAX_M boundary, and
+# signal tests assert across the BLOCKDOT_MAX_M boundary,
 # `natural_x_consumes` is the engagement witness of the slab chains'
 # operand (PR 42): every kernel body traced in a slab chain was handed x in
-# its own column order and dtype; there is no other form to fall back to.
+# its own column order and dtype; there is no other form to fall back to,
+# and `weight_passes_max` is the witness that a slab meets all of a call's
+# rows (PR 45): `InferenceEngine.path_facts()` says it at start-up, so a
+# run whose 1024-row calls still pay four passes is not silent.
 TRACE_STATS = {
     "acts_builds": 0,      # make_q80_acts executions (any caller)
     "shared_builds": 0,    # ... with shared=True (the models/llama.py hoist)
@@ -135,6 +146,9 @@ TRACE_STATS = {
     "stacked_consumes": 0,  # ... that read their layer's tiles out of a stack
     "natural_x_consumes": 0,  # kernel-body traces handed x itself (slab chains)
     "impl_traces": 0,      # kernel-body traces (one per compiled family)
+    # the most passes over its weight plane any traced kernel call makes
+    # (m_pad // m_block: 1 for every call of up to M_BLOCK_MAX rows)
+    "weight_passes_max": 0,
 }
 
 
@@ -142,14 +156,26 @@ def reset_trace_stats() -> None:
     for k in TRACE_STATS:
         TRACE_STATS[k] = 0
 
-M_TILE = 256
+M_TILE = 256  # rows a call is padded to whole multiples of above this
+# Rows one weight slab meets before the kernel moves to the next (PR 45): a
+# slab is fetched and dequantised ONCE for a block of rows, so a call of up
+# to this many rows (the widest prefill bucket) makes one pass over the
+# plane; a longer call is cut into equal blocks and pays a pass a block.
+M_BLOCK_MAX = 1024
+MIN_W_TILE = 1024  # a wide tile narrowed for such a block keeps kilobyte rows
 ROW_ALIGN = 8  # x rows padded to whole sublane tiles: 8 rows of 4-byte words
 # Mosaic's default scoped-VMEM limit (16 MiB) refuses the prefill-shaped
-# plans: a 256-row m tile against an 8192-wide slab needs 18.5 MiB (f32
-# accumulator + double-buffered output block), i.e. every prefill bucket
-# >= 256 against w1/w3/wcls. The v5e has 128 MiB of VMEM; the limit is a
-# ceiling, not a reservation, so decode-shaped calls are unaffected.
+# plans: a 256-row block against an 8192-wide slab needs 18.5 MiB (f32
+# accumulator + double-buffered output block). The limit is a ceiling, not
+# a reservation (the v5e has 128 MiB of VMEM). Every call whose pipelined
+# blocks (``_block_bytes``) leave VMEM_HEADROOM under this one asks for
+# exactly it: every call of 256 rows or fewer, so their compiler parameters
+# are what they were. A call whose blocks need more (a 1024-row block
+# against a 7168-wide tile: 59 MiB) asks for its blocks plus the headroom
+# (``_vmem_limit``); the blocks themselves never pass this limit
+# (``_row_plan``), so no call asks for more than 80 MiB.
 VMEM_LIMIT_BYTES = 64 << 20
+VMEM_HEADROOM = 16 << 20  # the body's transients beside the pipelined blocks
 
 
 def _f16_bits_to_f32(h: jnp.ndarray) -> jnp.ndarray:
@@ -305,7 +331,7 @@ def _block_sums(x):
 
 def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
                      w_dtype, sub_tiles, n_k, mode):
-    """One (m tile, d_out wide-tile, d_in chunk) step over a contiguous
+    """One (m block, d_out wide-tile, d_in chunk) step over a contiguous
     weight slab. ``x`` arrives as it is: this chunk's ``2 * rows`` columns in
     their own order and their own dtype.
 
@@ -506,13 +532,67 @@ def _resolve_w_dtype(w_dtype, interpret: bool):
 def _m_geometry(m: int, dtype) -> tuple[int, int]:
     """(m_pad, m_tile): x rows padded to whole sublane tiles of ``dtype``
     (8 rows of f32, 16 of bf16: a narrower type packs two rows a sublane),
-    tiled at M_TILE."""
+    and above M_TILE to whole tiles of M_TILE. The block of rows a weight
+    slab meets is whole tiles of ``m_tile`` (``_row_plan``)."""
     align = ROW_ALIGN * max(1, 4 // jnp.dtype(dtype).itemsize)
     m_pad = max(align, ((m + align - 1) // align) * align)
     m_tile = min(M_TILE, m_pad)
     if m_pad % m_tile != 0:
         m_pad = ((m_pad + m_tile - 1) // m_tile) * m_tile
     return m_pad, m_tile
+
+
+def _block_bytes(m_block: int, w_tile: int, rows: int, n_k: int,
+                 x_itemsize: int) -> int:
+    """VMEM the pipeline holds for one grid step of the slab kernel, from
+    shapes alone: the f32 accumulator (none when the reduction is one
+    chunk), and the double-buffered output, x, nibble and scale blocks."""
+    acc = m_block * w_tile * 4 if n_k > 1 else 0
+    out = m_block * w_tile * x_itemsize
+    x = m_block * 2 * rows * x_itemsize
+    weights = rows * w_tile + (rows // 16) * w_tile * 2
+    return acc + 2 * (out + x + weights)
+
+
+def _row_plan(m_pad: int, w_tile: int, rows: int, n_k: int,
+              x_itemsize: int) -> tuple[int, int]:
+    """(m_block, w_tile): the rows of x one fetched and dequantised weight
+    slab meets, and the wide tile they meet it at.
+
+    A call of up to M_TILE rows: all of them against the planned tile, the
+    grid it always had. Above that the most whole M_TILE tiles, up to
+    M_BLOCK_MAX rows, that divide ``m_pad`` (so the padding never grows: 300
+    rows are 512 as before, in one block; 1300 are 1536 in two of 768).
+    Where the pipelined blocks of that many rows would not fit
+    VMEM_LIMIT_BYTES (1024 rows against an 8192-wide tile: 67 MiB, which
+    the chip takes and runs SLOWER than four 256-row blocks, PERF.md
+    section 6, PR 45), the wide tile gives way first: the largest
+    128-multiple divisor of it, MIN_W_TILE lanes at least, that fits (8192
+    -> 4096). The k chunk's rows stay as planned, so every element sums the
+    same products in the same order at any tile. Only where no such divisor
+    exists does the block of rows shrink."""
+    if m_pad <= M_TILE:
+        return m_pad, w_tile
+    tiles = m_pad // M_TILE
+    widths = [w_tile] + [w for w in range(w_tile - 128, MIN_W_TILE - 1, -128)
+                         if w_tile % w == 0]
+    for n in range(min(tiles, M_BLOCK_MAX // M_TILE), 1, -1):
+        if tiles % n:
+            continue
+        for w in widths:
+            if _block_bytes(n * M_TILE, w, rows, n_k,
+                            x_itemsize) <= VMEM_LIMIT_BYTES:
+                return n * M_TILE, w
+    return M_TILE, w_tile
+
+
+def _vmem_limit(block_bytes: int) -> int:
+    """The scoped-VMEM ceiling a call asks for: VMEM_LIMIT_BYTES wherever
+    the blocks leave VMEM_HEADROOM under it (every call of M_TILE rows or
+    fewer: the compiler parameters they always had), else the blocks plus
+    the headroom: 80 MiB at most, since ``_row_plan`` keeps the blocks
+    under VMEM_LIMIT_BYTES."""
+    return max(VMEM_LIMIT_BYTES, block_bytes + VMEM_HEADROOM)
 
 
 class Q80Acts(NamedTuple):
@@ -702,15 +782,21 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
             f"shape ({d_in}, {d_out}) unsupported; use q40_matmul_xla"
         )
     w_tile, rows = plan
-    sub = _sub_tiles(w_tile)
     n_k = half // rows
 
     lead = acts.x.shape[:-1]
     m = acts.m
     m_pad = acts.x_rows.shape[0]
-    m_tile = min(M_TILE, m_pad)
+    x_itemsize = acts.x.dtype.itemsize
+    # the m axis is the grid's outermost and the weight blocks ignore it:
+    # a slab is fetched and dequantised once for every m block, so the
+    # block is the call's rows wherever they fit (one pass over the plane)
+    m_block, w_tile = _row_plan(m_pad, w_tile, rows, n_k, x_itemsize)
+    sub = _sub_tiles(w_tile)
+    TRACE_STATS["weight_passes_max"] = max(
+        TRACE_STATS["weight_passes_max"], m_pad // m_block)
 
-    grid = (m_pad // m_tile, d_out // w_tile, n_k)
+    grid = (m_pad // m_block, d_out // w_tile, n_k)
 
     # Mosaic has no f16 type, so the kernel takes the scales' bit patterns.
     # For XLA:TPU f16 -> s16 is not a relabelling but a pass over the data:
@@ -730,15 +816,15 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         kernel = partial(
             _q40_blockdot_kernel if blockdot else _q40_i8blockdot_kernel,
             sub_tiles=sub, n_k=n_k)
-        x_t_spec = pl.BlockSpec((rows, m_tile), lambda i, j, k, l: (k, i))
+        x_t_spec = pl.BlockSpec((rows, m_block), lambda i, j, k, l: (k, i))
         x_specs = [x_t_spec, x_t_spec, pl.BlockSpec(
-            ((rows // 16) * (1 if blockdot else 2), m_tile),
+            ((rows // 16) * (1 if blockdot else 2), m_block),
             lambda i, j, k, l: (k, i))]
     else:
         # x as it is: chunk k's 2 * rows columns, in its own dtype
         TRACE_STATS["natural_x_consumes"] += 1
         x_ops = (acts.x_rows,)
-        x_specs = [pl.BlockSpec((m_tile, 2 * rows), lambda i, j, k, l: (i, k))]
+        x_specs = [pl.BlockSpec((m_block, 2 * rows), lambda i, j, k, l: (i, k))]
         kernel = partial(_q40_slab_kernel, w_dtype=w_dtype, sub_tiles=sub,
                          n_k=n_k, mode=mode)
 
@@ -756,17 +842,18 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
                              lambda i, j, k, l: (l[0], k, j)),
                 pl.BlockSpec((rows // 16, w_tile), lambda i, j, k, l: (k, j)),
             ],
-            out_specs=pl.BlockSpec((m_tile, w_tile),
+            out_specs=pl.BlockSpec((m_block, w_tile),
                                    lambda i, j, k, l: (i, j)),
             scratch_shapes=[
-                pltpu.VMEM((m_tile, w_tile if n_k > 1 else SUB_TILE),
+                pltpu.VMEM((m_block, w_tile if n_k > 1 else SUB_TILE),
                            jnp.float32)
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, d_out), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+            vmem_limit_bytes=_vmem_limit(
+                _block_bytes(m_block, w_tile, rows, n_k, x_itemsize)),
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * m_pad * d_in * d_out,

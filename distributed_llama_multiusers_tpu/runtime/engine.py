@@ -1568,6 +1568,7 @@ class InferenceEngine:
         start-up (the ``runtime_device`` line, ``/stats``), so that a
         fallback is never silent."""
         from ..ops.linear import pallas_kernel_active
+        from ..ops.pallas_q40 import TRACE_STATS as q40_trace_stats
         from ..ops.pallas_q40_grouped import grouped_supports
 
         cfg = self.config
@@ -1588,7 +1589,11 @@ class InferenceEngine:
         else:
             experts = "xla_gathered_slabs"
         facts = {"attention_path": attention, "expert_path": experts,
-                 "sampler_groups": self.sampler_groups}
+                 "sampler_groups": self.sampler_groups,
+                 # the most passes over its weight plane any Q40 kernel call
+                 # traced so far makes (after warm-up: 1 where every prefill
+                 # bucket's rows share one dequantised slab; 0: none traced)
+                 "q40_weight_passes": q40_trace_stats["weight_passes_max"]}
         if cfg.sparse_attention:
             # how the chosen rows are read (models/deepseek.py: gathered,
             # at every width), and what an indexer's rows are not computed for

@@ -121,9 +121,9 @@ def test_default_mode_is_what_this_file_calls_default(monkeypatch):
     assert pq._env_dequant_default() == DEFAULT_MODE
 
 
-# m = 1: decode. m = 1024: the widest default prefill bucket — four 256-row
-# m tiles, the plan with the largest VMEM footprint (m = 128 is one smaller
-# tile of the same plan).
+# m = 1: decode. m = 1024: the widest default prefill bucket — since PR 45
+# one block of 1024 rows (four 256-row m tiles before), the plan with the
+# largest VMEM footprint (m = 128 is a smaller block of the same plan).
 # m = 16: a decode batch whose bf16 rows are one whole tile, handed over as
 # they are (PR 42).
 @pytest.mark.parametrize("m", [1, 16, 1024])
@@ -269,6 +269,96 @@ def test_stacked_weight_every_selectable_mode_compiles_for_v5e(
     hlo = _compile_stacked(v5e, mode, d_in, d_out, m)
     assert "tpu_custom_call" in hlo
     assert not (_is_slab_chain(mode, d_in, d_out, m) and _lane_splits(hlo))
+
+
+# PR 45: one block of rows a call. Every distinct (d_in, d_out) that the
+# benchmark's six configurations send through the slab kernel (the routed
+# experts' [L, E, ...] slabs go through ops/pallas_q40_grouped.py), and
+# whether the configuration holds it as a stack of layers (the kernel is
+# handed the stack and a layer index) or as one plane (the heads). The list
+# is held to the files by ``test_cell_shapes_are_what_the_config_files_give``.
+CELL_SHAPES = [
+    (1536, 2048, True), (1536, 8192, True), (1536, 24576, True),
+    (2048, 512, True), (2048, 576, True), (2048, 1536, True),
+    (2048, 2048, True), (2048, 6144, True), (2048, 7168, True),
+    (2048, 11776, True), (2048, 65536, False), (2048, 131072, False),
+    (2560, 128, True), (2560, 2560, True), (2560, 8192, True),
+    (2560, 10240, True), (2560, 65536, False), (3584, 512, True),
+    (3584, 3584, True), (3584, 18944, True), (3584, 152064, False),
+    (4096, 1024, True), (4096, 2048, True), (4096, 4096, True),
+    (4096, 14336, True), (4096, 32768, False), (5120, 192, True),
+    (5120, 2560, True), (6144, 2048, True), (7168, 128, True),
+    (7168, 576, True), (7168, 1536, True), (7168, 2048, True),
+    (7168, 16384, False), (7168, 18432, True), (8192, 2560, True),
+    (11776, 2048, True), (14336, 4096, True), (16384, 7168, True),
+    (18432, 7168, True), (18944, 3584, True),
+]
+
+
+def _config_file_shapes():
+    """{(d_in, d_out, stacked)} of every PackedQ40 leaf of rank 2 or 3 in the
+    parameter trees the benchmark's families build from the six files under
+    benchmarks/configs/, by shape only (nothing is generated)."""
+    import sys
+
+    import latent_toy
+
+    path = list(sys.path)
+    sys.path[:0] = [latent_toy.BENCH_DIR, latent_toy.ROOT]
+    try:
+        from harness import cells
+
+        bench = cells.load_benchmark()
+        found = set()
+        for name in sorted(os.listdir(os.path.join(latent_toy.BENCH_DIR, "configs"))):
+            cfg = cells.load_config_file(bench, name[:-len(".json")])
+            family = cells.load_family(cfg)
+            config = family.program_config(cfg)
+            tensors = jax.eval_shape(
+                lambda: family.device_weights(config, 0, jnp.bfloat16))
+            params = jax.eval_shape(
+                lambda t: family.assemble_params(config, t), tensors)
+            for w in jax.tree_util.tree_leaves(
+                    params, is_leaf=lambda n: isinstance(n, PackedQ40)):
+                if isinstance(w, PackedQ40) and w.packed.ndim in (2, 3):
+                    found.add((w.packed.shape[-2] * 2, w.packed.shape[-1],
+                               w.packed.ndim == 3))
+        return found
+    finally:
+        sys.path[:] = path
+
+
+def test_cell_shapes_are_what_the_config_files_give():
+    found = _config_file_shapes()
+    assert len(os.listdir(os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "configs"))) == 6
+    assert all(pq._plan_blocks(d_in, d_out) for d_in, d_out, _ in found)
+    assert found == set(CELL_SHAPES), found ^ set(CELL_SHAPES)
+    # the 8192-wide tiles among them: the heads of Mistral and Qwen (6912 x
+    # 22), Jamba's MLP
+    wide = {(d_in, d_out) for d_in, d_out, _ in found
+            if pq._plan_blocks(d_in, d_out)[0] == 8192}
+    assert {(4096, 32768), (2560, 8192), (2560, 65536)} <= wide
+
+
+@pytest.mark.parametrize("m", [512, 1024])
+@pytest.mark.parametrize("d_in,d_out,stacked", CELL_SHAPES)
+def test_one_row_block_compiles_for_v5e_at_every_cell_shape(
+        v5e, d_in, d_out, stacked, m):
+    """The default mode at the two prefill buckets above 256 rows, where the
+    block of rows is now the call's rows (PR 45): Mosaic takes the x block,
+    the f32 accumulator and the output block of ``m`` rows against every
+    wide tile the cells have, under the ceiling the plan asks for; one
+    kernel call, no lane of x split."""
+    w_tile, rows = pq._plan_blocks(d_in, d_out)
+    n_k = (d_in // 2) // rows
+    assert pq._row_plan(m, w_tile, rows, n_k, 2)[0] == m  # one pass
+    compile_ = _compile_stacked if stacked else _compile
+    hlo = compile_(v5e, DEFAULT_MODE, d_in, d_out, m)
+    assert hlo.count("tpu_custom_call") == 1
+    assert _lane_splits(hlo) == []
+    if stacked:
+        assert not _scales_stack_converted_whole(hlo, d_in, d_out)
 
 
 def _three_layer_decode_hlo(v5e, monkeypatch, lanes=16, n_heads=32, n_kv=8):

@@ -60,7 +60,8 @@ def test_f16_bit_conversion_exact():
         (4, 4096, 2048),
         # wide-tile grid (j > 1): d_out 16384 tiles as 2 x 8192
         (2, 512, 16384),
-        # multiple m tiles: m_pad 512 = 2 x 256 with full-extent checks on
+        # rows above M_TILE: m_pad 512 = 2 x 256 (one block of rows since
+        # PR 45, two m tiles before) with full-extent checks on
         # the bsum lane dim
         (300, 64, 256),
     ],
@@ -671,7 +672,7 @@ def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
 @pytest.mark.parametrize("m,d_in,d_out", [
     (4, 4096, 2048),   # n_k > 1: the k axis walks chunks of layer l's plane
     (2, 512, 16384),   # two wide tiles: the j axis
-    (300, 64, 256),    # two m tiles
+    (300, 64, 256),    # rows above M_TILE, padded to 512
 ])
 def test_stacked_weight_on_every_grid_axis(m, d_in, d_out):
     """The layer offset composes with each axis of the grid."""
@@ -798,7 +799,7 @@ NATURAL_SHAPES = [
     (64, 512, 16384),
     # one whole m tile; 43 blocks
     (256, 1376, 128),
-    # above M_TILE: two m tiles, and rows that need padding
+    # above M_TILE (one block of 512 rows), and rows that need padding
     (300, 64, 256),
     # rows that need padding under either dtype; 448 blocks (14336 / 32)
     (5, 14336, 128),
@@ -1073,3 +1074,163 @@ def test_the_witness_finds_the_split_the_kernel_took_before():
     assert SPLIT_RESHAPE.search(jax.jit(prep).lower(x).as_text())
     found = _arrays_under(jax.make_jaxpr(prep)(x).jaxpr, WITNESS_SCOPES)
     assert (16, 8, 16) in _lane_splits(found), found
+
+
+# ---------------------------------------------------------------------------
+# PR 45: a weight slab is fetched and dequantised once for a BLOCK of rows,
+# and the block is the call's rows up to M_BLOCK_MAX. A row's result does not
+# depend on which other rows share its block: the same chain, the same dots
+# over the same k chunks in the same order.
+# ---------------------------------------------------------------------------
+
+ROW_BLOCK_PLANS = {
+    # (d_in, d_out): one slab (no k axis), several k chunks through the f32
+    # accumulator (1024 x 1152 packed bytes: two chunks of 512 rows, sub
+    # tiles 512 + 512 + 128), two wide tiles of 8192
+    "one_slab": (64, 256),
+    "k_chunks": (2048, 1152),
+    "two_wide_tiles": (64, 16384),
+}
+
+
+@pytest.mark.parametrize("weight", ["plane", "stack"])
+@pytest.mark.parametrize("plan", list(ROW_BLOCK_PLANS))
+@pytest.mark.parametrize("m", [300, 512, 1024, 1300])
+def test_row_blocks_do_not_change_a_rows_result(m, plan, weight):
+    """The call's output equals, to the bit in interpret-mode f32, the
+    outputs of the same rows sent 256 at a time (calls of M_TILE rows or
+    fewer: the grid and the blocks they always had), and the XLA dequant to
+    this file's tolerance."""
+    d_in, d_out = ROW_BLOCK_PLANS[plan]
+    w_tile, rows = pq._plan_blocks(d_in, d_out)
+    assert ((d_in // 2) // rows, d_out // w_tile) == {
+        "one_slab": (1, 1), "k_chunks": (2, 1), "two_wide_tiles": (1, 2)}[plan]
+    rng = np.random.default_rng(m + d_in + d_out)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+    if weight == "stack":
+        w = _stack(rng, d_out, d_in, n=2)
+        kw, plane = dict(interpret=True, layer=1), _plane(w, 1)
+    else:
+        w = plane = _pack(rng, d_out, d_in)
+        kw = dict(interpret=True)
+    got = np.asarray(q40_matmul_pallas(x, w, **kw))
+    # the plan this call traced under (the trace itself may be another
+    # test's, so the counter is not read here): one block up to 1024 rows
+    m_pad, _ = pq._m_geometry(m, x.dtype)
+    m_block, _ = pq._row_plan(m_pad, w_tile, rows, (d_in // 2) // rows, 4)
+    assert m_pad // m_block == (1 if m <= 1024 else 2), (m_pad, m_block)
+    # whole 256-row tiles, as the parent's grid cut the padded rows (XLA:CPU
+    # sums a dot of 44 rows in another order than one of 256: the tail is
+    # padded here as the kernel pads it)
+    x_tiles = jnp.pad(x, ((0, -m % pq.M_TILE), (0, 0)))
+    by_tile = np.concatenate([
+        np.asarray(q40_matmul_pallas(x_tiles[r:r + pq.M_TILE], w, **kw))
+        for r in range(0, m, pq.M_TILE)])[:m]
+    np.testing.assert_array_equal(got, by_tile)
+    np.testing.assert_allclose(got, np.asarray(q40_matmul_xla(x, plane)),
+                               atol=2e-4, rtol=2e-4)
+
+
+def _parent_m_pad(m, itemsize):
+    """x rows as PR 44 padded them: whole sublane tiles, whole 256-row tiles
+    above 256."""
+    align = 8 * max(1, 4 // itemsize)
+    m_pad = max(align, -(-m // align) * align)
+    return m_pad if m_pad <= 256 else -(-m_pad // 256) * 256
+
+
+# (d_in, d_out) of the benchmark's dense cells and of every 8192-wide tile in
+# its six configurations (the heads, Jamba's MLP, DeepSeek's wide projections)
+PLAN_SHAPES = [(4096, 14336), (14336, 4096), (4096, 1024), (3584, 18944),
+               (18944, 3584), (4096, 32768), (3584, 152064), (2560, 8192),
+               (2560, 65536), (1536, 24576), (7168, 128)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("d_in,d_out", PLAN_SHAPES)
+def test_plan_from_shapes_one_pass_up_to_1024_rows(d_in, d_out, dtype):
+    """From shapes alone: the padding is what it was for every m; every call
+    of up to 256 rows keeps the grid, the wide tile and the VMEM ceiling it
+    had; every call of up to 1024 rows is ONE block of rows, one pass over
+    the plane, its pipelined blocks inside the 64 MiB the kernel always
+    asked for (an 8192-wide tile narrows to 4096 at 1024 rows; f32 rows
+    narrow a 7168-wide one too); longer calls are cut into equal blocks of
+    whole 256-row tiles."""
+    itemsize = jnp.dtype(dtype).itemsize
+    w_plan, rows = pq._plan_blocks(d_in, d_out)
+    n_k = (d_in // 2) // rows
+    for m in (1, 7, 16, 32, 200, 256, 257, 300, 512, 777, 1000, 1024, 1025,
+              1300, 2048, 2560, 4096):
+        m_pad, _ = pq._m_geometry(m, dtype)
+        assert m_pad == _parent_m_pad(m, itemsize), m
+        m_block, w_tile = pq._row_plan(m_pad, w_plan, rows, n_k, itemsize)
+        need = pq._block_bytes(m_block, w_tile, rows, n_k, itemsize)
+        assert m_pad % m_block == 0 and m_block <= pq.M_BLOCK_MAX, (m, m_block)
+        assert w_plan % w_tile == 0 and w_tile % 128 == 0
+        assert w_tile == w_plan or w_tile >= pq.MIN_W_TILE
+        limit = pq._vmem_limit(need)
+        assert pq.VMEM_LIMIT_BYTES <= limit <= 80 << 20 < 128 << 20
+        if m <= 256:
+            # the parent's m tile, wide tile and compiler parameters
+            assert (m_block, w_tile) == (min(256, m_pad), w_plan)
+            assert limit == pq.VMEM_LIMIT_BYTES
+        else:
+            assert need <= pq.VMEM_LIMIT_BYTES, (m, m_block, w_tile)
+            assert m_block % 256 == 0
+            if m <= 1024:
+                assert m_block == m_pad, (m, m_block)  # one pass
+    at_1024 = pq._row_plan(1024, w_plan, rows, n_k, itemsize)
+    if dtype == jnp.bfloat16:
+        # what the cells send: only the 8192-wide tile gives way, by halving
+        assert at_1024 == (1024, 4096 if w_plan == 8192 else w_plan)
+        # 1300 rows: 1536 padded as before, two blocks of 768 (not 6 of 256)
+        assert pq._row_plan(1536, w_plan, rows, n_k, 2)[0] == 768
+
+
+def test_a_narrowed_wide_tile_does_not_change_a_result():
+    """Jamba's MLP shape at a depth CPU interpret mode can afford: the plan
+    is one 8192-wide tile, which 1024 rows meet as two tiles of 4096 with
+    the k chunks as planned: every element sums the same products in the
+    same order, so the call equals the same rows sent 256 at a time
+    (against the 8192-wide tile) to the bit."""
+    d_in, d_out, m = 512, 8192, 1024
+    w_plan, rows = pq._plan_blocks(d_in, d_out)
+    n_k = (d_in // 2) // rows
+    assert (w_plan, n_k) == (8192, 2)
+    assert pq._row_plan(m, w_plan, rows, n_k, 4) == (1024, 4096)
+    assert pq._row_plan(256, w_plan, rows, n_k, 4) == (256, 8192)
+    rng = np.random.default_rng(45)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+    w = _pack(rng, d_out, d_in)
+    got = np.asarray(q40_matmul_pallas(x, w, interpret=True))
+    by_tile = np.concatenate([
+        np.asarray(q40_matmul_pallas(x[r:r + 256], w, interpret=True))
+        for r in range(0, m, 256)])
+    np.testing.assert_array_equal(got, by_tile)
+    np.testing.assert_allclose(got, np.asarray(q40_matmul_xla(x, w)),
+                               atol=2e-4, rtol=2e-4)
+
+
+def test_weight_passes_witness_in_trace_stats_and_path_facts(witness_engine):
+    """After tracing a 1024-row and a 16-row call the witness reads 1 (the
+    parent's plan made 4 passes at 1024 rows: ``m_pad // 256``), and the
+    engine's start-up facts carry it; a call past M_BLOCK_MAX says so."""
+    w = PackedQ40(packed=jax.ShapeDtypeStruct((2048, 14336), jnp.uint8),
+                  scales=jax.ShapeDtypeStruct((128, 14336), jnp.float16))
+
+    def trace(m):
+        x = jax.ShapeDtypeStruct((m, 4096), jnp.bfloat16)
+        jax.eval_shape(lambda x, w: pq._q40_matmul_core(
+            make_q80_acts(x), w, True, jnp.bfloat16, "v4"), x, w)
+
+    reset_trace_stats()
+    assert witness_engine.path_facts()["q40_weight_passes"] == 0  # none traced
+    trace(1024)
+    trace(16)
+    assert TRACE_STATS["weight_passes_max"] == 1, TRACE_STATS
+    assert witness_engine.path_facts()["q40_weight_passes"] == 1
+    trace(4096)
+    assert TRACE_STATS["weight_passes_max"] == 4
+    assert witness_engine.path_facts()["q40_weight_passes"] == 4
+    reset_trace_stats()
