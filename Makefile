@@ -156,16 +156,16 @@ leakcheck:
 # interpret-mode parity pins for the shipping dequant path, standalone on
 # jax CPU — no TPU needed. Two layers: the kernel-lab oracle check (every
 # variant vs numpy dequant, single-chunk plane) and the pytest pins —
-# the i8blockdot (d_in, d_out, m) parity grid, shared-Q80Acts vs raw-x
-# parity per mode, the BLOCKDOT_MAX_M routing boundary, and the
-# selection-table semantics behind DLLAMA_DEQUANT=auto. Run it before
-# shipping ops/pallas_q40.py or ops/dequant_select.py changes; the same
+# the i8blockdot (d_in, d_out, m) parity grid, consumers of one input
+# against their standalone calls per mode, the BLOCKDOT_MAX_M routing
+# boundary, and the block geometry and the modes `--dequant` offers. Run it
+# before shipping ops/pallas_q40.py changes; the same
 # pytest pins ride tier-1 via `verify` (the >=256-token decode-stream
 # token-identity pin is slow-marked — run it explicitly when touching
 # kernel numerics: pytest tests/test_pallas_q40.py -m slow).
 kernelcheck:
 	env JAX_PLATFORMS=cpu python scripts/kernel_lab3.py --check
-	env JAX_PLATFORMS=cpu python -m pytest tests/test_pallas_q40.py tests/test_dequant_select.py -q -m 'not slow'
+	env JAX_PLATFORMS=cpu python -m pytest tests/test_pallas_q40.py tests/test_q40_geometry.py -q -m 'not slow'
 
 # Install the git pre-commit hook running the diff-proportional lint
 # (`dlint --changed`, docs/LINT.md) so findings surface at commit time

@@ -166,13 +166,12 @@ def phase_prepare(args) -> int:
 
 def phase_kernels(args) -> int:
     """q40_matmul_pallas against q40_matmul_xla ON THE DEVICE for the default
-    mode and every selectable mode, inside the bound tests/test_pallas_q40.py
+    mode and every other mode, inside the bound tests/test_pallas_q40.py
     uses (max error over max |reference|: 2e-2; 5e-2 where the activations
     are Q80-quantized). Under --rehearse: interpret mode, tiny shapes."""
     import jax
     import jax.numpy as jnp
 
-    from distributed_llama_multiusers_tpu.ops import dequant_select
     from distributed_llama_multiusers_tpu.ops import pallas_q40 as pq
     from distributed_llama_multiusers_tpu.quants.packed import (
         PackedQ40,
@@ -186,7 +185,7 @@ def phase_kernels(args) -> int:
     interpret = dev.platform != "tpu"
     shapes = SHAPES_TINY if args.rehearse else SHAPES_1B
     modes = [pq.DEQUANT_MODE] + [
-        m for m in pq.SELECTABLE_MODES if m != pq.DEQUANT_MODE
+        m for m in pq.DEQUANT_MODES if m != pq.DEQUANT_MODE
     ]
     worst: dict[str, float] = {}
     bad = 0
@@ -207,10 +206,7 @@ def phase_kernels(args) -> int:
                 pq.set_dequant_mode(mode)
                 got = jax.device_get(pq.q40_matmul_pallas(
                     x, w, interpret=interpret, w_dtype=jnp.bfloat16))
-                resolved = mode
-                if mode == "auto":
-                    resolved = dequant_select.resolve_mode(d_in, d_out, m)
-                q80_acts = resolved == "i8blockdot" and m <= pq.BLOCKDOT_MAX_M
+                q80_acts = mode == "i8blockdot" and m <= pq.BLOCKDOT_MAX_M
                 bound = 5e-2 if q80_acts else 2e-2
                 rel = float(abs(got - ref).max()) / scale
                 worst[mode] = max(worst.get(mode, 0.0), rel)

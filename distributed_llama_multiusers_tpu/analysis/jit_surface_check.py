@@ -45,12 +45,6 @@ from .jitmodel import extract_jit_model
 from .lockgraph import walk_excluding_nested_defs
 
 ENGINE_SCOPE = ("runtime/engine.py",)
-# jit-stability additionally covers the dequant selection table: its rules
-# and resolution caches are read at trace time, so a device array stored
-# into table state would become a captured constant with a changeable aval
-# (the same recompile class as an engine leaf swap). Warmup coverage stays
-# engine-only — the table has no compiled families of its own.
-JIT_STABILITY_SCOPE = ENGINE_SCOPE + ("ops/dequant_select.py",)
 # donation sites exist beyond the engine (the trainer's fused step); the
 # jit surface the issue scopes is engine + model + ops + grammar slab
 DONATION_SCOPE = (
@@ -96,7 +90,7 @@ class JitStabilityChecker(Checker):
     )
 
     def check(self, sf: SourceFile, project: Project):
-        if not sf.endswith(*JIT_STABILITY_SCOPE):
+        if not sf.endswith(*ENGINE_SCOPE):
             return
         for node in ast.walk(sf.tree):
             if not isinstance(node, ast.ClassDef):

@@ -205,21 +205,17 @@ def build_parser(prog: str, api: bool = False) -> argparse.ArgumentParser:
                         "on (DLLAMA_RING_SYNC env equivalent); 'off' "
                         "restores the plain psum sync bit-for-bit "
                         "(escape hatch)")
-    # mirrors ops/pallas_q40.SELECTABLE_MODES; argparse must stay importable
+    # mirrors ops/pallas_q40.DEQUANT_MODES; argparse must stay importable
     # without jax, so the list is spelled out and the pairing is pinned by
-    # tests/test_dequant_select.py
+    # tests/test_pallas_q40.py
     p.add_argument("--dequant", default=None,
-                   choices=["auto", "v4", "bf16chain", "repeat", "u8chain",
+                   choices=["v4", "bf16chain", "repeat", "u8chain",
                             "blockdot", "i8blockdot"],
                    help="Q40 dequant arithmetic variant for the Pallas "
                         "kernel's bf16 dot path (DLLAMA_DEQUANT env "
-                        "equivalent; default v4). 'auto' resolves the mode "
-                        "per (d_in, d_out, m-class) matmul site from the "
-                        "persisted selection table "
-                        "(ops/dequant_table.json, written only by "
-                        "scripts/kernel_lab3.py --adopt) BEFORE warmup, so every program still "
-                        "compiles exactly once; interpret/CPU always runs "
-                        "the exact-f32 v4 chain")
+                        "equivalent; default v4), set BEFORE warmup, so "
+                        "every program compiles exactly once; "
+                        "interpret/CPU always runs the exact-f32 v4 chain")
     p.add_argument("--step-deadline", type=float, default=None,
                    help="serving: failure-containment watchdog — if a "
                         "dispatched engine step makes no progress for "

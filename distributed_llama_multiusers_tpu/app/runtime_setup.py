@@ -177,16 +177,7 @@ def load_stack(args, n_lanes: int | None = None):
 
     if getattr(args, "dequant", None) is not None:
         _pq.set_dequant_mode(args.dequant)
-    if _pq.DEQUANT_MODE == "auto":
-        from ..ops.dequant_select import freeze_for_serving
-
-        prov = freeze_for_serving() or {}
-        log("🎛️", f"Dequant mode: auto — per-site selection from "
-                  f"{prov.get('path', 'ops/dequant_table.json')} "
-                  f"(v{prov.get('version')}, {prov.get('rows')} rows, "
-                  f"updated {prov.get('updated')}); resolved at warmup "
-                  "trace time")
-    elif _pq.DEQUANT_MODE != "v4":
+    if _pq.DEQUANT_MODE != "v4":
         log("🎛️", f"Dequant mode: {_pq.DEQUANT_MODE} "
                   "(--dequant / DLLAMA_DEQUANT)")
 

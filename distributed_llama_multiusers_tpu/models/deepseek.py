@@ -106,7 +106,6 @@ from ..ops.linear import (
     pallas_kernel_active,
     pallas_w_dtype_kw,
     reads_q40_stack,
-    shared_q80_acts,
 )
 from ..ops.norm import rms_norm
 from ..ops.pallas_q40_grouped import (
@@ -505,27 +504,23 @@ def sparse_attention(cfg, q_nope, q_pe, qi, w, wuk, wuv, c_all, r_all, ik_all, l
 
 
 class FfnOps(NamedTuple):
-    """What a block's FFNs share across its layers: the activation, the Q80
-    emulation's cast (identity unless asked for) and the shared operand build
-    of two matmuls on one input (identity unless the weights are Q40)."""
+    """What a block's FFNs share across its layers: the activation and the
+    Q80 emulation's cast (identity unless asked for)."""
 
     act_fn: object
     maybe_qdq: object
-    share_q80: object
 
 
-def ffn_ops(cfg: LlamaConfig, emulate_q80_activations: bool, quantized: bool) -> FfnOps:
+def ffn_ops(cfg: LlamaConfig, emulate_q80_activations: bool) -> FfnOps:
     return FfnOps(
         act_fn=silu if cfg.hidden_act == HiddenAct.SILU else gelu,
         maybe_qdq=_qdq_q80 if emulate_q80_activations else (lambda y: y),
-        share_q80=shared_q80_acts if quantized else (lambda y: y),
     )
 
 
 def gated_ffn(ops: FfnOps, yq, w1, w2, w3):
     """``W2 (act(W1 y) * W3 y)``: a dense layer's FFN, or the shared experts."""
-    yqs = ops.share_q80(yq)  # one operand build for the gate and the up matmul
-    return matmul(ops.maybe_qdq(ops.act_fn(matmul(yqs, w1)) * matmul(yqs, w3)), w2)
+    return matmul(ops.maybe_qdq(ops.act_fn(matmul(yq, w1)) * matmul(yq, w3)), w2)
 
 
 def dense_ffn(cfg: LlamaConfig, ops: FfnOps, x, dp: "DenseFfnParams"):
@@ -620,8 +615,8 @@ def deepseek_forward_counted(
     n_heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     eps = cfg.norm_epsilon
-    ops = ffn_ops(cfg, emulate_q80_activations, isinstance(params.attn.wq, PackedQ40))
-    maybe_qdq, share_q80 = ops.maybe_qdq, ops.share_q80
+    ops = ffn_ops(cfg, emulate_q80_activations)
+    maybe_qdq = ops.maybe_qdq
     scale = cfg.softmax_scale_factor / float(nope + rope) ** 0.5
     sparse = cfg.sparse_attention
     if sparse != isinstance(cache, IndexedLatentCache):
@@ -645,11 +640,9 @@ def deepseek_forward_counted(
         c_all, r_all = leaves[:2]
         with jax.named_scope(SCOPE_QKV):
             y = rms_norm(x, ap.rms_att, eps)
-            yq = share_q80(maybe_qdq(y))  # one operand build for the projections of n
+            yq = maybe_qdq(y)
             if ap.wqa is not None:  # the query latent
                 cq = maybe_qdq(rms_norm(matmul(yq, ap.wqa), ap.rms_q, eps))
-                if sparse:
-                    cq = share_q80(cq)  # wq and the indexer's queries
             else:
                 cq = yq
             q = matmul(cq, ap.wq).reshape(b, t, n_heads, nope + rope)

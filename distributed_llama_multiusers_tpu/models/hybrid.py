@@ -329,10 +329,8 @@ def hybrid_forward_counted(
     b, t = tokens.shape
     eps, kinds = cfg.norm_epsilon, cfg.layer_kinds
     k_taps = cfg.conv_kernel
-    first = next(m for m in (params.conv, params.ssm, params.attn) if m is not None)
-    quantized = isinstance(first[0], PackedQ40)
-    ops = ffn_ops(cfg, emulate_q80_activations, quantized)
-    maybe_qdq, share_q80 = ops.maybe_qdq, ops.share_q80
+    ops = ffn_ops(cfg, emulate_q80_activations)
+    maybe_qdq = ops.maybe_qdq
 
     with jax.named_scope(SCOPE_EMBED):
         x = params.embedding[tokens]
@@ -359,7 +357,7 @@ def hybrid_forward_counted(
         ap = GqaParams(*(_pick(leaf, ai) for leaf in params.attn))
         with jax.named_scope(SCOPE_QKV):
             y = rms_norm(x, ap.rms, eps)
-            yq = share_q80(maybe_qdq(y))  # one operand build for wq/wk/wv
+            yq = maybe_qdq(y)
             q, k, v = gqa_project(
                 cfg, yq, ap.wq, ap.wk, ap.wv, positions, params.rope_cos, params.rope_sin,
                 norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None,

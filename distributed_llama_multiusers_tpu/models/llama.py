@@ -85,7 +85,6 @@ from ..ops.linear import (
     pallas_interpret,
     pallas_kernel_active,
     reads_q40_stack,
-    shared_q80_acts,
 )
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope
@@ -616,19 +615,6 @@ def llama_forward(
             return maybe_qdq(tp_reduced_matmul(y, w, mesh))
         return maybe_qdq(matmul(y, w))
 
-    # Shared Q80 activation operands (ops/pallas_q40.Q80Acts): wq/wk/wv
-    # consume one normed x and w1/w3 another, so each site builds its
-    # operand bundle ONCE instead of once per matmul (one build feeds
-    # three dots at the attention site, two at the FFN site). Since the
-    # kernel takes x as it is the bundle is x with its rows padded, no
-    # more. Single-chip only: under a mesh the matmuls take raw
-    # activations. shared_q80_acts itself no-ops when the Pallas kernel
-    # is off, so every other path sees the plain activation.
-    share = mesh is None and isinstance(
-        getattr(params.layers, "wq", None), PackedQ40
-    )
-    share_q80 = shared_q80_acts if share else (lambda y: y)
-
     # device scopes (telemetry/names.py): every part of the step carries a
     # fixed ``dl.*`` name in its HLO metadata, which is what a device trace
     # is reduced by — no cost on the device, none in the compile-cache key
@@ -718,7 +704,7 @@ def llama_forward(
 
         with jax.named_scope(SCOPE_QKV):
             y = rms_norm(x, lp.rms_att, eps)
-            yq = share_q80(maybe_qdq(y))  # one operand build for wq/wk/wv
+            yq = maybe_qdq(y)
             q, k, v = gqa_project(
                 h_cfg, yq, lp.wq, lp.wk, lp.wv, positions, params.rope_cos,
                 params.rope_sin, biases=(lp.bq, lp.bk, lp.bv), project=sliced_matmul,
@@ -768,9 +754,8 @@ def llama_forward(
                 )
                 x = x + maybe_qdq(d)
             else:
-                yqs = share_q80(yq)  # one operand build for w1/w3
-                g = act_fn(sliced_matmul(yqs, lp.w1))
-                u = sliced_matmul(yqs, lp.w3)
+                g = act_fn(sliced_matmul(yq, lp.w1))
+                u = sliced_matmul(yq, lp.w3)
                 x = x + synced_matmul(maybe_qdq(g * u), lp.w2)
 
         return (x, k_all, v_all), None

@@ -81,23 +81,6 @@ def pallas_interpret() -> bool:
     return _pallas_interpret
 
 
-def shared_q80_acts(x: jnp.ndarray):
-    """Build the shared operand bundle for ``x`` (x itself, its leading axes
-    merged and its rows padded: the kernel takes the columns as they are;
-    llama_forward builds it once per distinct input and feeds every matmul
-    sharing it),
-    or return x unchanged when sharing cannot engage (kernel off, or a d_in
-    that does not cover whole quant blocks). Callers pass the result to
-    ``matmul`` exactly like a raw activation."""
-    if not pallas_kernel_active():
-        return x
-    if x.shape[-1] % 32 != 0:
-        return x
-    from .pallas_q40 import make_q80_acts
-
-    return make_q80_acts(x, shared=True)
-
-
 def reads_q40_stack(w) -> bool:
     """Whether ``matmul`` multiplies by one layer of ``w`` without the plane
     being sliced out first: a PackedQ40 whose planes carry exactly one
@@ -111,51 +94,33 @@ def reads_q40_stack(w) -> bool:
     return pallas_supports_stack(w)
 
 
-def _raw_x(x):
-    """Unwrap a Q80Acts bundle to its original activation for every
-    non-kernel path (dense weights, XLA fallback)."""
-    from .pallas_q40 import Q80Acts
-
-    return x.x if isinstance(x, Q80Acts) else x
-
-
-def matmul(x, w) -> jnp.ndarray:
+def matmul(x: jnp.ndarray, w) -> jnp.ndarray:
     """y = x @ w for dense [.., d_in, d_out] arrays or PackedQ40 weights.
-    ``x`` may be a Q80Acts bundle from ``shared_q80_acts``: the Pallas
-    path consumes the prebuilt operands directly; every other path falls
-    back to the bundle's original activation. ``w`` may be a ``Q40Layer``, a
-    stack and a layer index, which a layer scan builds where
-    ``reads_q40_stack`` holds: the kernel reads that layer's tiles out of the
-    stack."""
+    What ``w`` is decides the route, never who calls:
+
+    - a ``Q40Layer`` (a stack and a layer index, which a layer scan builds
+      where ``reads_q40_stack`` holds): the kernel, which reads that layer's
+      tiles out of the stack. Nearly every call of a step on one device;
+    - a 2-D ``PackedQ40`` with the kernel on (the head's ``wcls``, a plane a
+      scan sliced out): ``q40_matmul_partitioned``, whose one-device body is
+      the kernel where it tiles the plane and the XLA dequant where not;
+    - any other ``PackedQ40``: the XLA dequant."""
     if isinstance(w, Q40Layer):
         from .pallas_q40 import q40_matmul_pallas
 
-        kw = {} if _pallas_w_dtype is None else {"w_dtype": _pallas_w_dtype}
         return q40_matmul_pallas(
-            x, w.stack, interpret=_pallas_interpret, layer=w.layer, **kw
+            x, w.stack, interpret=_pallas_interpret, layer=w.layer,
+            **pallas_w_dtype_kw()
         )
     if isinstance(w, PackedQ40):
         if w.packed.ndim == 2 and pallas_kernel_active():
-            from .pallas_q40 import (
-                Q80Acts,
-                pallas_supports,
-                q40_matmul_pallas,
-                q40_matmul_partitioned,
-            )
+            from .pallas_q40 import q40_matmul_partitioned
 
-            kw = {} if _pallas_w_dtype is None else {"w_dtype": _pallas_w_dtype}
-            if isinstance(x, Q80Acts):
-                # prebuilt operands skip the GSPMD wrapper: sharing is the
-                # single-chip (mesh-free) fast path, and the bundle's
-                # layouts are unsharded by construction
-                if pallas_supports(w) and x.d_in == w.d_in:
-                    return q40_matmul_pallas(
-                        x, w, interpret=_pallas_interpret, **kw
-                    )
-                x = x.x
-            return q40_matmul_partitioned(x, w, interpret=_pallas_interpret, **kw)
-        return q40_matmul_xla(_raw_x(x), w)
-    return _raw_x(x) @ w
+            return q40_matmul_partitioned(
+                x, w, interpret=_pallas_interpret, **pallas_w_dtype_kw()
+            )
+        return q40_matmul_xla(x, w)
+    return x @ w
 
 
 def q40_matmul_local(x: jnp.ndarray, w: PackedQ40) -> jnp.ndarray:
@@ -169,6 +134,7 @@ def q40_matmul_local(x: jnp.ndarray, w: PackedQ40) -> jnp.ndarray:
         # pallas_supports gates BOTH modes: interpret runs must not reach
         # the kernel with shapes the tiling planner rejects
         if pallas_supports(w):
-            kw = {} if _pallas_w_dtype is None else {"w_dtype": _pallas_w_dtype}
-            return q40_matmul_pallas(x, w, interpret=_pallas_interpret, **kw)
+            return q40_matmul_pallas(
+                x, w, interpret=_pallas_interpret, **pallas_w_dtype_kw()
+            )
     return q40_matmul_xla(x, w)

@@ -14,7 +14,7 @@ is moved:
 - ``[L, lanes, S, n_kv, hd]`` with ``hd == 128`` (models/llama.py), ``(n_kv,
   hd)`` tiled. Merging ``(S, n_kv)`` into one axis of ``S * n_kv`` rows of
   ``hd`` leaves every byte where it is (a bitcast for XLA, checked in
-  tests/test_chip_compile.py), and gives the kernel a plain ``[rows, hd]``
+  tests/test_chip_compile_attention.py), and gives the kernel a plain ``[rows, hd]``
   matrix a block: row ``s * n_kv + h`` is key head ``h`` of position ``s``,
   ``n_kv`` rows a position.
 - ``[A, lanes, S, n_kv * hd]`` (models/hybrid.py: heads narrower than a

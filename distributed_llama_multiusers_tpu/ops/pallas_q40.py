@@ -55,9 +55,9 @@ on TPU.
 
 from __future__ import annotations
 
+import math
 import os as _os
 from functools import partial
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -101,10 +101,6 @@ TARGET_BLOCK_BYTES = 1 << 20  # k-chunk size target (DMA/compute overlap)
 # v4 f32 chain regardless of this knob.
 DEQUANT_MODES = ("v4", "bf16chain", "repeat", "u8chain", "blockdot",
                  "i8blockdot")
-# "auto" is selectable but not a kernel mode: it resolves per (d_in, d_out,
-# m-class) from the persisted selection table (ops/dequant_select.py) inside
-# q40_matmul_pallas, at trace time, so every family still compiles once.
-SELECTABLE_MODES = DEQUANT_MODES + ("auto",)
 # the two modes whose kernels take pre-split, transposed operands; the other
 # four (the slab chains) take x as it is
 BLOCK_DOT_MODES = ("blockdot", "i8blockdot")
@@ -115,10 +111,10 @@ def _env_dequant_default() -> str:
     loudly here: the slab kernel's mode= else-branch would otherwise
     silently run the v4 chain under the wrong name."""
     mode = _os.environ.get("DLLAMA_DEQUANT", "v4")
-    if mode not in SELECTABLE_MODES:
+    if mode not in DEQUANT_MODES:
         raise ValueError(
             f"DLLAMA_DEQUANT={mode!r} is not a known dequant mode; "
-            f"one of {SELECTABLE_MODES}"
+            f"one of {DEQUANT_MODES}"
         )
     return mode
 
@@ -127,10 +123,7 @@ DEQUANT_MODE = _env_dequant_default()
 BLOCKDOT_MAX_M = 32  # above this, the post-scale FMA outweighs the savings
 
 # Trace-time counters (host side: these python bodies run only while jax
-# traces a NEW program, so steady-state jit-cache hits add nothing). They
-# are the operand-sharing and compile-churn witnesses: `shared_builds` /
-# `shared_consumes` pin that one Q80Acts build feeds every matmul sharing
-# its input (llama_forward: wq/wk/wv = 1 build, w1/w3 = 1 build per step),
+# traces a NEW program, so steady-state jit-cache hits add nothing).
 # `impl_traces` holding still across repeated calls is the no-recompile
 # signal tests assert across the BLOCKDOT_MAX_M boundary,
 # `natural_x_consumes` is the engagement witness of the slab chains'
@@ -140,10 +133,8 @@ BLOCKDOT_MAX_M = 32  # above this, the post-scale FMA outweighs the savings
 # rows (PR 45): `InferenceEngine.path_facts()` says it at start-up, so a
 # run whose 1024-row calls still pay four passes is not silent.
 TRACE_STATS = {
-    "acts_builds": 0,      # make_q80_acts executions (any caller)
-    "shared_builds": 0,    # ... with shared=True (the models/llama.py hoist)
-    "shared_consumes": 0,  # q40_matmul_pallas calls fed a prebuilt Q80Acts
-    "stacked_consumes": 0,  # ... that read their layer's tiles out of a stack
+    # q40_matmul_pallas calls that read their layer's tiles out of a stack
+    "stacked_consumes": 0,
     "natural_x_consumes": 0,  # kernel-body traces handed x itself (slab chains)
     "impl_traces": 0,      # kernel-body traces (one per compiled family)
     # the most passes over its weight plane any traced kernel call makes
@@ -257,14 +248,13 @@ def _final_writeback(k, n_k, out_ref, acc_ref):
 
 
 def set_dequant_mode(mode: str | None) -> None:
-    """Select the bf16-path dequant variant (None -> env/default; "auto" ->
-    per-site table resolution, ops/dequant_select.py). The mode is a static
-    argument of the jitted matmul, so switching retraces — resolve before
-    warmup_engine, never mid-serving."""
+    """Select the bf16-path dequant variant (None -> env/default). The mode
+    is a static argument of the jitted matmul, so switching retraces — set
+    it before warmup_engine, never mid-serving."""
     global DEQUANT_MODE
-    if mode is not None and mode not in SELECTABLE_MODES:
+    if mode is not None and mode not in DEQUANT_MODES:
         raise ValueError(
-            f"unknown dequant mode {mode!r}; one of {SELECTABLE_MODES}"
+            f"unknown dequant mode {mode!r}; one of {DEQUANT_MODES}"
         )
     DEQUANT_MODE = mode or _env_dequant_default()
 
@@ -595,56 +585,21 @@ def _vmem_limit(block_bytes: int) -> int:
     return max(VMEM_LIMIT_BYTES, block_bytes + VMEM_HEADROOM)
 
 
-class Q80Acts(NamedTuple):
-    """The activation operand of the Q40 matmul, made ONCE per distinct
-    input and consumed by every matmul sharing it — llama_forward's
-    wq/wk/wv share one normed x and w1/w3 another.
-
-    ``x_rows`` is x itself with its leading axes merged and its rows padded:
-    no column moves and the dtype stays. The slab chains (v4, bf16chain,
-    repeat, u8chain: every cell) take it as it is; the two block-dot modes
-    have their pre-split operands built from it where the kernel is called
-    (``_block_dot_operands``), and consumers that share an input inside one
-    program have the identical builds merged by XLA. `x` keeps the ORIGINAL
-    [..., d_in] input: it is the shape/dtype source and the operand for the
-    XLA fallback when a consumer's weight has no supported tiling."""
-
-    x: jnp.ndarray        # original input, [..., d_in]
-    x_rows: jnp.ndarray   # [m_pad, d_in], x's dtype and column order
-
-    @property
-    def d_in(self) -> int:
-        return self.x.shape[-1]
-
-    @property
-    def m(self) -> int:
-        m = 1
-        for s in self.x.shape[:-1]:
-            m *= s
-        return m
-
-
-def make_q80_acts(x: jnp.ndarray, shared: bool = False) -> Q80Acts:
-    """The Q40-matmul activation operand for `x` (idempotent on an existing
-    bundle): a merge of the leading axes and a row pad to whole tiles of
-    x's dtype, whatever the mode. The kernel takes x as it is and sums its
-    blocks itself; nothing of x is converted, split or transposed here."""
-    if isinstance(x, Q80Acts):
-        return x
+def _padded_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """x ``[..., d_in]`` as the kernel takes it: the leading axes merged and
+    the rows padded to whole tiles of x's dtype (``_m_geometry``), whatever
+    the mode. No column moves and the dtype stays: the kernel takes x as it
+    is and sums its blocks itself. Consumers of one input inside one program
+    (wq/wk/wv, w1/w3) have their identical pads merged by XLA."""
     d_in = x.shape[-1]
     if d_in % 32 != 0:
         raise ValueError(f"d_in={d_in} must cover whole 32-wide quant blocks")
-    TRACE_STATS["acts_builds"] += 1
-    if shared:
-        TRACE_STATS["shared_builds"] += 1
-    m = 1
-    for s in x.shape[:-1]:
-        m *= s
-    x_rows = x.reshape(m, d_in)
+    x_rows = x.reshape(-1, d_in)
+    m = x_rows.shape[0]
     m_pad, _ = _m_geometry(m, x.dtype)
     if m_pad != m:
         x_rows = jnp.pad(x_rows, ((0, m_pad - m), (0, 0)))
-    return Q80Acts(x=x, x_rows=x_rows)
+    return x_rows
 
 
 def _block_dot_operands(x_rows: jnp.ndarray, mode: str):
@@ -678,11 +633,10 @@ def _block_dot_operands(x_rows: jnp.ndarray, mode: str):
             aux.T)
 
 
-def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
+def q40_matmul_pallas(x: jnp.ndarray, w: PackedQ40, interpret: bool = False,
                       w_dtype=None, layer=None) -> jnp.ndarray:
-    """y = x @ dequant(w). x: [..., d_in] array OR a prebuilt ``Q80Acts``
-    bundle (operand sharing across matmuls); returns [..., d_out] in the
-    input's dtype.
+    """y = x @ dequant(w). x: [..., d_in]; returns [..., d_out] in the
+    input's dtype. The kernel's one entry: every caller hands it x as it is.
 
     ``layer``: with a STACKED weight (planes ``[L, d_in//2, d_out]`` and
     ``[L, d_in//32, d_out]``) the int32 index of the layer to multiply by,
@@ -700,52 +654,28 @@ def q40_matmul_pallas(x, w: PackedQ40, interpret: bool = False,
     Explicit f32 on TPU restores multi-pass f32 MXU semantics (slower,
     more mantissa); explicit bf16 under interpret is the ablation/test
     knob. The bf16 path's dequant arithmetic variant comes from
-    ``DEQUANT_MODE`` (env DLLAMA_DEQUANT / set_dequant_mode), resolved
-    here so switching modes retraces; "auto" resolves per (d_in, d_out,
-    m-class) from the persisted selection table (ops/dequant_select.py),
-    deterministically at trace time, so a warmed family never re-resolves.
-    Exact-f32 dots always use the v4 f32 chain; blockdot's post-scale FMA
-    scales with m, so large-m calls (prefill/training) fall back to
-    bf16chain."""
+    ``DEQUANT_MODE`` (env DLLAMA_DEQUANT / set_dequant_mode), read here so
+    switching modes retraces. Exact-f32 dots always use the v4 f32 chain;
+    blockdot's post-scale FMA scales with m, so large-m calls
+    (prefill/training) fall back to bf16chain."""
     w_dtype_r = _resolve_w_dtype(w_dtype, interpret)
-    acts = x if isinstance(x, Q80Acts) else None
-    xr = acts.x if acts is not None else x
-    m = 1
-    for s_ in xr.shape[:-1]:
-        m *= s_
+    m = math.prod(x.shape[:-1])
     mode = DEQUANT_MODE if w_dtype_r == jnp.bfloat16 else "v4"
-    if mode == "auto":
-        from .dequant_select import resolve_mode
-
-        mode = resolve_mode(w.d_in, w.d_out, m)
     if mode in BLOCK_DOT_MODES and m > BLOCKDOT_MAX_M:
         mode = "bf16chain"
-    at = ()  # a plain plane keeps the entries' five-argument call
+    at = ()  # a plain plane keeps the entry's five-argument call
     if layer is not None:
         TRACE_STATS["stacked_consumes"] += 1
         at = (jnp.asarray(layer, jnp.int32),)
-    if acts is not None:
-        TRACE_STATS["shared_consumes"] += 1
-        return _q40_matmul_acts_impl(acts, w, interpret, w_dtype_r, mode, *at)
     return _q40_matmul_pallas_impl(x, w, interpret, w_dtype_r, mode, *at)
 
 
 @partial(jax.jit, static_argnames=("interpret", "w_dtype", "mode"))
 def _q40_matmul_pallas_impl(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
                             mode, layer=None) -> jnp.ndarray:
-    """Raw-x entry: builds the operand bundle inside the same trace, then
-    runs the kernel."""
-    return _q40_matmul_core(make_q80_acts(x), w, interpret, w_dtype, mode,
-                            layer)
-
-
-@partial(jax.jit, static_argnames=("interpret", "w_dtype", "mode"))
-def _q40_matmul_acts_impl(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
-                          mode, layer=None) -> jnp.ndarray:
-    """Prebuilt-operand entry. Q80Acts is a NamedTuple pytree, so inside an
-    outer trace the bundle stays symbolic and one build feeds every
-    consumer without re-tracing the prep."""
-    return _q40_matmul_core(acts, w, interpret, w_dtype, mode, layer)
+    """The one jitted entry: a device trace and a compiled program name
+    every dense Q40 operation after it."""
+    return _q40_matmul_core(x, w, interpret, w_dtype, mode, layer)
 
 
 def _q40_matmul_kernel(layer_ref, *refs, body):
@@ -755,7 +685,7 @@ def _q40_matmul_kernel(layer_ref, *refs, body):
     body(*refs)
 
 
-def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
+def _q40_matmul_core(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
                      mode, layer=None) -> jnp.ndarray:
     TRACE_STATS["impl_traces"] += 1
     packed, scales = w.packed, w.scales
@@ -774,8 +704,8 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         )
     d_in, d_out = w.d_in, w.d_out
     half = d_in // 2
-    if acts.d_in != d_in:
-        raise ValueError(f"operand d_in {acts.d_in} != weight d_in {d_in}")
+    if x.shape[-1] != d_in:
+        raise ValueError(f"operand d_in {x.shape[-1]} != weight d_in {d_in}")
     plan = _plan_blocks(d_in, d_out)
     if plan is None:
         raise ValueError(
@@ -784,10 +714,10 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
     w_tile, rows = plan
     n_k = half // rows
 
-    lead = acts.x.shape[:-1]
-    m = acts.m
-    m_pad = acts.x_rows.shape[0]
-    x_itemsize = acts.x.dtype.itemsize
+    lead = x.shape[:-1]
+    x_rows = _padded_rows(x)
+    m_pad = x_rows.shape[0]
+    x_itemsize = x.dtype.itemsize
     # the m axis is the grid's outermost and the weight blocks ignore it:
     # a slab is fetched and dequantised once for every m block, so the
     # block is the call's rows wherever they fit (one pass over the plane)
@@ -811,7 +741,7 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         # x TRANSPOSED [rows, m]: the kernels slice 16-row (one quant
         # block) ranges, which must land on the sublane axis — sub-128
         # lane slices would relayout
-        x_ops = _block_dot_operands(acts.x_rows, mode)
+        x_ops = _block_dot_operands(x_rows, mode)
         blockdot = mode == "blockdot"
         kernel = partial(
             _q40_blockdot_kernel if blockdot else _q40_i8blockdot_kernel,
@@ -823,12 +753,12 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
     else:
         # x as it is: chunk k's 2 * rows columns, in its own dtype
         TRACE_STATS["natural_x_consumes"] += 1
-        x_ops = (acts.x_rows,)
+        x_ops = (x_rows,)
         x_specs = [pl.BlockSpec((m_block, 2 * rows), lambda i, j, k, l: (i, k))]
         kernel = partial(_q40_slab_kernel, w_dtype=w_dtype, sub_tiles=sub,
                          n_k=n_k, mode=mode)
 
-    out_dtype = acts.x.dtype
+    out_dtype = x.dtype
     out = pl.pallas_call(
         partial(_q40_matmul_kernel, body=kernel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -865,7 +795,7 @@ def _q40_matmul_core(acts: Q80Acts, w: PackedQ40, interpret, w_dtype,
         interpret=interpret,
     )(layer.reshape(1), *x_ops, packed, scale_bits)
 
-    return out[:m].reshape(*lead, d_out)
+    return out[:math.prod(lead)].reshape(*lead, d_out)
 
 
 # ---------------------------------------------------------------------------

@@ -119,7 +119,7 @@ def pack_q40_host(w: np.ndarray):
 # widest output block of the slab kernel: a positive multiple of 128 (a
 # plane's tile candidates are its 128-multiple divisors up to this; any
 # other value would send every plane to the XLA fallback), pinned against
-# the kernel's own block plan in tests/test_dequant_select.py
+# the kernel's own block plan in tests/test_q40_geometry.py
 PALLAS_W_MAX = 8192
 PALLAS_SUB = 512  # in-kernel dequant sub-tile (lanes)
 

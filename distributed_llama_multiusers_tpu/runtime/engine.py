@@ -2554,9 +2554,9 @@ class InferenceEngine:
         # stamp the dequant path the compiled step bakes in (static
         # argname): per-step traffic numbers are only comparable across
         # runs when the kernel mode they were measured under is recorded
-        from ..ops.dequant_select import dequant_stats
+        from ..ops import pallas_q40
 
-        stats.update(dequant_stats())
+        stats["dequant_mode"] = pallas_q40.DEQUANT_MODE
         # keep the executable for dispatch: decode shapes never change, so
         # this one AOT compile replaces the jit path's own compile
         self._decode_exec = compiled
@@ -2997,15 +2997,8 @@ def warmup_engine(
     restored afterwards."""
     n = engine.n_lanes
     z = np.zeros(n, np.int32)
-    # resolve + pin the dequant selection BEFORE anything compiles: the
-    # mode is a static argname of the Q40 matmul jit, so under
-    # DLLAMA_DEQUANT=auto the per-site table answers are baked into the
-    # programs warmed below, and a post-warmup table change would retrace
-    # every family mid-serving — freeze_for_serving makes that a loud
-    # error instead (ops/dequant_select.py)
-    from ..ops.dequant_select import dequant_stats, freeze_for_serving
+    from ..ops import pallas_q40
 
-    freeze_for_serving()
     jitcheck.install()  # count this warm-up's own compiles and cache loads
     # warmup's own compiles are the sanctioned ones: pause the recompile
     # witness for the duration (tests warm several engines per process —
@@ -3231,8 +3224,7 @@ def warmup_engine(
         # (and what is declined for a recurrent state): said here too, so
         # that a start without load_stack's runtime_device line says it
         **(engine.path_facts() if callable(getattr(engine, "path_facts", None)) else {}),
-        # the dequant path every warmed program baked in: the configured
-        # knob plus (under auto) the per-site table resolutions recorded
-        # while the families above traced
-        **dequant_stats(),
+        # the dequant arithmetic every warmed program baked in (a static
+        # argument of the Q40 matmul's jit)
+        dequant_mode=pallas_q40.DEQUANT_MODE,
     )

@@ -453,14 +453,12 @@ class ApiServer:
         # zero). bridge_stats republishes resources_live as a labelled
         # gauge and delta-feeds dllama_resource_leaks_total (telemetry/hub)
         out.update(leakcheck.stats())
-        # dequant path attribution (ops/dequant_select.py): the configured
-        # DLLAMA_DEQUANT knob, and — under auto — the per-(d_in, d_out,
-        # m-class) modes resolved at warmup trace time plus the selection
-        # table's provenance, so a /stats snapshot pins WHICH kernel chain
-        # produced the throughput it reports
-        from ..ops.dequant_select import dequant_stats
+        # the configured dequant arithmetic (--dequant / DLLAMA_DEQUANT), so
+        # a /stats snapshot pins WHICH kernel chain produced the throughput
+        # it reports
+        from ..ops import pallas_q40
 
-        out.update(dequant_stats())
+        out["dequant_mode"] = pallas_q40.DEQUANT_MODE
         # which device and weight/kernel path this process really serves
         # from (app/runtime_setup.load_stack — the runtime_device start-up
         # line carries the same facts); absent on engines built without it

@@ -18,7 +18,7 @@ VMEM) and that libtpu has no custom-call partitioner. A compile that passes
 is not a chip run; nothing here is a time or a rate of the device. Write the
 model with formats/synthetic (`chip_smoke.py --phase prepare` shows how); the
 1B at 8 lanes takes about 10 minutes of compiling. Only one process can hold
-libtpu: tests/test_chip_compile.py skips while this runs.
+libtpu: tests/test_chip_compile_*.py skip while this runs.
 """
 
 from __future__ import annotations

@@ -24,12 +24,6 @@ XLA-level (no Pallas) int4-resident alternatives:
 
 Run on TPU:  python scripts/kernel_lab3.py [d_in] [d_out] [L] [reps]
 Correctness: python scripts/kernel_lab3.py --check   (interpret mode, CPU)
-Adopt:       python scripts/kernel_lab3.py [shape...] --adopt
-
---adopt makes the lab adopt-and-verify: after timing, the fastest product
-variant is re-verified against the numpy oracle (the --check gate) and
-then recorded into ops/dequant_table.json as a per-(d_in, d_out) decode
-row for DLLAMA_DEQUANT=auto to pick up at the next serving start.
 """
 
 from __future__ import annotations
@@ -268,18 +262,6 @@ KERNELS = {
 }
 # i8blockdot is special-cased (int8 x operands + interleaved bsum/sx aux)
 
-# lab variant -> shipping DEQUANT_MODES name, for --adopt (the XLA int4
-# probes have no product counterpart and are never adopted)
-ADOPT_MODES = {
-    "full_v4": "v4",
-    "full_bf16chain": "bf16chain",
-    "full_repeat": "repeat",
-    "full_u8nib": "u8chain",
-    "full_blockdot": "blockdot",
-    "full_i8blockdot": "i8blockdot",
-}
-
-
 def _call_i8blockdot(xf, packed, sbits, d_in, d_out, chunk, tile):
     half = d_in // 2
     xq_lo, xq_hi, aux = _quantize_x_blocks(np.asarray(xf), d_in)
@@ -405,7 +387,6 @@ def main():
     if "--check" in sys.argv:
         check()
         return
-    adopt = "--adopt" in sys.argv
     args = [a for a in sys.argv[1:] if not a.startswith("--")]
     d_in = int(args[0]) if len(args) > 0 else 4096
     d_out = int(args[1]) if len(args) > 1 else 14336
@@ -439,7 +420,6 @@ def main():
         dimension_semantics=("arbitrary", "parallel", "arbitrary"),
     )
 
-    times: dict = {}
     for name, (kern, transposed) in KERNELS.items():
         if transposed:
             xa, xb_ = x_lo.T, x_hi.T
@@ -461,7 +441,7 @@ def main():
                 compiler_params=params,
             )(t, xa, xb_, bsum_t, packed, sbits)
 
-        times[name] = timeit(name, call, pbytes)
+        timeit(name, call, pbytes)
 
     # ---- i8blockdot: int8 MXU dots on Q80-quantized activations -----------
     xq_lo, xq_hi, aux = _quantize_x_blocks(np.asarray(xf), d_in)
@@ -480,7 +460,7 @@ def main():
             compiler_params=params,
         )(t, xq_lo, xq_hi, aux, packed, sbits)
 
-    times["full_i8blockdot"] = timeit("full_i8blockdot", call_i8, pbytes)
+    timeit("full_i8blockdot", call_i8, pbytes)
 
     # ---- XLA-level int4 alternatives (no Pallas) --------------------------
     try:
@@ -525,33 +505,6 @@ def main():
         timeit_xla("xla_int4_scaled", scaled, i4bytes)
     except Exception as e:  # noqa: BLE001
         print(f"xla_int4: unavailable ({type(e).__name__}: {str(e)[:120]})")
-
-    if adopt:
-        _adopt(times, d_in, d_out)
-
-
-def _adopt(times, d_in, d_out):
-    """--adopt: verify the fastest product variant against the numpy
-    oracle (the --check gate; exits non-zero on parity failure), then
-    record it into the persisted selection table as a per-(d_in, d_out)
-    decode row (M=8 here is squarely decode-class)."""
-    timed = {ADOPT_MODES[n]: t for n, t in times.items()
-             if t is not None and n in ADOPT_MODES}
-    if not timed:
-        print("ADOPT: no product variant timed; nothing recorded")
-        return
-    mode = min(timed, key=timed.get)
-    print(f"ADOPT: fastest product variant = {mode} "
-          f"({timed[mode] * 1e3:.3f} ms/pass); verifying before recording")
-    check()
-    from distributed_llama_multiusers_tpu.ops.dequant_select import record_win
-
-    path = record_win(
-        d_in, d_out, "decode", mode,
-        source=f"scripts/kernel_lab3.py --adopt "
-               f"({timed[mode] * 1e3:.3f} ms/pass, M={M})",
-    )
-    print(f"TABLE: {d_in}x{d_out}/decode -> {mode} recorded in {path}")
 
 
 def timeit(name, build_call, bytes_per_pass, reps=None):

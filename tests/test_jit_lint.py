@@ -128,41 +128,6 @@ def test_jit_stability_out_of_scope_file_ignored(tmp_path):
     assert findings == []
 
 
-def test_jit_stability_covers_dequant_select_scope(tmp_path):
-    # ops/dequant_select.py sits in the jit-stability scope: its rules
-    # are read at trace time, so a table (re)load that constructs device
-    # arrays into self state would become a captured leaf whose aval can
-    # change — the same recompile class as an engine leaf swap
-    findings = run_on(tmp_path, {"ops/dequant_select.py": """
-        import jax.numpy as jnp
-
-        class DequantTable:
-            def __init__(self, path):
-                self.rules = []
-
-            def load(self, rows):
-                self.rules = jnp.asarray(rows)
-    """})
-    assert checks_of(findings) == ["jit-stability"]
-
-
-def test_jit_stability_dequant_select_pure_host_clean(tmp_path):
-    # the real table's shape: plain dicts parsed from JSON, no device
-    # arrays anywhere near self state
-    findings = run_on(tmp_path, {"ops/dequant_select.py": """
-        import json
-
-        class DequantTable:
-            def __init__(self, path):
-                self.rules = []
-
-            def load(self, path):
-                with open(path) as f:
-                    self.rules = json.load(f).get("rules", [])
-    """})
-    assert findings == []
-
-
 # -- donation-discipline ------------------------------------------------------
 
 DONATE_HEADER = """
@@ -405,16 +370,6 @@ def test_warmup_coverage_waivable_with_reason(tmp_path):
 
 ENGINE = PACKAGE_ROOT / "runtime" / "engine.py"
 
-
-def test_real_dequant_select_lints_clean():
-    """The shipped selection table stays pure host state — the dlint
-    baseline for ops/dequant_select.py is (and must remain) empty."""
-    analyzer = Analyzer(default_checkers())
-    findings = analyzer.run(
-        [PACKAGE_ROOT / "ops" / "dequant_select.py"],
-        baseline=set(), root=PACKAGE_ROOT.parent,
-    )
-    assert findings == [], [str(f) for f in findings]
 
 # the full dispatchable family set the serving loop can reach; a new
 # `self.*_fn = jax.jit(...)`-style binding must join this list AND the

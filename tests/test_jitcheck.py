@@ -213,32 +213,15 @@ def test_serving_churn_is_compile_stable_under_witness(
     pod plane's form of it (speculation off, as a pod serves): a
     recompile on a mesh stalls every chip of it, and the sharded step
     families, the replicated token carry and the cache shardings must
-    all come out of warmup with the avals the churn dispatches.
-
-    Runs under ``DLLAMA_DEQUANT=auto`` (ISSUE 18): with f32 params the
-    resolved arithmetic is identical to the default, so the baseline pin
-    loses nothing, and the auto serving smoke rides the same churn —
-    warmup must freeze the selection table (a live reload would retrace
-    every warmed family) and per-site resolution must add no compiles.
-    The per-site mode routing itself is pinned under jit in
-    tests/test_pallas_q40.py (the BLOCKDOT_MAX_M boundary test)."""
-    from distributed_llama_multiusers_tpu.ops import dequant_select, pallas_q40
-
-    dequant_select._reset_for_tests()
-    pallas_q40.set_dequant_mode("auto")
-    try:
-        if plane == "tp2_mesh":
-            engine, tok = _stack(tiny_model, tp=2)
-            _churn(engine, tok, speculative=False)
-        else:
-            engine, tok = _stack(tiny_model)
-            _churn(engine, tok)
-        assert engine.stats.snapshot()["jit_compiles_after_warmup"] == 0
-        with pytest.raises(RuntimeError, match="frozen"):
-            dequant_select.reload_table()
-    finally:
-        pallas_q40.set_dequant_mode(None)
-        dequant_select._reset_for_tests()
+    all come out of warmup with the avals the churn dispatches. Runs
+    under the default dequant mode, as every cell does."""
+    if plane == "tp2_mesh":
+        engine, tok = _stack(tiny_model, tp=2)
+        _churn(engine, tok, speculative=False)
+    else:
+        engine, tok = _stack(tiny_model)
+        _churn(engine, tok)
+    assert engine.stats.snapshot()["jit_compiles_after_warmup"] == 0
 
 
 @pytest.fixture(scope="module")

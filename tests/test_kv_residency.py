@@ -37,7 +37,7 @@ from distributed_llama_multiusers_tpu.models.llama import (
     init_paged_kv_cache,
 )
 from distributed_llama_multiusers_tpu.ops.activations import silu
-from distributed_llama_multiusers_tpu.ops.linear import matmul, shared_q80_acts
+from distributed_llama_multiusers_tpu.ops.linear import matmul
 from distributed_llama_multiusers_tpu.ops.norm import rms_norm
 from distributed_llama_multiusers_tpu.ops.rope import apply_rope
 from distributed_llama_multiusers_tpu.runtime import InferenceEngine
@@ -96,7 +96,7 @@ def _layer_loop_forward(config, params, tokens, positions, cache):
     planes_k, planes_v = [], []
     for l in range(config.n_layers):
         lp = jax.tree.map(lambda a: a[l], params.layers)
-        y = shared_q80_acts(rms_norm(x, lp.rms_att, config.norm_epsilon))
+        y = rms_norm(x, lp.rms_att, config.norm_epsilon)
         q = _maybe_bias(matmul(y, lp.wq), lp.bq).reshape(b, t, n_heads, hd)
         k = _maybe_bias(matmul(y, lp.wk), lp.bk).reshape(b, t, n_kv, hd)
         v = _maybe_bias(matmul(y, lp.wv), lp.bv).reshape(b, t, n_kv, hd)
@@ -114,7 +114,7 @@ def _layer_loop_forward(config, params, tokens, positions, cache):
                                 v_plane.astype(jnp.float32), mask,
                                 1.0 / float(hd) ** 0.5)
         x = x + matmul(attn.reshape(b, t, n_heads * hd).astype(x.dtype), lp.wo)
-        y = shared_q80_acts(rms_norm(x, lp.rms_ffn, config.norm_epsilon))
+        y = rms_norm(x, lp.rms_ffn, config.norm_epsilon)
         x = x + matmul(silu(matmul(y, lp.w1)) * matmul(y, lp.w3), lp.w2)
     y = rms_norm(x, params.rms_final, config.norm_epsilon)
     logits = matmul(y, params.wcls).astype(jnp.float32)[..., : config.vocab_size]

@@ -2,7 +2,7 @@
 
 The kernel runs in interpret mode on the CPU, as tests/test_pallas_q40.py
 runs its kernel: that proves its arithmetic and its work list, not that it
-exists on the chip (tests/test_chip_compile.py compiles it for a v5e).
+exists on the chip (tests/test_chip_compile_attention.py compiles it for a v5e).
 `_dense_attention` on the same stacked cache is the reference; both take
 bf16 keys and values, the kernel rounds its probabilities to bf16 for the
 second product as the chip's default precision does, so they agree to a few

@@ -318,15 +318,16 @@ def test_bf16_w_dtype_greedy_stream_model_scale(tiny_model):
 
 
 # ---------------------------------------------------------------------------
-# Shared Q80 activation operands (Q80Acts): one build per distinct input,
-# every matmul sharing it consumes the prebuilt layouts.
+# One input, several consumers: every caller hands the kernel's one entry x
+# as it is (PR 46: the activation bundle, its second jitted entry and the
+# build / share counters went). Consumers of one x inside one program run the
+# math they run alone.
 # ---------------------------------------------------------------------------
 
 from distributed_llama_multiusers_tpu.ops.pallas_q40 import (  # noqa: E402
     BLOCKDOT_MAX_M,
     DEQUANT_MODES,
     TRACE_STATS,
-    make_q80_acts,
     reset_trace_stats,
     set_dequant_mode,
 )
@@ -334,71 +335,66 @@ from distributed_llama_multiusers_tpu.ops.pallas_q40 import (  # noqa: E402
 
 @pytest.mark.parametrize(
     "mode", ["v4", "blockdot", "i8blockdot", "bf16chain", "repeat", "u8chain"])
-def test_q80_acts_shared_vs_raw_parity(mode):
-    """A prebuilt Q80Acts bundle and a raw activation run the SAME traced
-    math per mode — only XLA fusion boundaries differ between the eager
-    build and the in-jit build, so i8blockdot (the one mode with a
-    reduction in operand prep) sits at ~1e-7 reduction-order wiggle. The
-    slab chains (PR 42) are handed x itself either way, and nothing is
-    prepared that a fusion boundary could move: to the bit."""
+def test_consumers_of_one_input_match_their_standalone_calls(mode):
+    """Three consumers of one x inside one ``jit`` (wq/wk/wv's shape: the
+    program XLA may merge their pads and operand builds in) against each
+    weight's standalone call — only XLA fusion boundaries differ, so
+    i8blockdot (the one mode with a reduction in operand prep) sits at ~1e-7
+    reduction-order wiggle. The slab chains (PR 42) are handed x itself
+    either way, and nothing is prepared that a fusion boundary could move:
+    to the bit."""
     rng = np.random.default_rng(5)
-    pw = _pack(rng, 256, 128)
+    weights = [_pack(rng, d_out, 128) for d_out in (256, 128, 384)]
     x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
+    call = partial(q40_matmul_pallas, interpret=True, w_dtype=jnp.bfloat16)
     set_dequant_mode(mode)
     try:
-        raw = np.asarray(
-            q40_matmul_pallas(x, pw, interpret=True, w_dtype=jnp.bfloat16)
-        )
-        acts = make_q80_acts(x)
-        assert make_q80_acts(acts) is acts  # idempotent
-        shared = np.asarray(
-            q40_matmul_pallas(acts, pw, interpret=True, w_dtype=jnp.bfloat16)
-        )
+        alone = [np.asarray(call(x, pw)) for pw in weights]
+        together = jax.jit(lambda x, ws: [call(x, pw) for pw in ws])(x, weights)
     finally:
         set_dequant_mode(None)
-    if mode in ("blockdot", "i8blockdot"):
-        np.testing.assert_allclose(shared, raw, rtol=1e-5, atol=1e-5)
-    else:
-        np.testing.assert_array_equal(shared, raw)
+    for got, want in zip(together, alone):
+        if mode in ("blockdot", "i8blockdot"):
+            np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(np.asarray(got), want)
 
 
-def test_q80_acts_build_and_consume_counters():
-    """Trace-time counters witness the sharing: one shared build feeds N
-    consumes with zero per-site rebuilds."""
+def test_kernel_body_trace_counters():
+    """Trace-time counters: each kernel body traced in a slab chain was
+    handed x as it is; the block-dot modes take the operands built for them;
+    a plane is no stack read."""
     rng = np.random.default_rng(6)
     weights = [_pack(rng, d_out, 128) for d_out in (128, 256, 384)]
     x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
     # the kernel bodies are traced here, whatever ran before in this process
-    pq._q40_matmul_acts_impl.clear_cache()
+    pq._q40_matmul_pallas_impl.clear_cache()
     reset_trace_stats()
-    acts = make_q80_acts(x, shared=True)
     for pw in weights:
-        q40_matmul_pallas(acts, pw, interpret=True)
-    assert TRACE_STATS["acts_builds"] == 1, TRACE_STATS
-    assert TRACE_STATS["shared_builds"] == 1, TRACE_STATS
-    assert TRACE_STATS["shared_consumes"] == 3, TRACE_STATS
+        q40_matmul_pallas(x, pw, interpret=True)
     # each traced kernel body (the f32 chain) was handed x as it is
     assert TRACE_STATS["impl_traces"] == 3, TRACE_STATS
     assert TRACE_STATS["natural_x_consumes"] == 3, TRACE_STATS
+    assert TRACE_STATS["stacked_consumes"] == 0, TRACE_STATS
+    assert TRACE_STATS["weight_passes_max"] == 1, TRACE_STATS
     # the block-dot modes take the operands built for them, not x itself
     set_dequant_mode("blockdot")
     try:
-        pq._q40_matmul_acts_impl.clear_cache()
+        pq._q40_matmul_pallas_impl.clear_cache()
         reset_trace_stats()
-        q40_matmul_pallas(acts, weights[0], interpret=True,
-                          w_dtype=jnp.bfloat16)
+        q40_matmul_pallas(x, weights[0], interpret=True, w_dtype=jnp.bfloat16)
     finally:
         set_dequant_mode(None)
     assert TRACE_STATS["impl_traces"] == 1, TRACE_STATS
     assert TRACE_STATS["natural_x_consumes"] == 0, TRACE_STATS
 
 
-def test_shared_acts_build_counts_model_scale(tiny_model):
-    """THE operand-sharing win at model scale: one llama_forward trace
-    builds exactly TWO shared bundles (the normed x for wq/wk/wv; the
-    FFN input for w1/w3) consumed at five matmul sites — the layer body
-    traces once under lax.scan. The remaining builds are the unshared
-    single-consumer sites (wo, w2 in the layer, wcls at the head)."""
+def test_trace_counters_at_model_scale(tiny_model):
+    """One llama_forward trace at model scale: the layer body traces once
+    under lax.scan and its seven matmuls read their layer out of a stack
+    (wq, wk, wv, wo, w1, w3, w2); the head's wcls is a plane. Whatever kernel
+    body the trace made took x in its own order: the model has no other form
+    to run, and no bundle stands between it and the kernel."""
     from distributed_llama_multiusers_tpu.formats.model_file import load_model_header
     from distributed_llama_multiusers_tpu.models import init_kv_cache, llama_forward
     from distributed_llama_multiusers_tpu.models.loader import (
@@ -414,20 +410,19 @@ def test_shared_acts_build_counts_model_scale(tiny_model):
     positions = jnp.asarray([[0, 1, 2]], jnp.int32)
     linear.set_pallas_interpret(True)
     try:
+        assert linear.reads_q40_stack(qparams.layers.wq)
+        pq._q40_matmul_pallas_impl.clear_cache()
         reset_trace_stats()
         llama_forward(
             config, qparams, tokens, positions, init_kv_cache(config, 1)
         )
-        assert TRACE_STATS["shared_builds"] == 2, TRACE_STATS
-        assert TRACE_STATS["shared_consumes"] == 5, TRACE_STATS
-        # the only other builds come from the three unshared sites, each
-        # at most once per kernel-family trace (0 on a warm jit cache) —
-        # never one-per-consumer like the pre-sharing layout
-        assert TRACE_STATS["acts_builds"] - 2 <= 3, TRACE_STATS
-        # whatever kernel body this trace made (none on a warm jit cache)
-        # took x in its own order: the model has no other form to run
+        assert TRACE_STATS["stacked_consumes"] == 7, TRACE_STATS
+        # one body a distinct (rows, d_in, d_out, stacked): wq = wo, wk = wv
+        # and w1 = w3 share theirs; then w2 and the head's plane
+        assert 1 <= TRACE_STATS["impl_traces"] <= 8, TRACE_STATS
         assert (TRACE_STATS["natural_x_consumes"]
                 == TRACE_STATS["impl_traces"]), TRACE_STATS
+        assert TRACE_STATS["weight_passes_max"] == 1, TRACE_STATS
     finally:
         linear.set_pallas_interpret(False)
 
@@ -463,18 +458,6 @@ def test_blockdot_max_m_cap_routes_and_caches():
                 )
                 q40_matmul_pallas(x, pw, interpret=True, w_dtype=jnp.bfloat16)
                 assert seen == [expect], (mode, m, seen)
-        # auto resolves through the same boundary: the table's decode
-        # class IS the blockdot cap, so the m-class flip and the kernel
-        # fallback agree at m = BLOCKDOT_MAX_M + 1
-        set_dequant_mode("auto")
-        for m, expect in [
-            (BLOCKDOT_MAX_M, "i8blockdot"),
-            (BLOCKDOT_MAX_M + 1, "bf16chain"),
-        ]:
-            seen.clear()
-            x = jnp.asarray(rng.standard_normal((m, 64), dtype=np.float32))
-            q40_matmul_pallas(x, pw, interpret=True, w_dtype=jnp.bfloat16)
-            assert seen == [expect], ("auto", m, seen)
         # no recompile churn: the second same-shape call is a jit cache
         # hit — the kernel core's python body does not run again
         set_dequant_mode("i8blockdot")
@@ -585,7 +568,7 @@ def test_set_dequant_mode_rejects_unknown():
     # the knob is unchanged after the rejection
     from distributed_llama_multiusers_tpu.ops.pallas_q40 import DEQUANT_MODE
 
-    assert DEQUANT_MODE in DEQUANT_MODES + ("auto",)
+    assert DEQUANT_MODE in DEQUANT_MODES
 
 
 def test_env_dequant_rejects_unknown_on_import():
@@ -624,11 +607,12 @@ def _plane(stack, l):
 
 
 @pytest.mark.parametrize("how", ["jit", "scan"])
-@pytest.mark.parametrize("entry", ["raw_x", "shared_acts"])
+@pytest.mark.parametrize("entry", ["raw_x", "rank3"])
 @pytest.mark.parametrize("mode", DEQUANT_MODES)
 def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
     """Layer ``l`` read out of the stack equals the 2-D kernel on plane ``l``
-    to the bit, in every dequant mode and through both jitted entries, with
+    to the bit, in every dequant mode, x two-dimensional or with leading axes
+    ``[lanes, t, d_in]`` that the kernel merges, with
     ``l`` a traced scalar (an argument of a jit; the counter of a lax.scan)
     at the stack's first and last layer. Both sides are computed inside one
     traced program, so the operand builds are the same operations."""
@@ -638,7 +622,7 @@ def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
     kw = dict(interpret=True, w_dtype=jnp.bfloat16)
 
     def both(x, stack, l):
-        xin = make_q80_acts(x) if entry == "shared_acts" else x
+        xin = x.reshape(2, 2, 128) if entry == "rank3" else x
         return (q40_matmul_pallas(xin, stack, layer=l, **kw),
                 q40_matmul_pallas(xin, _plane(stack, l), **kw))
 
@@ -749,7 +733,7 @@ def test_stack_and_layer_go_together():
 # The slab chains take x as it is (PR 42): its own column order, its own
 # dtype, one BlockSpec. The kernel puts the dequantised nibble planes back in
 # the input's order by whole 16-row tiles, multiplies in one dot and sums x's
-# quant blocks itself. Before, ``make_q80_acts`` split x's lane axis into
+# quant blocks itself. Before, the operand build split x's lane axis into
 # [n_blk, 2, 16] in XLA ahead of every distinct input.
 # ---------------------------------------------------------------------------
 
@@ -807,15 +791,15 @@ NATURAL_SHAPES = [
 
 
 @pytest.mark.parametrize("weight", ["plane", "stack"])
-@pytest.mark.parametrize("entry", ["raw_x", "shared_acts"])
+@pytest.mark.parametrize("entry", ["raw_x", "rank3"])
 @pytest.mark.parametrize("m,d_in,d_out", NATURAL_SHAPES)
 def test_natural_operand_matches_xla_and_the_two_dot_form(m, d_in, d_out,
                                                           entry, weight):
     """The kernel handed x itself, in exact f32: against the XLA dequant to
     the tolerance this file has always had, against the two-dot form (the
-    same products in another order) closer, and the four ways in (raw x or
-    the shared bundle, a plane or a layer of a stack under a traced index)
-    equal to the bit."""
+    same products in another order) closer, and the four ways in (x in two
+    dimensions or as ``[lanes, t, d_in]``, a plane or a layer of a stack under
+    a traced index) equal to the bit."""
     rng = np.random.default_rng(d_in + d_out + m)
     stack = _stack(rng, d_out, d_in, n=2)
     pw = _plane(stack, 1)
@@ -823,13 +807,16 @@ def test_natural_operand_matches_xla_and_the_two_dot_form(m, d_in, d_out,
 
     @jax.jit
     def run(x, stack, l):
-        xin = make_q80_acts(x) if entry == "shared_acts" else x
+        lanes = 2 if m % 2 == 0 else 1
+        xin = x.reshape(lanes, m // lanes, d_in) if entry == "rank3" else x
         if weight == "stack":
-            return q40_matmul_pallas(xin, stack, interpret=True, layer=l)
-        return q40_matmul_pallas(xin, _plane(stack, 1), interpret=True)
+            y = q40_matmul_pallas(xin, stack, interpret=True, layer=l)
+        else:
+            y = q40_matmul_pallas(xin, _plane(stack, 1), interpret=True)
+        assert y.shape == xin.shape[:-1] + (d_out,)
+        return y.reshape(m, d_out)
 
     got = np.asarray(run(x, stack, jnp.int32(1)))
-    assert got.shape == (m, d_out)
     np.testing.assert_allclose(
         got, np.asarray(q40_matmul_xla(x, pw)), atol=2e-4, rtol=2e-4)
     want = np.asarray(_two_dot_form(x, pw, jnp.float32))
@@ -937,8 +924,8 @@ def test_rows_pad_to_whole_tiles_of_their_dtype(m, dtype, want):
     one 8: decided by the input's dtype and static row count, nothing else."""
     assert pq._m_geometry(m, dtype) == want
     x = jnp.zeros((m, 64), dtype)
-    assert make_q80_acts(x).x_rows.shape == (want[0], 64)
-    assert make_q80_acts(x).x_rows.dtype == dtype
+    assert pq._padded_rows(x).shape == (want[0], 64)
+    assert pq._padded_rows(x).dtype == dtype
 
 
 # --- the lowered-program witness -------------------------------------------
@@ -1063,12 +1050,10 @@ def test_the_witness_finds_the_split_the_kernel_took_before():
     """Control on the preparation itself: the operands every chain took
     before PR 42, and the block-dot modes still take, are built by that
     split; lowered alone under a scope it shows what both searches look for,
-    and ``make_q80_acts`` builds none of it, under any mode."""
+    and the rows the kernel pads (``_padded_rows``) hold none of it."""
     def prep(x):
         with jax.named_scope("dl.ffn"):
-            return pq._block_dot_operands(make_q80_acts(x).x_rows, "blockdot")
-
-    assert pq.Q80Acts._fields == ("x", "x_rows")  # x and its padded rows
+            return pq._block_dot_operands(pq._padded_rows(x), "blockdot")
 
     x = jax.ShapeDtypeStruct((16, 256), jnp.bfloat16)
     assert SPLIT_RESHAPE.search(jax.jit(prep).lower(x).as_text())
@@ -1222,7 +1207,7 @@ def test_weight_passes_witness_in_trace_stats_and_path_facts(witness_engine):
     def trace(m):
         x = jax.ShapeDtypeStruct((m, 4096), jnp.bfloat16)
         jax.eval_shape(lambda x, w: pq._q40_matmul_core(
-            make_q80_acts(x), w, True, jnp.bfloat16, "v4"), x, w)
+            x, w, True, jnp.bfloat16, "v4"), x, w)
 
     reset_trace_stats()
     assert witness_engine.path_facts()["q40_weight_passes"] == 0  # none traced

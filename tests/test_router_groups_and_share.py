@@ -134,7 +134,7 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
     rng = np.random.default_rng(5)
     whole = _config()
     rp = _layer(rng, whole, E)
-    ops = deepseek.ffn_ops(whole, False, False)
+    ops = deepseek.ffn_ops(whole, False)
     x = jnp.asarray(rng.normal(size=(2, 6, D)), jnp.float32)
     live = jnp.ones(12, bool)
 

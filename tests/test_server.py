@@ -168,11 +168,12 @@ def test_stats_endpoint(server):
     assert 0 <= body["lanes_busy"] <= body["lanes_total"]
     assert "spec_tokens_per_lane_step" in body
     assert "spec_lane_steps" in body
-    # dequant attribution (ops/dequant_select): every /stats payload names
-    # the resolved dequant mode; under auto it adds per-site resolutions
-    from distributed_llama_multiusers_tpu.ops.pallas_q40 import SELECTABLE_MODES
+    # dequant attribution: every /stats payload names the configured dequant
+    # mode, and no selection table beside it
+    from distributed_llama_multiusers_tpu.ops.pallas_q40 import DEQUANT_MODES
 
-    assert body["dequant_mode"] in SELECTABLE_MODES
+    assert body["dequant_mode"] in DEQUANT_MODES
+    assert [k for k in body if k.startswith("dequant_")] == ["dequant_mode"]
 
 
 def test_text_completion(server):
