@@ -27,6 +27,7 @@ from distributed_llama_multiusers_tpu.formats.model_file import (
     ArchType,
     HiddenAct,
     LayerKind,
+    NormKind,
     ModelHeader,
     MoeScore,
     RopeType,
@@ -94,6 +95,10 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         # selective state-space mixers beside attention without rotation:
         # KEY_LAYER_KIND with LayerKind.SSM, the KEY_SSM_* keys, RopeType.NONE
         "jamba": ArchType.LLAMA,
+        # window attention with a ring beside full-context attention without
+        # rotation, heads of head_dim, a parallel block under one layer norm,
+        # shared experts averaged: LayerKind.WINDOW and the KEY_HEAD_DIM keys
+        "cohere2_moe": ArchType.LLAMA,
     }.get(cfg["model_type"])
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
@@ -124,6 +129,8 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         set_pattern_header(h, cfg)
     if cfg["model_type"] == "jamba":
         set_ssm_header(h, cfg)
+    if cfg["model_type"] == "cohere2_moe":
+        set_window_header(h, cfg)
     n_experts = cfg.get("num_local_experts")
     if n_experts:
         h.n_experts = int(n_experts)
@@ -249,6 +256,86 @@ def set_ssm_header(h: ModelHeader, cfg: dict) -> None:
     h.ssm_conv_kernel = int(cfg["mamba_d_conv"])
     h.ssm_conv_bias = int(bool(cfg.get("mamba_conv_bias", True)))
     h.ssm_inner_norms = 1  # dt_layernorm, b_layernorm, c_layernorm: the family's
+
+
+_WINDOW_LAYER_KINDS = {"sliding_attention": LayerKind.WINDOW,
+                       "full_attention": LayerKind.ATTENTION}
+
+
+def set_window_header(h: ModelHeader, cfg: dict) -> None:
+    """The header keys of ``model_type: cohere2_moe`` (formats/model_file.py
+    KEY_HEAD_DIM ...): the layer kinds as published (window layers rotate,
+    full-context layers do not), the window, a head's width, the
+    mean-subtracting norm, the parallel block, the routed FFN's keys and the
+    shared experts as one gated FFN scaled by their average. What the runtime
+    does not compute is refused here, by name, not converted wrongly."""
+    unknown = sorted(set(cfg["layer_types"]) - set(_WINDOW_LAYER_KINDS))
+    if unknown:
+        raise ValueError(f"Unsupported cohere2_moe layer types: {unknown}")
+    refused = {
+        "use_qk_norm": bool(cfg.get("use_qk_norm")),
+        "logit_scale": float(cfg.get("logit_scale", 1)) != 1.0,
+        "first_k_dense_replace": int(cfg.get("first_k_dense_replace", 0)) > 0,
+        "use_parallel_block": not cfg.get("use_parallel_block", False),
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "rotary_pct": float(cfg.get("rotary_pct", 1)) != 1.0,
+        "position_embedding_type": cfg.get("position_embedding_type", "rope_gptj") != "rope_gptj",
+        "expert_selection_fn": cfg.get("expert_selection_fn") != "sigmoid",
+        "shared_expert_combination_strategy":
+            cfg.get("shared_expert_combination_strategy", "average") != "average",
+        "use_gated_activation": not cfg.get("use_gated_activation", True),
+    }
+    for key, bad in refused.items():
+        if bad:
+            # (first_k_dense_replace > 0 would bring the prefix_dense_* layers)
+            raise ValueError(f"Unsupported cohere2_moe setting: {key} = {cfg.get(key)!r}")
+    if (cfg.get("rope_parameters") or {}).get("rope_type", "default") != "default":
+        raise ValueError(f"Unsupported rope parameters: {cfg['rope_parameters']}")
+    h.layer_kinds = [_WINDOW_LAYER_KINDS[k] for k in cfg["layer_types"]]
+    h.sliding_window = int(cfg["sliding_window"])
+    h.head_dim = int(cfg.get("head_dim") or 0)
+    h.full_attention_nope, h.norm_kind, h.parallel_block = 1, NormKind.LAYER, 1
+    h.norm_epsilon = float(cfg["layer_norm_eps"])
+    h.n_experts = int(cfg["num_experts"])
+    h.n_active_experts = int(cfg["num_experts_per_tok"])
+    h.moe_hidden_dim = cfg["intermediate_size"]
+    shared = int(cfg.get("num_shared_experts") or 0)
+    h.shared_hidden_dim = shared * cfg["intermediate_size"]
+    h.shared_expert_scale = 1.0 / shared if shared else 1.0
+    h.moe_score_func = MoeScore.SIGMOID
+    h.moe_norm_topk = int(bool(cfg.get("norm_topk_prob", True)))
+    h.moe_norm_floor = 0.0  # sigmoid scores are positive: the family's sum has no floor
+
+
+def write_window_layers(out, index, header: ModelHeader, wt: int) -> None:
+    """The layers of a cohere2_moe checkpoint in the order of
+    formats/model_file._pattern_block_specs, tensor names as the family's
+    dense sibling (``cohere2``) and the routed families here name theirs. No
+    row of q or k is permuted: ``rope_gptj`` turns adjacent pairs, the
+    runtime's own convention. The shared experts are folded into ONE gated
+    FFN (gate and up rows stacked, down columns side by side), which the
+    header's ``shared_expert_scale`` turns into their average. One norm a
+    layer; the router stays F32."""
+    held = range(header.experts_held_first,
+                 header.experts_held_first + (header.experts_held_count or header.n_experts))
+    n_shared = header.shared_hidden_dim // header.moe_hidden_dim
+    for l in range(header.n_layers):
+        pre = f"model.layers.{l}"
+        for name in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            write_tensor(out, index.get(f"{pre}.self_attn.{name}.weight"), wt)
+        write_tensor(out, index.get(f"{pre}.mlp.gate.weight"), FloatType.F32)
+        for e in held:  # the experts this file holds (all of them unless told)
+            epre = f"{pre}.mlp.experts.{e}"
+            write_tensor(out, index.get(f"{epre}.up_proj.weight"), wt)  # w3
+            write_tensor(out, index.get(f"{epre}.gate_proj.weight"), wt)  # w1
+            write_tensor(out, index.get(f"{epre}.down_proj.weight"), wt)  # w2
+        if n_shared:
+            part = lambda name: [  # noqa: E731
+                index.get(f"{pre}.mlp.shared_experts.{i}.{name}.weight") for i in range(n_shared)]
+            write_tensor(out, np.concatenate(part("gate_proj"), axis=0), wt)  # w1
+            write_tensor(out, np.concatenate(part("down_proj"), axis=1), wt)  # w2
+            write_tensor(out, np.concatenate(part("up_proj"), axis=0), wt)  # w3
+        write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
 
 
 def write_ssm_layers(out, index, header: ModelHeader, wt: int) -> None:
@@ -417,6 +504,8 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
             write_latent_layers(out, index, header, wt)
         elif header.ssm_d_inner:
             write_ssm_layers(out, index, header, wt)
+        elif header.parallel_block:
+            write_window_layers(out, index, header, wt)
         elif header.layer_kinds:
             write_pattern_layers(out, index, header, wt)
         for l in range(0 if header.kv_lora_rank or header.layer_kinds else header.n_layers):  # a Llama block's layers
@@ -452,6 +541,7 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
             write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
         # lfm2_moe names its final norm after the embedding, jamba after its place
         norm_key = ("model.final_layernorm.weight" if header.ssm_d_inner
+                    else "model.norm.weight" if header.parallel_block
                     else "model.embedding_norm.weight" if header.layer_kinds
                     else "model.norm.weight")
         write_tensor(out, index.get(norm_key), FloatType.F32)

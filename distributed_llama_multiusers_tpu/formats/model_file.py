@@ -122,6 +122,25 @@ KEY_SSM_DT_RANK = 47
 KEY_SSM_CONV_KERNEL = 48
 KEY_SSM_CONV_BIAS = 49
 KEY_SSM_INNER_NORMS = 50
+# framework extension: what ``model_type: cohere2_moe`` adds to a block of
+# mixed layers, each key written only where it is set, so every file without
+# them reads, and is written, as before. A head width that is not ``dim //
+# n_heads`` (KEY_HEAD_DIM; 0: that quotient, in a Llama block's file too);
+# window attention as a layer kind (``LayerKind.WINDOW``) and the window's
+# size in positions (a query reads the ``sliding_window`` newest keys, itself
+# among them); full-context layers that do not rotate where the window layers
+# do (KEY_FULL_ATTENTION_NOPE); a norm that subtracts the mean
+# (``NormKind.LAYER``: a gain and no bias); a block whose attention and FFN
+# read ONE normed input and are added together (KEY_PARALLEL_BLOCK: one norm
+# a layer in the file); the factor on the shared experts' output, in
+# millionths (four experts averaged: one gated FFN of four times the width
+# at 0.25).
+KEY_HEAD_DIM = 51
+KEY_SLIDING_WINDOW = 52
+KEY_FULL_ATTENTION_NOPE = 53
+KEY_NORM_KIND = 54
+KEY_PARALLEL_BLOCK = 55
+KEY_SHARED_EXPERT_SCALE_E6 = 56
 
 
 class ArchType:
@@ -146,6 +165,16 @@ class LayerKind:
     ATTENTION = 0  # GQA over the KV cache ("full_attention")
     CONV = 1  # gated short convolution over a window of inputs ("conv")
     SSM = 2  # selective state-space mixer: a running sum a channel ("mamba")
+    # GQA over the newest ``sliding_window`` positions, kept in a ring
+    # ("sliding_attention"); its weights are stacked with ATTENTION's
+    WINDOW = 3
+
+
+class NormKind:
+    """What a layer's norm divides by (KEY_NORM_KIND)."""
+
+    RMS = 0  # the root mean square
+    LAYER = 1  # the standard deviation, the mean subtracted first; no bias
 
 
 class RopeType:
@@ -216,16 +245,28 @@ class ModelHeader:
     ssm_conv_kernel: int = 0
     ssm_conv_bias: int = 0
     ssm_inner_norms: int = 0
+    # what cohere2_moe adds (KEY_HEAD_DIM ...); unset elsewhere
+    head_dim: int = 0  # 0: dim // n_heads
+    sliding_window: int = 0
+    full_attention_nope: int = 0
+    norm_kind: int = NormKind.RMS
+    parallel_block: int = 0
+    shared_expert_scale: float = 1.0
     header_size: int = 0
     file_size: int = 0
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def q_dim(self) -> int:
+        """Width of a layer's queries, and of attention's output before wo."""
+        return self.n_heads * self.head_size
 
     @property
     def kv_dim(self) -> int:
-        return (self.dim * self.n_kv_heads) // self.n_heads
+        return self.n_kv_heads * self.head_size
 
     def to_kv_pairs(self) -> list[tuple[int, int]]:
         """Serializable (key, int-value) pairs, converter order (writer.py:109-130)."""
@@ -270,6 +311,12 @@ class ModelHeader:
         ) + (
             [(key, getattr(self, name)) for key, name in _SSM_INT_KEYS.items()]
             if self.ssm_d_inner else []
+        ) + [
+            (key, getattr(self, name)) for key, name in _WINDOW_INT_KEYS.items()
+            if getattr(self, name)
+        ] + (
+            [(KEY_SHARED_EXPERT_SCALE_E6, int(round(self.shared_expert_scale * 1e6)))]
+            if self.shared_expert_scale != 1.0 else []
         )
 
 
@@ -304,6 +351,13 @@ _SSM_INT_KEYS = {
     KEY_SSM_CONV_BIAS: "ssm_conv_bias",
     KEY_SSM_INNER_NORMS: "ssm_inner_norms",
 }
+_WINDOW_INT_KEYS = {
+    KEY_HEAD_DIM: "head_dim",
+    KEY_SLIDING_WINDOW: "sliding_window",
+    KEY_FULL_ATTENTION_NOPE: "full_attention_nope",
+    KEY_NORM_KIND: "norm_kind",
+    KEY_PARALLEL_BLOCK: "parallel_block",
+}
 _YARN_E6_KEYS = {KEY_ROPE_YARN_MSCALE_ALL_DIM_E6: "rope_yarn_mscale_all_dim"}
 
 
@@ -319,6 +373,8 @@ LATENT_FIELDS = (
 )
 # every header field of a selective state-space mixer, as models/config.py takes them
 SSM_FIELDS = tuple(_SSM_INT_KEYS.values())
+# every header field cohere2_moe added, as models/config.py takes them
+WINDOW_FIELDS = (*_WINDOW_INT_KEYS.values(), "shared_expert_scale")
 
 
 def write_model_header(f: BinaryIO, header: ModelHeader) -> int:
@@ -406,6 +462,10 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 h.qk_norm = value
             elif key in _SSM_INT_KEYS:
                 setattr(h, _SSM_INT_KEYS[key], value)
+            elif key in _WINDOW_INT_KEYS:
+                setattr(h, _WINDOW_INT_KEYS[key], value)
+            elif key == KEY_SHARED_EXPERT_SCALE_E6:
+                h.shared_expert_scale = value / 1e6
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
@@ -418,6 +478,8 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
             raise ValueError(
                 "a state-space layer needs ssm_d_inner, ssm_d_state, ssm_dt_rank "
                 "and ssm_conv_kernel >= 2")
+        if LayerKind.WINDOW in h.layer_kinds and h.sliding_window < 1:
+            raise ValueError("a window layer needs sliding_window >= 1")
         h.header_size = header_size
         h.orig_seq_len = h.seq_len
         if max_seq_len > 0 and h.seq_len > max_seq_len:
@@ -460,22 +522,23 @@ def model_tensor_specs(h: ModelHeader) -> list[TensorSpec]:
 
     wt = h.weight_type
     dim, hidden, kv_dim, vocab = h.dim, h.hidden_dim, h.kv_dim, h.vocab_size
+    q_dim = h.q_dim
     add("embedding", 0, FloatType.F32, (vocab, dim))
     if h.kv_lora_rank:
         _latent_block_specs(h, add)
     elif h.layer_kinds:
         _pattern_block_specs(h, add)
     for l in range(0 if h.kv_lora_rank or h.layer_kinds else h.n_layers):  # a Llama block's layers
-        add("block_matmul_q", l, wt, (dim, dim))
+        add("block_matmul_q", l, wt, (q_dim, dim))
         if h.qkv_bias:
-            add("block_bias_q", l, FloatType.F32, (1, dim))
+            add("block_bias_q", l, FloatType.F32, (1, q_dim))
         add("block_matmul_k", l, wt, (kv_dim, dim))
         if h.qkv_bias:
             add("block_bias_k", l, FloatType.F32, (1, kv_dim))
         add("block_matmul_v", l, wt, (kv_dim, dim))
         if h.qkv_bias:
             add("block_bias_v", l, FloatType.F32, (1, kv_dim))
-        add("block_matmul_wo", l, wt, (dim, dim))
+        add("block_matmul_wo", l, wt, (dim, q_dim))
         if h.n_experts > 0:
             add("block_moe_gate", l, FloatType.F32, (h.n_experts, dim))
             for e in range(h.n_experts):
@@ -557,9 +620,10 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
     (``model_type: lfm2_moe``), a framework extension. A conv layer:
     ``conv_in`` (3 x dim rows: the gate B, the gate C, the input x, in that
     order), the taps (F32, ``[dim, conv_kernel]``), ``conv_out``. An attention
-    layer: q, k, v (rows permuted to the interleaved-pair layout, as a Llama
-    file's), their per-head norm gains (F32, permuted alike, where
-    ``qk_norm``), wo. A state-space layer (``model_type: jamba``): ``ssm_in``
+    layer, full-context or window (``model_type: cohere2_moe``): q, k, v (rows
+    permuted to the interleaved-pair layout, as a Llama file's; ``n_heads *
+    head_size`` rows of q), their per-head norm gains (F32, permuted alike,
+    where ``qk_norm``), wo. A state-space layer (``model_type: jamba``): ``ssm_in``
     (2 x ``ssm_d_inner`` rows: the input x, the gate z, in that order), the
     conv's taps (F32, ``[ssm_d_inner, ssm_conv_kernel]``) and its bias (F32,
     where the header says so), ``ssm_x`` (``ssm_dt_rank`` + 2 x
@@ -568,8 +632,9 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
     exponential, all F32: ``dt_proj`` ``[ssm_d_inner, ssm_dt_rank]`` and its
     bias, ``A_log`` ``[ssm_d_inner, ssm_d_state]``, ``D``; then ``ssm_out``.
     Then a dense FFN in the first ``n_dense_layers`` layers (every layer
-    where there are no experts) and a routed one in the others; then the two
-    norms."""
+    where there are no experts) and a routed one in the others, with the
+    shared experts as one gated FFN where the header has them; then the two
+    norms (one where ``parallel_block``)."""
     wt, dim, kv_dim = h.weight_type, h.dim, h.kv_dim
     e, n, r = h.ssm_d_inner, h.ssm_d_state, h.ssm_dt_rank
     for l, kind in enumerate(h.layer_kinds):
@@ -592,14 +657,14 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
             add("block_ssm_a_log", l, FloatType.F32, (e, n))
             add("block_ssm_d", l, FloatType.F32, (1, e))
             add("block_matmul_ssm_out", l, wt, (dim, e))
-        elif kind == LayerKind.ATTENTION:
-            add("block_matmul_q", l, wt, (dim, dim))
+        elif kind in (LayerKind.ATTENTION, LayerKind.WINDOW):
+            add("block_matmul_q", l, wt, (h.q_dim, dim))
             add("block_matmul_k", l, wt, (kv_dim, dim))
             add("block_matmul_v", l, wt, (kv_dim, dim))
             if h.qk_norm:
                 add("block_q_norm", l, FloatType.F32, (1, h.head_size))
                 add("block_k_norm", l, FloatType.F32, (1, h.head_size))
-            add("block_matmul_wo", l, wt, (dim, dim))
+            add("block_matmul_wo", l, wt, (dim, h.q_dim))
         else:
             raise ValueError(f"layer {l}: unknown layer kind {kind}")
         if l < h.n_dense_layers or h.n_experts == 0:
@@ -608,8 +673,13 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
             add("block_matmul_w3", l, wt, (h.hidden_dim, dim))
         else:
             _routed_ffn_specs(h, add, l)
+            if h.shared_hidden_dim:
+                add("block_matmul_shared_w1", l, wt, (h.shared_hidden_dim, dim))
+                add("block_matmul_shared_w2", l, wt, (dim, h.shared_hidden_dim))
+                add("block_matmul_shared_w3", l, wt, (h.shared_hidden_dim, dim))
         add("block_rms_norm_0", l, FloatType.F32, (1, dim))
-        add("block_rms_norm_1", l, FloatType.F32, (1, dim))
+        if not h.parallel_block:  # one norm feeds both halves there
+            add("block_rms_norm_1", l, FloatType.F32, (1, dim))
 
 
 def iter_model_tensors(path: str, header: ModelHeader) -> Iterator[tuple[TensorSpec, np.ndarray]]:

@@ -16,6 +16,7 @@ from .model_file import (
     LayerKind,
     ModelHeader,
     MoeScore,
+    NormKind,
     RopeType,
     model_tensor_specs,
     write_model_header,
@@ -107,6 +108,31 @@ def tiny_ssm_header(
     return h
 
 
+def tiny_window_header(
+    pattern: str = "WWWF",
+    sliding_window: int = 8,
+    head_dim: int = 16,
+    n_experts: int = 8,
+    experts_held: tuple = (0, 0),
+    **kw,
+) -> ModelHeader:
+    """A toy of what ``cohere2_moe`` adds (models/hybrid.py): ``pattern`` a
+    letter a layer, ``W`` window attention that rotates, ``F`` full-context
+    attention that does not; heads of ``head_dim`` on a narrower stream, a
+    mean-subtracting norm, a parallel block, every layer routed (sigmoid
+    scores) beside two shared experts averaged."""
+    kw = {"dim": 32, "hidden_dim": 64, "n_heads": 4, "n_kv_heads": 2, **kw}
+    h = tiny_header(n_layers=len(pattern), rope_theta=50000.0, **kw)
+    h.layer_kinds = [LayerKind.WINDOW if c == "W" else LayerKind.ATTENTION for c in pattern]
+    h.head_dim, h.sliding_window, h.full_attention_nope = head_dim, sliding_window, 1
+    h.norm_kind, h.parallel_block = NormKind.LAYER, 1
+    h.n_experts, h.n_active_experts, h.moe_hidden_dim = n_experts, 2, 32
+    h.shared_hidden_dim, h.shared_expert_scale = 2 * 32, 0.5
+    h.moe_score_func, h.moe_norm_topk = MoeScore.SIGMOID, 1
+    h.experts_held_first, h.experts_held_count = experts_held
+    return h
+
+
 def ssm_steering_init(name: str, shape, rng) -> np.ndarray | None:
     """What steers a state-space layer's exponential, as the mixer's own
     published initialisation draws it (it decides how long the state
@@ -177,9 +203,10 @@ def write_synthetic_model(path: str, header: ModelHeader, seed: int = 0, scale: 
     def rand(shape):
         return rng.standard_normal(shape, dtype=np.float32) * scale
 
-    if header.layer_kinds or header.kv_lora_rank:
-        # a layer pattern's or a latent block's file: the walk itself says
-        # what to write
+    if header.layer_kinds or header.kv_lora_rank or header.head_dim:
+        # a layer pattern's or a latent block's file, or a Llama block's
+        # whose heads are not dim // n_heads wide: the walk itself says what
+        # to write
         with open(path, "wb") as f:
             header.header_size = write_model_header(f, header)
             for spec in model_tensor_specs(header):

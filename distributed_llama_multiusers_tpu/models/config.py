@@ -7,10 +7,12 @@ from dataclasses import dataclass
 from ..formats.model_file import (
     LATENT_FIELDS,
     SSM_FIELDS,
+    WINDOW_FIELDS,
     HiddenAct,
     LayerKind,
     ModelHeader,
     MoeScore,
+    NormKind,
     RopeType,
 )
 
@@ -107,6 +109,22 @@ class LlamaConfig:
     ssm_conv_kernel: int = 0
     ssm_conv_bias: int = 0
     ssm_inner_norms: int = 0
+    # What ``model_type: cohere2_moe`` adds, each engaged by its own field.
+    # head_dim: a head's width where it is not dim // n_heads (0: that
+    # quotient), in either block. LayerKind.WINDOW among the kinds: GQA over
+    # the newest sliding_window positions (the query's own among them), kept
+    # in a ring (models/hybrid.py); full_attention_nope: the full-context
+    # layers rotate nothing while the window layers do. norm_kind
+    # (formats.model_file.NormKind): every norm of the block subtracts the
+    # mean (a gain, no bias). parallel_block: attention and the routed FFN
+    # read ONE normed input a layer and are added to the stream together.
+    # shared_expert_scale: the factor on the shared experts' output.
+    head_dim: int = 0
+    sliding_window: int = 0
+    full_attention_nope: int = 0
+    norm_kind: int = NormKind.RMS
+    parallel_block: int = 0
+    shared_expert_scale: float = 1.0
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -172,6 +190,15 @@ class LlamaConfig:
                     "a routed layer pattern needs moe_hidden_dim and "
                     "0 <= n_dense_layers <= n_layers"
                 )
+            if self.n_window_layers and self.sliding_window < 1:
+                raise ValueError("a window layer needs sliding_window >= 1")
+        if self.parallel_block and not (
+            self.layer_kinds and self.n_routed_layers == self.n_layers
+            and not self.n_conv_layers and not self.n_ssm_layers
+        ):
+            raise ValueError(
+                "a parallel block is a layer pattern of attention layers "
+                "(full-context or window) whose every layer routes (n_dense_layers 0)")
 
     @property
     def n_conv_layers(self) -> int:
@@ -182,16 +209,23 @@ class LlamaConfig:
         return sum(k == LayerKind.SSM for k in self.layer_kinds)
 
     @property
+    def n_window_layers(self) -> int:
+        return sum(k == LayerKind.WINDOW for k in self.layer_kinds)
+
+    @property
     def n_attention_layers(self) -> int:
-        """Layers that keep keys and values: what the KV stack holds."""
-        return self.n_layers - self.n_conv_layers - self.n_ssm_layers
+        """Layers that keep every position's keys and values: what the KV
+        stack holds (a window layer keeps a ring of its own)."""
+        return (self.n_layers - self.n_conv_layers - self.n_ssm_layers
+                - self.n_window_layers)
 
     @property
     def recurrent_state(self) -> bool:
         """Whether a lane carries state that is overwritten in place, beside
         what the cache keeps by position: nothing that rewinds a lane or
-        copies one at another position than its last holds for it."""
-        return self.n_conv_layers > 0 or self.n_ssm_layers > 0
+        copies one at another position than its last holds for it. A window
+        layer's ring is such a state."""
+        return self.n_conv_layers > 0 or self.n_ssm_layers > 0 or self.n_window_layers > 0
 
     @property
     def n_routed_layers(self) -> int:
@@ -229,7 +263,12 @@ class LlamaConfig:
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def q_dim(self) -> int:
+        """Width of a layer's queries, and of attention's output before wo."""
+        return self.n_heads * self.head_size
 
     @property
     def rope_dim(self) -> int:
@@ -239,7 +278,7 @@ class LlamaConfig:
 
     @property
     def kv_dim(self) -> int:
-        return (self.dim * self.n_kv_heads) // self.n_heads
+        return self.n_kv_heads * self.head_size
 
     @staticmethod
     def from_header(h: ModelHeader) -> "LlamaConfig":
@@ -267,4 +306,5 @@ class LlamaConfig:
             conv_kernel=h.conv_kernel,
             qk_norm=h.qk_norm,
             **{name: getattr(h, name) for name in SSM_FIELDS},
+            **{name: getattr(h, name) for name in WINDOW_FIELDS},
         )

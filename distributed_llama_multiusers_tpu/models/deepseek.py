@@ -107,7 +107,7 @@ from ..ops.linear import (
     pallas_w_dtype_kw,
     reads_q40_stack,
 )
-from ..ops.norm import rms_norm
+from ..ops.norm import layer_norm, rms_norm
 from ..ops.pallas_q40_grouped import (
     grouped_matmul_xla,
     grouped_supports,
@@ -354,13 +354,6 @@ QUERY_BLOCK = 256
 SCORE_BLOCK_ELEMENTS = 1 << 26
 
 
-def _layer_norm(x, gain, bias, eps):
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps) * gain + bias).astype(x.dtype)
-
-
 def _rope_first(x, n: int, cos, sin, positions):
     """The rotary embedding on the first ``n`` numbers of every head of ``x``
     ``[B, T, H, D]``, the rest as they are."""
@@ -530,19 +523,22 @@ def dense_ffn(cfg: LlamaConfig, ops: FfnOps, x, dp: "DenseFfnParams"):
         return x + ops.maybe_qdq(gated_ffn(ops, ops.maybe_qdq(y), dp.w1, dp.w2, dp.w3))
 
 
-def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live):
+def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live, normed=None):
     """A routed layer's FFN half. ``rp``: the layer's parameters, the expert
     stacks whole; ``lm`` its index into them; ``live`` ``[B * T]``: False for
     a parked row, which routes nowhere. Returns (x, slabs, assignments,
     unheld): distinct slabs fetched, (live row, expert) pairs that fetched
     one, and pairs whose expert lies outside the held share
     (``cfg.experts_held``): those fetch nothing and add nothing, exactly as a
-    parked row's do, and their weight stays in the renormalising sum."""
+    parked row's do, and their weight stays in the renormalising sum.
+    ``normed`` (a parallel block, ``cfg.parallel_block``): the layer's one
+    normed input; the FFN's TERM is returned in the place of ``x``, for the
+    caller to add beside attention's."""
     b, t, _ = x.shape
     n = b * t
     dtype = x.dtype
     with jax.named_scope(SCOPE_FFN):
-        y = rms_norm(x, rp.rms_ffn, cfg.norm_epsilon)
+        y = rms_norm(x, rp.rms_ffn, cfg.norm_epsilon) if normed is None else normed
         yq = ops.maybe_qdq(y)
         with jax.named_scope(SCOPE_ROUTER):
             topw, topi = moe_router(cfg, y.reshape(n, -1), rp.gate, rp.bias)
@@ -570,8 +566,12 @@ def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live
             out = routed.reshape(b, t, -1)
         if rp.s1 is not None:
             with jax.named_scope(SCOPE_SHARED_EXPERT):
-                out = out + gated_ffn(ops, yq, rp.s1, rp.s2, rp.s3)
-        x = x + ops.maybe_qdq(out.astype(dtype))
+                shared = gated_ffn(ops, yq, rp.s1, rp.s2, rp.s3)
+                if cfg.shared_expert_scale != 1.0:  # averaged shared experts
+                    shared = shared * cfg.shared_expert_scale
+                out = out + shared
+        out = ops.maybe_qdq(out.astype(dtype))
+        x = x + out if normed is None else out
     return x, plan.slabs, fetched, unheld
 
 
@@ -662,7 +662,7 @@ def deepseek_forward_counted(
                     qi = matmul(cq, ap.idx_wq).reshape(
                         b, t, cfg.index_n_heads, cfg.index_head_dim)
                     qi = _rope_first(qi, rope, cos, sin, positions)
-                    ki = _layer_norm(matmul(yq, ap.idx_wk), ap.idx_k_gain, ap.idx_k_bias, eps)
+                    ki = layer_norm(matmul(yq, ap.idx_wk), ap.idx_k_gain, ap.idx_k_bias, eps)
                     ki = _rope_first(ki[:, :, None], rope, cos, sin, positions)[:, :, 0]
                     # float32 like the router: the weights rank positions
                     w = jnp.einsum(

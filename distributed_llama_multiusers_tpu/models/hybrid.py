@@ -1,7 +1,8 @@
 """A block whose layers differ in their mixer (``model_type: lfm2_moe``,
-``jamba``): gated short convolutions, selective state-space mixers and GQA
-attention in a published per-layer pattern, leading dense FFNs, then routed
-ones (or dense ones throughout). Assembled from the parts of the other two
+``jamba``, ``cohere2_moe``): gated short convolutions, selective state-space
+mixers, GQA attention over the whole context and over a window of it, in a
+published per-layer pattern, leading dense FFNs, then routed ones (or dense
+ones throughout). Assembled from the parts of the other two
 blocks, with ``llama_forward``'s signature: the GQA projection, append and
 plane attention are ``models/llama.py``'s, the router, the route plan, the
 grouped kernel and the gated FFN ``models/deepseek.py``'s, the state-space
@@ -22,13 +23,23 @@ The layer (``h`` the stream, ``K = conv_kernel``):
                 S_t = exp(D_t (x) A) * S_{t-1} + (D_t * u_t) (x) B_t    S_{-1} = 0
                 h' = h + W_out ((S_t C_t + D * u_t) * silu(z_t))
     attention:  q, k normed per head (where ``qk_norm``), rotated (unless
-                ``rope_type`` NONE), GQA over the cache
-                h' = h + Wo o
+                ``rope_type`` NONE, or ``full_attention_nope``: then the
+                window layers alone rotate), GQA over the cache: ``n_heads``
+                heads of ``head_size`` (``head_dim``, else ``dim // n_heads``)
+                o(t) = sum over s <= t of softmax_s(q(t) . k(s) / sqrt(head_size)) v(s)
+                h' = h + Wo o                 Wo: n_heads * head_size -> dim
+    window:     the same with the sum over s in (t - W, t], W = ``sliding_window``
     FFN:        dense in the first ``n_dense_layers`` layers, routed in the others
+    ``norm_kind`` LAYER: every ``rmsnorm`` above is g * (h - mean h) / sqrt(var h + eps).
+    ``parallel_block``: ONE norm a layer, n = norm(h, g), feeds the mixer and
+                the routed FFN (shared experts scaled by ``shared_expert_scale``),
+                h' = h + Wo o + ffn(n)
 
 The state. Each kind of layer keeps its own stack, indexed by the count of
 that kind: ``k`` and ``v`` ``[attention layers, lanes, S, n_kv * head]`` (no
-plane for a conv layer), and the conv layers' window of inputs
+plane for a conv layer), the window layers' ring ``wk`` and ``wv`` ``[window
+layers, lanes, R, n_kv * head]`` with ``R < S`` (None in a block without such
+layers), and the conv layers' window of inputs
 ``[conv layers, lanes, (K-1) * dim]``: a lane's last ``K - 1`` rows of ``u``;
 and, where the block has state-space layers, their running sum ``ssm``
 ``[SSM layers, lanes, N * E]``, FLOAT32 whatever the cache's type, and their
@@ -60,6 +71,20 @@ them unchanged and the step leaves the state AFTER ROW ``a - 1`` (the state it
 read where ``a = 0``); a step whose first position is 0 reads ``S = 0`` and a
 zero window; a second chunk continues the first exactly.
 
+One rule for the ring (``ops/blocked_attention.py`` ``held_position``),
+beside those two. ``R`` is the window plus the largest step of more than one
+row a lane (the largest prefill bucket), in whole decode blocks (``ring_rows``:
+4096 + 512 = 4608 = 18 blocks of 256), so that a chunk's last row never lands
+on a row its first query still reads. Position ``p`` lives in row ``p mod R``;
+a reader at position ``t`` takes row ``r`` to hold the largest ``p <= t``
+congruent to ``r``, and reads it only where ``t - W < p`` and ``p >= 0``: by
+arithmetic, never by what the row contains, so nothing is cleared when a lane
+is given to a new request. A row at or past ``a`` (``n_valid``), or past the
+context, writes nothing: a parked lane's ring is untouched, a bucket's padded
+tail is ignored, and a second chunk continues the first exactly. Like the
+other two the ring is overwritten in place: a lane cannot be rewound past
+``R - W`` rows, and a copy of a lane is its ring at its LAST position.
+
 The layers. In a routed model the leading dense layers run first, unrolled;
 the others (every layer of a model without routed ones, its dense FFNs read
 by layer) run as one ``lax.scan`` over whole periods of their kinds (a run of
@@ -77,7 +102,12 @@ or f8 cache and a context that is not whole blocks take XLA's dense path over
 the layer's whole plane (``llama.decode_attention_engages`` decides, from the
 inputs). Dense, the five planes of a 64-lane step were 1.34 GB read and
 converted whatever the lanes held: 10.2 of a 28 ms decode step on a v5e
-(PERF.md section 6, PR 36).
+(PERF.md section 6, PR 36). A window layer's read at one row a lane is the
+same kernel over its ring with a work list of its own (``ring_blocks``: the
+blocks that hold ``(pos - W, pos]``), under ``dl.window_attention``. At more
+rows a lane, where dense scores ``[B, T, heads, rows]`` would pass
+``blocked_attention.DENSE_SCORE_BYTES``, either kind is computed a key block
+at a time over the blocks the mask admits (ops/blocked_attention.py).
 """
 
 from __future__ import annotations
@@ -88,10 +118,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout
 
-from ..formats.model_file import LayerKind
-from ..ops import pallas_attention
-from ..ops.linear import matmul, pallas_interpret
-from ..ops.norm import rms_norm
+from ..formats.model_file import LayerKind, NormKind
+from ..ops import blocked_attention, pallas_attention
+from ..ops.linear import matmul, pallas_interpret, pallas_kernel_active
+from ..ops.norm import layer_norm, rms_norm
 from ..ops.ssm_scan import state_step
 from ..quants.packed import PackedQ40, Q40Experts
 from ..telemetry.names import (
@@ -105,6 +135,7 @@ from ..telemetry.names import (
     SCOPE_LAYERS,
     SCOPE_QKV,
     SCOPE_SSM,
+    SCOPE_WINDOW_ATTENTION,
 )
 from .config import LlamaConfig
 from .deepseek import (
@@ -125,12 +156,13 @@ from .llama import (
 
 
 class GqaParams(NamedTuple):
-    """The attention layers' weights, stacked ``[attention layers, ...]``."""
+    """The attention layers' weights, full-context and window layers in one
+    stack in layer order, ``[La, ...]``."""
 
-    wq: jnp.ndarray  # [La, dim, dim]
-    wk: jnp.ndarray  # [La, dim, kv_dim]
+    wq: jnp.ndarray  # [La, dim, n_heads * head_size]
+    wk: jnp.ndarray  # [La, dim, n_kv * head_size]
     wv: jnp.ndarray
-    wo: jnp.ndarray  # [La, dim, dim]
+    wo: jnp.ndarray  # [La, n_heads * head_size, dim]
     q_norm: jnp.ndarray | None  # [La, head] f32 (config.qk_norm)
     k_norm: jnp.ndarray | None
     rms: jnp.ndarray  # [La, dim]: the layer's operator norm
@@ -187,11 +219,28 @@ class HybridCache(NamedTuple):
     # whatever the cache's type, and the conv's last K'-1 inputs
     ssm: jnp.ndarray | None = None  # [Ls, lanes, N * E]
     ssm_conv: jnp.ndarray | None = None  # [Ls, lanes, (K'-1) * E]
+    # window layers only (None elsewhere): their keys' and values' ring
+    wk: jnp.ndarray | None = None  # [Lw, lanes, R, n_kv * head]
+    wv: jnp.ndarray | None = None
 
 
-def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32) -> HybridCache:
+def ring_rows(config: LlamaConfig, max_chunk: int) -> int:
+    """Rows of a window layer's ring: the window plus the widest step of more
+    than one row a lane, in whole decode blocks where the context is (module
+    header); never more than the context."""
+    unit = pallas_attention.BLOCK_ROWS if config.seq_len % pallas_attention.BLOCK_ROWS == 0 else 1
+    return min(-(-(config.sliding_window + max_chunk) // unit) * unit, config.seq_len)
+
+
+def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
+                      max_chunk: int = 1) -> HybridCache:
+    """``max_chunk``: the most rows a lane any step writes (the largest prefill
+    bucket): it sizes the ring (``ring_rows``)."""
     kv = (config.n_attention_layers, n_lanes, config.seq_len, config.kv_dim)
-    ssm = ssm_conv = None
+    ssm = ssm_conv = wk = wv = None
+    if config.n_window_layers:
+        ring = (config.n_window_layers, n_lanes, ring_rows(config, max_chunk), config.kv_dim)
+        wk, wv = jnp.zeros(ring, dtype), jnp.zeros(ring, dtype)
     if config.n_ssm_layers:
         ls, e = config.n_ssm_layers, config.ssm_d_inner
         ssm = jnp.zeros((ls, n_lanes, config.ssm_d_state * e), jnp.float32)
@@ -200,7 +249,7 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32) -> H
         k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
         conv=jnp.zeros(
             (config.n_conv_layers, n_lanes, max(config.conv_kernel - 1, 0) * config.dim), dtype),
-        ssm=ssm, ssm_conv=ssm_conv,
+        ssm=ssm, ssm_conv=ssm_conv, wk=wk, wv=wv,
     )
 
 
@@ -209,7 +258,17 @@ def state_leaves(cache) -> tuple:
     position: a lane's recurrent state (none for any other cache)."""
     if not isinstance(cache, HybridCache):
         return ()
-    return tuple(x for x in (cache.conv, cache.ssm, cache.ssm_conv) if x is not None)
+    return tuple(x for x in (cache.conv, cache.ssm, cache.ssm_conv, cache.wk, cache.wv)
+                 if x is not None)
+
+
+def ring_attention_engages(cache, mesh, n_heads: int, n_kv: int) -> bool:
+    """``llama.decode_attention_engages`` for a window layer's ring: whether a
+    step of one row a lane reads it in place through the decode kernel."""
+    return (
+        getattr(cache, "wk", None) is not None and mesh is None
+        and pallas_kernel_active() and pallas_attention.supports(cache.wk, n_heads, n_kv)
+    )
 
 
 def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
@@ -242,7 +301,8 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
         routed = RoutedFfnParams(
             gate=t["moe_gate"], bias=t.get("moe_bias"),
             w1=experts(t["w1"]), w2=experts(t["w2"]), w3=experts(t["w3"]),
-            s1=None, s2=None, s3=None, rms_ffn=t["rms_ffn"],
+            s1=t.get("shared_w1"), s2=t.get("shared_w2"), s3=t.get("shared_w3"),
+            rms_ffn=t.get("rms_ffn"),  # none in a parallel block: the mixer's norm feeds it
         )
     return HybridParams(
         embedding=t["embedding"], attn=attn, conv=conv, dense=dense, routed=routed,
@@ -291,9 +351,14 @@ def kind_runs(kinds: tuple) -> list:
     return runs
 
 
+# a layer kind's place in the counts a layer is read by
+KIND_SLOTS = (LayerKind.ATTENTION, LayerKind.CONV, LayerKind.SSM, LayerKind.WINDOW)
+
+
 def kinds_after(kind, nth: tuple) -> tuple:
-    """The (attention, conv, state-space) counts after one more layer of ``kind``."""
-    slot = {LayerKind.ATTENTION: 0, LayerKind.CONV: 1, LayerKind.SSM: 2}[kind]
+    """The (attention, conv, state-space, window) counts after one more layer
+    of ``kind``."""
+    slot = KIND_SLOTS.index(kind)
     return tuple(n + (k == slot) for k, n in enumerate(nth))
 
 
@@ -331,6 +396,10 @@ def hybrid_forward_counted(
     k_taps = cfg.conv_kernel
     ops = ffn_ops(cfg, emulate_q80_activations)
     maybe_qdq = ops.maybe_qdq
+    if cfg.norm_kind == NormKind.LAYER:
+        norm = lambda x, g: layer_norm(x, g, None, eps)  # noqa: E731
+    else:
+        norm = lambda x, g: rms_norm(x, g, eps)  # noqa: E731
 
     with jax.named_scope(SCOPE_EMBED):
         x = params.embedding[tokens]
@@ -342,44 +411,85 @@ def hybrid_forward_counted(
         n_valid = jnp.sum(in_context, axis=1).astype(jnp.int32)
     # one row a lane: the K/V stack is attended in place (module header)
     in_place = t == 1 and decode_attention_engages(cache, mesh, cfg.n_heads, cfg.n_kv_heads)
+    window, ring = cfg.sliding_window, 0 if cache.wk is None else cache.wk.shape[2]
+    ring_in_place = t == 1 and ring_attention_engages(cache, mesh, cfg.n_heads, cfg.n_kv_heads)
+    # more rows a lane against many keys: a key block at a time (module header)
+    plane_blocked = blocked_attention.engages(b, t, cfg.n_heads, cfg.seq_len)
+    ring_blocked = blocked_attention.engages(b, t, cfg.n_heads, ring)
     with jax.named_scope(SCOPE_ATTENTION):
-        s_idx = jnp.arange(cfg.seq_len)
-        attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
         if in_place:
             attn_plan = pallas_attention.lane_blocks(positions, cfg.seq_len)
+        elif not plane_blocked:
+            s_idx = jnp.arange(cfg.seq_len)
+            attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
+        if ring_in_place:
+            ring_plan = pallas_attention.ring_blocks(positions, cfg.seq_len, window, ring)
+        elif ring and not ring_blocked:
+            ring_mask = blocked_attention.ring_mask(positions, ring, window)  # [B, T, R]
     row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
     scale = 1.0 / float(cfg.head_size) ** 0.5
     from_zero = (positions[:, :1] == 0)[:, :, None]  # [B, 1, 1]
     # rows a state may absorb: the first n_valid of a lane (module header)
     real_row = (jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None])[:, :, None]
+    if ring:
+        # where a row's key and value go in a ring: position mod R; past the
+        # ring (dropped) for a row that is not real or lies past the context
+        ring_at = jnp.where(real_row[:, :, 0] & in_context, positions % ring, ring)
 
-    def attention(x, ai, k_all, v_all):
+    def attention(x, ai, ci, k_all, v_all, windowed=False):
+        """A full-context layer's or a window layer's attention half: weights
+        at ``ai``, the kind's cache stack at ``ci``. Returns the stream with
+        the half added (a parallel block: the half's term alone), the normed
+        input, and the two stacks."""
         ap = GqaParams(*(_pick(leaf, ai) for leaf in params.attn))
+        rotate = windowed or not cfg.full_attention_nope
         with jax.named_scope(SCOPE_QKV):
-            y = rms_norm(x, ap.rms, eps)
+            y = norm(x, ap.rms)
             yq = maybe_qdq(y)
             q, k, v = gqa_project(
-                cfg, yq, ap.wq, ap.wk, ap.wv, positions, params.rope_cos, params.rope_sin,
+                cfg, yq, ap.wq, ap.wk, ap.wv, positions,
+                params.rope_cos if rotate else None, params.rope_sin if rotate else None,
                 norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None,
             )
         with jax.named_scope(SCOPE_KV_WRITE):
             k_all, v_all = kv_append(
-                k_all, v_all, (ai, lane_idx, positions),
+                k_all, v_all, (ci, lane_idx, ring_at if windowed else positions),
                 k.reshape(b, t, cfg.kv_dim), v.reshape(b, t, cfg.kv_dim), row_major)
         with jax.named_scope(SCOPE_ATTENTION):
-            if in_place:
+            if windowed:
+                with jax.named_scope(SCOPE_WINDOW_ATTENTION):
+                    attn = window_attention(q, ci, k_all, v_all)
+            elif in_place:
                 # the kernel fetches each lane's rows [0, pos] of attention
                 # layer ai out of the carry, AFTER the append
                 attn = pallas_attention.decode_attention(
-                    q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, ai,
+                    q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, ci,
                     attn_plan, scale, interpret=pallas_interpret())
+            elif plane_blocked:
+                attn = blocked_attention.blocked_attention(
+                    q, k_all, v_all, ci, positions, n_valid, cfg.n_kv_heads, scale)
             else:
                 attn = dense_plane_attention(
-                    q, k_all, v_all, ai, attn_mask, scale, cfg.n_kv_heads)
-            attn = attn.reshape(b, t, cfg.dim).astype(dtype)
+                    q, k_all, v_all, ci, attn_mask, scale, cfg.n_kv_heads)
+            attn = attn.reshape(b, t, cfg.q_dim).astype(dtype)
         with jax.named_scope(SCOPE_ATTN_OUT):
-            x = x + maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
-        return x, k_all, v_all
+            out = maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
+            if not cfg.parallel_block:
+                out = x + out
+        return out, y, k_all, v_all
+
+    def window_attention(q, wi, k_all, v_all):
+        """Window layer ``wi``'s read of its ring, after the append: the
+        decode kernel over the blocks that hold ``(pos - W, pos]``, a key
+        block at a time, or dense under the ring's mask (module header)."""
+        if ring_in_place:
+            return pallas_attention.decode_attention(
+                q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, wi,
+                ring_plan, scale, interpret=pallas_interpret())
+        if ring_blocked:
+            return blocked_attention.blocked_attention(
+                q, k_all, v_all, wi, positions, n_valid, cfg.n_kv_heads, scale, window=window)
+        return dense_plane_attention(q, k_all, v_all, wi, ring_mask, scale, cfg.n_kv_heads)
 
     def window_step(w_all, wi, u, taps_k: int):
         """Layer ``wi``'s window of inputs read (zeros where the step starts
@@ -395,7 +505,7 @@ def hybrid_forward_counted(
     def conv(x, ci, s_all):
         cp = ConvParams(*(_pick(leaf, ci) for leaf in params.conv))
         with jax.named_scope(SCOPE_CONV):
-            y = rms_norm(x, cp.rms, eps)
+            y = norm(x, cp.rms)
             bcx = matmul(maybe_qdq(y), cp.w_in)  # [B, T, 3 * dim]
             gate_b, gate_c, xin = jnp.split(bcx, 3, axis=-1)
             u = gate_b * xin
@@ -410,7 +520,7 @@ def hybrid_forward_counted(
         n_state, rank = cfg.ssm_d_state, cfg.ssm_dt_rank
         f32 = jnp.float32
         with jax.named_scope(SCOPE_SSM):
-            y = rms_norm(x, sp.rms, eps)
+            y = norm(x, sp.rms)
             xin, z = jnp.split(matmul(maybe_qdq(y), sp.w_in), 2, axis=-1)  # [B, T, E] each
             window, win_all = window_step(win_all, si, xin, cfg.ssm_conv_kernel)
             c = short_conv(window, sp.taps, t)
@@ -437,31 +547,45 @@ def hybrid_forward_counted(
 
     # the carry: the stream, then every state stack (a kind the block lacks
     # is None and no leaf), then a routed model's two counts
-    def mixer(kind, carry, ai, ci, si):
-        x, k_all, v_all, s_all, ssm_all, win_all, *counts = carry
+    def mixer(kind, carry, ai, ci, si, wi):
+        """The layer's mixer on the carry; with it, in a parallel block, the
+        mixer's term and the normed input it read (else None)."""
+        x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, *counts = carry
+        parallel = None
         if kind == LayerKind.CONV:
             x, s_all = conv(x, ci, s_all)
         elif kind == LayerKind.SSM:
             x, ssm_all, win_all = ssm(x, si, ssm_all, win_all)
         else:
-            x, k_all, v_all = attention(x, ai, k_all, v_all)
-        return (x, k_all, v_all, s_all, ssm_all, win_all, *counts)
+            # both kinds of attention layer: one stack of weights, in layer
+            # order, and a stack of cache a kind
+            at = ai + wi if cfg.n_window_layers else ai
+            if kind == LayerKind.WINDOW:
+                out, normed, wk_all, wv_all = attention(x, at, wi, wk_all, wv_all, True)
+            else:
+                out, normed, k_all, v_all = attention(x, at, ai, k_all, v_all)
+            x, parallel = (x, (out, normed)) if cfg.parallel_block else (out, None)
+        return (x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, *counts), parallel
 
     def kinds_before(lo, hi):
-        """(attention, conv, state-space) layers among ``kinds[lo:hi]``."""
-        n_conv = sum(k == LayerKind.CONV for k in kinds[lo:hi])
-        n_ssm = sum(k == LayerKind.SSM for k in kinds[lo:hi])
-        return (hi - lo) - n_conv - n_ssm, n_conv, n_ssm
+        """(attention, conv, state-space, window) layers among ``kinds[lo:hi]``."""
+        return tuple(sum(k == slot for k in kinds[lo:hi]) for slot in KIND_SLOTS)
 
     def dense_layer(kind, carry, nth, l):
-        x, *rest = mixer(kind, carry, *nth)
+        (x, *rest), _ = mixer(kind, carry, *nth)
         dp = DenseFfnParams(*(_pick(leaf, l) for leaf in params.dense))
         return (dense_ffn(cfg, ops, x, dp), *rest)
 
     def routed_layer(kind, carry, nth, lm):
-        x, *stacks, slabs, assigned = mixer(kind, carry, *nth)
+        (x, *stacks, slabs, assigned), parallel = mixer(kind, carry, *nth)
         rp = RoutedFfnParams(*(_pick(leaf, lm) for leaf in params.routed))
-        x, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live)
+        if parallel is None:
+            x, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live)
+        else:
+            # one norm fed both halves; their terms join the stream together
+            mixed, normed = parallel
+            ffn, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live, normed=normed)
+            x = x + mixed + ffn
         return (x, *stacks, slabs + s, assigned + a)
 
     routed = params.routed is not None
@@ -519,7 +643,7 @@ def hybrid_forward_counted(
             counts = (slabs, assigned)
 
     with jax.named_scope(SCOPE_HEAD):
-        y = rms_norm(x, params.rms_final, eps)
+        y = norm(x, params.rms_final)
         logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)
         logits = logits[..., : cfg.vocab_size]
     return logits, HybridCache(*stacks), counts

@@ -114,10 +114,10 @@ class LlamaLayerParams(NamedTuple):
     dense models carry moe_gate=None.
     """
 
-    wq: jnp.ndarray  # [L, dim, dim]
+    wq: jnp.ndarray  # [L, dim, n_heads * head_size] (head_size: config.head_dim, else dim // n_heads)
     wk: jnp.ndarray  # [L, dim, kv_dim]
     wv: jnp.ndarray  # [L, dim, kv_dim]
-    wo: jnp.ndarray  # [L, dim, dim]
+    wo: jnp.ndarray  # [L, n_heads * head_size, dim]
     w1: jnp.ndarray  # [L, dim, hidden]   gate     (MoE: [L, E, dim, hidden])
     w2: jnp.ndarray  # [L, hidden, dim]   down     (MoE: [L, E, hidden, dim])
     w3: jnp.ndarray  # [L, dim, hidden]   up       (MoE: [L, E, dim, hidden])

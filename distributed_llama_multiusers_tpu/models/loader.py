@@ -274,6 +274,9 @@ _PATTERN_NAME_MAP = {
     "block_matmul_w3": "w3",
     "block_moe_gate": "moe_gate",
     "block_moe_bias": "moe_bias",
+    "block_matmul_shared_w1": "shared_w1",
+    "block_matmul_shared_w2": "shared_w2",
+    "block_matmul_shared_w3": "shared_w3",
     "block_rms_norm_1": "rms_ffn",
     # a state-space layer (LayerKind.SSM)
     "block_matmul_ssm_in": "ssm_in",
@@ -311,7 +314,9 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
     n_dense = config.n_dense_layers if config.n_experts else config.n_layers
-    kinds = config.layer_kinds
+    # a window layer's weights are stacked with the full-context layers'
+    kinds = tuple(LayerKind.ATTENTION if k == LayerKind.WINDOW else k
+                  for k in config.layer_kinds)
     # a layer's index into its kind's stack
     nth = [sum(k == kinds[l] for k in kinds[:l]) for l in range(len(kinds))]
 
@@ -320,7 +325,8 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
         if spec.name == "block_rms_norm_0":  # the mixer's norm, filed by kind
             return mixer_rms[kinds[l]], (nth[l],)
         key = _PATTERN_NAME_MAP[spec.name]
-        if key in ("w1", "w2", "w3", "rms_ffn", "moe_gate", "moe_bias"):
+        if key in ("w1", "w2", "w3", "rms_ffn", "moe_gate", "moe_bias",
+                   "shared_w1", "shared_w2", "shared_w3"):
             if spec.expert < 0 and l < n_dense:
                 return "dense_" + key, (l,)
             return key, (l - n_dense,) + ((spec.expert,) if spec.expert >= 0 else ())
@@ -593,10 +599,10 @@ def params_from_random(
     E = config.n_experts
     ffn_lead = (L, E) if E > 0 else (L,)
     layers = LlamaLayerParams(
-        wq=r(L, dim, dim),
+        wq=r(L, dim, config.q_dim),
         wk=r(L, dim, kv_dim),
         wv=r(L, dim, kv_dim),
-        wo=r(L, dim, dim),
+        wo=r(L, config.q_dim, dim),
         w1=r(*ffn_lead, dim, hidden),
         w2=r(*ffn_lead, hidden, dim),
         w3=r(*ffn_lead, dim, hidden),

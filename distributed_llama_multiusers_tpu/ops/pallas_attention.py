@@ -58,6 +58,17 @@ step's prefill half) is one item that computes nothing and whose index stays
 on the block the pipeline already holds (the lane before it, or the first
 block the next live lane needs): no fetch is issued for an index that did not
 change, so it reads nothing, and it writes zeros.
+
+A ring. A window layer (models/hybrid.py) keeps ``R < seq_len`` rows a lane,
+position ``p`` in row ``p mod R``, and reads the ``W`` newest positions. Its
+work list (``ring_blocks``) is the at most ``W / BLOCK_ROWS + 1`` blocks that
+hold ``(pos - W, pos]``, in the order of their positions and wrapped into the
+ring; the first and the last are masked BY POSITION (the first holds positions
+at or under ``pos - W``, the last holds rows above ``pos`` that still carry
+what was written ``R`` positions ago), so two more rows ride the plan: the
+position of an item's first row and the oldest position the lane reads. The
+kernel is the same; which list it was handed is a static fact of the plan's
+shape, and a full-context layer's program is what it was.
 """
 
 from __future__ import annotations
@@ -144,6 +155,58 @@ def lane_blocks(positions: jnp.ndarray, seq_len: int):
     return end[-1], jnp.stack([lane, src, block, pos[lane], code])
 
 
+def ring_rows_read(positions, seq_len: int, window: int, block: int = BLOCK_ROWS) -> int:
+    """``rows_read`` for a window layer's ring: whole blocks that hold
+    ``(pos - window, pos]`` of each live lane. Host side (numpy)."""
+    pos = np.asarray(positions, np.int64)
+    live = pos[(pos >= 0) & (pos < seq_len)]
+    lo = np.maximum(live - window + 1, 0)
+    return int((block * (live // block - lo // block + 1)).sum())
+
+
+def ring_blocks(positions: jnp.ndarray, seq_len: int, window: int, ring: int):
+    """``lane_blocks`` for a window layer's ring of ``ring`` rows (whole
+    blocks, at least ``window + BLOCK_ROWS``): a live lane's items are the
+    blocks that hold positions ``(pos - window, pos]``, oldest first, each
+    fetched at its place in the ring. ``plan`` is ``int32 [7, lanes *
+    (window / BLOCK_ROWS + 2)]``: ``lane_blocks``' five rows (the block is the
+    one FETCHED; the first and last item of a lane carry ``LAST``: masked),
+    then the position of the item's first row and the oldest position the
+    lane reads."""
+    pos = positions.reshape(-1).astype(jnp.int32)
+    n_lanes = pos.shape[0]
+    ring_blocks_ = ring // BLOCK_ROWS
+    lanes = jnp.arange(n_lanes, dtype=jnp.int32)
+    is_live = (pos >= 0) & (pos < seq_len)
+    lo = jnp.maximum(pos - window + 1, 0)
+    first_block = lo // BLOCK_ROWS  # in the numbering of positions
+    n = jnp.where(is_live, pos // BLOCK_ROWS - first_block + 1, 0)
+    live = n > 0
+    items = jnp.maximum(n, 1)
+    end = jnp.cumsum(items)
+    w = jnp.arange(n_lanes * (-(-window // BLOCK_ROWS) + 1), dtype=jnp.int32)
+    lane = jnp.minimum(jnp.searchsorted(end, w, side="right", method="compare_all"), n_lanes - 1)
+    lane = lane.astype(jnp.int32)
+    j = w - (end - items)[lane]
+    # a parked lane stays on what the pipeline holds (lane_blocks)
+    prev = jax.lax.cummax(jnp.where(live, lanes, -1))
+    first = jnp.argmax(live).astype(jnp.int32)
+    held_lane = jnp.where(prev >= 0, prev, first)
+    last_of = (first_block + jnp.maximum(n - 1, 0)) % ring_blocks_
+    held_block = jnp.where(prev >= 0, last_of[held_lane], (first_block % ring_blocks_)[first])
+    src = jnp.where(live, lanes, held_lane)[lane]
+    at = first_block[lane] + j  # the item's block, in the numbering of positions
+    block = jnp.where(live[lane], at % ring_blocks_, held_block[lane])
+    edge = (j == 0) | (j == n[lane] - 1)
+    code = (
+        jnp.where(live[lane], jnp.where(edge, LAST, FULL), 0)
+        + jnp.where(j == 0, FIRST, 0)
+        + jnp.where(j == items[lane] - 1, FINAL, 0)
+    )
+    return end[-1], jnp.stack(
+        [lane, src, block, pos[lane], code, at * BLOCK_ROWS, lo[lane]])
+
+
 def _head_bias(n_heads: int, heads_pad: int, n_kv: int, rows: int) -> np.ndarray:
     """0 where a block's row (``col % n_kv`` is its kv head) belongs to the
     query head's group, ``-inf`` elsewhere and on the padding heads."""
@@ -168,6 +231,7 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
     k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-6:]
     w = pl.program_id(0)
     block_index, pos, code = plan_ref[2, w], plan_ref[3, w], plan_ref[4, w]
+    ring = plan_ref.shape[0] == 7  # a window layer's list (module header, "A ring")
 
     def across(stat):
         """A ``[heads, 128]`` statistic beside the ``[heads, width]`` values."""
@@ -187,7 +251,17 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
         ) * scale  # [heads, rows]
         if bias_ref is not None:
             s = s + bias_ref[...]
-        if last:
+        if last and ring:
+            # the rows that hold positions [oldest, pos], by where the block
+            # sits among the positions and not by what it contains
+            base, oldest = plan_ref[5, w], plan_ref[6, w]
+            limit = (pos - base + 1) * rows_per_pos
+            floor = (oldest - base) * rows_per_pos
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where((col < limit) & (col >= floor), s, -jnp.inf)
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where((row < limit) & (row >= floor), v, jnp.zeros_like(v))
+        elif last:
             # rows above the lane's position: out of the scores, and out of
             # the values (0 x NaN is NaN: a stale row must not reach the sum)
             limit = (pos - block_index * BLOCK_ROWS + 1) * rows_per_pos
@@ -224,9 +298,10 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
     q ``[lanes, n_heads, hd]`` (head ``h * group + g`` reads kv head ``h``);
     ``k_all`` / ``v_all`` ``[L, lanes, S, n_kv, hd]`` or ``[A, lanes, S, n_kv *
     hd]`` (``supports``) as the layer loop carries them, the lanes' fresh rows
-    already appended; ``work`` from ``lane_blocks``. Returns ``[lanes,
-    n_heads, hd]`` float32; a lane's result depends on that lane's rows
-    ``[0, pos]`` alone."""
+    already appended; ``work`` from ``lane_blocks``, or from ``ring_blocks``
+    where the stack is a window layer's ring. Returns ``[lanes, n_heads, hd]``
+    float32; a lane's result depends on that lane's rows ``[0, pos]`` alone
+    (a ring: on the rows that hold ``(pos - window, pos]``)."""
     n_heads, hd = q.shape[1:]
     n_layers, lanes, seq_len = k_all.shape[:3]
     merged = k_all.ndim == 4  # one row a position, every kv head in it

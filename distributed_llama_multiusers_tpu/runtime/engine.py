@@ -52,8 +52,8 @@ from ..models.deepseek import (
     forward_counted,
     init_latent_cache,
 )
-from ..models.hybrid import init_hybrid_cache, state_leaves
-from ..ops import pallas_attention
+from ..models.hybrid import init_hybrid_cache, ring_attention_engages, state_leaves
+from ..ops import blocked_attention, pallas_attention
 from ..telemetry.logs import log_event
 from ..telemetry import names
 from ..telemetry.names import SCOPE_CARRY, SCOPE_HEAD, SCOPE_SAMPLER
@@ -377,6 +377,26 @@ class EngineStats:
     ssm_lane_steps: int = 0
     ssm_rows_scanned: int = 0
     ssm_rows_computed: int = 0
+    # window attention layers (config.n_window_layers; all 0 elsewhere), in
+    # rows of ONE window layer's ring, as attn_kv_rows_* are rows of one
+    # full-context layer's plane (and stay so: in a model with both kinds
+    # that pair counts the full-context kind alone). Kept by the scheduler
+    # from its own lane positions over the decode steps: `window_rows_read`
+    # what a window layer fetched (whole blocks that hold (pos - window, pos]
+    # where the kernel engages; the whole ring where it does not),
+    # `full_rows_read` what a full-context layer fetched (attn_kv_rows_read
+    # under the name of its kind), `window_rows_plane` what a window layer
+    # would have fetched had it kept a plane and read it as the full-context
+    # layers read theirs. And the prefill attention computed a key block at a
+    # time (ops/blocked_attention.py; 0 where every chunk's scores are dense),
+    # in (query row, key block) pairs summed over the layers: what the loops
+    # ran (every row of the bucket), and the least the mask allows (a real
+    # row's blocks that hold a position it reads)
+    attn_window_rows_read: int = 0
+    attn_full_rows_read: int = 0
+    attn_window_rows_plane: int = 0
+    prefill_attn_blocks_visited: int = 0
+    prefill_attn_blocks_causal: int = 0
     # compile stability (analysis/jitcheck.py, ISSUE 15): XLA backend
     # compiles observed AFTER warmup_engine armed the recompile witness —
     # the machine-checked form of "one compiled program per (family,
@@ -421,6 +441,8 @@ class EngineStats:
             "indexer_rows_scored", "sparse_rows_selected",
             "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
             "ssm_lane_steps", "ssm_rows_scanned", "ssm_rows_computed",
+            "attn_window_rows_read", "attn_full_rows_read", "attn_window_rows_plane",
+            "prefill_attn_blocks_visited", "prefill_attn_blocks_causal",
             "jit_compiles_after_warmup",
         ),
     }
@@ -463,6 +485,9 @@ class EngineStats:
             self.moe_rows_unheld = self.indexer_rows_scored = self.sparse_rows_selected = 0
             self.state_zero_starts = self.prefix_reuse_declined = 0
             self.ssm_lane_steps = self.ssm_rows_scanned = self.ssm_rows_computed = 0
+            self.attn_window_rows_read = self.attn_full_rows_read = 0
+            self.attn_window_rows_plane = 0
+            self.prefill_attn_blocks_visited = self.prefill_attn_blocks_causal = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
             # compile stability since warmup, and a window reset hiding a
@@ -606,7 +631,9 @@ class InferenceEngine:
                 if hit:
                     raise ValueError(f"{subject} and does not serve {what}")
         init_contiguous = (
-            init_hybrid_cache if config.layer_kinds
+            # a window layer's ring holds the window and the widest chunk
+            partial(init_hybrid_cache, max_chunk=self.prefill_buckets[-1])
+            if config.layer_kinds
             else init_latent_cache if config.latent_attention else init_kv_cache
         )
         if paged_kv:
@@ -718,6 +745,24 @@ class InferenceEngine:
             # (latent rows are no K/V heads: that block's forward never asks)
             if not config.latent_attention and decode_attention_engages(
                 self.cache, mesh, config.n_heads, config.n_kv_heads)
+            else None
+        )
+        # the same for a window layer's ring (None: no window layers, or
+        # their decode steps read whole rings), and the ring's rows a lane
+        self.ring_rows = self.cache.wk.shape[2] if config.n_window_layers else 0
+        self.decode_ring_block = (
+            pallas_attention.BLOCK_ROWS
+            if ring_attention_engages(self.cache, mesh, config.n_heads, config.n_kv_heads)
+            else None
+        )
+        # the start from which a prompt chunk takes the second-largest bucket
+        # (None: never): where the full-context layers' planes are read by key
+        # blocks, a chunk far into a long prompt holds the decoding lanes as
+        # long as one near its start
+        self.chunk_taper_start = (
+            blocked_attention.taper_start(
+                self.prefill_buckets, config.n_heads, config.seq_len)
+            if config.layer_kinds and config.n_attention_layers and self.kvpool is None
             else None
         )
         # async decode pipeline: bounded ring of dispatched-but-unconsumed
@@ -1562,6 +1607,25 @@ class InferenceEngine:
 
     # -- grammar-constrained decoding (grammar/) ----------------------------
 
+    def _prefill_attn_blocks(self, start: int, n_rows: int, bucket: int) -> tuple[int, int]:
+        """(visited, causal) of one prompt chunk, in (query row, key block)
+        pairs over the layers whose attention is computed a key block at a
+        time (ops/blocked_attention.py): host integers, for the two
+        prefill_attn_blocks_* counters. (0, 0) where the chunk's scores are
+        dense, and for a block that has no such path."""
+        cfg = self.config
+        if not cfg.layer_kinds or self.kvpool is not None:
+            return 0, 0
+        visited = causal = 0
+        for layers, rows, window in (
+            (cfg.n_attention_layers, cfg.seq_len, 0),
+            (cfg.n_window_layers, self.ring_rows, cfg.sliding_window),
+        ):
+            if layers and blocked_attention.engages(1, bucket, cfg.n_heads, rows):
+                v, c = blocked_attention.chunk_block_counts(start, n_rows, bucket, rows, window)
+                visited, causal = visited + layers * v, causal + layers * c
+        return visited, causal
+
     def path_facts(self) -> dict:
         """Which attention and which expert path this engine's decode steps
         run, by the predicates the forward itself asks: said once at
@@ -1604,6 +1668,20 @@ class InferenceEngine:
             )
         if cfg.experts_held_count:
             facts["experts_held"] = f"{cfg.experts_held_count}/{cfg.n_experts}"
+        if cfg.n_window_layers:
+            # two kinds of cache a lane: the full-context layers' planes and
+            # the window layers' rings, in bytes over all lanes
+            facts.update(
+                window_attention_path=(
+                    "pallas_in_place_ring" if self.decode_ring_block is not None
+                    else "xla_dense_ring"),
+                sliding_window=cfg.sliding_window,
+                kv_ring_rows=self.ring_rows,
+                kv_ring_bytes=self.cache.wk.nbytes + self.cache.wv.nbytes,
+                kv_plane_bytes=self.cache.k.nbytes + self.cache.v.nbytes,
+            )
+        if self.chunk_taper_start is not None:
+            facts["chunk_taper"] = f"{self.prefill_buckets[-2]}@{self.chunk_taper_start}"
         if cfg.recurrent_state:
             # what is declined for a state overwritten in place, said where
             # the paths are said
@@ -1703,7 +1781,12 @@ class InferenceEngine:
                 return b
         return self.prefill_buckets[-1]
 
-    def max_chunk(self) -> int:
+    def max_chunk(self, start: int = 0) -> int:
+        """The most rows a prompt chunk whose first row stands at ``start``
+        takes: the largest bucket, and the one under it from
+        ``chunk_taper_start`` on (ops/blocked_attention.py ``taper_start``)."""
+        if self.chunk_taper_start is not None and start >= self.chunk_taper_start:
+            return self.prefill_buckets[-2]
         return self.prefill_buckets[-1]
 
     def prefill_chunk(
@@ -1758,6 +1841,9 @@ class InferenceEngine:
             self.stats.state_zero_starts += int(self.config.recurrent_state and start_pos == 0)
             self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
             self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
+            visited, causal = self._prefill_attn_blocks(start_pos, len(chunk), bucket)
+            self.stats.prefill_attn_blocks_visited += visited
+            self.stats.prefill_attn_blocks_causal += causal
         return last, greedy, sampled
 
     def prefill(
@@ -1778,7 +1864,7 @@ class InferenceEngine:
         remaining = list(tokens)
         last = greedy = None
         while remaining:
-            chunk = remaining[: self.max_chunk()]
+            chunk = remaining[: self.max_chunk(pos)]
             remaining = remaining[len(chunk) :]
             last, greedy, self.last_sampled = self.prefill_chunk(
                 lane, chunk, pos, temp=temp, topp=topp, seed=seed,
@@ -2142,6 +2228,9 @@ class InferenceEngine:
             self.stats.state_zero_starts += int(self.config.recurrent_state and p_start == 0)
             self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
             self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
+            visited, causal = self._prefill_attn_blocks(p_start, len(chunk), bucket)
+            self.stats.prefill_attn_blocks_visited += visited
+            self.stats.prefill_attn_blocks_causal += causal
             self.stats.fused_bucket_hist[bucket] = (
                 self.stats.fused_bucket_hist.get(bucket, 0) + 1
             )

@@ -51,7 +51,7 @@ from ..serving import (
 )
 from ..analysis import jitcheck, leakcheck
 from ..lockcheck import make_lock
-from ..ops.pallas_attention import rows_read
+from ..ops.pallas_attention import ring_rows_read, rows_read
 from ..serving.watchdog import deadline_from_env
 from ..telemetry import Telemetry
 from ..telemetry.names import (
@@ -470,6 +470,9 @@ class ContinuousBatchingScheduler:
         self._index_topk = int(getattr(cfg, "index_topk", 0) or 0)
         # selective state-space layers: the decode steps' ssm_lane_steps
         self._n_ssm_layers = int(getattr(cfg, "n_ssm_layers", 0) or 0)
+        # window attention layers: the decode steps' attn_window_rows_*
+        self._window = int(getattr(cfg, "sliding_window", 0) or 0) if int(
+            getattr(cfg, "n_window_layers", 0) or 0) else 0
         # a held share of the routed experts, "16/256" (None: every expert)
         held = int(getattr(cfg, "experts_held_count", 0) or 0)
         self._experts_held = f"{held}/{cfg.n_experts}" if held else None
@@ -929,10 +932,24 @@ class ContinuousBatchingScheduler:
         # a state-space layer advances every live lane's running sum a step
         ssm = self._n_ssm_layers and self._n_ssm_layers * steps * sum(
             1 for p in positions if p < seq_len)
+        # a window layer's ring, in rows of one such layer: what its read
+        # fetched. What a plane read as the full-context kind's would have
+        # fetched is `read` itself (the pair above stays that kind's)
+        ring_read = 0
+        if self._window:
+            ring_block = getattr(engine, "decode_ring_block", None)
+            ring_read = (
+                len(positions) * getattr(engine, "ring_rows", 0) * steps if ring_block is None
+                else sum(ring_rows_read(positions + s, seq_len, self._window, ring_block)
+                         for s in range(steps)))
         with engine.stats.lock:
             engine.stats.attn_kv_rows_read += read
             engine.stats.attn_kv_rows_whole += whole
             engine.stats.ssm_lane_steps += ssm
+            if self._window:
+                engine.stats.attn_window_rows_read += ring_read
+                engine.stats.attn_full_rows_read += read
+                engine.stats.attn_window_rows_plane += read
 
     def occupancy(self) -> tuple[int, int]:
         """(busy lanes, total lanes) — public surface for /stats."""
@@ -1340,7 +1357,7 @@ class ContinuousBatchingScheduler:
         self._prefill_rr = (lane_idx + 1) % n
         lane = self._lanes[lane_idx]
         req = lane.request
-        chunk = lane.pending[: self.engine.max_chunk()]
+        chunk = lane.pending[: self.engine.max_chunk(lane.pos)]
         t_chunk = time.perf_counter()
         self.telemetry.on_prefill_dispatch(req, time.monotonic())
         wd = self.watchdog
@@ -1731,7 +1748,7 @@ class ContinuousBatchingScheduler:
             return None, drafted
         lane = admitting[target]
         req = lane.request
-        chunk = lane.pending[: engine.max_chunk()]
+        chunk = lane.pending[: engine.max_chunk(lane.pos)]
         # the admitting lane's boundary token samples under its
         # automaton's START state (== lane.g_state until its first
         # emission); junk for mid-prompt chunks, decisive on the final one

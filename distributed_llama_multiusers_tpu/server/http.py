@@ -423,6 +423,17 @@ class ApiServer:
             "ssm_lane_steps": stats["ssm_lane_steps"],
             "ssm_rows_scanned": stats["ssm_rows_scanned"],
             "ssm_rows_computed": stats["ssm_rows_computed"],
+            # window attention layers, in rows of one such layer's ring: what
+            # the decode steps fetched, what a full-context layer fetched,
+            # what a window layer would have fetched of a plane; and the
+            # prefill attention computed a key block at a time, in (query
+            # row, key block) pairs: what ran and the least the mask allows;
+            # all 0 for a model without window layers
+            "attn_window_rows_read": stats["attn_window_rows_read"],
+            "attn_full_rows_read": stats["attn_full_rows_read"],
+            "attn_window_rows_plane": stats["attn_window_rows_plane"],
+            "prefill_attn_blocks_visited": stats["prefill_attn_blocks_visited"],
+            "prefill_attn_blocks_causal": stats["prefill_attn_blocks_causal"],
             # a routed FFN's reads of its expert stacks over the decode
             # steps: distinct (layer, expert) slabs fetched, what a sweep of
             # every expert fetches, and (row, expert) pairs routed; all 0

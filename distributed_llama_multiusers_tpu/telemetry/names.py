@@ -80,6 +80,11 @@ SCOPE_SSM = "dl.ssm"            # the state-space mixer: norm, projections, conv
 SCOPE_SSM_SCAN = "dl.ssm_scan"  # under dl.ssm: the running sum's read, the recurrence and its commit
 SSM_MIXER_SCOPES = (SCOPE_SSM, SCOPE_SSM_SCAN)
 
+# a window layer in such a block (LayerKind.WINDOW) keeps the four attention
+# scopes, and nests its read of the ring under dl.attention, so that a trace
+# tells the two kinds of attention layer apart
+SCOPE_WINDOW_ATTENTION = "dl.window_attention"  # under dl.attention: a window layer's read of its ring
+
 # scopes inside the layer scan, in program order
 LAYER_SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_ATTN_OUT, SCOPE_FFN)
 # every scope whose time is the model's own arithmetic (no children)

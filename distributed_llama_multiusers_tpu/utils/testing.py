@@ -246,7 +246,7 @@ class MockAsyncEngine:
             self._page_payloads = {}
             self.pages_imported = 0
 
-    def max_chunk(self):
+    def max_chunk(self, start=0):
         return self._max_chunk
 
     def bucket_for(self, n):

@@ -1,5 +1,5 @@
 """PR 45, one block of rows a call: the dense Q40 kernel's default mode at
-512 and 1024 rows against the six configurations' output heads, each one plane
+512 and 1024 rows against the seven configurations' output heads, each one plane
 of 8192-wide tiles or thereabouts (``CELL_SHAPES``' entries that are no stack,
 tests/chip_compile_util.py), compiled for a described v5e."""
 

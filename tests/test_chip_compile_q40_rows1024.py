@@ -1,5 +1,5 @@
 """PR 45, one block of rows a call: the dense Q40 kernel's default mode at
-1024 rows at every distinct (d_in, d_out) the benchmark's six configurations
+1024 rows at every distinct (d_in, d_out) the benchmark's seven configurations
 send through it as a stack of layers (``CELL_SHAPES``, tests/chip_compile_util.py;
 the heads, one plane each: test_chip_compile_q40_heads.py), compiled for a
 described v5e."""
