@@ -109,10 +109,12 @@ from ..ops.linear import (
 )
 from ..ops.norm import layer_norm, rms_norm
 from ..ops.pallas_q40_grouped import (
+    HEIGHTS,
     grouped_matmul_xla,
     grouped_supports,
     q40_grouped_pallas,
     route_plan,
+    tile_rows,
 )
 from ..ops.rope import apply_rope
 from ..quants.packed import PackedQ40, Q40Experts, Q40Layer, unpack_q40
@@ -527,10 +529,12 @@ def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live
     """A routed layer's FFN half. ``rp``: the layer's parameters, the expert
     stacks whole; ``lm`` its index into them; ``live`` ``[B * T]``: False for
     a parked row, which routes nowhere. Returns (x, slabs, assignments,
-    unheld): distinct slabs fetched, (live row, expert) pairs that fetched
-    one, and pairs whose expert lies outside the held share
-    (``cfg.experts_held``): those fetch nothing and add nothing, exactly as a
-    parked row's do, and their weight stays in the renormalising sum.
+    tiled_rows, unheld): distinct slabs fetched, (live row, expert) pairs
+    that fetched one, the rows of the tiles those pairs sit in (what each of
+    the three grouped products multiplied), and pairs whose expert lies
+    outside the held share (``cfg.experts_held``): those fetch nothing and
+    add nothing, exactly as a parked row's do, and their weight stays in the
+    renormalising sum.
     ``normed`` (a parallel block, ``cfg.parallel_block``): the layer's one
     normed input; the FFN's TERM is returned in the place of ``x``, for the
     caller to add beside attention's."""
@@ -543,16 +547,22 @@ def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live
         with jax.named_scope(SCOPE_ROUTER):
             topw, topi = moe_router(cfg, y.reshape(n, -1), rp.gate, rp.bias)
         with jax.named_scope(SCOPE_EXPERTS):
+            pairs = n * topi.shape[1]
             if cfg.experts_held_count:
                 first, held = cfg.experts_held
                 local = topi - first  # ids into the stacks, which hold the share
                 here = (local >= 0) & (local < held)
+                # a held share still picks between the two heights it had (a
+                # held expert's group is the pairs over the ROUTER's width);
+                # tile_rows is theirs after PERF.md question 42(f)
+                tm = HEIGHTS[0] if pairs <= HEIGHTS[0] * held else HEIGHTS[-1]
                 # an expert of another chip's share sorts with the parked rows
-                plan = route_plan(jnp.where(here, local, held), live, held)
+                plan = route_plan(jnp.where(here, local, held), live, held, tm)
                 unheld = jnp.sum(live[:, None] & ~here).astype(jnp.int32)
                 fetched = plan.assignments - unheld
             else:
-                plan = route_plan(topi, live, cfg.n_experts)
+                tm = tile_rows(pairs, cfg.n_experts, cfg.dim * cfg.moe_hidden_dim)
+                plan = route_plan(topi, live, cfg.n_experts, tm)
                 unheld, fetched = jnp.zeros((), jnp.int32), plan.assignments
             rows = jnp.concatenate(
                 [yq.reshape(n, -1), jnp.zeros((1, yq.shape[-1]), yq.dtype)]
@@ -572,7 +582,7 @@ def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live
                 out = out + shared
         out = ops.maybe_qdq(out.astype(dtype))
         x = x + out if normed is None else out
-    return x, plan.slabs, fetched, unheld
+    return x, plan.slabs, fetched, plan.tiled_rows, unheld
 
 
 def _pick(leaf, index):
@@ -600,9 +610,10 @@ def deepseek_forward_counted(
 ):
     """(logits ``[B, T, vocab]`` f32, updated cache, counts). ``counts`` is a
     tuple of int32 scalars summed over the layers, named by
-    ``count_names(config)``: ``(slabs, assignments)`` of the routed layers
-    (distinct (layer, expert) slabs one expert matrix read, and (row, expert)
-    pairs that read one), then ``unheld`` where a share of the experts is held
+    ``count_names(config)``: ``ROUTED_COUNTS`` of the routed layers (distinct
+    (layer, expert) slabs one expert matrix read, (row, expert) pairs that
+    read one, and the rows of the tiles they sat in), then ``unheld`` where a
+    share of the experts is held
     (pairs whose expert is another chip's), then ``(scored, selected)`` where
     an indexer chooses (index keys scored, rows attended); None for a model
     with none of them."""
@@ -728,9 +739,9 @@ def deepseek_forward_counted(
                 return (x, leaves, tuple(a + m for a, m in zip(routed, more_routed)),
                         tuple(a + m for a, m in zip(seen, more))), None
 
-            # (slabs, assignments) and, where a share of the experts is held,
-            # the pairs that fell outside it
-            routed0 = (zero,) * (3 if cfg.experts_held_count else 2)
+            # ROUTED_COUNTS and, where a share of the experts is held, the
+            # pairs that fell outside it
+            routed0 = (zero,) * (len(ROUTED_COUNTS) + bool(cfg.experts_held_count))
             (x, leaves, routed, seen), _ = jax.lax.scan(
                 layer_step, (x, leaves, routed0, seen),
                 jnp.arange(cfg.n_layers - n_dense, dtype=jnp.int32),
@@ -746,11 +757,18 @@ def deepseek_forward_counted(
     return logits, type(cache)(*leaves), counts
 
 
+# what a routed layer counts on the device, in ``routed_ffn``'s order
+ROUTED_COUNTS = ("slabs", "assignments", "tiled_rows")
+# the two of them that say how full the grouped kernel's tiles were: a prompt
+# chunk's are brought back too (the engine's fused step)
+TILE_COUNTS = ("assignments", "tiled_rows")
+
+
 def count_names(config: LlamaConfig) -> tuple:
     """The names of ``deepseek_forward_counted``'s counts, in their order."""
     names = ()
     if config.n_routed_layers:
-        names += ("slabs", "assignments") + (("unheld",) if config.experts_held_count else ())
+        names += ROUTED_COUNTS + (("unheld",) if config.experts_held_count else ())
     if config.sparse_attention:
         names += ("scored", "selected")
     return names

@@ -139,6 +139,7 @@ from ..telemetry.names import (
 )
 from .config import LlamaConfig
 from .deepseek import (
+    ROUTED_COUNTS,
     DenseFfnParams,
     RoutedFfnParams,
     _pick,
@@ -546,7 +547,7 @@ def hybrid_forward_counted(
         return x, ssm_all, win_all
 
     # the carry: the stream, then every state stack (a kind the block lacks
-    # is None and no leaf), then a routed model's two counts
+    # is None and no leaf), then a routed model's counts (ROUTED_COUNTS)
     def mixer(kind, carry, ai, ci, si, wi):
         """The layer's mixer on the carry; with it, in a parallel block, the
         mixer's term and the normed input it read (else None)."""
@@ -577,16 +578,18 @@ def hybrid_forward_counted(
         return (dense_ffn(cfg, ops, x, dp), *rest)
 
     def routed_layer(kind, carry, nth, lm):
-        (x, *stacks, slabs, assigned), parallel = mixer(kind, carry, *nth)
+        (x, *rest), parallel = mixer(kind, carry, *nth)
+        stacks, counts = rest[:-len(ROUTED_COUNTS)], rest[-len(ROUTED_COUNTS):]
         rp = RoutedFfnParams(*(_pick(leaf, lm) for leaf in params.routed))
         if parallel is None:
-            x, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live)
+            x, *more = routed_ffn(cfg, ops, x, rp, lm, live)
         else:
             # one norm fed both halves; their terms join the stream together
             mixed, normed = parallel
-            ffn, s, a, _ = routed_ffn(cfg, ops, x, rp, lm, live, normed=normed)
+            ffn, *more = routed_ffn(cfg, ops, x, rp, lm, live, normed=normed)
             x = x + mixed + ffn
-        return (x, *stacks, slabs + s, assigned + a)
+        # the pairs outside a held share, routed_ffn's last, are not carried here
+        return (x, *stacks, *(c + m for c, m in zip(counts, more)))
 
     routed = params.routed is not None
     n_lead = cfg.n_dense_layers if routed else 0
@@ -604,7 +607,7 @@ def hybrid_forward_counted(
         per = kinds_before(n_lead, n_lead + period)
         if routed:
             zero = jnp.zeros((), jnp.int32)
-            carry = (*carry, zero, zero)
+            carry = (*carry, *(zero,) * len(ROUTED_COUNTS))
         # a layer's FFN is read at its count after the leading layers (none
         # lead where every layer is dense: the dense stacks count from 0)
         layer = routed_layer if routed else dense_layer
@@ -639,8 +642,8 @@ def hybrid_forward_counted(
         x, *stacks = carry
         counts = None
         if routed:
-            *stacks, slabs, assigned = stacks
-            counts = (slabs, assigned)
+            counts = tuple(stacks[-len(ROUTED_COUNTS):])
+            stacks = stacks[:-len(ROUTED_COUNTS)]
 
     with jax.named_scope(SCOPE_HEAD):
         y = norm(x, params.rms_final)

@@ -21,8 +21,10 @@ A wider slab (1.5 MiB at 2048 x 1536) is walked in the reduction blocks
 ``_plan_blocks`` gives: a second, inner grid axis ``k`` over them, the weight
 block ``(l, e, k, 0)``, the partial products summed in an f32 scratch
 (``_acc_epilogue``). A tile then fetches its slab once, block by block; a
-second tile of the same expert fetches it again (at eight rows a tile few
-experts have one), and the unused tiles stay on the last block fetched.
+second tile of the same expert fetches it again, and the unused tiles stay on
+the last block fetched. Fetched or not, every tile dequantises its slab: on the
+chip a second tile of an expert costs what the first does, on either kind of
+slab, so ``tile_rows`` makes tiles tall enough that few groups fill two.
 
 Shapes are static: ``n_tiles`` is the most tiles any routing of ``n_assign``
 assignments over ``n_experts`` experts can need, so no token is ever dropped.
@@ -37,6 +39,7 @@ halves, the -8 offset folded into a correction dot against per-block sums of
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -56,15 +59,58 @@ from .pallas_q40 import (
     _sub_tiles,
 )
 
-ROWS_NARROW = 8  # rows a tile where an expert seldom has more (decode, verify)
-ROWS_WIDE = 128  # and where it has many (prefill chunks)
+HEIGHTS = (8, 16, 32, 64, 128)  # rows a tile: multiples of the 8 f32 sublanes
+
+# Weights of a slab whose dequantisation costs a call what one more padded row
+# costs it. Measured on a v5e (PERF.md section 6, PR 48): a used tile costs the
+# kernel 1.6 us at 8 rows and 2.6 at 128 on a 2048 x 768 slab (3.8 and 6.6 at
+# 2048 x 1536, 22 and 41 at 4096 x 4096): its slab's dequantisation, whatever
+# rows it holds, a second tile of an expert as much as the first; and every
+# padded row, in a used tile or not, costs 0.06-0.2 us in the operations
+# around the kernel (the gather into padded order, the float32 halves, the
+# activation, the gather back) and in the tile's own copies. The routed halves
+# of the four benchmark configurations are cheapest at one height for any
+# value from 50,000 to 80,000.
+WEIGHTS_A_ROW = 1 << 16
 
 
-def tile_rows(n_assign: int, n_experts: int) -> int:
-    """Rows a tile, chosen by the assignments at trace time: a slab is
-    dequantized once a tile, so tiles are as tall as an expert's group is
-    likely to be."""
-    return ROWS_NARROW if n_assign <= ROWS_NARROW * n_experts else ROWS_WIDE
+def _tiles_a_group(group: float, tm: int) -> float:
+    """Tiles of ``tm`` rows an expert's group fills, in expectation, where
+    rows choose their experts independently: ``E[ceil(G / tm)]`` for ``G``
+    Poisson with mean ``group``."""
+    spread = 8 * math.sqrt(group) + 8
+    return sum(
+        -(-k // tm) * math.exp(k * math.log(group) - group - math.lgamma(k + 1))
+        for k in range(max(1, int(group - spread)), int(group + spread) + 1)
+    )
+
+
+def tile_rows(n_assign: int, n_experts: int, slab_weights: int) -> int:
+    """Rows a tile, chosen at trace time from the call's static shapes:
+    ``n_assign`` (row, expert) pairs routed over ``n_experts`` experts, each a
+    slab of ``slab_weights`` weights. An expert's likely group is ``n_assign /
+    n_experts`` rows. A short tile makes a group fill several, each
+    dequantising the slab again; a tall one pads every group to its height,
+    and the padded rows are paid for around the kernel. The height is the
+    cheapest of ``HEIGHTS`` by that count, in rows: the tiles the groups are
+    likely to fill, each worth ``slab_weights / WEIGHTS_A_ROW`` rows, and the
+    rows of the static plan (``max_tiles``). On the lab's table (PR 48) that
+    is the smallest height at or above the group, give or take one step: a
+    step taller where the slab is large and half the groups would spill over,
+    a step shorter where it is small and many experts would be padded; 8 at
+    every decode width.
+
+    The expectation is a UNIFORM router's (the benchmark's weights are
+    random: no trained checkpoint is in the repository, and a trained
+    router's hot experts get taller groups), and the constant was measured
+    on slabs of 1.5M to 16.7M weights: measure before trusting it outside."""
+    group = n_assign / n_experts
+
+    def rows_worth(tm: int) -> float:
+        filled = n_experts * _tiles_a_group(group, tm)
+        return filled * slab_weights / WEIGHTS_A_ROW + max_tiles(n_assign, n_experts, tm) * tm
+
+    return min(HEIGHTS, key=rows_worth)
 
 
 def max_tiles(n_assign: int, n_experts: int, tm: int) -> int:
@@ -83,14 +129,16 @@ class RoutePlan(NamedTuple):
     pos: jnp.ndarray  # [n, k] int32: the padded row of each assignment; P if parked
     slabs: jnp.ndarray  # [] int32: distinct experts chosen (slabs a matrix read)
     assignments: jnp.ndarray  # [] int32: live rows x k
+    tiled_rows: jnp.ndarray  # [] int32: n_used * tm, the rows a call multiplies
 
 
-def route_plan(topi: jnp.ndarray, live: jnp.ndarray, n_experts: int) -> RoutePlan:
-    """Sort a step's assignments by expert. ``topi``: ``[n, k]`` expert ids;
-    ``live``: ``[n]`` bool, False for a parked row, which routes nowhere."""
+def route_plan(topi: jnp.ndarray, live: jnp.ndarray, n_experts: int, tm: int) -> RoutePlan:
+    """Sort a step's assignments by expert into tiles of ``tm`` rows (one of
+    ``HEIGHTS``; ``tile_rows`` is the rule). ``topi``: ``[n, k]`` expert ids,
+    an id of ``n_experts`` one that is not held here; ``live``: ``[n]`` bool,
+    False for a parked row, which routes nowhere."""
     n, k = topi.shape
     a = n * k
-    tm = tile_rows(a, n_experts)
     n_tiles = max_tiles(a, n_experts, tm)
     p = n_tiles * tm
     flat = jnp.where(live[:, None], topi, n_experts).reshape(a).astype(jnp.int32)
@@ -119,6 +167,7 @@ def route_plan(topi: jnp.ndarray, live: jnp.ndarray, n_experts: int) -> RoutePla
         tile_expert=tile_expert, n_used=n_used, src=src, pos=pos.reshape(n, k),
         slabs=jnp.sum(g > 0).astype(jnp.int32),
         assignments=jnp.sum(live).astype(jnp.int32) * k,
+        tiled_rows=n_used * tm,
     )
 
 

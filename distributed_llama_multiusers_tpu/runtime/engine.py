@@ -48,6 +48,8 @@ from ..models.llama import (
     init_paged_kv_cache,
 )
 from ..models.deepseek import (
+    ROUTED_COUNTS,
+    TILE_COUNTS,
     count_names,
     forward_counted,
     init_latent_cache,
@@ -342,6 +344,13 @@ class EngineStats:
     moe_slabs_read: int = 0
     moe_slabs_whole: int = 0
     moe_assignments: int = 0
+    # how full the grouped kernel's tiles are, over the decode steps AND the
+    # prompt chunks that rode them (a fused step's prefill half): `tile_pairs`
+    # the (row, expert) pairs that took a row, `tile_rows` the rows of the
+    # tiles they sat in (used tiles x their height: what each of the three
+    # products multiplied)
+    moe_tile_pairs: int = 0
+    moe_tile_rows: int = 0
     # a held share of the routed experts (config.experts_held_count; 0 and 0
     # where every expert is held): the experts a layer holds here (fixed at
     # start-up, kept by reset()), and the (row, expert) pairs of the decode
@@ -437,6 +446,7 @@ class EngineStats:
             "grammar_lanes", "grammar_masked_steps",
             "attn_kv_rows_read", "attn_kv_rows_whole",
             "moe_slabs_read", "moe_slabs_whole", "moe_assignments",
+            "moe_tile_pairs", "moe_tile_rows",
             "moe_experts_held", "moe_rows_unheld",
             "indexer_rows_scored", "sparse_rows_selected",
             "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
@@ -482,6 +492,7 @@ class EngineStats:
             self.grammar_lanes = self.grammar_masked_steps = 0
             self.attn_kv_rows_read = self.attn_kv_rows_whole = 0
             self.moe_slabs_read = self.moe_slabs_whole = self.moe_assignments = 0
+            self.moe_tile_pairs = self.moe_tile_rows = 0
             self.moe_rows_unheld = self.indexer_rows_scored = self.sparse_rows_selected = 0
             self.state_zero_starts = self.prefix_reuse_declined = 0
             self.ssm_lane_steps = self.ssm_rows_scanned = self.ssm_rows_computed = 0
@@ -725,7 +736,7 @@ class InferenceEngine:
         # what the counts that ride a decode step's token readback are, in
         # their order (models/deepseek.count_names); () where none ride it
         self._count_names = count_names(config) if config.latent_attention else (
-            ("slabs", "assignments") if config.n_routed_layers else ())
+            ROUTED_COUNTS if config.n_routed_layers else ())
         # an indexer's selection is made for one new row a lane or for a
         # prompt chunk; a verify step's rows are not served (declined by
         # name: path_facts, the scheduler's start-up line)
@@ -821,17 +832,20 @@ class InferenceEngine:
         # what they always were)
         forward_c = forward_counted(cfg)
 
-        def forward(*a, n_valid=None, **kw):
+        def forward_n(*a, n_valid=None, **kw):
             # n_valid (a prefill chunk's real tokens) reaches a block whose
             # lanes carry a state overwritten in place; no other has a use
             # for it, and their programs are what they always were
             if cfg.recurrent_state:
                 kw["n_valid"] = n_valid
-            return forward_c(*a, **kw)[:2]
+            return forward_c(*a, **kw)
+
+        def forward(*a, **kw):
+            return forward_n(*a, **kw)[:2]
 
         def _token_rows(greedy, sampled, counts):
             # [2, n]: the step's packed token readback; a routed model's
-            # counts ride it as two more rows (each count in every column)
+            # counts ride it as more rows (each count in every column)
             rows = [greedy, sampled]
             if counts is not None:
                 rows += [jnp.broadcast_to(c, greedy.shape) for c in counts]
@@ -1220,7 +1234,7 @@ class InferenceEngine:
             chunk's boundary greedy/sampled pair as one extra ROW
             ([n+1, K+2] — spec packs are row-per-lane, unlike the
             [2, n+1] column pack of the plain fused step)."""
-            _, p_greedy, p_sampled, cache = _prefill_half(
+            _, p_greedy, p_sampled, cache, _ = _prefill_half(
                 params, cache, p_lane, p_tokens, p_start, p_n,
                 p_temp, p_topp, p_seed, gtab, p_g,
             )
@@ -1332,7 +1346,7 @@ class InferenceEngine:
                 # its mapped blocks hit sentinel entries and drop)
                 with jax.named_scope(SCOPE_CARRY):
                     row = jax.lax.dynamic_slice_in_dim(cache.table, lane, 1, axis=0)
-                logits, lane_cache = forward(
+                logits, lane_cache, counts = forward_n(
                     cfg,
                     params,
                     tokens[None, :],
@@ -1353,7 +1367,7 @@ class InferenceEngine:
                 with jax.named_scope(SCOPE_CARRY):
                     lane_in = jax.tree_util.tree_map(
                         lambda a: jax.lax.dynamic_slice_in_dim(a, lane, 1, axis=1), cache)
-                logits, lane_cache = forward(
+                logits, lane_cache, counts = forward_n(
                     cfg,
                     params,
                     tokens[None, :],
@@ -1387,13 +1401,13 @@ class InferenceEngine:
                     ),
                     lambda: greedy,
                 )
-            return last, greedy, sampled, out_cache
+            return last, greedy, sampled, out_cache, counts
 
         @partial(jax.jit, donate_argnums=(1,))
         @_step_program("tokens")
         def _prefill(params, cache, lane, tokens, start_pos, n_tokens,
                      temp, topp, seed, gtab, p_g):
-            last, greedy, sampled, cache = _prefill_half(
+            last, greedy, sampled, cache, _ = _prefill_half(
                 params, cache, lane, tokens, start_pos, n_tokens,
                 temp, topp, seed, gtab, p_g,
             )
@@ -1434,7 +1448,7 @@ class InferenceEngine:
             idle lane's is. Output is ONE [2, n+1] pack: decode greedy/
             sampled rows plus the prefill boundary pair in the extra
             column."""
-            _, p_greedy, p_sampled, cache = _prefill_half(
+            _, p_greedy, p_sampled, cache, chunk_counts = _prefill_half(
                 params, cache, p_lane, p_tokens, p_start, p_n,
                 p_temp, p_topp, p_seed, gtab, p_g,
             )
@@ -1456,8 +1470,11 @@ class InferenceEngine:
                 # its grammar carry joins the same way: start state advanced
                 # by the boundary token (junk mid-prompt; final chunk wins)
                 new_g = new_g.at[p_lane].set(_g_next1(gtab, p_g, p_first))
-                # the boundary column counts nothing
-                p_counts = counts and tuple(jnp.zeros_like(c) for c in counts)
+                # the boundary column brings the chunk's tiles (TILE_COUNTS);
+                # every other count is the decode steps' alone
+                p_counts = counts and tuple(
+                    c if name in TILE_COUNTS else jnp.zeros_like(c)
+                    for name, c in zip(self._count_names, chunk_counts))
                 packed = jnp.concatenate(
                     [
                         _token_rows(greedy, sampled, counts),
@@ -2275,11 +2292,18 @@ class InferenceEngine:
         if toks_np.shape[0] > 2:
             # a decode step's counts (a routed FFN's slab reads, an indexer's
             # rows), made on the device, came with the tokens (``_token_rows``)
-            got = {name: int(toks_np[2 + i, 0]) for i, name in enumerate(self._count_names)}
+            def column(col):
+                return {name: int(toks_np[2 + i, col]) for i, name in enumerate(self._count_names)}
+
+            got = column(0)
+            # a fused step's boundary column: its chunk's TILE_COUNTS, the rest 0
+            chunk = column(-1) if toks_np.shape[1] > self.n_lanes else {}
             with self.stats.lock:
                 if "slabs" in got:
                     self.stats.moe_slabs_read += got["slabs"]
                     self.stats.moe_assignments += got["assignments"]
+                    self.stats.moe_tile_pairs += got["assignments"] + chunk.get("assignments", 0)
+                    self.stats.moe_tile_rows += got["tiled_rows"] + chunk.get("tiled_rows", 0)
                     self.stats.moe_slabs_whole += self.moe_slabs_per_step
                     self.stats.moe_rows_unheld += got.get("unheld", 0)
                 if "scored" in got:

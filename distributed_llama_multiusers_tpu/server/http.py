@@ -436,11 +436,15 @@ class ApiServer:
             "prefill_attn_blocks_causal": stats["prefill_attn_blocks_causal"],
             # a routed FFN's reads of its expert stacks over the decode
             # steps: distinct (layer, expert) slabs fetched, what a sweep of
-            # every expert fetches, and (row, expert) pairs routed; all 0
-            # for a model without routed layers
+            # every expert fetches, and (row, expert) pairs routed; then, over
+            # those steps AND the prompt chunks that rode them, the pairs that
+            # took a row of the grouped kernel's tiles and those tiles' rows;
+            # all 0 for a model without routed layers
             "moe_slabs_read": stats["moe_slabs_read"],
             "moe_slabs_whole": stats["moe_slabs_whole"],
             "moe_assignments": stats["moe_assignments"],
+            "moe_tile_pairs": stats["moe_tile_pairs"],
+            "moe_tile_rows": stats["moe_tile_rows"],
             # failure containment (multihost.worker_serve): supervised
             # restarts + classified protocol errors on THIS process —
             # non-zero only on pod processes that actually restarted

@@ -243,7 +243,7 @@ def test_the_fields_off_leave_the_latent_blocks_program_as_it_was():
     them set traces the two-leaf program, count for count."""
     cfg3, family3, _ = latent_toy.load()
     eng, _ = latent_toy.engine(family3, cfg3, 3, lanes=4)
-    assert type(eng.cache).__name__ == "KVCache" and eng._count_names == ("slabs", "assignments")
+    assert type(eng.cache).__name__ == "KVCache" and eng._count_names == ("slabs", "assignments", "tiled_rows")
     assert eng.supports_speculative and "index_topk" not in eng.path_facts()
     off = dataclasses.replace(eng.config)
     assert not off.sparse_attention and off.experts_held == (0, off.n_experts)

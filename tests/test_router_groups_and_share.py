@@ -139,7 +139,7 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
     live = jnp.ones(12, bool)
 
     def run(cfg, params):
-        out, slabs, fetched, unheld = deepseek.routed_ffn(cfg, ops, x, params, jnp.int32(0), live)
+        out, slabs, fetched, _, unheld = deepseek.routed_ffn(cfg, ops, x, params, jnp.int32(0), live)
         return np.asarray(out - x, np.float64), int(fetched), int(unheld)
 
     uncut, pairs, none_unheld = run(whole, rp)
