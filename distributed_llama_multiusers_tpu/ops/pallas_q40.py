@@ -19,11 +19,25 @@ and dequantised as one plane, so ONE dot against x as it is replaces the two
 dots against pre-split halves of x that rounds 4 to PR 41 ran: splitting
 x's lane axis into [n_blk, 2, 16] outside the kernel was five XLA
 relayouts a layer, 3.65 ms of an 18.8 ms Mistral decode step (PERF.md
-section 6, PR 42). The -8 nibble offset is folded into one small
-correction dot against per-block x sums instead of a per-weight subtract,
-and the kernel sums x's blocks itself, by a dot (``_block_sums``). Per
-packed byte the VPU does one shift+mask+scale-mul, the rest is MXU work.
-The two block-dot modes keep the transposed (and Q80-quantised) operands,
+section 6, PR 42).
+
+Where the nibbles' -8 goes is read off the call's block of rows, at trace
+time, like the plan (PR 49). Under SUBTRACT_MIN_ROWS rows (every decode step
+of 8-64 lanes) the kernel waits for the VPU's per-weight chain, so the
+offset is FOLDED into one small correction dot against per-block x sums
+instead of a per-weight subtract, and the kernel sums x's blocks itself, by
+a dot (``_block_sums``): per packed byte the VPU does one
+shift+mask+scale-mul, the rest is MXU work. From SUBTRACT_MIN_ROWS rows up
+(the prefill buckets, a decode step of 128 lanes and more) it waits for the
+MXU, and there the correction dot, ``[m, 8 or 16] @ [8 or 16, 512]`` a
+sub-tile a k chunk, fills a 128-deep pass with 8 or 16 rows: a quarter of
+the kernel's time at 1024 rows. So there the 8 is SUBTRACTED from the nibbles before they are
+converted and scaled, ``w = (q - 8) * s`` as the reference's own
+dequantisation writes it, and neither the block sums nor the correction dot
+is traced: one dot a sub-tile a k chunk is left, and the VPU's extra
+operation hides under it (PERF.md section 6, PR 49, has the lab's table).
+``TRACE_STATS["offset_subtracted_traces"]`` counts such bodies. The two
+block-dot modes keep the transposed (and Q80-quantised) operands,
 built where their kernels are called (``_block_dot_operands``).
 
 Block layout (round-4 rework, from pure-read measurements on a real v5e):
@@ -131,7 +145,9 @@ BLOCKDOT_MAX_M = 32  # above this, the post-scale FMA outweighs the savings
 # its own column order and dtype; there is no other form to fall back to,
 # and `weight_passes_max` is the witness that a slab meets all of a call's
 # rows (PR 45): `InferenceEngine.path_facts()` says it at start-up, so a
-# run whose 1024-row calls still pay four passes is not silent.
+# run whose 1024-row calls still pay four passes is not silent; it says
+# `offset_subtracted_traces` beside it (PR 49), so neither is a run whose
+# 1024-row calls still carry the correction dot.
 TRACE_STATS = {
     # q40_matmul_pallas calls that read their layer's tiles out of a stack
     "stacked_consumes": 0,
@@ -140,6 +156,9 @@ TRACE_STATS = {
     # the most passes over its weight plane any traced kernel call makes
     # (m_pad // m_block: 1 for every call of up to M_BLOCK_MAX rows)
     "weight_passes_max": 0,
+    # slab-chain kernel bodies traced with the -8 subtracted in the dequant
+    # chain and NO correction dot (blocks of SUBTRACT_MIN_ROWS rows and more)
+    "offset_subtracted_traces": 0,
 }
 
 
@@ -148,6 +167,13 @@ def reset_trace_stats() -> None:
         TRACE_STATS[k] = 0
 
 M_TILE = 256  # rows a call is padded to whole multiples of above this
+# Blocks of this many rows and more take the nibbles' -8 off in the dequant
+# chain; smaller ones fold it into a correction dot (``_q40_slab_kernel``).
+# The smallest row count from which subtracting is no slower than folding on
+# every shape of PR 49's lab (scripts/q40_offset_lab.py, the kernel alone on
+# a v5e over fifteen of the cells' planes): -3 to -9 % at 128 rows, -10 to
+# -18 % at 256, -12 to -27 % at 1024; +2 to +10 % at 16-64 rows.
+SUBTRACT_MIN_ROWS = 128
 # Rows one weight slab meets before the kernel moves to the next (PR 45): a
 # slab is fetched and dequantised ONCE for a block of rows, so a call of up
 # to this many rows (the widest prefill bucket) makes one pass over the
@@ -320,7 +346,7 @@ def _block_sums(x):
 
 
 def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
-                     w_dtype, sub_tiles, n_k, mode):
+                     w_dtype, sub_tiles, n_k, mode, fold):
     """One (m block, d_out wide-tile, d_in chunk) step over a contiguous
     weight slab. ``x`` arrives as it is: this chunk's ``2 * rows`` columns in
     their own order and their own dtype.
@@ -328,10 +354,17 @@ def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
     - the low/high nibble planes are interleaved by whole 16-row pieces
       into the input's order (``_natural_order``), dequantised as one plane
       and meet x in ONE dot of depth ``2 * rows``;
-    - NO per-weight -8 subtract: folded into one small correction dot,
-      8 * (per-block x sums) @ scales, subtracted from the partial sum. The
-      block sums are dots too, once a grid step, BSUM_SLICE columns of x at
-      most to a dot (``_block_sums``);
+    - ``fold`` (a block under SUBTRACT_MIN_ROWS rows, where the VPU's chain
+      sets the pace): NO per-weight -8 subtract: folded into one small
+      correction dot, 8 * (per-block x sums) @ scales, subtracted from the
+      partial sum. The block sums are dots too, once a grid step, BSUM_SLICE
+      columns of x at most to a dot (``_block_sums``);
+    - not ``fold`` (larger blocks, where the MXU does): 8 comes off the
+      joined nibble plane before it is converted and scaled (u8chain: after
+      its bf16 cast, exact there, Mosaic having no 8-bit-lane subtract), and
+      no block sum and no correction dot is traced: the main dot alone. The
+      weight is ``bf16((q - 8) * s)``, at most 8 s where the folded form
+      rounds ``q * s`` up to 15 s;
     - dequant walks the slab in `sub_tiles`-lane slices to bound the VMEM
       transient (the slab itself can be megabytes wide).
 
@@ -342,10 +375,11 @@ def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
     n_blk = rows // 16
     k = pl.program_id(2)
     # the dot's compute dtype first (a no-op in every cell: x is bf16), so
-    # both terms of the folded -8 see the same rounded x
+    # both terms of a folded -8 see the same rounded x
     x = x_ref[...].astype(w_dtype)
-    bsum = _block_sums(x)  # [mt, n_blk] f32, in slices of blk_c blocks
-    blk_c = n_blk // len(bsum)
+    if fold:
+        bsum = _block_sums(x)  # [mt, n_blk] f32, in slices of blk_c blocks
+        blk_c = n_blk // len(bsum)
 
     off = 0
     for t in sub_tiles:
@@ -361,9 +395,14 @@ def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
             hi8 = (p8.astype(jnp.int32) >> 4).astype(jnp.int8)
             nib = _natural_order(lo8.astype(jnp.bfloat16),
                                  hi8.astype(jnp.bfloat16), n_blk, t)
+            if not fold:
+                # exact in bf16 (Mosaic has no 8-bit-lane subtract either)
+                nib = nib - jnp.bfloat16(8)
         else:
             p = packed_ref[:, off:off + t].astype(jnp.int32)
             nib = _natural_order(p & 0x0F, p >> 4, n_blk, t)
+            if not fold:
+                nib = nib - 8
         if mode in ("bf16chain", "u8chain"):
             # dequant stays in bf16: nibbles (0..15, exact in bf16) cast
             # once, scales rounded to bf16 once per block (amortized /32),
@@ -380,13 +419,16 @@ def _q40_slab_kernel(x_ref, packed_ref, scales_ref, out_ref, acc_ref, *,
             w = (nib.astype(jnp.float32) * s[:, None, :]).reshape(
                 2 * rows, t).astype(w_dtype)
 
-        # folded -8 offset: 8 * bsum_b @ s == sum_i x_i * 8 * s_block(i)
-        corr = None
-        for j, b in enumerate(bsum):
-            c = jnp.dot(b, s[j * blk_c:(j + 1) * blk_c],
-                        preferred_element_type=jnp.float32)
-            corr = c if corr is None else corr + c
-        part = jnp.dot(x, w, preferred_element_type=jnp.float32) - 8.0 * corr
+        if fold:
+            # folded -8 offset: 8 * bsum_b @ s == sum_i x_i * 8 * s_block(i)
+            corr = None
+            for j, b in enumerate(bsum):
+                c = jnp.dot(b, s[j * blk_c:(j + 1) * blk_c],
+                            preferred_element_type=jnp.float32)
+                corr = c if corr is None else corr + c
+        part = jnp.dot(x, w, preferred_element_type=jnp.float32)
+        if fold:
+            part = part - 8.0 * corr
         _acc_epilogue(part, off, t, k, n_k, out_ref, acc_ref)
         off += t
     _final_writeback(k, n_k, out_ref, acc_ref)
@@ -755,8 +797,11 @@ def _q40_matmul_core(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
         TRACE_STATS["natural_x_consumes"] += 1
         x_ops = (x_rows,)
         x_specs = [pl.BlockSpec((m_block, 2 * rows), lambda i, j, k, l: (i, k))]
+        # where the -8 goes is read off the block of rows, like the plan
+        fold = m_block < SUBTRACT_MIN_ROWS
+        TRACE_STATS["offset_subtracted_traces"] += int(not fold)
         kernel = partial(_q40_slab_kernel, w_dtype=w_dtype, sub_tiles=sub,
-                         n_k=n_k, mode=mode)
+                         n_k=n_k, mode=mode, fold=fold)
 
     out_dtype = x.dtype
     out = pl.pallas_call(

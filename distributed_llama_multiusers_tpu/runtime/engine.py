@@ -1645,9 +1645,12 @@ class InferenceEngine:
 
     def path_facts(self) -> dict:
         """Which attention and which expert path this engine's decode steps
-        run, by the predicates the forward itself asks: said once at
-        start-up (the ``runtime_device`` line, ``/stats``), so that a
-        fallback is never silent."""
+        run, by the predicates the forward itself asks, and what the Q40
+        kernel bodies traced so far are (``q40_weight_passes``,
+        ``q40_offset_subtracted``: the trace-time witnesses of
+        ``ops/pallas_q40.py``): said once at start-up (the
+        ``runtime_device`` line, ``/stats``), so that a fallback is never
+        silent."""
         from ..ops.linear import pallas_kernel_active
         from ..ops.pallas_q40 import TRACE_STATS as q40_trace_stats
         from ..ops.pallas_q40_grouped import grouped_supports
@@ -1674,7 +1677,13 @@ class InferenceEngine:
                  # the most passes over its weight plane any Q40 kernel call
                  # traced so far makes (after warm-up: 1 where every prefill
                  # bucket's rows share one dequantised slab; 0: none traced)
-                 "q40_weight_passes": q40_trace_stats["weight_passes_max"]}
+                 "q40_weight_passes": q40_trace_stats["weight_passes_max"],
+                 # Q40 kernel bodies traced so far that take the nibbles' -8
+                 # off in the dequant chain and hold no correction dot (after
+                 # warm-up: above 0 wherever a prefill bucket or the lanes
+                 # reach ops.pallas_q40.SUBTRACT_MIN_ROWS rows; 0: every call
+                 # still folds it)
+                 "q40_offset_subtracted": q40_trace_stats["offset_subtracted_traces"]}
         if cfg.sparse_attention:
             # how the chosen rows are read (models/deepseek.py: gathered,
             # at every width), and what an indexer's rows are not computed for

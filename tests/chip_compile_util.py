@@ -20,6 +20,7 @@ files of under two minutes each (the cases and their names are the same):
   test_chip_compile_q40_decode.py     the dense Q40 kernel, decode-width rows
   test_chip_compile_q40_prefill.py    ... at 1024 rows, the 1B / 8B shapes, planes
   test_chip_compile_q40_prefill_stacked.py   ... the same rows, a layer of a stack
+  test_chip_compile_q40_rows256.py    ... one block of 256 rows, every cell's shapes
   test_chip_compile_q40_rows512.py    ... one block of 512 rows, every cell's stacks
   test_chip_compile_q40_rows1024.py   ... one block of 1024 rows, every cell's stacks
   test_chip_compile_q40_heads.py      ... 512 and 1024 rows, every cell's head
@@ -184,11 +185,12 @@ CELL_SHAPES = [
 
 
 def check_one_row_block(v5e, d_in, d_out, stacked, m):
-    """The default mode at a prefill bucket above 256 rows, where the block of
-    rows is the call's rows (PR 45): Mosaic takes the x block, the f32
-    accumulator and the output block of ``m`` rows against every wide tile
-    the cells have, under the ceiling the plan asks for; one kernel call, no
-    lane of x split."""
+    """The default mode at a prefill bucket's rows, where the block of rows is
+    the call's rows (PR 45) and, from SUBTRACT_MIN_ROWS up, the body takes
+    the -8 off in the dequant chain (PR 49): Mosaic takes the x block, the
+    f32 accumulator and the output block of ``m`` rows against every wide
+    tile the cells have, under the ceiling the plan asks for; one kernel
+    call, no lane of x split."""
     w_tile, rows = pq._plan_blocks(d_in, d_out)
     n_k = (d_in // 2) // rows
     assert pq._row_plan(m, w_tile, rows, n_k, 2)[0] == m  # one pass

@@ -41,6 +41,20 @@ def test_every_selectable_mode_compiles_for_v5e(v5e, mode, d_in, d_out, m):
     assert not (_is_slab_chain(mode, m) and _lane_splits(hlo))
 
 
+@pytest.mark.parametrize("m", [128, 256, 512])
+@pytest.mark.parametrize("d_in,d_out", TWO_SHAPES)
+@pytest.mark.parametrize("mode", [m for m in pq.DEQUANT_MODES
+                                  if m not in pq.BLOCK_DOT_MODES])
+def test_subtracted_offset_compiles_in_every_slab_chain(v5e, mode, d_in, d_out, m):
+    """PR 49: the body that takes the -8 off in the dequant chain (no block
+    sums, no correction dot) at the narrower blocks that trace it, in each
+    of the four slab chains (1024 rows: the two tests above; u8chain subtracts
+    after its bf16 cast, Mosaic having no 8-bit-lane subtract for the v5e)."""
+    assert m >= pq.SUBTRACT_MIN_ROWS
+    hlo = _compile(v5e, mode, d_in, d_out, m)
+    assert "tpu_custom_call" in hlo and _lane_splits(hlo) == []
+
+
 def test_bf16chain_compiles_at_the_widest_slab_for_v5e(v5e):
     """bf16chain (what a block-dot mode is served by above BLOCKDOT_MAX_M)
     at 1024 rows against the 1B head's 8192-wide slabs: the one case of the

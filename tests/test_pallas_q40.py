@@ -1197,18 +1197,20 @@ def test_a_narrowed_wide_tile_does_not_change_a_result():
                                atol=2e-4, rtol=2e-4)
 
 
+def _trace_w1_call(m, mode="v4"):
+    """Trace (nothing runs) a bf16 call of ``m`` rows at Mistral's w1."""
+    w = PackedQ40(packed=jax.ShapeDtypeStruct((2048, 14336), jnp.uint8),
+                  scales=jax.ShapeDtypeStruct((128, 14336), jnp.float16))
+    x = jax.ShapeDtypeStruct((m, 4096), jnp.bfloat16)
+    jax.eval_shape(lambda x, w: pq._q40_matmul_core(
+        x, w, True, jnp.bfloat16, mode), x, w)
+
+
 def test_weight_passes_witness_in_trace_stats_and_path_facts(witness_engine):
     """After tracing a 1024-row and a 16-row call the witness reads 1 (the
     parent's plan made 4 passes at 1024 rows: ``m_pad // 256``), and the
     engine's start-up facts carry it; a call past M_BLOCK_MAX says so."""
-    w = PackedQ40(packed=jax.ShapeDtypeStruct((2048, 14336), jnp.uint8),
-                  scales=jax.ShapeDtypeStruct((128, 14336), jnp.float16))
-
-    def trace(m):
-        x = jax.ShapeDtypeStruct((m, 4096), jnp.bfloat16)
-        jax.eval_shape(lambda x, w: pq._q40_matmul_core(
-            x, w, True, jnp.bfloat16, "v4"), x, w)
-
+    trace = _trace_w1_call
     reset_trace_stats()
     assert witness_engine.path_facts()["q40_weight_passes"] == 0  # none traced
     trace(1024)
@@ -1219,3 +1221,187 @@ def test_weight_passes_witness_in_trace_stats_and_path_facts(witness_engine):
     assert TRACE_STATS["weight_passes_max"] == 4
     assert witness_engine.path_facts()["q40_weight_passes"] == 4
     reset_trace_stats()
+
+
+# ---------------------------------------------------------------------------
+# PR 49: where the nibbles' -8 goes is read off the block of rows. A block of
+# SUBTRACT_MIN_ROWS rows and more takes it off the nibbles in the dequant
+# chain and traces neither the block sums nor the correction dot; a smaller
+# block traces the body it always did.
+# ---------------------------------------------------------------------------
+
+T = pq.SUBTRACT_MIN_ROWS
+# (d_in, d_out) small enough for interpret mode, one of every class of plan
+# the cells' shapes have: (k chunks?, wide tiles?, block-sum slices?)
+OFFSET_PLANS = {
+    "one_slab": (64, 256),
+    "k_chunks": (2048, 1152),          # sub tiles 512 + 512 + 128
+    "two_wide_tiles": (64, 16384),
+    "k_chunks_and_wide_tiles": (512, 16384),
+    "block_sum_slices": (7168, 128),   # the whole half one chunk: 4 slices
+    "k_chunks_of_slices": (7168, 576),  # DeepSeek's wkva: two chunks of two
+}
+
+
+def _plan_class(d_in, d_out):
+    w_tile, rows = pq._plan_blocks(d_in, d_out)
+    return ((d_in // 2) // rows > 1, d_out // w_tile > 1,
+            (2 * rows) // pq._sum_slice(2 * rows) > 1)
+
+
+def test_offset_plans_cover_every_cell_shapes_plan():
+    from chip_compile_util import CELL_SHAPES
+
+    tested = {_plan_class(*shape) for shape in OFFSET_PLANS.values()}
+    assert len(tested) == len(OFFSET_PLANS)
+    assert {_plan_class(d_in, d_out) for d_in, d_out, _ in CELL_SHAPES} <= tested
+
+
+def _kernel_dots(m, d_in, d_out, mode="v4", w_dtype=jnp.float32):
+    """dot_general equations in the kernel body a call of ``m`` rows traces."""
+    w = PackedQ40(packed=jax.ShapeDtypeStruct((d_in // 2, d_out), jnp.uint8),
+                  scales=jax.ShapeDtypeStruct((d_in // 32, d_out), jnp.float16))
+    x = jax.ShapeDtypeStruct((m, d_in), w_dtype)
+    jaxpr = jax.make_jaxpr(lambda x, w: pq._q40_matmul_core(
+        x, w, True, w_dtype, mode))(x, w)
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return str(call.params["jaxpr"]).count("dot_general")
+
+
+@pytest.mark.parametrize("plan", list(OFFSET_PLANS))
+@pytest.mark.parametrize("m", [T - 8, T, T + 8], ids=["under", "at", "over"])
+def test_offset_form_either_side_of_the_threshold(m, plan):
+    """Both forms against the XLA dequant, at every class of plan the cells
+    have; the witness counts the bodies without a correction dot and only
+    those; the subtracting body holds one dot a sub-tile, the folding one two
+    and a dot a block-sum slice."""
+    d_in, d_out = OFFSET_PLANS[plan]
+    rng = np.random.default_rng(49 + d_in + d_out)
+    w = _pack(rng, d_out, d_in)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+    pq._q40_matmul_pallas_impl.clear_cache()
+    reset_trace_stats()
+    got = np.asarray(q40_matmul_pallas(x, w, interpret=True))
+    assert TRACE_STATS["impl_traces"] == 1, TRACE_STATS
+    assert TRACE_STATS["offset_subtracted_traces"] == (m >= T), TRACE_STATS
+    np.testing.assert_allclose(got, np.asarray(q40_matmul_xla(x, w)),
+                               atol=2e-4, rtol=2e-4)
+    w_tile, rows = pq._plan_blocks(d_in, d_out)
+    m_block, w_tile = pq._row_plan(pq._m_geometry(m, x.dtype)[0], w_tile, rows,
+                                   (d_in // 2) // rows, 4)
+    n_sub = len(pq._sub_tiles(w_tile))
+    slices = (2 * rows) // pq._sum_slice(2 * rows)
+    assert _kernel_dots(m, d_in, d_out) == (
+        n_sub if m >= T else slices + n_sub * (1 + slices))
+
+
+@pytest.mark.parametrize("mode", ["v4", "bf16chain", "repeat", "u8chain"])
+def test_offset_subtracted_in_every_slab_chain(mode):
+    """The four slab chains in bf16, as the cells run v4: each takes the 8 off
+    before the scale and holds one dot a sub-tile; the result is as close to
+    the XLA dequant as the folded form's, and the three bf16 chains agree to
+    the bit (the nibbles less 8 are exact in bf16 wherever they are taken)."""
+    d_in, d_out = OFFSET_PLANS["k_chunks"]
+    rng = np.random.default_rng(490)
+    w = _pack(rng, d_out, d_in)
+    x = jnp.asarray(rng.standard_normal((T, d_in), dtype=np.float32)).astype(jnp.bfloat16)
+    want = np.asarray(q40_matmul_xla(x.astype(jnp.float32), w))
+    got = {}
+    for md in (mode, "bf16chain"):
+        set_dequant_mode(md)
+        try:
+            got[md] = np.asarray(q40_matmul_pallas(
+                x, w, interpret=True, w_dtype=jnp.bfloat16)).astype(np.float32)
+        finally:
+            set_dequant_mode(None)
+    assert _kernel_dots(T, d_in, d_out, mode, jnp.bfloat16) == 3
+    assert np.abs(got[mode] - want).max() <= 1e-2 * np.abs(want).max()
+    if mode != "v4":
+        np.testing.assert_array_equal(got[mode], got["bf16chain"])
+
+
+@pytest.mark.parametrize("m", [T - 8, T, 2 * T], ids=["under", "at", "two_tiles"])
+def test_offset_form_on_a_stack_with_a_traced_layer(m):
+    """A layer read out of a stack under a traced index, the counter of a scan
+    as the layer loop hands it: equal to the plane's own call to the bit on
+    either side of the threshold, and to the XLA dequant."""
+    d_in, d_out = OFFSET_PLANS["k_chunks"]
+    rng = np.random.default_rng(m)
+    stack = _stack(rng, d_out, d_in, n=2)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+    _, got = jax.lax.scan(
+        lambda c, l: (c, q40_matmul_pallas(x, stack, interpret=True, layer=l)),
+        0, jnp.arange(2, dtype=jnp.int32))
+    for l in (0, 1):
+        plane = _plane(stack, l)
+        np.testing.assert_array_equal(
+            np.asarray(got[l]), np.asarray(q40_matmul_pallas(x, plane, interpret=True)))
+        np.testing.assert_allclose(np.asarray(got[l]), np.asarray(q40_matmul_xla(x, plane)),
+                                   atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("plan", ["k_chunks", "two_wide_tiles"])
+def test_a_subtracted_rows_result_does_not_depend_on_its_block(plan):
+    """Rows in a block of 2 T with other rows beside them, in a block of
+    T alone, and beside different rows: the same bits each time."""
+    d_in, d_out = OFFSET_PLANS[plan]
+    rng = np.random.default_rng(4949)
+    w = _pack(rng, d_out, d_in)
+    a, b, c = (jnp.asarray(rng.standard_normal((T, d_in), dtype=np.float32))
+               for _ in range(3))
+    alone = np.asarray(q40_matmul_pallas(a, w, interpret=True))
+    beside_b = np.asarray(q40_matmul_pallas(jnp.concatenate([a, b]), w, interpret=True))
+    beside_c = np.asarray(q40_matmul_pallas(jnp.concatenate([c, a]), w, interpret=True))
+    np.testing.assert_array_equal(beside_b[:T], alone)
+    np.testing.assert_array_equal(beside_c[T:], alone)
+
+
+def test_offset_witness_in_trace_stats_and_path_facts(witness_engine):
+    """The engine's start-up facts carry the count of kernel bodies traced
+    without the correction dot: 0 after decode-width calls alone, one more a
+    prefill-width body."""
+    trace = _trace_w1_call
+    reset_trace_stats()
+    for m in (8, 16, 32, 64, T - 16):
+        trace(m)
+    trace(16, "blockdot")
+    assert TRACE_STATS["offset_subtracted_traces"] == 0, TRACE_STATS
+    assert witness_engine.path_facts()["q40_offset_subtracted"] == 0
+    for m in (T, 512, 1024):
+        trace(m)
+    assert TRACE_STATS["offset_subtracted_traces"] == 3, TRACE_STATS
+    assert witness_engine.path_facts()["q40_offset_subtracted"] == 3
+    reset_trace_stats()
+
+
+# sha256[:16] of the output bytes of the seeded call below, 112 rows, on the
+# PARENT of PR 49 (commit 0b69b2f), whose kernel folded the -8 at every row
+# count
+PARENT_FOLDED_DIGESTS = {"f32": "cfc4611c88a055e3", "v4": "438cc3ffae29ff8a"}
+
+
+@pytest.mark.parametrize("dot", ["f32", "v4"])
+def test_a_block_under_the_threshold_gives_what_the_parent_gave(dot):
+    """The largest block that still folds the -8 (16 rows under
+    SUBTRACT_MIN_ROWS: bf16 rows pad to whole 16-row tiles) traces the body
+    the tree had before PR 49: the same bits, and no body counted as
+    subtracting. (The traced programs of 16 such calls, the four slab chains
+    at four cell shapes, were compared with the parent's equation by
+    equation when this was written: identical.)"""
+    import hashlib
+
+    m = T - 16
+    assert m == 112  # what the digests were taken at
+    rng = np.random.default_rng(49)
+    pw = _pack(rng, 1152, 2048)
+    x = jnp.asarray(rng.standard_normal((m, 2048), dtype=np.float32))
+    kw = {} if dot == "f32" else {"w_dtype": jnp.bfloat16}
+    if dot == "v4":
+        x = x.astype(jnp.bfloat16)
+    pq._q40_matmul_pallas_impl.clear_cache()
+    reset_trace_stats()
+    got = np.asarray(q40_matmul_pallas(x, pw, interpret=True, **kw))
+    assert TRACE_STATS["impl_traces"] == 1, TRACE_STATS
+    assert TRACE_STATS["offset_subtracted_traces"] == 0, TRACE_STATS
+    assert (hashlib.sha256(got.tobytes()).hexdigest()[:16]
+            == PARENT_FOLDED_DIGESTS[dot])
