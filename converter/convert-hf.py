@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Convert a HuggingFace checkpoint folder to the `.m` format (model types
-llama, mistral, mixtral, qwen2, deepseek_v3, deepseek_v32, lfm2_moe, jamba).
+llama, mistral, mixtral, qwen2, deepseek_v3, deepseek_v32, lfm2_moe, jamba,
+cohere2_moe, minicpm_sala).
 
 Usage: python convert-hf.py <sourceFolderPath> <weightsFloatType> <name>
 
@@ -99,6 +100,10 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         # rotation, heads of head_dim, a parallel block under one layer norm,
         # shared experts averaged: LayerKind.WINDOW and the KEY_HEAD_DIM keys
         "cohere2_moe": ArchType.LLAMA,
+        # lightning linear-attention layers beside block-sparse GQA layers
+        # under scaled residuals: LayerKind.LINEAR / SPARSE, the
+        # KEY_LINEAR_* / KEY_SPARSE_* keys and the three scalars
+        "minicpm_sala": ArchType.LLAMA,
     }.get(cfg["model_type"])
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
@@ -131,6 +136,8 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         set_ssm_header(h, cfg)
     if cfg["model_type"] == "cohere2_moe":
         set_window_header(h, cfg)
+    if cfg["model_type"] == "minicpm_sala":
+        set_sala_header(h, cfg)
     n_experts = cfg.get("num_local_experts")
     if n_experts:
         h.n_experts = int(n_experts)
@@ -256,6 +263,90 @@ def set_ssm_header(h: ModelHeader, cfg: dict) -> None:
     h.ssm_conv_kernel = int(cfg["mamba_d_conv"])
     h.ssm_conv_bias = int(bool(cfg.get("mamba_conv_bias", True)))
     h.ssm_inner_norms = 1  # dt_layernorm, b_layernorm, c_layernorm: the family's
+
+
+# the sparse sizes of the family's MiniCPM4 release (arXiv:2506.07900), which a
+# minicpm_sala config.json does not repeat; a ``sparse_config`` in it wins
+_SALA_SPARSE_DEFAULTS = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64, "topk": 64,
+                         "window_size": 2048, "init_blocks": 1, "dense_len": 8192}
+_SALA_LAYER_KINDS = {"lightning-attn": LayerKind.LINEAR, "minicpm4": LayerKind.SPARSE}
+
+
+def set_sala_header(h: ModelHeader, cfg: dict) -> None:
+    """The header keys of ``model_type: minicpm_sala`` (formats/model_file.py
+    KEY_LINEAR_N_HEADS ...): a layer is lightning linear attention or
+    block-sparse GQA by ``mixer_types``, queries and keys are normed per head,
+    the lightning layers rotate and the sparse ones do not, every FFN is a
+    plain MLP, and the three scalars of the parametrisation ride the header.
+    What the runtime does not compute is refused here, by name."""
+    kinds = cfg["mixer_types"]
+    unknown = sorted(set(kinds) - set(_SALA_LAYER_KINDS))
+    if unknown or len(kinds) != h.n_layers:
+        raise ValueError(f"Unsupported minicpm_sala mixer types: {unknown or len(kinds)}")
+    wanted = {"qk_norm": True, "lightning_use_rope": True, "attn_use_rope": False,
+              "use_output_gate": True, "use_output_norm": True, "attn_use_output_gate": True,
+              "attention_bias": False}
+    for key, want in wanted.items():
+        if bool(cfg.get(key, want)) != want:
+            raise ValueError(f"Unsupported minicpm_sala setting: {key} = {cfg.get(key)!r}")
+    if int(cfg["lightning_nkv"]) != int(cfg["lightning_nh"]):
+        raise ValueError(
+            f"Unsupported minicpm_sala setting: lightning_nkv = {cfg['lightning_nkv']} "
+            "(a key head a query head)")
+    h.layer_kinds = [_SALA_LAYER_KINDS[k] for k in kinds]
+    h.qk_norm, h.full_attention_nope = 1, 1
+    h.head_dim = int(cfg.get("head_dim") or 0)
+    h.norm_epsilon = float(cfg["rms_norm_eps"])
+    h.linear_n_heads, h.linear_head_dim = int(cfg["lightning_nh"]), int(cfg["lightning_head_dim"])
+    sparse = {**_SALA_SPARSE_DEFAULTS, **(cfg.get("sparse_config") or {})}
+    h.sparse_kernel_size, h.sparse_kernel_stride = int(sparse["kernel_size"]), int(sparse["kernel_stride"])
+    h.sparse_block_size, h.sparse_topk = int(sparse["block_size"]), int(sparse["topk"])
+    h.sparse_window, h.sparse_init_blocks = int(sparse["window_size"]), int(sparse["init_blocks"])
+    h.sparse_dense_len = int(sparse["dense_len"])
+    h.embed_scale = float(cfg["scale_emb"])
+    h.residual_scale = float(cfg["scale_depth"]) / float(h.n_layers) ** 0.5
+    h.logit_divisor = float(cfg["hidden_size"]) / float(cfg["dim_model_base"])
+
+
+def write_sala_layers(out, index, header: ModelHeader, wt: int) -> None:
+    """The layers of a minicpm_sala checkpoint in the order of
+    formats/model_file._pattern_block_specs, under the tensor names the
+    family's modeling file is taken to give them (no checkpoint was read:
+    ``self_attn.{q,k,v,o}_proj``, ``q_norm``, ``k_norm``, ``o_gate`` in both
+    kinds, ``o_norm`` in a lightning layer). A lightning layer's q and k rows
+    are permuted to the interleaved-pair layout and their per-head norm gains
+    alike (they are rotated); a sparse layer's are not (nothing is rotated)."""
+    from numpy import ascontiguousarray as contiguous
+
+    def pairs(g):  # a head's gains in the interleaved-pair order of its rows
+        return contiguous(permute_rotary(g.reshape(-1, 1), 1).reshape(-1))
+
+    for l, kind in enumerate(header.layer_kinds):
+        a = f"model.layers.{l}.self_attn"
+        if kind == LayerKind.LINEAR:
+            heads = header.linear_n_heads
+            write_tensor(out, permute_rotary(index.get(f"{a}.q_proj.weight"), heads), wt)
+            write_tensor(out, permute_rotary(index.get(f"{a}.k_proj.weight"), heads), wt)
+            write_tensor(out, index.get(f"{a}.v_proj.weight"), wt)
+            write_tensor(out, pairs(index.get(f"{a}.q_norm.weight")), FloatType.F32)
+            write_tensor(out, pairs(index.get(f"{a}.k_norm.weight")), FloatType.F32)
+            write_tensor(out, index.get(f"{a}.o_gate.weight"), wt)
+            write_tensor(out, index.get(f"{a}.o_norm.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{a}.o_proj.weight"), wt)
+        else:
+            for name in ("q_proj", "k_proj", "v_proj"):
+                write_tensor(out, index.get(f"{a}.{name}.weight"), wt)
+            write_tensor(out, index.get(f"{a}.q_norm.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{a}.k_norm.weight"), FloatType.F32)
+            write_tensor(out, index.get(f"{a}.o_gate.weight"), wt)
+            write_tensor(out, index.get(f"{a}.o_proj.weight"), wt)
+        mlp = f"model.layers.{l}.mlp"
+        write_tensor(out, index.get(f"{mlp}.gate_proj.weight"), wt)  # w1
+        write_tensor(out, index.get(f"{mlp}.down_proj.weight"), wt)  # w2
+        write_tensor(out, index.get(f"{mlp}.up_proj.weight"), wt)  # w3
+        write_tensor(out, index.get(f"model.layers.{l}.input_layernorm.weight"), FloatType.F32)
+        write_tensor(out, index.get(f"model.layers.{l}.post_attention_layernorm.weight"),
+                     FloatType.F32)
 
 
 _WINDOW_LAYER_KINDS = {"sliding_attention": LayerKind.WINDOW,
@@ -504,6 +595,8 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
             write_latent_layers(out, index, header, wt)
         elif header.ssm_d_inner:
             write_ssm_layers(out, index, header, wt)
+        elif header.linear_n_heads:
+            write_sala_layers(out, index, header, wt)
         elif header.parallel_block:
             write_window_layers(out, index, header, wt)
         elif header.layer_kinds:
@@ -541,7 +634,7 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
             write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
         # lfm2_moe names its final norm after the embedding, jamba after its place
         norm_key = ("model.final_layernorm.weight" if header.ssm_d_inner
-                    else "model.norm.weight" if header.parallel_block
+                    else "model.norm.weight" if header.parallel_block or header.linear_n_heads
                     else "model.embedding_norm.weight" if header.layer_kinds
                     else "model.norm.weight")
         write_tensor(out, index.get(norm_key), FloatType.F32)

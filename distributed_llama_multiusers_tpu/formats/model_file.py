@@ -141,6 +141,32 @@ KEY_FULL_ATTENTION_NOPE = 53
 KEY_NORM_KIND = 54
 KEY_PARALLEL_BLOCK = 55
 KEY_SHARED_EXPERT_SCALE_E6 = 56
+# framework extension: what ``model_type: minicpm_sala`` adds to a block of
+# mixed layers, each key written only where it is set, so every file without
+# them reads, and is written, as before. A linear-attention layer
+# (``LayerKind.LINEAR``): its heads and their width (a float32 matrix state
+# ``[head, head]`` a head a lane; its decay a head is fixed by the head's
+# number, models/hybrid.py ``linear_decay_slopes``). A block-sparse GQA layer
+# (``LayerKind.SPARSE``): keys compressed by a mean over ``kernel_size``
+# positions every ``kernel_stride``, blocks of ``block_size`` positions of
+# which a query row at or past position ``dense_len`` attends the ``topk``
+# its compressed keys' scores choose, the first ``init_blocks`` and the
+# blocks of the newest ``window`` positions always among them. Three scalars
+# of a width-independent parametrisation, in millionths: the factor on the
+# embedding, the factor on every mixer's and FFN's term before it joins the
+# stream, the divisor under the final norm's output before the head.
+KEY_LINEAR_N_HEADS = 57
+KEY_LINEAR_HEAD_DIM = 58
+KEY_SPARSE_KERNEL_SIZE = 59
+KEY_SPARSE_KERNEL_STRIDE = 60
+KEY_SPARSE_BLOCK_SIZE = 61
+KEY_SPARSE_TOPK = 62
+KEY_SPARSE_WINDOW = 63
+KEY_SPARSE_INIT_BLOCKS = 64
+KEY_SPARSE_DENSE_LEN = 65
+KEY_EMBED_SCALE_E6 = 66
+KEY_RESIDUAL_SCALE_E6 = 67
+KEY_LOGIT_DIVISOR_E6 = 68
 
 
 class ArchType:
@@ -168,6 +194,12 @@ class LayerKind:
     # GQA over the newest ``sliding_window`` positions, kept in a ring
     # ("sliding_attention"); its weights are stacked with ATTENTION's
     WINDOW = 3
+    # linear attention: a decayed float32 matrix state a head a lane, no
+    # cache by position ("lightning-attn")
+    LINEAR = 4
+    # GQA over the blocks of the KV cache that its compressed keys choose,
+    # with an output gate ("minicpm4"); its planes are stacked with ATTENTION's
+    SPARSE = 5
 
 
 class NormKind:
@@ -252,6 +284,19 @@ class ModelHeader:
     norm_kind: int = NormKind.RMS
     parallel_block: int = 0
     shared_expert_scale: float = 1.0
+    # what minicpm_sala adds (KEY_LINEAR_N_HEADS ...); unset elsewhere
+    linear_n_heads: int = 0
+    linear_head_dim: int = 0
+    sparse_kernel_size: int = 0
+    sparse_kernel_stride: int = 0
+    sparse_block_size: int = 0
+    sparse_topk: int = 0
+    sparse_window: int = 0
+    sparse_init_blocks: int = 0
+    sparse_dense_len: int = 0
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
     header_size: int = 0
     file_size: int = 0
 
@@ -317,7 +362,13 @@ class ModelHeader:
         ] + (
             [(KEY_SHARED_EXPERT_SCALE_E6, int(round(self.shared_expert_scale * 1e6)))]
             if self.shared_expert_scale != 1.0 else []
-        )
+        ) + [
+            (key, getattr(self, name)) for key, name in _LINEAR_SPARSE_INT_KEYS.items()
+            if getattr(self, name)
+        ] + [
+            (key, int(round(getattr(self, name) * 1e6)))
+            for key, name in _SCALE_E6_KEYS.items() if getattr(self, name) != 1.0
+        ]
 
 
 _LATENT_INT_KEYS = {
@@ -358,6 +409,22 @@ _WINDOW_INT_KEYS = {
     KEY_NORM_KIND: "norm_kind",
     KEY_PARALLEL_BLOCK: "parallel_block",
 }
+_LINEAR_SPARSE_INT_KEYS = {
+    KEY_LINEAR_N_HEADS: "linear_n_heads",
+    KEY_LINEAR_HEAD_DIM: "linear_head_dim",
+    KEY_SPARSE_KERNEL_SIZE: "sparse_kernel_size",
+    KEY_SPARSE_KERNEL_STRIDE: "sparse_kernel_stride",
+    KEY_SPARSE_BLOCK_SIZE: "sparse_block_size",
+    KEY_SPARSE_TOPK: "sparse_topk",
+    KEY_SPARSE_WINDOW: "sparse_window",
+    KEY_SPARSE_INIT_BLOCKS: "sparse_init_blocks",
+    KEY_SPARSE_DENSE_LEN: "sparse_dense_len",
+}
+_SCALE_E6_KEYS = {
+    KEY_EMBED_SCALE_E6: "embed_scale",
+    KEY_RESIDUAL_SCALE_E6: "residual_scale",
+    KEY_LOGIT_DIVISOR_E6: "logit_divisor",
+}
 _YARN_E6_KEYS = {KEY_ROPE_YARN_MSCALE_ALL_DIM_E6: "rope_yarn_mscale_all_dim"}
 
 
@@ -375,6 +442,26 @@ LATENT_FIELDS = (
 SSM_FIELDS = tuple(_SSM_INT_KEYS.values())
 # every header field cohere2_moe added, as models/config.py takes them
 WINDOW_FIELDS = (*_WINDOW_INT_KEYS.values(), "shared_expert_scale")
+# every header field minicpm_sala added, as models/config.py takes them
+LINEAR_SPARSE_FIELDS = (*_LINEAR_SPARSE_INT_KEYS.values(), *_SCALE_E6_KEYS.values())
+
+
+def check_linear_sparse(h) -> None:
+    """What a linear-attention or a block-sparse layer needs of the header
+    (or of a config: the fields have the same names)."""
+    kinds = h.layer_kinds
+    if LayerKind.LINEAR in kinds and not (h.linear_n_heads > 0 and h.linear_head_dim > 0):
+        raise ValueError("a linear-attention layer needs linear_n_heads and linear_head_dim")
+    if LayerKind.SPARSE in kinds:
+        size, stride, block = h.sparse_kernel_size, h.sparse_kernel_stride, h.sparse_block_size
+        if not (stride > 0 and size >= stride and size % stride == 0 and block > 0
+                and block % stride == 0 and h.sparse_topk > 0
+                and h.sparse_window % block == 0 and h.sparse_dense_len % block == 0
+                and h.seq_len % block == 0):
+            raise ValueError(
+                "a block-sparse layer needs sparse_kernel_size a multiple of "
+                "sparse_kernel_stride, sparse_block_size a multiple of the stride, "
+                "sparse_topk >= 1, and the window, dense_len and the context whole blocks")
 
 
 def write_model_header(f: BinaryIO, header: ModelHeader) -> int:
@@ -466,6 +553,10 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 setattr(h, _WINDOW_INT_KEYS[key], value)
             elif key == KEY_SHARED_EXPERT_SCALE_E6:
                 h.shared_expert_scale = value / 1e6
+            elif key in _LINEAR_SPARSE_INT_KEYS:
+                setattr(h, _LINEAR_SPARSE_INT_KEYS[key], value)
+            elif key in _SCALE_E6_KEYS:
+                setattr(h, _SCALE_E6_KEYS[key], value / 1e6)
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
@@ -480,6 +571,7 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 "and ssm_conv_kernel >= 2")
         if LayerKind.WINDOW in h.layer_kinds and h.sliding_window < 1:
             raise ValueError("a window layer needs sliding_window >= 1")
+        check_linear_sparse(h)
         h.header_size = header_size
         h.orig_seq_len = h.seq_len
         if max_seq_len > 0 and h.seq_len > max_seq_len:
@@ -634,7 +726,13 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
     Then a dense FFN in the first ``n_dense_layers`` layers (every layer
     where there are no experts) and a routed one in the others, with the
     shared experts as one gated FFN where the header has them; then the two
-    norms (one where ``parallel_block``)."""
+    norms (one where ``parallel_block``). A block-sparse layer (``model_type:
+    minicpm_sala``): an attention layer's tensors with ``attn_gate`` (``n_heads
+    * head_size`` rows) before wo. A linear-attention layer: ``lin_q``,
+    ``lin_k``, ``lin_v`` (``linear_n_heads * linear_head_dim`` rows each, q
+    and k permuted as an attention layer's), the per-head gains of q's and
+    k's norms (F32, permuted alike), ``lin_gate``, the output norm's gain a
+    head channel (F32), ``lin_out``."""
     wt, dim, kv_dim = h.weight_type, h.dim, h.kv_dim
     e, n, r = h.ssm_d_inner, h.ssm_d_state, h.ssm_dt_rank
     for l, kind in enumerate(h.layer_kinds):
@@ -665,6 +763,25 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
                 add("block_q_norm", l, FloatType.F32, (1, h.head_size))
                 add("block_k_norm", l, FloatType.F32, (1, h.head_size))
             add("block_matmul_wo", l, wt, (dim, h.q_dim))
+        elif kind == LayerKind.SPARSE:
+            add("block_matmul_q", l, wt, (h.q_dim, dim))
+            add("block_matmul_k", l, wt, (kv_dim, dim))
+            add("block_matmul_v", l, wt, (kv_dim, dim))
+            if h.qk_norm:
+                add("block_q_norm", l, FloatType.F32, (1, h.head_size))
+                add("block_k_norm", l, FloatType.F32, (1, h.head_size))
+            add("block_matmul_attn_gate", l, wt, (h.q_dim, dim))
+            add("block_matmul_wo", l, wt, (dim, h.q_dim))
+        elif kind == LayerKind.LINEAR:
+            lin = h.linear_n_heads * h.linear_head_dim
+            add("block_matmul_lin_q", l, wt, (lin, dim))
+            add("block_matmul_lin_k", l, wt, (lin, dim))
+            add("block_matmul_lin_v", l, wt, (lin, dim))
+            add("block_lin_q_norm", l, FloatType.F32, (1, h.linear_head_dim))
+            add("block_lin_k_norm", l, FloatType.F32, (1, h.linear_head_dim))
+            add("block_matmul_lin_gate", l, wt, (lin, dim))
+            add("block_lin_o_norm", l, FloatType.F32, (1, h.linear_head_dim))
+            add("block_matmul_lin_out", l, wt, (dim, lin))
         else:
             raise ValueError(f"layer {l}: unknown layer kind {kind}")
         if l < h.n_dense_layers or h.n_experts == 0:

@@ -133,6 +133,31 @@ def tiny_window_header(
     return h
 
 
+def tiny_sala_header(
+    pattern: str = "SLLLLSSL",
+    sizes: tuple = (4, 2, 8, 4, 16, 1, 48),
+    **kw,
+) -> ModelHeader:
+    """A toy of what ``minicpm_sala`` adds (models/hybrid.py): ``pattern`` a
+    letter a layer, ``L`` linear attention (a float32 matrix state a head,
+    queries and keys normed and rotated), ``S`` block-sparse GQA that does
+    not rotate, with an output gate; ``sizes``: the sparse layers' kernel
+    size and stride, block size, top-k, window, leading blocks and the
+    position from which a row chooses, small enough that the top-k drops
+    blocks inside a 128-position context; every FFN dense; the three scalars
+    of the width-independent parametrisation."""
+    kw = {"dim": 64, "hidden_dim": 128, "n_heads": 4, "n_kv_heads": 2, "seq_len": 128, **kw}
+    h = tiny_header(n_layers=len(pattern), **kw)
+    h.layer_kinds = [LayerKind.LINEAR if c == "L" else LayerKind.SPARSE for c in pattern]
+    h.qk_norm, h.full_attention_nope = 1, 1
+    h.linear_n_heads, h.linear_head_dim = h.n_heads, h.dim // h.n_heads
+    (h.sparse_kernel_size, h.sparse_kernel_stride, h.sparse_block_size, h.sparse_topk,
+     h.sparse_window, h.sparse_init_blocks, h.sparse_dense_len) = sizes
+    h.embed_scale, h.residual_scale, h.logit_divisor = 12.0, 1.4 / len(pattern) ** 0.5, 2.0
+    h.norm_epsilon = 1e-6
+    return h
+
+
 def ssm_steering_init(name: str, shape, rng) -> np.ndarray | None:
     """What steers a state-space layer's exponential, as the mixer's own
     published initialisation draws it (it decides how long the state

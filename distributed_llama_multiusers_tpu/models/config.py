@@ -8,6 +8,8 @@ from ..formats.model_file import (
     LATENT_FIELDS,
     SSM_FIELDS,
     WINDOW_FIELDS,
+    LINEAR_SPARSE_FIELDS,
+    check_linear_sparse,
     HiddenAct,
     LayerKind,
     ModelHeader,
@@ -125,6 +127,33 @@ class LlamaConfig:
     norm_kind: int = NormKind.RMS
     parallel_block: int = 0
     shared_expert_scale: float = 1.0
+    # What ``model_type: minicpm_sala`` adds, each engaged by its own field.
+    # LayerKind.LINEAR among the kinds: linear attention, linear_n_heads heads
+    # of linear_head_dim whose lane state is a float32 [head, head] matrix a
+    # head, decayed by a factor a head every row (models/hybrid.py,
+    # ops/linear_attention.py); its queries and keys are normed per head and
+    # rotated. LayerKind.SPARSE: GQA whose rows at or past position
+    # sparse_dense_len attend sparse_topk blocks of sparse_block_size
+    # positions: the first sparse_init_blocks, the blocks of the newest
+    # sparse_window positions, and those its compressed keys (a mean over
+    # sparse_kernel_size positions every sparse_kernel_stride, cached beside
+    # the planes) score highest; with an output gate; such a block has no
+    # ATTENTION or WINDOW layer (one stack of attention weights, all gated).
+    # embed_scale multiplies the embedding, residual_scale every mixer's and
+    # FFN's term before it joins the stream, logit_divisor divides the final
+    # norm's output before the head.
+    linear_n_heads: int = 0
+    linear_head_dim: int = 0
+    sparse_kernel_size: int = 0
+    sparse_kernel_stride: int = 0
+    sparse_block_size: int = 0
+    sparse_topk: int = 0
+    sparse_window: int = 0
+    sparse_init_blocks: int = 0
+    sparse_dense_len: int = 0
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -192,6 +221,27 @@ class LlamaConfig:
                 )
             if self.n_window_layers and self.sliding_window < 1:
                 raise ValueError("a window layer needs sliding_window >= 1")
+            check_linear_sparse(self)
+            if self.n_sparse_layers and (
+                self.n_sparse_layers != self.n_attention_layers or self.n_window_layers
+            ):
+                raise ValueError(
+                    "a block with block-sparse layers has no other attention layers")
+            if (self.n_linear_layers or self.n_sparse_layers) and (
+                self.n_experts or self.parallel_block
+            ):
+                raise ValueError(
+                    "a block with linear-attention or block-sparse layers has dense FFNs")
+            if self.n_linear_layers and self.linear_head_dim != self.head_size:
+                raise ValueError(
+                    "a linear-attention layer's heads are as wide as the attention "
+                    "layers' (one rotation table)")
+        if self.residual_scale != 1.0 and (
+            self.n_linear_layers + self.n_sparse_layers != self.n_layers or not self.layer_kinds
+        ):
+            raise ValueError(
+                "residual_scale belongs to a block of linear-attention and "
+                "block-sparse layers")
         if self.parallel_block and not (
             self.layer_kinds and self.n_routed_layers == self.n_layers
             and not self.n_conv_layers and not self.n_ssm_layers
@@ -213,11 +263,25 @@ class LlamaConfig:
         return sum(k == LayerKind.WINDOW for k in self.layer_kinds)
 
     @property
+    def n_linear_layers(self) -> int:
+        return sum(k == LayerKind.LINEAR for k in self.layer_kinds)
+
+    @property
+    def n_sparse_layers(self) -> int:
+        return sum(k == LayerKind.SPARSE for k in self.layer_kinds)
+
+    @property
+    def linear_dim(self) -> int:
+        """Width of a linear-attention layer's queries, keys and values."""
+        return self.linear_n_heads * self.linear_head_dim
+
+    @property
     def n_attention_layers(self) -> int:
         """Layers that keep every position's keys and values: what the KV
-        stack holds (a window layer keeps a ring of its own)."""
+        stack holds (a window layer keeps a ring of its own; a block-sparse
+        layer's planes are among them)."""
         return (self.n_layers - self.n_conv_layers - self.n_ssm_layers
-                - self.n_window_layers)
+                - self.n_window_layers - self.n_linear_layers)
 
     @property
     def recurrent_state(self) -> bool:
@@ -225,7 +289,8 @@ class LlamaConfig:
         what the cache keeps by position: nothing that rewinds a lane or
         copies one at another position than its last holds for it. A window
         layer's ring is such a state."""
-        return self.n_conv_layers > 0 or self.n_ssm_layers > 0 or self.n_window_layers > 0
+        return (self.n_conv_layers > 0 or self.n_ssm_layers > 0 or self.n_window_layers > 0
+                or self.n_linear_layers > 0)
 
     @property
     def n_routed_layers(self) -> int:
@@ -307,4 +372,5 @@ class LlamaConfig:
             qk_norm=h.qk_norm,
             **{name: getattr(h, name) for name in SSM_FIELDS},
             **{name: getattr(h, name) for name in WINDOW_FIELDS},
+            **{name: getattr(h, name) for name in LINEAR_SPARSE_FIELDS},
         )

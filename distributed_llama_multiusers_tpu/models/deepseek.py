@@ -518,11 +518,13 @@ def gated_ffn(ops: FfnOps, yq, w1, w2, w3):
     return matmul(ops.maybe_qdq(ops.act_fn(matmul(yq, w1)) * matmul(yq, w3)), w2)
 
 
-def dense_ffn(cfg: LlamaConfig, ops: FfnOps, x, dp: "DenseFfnParams"):
-    """A leading dense layer's FFN half: norm, gated FFN, residual add."""
+def dense_ffn(cfg: LlamaConfig, ops: FfnOps, x, dp: "DenseFfnParams", residual_scale=1.0):
+    """A leading dense layer's FFN half: norm, gated FFN, residual add (the
+    FFN's term times ``residual_scale`` where a model scales it)."""
     with jax.named_scope(SCOPE_FFN):
         y = rms_norm(x, dp.rms_ffn, cfg.norm_epsilon)
-        return x + ops.maybe_qdq(gated_ffn(ops, ops.maybe_qdq(y), dp.w1, dp.w2, dp.w3))
+        out = ops.maybe_qdq(gated_ffn(ops, ops.maybe_qdq(y), dp.w1, dp.w2, dp.w3))
+        return x + (out if residual_scale == 1.0 else residual_scale * out)
 
 
 def routed_ffn(cfg: LlamaConfig, ops: FfnOps, x, rp: "RoutedFfnParams", lm, live, normed=None):

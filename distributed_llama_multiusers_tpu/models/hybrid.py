@@ -1,7 +1,8 @@
 """A block whose layers differ in their mixer (``model_type: lfm2_moe``,
-``jamba``, ``cohere2_moe``): gated short convolutions, selective state-space
-mixers, GQA attention over the whole context and over a window of it, in a
-published per-layer pattern, leading dense FFNs, then routed ones (or dense
+``jamba``, ``cohere2_moe``, ``minicpm_sala``): gated short convolutions,
+selective state-space mixers, linear attention, GQA attention over the whole
+context, over a window of it and over the blocks of it that a row chooses, in
+a published per-layer pattern, leading dense FFNs, then routed ones (or dense
 ones throughout). Assembled from the parts of the other two
 blocks, with ``llama_forward``'s signature: the GQA projection, append and
 plane attention are ``models/llama.py``'s, the router, the route plan, the
@@ -29,6 +30,20 @@ The layer (``h`` the stream, ``K = conv_kernel``):
                 o(t) = sum over s <= t of softmax_s(q(t) . k(s) / sqrt(head_size)) v(s)
                 h' = h + Wo o                 Wo: n_heads * head_size -> dim
     window:     the same with the sum over s in (t - W, t], W = ``sliding_window``
+    linear:     q, k normed per head and rotated, ``linear_n_heads`` heads of
+                ``d = linear_head_dim``; per head i, float32:
+                S_t = lambda_i * S_{t-1} + k_t^T v_t,  o_t = q_t S_t / sqrt(d)
+                h' = h + c W_out (rmsnorm(o_t, g_o) * sigmoid(W_g n))
+                lambda_i = exp(-2^(-8 (i + 1) / heads)) (ops/linear_attention.py)
+    sparse:     attention's q, k normed per head and NOT rotated; a row at
+                position t >= ``sparse_dense_len`` sums over the s <= t of
+                the ``sparse_topk`` blocks its compressed keys choose, the
+                first and the window's among them (ops/block_sparse.py), a
+                set a kv head; a row under it over every s <= t
+                h' = h + c Wo (o * sigmoid(W_g n))
+    ``c = residual_scale`` multiplies every mixer's and FFN's term in such a
+    block, ``embed_scale`` the embedding, and the final norm's output is
+    divided by ``logit_divisor`` before the head.
     FFN:        dense in the first ``n_dense_layers`` layers, routed in the others
     ``norm_kind`` LAYER: every ``rmsnorm`` above is g * (h - mean h) / sqrt(var h + eps).
     ``parallel_block``: ONE norm a layer, n = norm(h, g), feeds the mixer and
@@ -44,7 +59,12 @@ layers), and the conv layers' window of inputs
 and, where the block has state-space layers, their running sum ``ssm``
 ``[SSM layers, lanes, N * E]``, FLOAT32 whatever the cache's type, and their
 conv's window ``ssm_conv`` ``[SSM layers, lanes, (K'-1) * E]`` (both None in a
-block without such layers: its programs are what they were).
+block without such layers: its programs are what they were); the linear
+layers' matrix state ``lin`` ``[linear layers, lanes, H * d * d]``, FLOAT32
+too and under the running sum's rule; and, kept by position like the planes,
+the sparse layers' compressed keys ``ck`` ``[sparse layers, lanes, S /
+kernel_stride, n_kv * head]`` (a sparse layer's K and V are planes of ``k``
+and ``v``).
 All are flat in their last axis, so that it is whole tiles of a TPU's 128
 lanes: with a 64-wide head as the last axis XLA gave the K/V stack another
 layout inside the layer loop and copied it whole, in and out, every step
@@ -116,24 +136,29 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Layout
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import LayerKind, NormKind
-from ..ops import blocked_attention, pallas_attention
+from ..ops import block_sparse, blocked_attention, pallas_attention
 from ..ops.linear import matmul, pallas_interpret, pallas_kernel_active
+from ..ops.linear_attention import decay_slopes, linear_attention
 from ..ops.norm import layer_norm, rms_norm
+from ..ops.rope import apply_rope
 from ..ops.ssm_scan import state_step
 from ..quants.packed import PackedQ40, Q40Experts
 from ..telemetry.names import (
     SCOPE_ATTENTION,
     SCOPE_ATTN_OUT,
+    SCOPE_BLOCK_SCORES,
     SCOPE_CONV,
     SCOPE_CONV_STATE,
     SCOPE_EMBED,
     SCOPE_HEAD,
     SCOPE_KV_WRITE,
     SCOPE_LAYERS,
+    SCOPE_LINEAR_ATTENTION,
     SCOPE_QKV,
+    SCOPE_SPARSE_SELECT,
     SCOPE_SSM,
     SCOPE_WINDOW_ATTENTION,
 )
@@ -167,6 +192,23 @@ class GqaParams(NamedTuple):
     q_norm: jnp.ndarray | None  # [La, head] f32 (config.qk_norm)
     k_norm: jnp.ndarray | None
     rms: jnp.ndarray  # [La, dim]: the layer's operator norm
+    # block-sparse layers only (None elsewhere): the output gate
+    gate: jnp.ndarray | None = None  # [La, dim, n_heads * head_size]
+
+
+class LinearParams(NamedTuple):
+    """The linear-attention layers' weights, stacked ``[linear layers, ...]``;
+    ``D = linear_n_heads * linear_head_dim``."""
+
+    wq: jnp.ndarray  # [Ll, dim, D]
+    wk: jnp.ndarray
+    wv: jnp.ndarray
+    q_norm: jnp.ndarray  # [Ll, head] f32: the per-head norm of the queries
+    k_norm: jnp.ndarray
+    gate: jnp.ndarray  # [Ll, dim, D]: the output gate
+    o_norm: jnp.ndarray  # [Ll, head] f32: the per-head norm of the output
+    w_out: jnp.ndarray  # [Ll, D, dim]
+    rms: jnp.ndarray  # [Ll, dim]: the layer's input norm
 
 
 class ConvParams(NamedTuple):
@@ -208,6 +250,7 @@ class HybridParams(NamedTuple):
     rope_cos: jnp.ndarray | None  # [seq_len, head_size // 2] f32; None: no rotation
     rope_sin: jnp.ndarray | None
     ssm: SsmParams | None = None
+    linear: LinearParams | None = None
 
 
 class HybridCache(NamedTuple):
@@ -223,6 +266,12 @@ class HybridCache(NamedTuple):
     # window layers only (None elsewhere): their keys' and values' ring
     wk: jnp.ndarray | None = None  # [Lw, lanes, R, n_kv * head]
     wv: jnp.ndarray | None = None
+    # linear-attention layers only (None elsewhere): the matrix a head,
+    # float32 whatever the cache's type
+    lin: jnp.ndarray | None = None  # [Ll, lanes, H * head * head]
+    # block-sparse layers only (None elsewhere): the compressed keys, one a kv
+    # head every kernel_stride positions, kept by position like the planes
+    ck: jnp.ndarray | None = None  # [Lp, lanes, S / kernel_stride, n_kv * head]
 
 
 def ring_rows(config: LlamaConfig, max_chunk: int) -> int:
@@ -238,7 +287,15 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
     """``max_chunk``: the most rows a lane any step writes (the largest prefill
     bucket): it sizes the ring (``ring_rows``)."""
     kv = (config.n_attention_layers, n_lanes, config.seq_len, config.kv_dim)
-    ssm = ssm_conv = wk = wv = None
+    ssm = ssm_conv = wk = wv = lin = ck = None
+    if config.n_linear_layers:
+        lin = jnp.zeros(
+            (config.n_linear_layers, n_lanes,
+             config.linear_n_heads * config.linear_head_dim ** 2), jnp.float32)
+    if config.n_sparse_layers:
+        ck = jnp.zeros(
+            (config.n_sparse_layers, n_lanes, config.seq_len // config.sparse_kernel_stride,
+             config.kv_dim), dtype)
     if config.n_window_layers:
         ring = (config.n_window_layers, n_lanes, ring_rows(config, max_chunk), config.kv_dim)
         wk, wv = jnp.zeros(ring, dtype), jnp.zeros(ring, dtype)
@@ -250,7 +307,7 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
         k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
         conv=jnp.zeros(
             (config.n_conv_layers, n_lanes, max(config.conv_kernel - 1, 0) * config.dim), dtype),
-        ssm=ssm, ssm_conv=ssm_conv, wk=wk, wv=wv,
+        ssm=ssm, ssm_conv=ssm_conv, wk=wk, wv=wv, lin=lin, ck=ck,
     )
 
 
@@ -259,8 +316,9 @@ def state_leaves(cache) -> tuple:
     position: a lane's recurrent state (none for any other cache)."""
     if not isinstance(cache, HybridCache):
         return ()
-    return tuple(x for x in (cache.conv, cache.ssm, cache.ssm_conv, cache.wk, cache.wv)
-                 if x is not None)
+    return tuple(
+        x for x in (cache.conv, cache.ssm, cache.ssm_conv, cache.wk, cache.wv, cache.lin)
+        if x is not None)
 
 
 def ring_attention_engages(cache, mesh, n_heads: int, n_kv: int) -> bool:
@@ -272,6 +330,17 @@ def ring_attention_engages(cache, mesh, n_heads: int, n_kv: int) -> bool:
     )
 
 
+def block_sparse_engages(cache, mesh, config: LlamaConfig) -> bool:
+    """``llama.decode_attention_engages`` for a block-sparse layer: whether a
+    step of one row a lane fetches the blocks it chose out of the planes in
+    place (``pallas_attention.sparse_decode_attention``); else the planes are
+    read a key block at a time under the rows' mask."""
+    return (
+        config.n_sparse_layers > 0 and mesh is None and pallas_kernel_active()
+        and pallas_attention.supports_sparse(
+            cache.k, config.n_heads, config.n_kv_heads, config.sparse_block_size))
+
+
 def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     """The parameter tree around a model's arrays, by the tensor names of the
     ``.m`` walk without their ``block_`` prefix: what the loader and a
@@ -280,12 +349,18 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     def experts(w):
         return Q40Experts.from_packed(w) if isinstance(w, PackedQ40) else w
 
-    attn = conv = dense = routed = ssm = None
+    attn = conv = dense = routed = ssm = linear = None
     if "wq" in t:
         attn = GqaParams(
             wq=t["wq"], wk=t["wk"], wv=t["wv"], wo=t["wo"],
             q_norm=t.get("q_norm"), k_norm=t.get("k_norm"), rms=t["attn_rms"],
+            gate=t.get("attn_gate"),
         )
+    if "lin_q" in t:
+        linear = LinearParams(
+            wq=t["lin_q"], wk=t["lin_k"], wv=t["lin_v"], q_norm=t["lin_q_norm"],
+            k_norm=t["lin_k_norm"], gate=t["lin_gate"], o_norm=t["lin_o_norm"],
+            w_out=t["lin_out"], rms=t["lin_rms"])
     if "conv_in" in t:
         conv = ConvParams(
             w_in=t["conv_in"], taps=t["conv_taps"], w_out=t["conv_out"], rms=t["conv_rms"])
@@ -308,7 +383,7 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     return HybridParams(
         embedding=t["embedding"], attn=attn, conv=conv, dense=dense, routed=routed,
         rms_final=t["rms_final"], wcls=t["wcls"], rope_cos=rope_cos, rope_sin=rope_sin,
-        ssm=ssm,
+        ssm=ssm, linear=linear,
     )
 
 
@@ -353,12 +428,13 @@ def kind_runs(kinds: tuple) -> list:
 
 
 # a layer kind's place in the counts a layer is read by
-KIND_SLOTS = (LayerKind.ATTENTION, LayerKind.CONV, LayerKind.SSM, LayerKind.WINDOW)
+KIND_SLOTS = (LayerKind.ATTENTION, LayerKind.CONV, LayerKind.SSM, LayerKind.WINDOW,
+              LayerKind.LINEAR, LayerKind.SPARSE)
 
 
 def kinds_after(kind, nth: tuple) -> tuple:
-    """The (attention, conv, state-space, window) counts after one more layer
-    of ``kind``."""
+    """The (attention, conv, state-space, window, linear, sparse) counts after
+    one more layer of ``kind``."""
     slot = KIND_SLOTS.index(kind)
     return tuple(n + (k == slot) for k, n in enumerate(nth))
 
@@ -404,7 +480,11 @@ def hybrid_forward_counted(
 
     with jax.named_scope(SCOPE_EMBED):
         x = params.embedding[tokens]
+        if cfg.embed_scale != 1.0:
+            x = (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
     dtype = x.dtype
+    # the factor on every mixer's and FFN's term before it joins the stream
+    res = cfg.residual_scale
     lane_idx = jnp.arange(b)[:, None]
     in_context = positions < cfg.seq_len
     live = in_context.reshape(b * t)
@@ -429,9 +509,19 @@ def hybrid_forward_counted(
             ring_mask = blocked_attention.ring_mask(positions, ring, window)  # [B, T, R]
     row_major = Layout(major_to_minor=tuple(range(cache.k.ndim)))
     scale = 1.0 / float(cfg.head_size) ** 0.5
+    if cfg.n_linear_layers:
+        slopes = jnp.asarray(decay_slopes(cfg.linear_n_heads))
+        lin_scale = 1.0 / float(cfg.linear_head_dim) ** 0.5
     from_zero = (positions[:, :1] == 0)[:, :, None]  # [B, 1, 1]
     # rows a state may absorb: the first n_valid of a lane (module header)
     real_row = (jnp.arange(t, dtype=jnp.int32)[None, :] < n_valid[:, None])[:, :, None]
+    if cfg.n_sparse_layers:
+        sizes = block_sparse.SparseSizes.of(cfg)
+        n_blocks = cfg.seq_len // sizes.block_size
+        sparse_in_place = t == 1 and block_sparse_engages(cache, mesh, cfg)
+        # whether any real row chooses (stands at or past dense_len): else
+        # every row takes the blocks it holds and nothing is scored
+        any_sparse = jnp.any(real_row[:, :, 0] & in_context & (positions >= sizes.dense_len))
     if ring:
         # where a row's key and value go in a ring: position mod R; past the
         # ring (dropped) for a row that is not real or lies past the context
@@ -478,6 +568,78 @@ def hybrid_forward_counted(
             if not cfg.parallel_block:
                 out = x + out
         return out, y, k_all, v_all
+
+    def sparse_attention(x, at, pi, k_all, v_all, ck_all):
+        """A block-sparse layer's attention half: weights and planes at
+        ``at``, compressed keys at ``pi``. Queries and keys are normed and
+        not rotated; the rows choose their blocks (ops/block_sparse.py); the
+        output is gated before ``wo``."""
+        ap = GqaParams(*(_pick(leaf, at) for leaf in params.attn))
+        with jax.named_scope(SCOPE_QKV):
+            y = norm(x, ap.rms)
+            yq = maybe_qdq(y)
+            q, k, v = gqa_project(
+                cfg, yq, ap.wq, ap.wk, ap.wv, positions, None, None,
+                norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None)
+            gate = jax.nn.sigmoid(matmul(yq, ap.gate).astype(jnp.float32))
+        with jax.named_scope(SCOPE_KV_WRITE):
+            k_all, v_all = kv_append(
+                k_all, v_all, (at, lane_idx, positions),
+                k.reshape(b, t, cfg.kv_dim), v.reshape(b, t, cfg.kv_dim), row_major)
+        with jax.named_scope(SCOPE_BLOCK_SCORES):
+            # row-major like the planes (kv_append says why): left free, XLA
+            # gave the stack the score pass's layout and copied it whole
+            ck_all = with_layout_constraint(block_sparse.append_compressed(
+                ck_all, k_all, pi, at, positions, n_valid, sizes), row_major)
+        held = block_sparse.held_blocks(positions, n_blocks, sizes)
+
+        def choosing():
+            with jax.named_scope(SCOPE_BLOCK_SCORES):
+                r = block_sparse.block_scores(
+                    q, ck_all, pi, positions, cfg.n_kv_heads, sizes, scale)
+            with jax.named_scope(SCOPE_SPARSE_SELECT):
+                return block_sparse.choose(r, positions, sizes)
+
+        chosen = jax.lax.cond(
+            any_sparse, choosing,
+            lambda: jnp.broadcast_to(held, (b, t, cfg.n_kv_heads, n_blocks)))
+        if sparse_in_place:
+            with jax.named_scope(SCOPE_SPARSE_SELECT):
+                work = block_sparse.chosen_list(chosen, positions, cfg.seq_len, sizes)
+            with jax.named_scope(SCOPE_ATTENTION):
+                attn = pallas_attention.sparse_decode_attention(
+                    q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, at, work,
+                    scale, sizes.block_size, interpret=pallas_interpret())
+        else:
+            with jax.named_scope(SCOPE_ATTENTION):
+                attn = blocked_attention.blocked_attention(
+                    q, k_all, v_all, at, positions, n_valid, cfg.n_kv_heads, scale,
+                    chosen=chosen, chosen_block=sizes.block_size)
+        with jax.named_scope(SCOPE_ATTN_OUT):
+            gated = (attn.reshape(b, t, cfg.q_dim) * gate).astype(dtype)
+            x = x + res * maybe_qdq(matmul(maybe_qdq(gated), ap.wo))
+        return x, k_all, v_all, ck_all
+
+    def linear(x, li, lin_all):
+        """A linear-attention layer: queries and keys normed per head and
+        rotated, the decayed matrix state (ops/linear_attention.py), the
+        output normed per head and gated."""
+        lp = LinearParams(*(_pick(leaf, li) for leaf in params.linear))
+        heads = (b, t, cfg.linear_n_heads, cfg.linear_head_dim)
+        with jax.named_scope(SCOPE_LINEAR_ATTENTION):
+            y = norm(x, lp.rms)
+            yq = maybe_qdq(y)
+            q = rms_norm(matmul(yq, lp.wq).reshape(heads), lp.q_norm, eps)
+            k = rms_norm(matmul(yq, lp.wk).reshape(heads), lp.k_norm, eps)
+            v = matmul(yq, lp.wv).reshape(heads)
+            q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
+            k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
+            gate = jax.nn.sigmoid(matmul(yq, lp.gate).astype(jnp.float32))
+            o, lin_all = linear_attention(
+                lin_all, li, from_zero, q, k, v, real_row[:, :, 0], slopes, lin_scale)
+            o = rms_norm(o, lp.o_norm, eps).reshape(b, t, cfg.linear_dim)
+            x = x + res * maybe_qdq(matmul(maybe_qdq((o * gate).astype(dtype)), lp.w_out))
+        return x, lin_all
 
     def window_attention(q, wi, k_all, v_all):
         """Window layer ``wi``'s read of its ring, after the append: the
@@ -548,15 +710,20 @@ def hybrid_forward_counted(
 
     # the carry: the stream, then every state stack (a kind the block lacks
     # is None and no leaf), then a routed model's counts (ROUTED_COUNTS)
-    def mixer(kind, carry, ai, ci, si, wi):
+    def mixer(kind, carry, ai, ci, si, wi, li, pi):
         """The layer's mixer on the carry; with it, in a parallel block, the
         mixer's term and the normed input it read (else None)."""
-        x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, *counts = carry
+        (x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, lin_all, ck_all,
+         *counts) = carry
         parallel = None
         if kind == LayerKind.CONV:
             x, s_all = conv(x, ci, s_all)
         elif kind == LayerKind.SSM:
             x, ssm_all, win_all = ssm(x, si, ssm_all, win_all)
+        elif kind == LayerKind.LINEAR:
+            x, lin_all = linear(x, li, lin_all)
+        elif kind == LayerKind.SPARSE:
+            x, k_all, v_all, ck_all = sparse_attention(x, ai + pi, pi, k_all, v_all, ck_all)
         else:
             # both kinds of attention layer: one stack of weights, in layer
             # order, and a stack of cache a kind
@@ -566,16 +733,17 @@ def hybrid_forward_counted(
             else:
                 out, normed, k_all, v_all = attention(x, at, ai, k_all, v_all)
             x, parallel = (x, (out, normed)) if cfg.parallel_block else (out, None)
-        return (x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, *counts), parallel
+        return (x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, lin_all, ck_all,
+                *counts), parallel
 
     def kinds_before(lo, hi):
-        """(attention, conv, state-space, window) layers among ``kinds[lo:hi]``."""
+        """Layers of each kind (``KIND_SLOTS``) among ``kinds[lo:hi]``."""
         return tuple(sum(k == slot for k in kinds[lo:hi]) for slot in KIND_SLOTS)
 
     def dense_layer(kind, carry, nth, l):
         (x, *rest), _ = mixer(kind, carry, *nth)
         dp = DenseFfnParams(*(_pick(leaf, l) for leaf in params.dense))
-        return (dense_ffn(cfg, ops, x, dp), *rest)
+        return (dense_ffn(cfg, ops, x, dp, res), *rest)
 
     def routed_layer(kind, carry, nth, lm):
         (x, *rest), parallel = mixer(kind, carry, *nth)
@@ -636,9 +804,23 @@ def hybrid_forward_counted(
         if whole:
             carry, _ = jax.lax.scan(
                 period_step, carry, jnp.arange(whole, dtype=jnp.int32))
-        for l in range(n_lead + whole * period, cfg.n_layers):  # the odd tail
+        # the odd tail, unrolled but for its long runs of one kind (a list
+        # that repeats nothing is a "period" of over half of it and a tail)
+        tail = n_lead + whole * period
+        for j, n_run in kind_runs(kinds[tail:]):
+            l = tail + j
             nth = tuple(jnp.int32(n) for n in kinds_before(0, l))
-            carry = layer(kinds[l], carry, nth, jnp.int32(l - n_lead))
+            if n_run < RUN_SCAN_MIN:
+                for r in range(n_run):
+                    carry = layer(kinds[l + r], carry, nth, jnp.int32(l + r - n_lead))
+                    nth = kinds_after(kinds[l + r], nth)
+                continue
+
+            def tail_run(carry, r, l=l, nth=nth):
+                at = tuple(n + r * (m - n) for n, m in zip(nth, kinds_after(kinds[l], nth)))
+                return layer(kinds[l], carry, at, l - n_lead + r), None
+
+            carry, _ = jax.lax.scan(tail_run, carry, jnp.arange(n_run, dtype=jnp.int32))
         x, *stacks = carry
         counts = None
         if routed:
@@ -647,6 +829,8 @@ def hybrid_forward_counted(
 
     with jax.named_scope(SCOPE_HEAD):
         y = norm(x, params.rms_final)
+        if cfg.logit_divisor != 1.0:
+            y = (y.astype(jnp.float32) / cfg.logit_divisor).astype(y.dtype)
         logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)
         logits = logits[..., : cfg.vocab_size]
     return logits, HybridCache(*stacks), counts

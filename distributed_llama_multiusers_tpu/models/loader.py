@@ -291,11 +291,23 @@ _PATTERN_NAME_MAP = {
     "block_ssm_a_log": "ssm_a_log",
     "block_ssm_d": "ssm_d",
     "block_matmul_ssm_out": "ssm_out",
+    # a block-sparse layer's output gate (LayerKind.SPARSE)
+    "block_matmul_attn_gate": "attn_gate",
+    # a linear-attention layer (LayerKind.LINEAR)
+    "block_matmul_lin_q": "lin_q",
+    "block_matmul_lin_k": "lin_k",
+    "block_matmul_lin_v": "lin_v",
+    "block_lin_q_norm": "lin_q_norm",
+    "block_lin_k_norm": "lin_k_norm",
+    "block_matmul_lin_gate": "lin_gate",
+    "block_lin_o_norm": "lin_o_norm",
+    "block_matmul_lin_out": "lin_out",
 }
 _PATTERN_F32 = {"conv_taps", "q_norm", "k_norm", "moe_gate", "moe_bias", "rms_ffn",
                 "dense_rms_ffn", "attn_rms", "conv_rms", "ssm_rms", "ssm_taps",
                 "ssm_conv_bias", "ssm_dt_norm", "ssm_b_norm", "ssm_c_norm",
-                "ssm_dt_proj", "ssm_dt_bias", "ssm_a_log", "ssm_d"}
+                "ssm_dt_proj", "ssm_dt_bias", "ssm_a_log", "ssm_d",
+                "lin_rms", "lin_q_norm", "lin_k_norm", "lin_o_norm"}
 
 
 def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
@@ -310,12 +322,13 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
     from .hybrid import hybrid_params
 
     mixer_rms = {LayerKind.CONV: "conv_rms", LayerKind.SSM: "ssm_rms",
-                 LayerKind.ATTENTION: "attn_rms"}
+                 LayerKind.ATTENTION: "attn_rms", LayerKind.LINEAR: "lin_rms"}
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
     n_dense = config.n_dense_layers if config.n_experts else config.n_layers
-    # a window layer's weights are stacked with the full-context layers'
-    kinds = tuple(LayerKind.ATTENTION if k == LayerKind.WINDOW else k
+    # a window layer's and a block-sparse layer's weights are stacked with the
+    # full-context layers'
+    kinds = tuple(LayerKind.ATTENTION if k in (LayerKind.WINDOW, LayerKind.SPARSE) else k
                   for k in config.layer_kinds)
     # a layer's index into its kind's stack
     nth = [sum(k == kinds[l] for k in kinds[:l]) for l in range(len(kinds))]

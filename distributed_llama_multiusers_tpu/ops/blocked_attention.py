@@ -100,12 +100,16 @@ def chunk_blocks(first: int, last: int, ring: int, window: int = 0,
 
 
 def blocked_attention(q, k_all, v_all, layer, positions, n_valid, n_kv: int,
-                      scale: float, window: int = 0, block: int = BLOCK_KEYS):
+                      scale: float, window: int = 0, block: int = BLOCK_KEYS,
+                      chosen=None, chosen_block: int = 0):
     """GQA attention of ``q`` ``[B, T, n_heads, hd]`` over layer ``layer`` of
     the stacks ``k_all`` / ``v_all`` ``[L, B, R, n_kv * hd]`` (a plane, ``R =
     seq_len``, or a ring), read AFTER the chunk's rows were written.
     ``positions`` ``[B, T]``; ``n_valid`` ``[B]``: a lane's leading real rows
-    (the others compute nothing anyone reads and bound no loop). Returns
+    (the others compute nothing anyone reads and bound no loop). ``chosen``
+    ``[B, T, n_kv, S / chosen_block]`` (a block-sparse layer, ops/block_sparse.py):
+    the blocks of ``chosen_block`` positions a row's kv head reads, beside the
+    causal mask; a key block holds whole such blocks. Returns
     ``[B, T, n_heads, hd]`` float32."""
     b, t, n_heads, hd = q.shape
     ring, kv_dim = k_all.shape[2], k_all.shape[3]
@@ -137,7 +141,12 @@ def blocked_attention(q, k_all, v_all, layer, positions, n_valid, n_kv: int,
         ok = (p >= 0) & (rows >= j * block)
         if window:
             ok = ok & (p > t3 - window)
-        s = jnp.where(ok[:, :, None, None, :], s, -jnp.inf)
+        ok = ok[:, :, None, None, :]
+        if chosen is not None:
+            per = block // chosen_block
+            mine = jax.lax.dynamic_slice_in_dim(chosen, start // chosen_block, per, axis=3)
+            ok = ok & jnp.repeat(mine, chosen_block, axis=3)[:, :, :, None, :]
+        s = jnp.where(ok, s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)  # a row that has met no key yet
         alpha = jnp.exp(m - m_safe)

@@ -69,6 +69,18 @@ what was written ``R`` positions ago), so two more rows ride the plan: the
 position of an item's first row and the oldest position the lane reads. The
 kernel is the same; which list it was handed is a static fact of the plan's
 shape, and a full-context layer's program is what it was.
+
+Chosen blocks. A block-sparse layer (models/hybrid.py, ops/block_sparse.py)
+reads, for every (lane, kv head), the blocks of ``block_size`` positions (64
+as published) that the step chose from its compressed keys' scores: a third
+work list, computed by the step and different for each kv head. A grid step a
+chosen block would be 2048 steps a layer at 16 lanes, each fetching 16 KB.
+``sparse_decode_attention`` is a kernel of its own instead: one grid step a
+(lane, kv head), the planes left in HBM, the step's blocks (64 rows of the kv
+head's 128 columns each) copied into one VMEM buffer by as many DMAs, started
+a grid step ahead into the other half of a double buffer, and ONE softmax over
+the buffer's rows. The list is in rising order, so the row's own block is the
+last and the rows past the lane's position are the buffer's tail.
 """
 
 from __future__ import annotations
@@ -351,3 +363,106 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
         # and a sum with exact zeros, no product
         out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, hd), 0.0).sum(axis=2)
     return out[:, :n_heads]
+
+
+def supports_sparse(k_all, n_heads: int, n_kv: int, block_size: int) -> bool:
+    """Whether ``sparse_decode_attention`` takes this cache: a merged bf16
+    stack ``[A, lanes, S, n_kv * HEAD_SIZE]`` and blocks of whole bf16 sublane
+    tiles."""
+    return (
+        k_all.dtype == jnp.bfloat16 and k_all.ndim == 4 and n_kv > 0
+        and k_all.shape[3] == n_kv * HEAD_SIZE and n_heads % n_kv == 0
+        and block_size % 16 == 0 and k_all.shape[2] % block_size == 0
+    )
+
+
+def _sparse_decode_kernel(layer_ref, count_ref, blocks_ref, pos_ref, q_ref, k_hbm, v_hbm,
+                          o_ref, kbuf, vbuf, sem, *, scale, block_size, n_kv, hd):
+    g, n_items = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def copies(item, slot, i):
+        lane, head = item // n_kv, item % n_kv
+        at = blocks_ref[item, i] * block_size
+        src = lambda ref: ref.at[layer, lane, pl.ds(at, block_size), pl.ds(head * hd, hd)]
+        dst = lambda buf: buf.at[slot, pl.ds(i * block_size, block_size), :]
+        return (pltpu.make_async_copy(src(k_hbm), dst(kbuf), sem.at[slot, 0]),
+                pltpu.make_async_copy(src(v_hbm), dst(vbuf), sem.at[slot, 1]))
+
+    def fetch(item, slot):
+        def one(i, carry):
+            for c in copies(item, slot, i):
+                c.start()
+            return carry
+        jax.lax.fori_loop(0, count_ref[item], one, 0)
+
+    slot = g % 2
+
+    @pl.when(g == 0)
+    def _():
+        fetch(0, 0)
+
+    @pl.when(g + 1 < n_items)
+    def _():
+        fetch(g + 1, 1 - slot)
+
+    n = count_ref[g]
+
+    def arrived(i, carry):
+        for c in copies(g, slot, i):
+            c.wait()
+        return carry
+    jax.lax.fori_loop(0, n, arrived, 0)
+
+    # the list is in rising order: the last block is the row's own, and the
+    # buffer's rows from `limit` on hold later positions or nothing
+    limit = (n - 1) * block_size + pos_ref[g // n_kv] % block_size + 1
+    k, v = kbuf[slot], vbuf[slot]  # [list rows, hd]
+    s = jax.lax.dot_general(
+        q_ref[...], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+    s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < limit, s, -jnp.inf)
+    # 0 x NaN is NaN: a row nothing was copied into must not reach the sum
+    v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) < limit, v, jnp.zeros_like(v))
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.exp(s - jnp.where(m == -jnp.inf, 0.0, m))
+    l = jnp.sum(p, axis=1, keepdims=True)
+    o = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    # a parked lane chose nothing: zeros, and no division by its sum
+    o_ref[...] = jnp.where(l > 0.0, o / jnp.where(l > 0.0, l, 1.0), 0.0)
+
+
+def sparse_decode_attention(q, k_all, v_all, layer, work, scale: float, block_size: int,
+                            interpret: bool = False) -> jnp.ndarray:
+    """One query row a lane against the blocks each (lane, kv head) chose of
+    layer ``layer`` of the merged stacks ``[A, lanes, S, n_kv * hd]``
+    (``supports_sparse``), the lanes' fresh rows already appended. ``work``
+    from ``block_sparse.chosen_list``: ``(count [lanes * n_kv], blocks [lanes *
+    n_kv, list], pos [lanes])``. q ``[lanes, n_heads, hd]``; returns ``[lanes,
+    n_heads, hd]`` float32; a head's result depends on its kv head's chosen
+    blocks alone, up to the lane's position."""
+    lanes, n_heads, hd = q.shape
+    n_kv = k_all.shape[3] // hd
+    group = n_heads // n_kv
+    count, blocks, pos = work
+    group_pad = -(-group // 16) * 16  # whole bf16 sublane tiles
+    rows = blocks.shape[1] * block_size
+    qg = q.astype(k_all.dtype).reshape(lanes * n_kv, group, hd)
+    qg = jnp.pad(qg, ((0, 0), (0, group_pad - group), (0, 0)))
+    item = pl.BlockSpec((None, group_pad, hd), lambda g, *_: (g, 0, 0))
+    out = pl.pallas_call(
+        partial(_sparse_decode_kernel, scale=scale, block_size=block_size, n_kv=n_kv, hd=hd),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # the layer index, and the work list's three parts
+            grid=(lanes * n_kv,),
+            in_specs=[item, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=item,
+            scratch_shapes=[pltpu.VMEM((2, rows, hd), k_all.dtype)] * 2
+            + [pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes * n_kv, group_pad, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=64 * 2**20),
+        name="sparse_decode_attention",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), count, blocks, pos, qg, k_all, v_all)
+    return out[:, :group].reshape(lanes, n_heads, hd)

@@ -54,7 +54,12 @@ from ..models.deepseek import (
     forward_counted,
     init_latent_cache,
 )
-from ..models.hybrid import init_hybrid_cache, ring_attention_engages, state_leaves
+from ..models.hybrid import (
+    block_sparse_engages,
+    init_hybrid_cache,
+    ring_attention_engages,
+    state_leaves,
+)
 from ..ops import blocked_attention, pallas_attention
 from ..telemetry.logs import log_event
 from ..telemetry import names
@@ -386,6 +391,21 @@ class EngineStats:
     ssm_lane_steps: int = 0
     ssm_rows_scanned: int = 0
     ssm_rows_computed: int = 0
+    # linear-attention and block-sparse layers (config.n_linear_layers,
+    # config.n_sparse_layers; all 0 elsewhere), kept by the scheduler from its
+    # own lane positions over the decode steps: the bytes of float32 matrix
+    # state the steps read and wrote (a live lane's, every linear layer, in
+    # and out); blocks of sparse_block_size positions a sparse layer's kv head
+    # attended and blocks it held, summed over live lanes and steps (ONE
+    # layer's, ONE kv head's: every layer and head has the same counts); and
+    # (live lane, step) pairs at or past sparse_dense_len, whose rows chose.
+    # By the engine, as ssm_rows_computed: prompt rows through the chunk form
+    # summed over the linear layers, every row of the bucket the chunk rode
+    linear_state_bytes_moved: int = 0
+    linear_rows_computed: int = 0
+    attn_blocks_read: int = 0
+    attn_blocks_held: int = 0
+    sparse_lane_steps: int = 0
     # window attention layers (config.n_window_layers; all 0 elsewhere), in
     # rows of ONE window layer's ring, as attn_kv_rows_* are rows of one
     # full-context layer's plane (and stay so: in a model with both kinds
@@ -451,6 +471,9 @@ class EngineStats:
             "indexer_rows_scored", "sparse_rows_selected",
             "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
             "ssm_lane_steps", "ssm_rows_scanned", "ssm_rows_computed",
+            "linear_state_bytes_moved", "linear_rows_computed", "attn_blocks_read",
+            "attn_blocks_held",
+            "sparse_lane_steps",
             "attn_window_rows_read", "attn_full_rows_read", "attn_window_rows_plane",
             "prefill_attn_blocks_visited", "prefill_attn_blocks_causal",
             "jit_compiles_after_warmup",
@@ -496,6 +519,8 @@ class EngineStats:
             self.moe_rows_unheld = self.indexer_rows_scored = self.sparse_rows_selected = 0
             self.state_zero_starts = self.prefix_reuse_declined = 0
             self.ssm_lane_steps = self.ssm_rows_scanned = self.ssm_rows_computed = 0
+            self.linear_state_bytes_moved = self.attn_blocks_read = self.linear_rows_computed = 0
+            self.attn_blocks_held = self.sparse_lane_steps = 0
             self.attn_window_rows_read = self.attn_full_rows_read = 0
             self.attn_window_rows_plane = 0
             self.prefill_attn_blocks_visited = self.prefill_attn_blocks_causal = 0
@@ -623,6 +648,7 @@ class InferenceEngine:
             unserved = (
                 "a model with a per-layer pattern of mixers "
                 f"({config.n_conv_layers} conv, {config.n_ssm_layers} state-space, "
+                f"{config.n_linear_layers} linear-attention, "
                 f"{config.n_attention_layers} attention layers) keeps a stack a kind "
                 "on one device",
                 "a lane's state is not pages of a K/V pair a layer",
@@ -1706,6 +1732,21 @@ class InferenceEngine:
                 kv_ring_bytes=self.cache.wk.nbytes + self.cache.wv.nbytes,
                 kv_plane_bytes=self.cache.k.nbytes + self.cache.v.nbytes,
             )
+        if cfg.n_linear_layers:
+            facts.update(
+                linear_attention_layers=cfg.n_linear_layers,
+                linear_state_bytes=self.cache.lin.nbytes,
+            )
+        if cfg.n_sparse_layers:
+            # a third kind of cache a lane beside planes and matrix state
+            facts.update(
+                block_sparse_path=(
+                    "pallas_chosen_blocks" if block_sparse_engages(self.cache, self.mesh, cfg)
+                    else "xla_masked_key_blocks"),
+                sparse_blocks=f"{cfg.sparse_topk}x{cfg.sparse_block_size}@{cfg.sparse_dense_len}",
+                compressed_key_bytes=self.cache.ck.nbytes,
+                kv_plane_bytes=self.cache.k.nbytes + self.cache.v.nbytes,
+            )
         if self.chunk_taper_start is not None:
             facts["chunk_taper"] = f"{self.prefill_buckets[-2]}@{self.chunk_taper_start}"
         if cfg.recurrent_state:
@@ -1867,6 +1908,7 @@ class InferenceEngine:
             self.stats.state_zero_starts += int(self.config.recurrent_state and start_pos == 0)
             self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
             self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
+            self.stats.linear_rows_computed += bucket * self.config.n_linear_layers
             visited, causal = self._prefill_attn_blocks(start_pos, len(chunk), bucket)
             self.stats.prefill_attn_blocks_visited += visited
             self.stats.prefill_attn_blocks_causal += causal
@@ -2254,6 +2296,7 @@ class InferenceEngine:
             self.stats.state_zero_starts += int(self.config.recurrent_state and p_start == 0)
             self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
             self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
+            self.stats.linear_rows_computed += bucket * self.config.n_linear_layers
             visited, causal = self._prefill_attn_blocks(p_start, len(chunk), bucket)
             self.stats.prefill_attn_blocks_visited += visited
             self.stats.prefill_attn_blocks_causal += causal

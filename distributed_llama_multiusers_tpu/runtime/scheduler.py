@@ -51,6 +51,7 @@ from ..serving import (
 )
 from ..analysis import jitcheck, leakcheck
 from ..lockcheck import make_lock
+from ..ops.block_sparse import SparseSizes, blocks_attended
 from ..ops.pallas_attention import ring_rows_read, rows_read
 from ..serving.watchdog import deadline_from_env
 from ..telemetry import Telemetry
@@ -473,6 +474,14 @@ class ContinuousBatchingScheduler:
         # window attention layers: the decode steps' attn_window_rows_*
         self._window = int(getattr(cfg, "sliding_window", 0) or 0) if int(
             getattr(cfg, "n_window_layers", 0) or 0) else 0
+        # linear-attention layers' state bytes a live lane a decode step (in
+        # and out), and the block-sparse layers' sizes: the decode steps'
+        # linear_state_bytes_moved, attn_blocks_* and sparse_lane_steps
+        self._linear_step_bytes = 2 * 4 * int(getattr(cfg, "n_linear_layers", 0) or 0) * (
+            int(getattr(cfg, "linear_n_heads", 0) or 0)
+            * int(getattr(cfg, "linear_head_dim", 0) or 0) ** 2)
+        self._sparse_sizes = (
+            SparseSizes.of(cfg) if int(getattr(cfg, "n_sparse_layers", 0) or 0) else None)
         # a held share of the routed experts, "16/256" (None: every expert)
         held = int(getattr(cfg, "experts_held_count", 0) or 0)
         self._experts_held = f"{held}/{cfg.n_experts}" if held else None
@@ -942,10 +951,26 @@ class ContinuousBatchingScheduler:
                 len(positions) * getattr(engine, "ring_rows", 0) * steps if ring_block is None
                 else sum(ring_rows_read(positions + s, seq_len, self._window, ring_block)
                          for s in range(steps)))
+        blocks_read = blocks_held = choosing = live_steps = 0
+        if self._linear_step_bytes or self._sparse_sizes:
+            # dlint: ok[host-sync] the host's own lane positions (numpy ints), no device value
+            at = np.asarray(positions, np.int64)[:, None] + np.arange(steps)[None, :]
+            at = at[at < seq_len]  # a lane's steps inside the context
+            live_steps = int(at.size)
+            if self._sparse_sizes:
+                attended, held = blocks_attended(at, self._sparse_sizes)
+                blocks_read, blocks_held = int(attended.sum()), int(held.sum())
+                choosing = int((at >= self._sparse_sizes.dense_len).sum())
+                # what a kv head's attention fetched, in rows of one plane
+                read = blocks_read * self._sparse_sizes.block_size
         with engine.stats.lock:
             engine.stats.attn_kv_rows_read += read
             engine.stats.attn_kv_rows_whole += whole
             engine.stats.ssm_lane_steps += ssm
+            engine.stats.linear_state_bytes_moved += live_steps * self._linear_step_bytes
+            engine.stats.attn_blocks_read += blocks_read
+            engine.stats.attn_blocks_held += blocks_held
+            engine.stats.sparse_lane_steps += choosing
             if self._window:
                 engine.stats.attn_window_rows_read += ring_read
                 engine.stats.attn_full_rows_read += read

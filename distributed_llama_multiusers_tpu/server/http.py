@@ -423,6 +423,15 @@ class ApiServer:
             "ssm_lane_steps": stats["ssm_lane_steps"],
             "ssm_rows_scanned": stats["ssm_rows_scanned"],
             "ssm_rows_computed": stats["ssm_rows_computed"],
+            # linear-attention layers: bytes of float32 matrix state the
+            # decode steps read and wrote; block-sparse layers: blocks a kv
+            # head attended and held over live lanes and steps, and lane-steps
+            # at or past sparse_dense_len; all 0 for a model without them
+            "linear_state_bytes_moved": stats["linear_state_bytes_moved"],
+            "linear_rows_computed": stats["linear_rows_computed"],
+            "attn_blocks_read": stats["attn_blocks_read"],
+            "attn_blocks_held": stats["attn_blocks_held"],
+            "sparse_lane_steps": stats["sparse_lane_steps"],
             # window attention layers, in rows of one such layer's ring: what
             # the decode steps fetched, what a full-context layer fetched,
             # what a window layer would have fetched of a plane; and the

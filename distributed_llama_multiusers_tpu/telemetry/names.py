@@ -85,6 +85,17 @@ SSM_MIXER_SCOPES = (SCOPE_SSM, SCOPE_SSM_SCAN)
 # tells the two kinds of attention layer apart
 SCOPE_WINDOW_ATTENTION = "dl.window_attention"  # under dl.attention: a window layer's read of its ring
 
+# a linear-attention layer in such a block (LayerKind.LINEAR;
+# ops/linear_attention.py) takes dl.linear_attention in the place of the four
+# attention scopes
+SCOPE_LINEAR_ATTENTION = "dl.linear_attention"  # the mixer: norm, projections, head norms, rotation, gate, out-projection
+SCOPE_LINEAR_STATE = "dl.linear_state"  # under dl.linear_attention: the matrix state's read, the recurrence and its commit
+LINEAR_MIXER_SCOPES = (SCOPE_LINEAR_ATTENTION, SCOPE_LINEAR_STATE)
+# a block-sparse layer (LayerKind.SPARSE) keeps the four attention scopes and
+# adds two beside dl.attention: dl.block_scores, and dl.sparse_select (above)
+# for the top-k of the block scores and the list or mask made of it
+SCOPE_BLOCK_SCORES = "dl.block_scores"  # the compressed keys' append, the score pass over them, the pooling to blocks
+
 # scopes inside the layer scan, in program order
 LAYER_SCOPES = (SCOPE_QKV, SCOPE_KV_WRITE, SCOPE_ATTENTION, SCOPE_ATTN_OUT, SCOPE_FFN)
 # every scope whose time is the model's own arithmetic (no children)

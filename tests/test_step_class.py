@@ -57,6 +57,10 @@ TOYS = {
     # an indexer beside the latent block's attention: indexer_step_ms and
     # sparse_select_step_ms read these two
     "sparse": ("tiny_deepseek_v32.json", names.LATENT_BLOCK_SCOPES + names.SPARSE_ATTENTION_SCOPES),
+    # linear-attention layers beside block-sparse ones: the six readers of the
+    # minicpm_sala cell read these scopes
+    "sala": ("tiny_minicpm_sala.json", names.LINEAR_MIXER_SCOPES
+             + (names.SCOPE_BLOCK_SCORES, names.SCOPE_SPARSE_SELECT, names.SCOPE_ATTENTION)),
 }
 
 
