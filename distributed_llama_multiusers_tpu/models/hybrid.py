@@ -117,12 +117,14 @@ of every fused step) reads the K/V stack in place, as ``models/llama.py``'s
 block does ("How the cache is read at decode width"): the decode kernel
 (ops/pallas_attention.py) is handed the merged stack as it sits, the count of
 attention layers before this one and the lanes' positions, and fetches for
-each lane the row blocks up to its position. Wider steps, the CPU, a float32
-or f8 cache and a context that is not whole blocks take XLA's dense path over
-the layer's whole plane (``llama.decode_attention_engages`` decides, from the
-inputs). Dense, the five planes of a 64-lane step were 1.34 GB read and
-converted whatever the lanes held: 10.2 of a 28 ms decode step on a v5e
-(PERF.md section 6, PR 36). A window layer's read at one row a lane is the
+each lane the row blocks up to its position. A prefill bucket's rows a lane
+read the same stack in place too, the key blocks up to the chunk's last real
+row (``prefill_attention``; ``llama.prefill_attention_engages`` decides, from
+the inputs). Other widths, the CPU, a float32 or f8 cache and a context that
+is not whole blocks take XLA's dense path over the layer's whole plane
+(``llama.decode_attention_engages`` decides, from the inputs). Dense, the
+five planes of a 64-lane step were 1.34 GB read and converted whatever the
+lanes held: 10.2 of a 28 ms decode step on a v5e (PERF.md section 6, PR 36). A window layer's read at one row a lane is the
 same kernel over its ring with a work list of its own (``ring_blocks``: the
 blocks that hold ``(pos - W, pos]``), under ``dl.window_attention``. At more
 rows a lane, where dense scores ``[B, T, heads, rows]`` would pass
@@ -178,6 +180,7 @@ from .llama import (
     dense_plane_attention,
     gqa_project,
     kv_append,
+    prefill_attention_engages,
 )
 
 
@@ -497,9 +500,17 @@ def hybrid_forward_counted(
     # more rows a lane against many keys: a key block at a time (module header)
     plane_blocked = blocked_attention.engages(b, t, cfg.n_heads, cfg.seq_len)
     ring_blocked = blocked_attention.engages(b, t, cfg.n_heads, ring)
+    # a prefill bucket's rows against a plane whose scores would be dense: in
+    # place too, a key block at a time (models/llama.py, "How the cache is
+    # read at prefill width")
+    chunk_in_place = prefill_attention_engages(
+        cache, mesh, b, t, cfg.n_heads, cfg.n_kv_heads)
     with jax.named_scope(SCOPE_ATTENTION):
         if in_place:
             attn_plan = pallas_attention.lane_blocks(positions, cfg.seq_len)
+        elif chunk_in_place:
+            attn_plan = pallas_attention.chunk_blocks(
+                positions, n_valid, cfg.seq_len, pallas_attention.query_rows(t))
         elif not plane_blocked:
             s_idx = jnp.arange(cfg.seq_len)
             attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
@@ -556,6 +567,10 @@ def hybrid_forward_counted(
                 attn = pallas_attention.decode_attention(
                     q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, ci,
                     attn_plan, scale, interpret=pallas_interpret())
+            elif chunk_in_place:
+                attn = pallas_attention.prefill_attention(
+                    q, k_all, v_all, ci, attn_plan, scale,
+                    interpret=pallas_interpret())
             elif plane_blocked:
                 attn = blocked_attention.blocked_attention(
                     q, k_all, v_all, ci, positions, n_valid, cfg.n_kv_heads, scale)

@@ -41,13 +41,31 @@ whole, the two planes were 4.3 GB a 7B decode step whatever the lanes held,
 two thirds of attention's time (PERF.md section 6, PR 32). The stack goes in
 as the carry holds it, ``(S, n_kv)`` merged into rows by a reshape that moves
 no byte (``models/hybrid.py``'s stack of narrower heads, kept as rows of
-``n_kv * head``, goes in as it sits, with block-diagonal queries). Everything
-else takes ``_dense_attention`` over the plane read out of the carry, as
-before: a prefill chunk or a verify step (a ``[T, S]`` score tile is the right
-shape there), the paged pool's gather, any mesh, a cache the kernel does not
-tile, the CPU. What the inputs are decides it
-(``decode_attention_engages``); the two paths share no logic, the dense one
-being the plain form the kernel is tested against.
+``n_kv * head``, goes in as it sits, with block-diagonal queries).
+
+How the cache is read at prefill width. A step of a prefill bucket's rows a
+lane (``t`` whole query blocks of the kernel: 64 / 256 / 512 / 1024; the
+prefill step and the prefill half of every fused step) over the same kind of
+cache reads it in place too: ``pallas_attention.prefill_attention`` is handed
+the stack, the layer index, the rows' positions and a work list built once
+from them and from each lane's count of real rows (``n_valid``), and fetches
+the key blocks ``[0, last real position]`` of layer ``l`` that each block of
+query rows can see, and no other. Scores and probabilities live in VMEM a
+``[query rows x group, key block]`` tile at a time; a kv head's ``group`` query
+heads ride one product as more rows; causal masking is by position, inside
+the blocks a query block's rows end in. Dense, a 1024-row chunk formed
+``[T, heads, S]`` float32 scores over all 2048 positions, 268 MB a layer
+written and read back, a quarter of a 7B prefill half on a v5e (PERF.md
+section 6, PR 51). Where the dense scores would pass
+``blocked_attention.DENSE_SCORE_BYTES`` (no 2048-position configuration) the
+kernel does not engage (``prefill_attention_engages``).
+
+Everything else takes ``_dense_attention`` over the plane read out of the
+carry, as before: a verify step's ``K + 1`` rows (no whole query block), the
+paged pool's gather, any mesh, a cache the kernels do not tile, the CPU. What
+the inputs are decides it (``decode_attention_engages``,
+``prefill_attention_engages``); the paths share no logic, the dense one being
+the plain form the kernels are tested against.
 
 How the weights move. On one device with the Pallas kernel on, the scan's body
 closes over the stacked Q40 planes (``PackedQ40`` leaves ``[L, d_in/2, d_out]``
@@ -78,7 +96,7 @@ from jax import shard_map
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import HiddenAct
-from ..ops import pallas_attention
+from ..ops import blocked_attention, pallas_attention
 from ..ops.activations import gelu, silu
 from ..ops.linear import (
     matmul,
@@ -406,12 +424,12 @@ def _dense_attention(qf, kf, vf, mask, scale):
     kf/vf: [B,S,K,H] f32; mask: [B,T,S].
 
     Who still takes it: ``llama_forward`` and ``models/hybrid.py``'s
-    attention layers wherever the in-place decode kernel does not engage (a
-    prefill chunk, the verify programs' ``K + 1`` rows, the paged pool's
-    gathered view, a mesh without sp, a float32 or f8 cache, a head size or
-    row width the kernel does not tile, a context that is not whole blocks,
-    the CPU), and training (``train_layer_step_fn``). It is also what
-    tests/test_pallas_attention.py holds the kernel to, at both forms of
+    attention layers wherever neither in-place kernel engages (the verify
+    programs' ``K + 1`` rows, the paged pool's gathered view, a mesh without
+    sp, a float32 or f8 cache, a head size or row width the kernels do not
+    tile, a context that is not whole blocks, the CPU; a prefill chunk over
+    any of those), and training (``train_layer_step_fn``). It is also what
+    tests/test_pallas_attention.py holds both kernels to, at both forms of
     stack."""
     scores = jnp.einsum("btkgh,bskh->btkgs", qf * scale, kf)
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
@@ -500,6 +518,27 @@ def decode_attention_engages(cache, mesh, n_heads: int, n_kv: int | None = None)
     )
 
 
+def prefill_attention_engages(cache, mesh, b: int, t: int, n_heads: int,
+                             n_kv: int | None = None) -> bool:
+    """Whether a step of ``t > 1`` rows a lane attends a plain full-context
+    plane of this cache in place, a key block at a time
+    (``pallas_attention.prefill_attention``): ``t`` whole query blocks of the
+    kernel (the prefill buckets; not a verify step's ``K + 1`` rows), a cache
+    the decode kernel takes (``decode_attention_engages``) in a form the
+    prefill kernel takes too, and dense scores under the size from which
+    ``ops/blocked_attention.py`` walks the key blocks as an XLA loop. Both
+    blocks' forwards and the engine's ``path_facts`` ask this one question; a
+    window layer's ring and a block-sparse layer's chosen blocks are not
+    plain planes and do not ask."""
+    return (
+        t > 1
+        and pallas_attention.query_rows(t) is not None
+        and decode_attention_engages(cache, mesh, n_heads, n_kv)
+        and pallas_attention.supports_prefill(cache.k, n_heads, n_kv)
+        and not blocked_attention.engages(b, t, n_heads, cache.k.shape[2])
+    )
+
+
 def llama_forward(
     config: LlamaConfig,
     params: LlamaParams,
@@ -509,6 +548,7 @@ def llama_forward(
     emulate_q80_activations: bool = False,
     mesh=None,
     q80_sync: bool = False,
+    n_valid: jnp.ndarray | None = None,  # [B] int32: leading real rows a lane
 ) -> tuple[jnp.ndarray, KVCache]:
     """Returns (logits [B, T, vocab] float32, updated cache).
 
@@ -522,7 +562,12 @@ def llama_forward(
     layer ``l``'s plane AFTER that layer's append, so a query sees its own
     fresh key, as it did when each plane was updated on its own. At ``T = 1``
     the read is each lane's rows up to its position, in place (module header,
-    "How the cache is read at decode width").
+    "How the cache is read at decode width"); at a prefill bucket's ``T`` it is
+    the key blocks up to the chunk's last real row, in place too ("How the
+    cache is read at prefill width"). ``n_valid`` says how many leading rows
+    of each lane are real (a bucket's padded tail bounds no read; None: the
+    rows whose position lies inside the context); it changes no real row's
+    result.
 
     ``cache`` may be a :class:`PagedKVCache` (paged attention): K/V are
     gathered per lane through the page table into the same ``[B, S, ...]``
@@ -625,12 +670,21 @@ def llama_forward(
     # one row a lane on one device: the cache is attended in place (module
     # header, "How the cache is read at decode width")
     in_place = t == 1 and decode_attention_engages(cache, mesh, n_heads)
+    # a prefill bucket's rows on one device: in place too, a key block at a
+    # time (module header, "How the cache is read at prefill width")
+    chunk_in_place = prefill_attention_engages(cache, mesh, b, t, n_heads)
     with jax.named_scope(SCOPE_ATTENTION):
-        # cache index validity: query at position p attends to cache slots s <= p
-        s_idx = jnp.arange(h_cfg.seq_len)  # [S]
-        attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
         if in_place:
             attn_plan = pallas_attention.lane_blocks(positions, h_cfg.seq_len)
+        elif chunk_in_place:
+            if n_valid is None:
+                n_valid = jnp.sum(positions < h_cfg.seq_len, axis=1).astype(jnp.int32)
+            attn_plan = pallas_attention.chunk_blocks(
+                positions, n_valid, h_cfg.seq_len, pallas_attention.query_rows(t))
+        else:
+            # cache index validity: query at position p attends to cache slots s <= p
+            s_idx = jnp.arange(h_cfg.seq_len)  # [S]
+            attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
 
     if paged:
         # page indirection, computed ONCE (the table is layer-invariant):
@@ -732,6 +786,13 @@ def llama_forward(
                 attn = pallas_attention.decode_attention(
                     q.reshape(b, n_heads, hd), k_all, v_all, l, attn_plan,
                     scale, interpret=pallas_interpret(),
+                )
+            elif chunk_in_place:
+                # the key blocks up to the chunk's last real row, of layer l,
+                # out of the carry: no plane is sliced out, no score leaves VMEM
+                attn = pallas_attention.prefill_attention(
+                    q, k_all, v_all, l, attn_plan, scale,
+                    interpret=pallas_interpret(),
                 )
             else:
                 attn = plane_attention(q, k_all, v_all, l, scale)

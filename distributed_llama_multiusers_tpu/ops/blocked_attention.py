@@ -20,8 +20,10 @@ layer's plane is the case ``R = seq_len``, no window: ``p = r`` for ``r <= t``
 and negative after it, which is the causal mask.
 
 It engages by shape (``engages``): where the dense scores would exceed
-``DENSE_SCORE_BYTES``. Every 2048-position configuration stays under it and
-keeps ``_dense_attention`` and its programs.
+``DENSE_SCORE_BYTES``. Every 2048-position configuration stays under it: a
+plain plane's chunk there takes the same walk as a Pallas kernel over the
+in-place stack (ops/pallas_attention.py ``prefill_attention``, where
+``llama.prefill_attention_engages``), else ``_dense_attention``.
 """
 
 from __future__ import annotations

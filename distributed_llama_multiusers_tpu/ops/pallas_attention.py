@@ -1,4 +1,6 @@
-"""Decode-width attention over the stacked contiguous KV cache, read in place.
+"""Attention over the stacked contiguous KV cache, read in place: at one query
+row a lane (``decode_attention``, below), and at a prefill bucket's rows a lane
+(``prefill_attention``: "More than one row a lane", at the end).
 
 At one query row a lane (``t == 1``) attention is a read of the lane's keys
 and values and little else. The dense path (``models/llama.py``
@@ -81,6 +83,53 @@ head's 128 columns each) copied into one VMEM buffer by as many DMAs, started
 a grid step ahead into the other half of a double buffer, and ONE softmax over
 the buffer's rows. The list is in rising order, so the row's own block is the
 last and the rows past the lane's position are the buffer's tail.
+
+More than one row a lane. A prefill chunk (``T`` rows of one lane, whole blocks
+of ``QUERY_ROWS``: the buckets 64 / 256 / 512 / 1024) is attended by
+``prefill_attention`` over the same stacks, neither moved. The dense path
+forms ``[T, heads, S]`` float32 scores over the lane's whole context, 268 MB a
+layer for 1024 rows of 32 heads against 2048 positions, written to HBM and
+read back three times: a quarter of a 7B prefill half (PERF.md section 6, PR
+51). Here a grid step is one key block of ``BLOCK_ROWS`` positions against one
+block of query rows for the kv heads of one UNIT, scores and probabilities a
+``[rows x group, BLOCK_ROWS]`` tile in VMEM, with the decode kernel's online
+softmax in f32 scratch across a query block's key blocks.
+
+- The work list (``chunk_blocks``, built once a forward from the rows'
+  positions and each lane's count of real rows): for every block of query
+  rows the key blocks ``[0, hi // BLOCK_ROWS]``, ``hi`` the highest position
+  of its real rows. A key block past the chunk's last real row, or wholly
+  above a query block's rows, is no item; a query block of padded rows alone
+  is one item that computes nothing, keeps the index the pipeline holds (the
+  parked-lane rule) and writes zeros. Padded rows inside a live query block
+  compute finite values nobody reads (the next layer's K/V rows are made of
+  them, and a NaN there would reach a later dense read through ``0 x NaN``).
+- GQA: a kv head's ``group`` query heads ride one product as more rows
+  (``[rows x group, 128] x [BLOCK_ROWS, 128]^T``), so a K or V block is
+  fetched once a unit and the MXU's latched tile meets ``group`` times the
+  rows.
+- The mask is by position (``key <= row's position``, the positions handed in
+  lane-replicated as the running maximum is kept) and only in the blocks a
+  query block's rows end in (``LAST``); every other block is ``FULL``. In a
+  ``LAST`` block the value rows above ``hi`` are zeroed.
+- Which heads a unit is. Merged rows: the kv heads of one 128-lane column
+  tile (one of 128, two of 64), the block ``[BLOCK_ROWS, 128]`` as it sits;
+  with heads under 128 wide the QUERIES are zero outside their kv head's
+  columns of the tile (the decode kernel's block-diagonal queries, a tile
+  wide) and a head keeps its columns of the value product. 128-wide heads on
+  their own axis: with ``(S, n_kv)`` merged into rows, a position's kv heads
+  are ``n_kv`` consecutive rows, and the chip packs bf16 rows ``2i`` and ``2i
+  + 1`` into the two halves of one 32-bit sublane. Read as 32-bit words
+  (``ref.bitcast``, no data moves) every ``n_kv / 2``-th word row from ``u``
+  holds kv heads ``2u`` (low half) and ``2u + 1`` (high half) of every
+  position of the block: one strided load, two shifts, and both heads'
+  ``[BLOCK_ROWS, 128]`` matrices are there. Hence a unit of two, and an even
+  ``n_kv`` (``supports_prefill``).
+- The row sum stays a sum a LANE (whole-tile adds) until the query block's
+  last item, where one reduction across lanes finishes it; only the running
+  maximum is reduced across lanes every step. Reducing both every step was
+  0.263 against 0.154 ms a layer for a 1024-row chunk on a v5e (PERF.md
+  section 6, PR 51).
 """
 
 from __future__ import annotations
@@ -363,6 +412,236 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
         # and a sum with exact zeros, no product
         out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, hd), 0.0).sum(axis=2)
     return out[:, :n_heads]
+
+
+# query rows a block of ``prefill_attention``: the largest of these that
+# divides the chunk (PERF.md section 6, PR 51: the sizes tried on a v5e)
+QUERY_ROWS = (256, 128, 64)
+# what the prefill kernel's bodies traced so far are (``TRACE_STATS`` of
+# ops/pallas_q40.py): read by the engine's ``path_facts`` and printed by
+# ``warmup_engine``, so a run whose chunks still form dense scores is not silent
+TRACE_STATS = {"prefill_kernel_traces": 0}
+
+
+def query_rows(t: int) -> int | None:
+    """Query rows a block of ``prefill_attention`` for a chunk of ``t`` rows a
+    lane; None where ``t`` is no whole blocks (a verify step's ``K + 1``)."""
+    return next((r for r in QUERY_ROWS if t % r == 0), None)
+
+
+def supports_prefill(k_all, n_heads: int, n_kv: int | None = None) -> bool:
+    """Whether ``prefill_attention`` takes this cache: one ``supports`` takes,
+    and, of 128-wide heads, an even number of kv heads (they leave a block in
+    the pairs the chip packs them in; module header, "More than one row"), of
+    a merged row, heads that fill or evenly share a 128-lane tile."""
+    if not supports(k_all, n_heads, n_kv):
+        return False
+    if k_all.ndim == 5:
+        return k_all.shape[3] % 2 == 0
+    return HEAD_SIZE % (k_all.shape[3] // n_kv) == 0  # heads do not straddle a column tile
+
+
+def chunk_blocks(positions: jnp.ndarray, n_valid: jnp.ndarray, seq_len: int, rows: int):
+    """``lane_blocks`` for chunks of more than one row a lane: ``(n_items,
+    plan, row_positions)``, layer invariant. ``positions`` ``[B, T]``,
+    ``n_valid`` ``[B]`` (a lane's leading real rows), ``rows`` from
+    ``query_rows(T)``. ``row_positions`` ``int32 [B, T, 128]``: every row's
+    position across a lane tile, as the kernel keeps its running maximum (its
+    mask compares whole tiles and broadcasts no column). Item ``w <
+    n_items`` is one key block of one block of ``rows`` query rows: the lanes
+    in order, a lane's query blocks in order, and of a query block the key
+    blocks ``[0, hi // BLOCK_ROWS]`` where ``hi`` is the highest position of
+    its real rows. A query block with no real row is one item that computes
+    nothing, fetches nothing (its index stays on what the pipeline holds) and
+    writes zeros. ``plan`` is ``int32 [6, B * T / rows * S / BLOCK_ROWS]``:
+    the item's lane and query block, the lane and key block it fetches, a code
+    (``FULL``: every row reads every key of the block; ``LAST``: masked by
+    position; ``FIRST`` / ``FINAL`` item of its query block), and ``hi``."""
+    b, t = positions.shape
+    nq = t // rows
+    pos = positions.astype(jnp.int32).reshape(b * nq, rows)
+    row = jnp.arange(t, dtype=jnp.int32).reshape(1, nq, rows)
+    real = (row < n_valid.astype(jnp.int32)[:, None, None]).reshape(b * nq, rows)
+    real = real & (pos >= 0) & (pos < seq_len)
+    hi = jnp.max(jnp.where(real, pos, -1), axis=1)
+    lo = jnp.min(jnp.where(real, pos, seq_len), axis=1)
+    live = hi >= 0
+    n = jnp.where(live, hi // BLOCK_ROWS + 1, 0)
+    items = jnp.maximum(n, 1)
+    end = jnp.cumsum(items)
+    w = jnp.arange(b * nq * (seq_len // BLOCK_ROWS), dtype=jnp.int32)
+    seg = jnp.minimum(jnp.searchsorted(end, w, side="right", method="compare_all"), b * nq - 1)
+    seg = seg.astype(jnp.int32)
+    j = w - (end - items)[seg]
+    # an item that computes nothing stays on what the pipeline holds: the
+    # block of the nearest live item before it, else of the first live item
+    prev = jax.lax.cummax(jnp.where(live[seg], w, -1))
+    held = jnp.where(prev >= 0, prev, jnp.argmax(live[seg]).astype(jnp.int32))
+    code = (
+        jnp.where(live[seg],
+                  jnp.where((j + 1) * BLOCK_ROWS - 1 <= lo[seg], FULL, LAST), 0)
+        + jnp.where(j == 0, FIRST, 0)
+        + jnp.where(j == items[seg] - 1, FINAL, 0)
+    )
+    plan = jnp.stack([seg // nq, seg % nq, (seg // nq)[held], j[held], code, hi[seg]])
+    return end[-1], plan, jnp.broadcast_to(
+        positions.astype(jnp.int32)[:, :, None], (b, t, HEAD_SIZE))
+
+
+def _prefill_attention_kernel(layer_ref, plan_ref, q_ref, qpos_ref, k_ref, v_ref, o_ref,
+                              m_ref, l_ref, acc_ref, *, scale, group, pair_stride):
+    del layer_ref  # spent in the index maps
+    unit, w = pl.program_id(0), pl.program_id(1)
+    block_index, code, hi = plan_ref[3, w], plan_ref[4, w], plan_ref[5, w]
+    per = m_ref.shape[0]  # kv heads a grid step
+    rows = q_ref.shape[0]
+    lane_tiles = BLOCK_ROWS // HEAD_SIZE
+
+    @pl.when(code & FIRST != 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def heads_of(ref):
+        """The block's ``[BLOCK_ROWS, 128]`` matrix of each kv head of this
+        grid step (module header, "More than one row")."""
+        if pair_stride is None:  # a merged row's column tile, as it is
+            return [ref[...]] * per
+        # rows (position, kv head): the chip packs rows 2i and 2i + 1 into one
+        # 32-bit sublane, so kv heads 2u and 2u + 1 of every position are the
+        # low and high halves of every pair_stride-th word row from u
+        words = ref.bitcast(jnp.uint32)[pl.ds(unit, BLOCK_ROWS, stride=pair_stride), :]
+        halves = (words << 16, words & jnp.uint32(0xFFFF0000))
+        return [jax.lax.bitcast_convert_type(h, jnp.float32).astype(ref.dtype) for h in halves]
+
+    def block(masked: bool):
+        ks, vs = heads_of(k_ref), heads_of(v_ref)
+        if masked:
+            # by position, inside a block the chunk's rows end in: a row reads
+            # the keys up to its own, and a row above the highest real one
+            # (stale: 0 x NaN is NaN) reaches neither scores nor values
+            key_pos = block_index * BLOCK_ROWS + jax.lax.broadcasted_iota(
+                jnp.int32, (rows * group, BLOCK_ROWS), 1)
+            seen = key_pos <= jnp.concatenate([qpos_ref[...][:, :1]] * group, axis=0)
+            row_pos = block_index * BLOCK_ROWS + jax.lax.broadcasted_iota(
+                jnp.int32, (BLOCK_ROWS, HEAD_SIZE), 0)
+            vs = [jnp.where(row_pos <= hi, v, jnp.zeros_like(v)) for v in vs]
+        for i in range(per):
+            # the kv head's `group` query heads ride one product as more rows
+            q = jnp.concatenate(
+                [q_ref[:, (i * group + g) * HEAD_SIZE:(i * group + g + 1) * HEAD_SIZE]
+                 for g in range(group)], axis=0)  # [group * rows, 128]
+            s = jax.lax.dot_general(
+                q, ks[i], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * scale  # [group * rows, BLOCK_ROWS]
+            if masked:
+                s = jnp.where(seen, s, -jnp.inf)
+            m_prev = m_ref[i]  # [group * rows, 128], every lane of a row the same
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)  # a row that has met no key
+            alpha = jnp.exp(m_prev - m_safe)
+            # the maximum as it is kept, a lane tile beside a lane tile: no
+            # column is broadcast
+            p = jnp.exp(s - jnp.concatenate([m_safe] * lane_tiles, axis=1))
+            # the sum stays a sum a LANE until the query block's last item:
+            # adds of whole tiles here, one reduction across lanes there (the
+            # reductions across lanes are what a step waits for; PERF.md
+            # section 6, PR 51)
+            tiles = [p[:, j * HEAD_SIZE:(j + 1) * HEAD_SIZE] for j in range(lane_tiles)]
+            l_ref[i] = alpha * l_ref[i] + sum(tiles[1:], tiles[0])
+            acc_ref[i] = alpha * acc_ref[i] + jnp.dot(
+                p.astype(vs[i].dtype), vs[i], preferred_element_type=jnp.float32)
+            m_ref[i] = m_new
+
+    pl.when(code & FULL != 0)(partial(block, False))
+    pl.when(code & LAST != 0)(partial(block, True))
+
+    @pl.when(code & FINAL != 0)
+    def _():
+        for i in range(per):
+            l = jnp.sum(l_ref[i], axis=1, keepdims=True)
+            # a block with no real row summed nothing: zeros, no division
+            out = jnp.where(l > 0.0, acc_ref[i] / l, 0.0)
+            for g in range(group):
+                at = (i * group + g) * HEAD_SIZE
+                o_ref[:, at:at + HEAD_SIZE] = out[g * rows:(g + 1) * rows].astype(o_ref.dtype)
+
+
+def prefill_attention(q, k_all, v_all, layer, work, scale: float,
+                      interpret: bool = False) -> jnp.ndarray:
+    """``T`` query rows a lane (whole blocks: ``query_rows``) against layer
+    ``layer`` of the stacked cache, a key block at a time (module header, "More
+    than one row a lane").
+
+    q ``[B, T, n_heads, hd]`` (head ``h * group + g`` reads kv head ``h``);
+    ``k_all`` / ``v_all`` as ``decode_attention`` takes them
+    (``supports_prefill``), the chunk's rows already appended; ``work`` from
+    ``chunk_blocks``.
+    Returns ``[B, T, n_heads, hd]`` in q's type; a real row's result depends on
+    its lane's rows ``[0, position]`` alone, a row at or past ``n_valid`` holds
+    finite values nobody reads."""
+    TRACE_STATS["prefill_kernel_traces"] += 1
+    b, t, n_heads, hd = q.shape
+    n_layers, lanes, seq_len = k_all.shape[:3]
+    merged = k_all.ndim == 4
+    n_kv = k_all.shape[3] // hd if merged else k_all.shape[3]
+    group = n_heads // n_kv
+    # kv heads a grid step: the two a packed word row holds, or those of one
+    # 128-lane column tile of a merged row
+    per = HEAD_SIZE // hd if merged else 2
+    rows = query_rows(t)
+    n_items, plan, qpos = work
+    qk = q.astype(k_all.dtype)
+    if hd < HEAD_SIZE:
+        # a head's values in its kv head's columns of the tile, zeros in the
+        # others' (``decode_attention``'s block-diagonal queries, a tile wide)
+        own = (np.arange(per)[None, :] == (np.arange(n_heads) // group % per)[:, None])[:, :, None]
+        qk = jnp.where(own, qk[:, :, :, None, :], 0).reshape(b, t, n_heads, HEAD_SIZE)
+    qk = qk.reshape(b, t, n_heads * HEAD_SIZE)
+    width = per * group * HEAD_SIZE  # a grid step's query columns
+    if merged:
+        kv_shape = k_all.shape
+        kv_spec = pl.BlockSpec(
+            (None, None, BLOCK_ROWS, HEAD_SIZE),
+            lambda u, w, layer_ref, plan_ref: (layer_ref[0], plan_ref[2, w], plan_ref[3, w], u))
+    else:
+        # every axis down to n_kv merged: a bitcast (``decode_attention``), and
+        # a block is a plain matrix, which the view as 32-bit words needs
+        kv_shape = (n_layers * lanes * seq_len * n_kv, HEAD_SIZE)
+        n_blocks = seq_len // BLOCK_ROWS
+        kv_spec = pl.BlockSpec(
+            (BLOCK_ROWS * n_kv, HEAD_SIZE),
+            lambda u, w, layer_ref, plan_ref: (
+                (layer_ref[0] * lanes + plan_ref[2, w]) * n_blocks + plan_ref[3, w], 0))
+    q_spec = pl.BlockSpec(
+        (None, rows, width), lambda u, w, layer_ref, plan_ref: (plan_ref[0, w], plan_ref[1, w], u))
+    qpos_spec = pl.BlockSpec(
+        (None, rows, HEAD_SIZE), lambda u, w, layer_ref, plan_ref: (plan_ref[0, w], plan_ref[1, w], 0))
+    stat = pltpu.VMEM((per, group * rows, HEAD_SIZE), jnp.float32)
+    out = pl.pallas_call(
+        partial(_prefill_attention_kernel, scale=scale, group=group,
+                pair_stride=None if merged else n_kv // 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # the layer index and the work list
+            grid=(n_kv // per, n_items),
+            in_specs=[q_spec, qpos_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[stat] * 3,
+        ),
+        out_shape=jax.ShapeDtypeStruct(qk.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=64 * 2**20),
+        name="prefill_attention",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan, qk, qpos,
+      k_all.reshape(kv_shape), v_all.reshape(kv_shape))
+    out = out.reshape(b, t, n_heads, HEAD_SIZE)
+    if hd < HEAD_SIZE:
+        # a head keeps its kv head's columns of the value product: a select
+        # and a sum with exact zeros
+        out = jnp.where(own, out.reshape(b, t, n_heads, per, hd), 0).sum(axis=3)
+    return out
 
 
 def supports_sparse(k_all, n_heads: int, n_kv: int, block_size: int) -> bool:

@@ -46,6 +46,7 @@ from ..models.llama import (
     decode_attention_engages,
     init_kv_cache,
     init_paged_kv_cache,
+    prefill_attention_engages,
 )
 from ..models.deepseek import (
     ROUTED_COUNTS,
@@ -860,9 +861,11 @@ class InferenceEngine:
 
         def forward_n(*a, n_valid=None, **kw):
             # n_valid (a prefill chunk's real tokens) reaches a block whose
-            # lanes carry a state overwritten in place; no other has a use
-            # for it, and their programs are what they always were
-            if cfg.recurrent_state:
+            # lanes carry a state overwritten in place, and a Llama block,
+            # whose prefill attention then fetches no key block past the last
+            # real row; the latent block has no use for it, and its programs
+            # are what they always were
+            if cfg.recurrent_state or not (cfg.latent_attention or cfg.layer_kinds):
                 kw["n_valid"] = n_valid
             return forward_c(*a, **kw)
 
@@ -1671,10 +1674,12 @@ class InferenceEngine:
 
     def path_facts(self) -> dict:
         """Which attention and which expert path this engine's decode steps
-        run, by the predicates the forward itself asks, and what the Q40
-        kernel bodies traced so far are (``q40_weight_passes``,
-        ``q40_offset_subtracted``: the trace-time witnesses of
-        ``ops/pallas_q40.py``): said once at start-up (the
+        run and how its prefill chunks read the cache, by the predicates the
+        forward itself asks, and what the kernel bodies traced so far are
+        (``q40_weight_passes``, ``q40_offset_subtracted``,
+        ``prefill_kernel_traces``: the trace-time witnesses of
+        ``ops/pallas_q40.py`` and ``ops/pallas_attention.py``): said once at
+        start-up (the
         ``runtime_device`` line, ``/stats``), so that a fallback is never
         silent."""
         from ..ops.linear import pallas_kernel_active
@@ -1698,7 +1703,27 @@ class InferenceEngine:
             experts = "q40_grouped_kernel"
         else:
             experts = "xla_gathered_slabs"
-        facts = {"attention_path": attention, "expert_path": experts,
+        # how a prefill chunk's rows (one lane, the largest bucket's) read a
+        # plain full-context plane: the key blocks the chunk can see, in
+        # place on the chip; the same walk as an XLA loop (a layer-pattern
+        # block's long planes); or dense scores over the whole plane. A latent
+        # cache has no such plane: its own path's name
+        chunk = self.prefill_buckets[-1]
+        if cfg.latent_attention:
+            prefill = attention
+        elif prefill_attention_engages(
+                self.cache, self.mesh, 1, chunk, cfg.n_heads, cfg.n_kv_heads):
+            prefill = "in_place_kernel"
+        elif cfg.layer_kinds and blocked_attention.engages(1, chunk, cfg.n_heads, cfg.seq_len):
+            prefill = "blocked"
+        else:
+            prefill = "dense"
+        facts = {"attention_path": attention, "prefill_attention_path": prefill,
+                 # prefill kernel bodies traced so far (after warm-up: above 0
+                 # wherever the path above says in_place_kernel; 0 there: the
+                 # chunks still form dense scores)
+                 "prefill_kernel_traces": pallas_attention.TRACE_STATS["prefill_kernel_traces"],
+                 "expert_path": experts,
                  "sampler_groups": self.sampler_groups,
                  # the most passes over its weight plane any Q40 kernel call
                  # traced so far makes (after warm-up: 1 where every prefill

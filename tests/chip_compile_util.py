@@ -203,17 +203,18 @@ def check_one_row_block(v5e, d_in, d_out, stacked, m):
         assert not _scales_stack_converted_whole(hlo, d_in, d_out)
 
 
-def _three_layer_decode_hlo(v5e, monkeypatch, lanes=16, n_heads=32, n_kv=8):
-    """The optimized HLO of a three-layer decode forward (one row a lane, the
-    cache donated) for a described v5e, and its dimensions: Mistral-7B's
-    widths, or Qwen2.5-7B's at 28 heads."""
+def _three_layer_decode_hlo(v5e, monkeypatch, lanes=16, n_heads=32, n_kv=8, rows=1, seq=256):
+    """The optimized HLO of a three-layer forward (one row a lane: a decode
+    step; ``rows`` a lane: a prefill chunk; the cache donated) for a described
+    v5e, and its dimensions: Mistral-7B's widths, or Qwen2.5-7B's at 28
+    heads."""
     from distributed_llama_multiusers_tpu.models import llama
     from distributed_llama_multiusers_tpu.models.config import LlamaConfig
 
     monkeypatch.setattr(
         linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas
     )
-    L, d, kv, vocab, seq = 3, n_heads * 128, n_kv * 128, 8192, 256
+    L, d, kv, vocab = 3, n_heads * 128, n_kv * 128, 8192
     h = {4096: 14336, 3584: 18944}[d]
     cfg = LlamaConfig(dim=d, hidden_dim=h, n_layers=L, n_heads=n_heads,
                       n_kv_heads=n_kv, vocab_size=vocab, seq_len=seq)
@@ -230,7 +231,7 @@ def _three_layer_decode_hlo(v5e, monkeypatch, lanes=16, n_heads=32, n_kv=8):
         rms_final=sds((d,), jnp.float32), wcls=q40(d, vocab, ()),
         rope_cos=sds((seq, 64), jnp.float32), rope_sin=sds((seq, 64), jnp.float32))
     cache = llama.KVCache(*(sds((L, lanes, seq, n_kv, 128), jnp.bfloat16),) * 2)
-    tok = sds((lanes, 1), jnp.int32)
+    tok = sds((lanes, rows), jnp.int32)
     hlo = jax.jit(
         lambda p, t, c: llama.llama_forward(cfg, p, t, t, c), donate_argnums=(2,)
     ).lower(params, tok, cache).compile().as_text()

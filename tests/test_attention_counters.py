@@ -125,6 +125,7 @@ def test_a_layer_pattern_engine_counts_what_its_decode_attention_fetches(pallas)
                                       pipeline_depth=0, prefill_buckets=(64,))
         facts = engine.path_facts()
         assert facts["attention_path"] == ("pallas_in_place" if pallas else "xla_dense")
+        assert facts["prefill_attention_path"] == ("in_place_kernel" if pallas else "dense")
         assert engine.decode_attention_block == (BLOCK if pallas else None)
         seen, real = [], engine.decode
         engine.decode = lambda tokens, positions, *a, **kw: (
@@ -155,3 +156,77 @@ def test_a_layer_pattern_engine_counts_what_its_decode_attention_fetches(pallas)
         assert stats["attn_kv_rows_read"] == hand < stats["attn_kv_rows_whole"] // 2
     else:
         assert stats["attn_kv_rows_read"] == stats["attn_kv_rows_whole"]
+
+
+def _llama_engine(**kw):
+    """A Llama-block engine at a shape both in-place kernels take: two kv
+    heads of 128, bfloat16, two blocks of context, one 64-row bucket."""
+    import jax.numpy as jnp
+
+    from distributed_llama_multiusers_tpu.models.config import LlamaConfig
+    from distributed_llama_multiusers_tpu.models.loader import params_from_random
+    from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
+
+    cfg = LlamaConfig(dim=512, hidden_dim=512, n_layers=2, n_heads=4, n_kv_heads=2,
+                      vocab_size=64, seq_len=2 * BLOCK)
+    params = params_from_random(cfg, seed=0, dtype=jnp.bfloat16, scale=0.05)
+    if kw.get("mesh") is not None:
+        from distributed_llama_multiusers_tpu.parallel.sharding import shard_params
+
+        params = shard_params(params, kw["mesh"])
+    return InferenceEngine(cfg, params, n_lanes=2, prefill_buckets=(64,),
+                           cache_dtype=jnp.bfloat16, **kw)
+
+
+def _pattern_engine(**kw):
+    import jax.numpy as jnp
+
+    import latent_toy
+
+    cfg, family, _ = latent_toy.wide_lfm2()
+    return latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16, lanes=2,
+                             prefill_buckets=(64,), **kw)[0]
+
+
+def _mesh_engine():
+    from distributed_llama_multiusers_tpu.parallel import MeshPlan, make_mesh
+
+    return _llama_engine(mesh=make_mesh(MeshPlan(tp=2)), replicate_outputs=True)
+
+
+@pytest.mark.parametrize("build,pallas,blocked,want", [
+    (_llama_engine, True, False, "in_place_kernel"),
+    (_pattern_engine, True, False, "in_place_kernel"),
+    (_pattern_engine, True, True, "blocked"),
+    (_llama_engine, False, False, "dense"),
+    (_pattern_engine, False, False, "dense"),
+    (lambda: _llama_engine(paged_kv=True), True, False, "dense"),
+    (_mesh_engine, True, False, "dense"),
+], ids=["llama_block", "layer_pattern", "layer_pattern_long_plane", "llama_block_pallas_off",
+        "layer_pattern_pallas_off", "paged_pool", "mesh"])
+def test_path_facts_say_how_a_prefill_chunk_reads_the_cache(monkeypatch, build, pallas,
+                                                             blocked, want):
+    """`prefill_attention_path` by the predicates the forwards ask: the kernel
+    in place where both blocks' contiguous bf16 stacks meet Pallas on one
+    device; the XLA walk over key blocks where a layer-pattern block's dense
+    scores would pass the stated size (moved down here to the toy's); dense
+    scores for Pallas off, the paged pool's gathered view and a mesh."""
+    from distributed_llama_multiusers_tpu.ops import blocked_attention, linear
+
+    if blocked:
+        monkeypatch.setattr(blocked_attention, "DENSE_SCORE_BYTES", 0)
+    linear.set_pallas_interpret(pallas)
+    try:
+        facts = build().path_facts()
+    finally:
+        linear.set_pallas_interpret(False)
+    assert facts["prefill_attention_path"] == want
+    assert "prefill_kernel_traces" in facts
+
+
+def test_a_latent_cache_names_its_own_prefill_path():
+    import latent_toy
+
+    cfg, family, _ = latent_toy.load()
+    facts = latent_toy.engine(family, cfg, lanes=2)[0].path_facts()
+    assert facts["prefill_attention_path"] == facts["attention_path"] != "in_place_kernel"

@@ -1,6 +1,6 @@
-"""Decode attention in place (PR 32, ops/pallas_attention.py; PR 36: the
-layer-pattern block's merged stack), compiled for a described v5e
-(tests/chip_compile_util.py)."""
+"""Attention in place (ops/pallas_attention.py: decode width, PR 32; the
+layer-pattern block's merged stack, PR 36; prefill width, PR 51), compiled for
+a described v5e (tests/chip_compile_util.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +107,75 @@ def test_pattern_decode_forward_reads_no_kv_plane_for_v5e(v5e, monkeypatch, in_p
         assert kernels == 0
         reads = [m for m in made if "dynamic-slice" in m or "fusion" in m or "convert" in m]
         assert len(reads) >= 4, made  # K's plane and V's, in the body and in the tail
+
+
+# ---- prefill width (PR 51) ---------------------------------------------------
+
+PREFILL_ROWS = [64, 256, 512, 1024]
+
+
+@pytest.mark.parametrize("rows", PREFILL_ROWS)
+@pytest.mark.parametrize("n_heads,n_kv,hd,merged", [
+    (32, 8, 128, False), (28, 4, 128, False), (32, 8, 64, True), (20, 1, 128, True)],
+    ids=["mistral7b", "qwen25_7b", "lfm2_merged", "jamba_merged"])
+def test_prefill_attention_compiles_for_v5e(v5e, n_heads, n_kv, hd, merged, rows):
+    """Mosaic takes the prefill kernel at the cells' head shapes and at every
+    prefill bucket: one lane of a stack of a few layers as the carry holds it
+    (a layer-pattern block's with the heads merged into the row), the layer,
+    the positions and the work list traced."""
+    from distributed_llama_multiusers_tpu.ops import pallas_attention as pa
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    stack = sds((STACK_LAYERS, 1, 2048) + ((n_kv * hd,) if merged else (n_kv, hd)),
+                jnp.bfloat16)
+    assert pa.supports_prefill(stack, n_heads, n_kv)
+
+    def attend(q, k, v, layer, positions, n_valid):
+        work = pa.chunk_blocks(positions, n_valid, 2048, pa.query_rows(rows))
+        return pa.prefill_attention(q, k, v, layer, work, hd ** -0.5)
+
+    hlo = jax.jit(attend).lower(
+        sds((1, rows, n_heads, hd), jnp.bfloat16), stack, stack,
+        sds((), jnp.int32), sds((1, rows), jnp.int32), sds((1,), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo and "prefill_attention" in hlo
+
+
+def _scores_and_planes(hlo: str, L: int, seq: int, n_kv: int, rows: int) -> tuple[list, list]:
+    """(float32 arrays with both a ``rows`` and a ``seq`` axis, what makes an
+    array of the size of one lane's K or V plane or of its stack) in a prefill
+    forward of one lane."""
+    import re
+
+    scores = sorted(set(re.findall(
+        rf"f32\[(?:\d+,)*(?:{rows},(?:\d+,)*{seq}|{seq},(?:\d+,)*{rows})(?:,\d+)*\]", hlo)))
+    planes = _cache_sized_results(hlo, L, 1, seq, n_kv) + _results_of_shape(
+        hlo, rf"(?:bf16|f32)\[(?:{L * seq * n_kv}|{seq * n_kv}),128\]")
+    return scores, planes
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel_reads_in_place", "control_dense_scores"])
+@pytest.mark.parametrize("n_heads,n_kv", [(32, 8), (28, 4)], ids=["mistral7b", "qwen25_7b"])
+def test_prefill_forward_forms_no_dense_scores_for_v5e(v5e, monkeypatch, n_heads, n_kv, in_place):
+    """The optimized HLO of a three-layer 1024-row prefill forward of one lane
+    of a 2048-position configuration: no float32 tensor has both a ``T`` and an
+    ``S`` axis and nothing has a K or V plane, or the stack, as its result but
+    the in-place appends: the kernel is handed the carry. The control patches
+    the predicate off, as the program was before PR 51, and shows what the
+    check looks for: ``[T, heads, S]`` scores and each plane read out of the
+    stack and converted."""
+    from distributed_llama_multiusers_tpu.models import llama
+
+    if not in_place:
+        monkeypatch.setattr(llama, "prefill_attention_engages", lambda *a: False)
+    rows, seq = 1024, 2048
+    hlo, dims = _three_layer_decode_hlo(
+        v5e, monkeypatch, lanes=1, n_heads=n_heads, n_kv=n_kv, rows=rows, seq=seq)
+    scores, planes = _scores_and_planes(hlo, dims["L"], seq, n_kv, rows)
+    assert hlo.count("prefill_attention") >= int(in_place)
+    if in_place:
+        assert scores == [] and planes == [], (scores, planes)
+    else:
+        assert "prefill_attention" not in hlo
+        assert scores and len(planes) >= 2, (scores, planes)

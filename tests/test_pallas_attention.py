@@ -1,4 +1,5 @@
-"""Decode attention in place (ops/pallas_attention.py) against the dense path.
+"""Attention in place (ops/pallas_attention.py: the decode kernel, and at the
+end of the file the prefill kernel of PR 51) against the dense path.
 
 The kernel runs in interpret mode on the CPU, as tests/test_pallas_q40.py
 runs its kernel: that proves its arithmetic and its work list, not that it
@@ -230,3 +231,207 @@ def test_forward_takes_the_kernel_only_where_its_inputs_allow(monkeypatch):
             np.asarray(got[0], np.float32), np.asarray(want[0], np.float32))
     got, want = np.asarray(logits)[live], np.asarray(dense_logits)[live]
     assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
+
+
+# ---- more than one row a lane: the prefill kernel (PR 51) -------------------
+
+def _chunk_stack(n_kv, group, hd, lanes, t, seed):
+    rng = np.random.default_rng(seed)
+    shape = (LAYERS, lanes, SEQ) + ((n_kv, hd) if hd == HD else (n_kv * hd,))
+    k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((lanes, t, n_kv * group, hd)), jnp.bfloat16)
+    return q, k, v
+
+
+def _chunk_kernel(q, k, v, positions, n_valid, layer=LAYER):
+    positions = jnp.asarray(positions, jnp.int32)
+    work = pa.chunk_blocks(positions, jnp.asarray(n_valid, jnp.int32), SEQ,
+                           pa.query_rows(q.shape[1]))
+    out = pa.prefill_attention(q, k, v, layer, work, q.shape[-1] ** -0.5, interpret=True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    return np.asarray(out.astype(jnp.float32))
+
+
+def _chunk_dense(q, k, v, positions, layer=LAYER):
+    lanes, t, n_heads, hd = q.shape
+    n_kv = k[layer].size // (lanes * SEQ * hd)
+    qf = q.astype(jnp.float32).reshape(lanes, t, n_kv, n_heads // n_kv, hd)
+    mask = jnp.arange(SEQ)[None, None, :] <= jnp.asarray(positions)[:, :, None]
+    plane = lambda c: c[layer].astype(jnp.float32).reshape(lanes, SEQ, n_kv, hd)
+    out = llama._dense_attention(qf, plane(k), plane(v), mask, hd ** -0.5)
+    return np.asarray(out).reshape(lanes, t, n_heads, hd)
+
+
+# (rows a lane, each lane's start, each lane's real rows)
+CHUNKS = {
+    "start_0": (64, [0], [64]),
+    "start_inside_a_block": (64, [100], [64]),
+    "across_a_block_edge": (64, [BLOCK - 20], [64]),
+    "padded_rows_ignored": (64, [BLOCK + 30], [23]),
+    "two_lanes_unlike_starts_two_query_blocks": (2 * pa.QUERY_ROWS[0], [3, 2 * BLOCK - 100],
+                                                 [2 * pa.QUERY_ROWS[0], pa.QUERY_ROWS[0] + 9]),
+    "a_lane_with_no_real_row": (64, [40, 2 * BLOCK, 7], [64, 0, 64]),
+}
+
+
+@pytest.mark.parametrize("chunk", CHUNKS, ids=list(CHUNKS))
+@shapes
+def test_prefill_kernel_matches_dense_attention(n_kv, group, hd, chunk):
+    """Real rows agree with `_dense_attention` over the whole plane; a padded
+    row holds finite values nobody reads (the next layer's K/V rows are made
+    of them); a lane with no real row yields zeros."""
+    t, starts, n_valid = CHUNKS[chunk]
+    q, k, v = _chunk_stack(n_kv, group, hd, len(starts), t, seed=n_kv + t)
+    positions = np.asarray(starts)[:, None] + np.arange(t)[None, :]
+    got = _chunk_kernel(q, k, v, positions, n_valid)
+    want = _chunk_dense(q, k, v, positions)
+    assert np.isfinite(got).all()
+    for b, n in enumerate(n_valid):
+        if n == 0:
+            assert not got[b].any()
+            continue
+        np.testing.assert_allclose(
+            got[b, :n], want[b, :n], rtol=0, atol=6e-3 * np.abs(want[b, :n]).max(),
+            err_msg=f"lane {b} from {starts[b]}, {n} real rows")
+
+
+def test_prefill_kernel_takes_a_merged_row_of_one_128_wide_kv_head():
+    """Jamba's attention layers: every query head on ONE kv head of 128, kept
+    merged (a row IS the head): a unit of one head, no pairs to take apart."""
+    t, n_kv, group = 64, 1, 5
+    q, k, v = _chunk_stack(n_kv, group, HD, 2, t, seed=9)
+    merge = lambda c: c.reshape(*c.shape[:3], n_kv * HD)
+    positions = np.asarray([BLOCK - 9, 0])[:, None] + np.arange(t)[None, :]
+    got = _chunk_kernel(q, merge(k), merge(v), positions, [t, 31])
+    want = _chunk_dense(q, k, v, positions)
+    for b, n in enumerate([t, 31]):
+        np.testing.assert_allclose(
+            got[b, :n], want[b, :n], rtol=0, atol=6e-3 * np.abs(want[b, :n]).max())
+
+
+@shapes
+def test_prefill_rows_do_not_see_rows_above_the_chunks_last_real_one(n_kv, group, hd):
+    """NaN in every cache row past the last real position (a stale row of an
+    earlier request, or nothing at all) reaches no real row: the rows of the
+    last block are masked by position and its values zeroed."""
+    t, start, n = 64, BLOCK + 10, 40
+    q, k, v = _chunk_stack(n_kv, group, hd, 1, t, seed=5)
+    positions = start + np.arange(t)[None, :]
+    want = _chunk_kernel(q, k, v, positions, [n])[0, :n]
+    poison = lambda c: c.at[:, :, start + n:].set(jnp.nan)
+    got = _chunk_kernel(q, poison(k), poison(v), positions, [n])
+    np.testing.assert_array_equal(got[0, :n], want)
+    assert np.isfinite(got).all()
+
+
+def test_chunk_work_list_visits_the_blocks_each_query_block_can_see():
+    """Two lanes, two query blocks each: lane 0 from 3 (its second query block
+    ends in key block 2), lane 1 with no real row in its second query block
+    (one item that fetches nothing new and computes nothing)."""
+    rows = pa.QUERY_ROWS[0]
+    t = 2 * rows
+    positions = jnp.asarray([[3], [BLOCK + 5]], jnp.int32) + jnp.arange(t, dtype=jnp.int32)[None, :]
+    n_items, plan, row_positions = pa.chunk_blocks(
+        positions, jnp.asarray([t, rows - 1], jnp.int32), SEQ, rows)
+    n_items, plan = int(n_items), np.asarray(plan)[:, :int(n_items)]
+    np.testing.assert_array_equal(
+        np.asarray(row_positions), np.broadcast_to(np.asarray(positions)[:, :, None], (2, t, HD)))
+    hi = [3 + rows - 1, 3 + t - 1, BLOCK + 5 + rows - 2]
+    want = []  # (lane, query block, fetched lane, fetched block, code, hi)
+    for (lane, qb), h, lo in zip([(0, 0), (0, 1), (1, 0)], hi, [3, 3 + rows, BLOCK + 5]):
+        last = h // BLOCK
+        for j in range(last + 1):
+            code = pa.FULL if (j + 1) * BLOCK - 1 <= lo else pa.LAST
+            code += pa.FIRST * (j == 0) + pa.FINAL * (j == last)
+            want.append((lane, qb, lane, j, code, h))
+    want.append((1, 1, 1, hi[2] // BLOCK, pa.FIRST + pa.FINAL, -1))  # held, idle
+    assert n_items == len(want)
+    np.testing.assert_array_equal(plan.T, np.asarray(want))
+
+
+@pytest.mark.parametrize("shape,n_kv,want", [
+    ((2, 3, SEQ, 4, HD), None, True),
+    ((2, 3, SEQ, 1, HD), None, False),  # 128-wide heads leave a block in pairs
+    ((2, 3, SEQ, 3, HD), None, False),
+    ((2, 3, SEQ, 8 * 64), 8, True),  # two 64-wide heads a column tile
+    ((2, 3, SEQ, 2 * 128), 2, True),  # merged rows of 128-wide heads
+    ((2, 3, SEQ, 4 * 96), 4, False),  # a head would straddle two tiles
+    ((2, 3, SEQ + 8, 4, HD), None, False),  # what `supports` declines
+], ids=["4x128", "1x128", "3x128", "8x64_merged", "2x128_merged", "4x96_merged", "ragged_context"])
+def test_supports_prefill_says_which_stacks_the_prefill_kernel_takes(shape, n_kv, want):
+    k = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert pa.supports_prefill(k, 8, n_kv) is want
+
+
+@pytest.mark.parametrize("t,want", [(1024, 256), (512, 256), (256, 256), (64, 64), (128, 128),
+                                    (5, None), (17, None), (96, None)])
+def test_query_rows_are_the_largest_block_that_divides_the_chunk(t, want):
+    assert pa.query_rows(t) == want
+
+
+def _chunk_forward_setup():
+    cfg = LlamaConfig(dim=512, hidden_dim=512, n_layers=2, n_heads=4,
+                      n_kv_heads=2, vocab_size=64, seq_len=2 * BLOCK)
+    from distributed_llama_multiusers_tpu.models.loader import params_from_random
+
+    params = params_from_random(cfg, seed=0, dtype=jnp.bfloat16, scale=0.05)
+    cache = llama.init_kv_cache(cfg, 2, dtype=jnp.bfloat16)
+    rng = np.random.default_rng(1)
+    cache = llama.KVCache(*(
+        jnp.asarray(rng.standard_normal(c.shape) * 0.5, jnp.bfloat16) for c in cache))
+    t = 64
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, t)), jnp.int32)
+    positions = jnp.asarray([[BLOCK - 30], [0]], jnp.int32) + jnp.arange(t, dtype=jnp.int32)[None, :]
+    return cfg, params, tokens, positions, cache
+
+
+def test_forward_takes_the_prefill_kernel_only_where_its_inputs_allow(monkeypatch):
+    """`llama_forward` at a prefill bucket's rows with the kernel on (interpret
+    mode) agrees with the dense path on the real rows, appends the same rows in
+    the first layer, says nothing different with `n_valid`, and leaves a verify
+    step's rows, a float32 cache, a mesh's and the paged pool's to the dense
+    path."""
+    cfg, params, tokens, positions, cache = _chunk_forward_setup()
+    b, t = tokens.shape
+    fwd = lambda **kw: jax.jit(
+        lambda p, tok, pos, c: llama.llama_forward(cfg, p, tok, pos, c, **kw))
+    dense_logits, dense_cache = fwd()(params, tokens, positions, cache)
+    assert not llama.prefill_attention_engages(cache, None, b, t, cfg.n_heads)  # the CPU
+
+    calls = []
+    real = pa.prefill_attention
+    monkeypatch.setattr(pa, "prefill_attention",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    before = pa.TRACE_STATS["prefill_kernel_traces"]
+    linear.set_pallas_interpret(True)
+    try:
+        assert llama.prefill_attention_engages(cache, None, b, t, cfg.n_heads)
+        logits, new_cache = fwd()(params, tokens, positions, cache)
+        assert len(calls) == 1  # the scan's body, traced once
+        assert pa.TRACE_STATS["prefill_kernel_traces"] == before + 1
+        n_valid = jnp.asarray([t, 20], jnp.int32)
+        short_logits, _ = fwd(n_valid=n_valid)(params, tokens, positions, cache)
+        assert len(calls) == 2
+        # a verify step's rows, one row, a float32 cache, a mesh: not this kernel
+        assert not llama.prefill_attention_engages(cache, None, b, 5, cfg.n_heads)
+        assert not llama.prefill_attention_engages(cache, None, b, 1, cfg.n_heads)
+        assert not llama.prefill_attention_engages(cache, object(), b, t, cfg.n_heads)
+        cache32 = llama.init_kv_cache(cfg, 2, dtype=jnp.float32)
+        assert not llama.prefill_attention_engages(cache32, None, b, t, cfg.n_heads)
+        paged = llama.init_paged_kv_cache(cfg, 2, n_pages=8, page_size=64, dtype=jnp.bfloat16)
+        assert not llama.prefill_attention_engages(paged, None, b, t, cfg.n_heads)
+        llama.llama_forward(cfg, params, tokens[:, :5], positions[:, :5], cache)
+        assert len(calls) == 2
+    finally:
+        linear.set_pallas_interpret(False)
+    # layer 0's appends are made of the embeddings alone
+    for got, want in zip(new_cache, dense_cache):
+        np.testing.assert_array_equal(
+            np.asarray(got[0], np.float32), np.asarray(want[0], np.float32))
+    got, want = np.asarray(logits), np.asarray(dense_logits)
+    assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
+    short = np.asarray(short_logits)
+    assert np.abs(short[0] - want[0]).max() <= 2e-2 * np.abs(want).max()
+    assert np.abs(short[1, :20] - want[1, :20]).max() <= 2e-2 * np.abs(want).max()
+    assert np.isfinite(short).all()
