@@ -90,6 +90,11 @@ SYNC_METHODS = {"item", "tolist", "block_until_ready", "all_logits",
                 "lane_logits", "device_get"}
 SYNC_FUNCS = {"np.asarray", "numpy.asarray", "np.array", "numpy.array",
               "jax.device_get"}
+# declared for what it is: ``jax.Array.is_ready()`` polls the array's state,
+# neither blocks nor transfers, and is legal wherever a sync is not (the
+# scheduler's dry-dispatch witness, engine.pipeline_ready). Listed so that
+# nobody adds it to SYNC_METHODS by analogy with block_until_ready
+POLL_METHODS = {"is_ready"}
 CASTS = {"int", "float", "bool"}
 # compiled-step callables by convention: jit handles stored as *_fn/*_exec
 DEVICE_FN_RE = re.compile(r"(_fn|_exec)$")

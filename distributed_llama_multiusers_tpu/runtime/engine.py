@@ -297,6 +297,17 @@ class EngineStats:
     # so steady-state decode — churn included — reads 0
     pipeline_depth_hist: dict = field(default_factory=dict)  # ring depth
     # right after each dispatch -> count (how deep the overlap actually ran)
+    # the dry-dispatch witness, kept by the scheduler's loop: dispatches it
+    # made when the device had already finished everything in flight
+    # (pipeline_ready() just before the hand-over; a chain's first fill is
+    # not counted, nothing was running to run dry), and for those the host's
+    # seconds from the readback before to the dispatch's return: the most the
+    # device can have stood idle for want of work, on the host's clock
+    pipeline_dry_dispatches: int = 0
+    pipeline_dry_s: float = 0.0
+    # live lanes summed over the pipelined dispatches: over
+    # pipeline_dispatches x lanes, how full the decode batch ran
+    live_lane_steps: int = 0
     # stall-free admissions (decode_prefill_fused):
     fused_steps: int = 0  # fused prefill+decode dispatches (each advances
     # every generating lane one token AND consumes one prompt chunk)
@@ -461,6 +472,7 @@ class EngineStats:
             "spec_pipelined_steps", "spec_accept_hist", "host_exact_lanes",
             "overlap_s", "pipeline_dispatches", "pipeline_flushes",
             "pipeline_depth_hist",
+            "pipeline_dry_dispatches", "pipeline_dry_s", "live_lane_steps",
             "fused_steps", "admission_stall_s", "fused_bucket_hist",
             "sync_bytes_per_decode", "sync_collectives_per_decode",
             "sync_bytes_total", "worker_restarts", "worker_replay_errors",
@@ -508,6 +520,8 @@ class EngineStats:
             self.spec_accept_hist = {}
             self.pipeline_dispatches = self.pipeline_flushes = 0
             self.pipeline_depth_hist = {}
+            self.pipeline_dry_dispatches = self.live_lane_steps = 0
+            self.pipeline_dry_s = 0.0
             self.fused_steps = 0
             self.admission_stall_s = 0.0
             self.fused_bucket_hist = {}
@@ -2092,6 +2106,14 @@ class InferenceEngine:
     def pipeline_inflight(self) -> int:
         """Dispatched-but-unconsumed pipelined steps (ring occupancy)."""
         return len(self._pl_inflight)
+
+    def pipeline_ready(self) -> bool:
+        """Whether the device has finished every step in flight: the
+        youngest one's packed output is ready (steps run in dispatch order,
+        so the older ones are too). A poll of the array's state:
+        ``jax.Array.is_ready()`` neither blocks nor transfers. False on an
+        empty ring: nothing was running."""
+        return len(self._pl_inflight) > 0 and self._pl_inflight[-1][1].is_ready()
 
     @property
     def pipeline_active(self) -> bool:

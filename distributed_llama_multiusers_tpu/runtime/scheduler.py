@@ -54,13 +54,15 @@ from ..lockcheck import make_lock
 from ..ops.block_sparse import SparseSizes, blocks_attended
 from ..ops.pallas_attention import ring_rows_read, rows_read
 from ..serving.watchdog import deadline_from_env
-from ..telemetry import Telemetry
+from ..telemetry import StepRecord, Telemetry
 from ..telemetry.names import (
     LOOP_ADMIT,
     LOOP_DISPATCH,
     LOOP_STREAM,
     LOOP_TRACK,
     LOOP_WAIT,
+    pipelined_step_class,
+    step_class,
 )
 from ..tokenizer import EosDetector, EosResult, Sampler, Tokenizer, TokenizerChatStops
 from ..utils import faults
@@ -451,6 +453,10 @@ class ContinuousBatchingScheduler:
         # sequence number of the pipelined dispatches: the `step` every
         # loop.* span and step slice of one dispatch carries
         self._step_seq = 0
+        # when the chain's last readback returned (perf_counter; None until
+        # a chain has been read back once): where a step's interval and a
+        # dry dispatch's idle bound start
+        self._readback_at: float | None = None
         # queue-wait histogram source: the queue's own pop-time measurement
         # when it offers one (reconciles with queue_popped exactly), else
         # observed at lane-claim time
@@ -1413,7 +1419,8 @@ class ContinuousBatchingScheduler:
         self.breaker.record_success()
         self.telemetry.on_prefill_chunk(
             req, lane_idx, t_chunk, len(chunk),
-            bucket=self.engine.bucket_for(len(chunk)))
+            bucket=self.engine.bucket_for(len(chunk)),
+            p_start=lane.pos, final=len(chunk) == len(lane.pending))
         lane.pos += len(chunk)
         lane.pending = lane.pending[len(chunk):]
         self._lane_kv[lane_idx].extend(chunk)  # committed: prefix-cacheable
@@ -1810,8 +1817,9 @@ class ContinuousBatchingScheduler:
         step's packed token readback and run the host work the synchronous
         loop does inline — stream decode, EOS/stop, cancel/budget checks —
         while the younger dispatches keep the device busy. ``entry`` is
-        ``(step_lanes, fused, t_dispatch, spec_drafted, step)`` recorded AT
-        DISPATCH TIME: ``step_lanes`` pairs each live lane index with its
+        ``(step_lanes, fused, t_dispatch, spec_drafted, step, dry_s,
+        p_start)`` recorded AT DISPATCH TIME: ``step_lanes`` pairs each
+        live lane index with its
         lane OBJECT — the identity check skips both lanes that finished at
         an earlier consumed step AND lanes already reclaimed by a NEW
         request while this step was still in flight (either way the
@@ -1830,43 +1838,68 @@ class ContinuousBatchingScheduler:
         only when the lane actually fed tokens: a lane cancelled mid-draft
         must not count a lane-step with zero emitted, which would push the
         /stats acceptance ratio below its [1, K+1] class). ``t_dispatch``
-        is the step's dispatch stamp: the telemetry slice spans dispatch
-        -> this lagged readback, recorded HERE (the consume half) so the
-        dispatch half stays span-free (dlint pipeline-sync); ``step`` is
-        the dispatch's sequence number, which this half's ``loop.wait`` and
-        ``loop.stream`` spans and the step slice carry."""
-        step_lanes, fused, t_dispatch, spec_drafted, step = entry
-        span_args = {"step": step}
+        is the step's dispatch stamp (its ``prefill.fused`` slice starts
+        there); ``step`` is the dispatch's sequence number, which this
+        half's ``loop.wait`` and ``loop.stream`` spans and the step slice
+        carry; ``dry_s`` is the dispatch half's witness (None: the device
+        still had work when the step was handed over) and ``p_start`` where
+        the chunk began. The step's RECORD (``StepRecord``) is made HERE,
+        when the readback returns, so the dispatch half stays span-free
+        (dlint pipeline-sync): its interval runs from the readback before
+        (a chain's first: from its own dispatch) to this one."""
+        step_lanes, fused, t_dispatch, spec_drafted, step, dry_s, p_start = entry
         wd = self.watchdog
         if wd is not None:
             wd.begin_step()
+        t_wait = time.perf_counter()
         try:
             # loop.wait: the lagged readback alone — the one span of the
             # loop in which the host has nothing to do but wait
-            with self.telemetry.span(LOOP_WAIT, LOOP_TRACK, args=span_args):
+            with self.telemetry.span(LOOP_WAIT, LOOP_TRACK,
+                                     args={"step": step}):
                 out_a, out_b = self.engine.pipeline_consume()
         finally:
             if wd is not None:
                 wd.step_done()
-        # loop.stream: everything the host does with the step's tokens
-        with self.telemetry.span(LOOP_STREAM, LOOP_TRACK, args=span_args):
+        t_done = time.perf_counter()
+        since = t_dispatch if self._readback_at is None else self._readback_at
+        self._readback_at = t_done
+        bucket = None if fused is None else self.engine.bucket_for(fused[3])
+        record = StepRecord(
+            step=step,
+            cls=pipelined_step_class(spec_drafted is not None, bucket),
+            chunk=0 if fused is None else fused[3],
+            p_start=p_start,
+            final=fused is not None and fused[2],
+            lanes=len(step_lanes),
+            dry=dry_s is not None,
+            dry_s=dry_s or 0.0,
+            interval_s=t_done - since,
+            wait_s=t_done - t_wait,
+            host_s=t_wait - since,
+            at=time.monotonic(),
+        )
+        # loop.stream: everything the host does with the step's tokens; the
+        # span (and so the profiler's dl.loop.stream) carries the record
+        with self.telemetry.span(LOOP_STREAM, LOOP_TRACK, args=record.args()):
             self._pipeline_stream(
-                live, step_lanes, fused, t_dispatch, spec_drafted, step,
-                out_a, out_b,
+                live, step_lanes, fused, t_dispatch, spec_drafted, record,
+                bucket, t_done, out_a, out_b,
             )
 
     def _pipeline_stream(self, live: dict, step_lanes, fused, t_dispatch,
-                         spec_drafted, step: int, out_a, out_b) -> None:
+                         spec_drafted, record: StepRecord, bucket,
+                         t_done: float, out_a, out_b) -> None:
         """The host half of one consumed step (``_pipeline_consume``'s
         contract): per-lane ``_consume`` with detokenize/``on_delta``,
         finishes, and the fused boundary token."""
         self.breaker.record_success()
-        now = time.monotonic()
+        now = record.at
         is_spec = spec_drafted is not None
         self.telemetry.on_pipelined_step(
             t_dispatch, fused,
-            kind="spec_pipelined" if is_spec else "pipelined", step=step,
-            bucket=None if fused is None else self.engine.bucket_for(fused[3]),
+            kind="spec_pipelined" if is_spec else "pipelined",
+            bucket=bucket, record=record, t_done=t_done,
         )
         if is_spec:
             emitted, n_emit = out_a, out_b
@@ -2064,10 +2097,12 @@ class ContinuousBatchingScheduler:
         for i, lane in live.items():
             feed[i] = lane.next_token
         # (live lanes, fused info, dispatch stamp, spec-drafted set, step
-        # number) per dispatch — positions no longer tracked host-side:
+        # number, dry seconds or None, the chunk's start) per dispatch —
+        # positions no longer tracked host-side:
         # they ride the device carry (spec accept counts are only known
         # one step behind)
         meta: deque = deque()
+        self._readback_at = None  # a new chain: no readback of its own yet
         host_feed = True  # first dispatch reseeds the chain from host tokens
         dispatched_any = False
         # both entry gates (_run's early fused entry and the post-spec
@@ -2103,19 +2138,35 @@ class ContinuousBatchingScheduler:
                 )
                 self._step_seq += 1
                 step = self._step_seq
+                # the dry-dispatch witness: this chain has been read back
+                # (its first fill had nothing running to run dry) and the
+                # device has already finished all that is in flight, so it
+                # stands idle until this step reaches it. A poll, asked
+                # here and not inside the dispatch half
+                dry = self._readback_at is not None and engine.pipeline_ready()
                 # the span wraps the CALL; nothing is recorded inside the
                 # dispatch half (dlint pipeline-sync)
-                with tel.span(LOOP_DISPATCH, LOOP_TRACK, args={"step": step}):
+                with tel.span(LOOP_DISPATCH, LOOP_TRACK,
+                              args={"step": step, "dry": int(dry)}):
                     fused_info, spec_drafted = self._pipeline_dispatch(
                         live, admitting, feed if host_feed else None, spec_ok
                     )
+                dry_s = time.perf_counter() - self._readback_at if dry else None
+                with engine.stats.lock:
+                    engine.stats.live_lane_steps += len(live)
+                    if dry:
+                        engine.stats.pipeline_dry_dispatches += 1
+                        engine.stats.pipeline_dry_s += dry_s
                 if spec_drafted is None:
                     self._count_attention_rows(self._device_rows(live, meta))
                 host_feed = False
                 dispatched_any = True
-                meta.append(
-                    (tuple(live.items()), fused_info, t_d, spec_drafted, step)
-                )
+                # the chunk's bookkeeping committed at dispatch: its lane
+                # stands at the chunk's end
+                p_start = (0 if fused_info is None
+                           else fused_info[1].pos - fused_info[3])
+                meta.append((tuple(live.items()), fused_info, t_d,
+                             spec_drafted, step, dry_s, p_start))
                 if fused_info is not None:
                     i, lane, final, _ = fused_info
                     tel.on_prefill_dispatch(lane.request, t_mono)
@@ -2507,10 +2558,16 @@ class ContinuousBatchingScheduler:
                         want_logits=host_exact_active,
                         g_states=g_states,
                     )
+                if draft_len is not None:
+                    kind, cls = "spec", step_class("spec")
+                elif h > 1:
+                    kind, cls = "multi", step_class("decode_multi", h)
+                else:
+                    kind, cls = "sync", step_class(
+                        "decode_sync" if host_exact_active
+                        else "decode_sync_nologits")
                 self.telemetry.on_step(
-                    "spec" if draft_len is not None
-                    else ("multi" if h > 1 else "sync"),
-                    t_step, args={"h": h} if h > 1 else None,
+                    kind, t_step, args={"h": h} if h > 1 else None, cls=cls,
                 )
                 # host-exact lanes (host_sampling=True only — the
                 # bit-exact reference-xorshift escape hatch; the device

@@ -392,6 +392,14 @@ class ApiServer:
                 str(k): v
                 for k, v in sorted(stats["pipeline_depth_hist"].items())
             },
+            # the dry-dispatch witness (runtime/scheduler.py): dispatches
+            # made when the device had already finished everything in
+            # flight, the most it can have stood idle for them (host
+            # clock), and live lanes summed over the pipelined dispatches
+            # (over pipeline_dispatches x lanes: how full the batch ran)
+            "pipeline_dry_dispatches": stats["pipeline_dry_dispatches"],
+            "pipeline_dry_s": round(stats["pipeline_dry_s"], 6),
+            "live_lane_steps": stats["live_lane_steps"],
             # stall-free admissions: fused prefill+decode dispatches taken
             # (admissions riding the live chain), host time decode lanes
             # spent stalled behind admission work, and which prefill

@@ -40,7 +40,7 @@ from .metrics import (
     MetricsRegistry,
     log_buckets,
 )
-from .spans import RequestTrace, SpanEvent, SpanTracer
+from .spans import RequestTrace, SpanEvent, SpanTracer, StepRecord
 from .trace import (
     chrome_trace,
     dump_chrome_trace,
@@ -68,6 +68,7 @@ __all__ = [
     "RequestTrace",
     "SpanEvent",
     "SpanTracer",
+    "StepRecord",
     "TRACE_HEADER",
     "Telemetry",
     "TraceContext",

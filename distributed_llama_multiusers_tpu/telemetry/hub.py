@@ -8,16 +8,18 @@ logger (logs.py), and exposes the lifecycle hooks the scheduler calls:
     admit  -> on_admit           (queued slice, queue-wait histogram)
     chunk  -> on_prefill_chunk   (lane slice, step-duration histogram)
     token  -> on_token           (TTFT on first, inter-token gaps after)
-    step   -> on_step / on_pipelined_step  (pipeline-track slices)
+    step   -> on_step / on_pipelined_step  (pipeline-track slices; a
+              pipelined step's carries its StepRecord)
     loop   -> span(name, track)  (a slice AND a profiler annotation: the
               batching loop's own work, on the ring and on the device's clock)
     end    -> on_finish / on_unadmitted / on_error  (summary, counters,
               one JSON log line, finish instant)
 
 Design constraint, inherited from the async pipeline: NO hook runs
-inside the pipelined dispatch half. Dispatch→consume step slices are
-recorded by ``on_pipelined_step`` from the scheduler's consume half, one
-step behind, where the host is already blocking on the lagged readback —
+inside the pipelined dispatch half. A step's slice (the interval between
+two readbacks) is recorded by ``on_pipelined_step`` from the scheduler's
+consume half, one step behind, where the host has just blocked on the
+lagged readback —
 dlint's ``pipeline-sync`` check stays green because the dispatch half
 never calls in here.
 
@@ -33,8 +35,8 @@ import time
 
 from .logs import JsonLogger, default_logger
 from .metrics import LATENCY_BUCKETS_S, MetricsRegistry
-from .names import ANNOTATION_PREFIX
-from .spans import RequestTrace, SpanTracer
+from .names import ANNOTATION_PREFIX, step_class
+from .spans import RequestTrace, SpanTracer, StepRecord
 from .trace import dump_chrome_trace, tracer_chrome_trace
 from .tracectx import trace_id_of
 
@@ -44,8 +46,11 @@ STATS_PREFIX = "dllama_stats_"
 class _Span:
     """One open ``Telemetry.span``: the annotation (if a factory is set)
     is held open from enter to exit, and the ring slice is appended on
-    exit, as ``SpanTracer.slice`` would. Always on: with no profiler
-    session a ``jax.profiler.TraceAnnotation`` is one atomic load."""
+    exit, as ``SpanTracer.slice`` would. The annotation is handed the
+    span's args as keywords, so the profiler's copy of ``dl.loop.*`` says
+    the ``step`` the ring's does. Always on: with no profiler session a
+    ``jax.profiler.TraceAnnotation`` is one atomic load, and encodes
+    nothing."""
 
     __slots__ = ("_tel", "_name", "_track", "_req_id", "_args", "_t0", "_ann")
 
@@ -56,7 +61,8 @@ class _Span:
     def __enter__(self):
         factory = self._tel.annotation_factory
         self._ann = ann = (
-            None if factory is None else factory(ANNOTATION_PREFIX + self._name)
+            None if factory is None
+            else factory(ANNOTATION_PREFIX + self._name, **(self._args or {}))
         )
         self._t0 = time.perf_counter()
         if ann is not None:
@@ -91,7 +97,7 @@ class Telemetry:
         # (ApiServer stamps it when the scheduler built its own hub).
         self.replica = replica
         # what holds a span open on the PROFILER's clock: a callable
-        # ``name -> context manager``, injected by the scheduler
+        # ``(name, **args) -> context manager``, injected by the scheduler
         # (``jax.profiler.TraceAnnotation``) so this package stays free of
         # jax. None: spans are ring-only.
         self.annotation_factory = None
@@ -112,10 +118,12 @@ class Telemetry:
             "cancelled/expired without claiming a lane included)",
             LATENCY_BUCKETS_S,
         )
-        self.step_duration = reg.histogram(
+        self.step_duration = reg.labelled_histogram(
             "dllama_step_duration_seconds",
-            "one engine dispatch: prefill chunk, decode step (sync/spec/"
-            "multi horizon), or pipelined dispatch->lagged-consume span",
+            "one engine step by the class of its program (dlstep.*): a "
+            "pipelined step's interval between two lagged readbacks (what "
+            "every live lane waited for its token); a synchronous prefill "
+            "chunk or decode step (sync/spec/multi horizon) start to end",
             LATENCY_BUCKETS_S,
         )
         self.requests_finished = reg.counter(
@@ -125,11 +133,6 @@ class Telemetry:
         )
         self.tokens_generated = reg.counter(
             "dllama_tokens_generated_total", "tokens consumed across lanes"
-        )
-        self.overlap_fraction = reg.gauge(
-            "dllama_overlap_fraction",
-            "overlap_s / (overlap_s + decode_s): fraction of engine decode "
-            "wall-time the async pipeline hid behind device execution",
         )
         # pod-serving sync cost next to TTFT/TBT: the estimated collective
         # payload accrued per decode-family dispatch (reconciles with the
@@ -325,9 +328,14 @@ class Telemetry:
 
     def on_prefill_chunk(self, req, lane: int, t0: float, n_tokens: int,
                          fused: bool = False, step: int | None = None,
-                         bucket: int | None = None) -> None:
+                         bucket: int | None = None, p_start: int = 0,
+                         final: bool = False) -> None:
         """``bucket`` is the prefill bucket the chunk rode
-        (``engine.bucket_for(n_tokens)``): the rows the program computed."""
+        (``engine.bucket_for(n_tokens)``): the rows the program computed.
+        A chunk dispatched alone (not ``fused``) is timed start to end and
+        leaves its own row on the request's ``chunks`` (nothing overlapped
+        it, so its interval is its wait); a fused chunk's row is its step's
+        record, which ``on_pipelined_step`` appends."""
         now_pc = self.tracer.now()
         extra = {"tokens": n_tokens}
         if bucket is not None:
@@ -340,9 +348,14 @@ class Telemetry:
             args=self.span_args(req, extra),
         )
         if not fused:
-            # fused chunks ride a pipelined dispatch that on_pipelined_step
-            # already times; observing both would double-count the span
-            self.step_duration.observe(max(0.0, now_pc - t0))
+            took = max(0.0, now_pc - t0)
+            cls = step_class("prefill", bucket)
+            self.step_duration.observe(took, **{"class": cls})
+            self.trace_of(req).chunks.append(StepRecord(
+                step=0, cls=cls, chunk=n_tokens, p_start=p_start, final=final,
+                lanes=0, dry=False, dry_s=0.0, interval_s=took, wait_s=took,
+                host_s=0.0, at=time.monotonic(),
+            ))
 
     def on_token(self, req, now: float | None = None) -> None:
         """One consumed token (``now`` = time.monotonic()). First token
@@ -361,35 +374,45 @@ class Telemetry:
 
     # -- step hooks ----------------------------------------------------------
 
-    def on_step(self, kind: str, t0: float, args: dict | None = None) -> None:
-        """One synchronous engine dispatch (kind: sync/spec/multi)."""
+    def on_step(self, kind: str, t0: float, args: dict | None = None,
+                *, cls: str) -> None:
+        """One synchronous engine dispatch (kind: sync/spec/multi), timed
+        start to end; ``cls`` is the class of the program it ran."""
         now_pc = self.tracer.now()
         self.tracer.slice(f"step.{kind}", "pipeline", t0, now_pc,
                           args=self.span_args(extra=args))
-        self.step_duration.observe(max(0.0, now_pc - t0))
+        self.step_duration.observe(max(0.0, now_pc - t0),
+                                   **{"class": cls})
 
     def on_pipelined_step(self, t_dispatch: float, fused_info=None,
                           kind: str = "pipelined",
-                          step: int | None = None,
-                          bucket: int | None = None) -> None:
-        """One pipelined step, recorded at CONSUME time (one step behind):
-        the slice spans dispatch -> lagged readback completion. ``kind``
-        distinguishes the in-chain spec verify steps
-        (``"spec_pipelined"`` — the zero-flush speculation path) from
-        plain pipelined decodes on the trace. For a fused prefill+decode
-        step, ``fused_info`` is the scheduler's
-        ``(lane_idx, lane, final, n_chunk)`` and the admitting lane also
-        gets a ``prefill.fused`` slice on its own track. ``step`` is the
-        dispatch's sequence number, the one its ``loop.*`` spans carry;
+                          bucket: int | None = None, *,
+                          record: StepRecord,
+                          t_done: float) -> None:
+        """One pipelined step, recorded at CONSUME time (one step behind).
+        The slice spans the step's INTERVAL, from the readback before it to
+        its own (``t_done``, on the tracer's clock): the slices of a chain
+        tile the ``pipeline`` track, each as long as the lanes waited for
+        that step's token, and its args are the loop's ``record`` of the
+        step (``StepRecord.args``: class, lanes, dry, interval / wait /
+        host seconds, the chunk's tokens, start and whether it was its
+        prompt's last). ``kind`` distinguishes the in-chain spec verify
+        steps (``"spec_pipelined"`` — the zero-flush speculation path)
+        from plain pipelined decodes on the trace. For a fused
+        prefill+decode step, ``fused_info`` is the scheduler's
+        ``(lane_idx, lane, final, n_chunk)``; the admitting lane also gets
+        a ``prefill.fused`` slice on its own track (dispatch -> readback)
+        and the record joins its request's ``chunks``. ``record.step`` is
+        the dispatch's sequence number, the one its ``loop.*`` spans carry;
         ``bucket`` the prefill bucket a fused step's chunk rode, which is
         the class of step program the lanes waited through."""
-        now_pc = self.tracer.now()
-        step_args = {} if step is None else {"step": step}
+        step_args = record.args()
+        t0 = t_done - record.interval_s
         if fused_info is None:
-            self.tracer.slice(f"step.{kind}", "pipeline", t_dispatch,
-                              now_pc, args=self.span_args(extra=step_args))
+            self.tracer.slice(f"step.{kind}", "pipeline", t0,
+                              t_done, args=self.span_args(extra=step_args))
         else:
-            lane_idx, lane, final, n_chunk = fused_info
+            lane_idx, lane, _final, n_chunk = fused_info
             if bucket is not None:
                 step_args["bucket"] = bucket
             req = lane.request
@@ -400,15 +423,15 @@ class Telemetry:
             # fused slices
             name = "step.fused" if kind == "pipelined" else "step.spec_fused"
             self.tracer.slice(
-                name, "pipeline", t_dispatch, now_pc, req_id=req_id,
-                args=self.span_args(
-                    req, {"chunk": n_chunk, "final": final, **step_args}
-                ),
+                name, "pipeline", t0, t_done, req_id=req_id,
+                args=self.span_args(req, step_args),
             )
             if req is not None:
                 self.on_prefill_chunk(req, lane_idx, t_dispatch, n_chunk,
-                                      fused=True, step=step, bucket=bucket)
-        self.step_duration.observe(max(0.0, now_pc - t_dispatch))
+                                      fused=True, step=record.step,
+                                      bucket=bucket)
+                self.trace_of(req).chunks.append(record)
+        self.step_duration.observe(record.interval_s, **{"class": record.cls})
 
     def on_flush(self, live: int, admitting: int) -> None:
         self.tracer.instant(
@@ -510,9 +533,9 @@ class Telemetry:
 
     def bridge_stats(self, stats: dict) -> None:
         """Republish a ``/stats`` payload as ``dllama_stats_*`` gauges
-        (dict-valued histogram counters become labelled gauges), plus the
-        derived overlap-fraction gauge. Values land verbatim, so a scrape
-        reconciles with the JSON endpoint field-for-field."""
+        (dict-valued histogram counters become labelled gauges). Values
+        land verbatim, so a scrape reconciles with the JSON endpoint
+        field-for-field."""
         reg = self.registry
         for key, value in stats.items():
             if value is None:
@@ -527,10 +550,6 @@ class Telemetry:
                 for k, v in value.items():
                     if isinstance(v, (int, float)):
                         g.set(float(v), key=str(k))
-        overlap = float(stats.get("overlap_s") or 0.0)
-        decode = float(stats.get("decode_s") or 0.0)
-        if overlap + decode > 0:
-            self.overlap_fraction.set(overlap / (overlap + decode))
         # the native sync-bytes counter tracks the same accounting the
         # dllama_stats_sync_bytes_total gauge republishes, delta-fed so it
         # keeps Prometheus counter semantics across engine.stats.reset()

@@ -162,6 +162,17 @@ def step_class(family: str, width: int | None = None) -> str:
     return f"{STEP_PREFIX}{family}" if width is None else f"{STEP_PREFIX}{family}.b{int(width)}"
 
 
+def pipelined_step_class(spec: bool, bucket: int | None = None) -> str:
+    """The class of the program a step of the pipelined loop dispatched, from
+    what the host holds: whether it shipped drafts to verify (``spec``) and
+    the prefill bucket its prompt chunk rode (None: no chunk). The SAME
+    string the device program wraps its body in, so a host record and a
+    device execution of one step carry one name."""
+    if bucket is None:
+        return step_class("spec_pl" if spec else "decode")
+    return step_class("spec_fused" if spec else "fused", bucket)
+
+
 def step_class_of(op_name: str) -> str | None:
     """The ``dlstep.*`` class in an ``op_name`` (the outermost, should a step
     program ever be traced inside another); None where there is none."""

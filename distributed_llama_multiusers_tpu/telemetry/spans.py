@@ -31,6 +31,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass
+from typing import NamedTuple
 
 from ..lockcheck import make_lock
 from .tracectx import trace_id_of
@@ -42,8 +43,9 @@ from .tracectx import trace_id_of
 #   prefill.sync    X  one synchronous prompt chunk on a lane
 #   prefill.fused   X  one fused-dispatch prompt chunk on a lane
 #   step.sync/spec/multi  X  one synchronous engine dispatch
-#   step.pipelined  X  pipelined step, dispatch -> lagged consume
-#   step.fused      X  fused prefill+decode step, dispatch -> lagged consume
+#   step.pipelined  X  pipelined step: the interval between two readbacks
+#   step.fused      X  fused prefill+decode step: the same interval
+#                      (both carry the step's record, StepRecord.args)
 #   loop.admit/dispatch/wait/stream  X  the four parts of one iteration of
 #                      the pipelined batching loop (names.py), track "loop"
 #   submitted / finish.<reason> / pipeline.flush   i  instants
@@ -164,6 +166,64 @@ class SpanTracer:
             }
 
 
+class StepRecord(NamedTuple):
+    """What the batching loop knows of one step when its readback returns,
+    made once (``runtime/scheduler.py`` ``_pipeline_consume``) and kept where
+    a reader can reach it: the args of the step's ``step.*`` ring slice and
+    of its ``dl.loop.stream`` annotation (``args()``), the admitting
+    request's ``RequestTrace.chunks`` when the step carried a prompt chunk,
+    and ``dllama_step_duration_seconds{class=...}``. A synchronous prompt
+    chunk leaves one too (``lanes`` 0, ``host_s`` 0: nothing overlapped).
+
+    ``cls`` is the class the device program wraps its body in
+    (``names.pipelined_step_class``): one name on both clocks. ``dry``: the
+    device had run everything it was given when this step was handed over;
+    ``dry_s`` is the host's time from the readback before to the dispatch's
+    return, the most it can have idled SINCE that readback (a device that
+    finished while the readback was still blocking idled longer: that
+    step's ``wait_s`` shows it). ``interval_s`` runs from the readback before
+    this one to this one (what every live lane waited for its token),
+    ``wait_s`` is the part inside ``engine.pipeline_consume`` and ``host_s``
+    the rest: the admit, dispatch and stream of that turn of the loop.
+    ``at`` is ``time.monotonic()`` at the readback's return, the clock of
+    every other stamp of a ``RequestTrace``."""
+
+    step: int
+    cls: str
+    chunk: int      # prompt tokens the step carried (0: none)
+    p_start: int    # where the chunk began in its prompt (0 without one)
+    final: bool     # the chunk was its prompt's last
+    lanes: int      # live lanes the decode half carried
+    dry: bool
+    dry_s: float
+    interval_s: float
+    wait_s: float
+    host_s: float
+    at: float
+
+    def args(self) -> dict:
+        """The record as span args: plain ints, floats and strings, which a
+        ring slice keeps and a profiler annotation encodes (a step without a
+        chunk says nothing of one)."""
+        out = {"step": self.step, "class": self.cls, "lanes": self.lanes,
+               "dry": int(self.dry), "interval_s": self.interval_s,
+               "wait_s": self.wait_s, "host_s": self.host_s}
+        if self.dry:
+            out["dry_s"] = self.dry_s
+        if self.chunk:
+            out.update(chunk=self.chunk, p_start=self.p_start,
+                       final=int(self.final))
+        return out
+
+    def brief(self) -> dict:
+        """One row of a completion's ``summary["chunks"]``."""
+        ms = lambda v: round(v * 1e3, 3)
+        return {"class": self.cls, "tokens": self.chunk,
+                "p_start": self.p_start, "interval_ms": ms(self.interval_s),
+                "wait_ms": ms(self.wait_s), "host_ms": ms(self.host_s),
+                "dry": self.dry}
+
+
 def _ttft_parts(submitted, admitted, first_dispatch, prefill_done,
                 first_token) -> tuple:
     """(queue wait, dispatch wait, prefill, first-token hold) in seconds,
@@ -192,7 +252,7 @@ class RequestTrace:
     __slots__ = (
         "submitted_at", "admitted_at", "first_dispatch_at",
         "prefill_done_at", "first_token_at", "last_token_at", "gaps", "n_tokens", "fused_admitted", "prefix_saved",
-        "span_t0", "lane", "swap_in_s",
+        "span_t0", "lane", "swap_in_s", "chunks",
     )
 
     def __init__(self, submitted_at: float | None = None):
@@ -207,6 +267,9 @@ class RequestTrace:
         # the NEXT consumed step emits as the first token
         self.first_dispatch_at: float | None = None
         self.prefill_done_at: float | None = None
+        # one StepRecord a prompt chunk, in dispatch order: why prefill_ms
+        # was what it was (and the benchmark's view of every fused step)
+        self.chunks: list[StepRecord] = []
         self.first_token_at: float | None = None
         self.last_token_at: float | None = None
         self.gaps: list[float] = []  # inter-token gaps, seconds
@@ -307,6 +370,7 @@ class RequestTrace:
             "prefix_tokens_saved": self.prefix_saved,
             "fused_admitted": self.fused_admitted,
             "phases": self.phases(),
+            "chunks": [c.brief() for c in self.chunks],
         }
         # requests carry the wire-form context ("<trace>-<span>", the
         # X-DLlama-Trace value); the summary surfaces just the trace id,

@@ -6,7 +6,9 @@ parts every viewer (chrome://tracing, ui.perfetto.dev) honours:
 
 - one process (pid 1, named for the model/server),
 - one *thread* per logical track — ``lane0..laneN`` (requests pinned to
-  their KV lane), ``pipeline`` (per-dispatch step slices), ``queue``
+  their KV lane), ``pipeline`` (one slice a step: a pipelined step's spans
+  its interval between two readbacks, so a chain's slices tile the row,
+  and holds the loop's record of the step in its args), ``queue``
   (submit→admit waits) — named via ``M``/``thread_name`` metadata and
   ordered via ``thread_sort_index``,
 - ``X`` complete events (``ts``+``dur`` in µs) for spans,

@@ -194,6 +194,9 @@ class MockAsyncEngine:
         self._content_keyed = content_keyed
         self._lane_key = np.zeros(n_lanes, np.int64)
         self._free_at = 0.0  # simulated device busy-until timestamp
+        # None: pipeline_ready() goes by the simulated clock; True / False:
+        # the answer a test wants (the dry-dispatch witness, step by step)
+        self.ready_override = None
         # (ready_at, dispatched_at, step_idx, kind, payload): payload is
         # (toks, boundary|None) for "tok" steps, (emitted, n_emit) for
         # "spec" steps — computed AT DISPATCH (the sim is deterministic),
@@ -646,6 +649,16 @@ class MockAsyncEngine:
 
     def pipeline_inflight(self):
         return len(self._ring)
+
+    def pipeline_ready(self):
+        """The real engine's poll: has the device finished everything in
+        flight? The simulated clock answers; a test that wants the device
+        dry (or busy) whatever the clock says sets ``ready_override``."""
+        if not self._ring:
+            return False
+        if self.ready_override is not None:
+            return self.ready_override
+        return time.monotonic() >= self._ring[-1][0]
 
     @property
     def pipeline_active(self):

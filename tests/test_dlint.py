@@ -488,6 +488,32 @@ def test_pipeline_sync_clean_dispatch_half(tmp_path):
     assert findings == []
 
 
+def test_a_poll_of_readiness_is_no_sync(tmp_path):
+    """``jax.Array.is_ready()`` neither blocks nor transfers: the engine's
+    ``pipeline_ready`` and the loop that asks it carry no waiver, under
+    host-sync and pipeline-sync alike; ``block_until_ready`` in the same
+    places is a finding. The poll is DECLARED (``POLL_METHODS``), so that it
+    is not added to the sync list by analogy."""
+    import distributed_llama_multiusers_tpu.analysis.host_sync_check as hs
+    import distributed_llama_multiusers_tpu.analysis.pipeline_check as pc
+
+    assert hs.POLL_METHODS == {"is_ready"}
+    assert not hs.POLL_METHODS & (hs.SYNC_METHODS | pc.SYNC_METHODS)
+    src = """
+        class Engine:
+            def pipeline_ready(self):
+                return len(self._pl_inflight) > 0 and self._pl_inflight[-1][1].POLL()
+
+            def decode_pipelined(self, positions):
+                idle = self._pl_inflight[-1][1].POLL()
+                self._pl_inflight.append(self._decode_pl_fn(positions))
+    """
+    assert run_on(tmp_path / "poll", {"runtime/engine.py": src.replace("POLL", "is_ready")}) == []
+    blocking = run_on(tmp_path / "block",
+                      {"runtime/engine.py": src.replace("POLL", "block_until_ready")})
+    assert sorted(checks_of(blocking)) == ["host-sync", "host-sync", "pipeline-sync"]
+
+
 def test_pipeline_sync_implicit_bool_and_cast(tmp_path):
     findings = run_on(tmp_path, {"runtime/engine.py": """
         class E:

@@ -139,11 +139,12 @@ def test_scope_of_takes_the_deepest_component():
 
 
 class FakeAnnotation:
-    """Stands in for jax.profiler.TraceAnnotation: logs its open and close."""
+    """Stands in for jax.profiler.TraceAnnotation: logs its open and close
+    (the keywords a span hands it are tests/test_step_record.py's)."""
 
     log: list = []
 
-    def __init__(self, name):
+    def __init__(self, name, **_kwargs):
         self.name = name
 
     def __enter__(self):
@@ -391,10 +392,9 @@ class StepStamps(Telemetry):
         self.prefill_done_step = {}
         self.first_token_step = {}
 
-    def on_pipelined_step(self, t_dispatch, fused_info=None, kind="pipelined",
-                          step=None, **kw):
-        self.consuming = step
-        super().on_pipelined_step(t_dispatch, fused_info, kind=kind, step=step, **kw)
+    def on_pipelined_step(self, *args, record, **kw):
+        self.consuming = record.step
+        super().on_pipelined_step(*args, record=record, **kw)
 
     def on_prefill_done(self, req, now):
         self.prefill_done_step[req.id] = self.consuming
