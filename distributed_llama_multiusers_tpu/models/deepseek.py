@@ -101,6 +101,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..formats.model_file import HiddenAct, MoeScore
 from ..ops.activations import gelu, silu
 from ..ops.linear import (
+    head,
     matmul,
     pallas_interpret,
     pallas_kernel_active,
@@ -124,7 +125,6 @@ from ..telemetry.names import (
     SCOPE_EMBED,
     SCOPE_EXPERTS,
     SCOPE_FFN,
-    SCOPE_HEAD,
     SCOPE_INDEXER,
     SCOPE_KV_LATENT,
     SCOPE_KV_WRITE,
@@ -609,8 +609,11 @@ def deepseek_forward_counted(
     emulate_q80_activations: bool = False,
     mesh=None,
     q80_sync: bool = False,
+    head_row: jnp.ndarray | None = None,  # [B] int32: the one row a lane whose logits are kept
 ):
-    """(logits ``[B, T, vocab]`` f32, updated cache, counts). ``counts`` is a
+    """(logits ``[B, T, vocab]`` f32, updated cache, counts); with
+    ``head_row``, logits ``[B, 1, vocab]``: each lane's row at that index
+    alone, as in ``llama_forward``. ``counts`` is a
     tuple of int32 scalars summed over the layers, named by
     ``count_names(config)``: ``ROUTED_COUNTS`` of the routed layers (distinct
     (layer, expert) slabs one expert matrix read, (row, expert) pairs that
@@ -752,10 +755,10 @@ def deepseek_forward_counted(
         elif sparse:
             counts = seen
 
-    with jax.named_scope(SCOPE_HEAD):
-        y = rms_norm(x, params.rms_final, eps)
-        logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)
-        logits = logits[..., : cfg.vocab_size]
+    logits = head(
+        x, lambda x: rms_norm(x, params.rms_final, eps), params.wcls, cfg.vocab_size,
+        head_row=head_row, qdq=maybe_qdq,
+    )
     return logits, type(cache)(*leaves), counts
 
 
@@ -789,7 +792,8 @@ def forward_counted(config: LlamaConfig):
     (logits, cache, counts)``. What the configuration is decides it; a Llama
     block counts nothing (None). A block with a recurrent state
     (models/hybrid.py) also takes ``n_valid``: how many leading rows of each
-    lane are real."""
+    lane are real. Every block takes ``head_row`` (``ops.linear.head``): the
+    one row a lane whose logits its caller keeps, ``[B, 1, vocab]`` then."""
     if config.layer_kinds:
         from .hybrid import hybrid_forward_counted
 

@@ -142,7 +142,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..formats.model_file import LayerKind, NormKind
 from ..ops import block_sparse, blocked_attention, pallas_attention
-from ..ops.linear import matmul, pallas_interpret, pallas_kernel_active
+from ..ops.linear import head, matmul, pallas_interpret, pallas_kernel_active
 from ..ops.linear_attention import decay_slopes, linear_attention
 from ..ops.norm import layer_norm, rms_norm
 from ..ops.rope import apply_rope
@@ -155,7 +155,6 @@ from ..telemetry.names import (
     SCOPE_CONV,
     SCOPE_CONV_STATE,
     SCOPE_EMBED,
-    SCOPE_HEAD,
     SCOPE_KV_WRITE,
     SCOPE_LAYERS,
     SCOPE_LINEAR_ATTENTION,
@@ -463,9 +462,12 @@ def hybrid_forward_counted(
     emulate_q80_activations: bool = False,
     mesh=None,
     q80_sync: bool = False,
+    head_row: jnp.ndarray | None = None,  # [B] int32: the one row a lane whose logits are kept
 ):
     """(logits ``[B, T, vocab]`` f32, updated cache, counts), as
-    ``deepseek_forward_counted``; ``counts`` None without routed layers."""
+    ``deepseek_forward_counted``; ``counts`` None without routed layers. With
+    ``head_row``, logits ``[B, 1, vocab]``: each lane's row at that index
+    alone, as in ``llama_forward``."""
     if mesh is not None or q80_sync:
         raise ValueError("the layer-pattern block runs on one device: no mesh")
     if not isinstance(cache, HybridCache):
@@ -842,10 +844,8 @@ def hybrid_forward_counted(
             counts = tuple(stacks[-len(ROUTED_COUNTS):])
             stacks = stacks[:-len(ROUTED_COUNTS)]
 
-    with jax.named_scope(SCOPE_HEAD):
-        y = norm(x, params.rms_final)
-        if cfg.logit_divisor != 1.0:
-            y = (y.astype(jnp.float32) / cfg.logit_divisor).astype(y.dtype)
-        logits = matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)
-        logits = logits[..., : cfg.vocab_size]
+    logits = head(
+        x, lambda x: norm(x, params.rms_final), params.wcls, cfg.vocab_size,
+        head_row=head_row, logit_divisor=cfg.logit_divisor, qdq=maybe_qdq,
+    )
     return logits, HybridCache(*stacks), counts

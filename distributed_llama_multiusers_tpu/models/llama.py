@@ -99,6 +99,7 @@ from ..formats.model_file import HiddenAct
 from ..ops import blocked_attention, pallas_attention
 from ..ops.activations import gelu, silu
 from ..ops.linear import (
+    head,
     matmul,
     pallas_interpret,
     pallas_kernel_active,
@@ -111,7 +112,6 @@ from ..telemetry.names import (
     SCOPE_ATTN_OUT,
     SCOPE_EMBED,
     SCOPE_FFN,
-    SCOPE_HEAD,
     SCOPE_KV_WRITE,
     SCOPE_LAYERS,
     SCOPE_QKV,
@@ -549,8 +549,14 @@ def llama_forward(
     mesh=None,
     q80_sync: bool = False,
     n_valid: jnp.ndarray | None = None,  # [B] int32: leading real rows a lane
+    head_row: jnp.ndarray | None = None,  # [B] int32: the one row a lane whose logits are kept
 ) -> tuple[jnp.ndarray, KVCache]:
-    """Returns (logits [B, T, vocab] float32, updated cache).
+    """Returns (logits [B, T, vocab] float32, updated cache); with
+    ``head_row``, logits ``[B, 1, vocab]``: each lane's row at that index and
+    no other (``ops.linear.head`` cuts the hidden state to it before the final
+    norm and ``wcls``; the cache is written for all ``T`` rows either way). A
+    prefill chunk names the row of its last real token; a decode step and a
+    verify window name none and get every row.
 
     Works for prefill (T > 1) and decode (T = 1) alike; the KV cache is
     per-lane (fixes reference defect (c) where all lanes shared one cache).
@@ -827,12 +833,10 @@ def llama_forward(
             layer_step, (x, cache.k, cache.v), (scanned_layers, layer_index)
         )
 
-    with jax.named_scope(SCOPE_HEAD):
-        y = rms_norm(x, params.rms_final, eps)
-        logits = sliced_matmul(maybe_qdq(y), params.wcls).astype(jnp.float32)  # [B, T, vocab]
-        # wcls may be padded past vocab_size for the slab kernel's wide tiles
-        # (quants/packed.pad_packed_d_out); identity slice otherwise
-        logits = logits[..., : h_cfg.vocab_size]
+    logits = head(  # [B, T, vocab], or [B, 1, vocab] at head_row
+        x, lambda x: rms_norm(x, params.rms_final, eps), params.wcls, h_cfg.vocab_size,
+        head_row=head_row, qdq=maybe_qdq, project=sliced_matmul,
+    )
     out_cache = (
         PagedKVCache(k=new_k, v=new_v, table=cache.table)
         if paged

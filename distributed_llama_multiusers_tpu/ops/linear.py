@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..quants.packed import PackedQ40, Q40Layer, q40_matmul_xla
+from ..telemetry.names import SCOPE_HEAD
 
 # The kernel carries its own GSPMD partitioning rule
 # (ops/pallas_q40.q40_matmul_partitioned), so it stays on under meshes:
@@ -121,6 +122,33 @@ def matmul(x: jnp.ndarray, w) -> jnp.ndarray:
             )
         return q40_matmul_xla(x, w)
     return x @ w
+
+
+def head(x: jnp.ndarray, norm, wcls, vocab_size: int, *, head_row=None,
+         logit_divisor: float = 1.0, qdq=lambda y: y, project=matmul) -> jnp.ndarray:
+    """Every block's head, under ``dl.head``: the final norm (``norm``, the
+    block's own, of ``x`` alone), ``logit_divisor`` where the family has one,
+    ``wcls`` through ``project`` (``matmul``, or a mesh's sliced matmul),
+    float32, the vocabulary's columns (``wcls`` may be padded past
+    ``vocab_size`` for the slab kernel's wide tiles:
+    quants/packed.pad_packed_d_out). x: ``[B, T, dim]``; returns
+    ``[B, T, vocab]`` float32.
+
+    ``head_row`` (``[B]`` int32; None: every row) is the one row a lane its
+    caller keeps: ``x`` is cut to ``[B, 1, dim]`` BEFORE the norm, and the
+    result is ``[B, 1, vocab]``, the row a whole head would hold there. A
+    prefill chunk keeps the row of its last real token (the engine's
+    ``_prefill_half``) and pays ``wcls`` for that row, not for its bucket."""
+    with jax.named_scope(SCOPE_HEAD):
+        if head_row is not None:
+            x = jax.vmap(
+                lambda lane, row: jax.lax.dynamic_slice_in_dim(lane, row, 1, axis=0)
+            )(x, head_row)
+        y = norm(x)
+        if logit_divisor != 1.0:
+            y = (y.astype(jnp.float32) / logit_divisor).astype(y.dtype)
+        logits = project(qdq(y), wcls).astype(jnp.float32)
+        return logits[..., :vocab_size]
 
 
 def q40_matmul_local(x: jnp.ndarray, w: PackedQ40) -> jnp.ndarray:

@@ -878,7 +878,8 @@ class InferenceEngine:
             # lanes carry a state overwritten in place, and a Llama block,
             # whose prefill attention then fetches no key block past the last
             # real row; the latent block has no use for it, and its programs
-            # are what they always were
+            # are what they always were. head_row (the one row a prefill half
+            # keeps the logits of) every block takes: it rides kw
             if cfg.recurrent_state or not (cfg.latent_attention or cfg.layer_kinds):
                 kw["n_valid"] = n_valid
             return forward_c(*a, **kw)
@@ -1362,6 +1363,10 @@ class InferenceEngine:
 
         self._decode_spec_fn = _decode_spec
 
+        # the most rows of logits a forward has handed a prefill half, of the
+        # programs traced so far (0: none traced; path_facts)
+        self._prefill_head_rows = 0
+
         @jax.named_scope(names.HALF_PREFILL)
         def _prefill_half(params, cache, lane, tokens, start_pos, n_tokens,
                           temp, topp, seed, gtab, p_g):
@@ -1378,10 +1383,17 @@ class InferenceEngine:
             (mask s <= pos), so no masking is needed. First-token sampling
             is compiled into the step: multi-host pods replay the
             identical program (a root-only jit over the global-mesh logits
-            would not be dispatchable)."""
+            would not be dispatchable).
+
+            The one row of logits a chunk keeps is its last real token's:
+            the forward is told so (``head_row``) and runs its head, the
+            final norm and ``wcls``, over that row of the hidden state
+            alone, not over the bucket (``path_facts``:
+            ``prefill_head_rows``)."""
             bucket = tokens.shape[0]
             with jax.named_scope(SCOPE_CARRY):
                 positions = start_pos + jnp.arange(bucket, dtype=jnp.int32)
+                head_row = (n_tokens - 1)[None]
             if isinstance(cache, PagedKVCache):
                 # paged layout: there is no per-lane plane to slice — the
                 # POOL rides whole and the lane's one-ROW page table scopes
@@ -1395,6 +1407,7 @@ class InferenceEngine:
                     tokens[None, :],
                     positions[None, :],
                     PagedKVCache(k=cache.k, v=cache.v, table=row),
+                    head_row=head_row,
                     emulate_q80_activations=q80,
                     mesh=sp_mesh,
                     q80_sync=q80s,
@@ -1419,6 +1432,7 @@ class InferenceEngine:
                     # the chunk's real tokens: a state that is overwritten in
                     # place must not absorb the bucket's padded tail
                     n_valid=n_tokens[None],
+                    head_row=head_row,
                     emulate_q80_activations=q80,
                     mesh=sp_mesh,
                     q80_sync=q80s,
@@ -1427,8 +1441,10 @@ class InferenceEngine:
                     out_cache = jax.tree_util.tree_map(
                         lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, lane, axis=1),
                         cache, lane_cache)
+            # trace-time witness: the rows of logits the forward handed back
+            self._prefill_head_rows = max(self._prefill_head_rows, logits.shape[1])
             with jax.named_scope(SCOPE_HEAD):
-                last = jax.lax.dynamic_index_in_dim(logits[0], n_tokens - 1, axis=0, keepdims=False)
+                last = logits[0, 0]
             # grammar: the boundary token — the request's FIRST generated
             # token when this is the final chunk — samples under the
             # automaton's start-state mask (p_g; 0 = FREE = identity)
@@ -1692,10 +1708,10 @@ class InferenceEngine:
         forward itself asks, and what the kernel bodies traced so far are
         (``q40_weight_passes``, ``q40_offset_subtracted``,
         ``prefill_kernel_traces``: the trace-time witnesses of
-        ``ops/pallas_q40.py`` and ``ops/pallas_attention.py``): said once at
-        start-up (the
-        ``runtime_device`` line, ``/stats``), so that a fallback is never
-        silent."""
+        ``ops/pallas_q40.py`` and ``ops/pallas_attention.py``;
+        ``prefill_head_rows``: this engine's own, of ``_prefill_half``): said
+        once at start-up (the ``runtime_device`` line, ``/stats``), so that a
+        fallback is never silent."""
         from ..ops.linear import pallas_kernel_active
         from ..ops.pallas_q40 import TRACE_STATS as q40_trace_stats
         from ..ops.pallas_q40_grouped import grouped_supports
@@ -1737,6 +1753,11 @@ class InferenceEngine:
                  # wherever the path above says in_place_kernel; 0 there: the
                  # chunks still form dense scores)
                  "prefill_kernel_traces": pallas_attention.TRACE_STATS["prefill_kernel_traces"],
+                 # rows of a chunk that meet the final norm and wcls: 1 once a
+                 # prefill program is traced and the forward cut the hidden
+                 # state to the row the chunk keeps; the largest bucket before
+                 # that, and after it if a forward still heads every row
+                 "prefill_head_rows": self._prefill_head_rows or chunk,
                  "expert_path": experts,
                  "sampler_groups": self.sampler_groups,
                  # the most passes over its weight plane any Q40 kernel call
