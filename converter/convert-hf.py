@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Convert a HuggingFace checkpoint folder to the `.m` format (model types
 llama, mistral, mixtral, qwen2, deepseek_v3, deepseek_v32, lfm2_moe, jamba,
-cohere2_moe, minicpm_sala).
+cohere2_moe, minicpm_sala, mimo_v2_flash).
 
 Usage: python convert-hf.py <sourceFolderPath> <weightsFloatType> <name>
 
@@ -104,6 +104,10 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         # under scaled residuals: LayerKind.LINEAR / SPARSE, the
         # KEY_LINEAR_* / KEY_SPARSE_* keys and the three scalars
         "minicpm_sala": ArchType.LLAMA,
+        # window layers with a sink and kv heads and a rotation base of their
+        # own beside full-context layers, keys wider than values, a head that
+        # rotates in part, scaled values: the KEY_ROTARY_DIM ... keys
+        "mimo_v2_flash": ArchType.LLAMA,
     }.get(cfg["model_type"])
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
@@ -138,6 +142,8 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         set_window_header(h, cfg)
     if cfg["model_type"] == "minicpm_sala":
         set_sala_header(h, cfg)
+    if cfg["model_type"] == "mimo_v2_flash":
+        set_mixed_head_header(h, cfg)
     n_experts = cfg.get("num_local_experts")
     if n_experts:
         h.n_experts = int(n_experts)
@@ -429,6 +435,103 @@ def write_window_layers(out, index, header: ModelHeader, wt: int) -> None:
         write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
 
 
+def permute_rotary_first(w: "np.ndarray", n_heads: int, rotary: int) -> "np.ndarray":
+    """``permute_rotary`` on the first ``rotary`` rows of every head (the rows
+    that rotate, published in the half-rotation pairing ``i, i + rotary / 2``),
+    the head's other rows as they are."""
+    d_out, d_in = w.shape
+    heads = w.reshape(n_heads, d_out // n_heads, d_in)
+    first = permute_rotary(heads[:, :rotary].reshape(n_heads * rotary, d_in), n_heads)
+    return np.concatenate(
+        [first.reshape(n_heads, rotary, d_in), heads[:, rotary:]], axis=1).reshape(d_out, d_in)
+
+
+def set_mixed_head_header(h: ModelHeader, cfg: dict) -> None:
+    """The header keys of ``model_type: mimo_v2_flash`` (formats/model_file.py
+    KEY_ROTARY_DIM ...): the layer kinds as published (``hybrid_layer_pattern``:
+    0 full context, 1 window), each kind's kv heads and rotation base, a key
+    head's and a value head's width, the width that rotates, the value scale,
+    the window layers' sink, the leading dense layers (``moe_layer_freq``) and
+    the routed FFN's keys (sigmoid scores, a selection bias, no shared expert).
+    What the runtime does not compute is refused here, by name, not converted
+    wrongly."""
+    kinds, freq = list(cfg["hybrid_layer_pattern"]), list(cfg["moe_layer_freq"])
+    n_dense = next((i for i, f in enumerate(freq) if f), len(freq))
+    refused = {
+        "hybrid_layer_pattern": len(kinds) != cfg["num_hidden_layers"] or set(kinds) - {0, 1},
+        "moe_layer_freq": len(freq) != len(kinds) or any(f != 1 for f in freq[n_dense:]),
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "add_full_attention_sink_bias": bool(cfg.get("add_full_attention_sink_bias")),
+        "swa_num_attention_heads": cfg["swa_num_attention_heads"] != cfg["num_attention_heads"],
+        "swa_head_dim": cfg["swa_head_dim"] != cfg["head_dim"],
+        "swa_v_head_dim": cfg["swa_v_head_dim"] != cfg["v_head_dim"],
+        "n_shared_experts": bool(cfg.get("n_shared_experts")),
+        "routed_scaling_factor": cfg.get("routed_scaling_factor") not in (None, 1, 1.0),
+        "n_group": cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1,
+        "scoring_func": cfg.get("scoring_func") != "sigmoid",
+        "topk_method": cfg.get("topk_method") not in ("noaux_tc", "greedy"),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(f"Unsupported mimo_v2_flash setting: {key} = {cfg.get(key)!r}")
+    h.layer_kinds = [LayerKind.WINDOW if k else LayerKind.ATTENTION for k in kinds]
+    h.head_dim, h.v_head_dim = int(cfg["head_dim"]), int(cfg["v_head_dim"])
+    # (the rounding of head_dim x partial_rotary_factor is not published: down)
+    h.rotary_dim = int(cfg["head_dim"] * cfg.get("partial_rotary_factor", 1.0))
+    h.sliding_window = int(cfg["sliding_window"])
+    h.window_n_kv_heads = int(cfg["swa_num_key_value_heads"])
+    h.window_rope_theta = float(cfg["swa_rope_theta"])
+    h.attn_value_scale = float(cfg.get("attention_value_scale") or 1.0)
+    h.window_sink = int(bool(cfg.get("add_swa_attention_sink_bias")))
+    h.norm_epsilon = float(cfg["layernorm_epsilon"])
+    h.n_dense_layers = n_dense
+    h.n_experts = int(cfg["n_routed_experts"])
+    h.n_active_experts = int(cfg["num_experts_per_tok"])
+    h.moe_hidden_dim = int(cfg["moe_intermediate_size"])
+    h.moe_score_func = MoeScore.SIGMOID
+    h.moe_select_bias = int(cfg.get("topk_method") == "noaux_tc")
+    h.moe_norm_topk = int(bool(cfg.get("norm_topk_prob", True)))
+    h.moe_norm_floor = 0.0  # sigmoid scores are positive: the family's sum has no floor
+
+
+def write_mixed_head_layers(out, index, header: ModelHeader, wt: int) -> None:
+    """The layers of a mimo_v2_flash checkpoint in the order of
+    formats/model_file._pattern_block_specs: q, k, v of the layer's kind (the
+    first ``rotary_dim`` rows of every q and k head permuted from the
+    published half-rotation pairing to adjacent pairs), wo, a window layer's
+    ``attention_sink_bias`` (F32); then a dense FFN in the leading layers and
+    in the others the router (F32), its ``e_score_correction_bias`` (F32) and
+    the held experts' up, gate, down; then the two norms."""
+    held = range(header.experts_held_first,
+                 header.experts_held_first + (header.experts_held_count or header.n_experts))
+    for l, kind in enumerate(header.layer_kinds):
+        pre = f"model.layers.{l}"
+        n_kv = header.kv_heads(kind == LayerKind.WINDOW)
+        for name, heads in (("q_proj", header.n_heads), ("k_proj", n_kv)):
+            write_tensor(out, permute_rotary_first(
+                index.get(f"{pre}.self_attn.{name}.weight"), heads, header.rotary_dim), wt)
+        write_tensor(out, index.get(f"{pre}.self_attn.v_proj.weight"), wt)
+        write_tensor(out, index.get(f"{pre}.self_attn.o_proj.weight"), wt)
+        if header.window_sink and kind == LayerKind.WINDOW:
+            write_tensor(out, index.get(f"{pre}.self_attn.attention_sink_bias"), FloatType.F32)
+        if l < header.n_dense_layers:
+            write_tensor(out, index.get(f"{pre}.mlp.gate_proj.weight"), wt)  # w1
+            write_tensor(out, index.get(f"{pre}.mlp.down_proj.weight"), wt)  # w2
+            write_tensor(out, index.get(f"{pre}.mlp.up_proj.weight"), wt)  # w3
+        else:
+            write_tensor(out, index.get(f"{pre}.mlp.gate.weight"), FloatType.F32)
+            if header.moe_select_bias:
+                write_tensor(out, index.get(f"{pre}.mlp.gate.e_score_correction_bias"),
+                             FloatType.F32)
+            for e in held:  # the experts this file holds (all of them unless told)
+                epre = f"{pre}.mlp.experts.{e}"
+                write_tensor(out, index.get(f"{epre}.up_proj.weight"), wt)  # w3
+                write_tensor(out, index.get(f"{epre}.gate_proj.weight"), wt)  # w1
+                write_tensor(out, index.get(f"{epre}.down_proj.weight"), wt)  # w2
+        write_tensor(out, index.get(f"{pre}.input_layernorm.weight"), FloatType.F32)
+        write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
+
+
 def write_ssm_layers(out, index, header: ModelHeader, wt: int) -> None:
     """The layers of a jamba checkpoint in the order of
     formats/model_file._pattern_block_specs. ``mamba.in_proj`` (x, z) and
@@ -599,6 +702,8 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
             write_sala_layers(out, index, header, wt)
         elif header.parallel_block:
             write_window_layers(out, index, header, wt)
+        elif header.window_n_kv_heads or header.window_sink:
+            write_mixed_head_layers(out, index, header, wt)
         elif header.layer_kinds:
             write_pattern_layers(out, index, header, wt)
         for l in range(0 if header.kv_lora_rank or header.layer_kinds else header.n_layers):  # a Llama block's layers
@@ -634,7 +739,8 @@ def write_model(header: ModelHeader, index, weight_type: int, out_path: str) -> 
             write_tensor(out, index.get(f"{pre}.post_attention_layernorm.weight"), FloatType.F32)
         # lfm2_moe names its final norm after the embedding, jamba after its place
         norm_key = ("model.final_layernorm.weight" if header.ssm_d_inner
-                    else "model.norm.weight" if header.parallel_block or header.linear_n_heads
+                    else "model.norm.weight" if (header.parallel_block or header.linear_n_heads
+                                                 or header.window_n_kv_heads or header.window_sink)
                     else "model.embedding_norm.weight" if header.layer_kinds
                     else "model.norm.weight")
         write_tensor(out, index.get(norm_key), FloatType.F32)

@@ -167,6 +167,22 @@ KEY_SPARSE_DENSE_LEN = 65
 KEY_EMBED_SCALE_E6 = 66
 KEY_RESIDUAL_SCALE_E6 = 67
 KEY_LOGIT_DIVISOR_E6 = 68
+# framework extension: what ``model_type: mimo_v2_flash`` adds to a block of
+# full-context and window attention layers, each key written only where it is
+# set, so every file without them reads, and is written, as before. A value
+# head narrower than a key head rides KEY_V_HEAD_DIM (a layer-kind file wrote
+# it as 0 before: the key head's width). The width of a head that rotates
+# (its FIRST ``rotary_dim`` numbers; 0: the whole head); the window kind's
+# own count of kv heads (0: ``n_kv_heads``) and rotation base (0:
+# ``rope_theta``); the factor on every value, in millionths; and whether a
+# window layer's softmax has a learned sink a query head (one F32 vector of
+# ``n_heads`` a window layer, after its wo: a column of the softmax that takes
+# mass and gives no value).
+KEY_ROTARY_DIM = 69
+KEY_WINDOW_N_KV_HEADS = 70
+KEY_WINDOW_ROPE_THETA = 71
+KEY_ATTN_VALUE_SCALE_E6 = 72
+KEY_WINDOW_SINK = 73
 
 
 class ArchType:
@@ -297,6 +313,12 @@ class ModelHeader:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
+    # what mimo_v2_flash adds (KEY_ROTARY_DIM ...); unset elsewhere
+    rotary_dim: int = 0  # 0: the whole head rotates
+    window_n_kv_heads: int = 0  # 0: n_kv_heads
+    window_rope_theta: float = 0.0  # 0: rope_theta
+    attn_value_scale: float = 1.0
+    window_sink: int = 0
     header_size: int = 0
     file_size: int = 0
 
@@ -306,12 +328,27 @@ class ModelHeader:
 
     @property
     def q_dim(self) -> int:
-        """Width of a layer's queries, and of attention's output before wo."""
+        """Width of a layer's queries."""
         return self.n_heads * self.head_size
 
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_size
+
+    @property
+    def value_head_size(self) -> int:
+        """A GQA value head's width: the key head's unless the file says
+        otherwise (``v_head_dim``, which a latent block reads as its own)."""
+        return (0 if self.kv_lora_rank else self.v_head_dim) or self.head_size
+
+    @property
+    def o_dim(self) -> int:
+        """Width of attention's output before wo."""
+        return self.n_heads * self.value_head_size
+
+    def kv_heads(self, windowed: bool = False) -> int:
+        """The kv heads of a layer kind: the window kind's own where named."""
+        return (self.window_n_kv_heads if windowed else 0) or self.n_kv_heads
 
     def to_kv_pairs(self) -> list[tuple[int, int]]:
         """Serializable (key, int-value) pairs, converter order (writer.py:109-130)."""
@@ -368,7 +405,13 @@ class ModelHeader:
         ] + [
             (key, int(round(getattr(self, name) * 1e6)))
             for key, name in _SCALE_E6_KEYS.items() if getattr(self, name) != 1.0
-        ]
+        ] + [
+            (key, int(getattr(self, name))) for key, name in _MIXED_HEAD_INT_KEYS.items()
+            if getattr(self, name)
+        ] + (
+            [(KEY_ATTN_VALUE_SCALE_E6, int(round(self.attn_value_scale * 1e6)))]
+            if self.attn_value_scale != 1.0 else []
+        )
 
 
 _LATENT_INT_KEYS = {
@@ -426,6 +469,12 @@ _SCALE_E6_KEYS = {
     KEY_LOGIT_DIVISOR_E6: "logit_divisor",
 }
 _YARN_E6_KEYS = {KEY_ROPE_YARN_MSCALE_ALL_DIM_E6: "rope_yarn_mscale_all_dim"}
+_MIXED_HEAD_INT_KEYS = {
+    KEY_ROTARY_DIM: "rotary_dim",
+    KEY_WINDOW_N_KV_HEADS: "window_n_kv_heads",
+    KEY_WINDOW_ROPE_THETA: "window_rope_theta",
+    KEY_WINDOW_SINK: "window_sink",
+}
 
 
 def _floor_to_exp10(floor: float) -> int:
@@ -444,6 +493,25 @@ SSM_FIELDS = tuple(_SSM_INT_KEYS.values())
 WINDOW_FIELDS = (*_WINDOW_INT_KEYS.values(), "shared_expert_scale")
 # every header field minicpm_sala added, as models/config.py takes them
 LINEAR_SPARSE_FIELDS = (*_LINEAR_SPARSE_INT_KEYS.values(), *_SCALE_E6_KEYS.values())
+# every header field mimo_v2_flash added, as models/config.py takes them
+MIXED_HEAD_FIELDS = (*_MIXED_HEAD_INT_KEYS.values(), "attn_value_scale")
+
+
+def check_mixed_heads(h) -> None:
+    """What the per-kind head fields need of the header (or of a config: the
+    fields have the same names)."""
+    named = h.rotary_dim or h.window_n_kv_heads or h.window_rope_theta or h.window_sink
+    if named and not h.layer_kinds:
+        raise ValueError("rotary_dim, window_n_kv_heads, window_rope_theta and "
+                         "window_sink belong to a block with a layer-kind list")
+    if h.rotary_dim and (h.rotary_dim % 2 or h.rotary_dim > h.head_size):
+        raise ValueError("rotary_dim is an even width of at most a head")
+    if h.window_n_kv_heads and h.n_heads % h.window_n_kv_heads:
+        raise ValueError("the query heads are a multiple of window_n_kv_heads")
+    if (h.window_n_kv_heads or h.window_rope_theta or h.window_sink) and (
+            LayerKind.WINDOW not in h.layer_kinds):
+        raise ValueError("window_n_kv_heads, window_rope_theta and window_sink "
+                         "need a window layer")
 
 
 def check_linear_sparse(h) -> None:
@@ -557,6 +625,12 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 setattr(h, _LINEAR_SPARSE_INT_KEYS[key], value)
             elif key in _SCALE_E6_KEYS:
                 setattr(h, _SCALE_E6_KEYS[key], value / 1e6)
+            elif key == KEY_WINDOW_ROPE_THETA:
+                h.window_rope_theta = float(value)
+            elif key in _MIXED_HEAD_INT_KEYS:
+                setattr(h, _MIXED_HEAD_INT_KEYS[key], value)
+            elif key == KEY_ATTN_VALUE_SCALE_E6:
+                h.attn_value_scale = value / 1e6
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
@@ -572,6 +646,7 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
         if LayerKind.WINDOW in h.layer_kinds and h.sliding_window < 1:
             raise ValueError("a window layer needs sliding_window >= 1")
         check_linear_sparse(h)
+        check_mixed_heads(h)
         h.header_size = header_size
         h.orig_seq_len = h.seq_len
         if max_seq_len > 0 and h.seq_len > max_seq_len:
@@ -728,7 +803,12 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
     shared experts as one gated FFN where the header has them; then the two
     norms (one where ``parallel_block``). A block-sparse layer (``model_type:
     minicpm_sala``): an attention layer's tensors with ``attn_gate`` (``n_heads
-    * head_size`` rows) before wo. A linear-attention layer: ``lin_q``,
+    * head_size`` rows) before wo. Where the file names them (``model_type:
+    mimo_v2_flash``), k and v have the rows of THEIR layer kind's kv heads (k
+    ``kv_heads(kind) * head_size``, v ``kv_heads(kind) * value_head_size``), wo
+    reads ``n_heads * value_head_size`` columns, only the first ``rotary_dim``
+    rows of every q and k head are permuted to pairs, and a window layer's
+    ``attn_sink`` (F32, ``n_heads``) follows its wo. A linear-attention layer: ``lin_q``,
     ``lin_k``, ``lin_v`` (``linear_n_heads * linear_head_dim`` rows each, q
     and k permuted as an attention layer's), the per-head gains of q's and
     k's norms (F32, permuted alike), ``lin_gate``, the output norm's gain a
@@ -756,13 +836,16 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
             add("block_ssm_d", l, FloatType.F32, (1, e))
             add("block_matmul_ssm_out", l, wt, (dim, e))
         elif kind in (LayerKind.ATTENTION, LayerKind.WINDOW):
+            n_kv = h.kv_heads(kind == LayerKind.WINDOW)
             add("block_matmul_q", l, wt, (h.q_dim, dim))
-            add("block_matmul_k", l, wt, (kv_dim, dim))
-            add("block_matmul_v", l, wt, (kv_dim, dim))
+            add("block_matmul_k", l, wt, (n_kv * h.head_size, dim))
+            add("block_matmul_v", l, wt, (n_kv * h.value_head_size, dim))
             if h.qk_norm:
                 add("block_q_norm", l, FloatType.F32, (1, h.head_size))
                 add("block_k_norm", l, FloatType.F32, (1, h.head_size))
-            add("block_matmul_wo", l, wt, (dim, h.q_dim))
+            add("block_matmul_wo", l, wt, (dim, h.o_dim))
+            if h.window_sink and kind == LayerKind.WINDOW:
+                add("block_attn_sink", l, FloatType.F32, (1, h.n_heads))
         elif kind == LayerKind.SPARSE:
             add("block_matmul_q", l, wt, (h.q_dim, dim))
             add("block_matmul_k", l, wt, (kv_dim, dim))

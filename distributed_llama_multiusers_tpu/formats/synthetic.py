@@ -133,6 +133,36 @@ def tiny_window_header(
     return h
 
 
+def tiny_mixed_head_header(
+    pattern: str = "FWWWWFWW",
+    sliding_window: int = 8,
+    heads: tuple = (8, 2, 4),
+    widths: tuple = (24, 16, 8),
+    n_experts: int = 16,
+    experts_held: tuple = (0, 4),
+    **kw,
+) -> ModelHeader:
+    """A toy of what ``mimo_v2_flash`` adds (models/hybrid.py): ``pattern`` a
+    letter a layer, ``F`` full-context attention of ``heads[1]`` kv heads at
+    one rotation base, ``W`` window attention of ``heads[2]`` kv heads at
+    another, with a sink a query head; ``widths``: a key head, a value head
+    and the part of a key head that rotates; every value scaled; RMS norms, a
+    sequential block, layer 0's FFN dense and the others routed (sigmoid
+    scores, a selection bias, no shared expert), of which a share is held."""
+    kw = {"dim": 64, "hidden_dim": 128, "n_heads": heads[0], "n_kv_heads": heads[1],
+          "seq_len": 64, **kw}
+    h = tiny_header(n_layers=len(pattern), rope_theta=5000000.0, **kw)
+    h.layer_kinds = [LayerKind.WINDOW if c == "W" else LayerKind.ATTENTION for c in pattern]
+    h.head_dim, h.v_head_dim, h.rotary_dim = widths
+    h.sliding_window, h.window_n_kv_heads, h.window_rope_theta = sliding_window, heads[2], 10000.0
+    h.attn_value_scale, h.window_sink = 0.707, 1
+    h.n_dense_layers = 1
+    h.n_experts, h.n_active_experts, h.moe_hidden_dim = n_experts, 4, 32
+    h.moe_score_func, h.moe_select_bias, h.moe_norm_topk = MoeScore.SIGMOID, 1, 1
+    h.experts_held_first, h.experts_held_count = experts_held
+    return h
+
+
 def tiny_sala_header(
     pattern: str = "SLLLLSSL",
     sizes: tuple = (4, 2, 8, 4, 16, 1, 48),

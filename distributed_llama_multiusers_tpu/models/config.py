@@ -9,7 +9,9 @@ from ..formats.model_file import (
     SSM_FIELDS,
     WINDOW_FIELDS,
     LINEAR_SPARSE_FIELDS,
+    MIXED_HEAD_FIELDS,
     check_linear_sparse,
+    check_mixed_heads,
     HiddenAct,
     LayerKind,
     ModelHeader,
@@ -154,6 +156,22 @@ class LlamaConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
+    # What ``model_type: mimo_v2_flash`` adds to a block of ATTENTION and
+    # WINDOW layers, each engaged by its own field. A value head of
+    # v_head_dim (above; 0: the key head's width) under GQA: keys and values
+    # are cached at widths of their own and wo reads n_heads * v_head_dim.
+    # rotary_dim: the FIRST rotary_dim numbers of every query and key head
+    # rotate (0: the whole head). window_n_kv_heads / window_rope_theta: the
+    # window kind's own kv heads and rotation base (0: the full-context
+    # kind's), which give it K/V projections, rings and rotation tables of its
+    # own. attn_value_scale multiplies every value. window_sink: a window
+    # layer's softmax has one more column a query head, a learned logit that
+    # takes mass and gives no value.
+    rotary_dim: int = 0
+    window_n_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    attn_value_scale: float = 1.0
+    window_sink: int = 0
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -222,6 +240,12 @@ class LlamaConfig:
             if self.n_window_layers and self.sliding_window < 1:
                 raise ValueError("a window layer needs sliding_window >= 1")
             check_linear_sparse(self)
+            if (self.value_head_size != self.head_size or self.rotary_dim) and (
+                self.n_sparse_layers or self.n_linear_layers
+            ):
+                raise ValueError(
+                    "a value head of its own width and a head that rotates in part "
+                    "belong to a block of full-context and window layers")
             if self.n_sparse_layers and (
                 self.n_sparse_layers != self.n_attention_layers or self.n_window_layers
             ):
@@ -236,6 +260,7 @@ class LlamaConfig:
                 raise ValueError(
                     "a linear-attention layer's heads are as wide as the attention "
                     "layers' (one rotation table)")
+        check_mixed_heads(self)
         if self.residual_scale != 1.0 and (
             self.n_linear_layers + self.n_sparse_layers != self.n_layers or not self.layer_kinds
         ):
@@ -332,18 +357,50 @@ class LlamaConfig:
 
     @property
     def q_dim(self) -> int:
-        """Width of a layer's queries, and of attention's output before wo."""
+        """Width of a layer's queries."""
         return self.n_heads * self.head_size
 
     @property
+    def value_head_size(self) -> int:
+        """A GQA value head's width: the key head's unless ``v_head_dim``
+        says otherwise (a latent block reads that field as its own)."""
+        return (0 if self.latent_attention else self.v_head_dim) or self.head_size
+
+    @property
+    def o_dim(self) -> int:
+        """Width of GQA attention's output before wo."""
+        return self.n_heads * self.value_head_size
+
+    @property
     def rope_dim(self) -> int:
-        """Width the rotary embedding turns: the whole head of a Llama block,
-        the ``qk_rope_head_dim`` part of a latent-attention head."""
-        return self.qk_rope_head_dim if self.latent_attention else self.head_size
+        """Width the rotary embedding turns: the whole head of a Llama block
+        (its first ``rotary_dim`` numbers where that is named), the
+        ``qk_rope_head_dim`` part of a latent-attention head."""
+        if self.latent_attention:
+            return self.qk_rope_head_dim
+        return self.rotary_dim or self.head_size
 
     @property
     def kv_dim(self) -> int:
+        """Width of a cached key row of the full-context kind (and of every
+        kind, and of a value row, where no field says otherwise)."""
         return self.n_kv_heads * self.head_size
+
+    def kv_heads(self, windowed: bool = False) -> int:
+        """The kv heads of a layer kind: the window kind's own where named."""
+        return (self.window_n_kv_heads if windowed else 0) or self.n_kv_heads
+
+    def kv_widths(self, windowed: bool = False) -> tuple:
+        """(key row, value row) widths a position of a layer kind's cache."""
+        n_kv = self.kv_heads(windowed)
+        return n_kv * self.head_size, n_kv * self.value_head_size
+
+    @property
+    def split_kv_kinds(self) -> bool:
+        """Whether the window kind has K/V projections of its own beside the
+        full-context kind's (another count of kv heads): then ``wk`` / ``wv``
+        are a stack a kind, each indexed by the count of its kind."""
+        return self.window_n_kv_heads not in (0, self.n_kv_heads)
 
     @staticmethod
     def from_header(h: ModelHeader) -> "LlamaConfig":
@@ -373,4 +430,5 @@ class LlamaConfig:
             **{name: getattr(h, name) for name in SSM_FIELDS},
             **{name: getattr(h, name) for name in WINDOW_FIELDS},
             **{name: getattr(h, name) for name in LINEAR_SPARSE_FIELDS},
+            **{name: getattr(h, name) for name in MIXED_HEAD_FIELDS},
         )

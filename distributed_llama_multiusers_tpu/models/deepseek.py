@@ -117,7 +117,7 @@ from ..ops.pallas_q40_grouped import (
     route_plan,
     tile_rows,
 )
-from ..ops.rope import apply_rope
+from ..ops.rope import apply_rope, apply_rope_first
 from ..quants.packed import PackedQ40, Q40Experts, Q40Layer, unpack_q40
 from ..telemetry.names import (
     SCOPE_ATTENTION,
@@ -354,12 +354,6 @@ TOPK_SEGMENT = 8192
 QUERY_BLOCK = 256
 # index scores made at a time, all heads: rows x heads x keys float32
 SCORE_BLOCK_ELEMENTS = 1 << 26
-
-
-def _rope_first(x, n: int, cos, sin, positions):
-    """The rotary embedding on the first ``n`` numbers of every head of ``x``
-    ``[B, T, H, D]``, the rest as they are."""
-    return jnp.concatenate([apply_rope(x[..., :n], cos, sin, positions), x[..., n:]], axis=-1)
 
 
 def index_scores_block(qi, w, ik_block):
@@ -677,9 +671,9 @@ def deepseek_forward_counted(
                 with jax.named_scope(SCOPE_INDEXER):
                     qi = matmul(cq, ap.idx_wq).reshape(
                         b, t, cfg.index_n_heads, cfg.index_head_dim)
-                    qi = _rope_first(qi, rope, cos, sin, positions)
+                    qi = apply_rope_first(qi, rope, cos, sin, positions)
                     ki = layer_norm(matmul(yq, ap.idx_wk), ap.idx_k_gain, ap.idx_k_bias, eps)
-                    ki = _rope_first(ki[:, :, None], rope, cos, sin, positions)[:, :, 0]
+                    ki = apply_rope_first(ki[:, :, None], rope, cos, sin, positions)[:, :, 0]
                     # float32 like the router: the weights rank positions
                     w = jnp.einsum(
                         "btd,dj->btj", y.astype(jnp.float32), ap.idx_ww.astype(jnp.float32),

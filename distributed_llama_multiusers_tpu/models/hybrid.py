@@ -45,6 +45,16 @@ The layer (``h`` the stream, ``K = conv_kernel``):
     block, ``embed_scale`` the embedding, and the final norm's output is
     divided by ``logit_divisor`` before the head.
     FFN:        dense in the first ``n_dense_layers`` layers, routed in the others
+    mixed heads (``model_type: mimo_v2_flash``; each by its own config field):
+                the window kind has ``window_n_kv_heads`` kv heads and rotates
+                at ``window_rope_theta`` where the full-context kind has
+                ``n_kv_heads`` and ``rope_theta``; either rotates only the
+                first ``rotary_dim`` numbers of a head; a value head is
+                ``v_head_dim`` wide (wo reads ``n_heads * v_head_dim``) and
+                every value is scaled by ``attn_value_scale``; a window row's
+                softmax has one more column, a learned logit b_i a query head
+                (``window_sink``) that takes mass and gives no value:
+                p(t, s) = exp(e(t, s)) / (exp(b_i) + sum_s' exp(e(t, s')))
     ``norm_kind`` LAYER: every ``rmsnorm`` above is g * (h - mean h) / sqrt(var h + eps).
     ``parallel_block``: ONE norm a layer, n = norm(h, g), feeds the mixer and
                 the routed FFN (shared experts scaled by ``shared_expert_scale``),
@@ -54,7 +64,9 @@ The state. Each kind of layer keeps its own stack, indexed by the count of
 that kind: ``k`` and ``v`` ``[attention layers, lanes, S, n_kv * head]`` (no
 plane for a conv layer), the window layers' ring ``wk`` and ``wv`` ``[window
 layers, lanes, R, n_kv * head]`` with ``R < S`` (None in a block without such
-layers), and the conv layers' window of inputs
+layers), each of the four as wide as ITS kind's kv heads times ITS head (a
+key 192 and a value 128 wide at 4 and 8 kv heads: 768, 512, 1536 and 1024;
+``config.kv_widths``), and the conv layers' window of inputs
 ``[conv layers, lanes, (K-1) * dim]``: a lane's last ``K - 1`` rows of ``u``;
 and, where the block has state-space layers, their running sum ``ssm``
 ``[SSM layers, lanes, N * E]``, FLOAT32 whatever the cache's type, and their
@@ -185,17 +197,25 @@ from .llama import (
 
 class GqaParams(NamedTuple):
     """The attention layers' weights, full-context and window layers in one
-    stack in layer order, ``[La, ...]``."""
+    stack in layer order, ``[La, ...]``; where the kinds' kv heads differ
+    (``config.split_kv_kinds``) ``wk`` / ``wv`` are the full-context kind's
+    alone and the window kind's are ``wk_w`` / ``wv_w``, each stacked by the
+    count of its kind."""
 
     wq: jnp.ndarray  # [La, dim, n_heads * head_size]
     wk: jnp.ndarray  # [La, dim, n_kv * head_size]
-    wv: jnp.ndarray
-    wo: jnp.ndarray  # [La, n_heads * head_size, dim]
+    wv: jnp.ndarray  # [La, dim, n_kv * value_head_size]
+    wo: jnp.ndarray  # [La, n_heads * value_head_size, dim]
     q_norm: jnp.ndarray | None  # [La, head] f32 (config.qk_norm)
     k_norm: jnp.ndarray | None
     rms: jnp.ndarray  # [La, dim]: the layer's operator norm
     # block-sparse layers only (None elsewhere): the output gate
     gate: jnp.ndarray | None = None  # [La, dim, n_heads * head_size]
+    # the window kind's own (None elsewhere): its K/V projections, and the
+    # sink's logit a query head (config.window_sink), float32
+    wk_w: jnp.ndarray | None = None  # [Lw, dim, window_n_kv * head_size]
+    wv_w: jnp.ndarray | None = None
+    sink: jnp.ndarray | None = None  # [Lw, n_heads]
 
 
 class LinearParams(NamedTuple):
@@ -253,21 +273,25 @@ class HybridParams(NamedTuple):
     rope_sin: jnp.ndarray | None
     ssm: SsmParams | None = None
     linear: LinearParams | None = None
+    # the window kind's rotation tables where its base is its own
+    # (config.window_rope_theta; None: the ones above)
+    rope_cos_w: jnp.ndarray | None = None
+    rope_sin_w: jnp.ndarray | None = None
 
 
 class HybridCache(NamedTuple):
     """A lane's state by kind of layer; the lane axis is axis 1 of each."""
 
     k: jnp.ndarray  # [La, lanes, S, n_kv * head]
-    v: jnp.ndarray
+    v: jnp.ndarray  # [La, lanes, S, n_kv * value head]
     conv: jnp.ndarray  # [Lc, lanes, (K-1) * dim]: the last K-1 rows of u
     # state-space layers only (None elsewhere): the running sum, float32
     # whatever the cache's type, and the conv's last K'-1 inputs
     ssm: jnp.ndarray | None = None  # [Ls, lanes, N * E]
     ssm_conv: jnp.ndarray | None = None  # [Ls, lanes, (K'-1) * E]
     # window layers only (None elsewhere): their keys' and values' ring
-    wk: jnp.ndarray | None = None  # [Lw, lanes, R, n_kv * head]
-    wv: jnp.ndarray | None = None
+    wk: jnp.ndarray | None = None  # [Lw, lanes, R, window n_kv * head]
+    wv: jnp.ndarray | None = None  # [Lw, lanes, R, window n_kv * value head]
     # linear-attention layers only (None elsewhere): the matrix a head,
     # float32 whatever the cache's type
     lin: jnp.ndarray | None = None  # [Ll, lanes, H * head * head]
@@ -288,7 +312,8 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
                       max_chunk: int = 1) -> HybridCache:
     """``max_chunk``: the most rows a lane any step writes (the largest prefill
     bucket): it sizes the ring (``ring_rows``)."""
-    kv = (config.n_attention_layers, n_lanes, config.seq_len, config.kv_dim)
+    plane = (config.n_attention_layers, n_lanes, config.seq_len)
+    k_dim, v_dim = config.kv_widths()
     ssm = ssm_conv = wk = wv = lin = ck = None
     if config.n_linear_layers:
         lin = jnp.zeros(
@@ -299,14 +324,15 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
             (config.n_sparse_layers, n_lanes, config.seq_len // config.sparse_kernel_stride,
              config.kv_dim), dtype)
     if config.n_window_layers:
-        ring = (config.n_window_layers, n_lanes, ring_rows(config, max_chunk), config.kv_dim)
-        wk, wv = jnp.zeros(ring, dtype), jnp.zeros(ring, dtype)
+        ring = (config.n_window_layers, n_lanes, ring_rows(config, max_chunk))
+        wk_dim, wv_dim = config.kv_widths(windowed=True)
+        wk, wv = jnp.zeros((*ring, wk_dim), dtype), jnp.zeros((*ring, wv_dim), dtype)
     if config.n_ssm_layers:
         ls, e = config.n_ssm_layers, config.ssm_d_inner
         ssm = jnp.zeros((ls, n_lanes, config.ssm_d_state * e), jnp.float32)
         ssm_conv = jnp.zeros((ls, n_lanes, (config.ssm_conv_kernel - 1) * e), dtype)
     return HybridCache(
-        k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
+        k=jnp.zeros((*plane, k_dim), dtype), v=jnp.zeros((*plane, v_dim), dtype),
         conv=jnp.zeros(
             (config.n_conv_layers, n_lanes, max(config.conv_kernel - 1, 0) * config.dim), dtype),
         ssm=ssm, ssm_conv=ssm_conv, wk=wk, wv=wv, lin=lin, ck=ck,
@@ -328,7 +354,8 @@ def ring_attention_engages(cache, mesh, n_heads: int, n_kv: int) -> bool:
     step of one row a lane reads it in place through the decode kernel."""
     return (
         getattr(cache, "wk", None) is not None and mesh is None
-        and pallas_kernel_active() and pallas_attention.supports(cache.wk, n_heads, n_kv)
+        and pallas_kernel_active()
+        and pallas_attention.supports(cache.wk, n_heads, n_kv, cache.wv)
     )
 
 
@@ -343,11 +370,12 @@ def block_sparse_engages(cache, mesh, config: LlamaConfig) -> bool:
             cache.k, config.n_heads, config.n_kv_heads, config.sparse_block_size))
 
 
-def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
+def hybrid_params(t: dict, rope_cos, rope_sin, rope_cos_w=None, rope_sin_w=None) -> HybridParams:
     """The parameter tree around a model's arrays, by the tensor names of the
     ``.m`` walk without their ``block_`` prefix: what the loader and a
     benchmark's generator both hand over. Expert stacks that arrive as
-    ``PackedQ40`` become ``Q40Experts``."""
+    ``PackedQ40`` become ``Q40Experts``. ``rope_cos_w`` / ``rope_sin_w``: the
+    window kind's tables where it rotates at a base of its own."""
     def experts(w):
         return Q40Experts.from_packed(w) if isinstance(w, PackedQ40) else w
 
@@ -357,6 +385,7 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
             wq=t["wq"], wk=t["wk"], wv=t["wv"], wo=t["wo"],
             q_norm=t.get("q_norm"), k_norm=t.get("k_norm"), rms=t["attn_rms"],
             gate=t.get("attn_gate"),
+            wk_w=t.get("wk_w"), wv_w=t.get("wv_w"), sink=t.get("attn_sink"),
         )
     if "lin_q" in t:
         linear = LinearParams(
@@ -385,7 +414,7 @@ def hybrid_params(t: dict, rope_cos, rope_sin) -> HybridParams:
     return HybridParams(
         embedding=t["embedding"], attn=attn, conv=conv, dense=dense, routed=routed,
         rms_final=t["rms_final"], wcls=t["wcls"], rope_cos=rope_cos, rope_sin=rope_sin,
-        ssm=ssm, linear=linear,
+        ssm=ssm, linear=linear, rope_cos_w=rope_cos_w, rope_sin_w=rope_sin_w,
     )
 
 
@@ -498,7 +527,8 @@ def hybrid_forward_counted(
     # one row a lane: the K/V stack is attended in place (module header)
     in_place = t == 1 and decode_attention_engages(cache, mesh, cfg.n_heads, cfg.n_kv_heads)
     window, ring = cfg.sliding_window, 0 if cache.wk is None else cache.wk.shape[2]
-    ring_in_place = t == 1 and ring_attention_engages(cache, mesh, cfg.n_heads, cfg.n_kv_heads)
+    ring_in_place = t == 1 and ring_attention_engages(
+        cache, mesh, cfg.n_heads, cfg.kv_heads(windowed=True))
     # more rows a lane against many keys: a key block at a time (module header)
     plane_blocked = blocked_attention.engages(b, t, cfg.n_heads, cfg.seq_len)
     ring_blocked = blocked_attention.engages(b, t, cfg.n_heads, ring)
@@ -545,24 +575,40 @@ def hybrid_forward_counted(
         at ``ai``, the kind's cache stack at ``ci``. Returns the stream with
         the half added (a parallel block: the half's term alone), the normed
         input, and the two stacks."""
-        ap = GqaParams(*(_pick(leaf, ai) for leaf in params.attn))
+        # the stacks in layer order are read at ai, a kind's own (its K/V
+        # projections where the kinds' kv heads differ, a window layer's
+        # sink) at the count of its kind, which is its cache stack's too
+        pa = params.attn
+        own = pa._replace(wk_w=None, wv_w=None, sink=None)
+        if cfg.split_kv_kinds:
+            own = own._replace(wk=None, wv=None)
+        ap = GqaParams(*(_pick(leaf, ai) for leaf in own))
+        if cfg.split_kv_kinds:
+            wk, wv = (pa.wk_w, pa.wv_w) if windowed else (pa.wk, pa.wv)
+            ap = ap._replace(wk=_pick(wk, ci), wv=_pick(wv, ci))
+        sink = _pick(pa.sink, ci) if windowed else None
         rotate = windowed or not cfg.full_attention_nope
+        cos, sin = params.rope_cos, params.rope_sin
+        if windowed and params.rope_cos_w is not None:
+            cos, sin = params.rope_cos_w, params.rope_sin_w
+        n_kv = cfg.kv_heads(windowed)
+        k_dim, v_dim = cfg.kv_widths(windowed)
         with jax.named_scope(SCOPE_QKV):
             y = norm(x, ap.rms)
             yq = maybe_qdq(y)
             q, k, v = gqa_project(
                 cfg, yq, ap.wq, ap.wk, ap.wv, positions,
-                params.rope_cos if rotate else None, params.rope_sin if rotate else None,
-                norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None,
+                cos if rotate else None, sin if rotate else None,
+                norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None, n_kv=n_kv,
             )
         with jax.named_scope(SCOPE_KV_WRITE):
             k_all, v_all = kv_append(
                 k_all, v_all, (ci, lane_idx, ring_at if windowed else positions),
-                k.reshape(b, t, cfg.kv_dim), v.reshape(b, t, cfg.kv_dim), row_major)
+                k.reshape(b, t, k_dim), v.reshape(b, t, v_dim), row_major)
         with jax.named_scope(SCOPE_ATTENTION):
             if windowed:
                 with jax.named_scope(SCOPE_WINDOW_ATTENTION):
-                    attn = window_attention(q, ci, k_all, v_all)
+                    attn = window_attention(q, ci, k_all, v_all, n_kv, sink)
             elif in_place:
                 # the kernel fetches each lane's rows [0, pos] of attention
                 # layer ai out of the carry, AFTER the append
@@ -579,7 +625,10 @@ def hybrid_forward_counted(
             else:
                 attn = dense_plane_attention(
                     q, k_all, v_all, ci, attn_mask, scale, cfg.n_kv_heads)
-            attn = attn.reshape(b, t, cfg.q_dim).astype(dtype)
+            if cfg.attn_value_scale != 1.0:
+                # every value is scaled: by linearity the float32 sum is
+                attn = attn.astype(jnp.float32) * cfg.attn_value_scale
+            attn = attn.reshape(b, t, cfg.o_dim).astype(dtype)
         with jax.named_scope(SCOPE_ATTN_OUT):
             out = maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
             if not cfg.parallel_block:
@@ -658,18 +707,20 @@ def hybrid_forward_counted(
             x = x + res * maybe_qdq(matmul(maybe_qdq((o * gate).astype(dtype)), lp.w_out))
         return x, lin_all
 
-    def window_attention(q, wi, k_all, v_all):
+    def window_attention(q, wi, k_all, v_all, n_kv, sink):
         """Window layer ``wi``'s read of its ring, after the append: the
         decode kernel over the blocks that hold ``(pos - W, pos]``, a key
-        block at a time, or dense under the ring's mask (module header)."""
+        block at a time, or dense under the ring's mask (module header); the
+        sink's column (None: none) joins the softmax in all three."""
         if ring_in_place:
             return pallas_attention.decode_attention(
                 q.reshape(b, cfg.n_heads, cfg.head_size), k_all, v_all, wi,
-                ring_plan, scale, interpret=pallas_interpret())
+                ring_plan, scale, interpret=pallas_interpret(), sink=sink)
         if ring_blocked:
             return blocked_attention.blocked_attention(
-                q, k_all, v_all, wi, positions, n_valid, cfg.n_kv_heads, scale, window=window)
-        return dense_plane_attention(q, k_all, v_all, wi, ring_mask, scale, cfg.n_kv_heads)
+                q, k_all, v_all, wi, positions, n_valid, n_kv, scale, window=window,
+                sink=sink)
+        return dense_plane_attention(q, k_all, v_all, wi, ring_mask, scale, n_kv, sink)
 
     def window_step(w_all, wi, u, taps_k: int):
         """Layer ``wi``'s window of inputs read (zeros where the step starts

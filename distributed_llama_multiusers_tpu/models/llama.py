@@ -106,7 +106,7 @@ from ..ops.linear import (
     reads_q40_stack,
 )
 from ..ops.norm import rms_norm
-from ..ops.rope import apply_rope
+from ..ops.rope import apply_rope, apply_rope_first
 from ..telemetry.names import (
     SCOPE_ATTENTION,
     SCOPE_ATTN_OUT,
@@ -418,10 +418,12 @@ def _moe_ffn(y, yq, lp, act_fn, n_active: int, maybe_qdq, ep_sharded: bool = Fal
     return jnp.einsum("bted,bte->btd", d, rw.astype(d.dtype))
 
 
-def _dense_attention(qf, kf, vf, mask, scale):
+def _dense_attention(qf, kf, vf, mask, scale, sink=None):
     """Single-device GQA attention with materialized scores (reference
     multiheadAtt_F32, src/nn/nn-cpu-ops.cpp:749-784). qf: [B,T,K,G,H] f32;
-    kf/vf: [B,S,K,H] f32; mask: [B,T,S].
+    kf: [B,S,K,H] f32, vf: [B,S,K,Hv] f32; mask: [B,T,S]. ``sink`` [K,G] f32
+    (None: none): a logit a head that joins the softmax as one more column
+    and gives no value.
 
     Who still takes it: ``llama_forward`` and ``models/hybrid.py``'s
     attention layers wherever neither in-place kernel engages (the verify
@@ -433,32 +435,39 @@ def _dense_attention(qf, kf, vf, mask, scale):
     stack."""
     scores = jnp.einsum("btkgh,bskh->btkgs", qf * scale, kf)
     scores = jnp.where(mask[:, :, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sink is not None:
+        column = jnp.broadcast_to(sink[None, None, :, :, None], (*scores.shape[:-1], 1))
+        probs = jax.nn.softmax(jnp.concatenate([scores, column], axis=-1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("btkgs,bskh->btkgh", probs, vf)
 
 
 def gqa_project(cfg: LlamaConfig, yq, wq, wk, wv, positions, rope_cos, rope_sin,
-                biases=(None, None, None), norms=None, project=matmul):
+                biases=(None, None, None), norms=None, project=matmul, n_kv=None):
     """A GQA layer's queries, keys and values from its normed input: the
     three projections (``project``: ``matmul``, or a mesh's sliced matmul),
     their biases where the family has them, the per-head norm of queries and
     keys where it has that (``norms``: the two gains, applied over a head
     BEFORE the rotation), the rotation (none where ``rope_cos`` is None: a
-    model without a positional term), and the barrier that holds the cache
-    back until all three are done. One form for models/llama.py's scan and
+    model without a positional term; the first ``rotary_dim`` numbers of a
+    head alone where the config names that width), and the barrier that holds
+    the cache back until all three are done. ``n_kv``: the kv heads of this
+    layer's kind where they are not ``n_kv_heads``; a value head is
+    ``value_head_size`` wide. One form for models/llama.py's scan and
     models/hybrid.py's attention layers."""
     b, t = positions.shape
-    n_heads, n_kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    n_heads, n_kv, hd = cfg.n_heads, n_kv or cfg.n_kv_heads, cfg.head_size
     q = _maybe_bias(project(yq, wq), biases[0]).reshape(b, t, n_heads, hd)
     k = _maybe_bias(project(yq, wk), biases[1]).reshape(b, t, n_kv, hd)
-    v = _maybe_bias(project(yq, wv), biases[2]).reshape(b, t, n_kv, hd)
+    v = _maybe_bias(project(yq, wv), biases[2]).reshape(b, t, n_kv, cfg.value_head_size)
     if norms is not None:
         q = rms_norm(q, norms[0], cfg.norm_epsilon)
         k = rms_norm(k, norms[1], cfg.norm_epsilon)
 
     if rope_cos is not None:
-        q = apply_rope(q, rope_cos, rope_sin, positions)
-        k = apply_rope(k, rope_cos, rope_sin, positions)
+        q = apply_rope_first(q, cfg.rope_dim, rope_cos, rope_sin, positions)
+        k = apply_rope_first(k, cfg.rope_dim, rope_cos, rope_sin, positions)
     # all three projections finish before the cache is touched. Left
     # to itself XLA schedules the wq kernel between the K plane's read
     # and the scores that use it, the kernel claims the fast memory
@@ -486,17 +495,22 @@ def kv_append(k_all, v_all, at, k, v, row_major=None):
     return k_all, v_all
 
 
-def dense_plane_attention(q, k_all, v_all, l, attn_mask, scale, n_kv: int):
+def dense_plane_attention(q, k_all, v_all, l, attn_mask, scale, n_kv: int, sink=None):
     """GQA attention over layer ``l``'s whole contiguous plane
     (``[B, S, n_kv, hd]``, or with the two last axes merged, as
-    models/hybrid.py keeps 64-wide heads), read out of the carry AFTER the
-    append: the fresh rows are in it. q: ``[B, T, n_heads, hd]``."""
+    models/hybrid.py keeps 64-wide heads; a merged value plane may hold heads
+    of another width), read out of the carry AFTER the append: the fresh rows
+    are in it. q: ``[B, T, n_heads, hd]``; ``sink`` ``[n_heads]``
+    (``_dense_attention``)."""
     b, t, n_heads, hd = q.shape
     qf = q.astype(jnp.float32).reshape(b, t, n_kv, n_heads // n_kv, hd)
     k_cache = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
     v_cache = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
-    planes = (c.astype(jnp.float32).reshape(b, -1, n_kv, hd) for c in (k_cache, v_cache))
-    return _dense_attention(qf, *planes, attn_mask, scale)
+    planes = (c.astype(jnp.float32).reshape(b, c.shape[1], n_kv, -1)
+              for c in (k_cache, v_cache))
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(n_kv, n_heads // n_kv)
+    return _dense_attention(qf, *planes, attn_mask, scale, sink)
 
 
 def decode_attention_engages(cache, mesh, n_heads: int, n_kv: int | None = None) -> bool:
@@ -511,10 +525,11 @@ def decode_attention_engages(cache, mesh, n_heads: int, n_kv: int | None = None)
     engine's counters ask this one question."""
     return (
         not isinstance(cache, PagedKVCache)
-        and cache.k.shape == cache.v.shape
         and mesh is None
         and pallas_kernel_active()
-        and pallas_attention.supports(cache.k, n_heads, n_kv)
+        # (a latent cache's two unlike leaves, or a merged value stack of
+        # another width than the keys': the kernel says which it takes)
+        and pallas_attention.supports(cache.k, n_heads, n_kv, cache.v)
     )
 
 
@@ -534,6 +549,7 @@ def prefill_attention_engages(cache, mesh, b: int, t: int, n_heads: int,
         t > 1
         and pallas_attention.query_rows(t) is not None
         and decode_attention_engages(cache, mesh, n_heads, n_kv)
+        and cache.k.shape == cache.v.shape
         and pallas_attention.supports_prefill(cache.k, n_heads, n_kv)
         and not blocked_attention.engages(b, t, n_heads, cache.k.shape[2])
     )

@@ -111,11 +111,13 @@ def read_m_tensors(path: str, header: ModelHeader) -> dict:
     return w
 
 
-def _rope_cache(config: LlamaConfig):
+def _rope_cache(config: LlamaConfig, theta: float | None = None):
+    """The rotation tables of ``config`` at its base, or at ``theta`` (a layer
+    kind's own)."""
     return build_rope_cache(
         config.seq_len,
         config.rope_dim,
-        config.rope_theta,
+        theta or config.rope_theta,
         config.rope_scaling_factor,
         config.rope_scaling_low_freq_factor,
         config.rope_scaling_high_freq_factor,
@@ -302,9 +304,11 @@ _PATTERN_NAME_MAP = {
     "block_matmul_lin_gate": "lin_gate",
     "block_lin_o_norm": "lin_o_norm",
     "block_matmul_lin_out": "lin_out",
+    # a window layer's learned sink a query head (header.window_sink)
+    "block_attn_sink": "attn_sink",
 }
-_PATTERN_F32 = {"conv_taps", "q_norm", "k_norm", "moe_gate", "moe_bias", "rms_ffn",
-                "dense_rms_ffn", "attn_rms", "conv_rms", "ssm_rms", "ssm_taps",
+_PATTERN_F32 = {"attn_sink", "conv_taps", "q_norm", "k_norm", "moe_gate", "moe_bias",
+                "rms_ffn", "dense_rms_ffn", "attn_rms", "conv_rms", "ssm_rms", "ssm_taps",
                 "ssm_conv_bias", "ssm_dt_norm", "ssm_b_norm", "ssm_c_norm",
                 "ssm_dt_proj", "ssm_dt_bias", "ssm_a_log", "ssm_d",
                 "lin_rms", "lin_q_norm", "lin_k_norm", "lin_o_norm"}
@@ -332,12 +336,22 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
                   for k in config.layer_kinds)
     # a layer's index into its kind's stack
     nth = [sum(k == kinds[l] for k in kinds[:l]) for l in range(len(kinds))]
+    # and into the stacks a window layer has to itself: its sink, and its K/V
+    # projections where the kinds' kv heads differ (the full-context kind's
+    # are then counted apart too)
+    own = [sum(k == config.layer_kinds[l] for k in config.layer_kinds[:l])
+           for l in range(len(kinds))]
 
     def place(spec):
         l = spec.layer
         if spec.name == "block_rms_norm_0":  # the mixer's norm, filed by kind
             return mixer_rms[kinds[l]], (nth[l],)
         key = _PATTERN_NAME_MAP[spec.name]
+        if key == "attn_sink":
+            return key, (own[l],)
+        if key in ("wk", "wv") and config.split_kv_kinds:
+            windowed = config.layer_kinds[l] == LayerKind.WINDOW
+            return key + ("_w" if windowed else ""), (own[l],)
         if key in ("w1", "w2", "w3", "rms_ffn", "moe_gate", "moe_bias",
                    "shared_w1", "shared_w2", "shared_w3"):
             if spec.expert < 0 and l < n_dense:
@@ -349,7 +363,11 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
     if config.rope_type == RopeType.NONE:  # nothing is rotated: no tables
         return config, hybrid_params(t, None, None)
     cos, sin = _rope_cache(config)
-    return config, hybrid_params(t, put("rope_cos", cos), put("rope_sin", sin))
+    window_rope = ()
+    if config.window_rope_theta:  # the window kind rotates at a base of its own
+        cos_w, sin_w = _rope_cache(config, config.window_rope_theta)
+        window_rope = (put("rope_cos_w", cos_w), put("rope_sin_w", sin_w))
+    return config, hybrid_params(t, put("rope_cos", cos), put("rope_sin", sin), *window_rope)
 
 
 def load_params_from_m(

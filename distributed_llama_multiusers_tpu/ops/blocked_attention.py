@@ -103,7 +103,7 @@ def chunk_blocks(first: int, last: int, ring: int, window: int = 0,
 
 def blocked_attention(q, k_all, v_all, layer, positions, n_valid, n_kv: int,
                       scale: float, window: int = 0, block: int = BLOCK_KEYS,
-                      chosen=None, chosen_block: int = 0):
+                      chosen=None, chosen_block: int = 0, sink=None):
     """GQA attention of ``q`` ``[B, T, n_heads, hd]`` over layer ``layer`` of
     the stacks ``k_all`` / ``v_all`` ``[L, B, R, n_kv * hd]`` (a plane, ``R =
     seq_len``, or a ring), read AFTER the chunk's rows were written.
@@ -111,10 +111,14 @@ def blocked_attention(q, k_all, v_all, layer, positions, n_valid, n_kv: int,
     (the others compute nothing anyone reads and bound no loop). ``chosen``
     ``[B, T, n_kv, S / chosen_block]`` (a block-sparse layer, ops/block_sparse.py):
     the blocks of ``chosen_block`` positions a row's kv head reads, beside the
-    causal mask; a key block holds whole such blocks. Returns
-    ``[B, T, n_heads, hd]`` float32."""
+    causal mask; a key block holds whole such blocks. The value stack may hold
+    heads of another width ``vd`` (``[L, B, R, n_kv * vd]``). ``sink``
+    ``[n_heads]`` float32 (None: none): a logit a head that joins the softmax
+    as one more column and gives no value; the running maximum starts at it
+    and the sum at 1. Returns ``[B, T, n_heads, vd]`` float32."""
     b, t, n_heads, hd = q.shape
-    ring, kv_dim = k_all.shape[2], k_all.shape[3]
+    ring, kv_dim, v_dim = k_all.shape[2], k_all.shape[3], v_all.shape[3]
+    vd = v_dim // n_kv
     group = n_heads // n_kv
     block = min(block, ring)
     q5 = q.astype(k_all.dtype).reshape(b, t, n_kv, group, hd)
@@ -133,9 +137,9 @@ def blocked_attention(q, k_all, v_all, layer, positions, n_valid, n_kv: int,
         # the rows it shares with the block before it are left out
         start = jnp.minimum(j * block, ring - block)
         kb = jax.lax.dynamic_slice(k_all, (layer, 0, start, 0), (1, b, block, kv_dim))
-        vb = jax.lax.dynamic_slice(v_all, (layer, 0, start, 0), (1, b, block, kv_dim))
+        vb = jax.lax.dynamic_slice(v_all, (layer, 0, start, 0), (1, b, block, v_dim))
         kb = kb.reshape(b, block, n_kv, hd)
-        vb = vb.reshape(b, block, n_kv, hd)
+        vb = vb.reshape(b, block, n_kv, vd)
         s = jnp.einsum("btkgh,bskh->btkgs", q5, kb,
                        preferred_element_type=jnp.float32) * scale
         rows = start + jnp.arange(block, dtype=jnp.int32)
@@ -159,11 +163,15 @@ def blocked_attention(q, k_all, v_all, layer, positions, n_valid, n_kv: int,
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    init = (jnp.full(stat, -jnp.inf, jnp.float32), jnp.zeros(stat, jnp.float32),
-            jnp.zeros((*stat, hd), jnp.float32))
+    if sink is None:
+        m0, l0 = jnp.full(stat, -jnp.inf, jnp.float32), jnp.zeros(stat, jnp.float32)
+    else:  # the sink's column, met first
+        m0 = jnp.broadcast_to(sink.astype(jnp.float32).reshape(n_kv, group), stat)
+        l0 = jnp.ones(stat, jnp.float32)
+    init = (m0, l0, jnp.zeros((*stat, vd), jnp.float32))
     _, l, acc = jax.lax.fori_loop(j0, j1, visit, init)
     out = jnp.where(l[..., None] > 0.0, acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
-    return out.reshape(b, t, n_heads, hd)
+    return out.reshape(b, t, n_heads, vd)
 
 
 def chunk_block_counts(start: int, n_rows: int, bucket: int, ring: int, window: int = 0,

@@ -72,6 +72,16 @@ position of an item's first row and the oldest position the lane reads. The
 kernel is the same; which list it was handed is a static fact of the plan's
 shape, and a full-context layer's program is what it was.
 
+A sink, and values of another width. A window layer may have a learned logit
+a query head that joins its softmax as one more column and gives no value
+(``model_type: mimo_v2_flash``): the lane's running maximum then starts at the
+sink's logit and its sum at 1 where they otherwise start at ``-inf`` and 0, and
+nothing else changes: the column is met first, exactly. A merged value stack
+may hold heads narrower than the keys' (192-wide keys beside 128-wide values):
+the K and V blocks, the output and the accumulator take their own widths, and
+a key head that straddles 128-lane tiles costs nothing here, since the
+block-diagonal product runs over the whole row.
+
 Chosen blocks. A block-sparse layer (models/hybrid.py, ops/block_sparse.py)
 reads, for every (lane, kv head), the blocks of ``block_size`` positions (64
 as published) that the step chose from its compressed keys' scores: a third
@@ -146,28 +156,36 @@ from jax.experimental.pallas import tpu as pltpu
 # (n_kv 8 / group 4 at 16 lanes, n_kv 4 / group 7 at 32): PERF.md section 6
 BLOCK_ROWS = 256
 HEAD_SIZE = 128  # one lane tile: the scratch statistics are [heads, 128]
-# the widest merged row taken: a K block of it is the 128-wide form's largest
-# compiled one (8 kv heads a position, 512 KB)
-MAX_ROW_WIDTH = 1024
+# the widest merged row taken: 8 kv heads of 192 a position, a K block of 768
+# KB (compiled for a v5e and run there, PR 54; 1024 before: the 128-wide
+# form's largest block)
+MAX_ROW_WIDTH = 1536
 # an item's code in the work list (``lane_blocks``)
 FULL, LAST, FIRST, FINAL = 1, 2, 4, 8
 
 
-def supports(k_all, n_heads: int, n_kv: int | None = None) -> bool:
+def supports(k_all, n_heads: int, n_kv: int | None = None, v_all=None) -> bool:
     """Whether the kernel takes this cache: a bf16 stack whose context is
     whole blocks, query heads a multiple of kv heads, and either form of the
     module header: ``[L, lanes, S, n_kv, HEAD_SIZE]``, or ``[A, lanes, S,
     n_kv * hd]`` whose rows are whole 128-lane tiles of the caller's ``n_kv``
-    heads (a merged row does not say how many heads it holds)."""
+    heads (a merged row does not say how many heads it holds). ``v_all``: the
+    value stack where it may differ from the keys' (None: of ``k_all``'s
+    shape): merged rows of another width (a value head narrower than a key
+    head) are taken, under the same rule; anything else unlike the keys is
+    not."""
     if k_all.dtype != jnp.bfloat16 or k_all.ndim not in (4, 5):
+        return False
+    if v_all is not None and v_all.shape != k_all.shape and (
+            k_all.ndim != 4 or v_all.dtype != k_all.dtype or v_all.shape[:3] != k_all.shape[:3]):
         return False
     if k_all.ndim == 5:
         n_kv = k_all.shape[3]
         tiled = k_all.shape[4] == HEAD_SIZE
     else:
-        width = k_all.shape[3]
-        tiled = bool(n_kv) and (
-            width % HEAD_SIZE == 0 and width % n_kv == 0 and width <= MAX_ROW_WIDTH)
+        tiled = bool(n_kv) and all(
+            width % HEAD_SIZE == 0 and width % n_kv == 0 and width <= MAX_ROW_WIDTH
+            for width in {k_all.shape[3], k_all.shape[3] if v_all is None else v_all.shape[3]})
     return tiled and k_all.shape[2] % BLOCK_ROWS == 0 and n_heads % n_kv == 0
 
 
@@ -285,10 +303,14 @@ def _own_columns(n_heads: int, heads_pad: int, n_kv: int) -> np.ndarray:
     return own[:, :, None]
 
 
-def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_pos):
+def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_pos,
+                             biased, sunk):
     del layer_ref  # spent in the index maps
-    # the head bias goes in with 128-wide heads only (module header)
-    bias_ref = refs[0] if len(refs) == 7 else None
+    # the head bias goes in with 128-wide heads only (module header); the
+    # sink with a layer that has one ("A sink")
+    extra = list(refs[:-6])
+    bias_ref = extra.pop(0) if biased else None
+    sink_ref = extra.pop(0) if sunk else None
     k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-6:]
     w = pl.program_id(0)
     block_index, pos, code = plan_ref[2, w], plan_ref[3, w], plan_ref[4, w]
@@ -300,12 +322,20 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
 
     @pl.when(code & FIRST != 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink_ref is None:
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+        else:
+            # the sink is one more column of the softmax, met first: the
+            # running maximum starts at its logit and the sum at exp(0); it
+            # gives no value (a padding head's is -inf and sums nothing)
+            sink = sink_ref[...]
+            m_ref[...] = sink
+            l_ref[...] = jnp.where(sink > -jnp.inf, 1.0, 0.0)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def block(last: bool):
-        k, v = k_ref[...], v_ref[...]  # [rows, width]
+        k, v = k_ref[...], v_ref[...]  # [rows, key width], [rows, value width]
         s = jax.lax.dot_general(
             q_ref[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -348,26 +378,31 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
     @pl.when(code & FINAL != 0)
     def _():
         l = across(l_ref[...])
-        # a parked lane summed nothing: zeros, and no division by its sum
+        # a parked lane summed nothing: zeros, and no division by its sum (a
+        # sink's lane summed the sink alone: zeros over one)
         o_ref[...] = jnp.where(l > 0.0, acc_ref[...] / l, 0.0)
 
 
 def decode_attention(q, k_all, v_all, layer, work, scale: float,
-                     interpret: bool = False) -> jnp.ndarray:
+                     interpret: bool = False, sink=None) -> jnp.ndarray:
     """One query row a lane against layer ``layer`` of the stacked cache.
 
     q ``[lanes, n_heads, hd]`` (head ``h * group + g`` reads kv head ``h``);
     ``k_all`` / ``v_all`` ``[L, lanes, S, n_kv, hd]`` or ``[A, lanes, S, n_kv *
     hd]`` (``supports``) as the layer loop carries them, the lanes' fresh rows
-    already appended; ``work`` from ``lane_blocks``, or from ``ring_blocks``
-    where the stack is a window layer's ring. Returns ``[lanes, n_heads, hd]``
+    already appended; a merged value stack may hold heads of another width
+    ``vd`` (``[A, lanes, S, n_kv * vd]``); ``work`` from ``lane_blocks``, or
+    from ``ring_blocks`` where the stack is a window layer's ring. ``sink``
+    ``[n_heads]`` float32 (None: none): a logit a head that joins the softmax
+    as one more column and gives no value. Returns ``[lanes, n_heads, vd]``
     float32; a lane's result depends on that lane's rows ``[0, pos]`` alone
     (a ring: on the rows that hold ``(pos - window, pos]``)."""
     n_heads, hd = q.shape[1:]
     n_layers, lanes, seq_len = k_all.shape[:3]
     merged = k_all.ndim == 4  # one row a position, every kv head in it
-    width = k_all.shape[-1]
+    width, v_width = k_all.shape[-1], v_all.shape[-1]
     n_kv = width // hd if merged else k_all.shape[3]
+    vd = v_width // n_kv if merged else hd
     rows_per_pos = 1 if merged else n_kv
     n_items, plan = work
     heads_pad = -(-n_heads // 16) * 16  # whole bf16 sublane tiles
@@ -377,40 +412,51 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
         # block-diagonal queries: a head's values in its kv head's columns
         own = _own_columns(n_heads, heads_pad, n_kv)
         q = jnp.where(own, q[:, :, None, :], 0).reshape(lanes, heads_pad, width)
-    # (S, n_kv) of 128-wide heads merged: a bitcast; a merged stack as it is
-    flat = (n_layers, lanes, seq_len * rows_per_pos, width)
 
-    kv_spec = pl.BlockSpec(
-        (None, None, rows, width),
-        lambda w, layer_ref, plan_ref: (layer_ref[0], plan_ref[1, w], plan_ref[2, w], 0),
-    )
-    lane_spec = pl.BlockSpec(
-        (None, heads_pad, width), lambda w, layer_ref, plan_ref: (plan_ref[0, w], 0, 0)
-    )
-    bias = () if merged else (_head_bias(n_heads, heads_pad, n_kv, rows),)
-    bias_spec = [pl.BlockSpec((heads_pad, rows), lambda w, *_: (0, 0))] * len(bias)
+    def stack_spec(w_):  # a block of a stack whose rows are w_ wide
+        return pl.BlockSpec(
+            (None, None, rows, w_),
+            lambda w, layer_ref, plan_ref: (layer_ref[0], plan_ref[1, w], plan_ref[2, w], 0))
+
+    def lane_spec(w_):
+        return pl.BlockSpec(
+            (None, heads_pad, w_), lambda w, layer_ref, plan_ref: (plan_ref[0, w], 0, 0))
+
+    extra, extra_spec = [], []
+    if not merged:
+        extra.append(_head_bias(n_heads, heads_pad, n_kv, rows))
+        extra_spec.append(pl.BlockSpec((heads_pad, rows), lambda w, *_: (0, 0)))
+    if sink is not None:
+        # a lane tile wide, as the running maximum it starts is kept
+        sunk = jnp.pad(sink.astype(jnp.float32), (0, heads_pad - n_heads),
+                       constant_values=-jnp.inf)
+        extra.append(jnp.broadcast_to(sunk[:, None], (heads_pad, HEAD_SIZE)))
+        extra_spec.append(pl.BlockSpec((heads_pad, HEAD_SIZE), lambda w, *_: (0, 0)))
     out = pl.pallas_call(
-        partial(_decode_attention_kernel, scale=scale, rows_per_pos=rows_per_pos),
+        partial(_decode_attention_kernel, scale=scale, rows_per_pos=rows_per_pos,
+                biased=not merged, sunk=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # the layer index and the work list
             grid=(n_items,),
-            in_specs=[lane_spec, *bias_spec, kv_spec, kv_spec],
-            out_specs=lane_spec,
+            in_specs=[lane_spec(width), *extra_spec, stack_spec(width), stack_spec(v_width)],
+            out_specs=lane_spec(v_width),
             scratch_shapes=[pltpu.VMEM((heads_pad, HEAD_SIZE), jnp.float32)] * 2
-            + [pltpu.VMEM((heads_pad, width), jnp.float32)],
+            + [pltpu.VMEM((heads_pad, v_width), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, width), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, v_width), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         name="decode_attention",
         interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), plan, q, *bias,
-      k_all.reshape(flat), v_all.reshape(flat))
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan, q, *extra,
+      # (S, n_kv) of 128-wide heads merged: a bitcast; a merged stack as it is
+      k_all.reshape(n_layers, lanes, seq_len * rows_per_pos, width),
+      v_all.reshape(n_layers, lanes, seq_len * rows_per_pos, v_width))
     if merged:
         # a head keeps its kv head's columns of the value product: a select
         # and a sum with exact zeros, no product
-        out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, hd), 0.0).sum(axis=2)
+        out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, vd), 0.0).sum(axis=2)
     return out[:, :n_heads]
 
 
@@ -438,7 +484,9 @@ def supports_prefill(k_all, n_heads: int, n_kv: int | None = None) -> bool:
         return False
     if k_all.ndim == 5:
         return k_all.shape[3] % 2 == 0
-    return HEAD_SIZE % (k_all.shape[3] // n_kv) == 0  # heads do not straddle a column tile
+    # heads do not straddle a column tile (a 192-wide key does: such a chunk
+    # is read by ops/blocked_attention.py or dense)
+    return HEAD_SIZE % (k_all.shape[3] // n_kv) == 0
 
 
 def chunk_blocks(positions: jnp.ndarray, n_valid: jnp.ndarray, seq_len: int, rows: int):

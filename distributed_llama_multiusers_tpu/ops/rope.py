@@ -119,3 +119,13 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, positions: jn
     r1 = x0 * s + x1 * c
     out = jnp.stack([r0, r1], axis=-1).reshape(b, t, h, d)
     return out.astype(x.dtype)
+
+
+def apply_rope_first(x: jnp.ndarray, n: int, cos: jnp.ndarray, sin: jnp.ndarray,
+                     positions: jnp.ndarray) -> jnp.ndarray:
+    """``apply_rope`` on the first ``n`` numbers of every head of ``x``
+    ``[B, T, H, D]`` (``cos`` / ``sin`` ``[seq_len, n // 2]``), the rest as
+    they are; the whole head where ``n`` is its width."""
+    if n >= x.shape[-1]:
+        return apply_rope(x, cos, sin, positions)
+    return jnp.concatenate([apply_rope(x[..., :n], cos, sin, positions), x[..., n:]], axis=-1)

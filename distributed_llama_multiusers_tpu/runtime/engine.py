@@ -436,6 +436,12 @@ class EngineStats:
     attn_window_rows_read: int = 0
     attn_full_rows_read: int = 0
     attn_window_rows_plane: int = 0
+    # and the least either kind's read needs, by the same arithmetic: the rows
+    # a live lane's step attends, `pos + 1` of a full-context layer's plane and
+    # `min(pos + 1, window)` of a window layer's ring (whole blocks are what
+    # the `*_rows_read` pair above counts)
+    attn_full_rows_needed: int = 0
+    attn_window_rows_needed: int = 0
     prefill_attn_blocks_visited: int = 0
     prefill_attn_blocks_causal: int = 0
     # compile stability (analysis/jitcheck.py, ISSUE 15): XLA backend
@@ -488,6 +494,7 @@ class EngineStats:
             "attn_blocks_held",
             "sparse_lane_steps",
             "attn_window_rows_read", "attn_full_rows_read", "attn_window_rows_plane",
+            "attn_full_rows_needed", "attn_window_rows_needed",
             "prefill_attn_blocks_visited", "prefill_attn_blocks_causal",
             "jit_compiles_after_warmup",
         ),
@@ -538,6 +545,7 @@ class EngineStats:
             self.attn_blocks_held = self.sparse_lane_steps = 0
             self.attn_window_rows_read = self.attn_full_rows_read = 0
             self.attn_window_rows_plane = 0
+            self.attn_full_rows_needed = self.attn_window_rows_needed = 0
             self.prefill_attn_blocks_visited = self.prefill_attn_blocks_causal = 0
             # per-decode sync_* stay: they describe the compiled program,
             # not a window; jit_compiles_after_warmup stays: it describes
@@ -804,7 +812,8 @@ class InferenceEngine:
         self.ring_rows = self.cache.wk.shape[2] if config.n_window_layers else 0
         self.decode_ring_block = (
             pallas_attention.BLOCK_ROWS
-            if ring_attention_engages(self.cache, mesh, config.n_heads, config.n_kv_heads)
+            if ring_attention_engages(
+                self.cache, mesh, config.n_heads, config.kv_heads(windowed=True))
             else None
         )
         # the start from which a prompt chunk takes the second-largest bucket
@@ -1792,6 +1801,19 @@ class InferenceEngine:
                 kv_ring_bytes=self.cache.wk.nbytes + self.cache.wv.nbytes,
                 kv_plane_bytes=self.cache.k.nbytes + self.cache.v.nbytes,
             )
+            if cfg.split_kv_kinds or cfg.window_sink or cfg.value_head_size != cfg.head_size:
+                # heads that differ by kind: the decode path and a cached
+                # position's (key, value) row widths, a kind
+                facts.update(
+                    attention_path_by_kind={
+                        "full": "pallas_in_place" if self.decode_attention_block is not None
+                        else "xla_dense",
+                        "window": "pallas_in_place" if self.decode_ring_block is not None
+                        else "xla_dense"},
+                    kv_row_widths_by_kind={
+                        "full": list(cfg.kv_widths()), "window": list(cfg.kv_widths(True))},
+                    window_sink=cfg.window_sink == 1,
+                )
         if cfg.n_linear_layers:
             facts.update(
                 linear_attention_layers=cfg.n_linear_layers,

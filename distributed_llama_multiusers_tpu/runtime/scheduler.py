@@ -950,8 +950,16 @@ class ContinuousBatchingScheduler:
         # a window layer's ring, in rows of one such layer: what its read
         # fetched. What a plane read as the full-context kind's would have
         # fetched is `read` itself (the pair above stays that kind's)
-        ring_read = 0
+        ring_read = full_needed = window_needed = 0
+        at = None
+        if self._window or self._linear_step_bytes or self._sparse_sizes:
+            # dlint: ok[host-sync] the host's own lane positions (numpy ints), no device value
+            at = np.asarray(positions, np.int64)[:, None] + np.arange(steps)[None, :]
+            at = at[at < seq_len]  # a lane's steps inside the context
         if self._window:
+            # the rows either kind's read attends: pos + 1 and min(pos + 1, W)
+            full_needed = int((at + 1).sum())
+            window_needed = int(np.minimum(at + 1, self._window).sum())
             ring_block = getattr(engine, "decode_ring_block", None)
             ring_read = (
                 len(positions) * getattr(engine, "ring_rows", 0) * steps if ring_block is None
@@ -959,9 +967,6 @@ class ContinuousBatchingScheduler:
                          for s in range(steps)))
         blocks_read = blocks_held = choosing = live_steps = 0
         if self._linear_step_bytes or self._sparse_sizes:
-            # dlint: ok[host-sync] the host's own lane positions (numpy ints), no device value
-            at = np.asarray(positions, np.int64)[:, None] + np.arange(steps)[None, :]
-            at = at[at < seq_len]  # a lane's steps inside the context
             live_steps = int(at.size)
             if self._sparse_sizes:
                 attended, held = blocks_attended(at, self._sparse_sizes)
@@ -981,6 +986,8 @@ class ContinuousBatchingScheduler:
                 engine.stats.attn_window_rows_read += ring_read
                 engine.stats.attn_full_rows_read += read
                 engine.stats.attn_window_rows_plane += read
+                engine.stats.attn_full_rows_needed += full_needed
+                engine.stats.attn_window_rows_needed += window_needed
 
     def occupancy(self) -> tuple[int, int]:
         """(busy lanes, total lanes) — public surface for /stats."""

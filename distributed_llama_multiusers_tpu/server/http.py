@@ -449,6 +449,9 @@ class ApiServer:
             "attn_window_rows_read": stats["attn_window_rows_read"],
             "attn_full_rows_read": stats["attn_full_rows_read"],
             "attn_window_rows_plane": stats["attn_window_rows_plane"],
+            # the rows either kind's reads attend (pos + 1; min(pos + 1, W))
+            "attn_full_rows_needed": stats["attn_full_rows_needed"],
+            "attn_window_rows_needed": stats["attn_window_rows_needed"],
             "prefill_attn_blocks_visited": stats["prefill_attn_blocks_visited"],
             "prefill_attn_blocks_causal": stats["prefill_attn_blocks_causal"],
             # a routed FFN's reads of its expert stacks over the decode

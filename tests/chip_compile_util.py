@@ -174,12 +174,14 @@ CELL_SHAPES = [
     (2560, 128, True), (2560, 2560, True), (2560, 8192, True),
     (2560, 10240, True), (2560, 65536, False), (3584, 512, True),
     (3584, 3584, True), (3584, 18944, True), (3584, 152064, False),
-    (4096, 256, True), (4096, 1024, True), (4096, 2048, True), (4096, 4096, True),
-    (4096, 14336, True), (4096, 16384, True), (4096, 32768, False), (4096, 73728, False),
+    (4096, 256, True), (4096, 512, True), (4096, 768, True), (4096, 1024, True),
+    (4096, 1536, True), (4096, 2048, True), (4096, 4096, True), (4096, 12288, True),
+    (4096, 14336, True), (4096, 16384, True), (4096, 19072, False), (4096, 32768, False),
+    (4096, 73728, False),
     (5120, 192, True),
     (5120, 2560, True), (6144, 2048, True), (7168, 128, True),
     (7168, 576, True), (7168, 1536, True), (7168, 2048, True),
-    (7168, 16384, False), (7168, 18432, True), (8192, 2560, True),
+    (7168, 16384, False), (7168, 18432, True), (8192, 2560, True), (8192, 4096, True),
     (11776, 2048, True), (14336, 4096, True), (16384, 4096, True), (16384, 7168, True),
     (18432, 7168, True), (18944, 3584, True),
 ]

@@ -40,3 +40,28 @@ globals().update(
     {name: obj for name, obj in vars(_cases).items()
      if name.startswith("test_") or name == "recorded"}
 )
+
+
+def test_benchmark_json_lists_every_new_metric_with_its_cells():
+    """The case of that name, which holds PR 52's six metrics to the LAST six
+    entries of ``per_layer``: a later PR's metrics come after them (PR 54's
+    four do: new entries go at the end of their lists), and a file under
+    ``benchmarks/`` is a benchmark PR's to edit (PERF.md, open questions).
+    Held here: the six are there, in their order and next to one another, with
+    the lists the case asks of them."""
+    from harness.cells import cell_metrics, load_benchmark
+
+    bench = load_benchmark()
+    six = ["top_rung_step_ms", "fused_step_late_share", "dry_dispatch_share",
+           "device_starved_share", "lane_fill_share", "device_idle_largest_gap_ms"]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(six[0])
+    assert names[at:at + 6] == six
+    new = {m["name"]: m for m in bench["per_layer"][at:at + 6]}
+    every = [w["name"] for w in bench["workloads"]]
+    saturated = [w for w in every if w.endswith("_saturated")]
+    assert new["fused_step_late_share"]["workloads"] == every
+    for name in six[2:]:
+        assert new[name]["workloads"] == saturated and new[name]["moves"] == "tokens_per_s"
+    steady = {m["name"] for m in cell_metrics(bench, "mistral7b_chat_steady", "per_layer")}
+    assert steady & set(new) == {"fused_step_late_share"}

@@ -1240,6 +1240,9 @@ OFFSET_PLANS = {
     "k_chunks_and_wide_tiles": (512, 16384),
     "block_sum_slices": (7168, 128),   # the whole half one chunk: 4 slices
     "k_chunks_of_slices": (7168, 576),  # DeepSeek's wkva: two chunks of two
+    # a head whose width only 128 divides (MiMo's vocabulary slice, 19072 =
+    # 149 x 128, which padding to 8192s would grow by 29 %): 67 tiles here
+    "wide_tiles_of_slices": (2304, 8576),
 }
 
 
