@@ -275,6 +275,10 @@ def load_stack(args, n_lanes: int | None = None):
         # agree — the slab arrays are compiled-program operands
         grammar_slab_states=getattr(args, "grammar_slab_states", None),
     )
+    # the tree the engine serves from: where it made the kernel's form of a
+    # Q40 scale stack (quants/packed.py ``q40_at_rest``) the loader's float16
+    # copy is dropped here and not held beside it for the process's life
+    params = engine.params
     if engine.kvpool is not None:
         log(
             "📑",

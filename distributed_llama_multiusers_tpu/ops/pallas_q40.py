@@ -11,7 +11,18 @@ the main single-chip throughput lever.
 Layout (quants/packed.py): block-local nibble halves — each 32-input quant
 block is 16 consecutive packed rows (low nibble = block inputs [0,16), high
 nibble = [16,32)) + 1 scale row, so a chunk of whole blocks covers the same
-contiguous input range in `packed`, `scales`, and `x`.
+contiguous input range in `packed`, `scales`, and `x`. The kernel takes the
+scales as the int16 bits of their float16 values (Mosaic takes no f16
+operand). Since PR 55 a stack that RESTS so (``q40_at_rest``) has layer l's
+scale tiles read out of it by the index maps, exactly as the nibbles are,
+wherever XLA cannot stage the stack whole (``reads_scales_in_place``: a 7B
+model's FFN stacks, two thirds of the scales' bytes; the engine asks the same
+predicate which leaves to convert, once, and is the one place that does).
+Every other call has its layer's plane sliced out first, and converted where
+it is float16, as every call had until then (the FFN's three converts were
+0.55 ms of a 13.7 ms Mistral decode step).
+``TRACE_STATS["scale_stack_reads"]`` counts the bodies that read in place,
+``["scale_converts"]`` those fed by a converted plane.
 
 Kernel formulation: the low/high nibble planes are split off the packed
 bytes, interleaved by whole 16-row pieces into the input's own column order
@@ -159,6 +170,14 @@ TRACE_STATS = {
     # slab-chain kernel bodies traced with the -8 subtracted in the dequant
     # chain and NO correction dot (blocks of SUBTRACT_MIN_ROWS rows and more)
     "offset_subtracted_traces": 0,
+    # kernel-body traces whose scale operand was the weight's own int16 plane
+    # or stack, addressed in place (``reads_scales_in_place`` says which stacks)
+    "scale_stack_reads": 0,
+    # kernel-body traces fed by a FLOAT16 scale plane sliced out and converted
+    # before the call (the parent's program for that call). An engine's every
+    # body is one or the other; a small stack that a direct caller hands over
+    # as bits all the same has its plane sliced out as bits, and is neither
+    "scale_converts": 0,
 }
 
 
@@ -192,6 +211,7 @@ ROW_ALIGN = 8  # x rows padded to whole sublane tiles: 8 rows of 4-byte words
 # (``_vmem_limit``); the blocks themselves never pass this limit
 # (``_row_plan``), so no call asks for more than 80 MiB.
 VMEM_LIMIT_BYTES = 64 << 20
+VMEM_BYTES = 128 << 20  # a TensorCore's fast memory (v5e; ``reads_scales_in_place``)
 VMEM_HEADROOM = 16 << 20  # the body's transients beside the pipelined blocks
 
 
@@ -682,7 +702,8 @@ def q40_matmul_pallas(x: jnp.ndarray, w: PackedQ40, interpret: bool = False,
 
     ``layer``: with a STACKED weight (planes ``[L, d_in//2, d_out]`` and
     ``[L, d_in//32, d_out]``) the int32 index of the layer to multiply by,
-    traced or not. The kernel's weight blocks are then addressed
+    traced or not. The kernel's weight blocks (the nibbles', and the scales'
+    where a stack too large to stage rests as int16 bits) are then addressed
     ``(layer, k, j)`` inside the stack, so a layer scan hands the whole
     stack over as a loop invariant and no copy of the plane is made for
     the call (a Pallas call is opaque to XLA and gets its operands
@@ -720,6 +741,32 @@ def _q40_matmul_pallas_impl(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
     return _q40_matmul_core(x, w, interpret, w_dtype, mode, layer)
 
 
+def reads_scales_in_place(scales) -> bool:
+    """Whether a layer's kernel call addresses its scale tiles inside this
+    ``[L, d_in//32, d_out]`` stack, once the stack rests as int16 bits, and so
+    which leaves ``InferenceEngine`` converts when it takes its weights: a
+    stack of more than one layer that XLA cannot hold whole in fast memory
+    beside the kernel's own scoped VMEM. A Pallas call is opaque to XLA: its
+    memory-space assignment sees the operand and not the index maps, and
+    where the operand fits it copies ALL of it into fast memory ahead of the
+    call, inside the layer loop, for the one plane the call reads
+    (``pltpu.with_memory_space_constraint`` does not stop it, PR 30). That
+    traffic is not free (chip, PR 55, every stack read in place against the
+    parent: DeepSeek-V3.2's decode step 14.28 -> 14.81 ms, its grouped expert
+    kernel 2.53 -> 3.21 ms beside 89 MB of staging a layer; Command A+'s
+    expert kernel 6.60 -> 6.96 ms; it hid only where the kernels leave HBM
+    bandwidth over, Qwen2.5-7B 16.35 -> 16.21). So such a stack's plane is
+    sliced out a call whatever form the leaf is in (the slice is what costs,
+    not the convert: the same 0.05-0.07 ms a 7B step for wq's plane either
+    way, PERF.md section 5), the leaf stays as it arrived, and its programs
+    are the ones they were. A stack over the line (the FFN scale stacks of a
+    7B model are 117 MB each, MiniCPM-SALA's 134) is read in place. A call
+    whose own limit is raised (``_vmem_limit``) leaves XLA less than the line
+    assumes, so what is over it can be staged beside no call."""
+    return (scales.ndim == 3 and scales.shape[0] > 1
+            and scales.size * scales.dtype.itemsize > VMEM_BYTES - VMEM_LIMIT_BYTES)
+
+
 def _q40_matmul_kernel(layer_ref, *refs, body):
     """Every mode's kernel behind the scalar-prefetch operand: the layer
     index is spent in the weight BlockSpecs' index maps, before the body."""
@@ -736,10 +783,7 @@ def _q40_matmul_core(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
         # the same pallas_call as a layer of a real stack
         packed = packed[None]
         layer = jnp.zeros((), jnp.int32)
-    elif packed.ndim == 3 and layer is not None:
-        # the scales (a ninth of the bytes) are still sliced out: see below
-        scales = jax.lax.dynamic_index_in_dim(scales, layer, 0, keepdims=False)
-    else:
+    elif packed.ndim != 3 or layer is None:
         raise ValueError(
             f"expected a 2D packed weight, or a [L, ...] stack and its layer "
             f"index; got {packed.shape} and layer={layer!r}"
@@ -771,12 +815,28 @@ def _q40_matmul_core(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
     grid = (m_pad // m_block, d_out // w_tile, n_k)
 
     # Mosaic has no f16 type, so the kernel takes the scales' bit patterns.
-    # For XLA:TPU f16 -> s16 is not a relabelling but a pass over the data:
-    # on a whole stack it is hoisted out of the layer loop and costs the
-    # program the stack's size in temporaries and its read and write every
-    # step (441 MB at 7B widths, compiled for a v5e, PR 30). Fused with the
-    # slice of ONE layer's scale plane it costs that plane's read, as ever.
-    scale_bits = jax.lax.bitcast_convert_type(scales, jnp.int16)
+    # A stack at rest (``quants.packed.q40_at_rest``) holds them so, and layer
+    # l's scale tiles are addressed inside it like its nibbles: no slice, no
+    # convert, no temporary. That holds for a stack XLA cannot hold in fast
+    # memory (``reads_scales_in_place``); of any other the plane is sliced out,
+    # and converted where it is still float16: for XLA:TPU f16 -> s16 is not
+    # a relabelling but a pass over the data (on a whole stack it would be
+    # hoisted out of the layer loop: 441 MB of temporaries at 7B widths,
+    # PR 30), fused with the slice of ONE plane it costs that plane's read,
+    # as it did for every call before PR 55. A 2-D plane is its own operand.
+    at_rest = scales.dtype == jnp.int16
+    in_stack = at_rest and reads_scales_in_place(scales)
+    TRACE_STATS["scale_stack_reads"] += int(at_rest and (in_stack or scales.ndim == 2))
+    TRACE_STATS["scale_converts"] += int(not at_rest)
+    if in_stack:
+        scale_spec = pl.BlockSpec((None, rows // 16, w_tile),
+                                  lambda i, j, k, l: (l[0], k, j))
+    else:
+        if scales.ndim == 3:
+            scales = jax.lax.dynamic_index_in_dim(scales, layer, 0, keepdims=False)
+        if not at_rest:
+            scales = jax.lax.bitcast_convert_type(scales, jnp.int16)
+        scale_spec = pl.BlockSpec((rows // 16, w_tile), lambda i, j, k, l: (k, j))
 
     # index maps take the grid position and then the scalar-prefetch ref
     if mode in BLOCK_DOT_MODES:
@@ -815,7 +875,7 @@ def _q40_matmul_core(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
                 # leading block dimension is squeezed: the body sees [rows, W]
                 pl.BlockSpec((None, rows, w_tile),
                              lambda i, j, k, l: (l[0], k, j)),
-                pl.BlockSpec((rows // 16, w_tile), lambda i, j, k, l: (k, j)),
+                scale_spec,  # its scale tiles: in the stack too, or its plane's
             ],
             out_specs=pl.BlockSpec((m_block, w_tile),
                                    lambda i, j, k, l: (i, j)),
@@ -838,7 +898,7 @@ def _q40_matmul_core(x: jnp.ndarray, w: PackedQ40, interpret, w_dtype,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(layer.reshape(1), *x_ops, packed, scale_bits)
+    )(layer.reshape(1), *x_ops, packed, scales)
 
     return out[:math.prod(lead)].reshape(*lead, d_out)
 

@@ -14,8 +14,17 @@ src/nn/nn-quants.hpp:64-67):
     packed: uint8 [..., d_in//2, d_out]
         row r = (b, j) with b = r // 16, j = r % 16:
         packed[r, o] = (v[32b + j, o] + 8) | ((v[32b + j + 16, o] + 8) << 4)
-    scales: float16 [..., d_in//32, d_out]
+    scales: float16 [..., d_in//32, d_out], or the same bits as int16
         scales[b, o] covers input rows i in [32b, 32b+32)
+
+Scales REST on the device as int16, the float16 values' bit patterns, where
+the Pallas kernel reads a layer's scale tiles out of the stack in place: that
+is the form it takes (Mosaic has no f16 type, and for XLA:TPU f16 -> s16 is a
+pass over the data, not a relabelling). What makes them so is ``q40_at_rest``,
+once, in ONE place: ``InferenceEngine.__init__``, for the stacks its
+predicate names. The packers, the loaders and the generators make float16; a
+leaf's dtype says which form it is in, and every reader here takes either
+(``scale_values`` pays the bitcast back on the XLA paths).
 
 i.e. the weight is stored transposed ([d_in, d_out], ready for y = x @ W)
 and each 32-input quant block occupies 16 consecutive packed rows + 1 scale
@@ -32,8 +41,9 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .codec import Q40_BLOCK_SIZE, q40_to_planar, quantize_q40
 
@@ -46,7 +56,9 @@ class PackedQ40(NamedTuple):
     """
 
     packed: jnp.ndarray  # uint8 [..., d_in//2, d_out]
-    scales: jnp.ndarray  # float16 [..., d_in//32, d_out]
+    # [..., d_in//32, d_out]: float16 as the packers make it, int16 (the
+    # same bits) as the program serves from it (``q40_at_rest``)
+    scales: jnp.ndarray
 
     @property
     def d_in(self) -> int:
@@ -55,6 +67,44 @@ class PackedQ40(NamedTuple):
     @property
     def d_out(self) -> int:
         return self.packed.shape[-1]
+
+
+def scale_bits(scales):
+    """A scale plane as the kernels take it: the float16 values' bit
+    patterns, int16. Bits pass through as they are; a host array is viewed,
+    not copied; a device array pays one pass (``bitcast_convert_type``)."""
+    if scales.dtype == jnp.int16:
+        return scales
+    if scales.dtype != jnp.float16:
+        raise TypeError(f"Q40 scales are float16 or their int16 bits, not {scales.dtype}")
+    if isinstance(scales, np.ndarray):
+        return scales.view(np.int16)
+    return jax.lax.bitcast_convert_type(scales, jnp.int16)
+
+
+def scale_values(scales) -> jnp.ndarray:
+    """A scale plane's float16 values, from either form: what the XLA paths
+    multiply by (the bitcast back is theirs to pay)."""
+    if scales.dtype != jnp.int16:
+        return scales
+    return jax.lax.bitcast_convert_type(scales, jnp.float16)
+
+
+def q40_at_rest(tree, only=None):
+    """``tree`` with its ``PackedQ40`` leaves' scales as int16 bits, the form
+    the kernel reads a layer's scale tiles out of the stack in, by its index
+    maps, like the nibbles (a float16 plane is sliced and converted before
+    every call). ``only``: a predicate on a leaf's scale plane or stack
+    (``ops.pallas_q40.reads_scales_in_place``: the engine's) that says which
+    leaves; every leaf by default. The choice is read off each leaf's dtype,
+    so a tree already at rest comes back as it is, and nothing else in the
+    tree is touched. A host plane is viewed, a device plane costs one pass."""
+    def rest(leaf):
+        if not isinstance(leaf, PackedQ40) or (only is not None and not only(leaf.scales)):
+            return leaf
+        return leaf._replace(scales=scale_bits(leaf.scales))
+
+    return jax.tree_util.tree_map(rest, tree, is_leaf=lambda x: isinstance(x, PackedQ40))
 
 
 class Q40Layer(NamedTuple):
@@ -208,7 +258,7 @@ def unpack_q40(w: PackedQ40, dtype=jnp.float32) -> jnp.ndarray:
     lo = (pb & 0x0F).astype(jnp.int8) - 8
     hi = (pb >> 4).astype(jnp.int8) - 8
     vals = jnp.concatenate([lo, hi], axis=-2)  # [..., n_blk, 32, d_out]
-    scales = w.scales.astype(jnp.float32)[..., :, None, :]
+    scales = scale_values(w.scales).astype(jnp.float32)[..., :, None, :]
     out = vals.astype(jnp.float32) * scales
     return out.reshape(*lead, d_in, d_out).astype(dtype)
 
@@ -226,10 +276,11 @@ class Q40Experts(NamedTuple):
     """The Q40 weights of every expert of every routed layer, one stack
     ``[L, E, ...]`` a matrix, as the grouped kernel reads them
     (ops/pallas_q40_grouped.py): by layer index and expert id, in place. The
-    scales are held as their float16 BIT PATTERNS. Mosaic has no f16 type, and
-    for XLA:TPU f16 -> s16 is a pass over the data: ``PackedQ40`` pays it on
-    one layer's scale plane a call, which here would be every expert's,
-    chosen or not. ``from_packed`` pays it once, at load."""
+    scales are held as their float16 BIT PATTERNS (``scale_bits``), as a
+    ``PackedQ40`` at rest holds them: Mosaic has no f16 type, and for XLA:TPU
+    f16 -> s16 is a pass over the data, which a call would pay on every
+    expert's plane, chosen or not. ``from_packed`` pays it once, at load, and
+    nothing where the stack arrives as bits already."""
 
     packed: jnp.ndarray  # uint8 [L, E, d_in//2, d_out]
     scale_bits: jnp.ndarray  # int16 [L, E, d_in//32, d_out]: float16 bits
@@ -248,19 +299,14 @@ class Q40Experts(NamedTuple):
 
     @staticmethod
     def from_packed(w: PackedQ40) -> "Q40Experts":
-        import jax
-
         if w.packed.ndim != 4:
             raise ValueError(f"expected [L, E, d_in//2, d_out] planes, got {w.packed.shape}")
-        return Q40Experts(w.packed, jax.lax.bitcast_convert_type(w.scales, jnp.int16))
+        return Q40Experts(w.packed, scale_bits(w.scales))
 
 
 def unpack_q40_slabs(w: Q40Experts, layer, experts, dtype=jnp.float32) -> jnp.ndarray:
     """Dequantize the slabs ``(layer, experts[i])`` to ``[n, d_in, d_out]``:
     the XLA form of the grouped kernel's read (the CPU, and what the kernel
     is tested against). Only the slabs named are touched."""
-    import jax
-
     packed = w.packed[layer, experts]  # [n, d_in//2, d_out]
-    scales = jax.lax.bitcast_convert_type(w.scale_bits[layer, experts], jnp.float16)
-    return unpack_q40(PackedQ40(packed, scales), dtype)
+    return unpack_q40(PackedQ40(packed, w.scale_bits[layer, experts]), dtype)

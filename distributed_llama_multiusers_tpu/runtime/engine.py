@@ -62,6 +62,7 @@ from ..models.hybrid import (
     state_leaves,
 )
 from ..ops import blocked_attention, pallas_attention
+from ..quants.packed import q40_at_rest
 from ..telemetry.logs import log_event
 from ..telemetry import names
 from ..telemetry.names import SCOPE_CARRY, SCOPE_HEAD, SCOPE_SAMPLER
@@ -638,7 +639,15 @@ class InferenceEngine:
         # source locations moved.
         jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
         self.config = config
-        self.params = params
+        # the Q40 scale stacks whose tiles the kernel reads in place, as the
+        # int16 bits it reads (quants/packed.py): made once, HERE and nowhere
+        # else, for a tree that arrives with float16 scales (the loaders', a
+        # generator's). Those float16 stacks are the caller's to keep or drop
+        # (app/runtime_setup.load_stack drops them), not held here; a leaf
+        # whose plane is sliced out a call stays as it arrived.
+        from ..ops.pallas_q40 import reads_scales_in_place
+
+        self.params = q40_at_rest(params, only=reads_scales_in_place)
         self.n_lanes = n_lanes
         self.mesh = mesh
         self.prefill_buckets = tuple(
@@ -1716,6 +1725,7 @@ class InferenceEngine:
         run and how its prefill chunks read the cache, by the predicates the
         forward itself asks, and what the kernel bodies traced so far are
         (``q40_weight_passes``, ``q40_offset_subtracted``,
+        ``q40_scales_in_stack``, ``q40_scale_converts``,
         ``prefill_kernel_traces``: the trace-time witnesses of
         ``ops/pallas_q40.py`` and ``ops/pallas_attention.py``;
         ``prefill_head_rows``: this engine's own, of ``_prefill_half``): said
@@ -1778,7 +1788,15 @@ class InferenceEngine:
                  # warm-up: above 0 wherever a prefill bucket or the lanes
                  # reach ops.pallas_q40.SUBTRACT_MIN_ROWS rows; 0: every call
                  # still folds it)
-                 "q40_offset_subtracted": q40_trace_stats["offset_subtracted_traces"]}
+                 "q40_offset_subtracted": q40_trace_stats["offset_subtracted_traces"],
+                 # Q40 kernel bodies traced so far whose scale tiles were read
+                 # out of the weight's own int16 plane or stack in place (after
+                 # warm-up: above 0 where a stack is too large for XLA to stage
+                 # whole, a 7B model's FFN stacks; 0 there: every call still
+                 # slices its plane out), and those fed by a FLOAT16 scale
+                 # plane sliced out and converted before the call
+                 "q40_scales_in_stack": q40_trace_stats["scale_stack_reads"],
+                 "q40_scale_converts": q40_trace_stats["scale_converts"]}
         if cfg.sparse_attention:
             # how the chosen rows are read (models/deepseek.py: gathered,
             # at every width), and what an indexer's rows are not computed for

@@ -56,3 +56,19 @@ def engine(family, cfg, seed=11, dtype=None, lanes=8, **kw):
     eng = InferenceEngine(config, family.assemble_params(config, tensors), n_lanes=lanes,
                           cache_dtype=dtype, **kw)
     return eng, tensors
+
+
+def scale_dtypes(tree) -> set:
+    """The dtypes of every Q40 scale plane in ``tree`` (``PackedQ40.scales``,
+    ``Q40Experts.scale_bits``): int16 for an expert stack and for a
+    ``PackedQ40`` at rest (``q40_at_rest``: of an engine's ``params``, the
+    stacks the kernel reads in place), float16 for a leaf as the loaders and
+    packers make it."""
+    import jax
+    import numpy as np
+
+    from distributed_llama_multiusers_tpu.quants.packed import PackedQ40, Q40Experts
+
+    q40 = (PackedQ40, Q40Experts)
+    leaves = jax.tree_util.tree_leaves(tree, is_leaf=lambda x: isinstance(x, q40))
+    return {np.dtype(w[1].dtype) for w in leaves if isinstance(w, q40)}

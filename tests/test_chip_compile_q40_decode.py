@@ -13,6 +13,7 @@ from distributed_llama_multiusers_tpu.ops import linear, pallas_q40 as pq, ring_
 from distributed_llama_multiusers_tpu.quants.packed import PackedQ40
 
 from chip_compile_util import (  # noqa: F401  (v5e, v5e_devices: the fixtures)
+    AT_REST,
     DEFAULT_MODE,
     OTHER_MODES,
     SHAPES,
@@ -118,13 +119,23 @@ def test_pure_tp_kernel_paths_compile_for_a_v5e_mesh(
 
 # Stacked weights (PR 30): the kernel reads layer l's tiles out of a [L, ...]
 # stack by a scalar-prefetch index (STACK_SHAPES: tests/chip_compile_util.py)
+@pytest.mark.parametrize("scales", [jnp.float16, AT_REST], ids=["float16", "at_rest"])
 @pytest.mark.parametrize("prefill", [False], ids=["decode"])
 @pytest.mark.parametrize("d_in,d_out,m", STACK_SHAPES)
-def test_stacked_weight_default_mode_compiles_for_v5e(v5e, d_in, d_out, m, prefill):
-    hlo = _compile_stacked(v5e, DEFAULT_MODE, d_in, d_out, 1024 if prefill else m)
+def test_stacked_weight_default_mode_compiles_for_v5e(v5e, d_in, d_out, m, prefill, scales):
+    """A stack as small as these four layers is one the engine leaves float16
+    (``pq.reads_scales_in_place``): its layer's scale plane is sliced out and
+    converted beside the call, the parent's program. Handed over at rest all
+    the same (a direct caller), its plane is sliced out as bits and nothing
+    converts (a stack read in place is compiled at a model's depth in
+    tests/test_chip_compile_steps.py)."""
+    hlo = _compile_stacked(v5e, DEFAULT_MODE, d_in, d_out, 1024 if prefill else m,
+                           scales=scales)
     assert "tpu_custom_call" in hlo
     assert not _scales_stack_converted_whole(hlo, d_in, d_out)
     assert _lane_splits(hlo) == []
+    assert f"= s16[{d_in // 32},{d_out}]" in hlo  # the kernel's scale operand: one plane
+    assert hlo.count(" bitcast-convert(") == (0 if scales == AT_REST else 1)
 
 
 @pytest.mark.parametrize("prefill", [False], ids=["decode"])

@@ -15,6 +15,7 @@ from chip_compile_util import (  # noqa: F401  (v5e, v5e_devices: the fixtures)
     _lane_splits,
     _pattern_decode_hlo,
     _three_layer_decode_hlo,
+    engine_scales,
     v5e,
     v5e_devices,
 )
@@ -82,6 +83,49 @@ def test_decode_forward_splits_no_activation_lane_for_v5e(v5e, monkeypatch, mode
     assert bool(found) == splits, found
 
 
+@pytest.mark.parametrize("scales", [None, jnp.float16], ids=["as_the_engine_holds", "control_float16"])
+@pytest.mark.parametrize("widths", [
+    dict(layers=32, lanes=16, n_heads=32, n_kv=8),
+    dict(layers=28, lanes=32, n_heads=28, n_kv=4),
+], ids=["mistral", "qwen"])
+def test_decode_forward_reads_scale_tiles_out_of_the_stack_for_v5e(v5e, monkeypatch, widths, scales):
+    """A decode forward at Mistral-7B's and Qwen2.5-7B's widths, depth and
+    lanes over the tree AS AN ENGINE HOLDS IT (``engine_scales``: the FFN's
+    three scale stacks, two thirds of the scales' bytes, at rest as int16
+    bits; the attention projections' stacks and the head's plane float16, as
+    they arrived): the scale operand of the FFN's three kernel calls is the
+    loop's own ``[L, d_in/32, d_out]`` stack, which no instruction slices,
+    copies, converts or stages (their three ``bitcast-convert`` fusions were
+    0.55 ms of a 13.7 ms Mistral decode step, PERF.md section 6, PR 55); the
+    other five calls are handed one plane, sliced out and converted beside
+    the call as the parent's were. The control hands the same forward
+    float16 scales throughout, as a direct caller may: it still compiles, and
+    slices and converts one plane a kernel call."""
+    import re
+
+    hlo, dims = _three_layer_decode_hlo(v5e, monkeypatch, seq=2048, scales=scales, **widths)
+    assert hlo.count("tpu_custom_call") == 9
+    converts = hlo.count(" bitcast-convert(")
+    if scales is not None:
+        assert converts == 8, converts
+        return
+    assert converts == 5, converts
+    L, d, h, kv = (dims[k] for k in ("L", "d", "h", "kv"))
+    ffn = sorted(f"s16[{L},{a // 32},{b}]" for a, b in ((d, h), (d, h), (h, d)))
+    planes = sorted([f"s16[{d // 32},{d}]"] * 2 + [f"s16[{d // 32},{kv}]"] * 2
+                    + [f"s16[{d // 32},8192]"])
+    made = {name: (shape, op) for name, shape, op in re.findall(
+        r"(%[\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(", hlo)}
+    scale_ops = [made[ops.split(",")[3].strip()] for ops in re.findall(
+        r"%_q40_matmul_\w+\.\d+ = \S+ custom-call\(([^)]*)\)", hlo)]
+    assert sorted(shape for shape, op in scale_ops if op == "get-tuple-element") == ffn, scale_ops
+    assert sorted(shape for shape, op in scale_ops if op != "get-tuple-element") == planes, scale_ops
+    # nothing makes an array of an FFN scale stack's size or of one of its planes
+    assert not re.findall(
+        rf"= (?:s16|f16)\[(?:{L}|1),(?:{d // 32},{h}|{h // 32},{d})\]\S* "
+        r"(?!parameter\(|get-tuple-element\(|bitcast\()\S+?\(", hlo)
+
+
 def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, monkeypatch):
     """Three layers (one dense, two routed) of the benchmark's latent block at
     its published widths, one row a lane, the cache donated: the kernels are
@@ -107,7 +151,7 @@ def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, 
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
     q40 = lambda d_in, d_out, lead: PackedQ40(
         packed=sds(lead + (d_in // 2, d_out), jnp.uint8),
-        scales=sds(lead + (d_in // 32, d_out), jnp.float16))
+        scales=sds(lead + (d_in // 32, d_out), engine_scales(lead + (d_in // 32, d_out))))
     experts = lambda d_in, d_out: Q40Experts(
         sds((Lm, E, d_in // 2, d_out), jnp.uint8), sds((Lm, E, d_in // 32, d_out), jnp.int16))
     params = deepseek.DeepseekParams(

@@ -20,8 +20,16 @@ from distributed_llama_multiusers_tpu.ops.pallas_q40 import (
 from distributed_llama_multiusers_tpu.quants.packed import (
     PackedQ40,
     pack_q40_host,
+    q40_at_rest,
     q40_matmul_xla,
 )
+
+# the two forms a weight's scales arrive in (quants/packed.py): float16 as
+# the packers make them, and their int16 bits as the engine serves from them.
+# Through the kernel's entry a PLANE at rest is read in place and a stack as
+# small as a test's has its layer's plane sliced out, as bits (XLA would stage
+# it whole: ``pq.reads_scales_in_place``); a stack read in place is ``_in_place`` below
+SCALE_FORMS = {"f16": lambda w: w, "bits": q40_at_rest}
 
 
 def _pack(rng, d_out, d_in, scale=0.1):
@@ -116,11 +124,14 @@ def _sharded(arr, mesh, *spec):
     ((None, "tp"), True),   # row-sliced: d_out sharded, output stays sharded
     (("tp", None), False),  # col-sliced: d_in sharded, psum -> replicated
 ])
-def test_partitioned_matmul_parity(w_spec, expect_out_tp):
+@pytest.mark.parametrize("scales", list(SCALE_FORMS))
+def test_partitioned_matmul_parity(w_spec, expect_out_tp, scales):
     rng = np.random.default_rng(7)
     pw = _pack(rng, 256, 128)
     x = jnp.asarray(rng.standard_normal((8, 128), dtype=np.float32))
     ref = q40_matmul_xla(x, pw)
+    # the partitioning rule passes the scale plane through by shape
+    pw = SCALE_FORMS[scales](pw)
 
     mesh = make_mesh(MeshPlan(tp=2, dp=2))
     w_sh = PackedQ40(
@@ -615,7 +626,9 @@ def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
     ``[lanes, t, d_in]`` that the kernel merges, with
     ``l`` a traced scalar (an argument of a jit; the counter of a lax.scan)
     at the stack's first and last layer. Both sides are computed inside one
-    traced program, so the operand builds are the same operations."""
+    traced program, so the operand builds are the same operations. (A stack
+    at rest: ``test_stacked_weight_on_every_grid_axis`` and the ``in_place``
+    tests below.)"""
     rng = np.random.default_rng(30)
     stack = _stack(rng, 256, 128)
     x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
@@ -639,6 +652,8 @@ def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
             pairs = {l: (got[l], want[l]) for l in (0, STACK_L - 1)}
         # one trace of ``both``: one kernel call that indexes a stack
         assert TRACE_STATS["stacked_consumes"] == 1, TRACE_STATS
+        # float16 scales are never read in place
+        assert TRACE_STATS["scale_stack_reads"] == 0, TRACE_STATS
         # stack and plane alike: x itself in a slab chain, never in a
         # block-dot mode
         natural = mode not in ("blockdot", "i8blockdot")
@@ -658,18 +673,87 @@ def test_stacked_weight_equals_its_plane_bit_for_bit(mode, entry, how):
     (2, 512, 16384),   # two wide tiles: the j axis
     (300, 64, 256),    # rows above M_TILE, padded to 512
 ])
-def test_stacked_weight_on_every_grid_axis(m, d_in, d_out):
-    """The layer offset composes with each axis of the grid."""
+@pytest.mark.parametrize("scales", list(SCALE_FORMS))
+def test_stacked_weight_on_every_grid_axis(m, d_in, d_out, scales):
+    """The layer offset composes with each axis of the grid, for the nibbles
+    and, where the scales rest as bits, for the scale tiles beside them
+    (against the float16 plane's call)."""
     rng = np.random.default_rng(d_in + d_out)
     stack = _stack(rng, d_out, d_in, n=2)
     x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
     for l in (0, 1):
-        got = q40_matmul_pallas(x, stack, interpret=True, layer=l)
+        got = q40_matmul_pallas(x, SCALE_FORMS[scales](stack), interpret=True, layer=l)
         want = q40_matmul_pallas(x, _plane(stack, l), interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(q40_matmul_xla(x, _plane(stack, l))),
             atol=2e-4, rtol=2e-4)
+
+
+def _in_place(monkeypatch):
+    """A call of the kernel's core over a stack at rest whose scale tiles are
+    read IN PLACE, as a stack too large to stage is: ``reads_scales_in_place``
+    says so for the trace, and the core is traced anew under a jit of its own
+    (the entry's jit would serve a body traced before the patch)."""
+    monkeypatch.setattr(pq, "reads_scales_in_place", lambda scales: scales.ndim == 3)
+
+    def call(x, stack, layer, mode="v4", w_dtype=jnp.float32):
+        return pq._q40_matmul_core(x, q40_at_rest(stack), True, w_dtype, mode, layer)
+
+    return call
+
+
+@pytest.mark.parametrize("how", ["jit", "scan"])
+@pytest.mark.parametrize("m,d_in,d_out", [
+    (4, 4096, 2048),    # n_k > 1: the k axis walks chunks of layer l's scale plane
+    (2, 512, 16384),    # two wide tiles: the j axis
+    (300, 64, 256),     # rows above M_TILE: the -8 subtracted, one block of 512
+    (16, 2048, 1152),   # k chunks and sub tiles 512 + 512 + 128, the -8 folded
+    (128, 2048, 1152),  # the same plan at the threshold: subtracted
+])
+def test_scale_tiles_read_in_place_equal_the_float16_planes_call(monkeypatch, m, d_in, d_out, how):
+    """Layer l's scale tiles addressed inside the int16 stack by the index
+    maps, ``l`` traced (a jit's argument; a scan's counter): the bit-identical
+    result of the float16 plane's own call, on every grid axis, decode and
+    prefill widths, fold and subtract."""
+    rng = np.random.default_rng(d_in + d_out + m)
+    stack = _stack(rng, d_out, d_in, n=3)
+    x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
+    call = _in_place(monkeypatch)
+    reset_trace_stats()
+    if how == "jit":
+        fn = jax.jit(lambda x, s, l: call(x, s, l))
+        got = {l: fn(x, stack, jnp.int32(l)) for l in (0, 2)}
+    else:
+        _, ys = jax.lax.scan(lambda c, l: (c, call(x, stack, l)), 0,
+                             jnp.arange(3, dtype=jnp.int32))
+        got = {l: ys[l] for l in (0, 2)}
+    assert TRACE_STATS["scale_stack_reads"] == TRACE_STATS["impl_traces"] == 1, TRACE_STATS
+    assert TRACE_STATS["scale_converts"] == 0
+    for l, y in got.items():
+        want = q40_matmul_pallas(x, _plane(stack, l), interpret=True)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want), err_msg=f"layer {l}")
+    assert not np.array_equal(np.asarray(got[0]), np.asarray(got[2]))
+
+
+@pytest.mark.parametrize("mode", DEQUANT_MODES)
+def test_scale_tiles_read_in_place_in_every_mode(monkeypatch, mode):
+    """The block-dot modes' kernels take the scale operand by the same spec:
+    in place out of the stack in each of the six, equal to the float16
+    plane's call in that mode to the bit."""
+    rng = np.random.default_rng(55)
+    stack = _stack(rng, 256, 128)
+    x = jnp.asarray(rng.standard_normal((4, 128), dtype=np.float32))
+    call = _in_place(monkeypatch)
+    set_dequant_mode(mode)
+    try:
+        got = jax.jit(lambda x, s, l: call(x, s, l, mode, jnp.bfloat16))(
+            x, stack, jnp.int32(STACK_L - 1))
+        want = q40_matmul_pallas(x, _plane(stack, STACK_L - 1), interpret=True,
+                                 w_dtype=jnp.bfloat16)
+    finally:
+        set_dequant_mode(None)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # sha256[:16] of the output bytes of the seeded call below. The two block-dot
@@ -705,10 +789,13 @@ def test_plain_weight_gives_what_it_gave_before_stacks(mode):
         as_stack = np.asarray(q40_matmul_pallas(
             x, PackedQ40(pw.packed[None], pw.scales[None]), interpret=True,
             layer=0, **kw))
+        at_rest = np.asarray(q40_matmul_pallas(
+            x, q40_at_rest(pw), interpret=True, **kw))
     finally:
         set_dequant_mode(None)
     assert hashlib.sha256(got.tobytes()).hexdigest()[:16] == PARENT_2D_DIGESTS[mode]
     np.testing.assert_array_equal(as_stack, got)
+    np.testing.assert_array_equal(at_rest, got)  # the plane's scales as bits
     if mode in ("f32", "v4"):
         # the same products as the two-dot form, summed in another order
         want = np.asarray(_two_dot_form(
@@ -1036,6 +1123,11 @@ def test_decode_step_program_splits_no_activation_lane(witness_engine, mode,
         jax.clear_caches()
     found = _arrays_under(jaxpr, WITNESS_SCOPES)
     assert any("dl.ffn" in p for p, _ in found), "no dl.ffn scope in the program"
+    # the loader's tree is float16 and the engine leaves stacks this small so
+    # (``reads_scales_in_place``): every kernel body of the step, in any mode,
+    # was fed by a plane sliced out and converted, and says so
+    assert TRACE_STATS["scale_converts"] == TRACE_STATS["impl_traces"], TRACE_STATS
+    assert TRACE_STATS["scale_stack_reads"] == 0, TRACE_STATS
     if splits:
         assert SPLIT_RESHAPE.search(text)
         assert _lane_splits(found), found
@@ -1197,13 +1289,16 @@ def test_a_narrowed_wide_tile_does_not_change_a_result():
                                atol=2e-4, rtol=2e-4)
 
 
-def _trace_w1_call(m, mode="v4"):
-    """Trace (nothing runs) a bf16 call of ``m`` rows at Mistral's w1."""
-    w = PackedQ40(packed=jax.ShapeDtypeStruct((2048, 14336), jnp.uint8),
-                  scales=jax.ShapeDtypeStruct((128, 14336), jnp.float16))
+def _trace_w1_call(m, mode="v4", scales=jnp.float16, layers=None):
+    """Trace (nothing runs) a bf16 call of ``m`` rows at Mistral's w1: the
+    plane, or a layer of a stack of ``layers``."""
+    lead = () if layers is None else (layers,)
+    w = PackedQ40(packed=jax.ShapeDtypeStruct(lead + (2048, 14336), jnp.uint8),
+                  scales=jax.ShapeDtypeStruct(lead + (128, 14336), scales))
     x = jax.ShapeDtypeStruct((m, 4096), jnp.bfloat16)
-    jax.eval_shape(lambda x, w: pq._q40_matmul_core(
-        x, w, True, jnp.bfloat16, mode), x, w)
+    layer = None if layers is None else jax.ShapeDtypeStruct((), jnp.int32)
+    jax.eval_shape(lambda x, w, l: pq._q40_matmul_core(
+        x, w, True, jnp.bfloat16, mode, l), x, w, layer)
 
 
 def test_weight_passes_witness_in_trace_stats_and_path_facts(witness_engine):
@@ -1323,17 +1418,20 @@ def test_offset_subtracted_in_every_slab_chain(mode):
         np.testing.assert_array_equal(got[mode], got["bf16chain"])
 
 
+@pytest.mark.parametrize("scales", list(SCALE_FORMS))
 @pytest.mark.parametrize("m", [T - 8, T, 2 * T], ids=["under", "at", "two_tiles"])
-def test_offset_form_on_a_stack_with_a_traced_layer(m):
+def test_offset_form_on_a_stack_with_a_traced_layer(m, scales):
     """A layer read out of a stack under a traced index, the counter of a scan
-    as the layer loop hands it: equal to the plane's own call to the bit on
-    either side of the threshold, and to the XLA dequant."""
+    as the layer loop hands it, the stack's scales float16 or at rest as bits:
+    equal to the float16 plane's own call to the bit on either side of the
+    threshold (the -8 folded, the -8 subtracted), and to the XLA dequant."""
     d_in, d_out = OFFSET_PLANS["k_chunks"]
     rng = np.random.default_rng(m)
     stack = _stack(rng, d_out, d_in, n=2)
+    served = SCALE_FORMS[scales](stack)
     x = jnp.asarray(rng.standard_normal((m, d_in), dtype=np.float32))
     _, got = jax.lax.scan(
-        lambda c, l: (c, q40_matmul_pallas(x, stack, interpret=True, layer=l)),
+        lambda c, l: (c, q40_matmul_pallas(x, served, interpret=True, layer=l)),
         0, jnp.arange(2, dtype=jnp.int32))
     for l in (0, 1):
         plane = _plane(stack, l)
@@ -1375,6 +1473,55 @@ def test_offset_witness_in_trace_stats_and_path_facts(witness_engine):
     assert TRACE_STATS["offset_subtracted_traces"] == 3, TRACE_STATS
     assert witness_engine.path_facts()["q40_offset_subtracted"] == 3
     reset_trace_stats()
+
+
+def test_scale_stack_witness_in_trace_stats_and_path_facts(witness_engine):
+    """The engine's start-up facts carry the Q40 kernel bodies traced whose
+    scale tiles were read out of the weight's own int16 plane or stack in
+    place and those fed by a float16 plane sliced out and converted (beside
+    them here: all bodies traced), whatever the mode: a plane at rest and a 7B model's
+    32-layer FFN stack are read in place, a stack XLA could stage whole has
+    its plane sliced out as bits (neither count), float16 is converted."""
+    def facts():
+        f = witness_engine.path_facts()
+        return f["q40_scales_in_stack"], f["q40_scale_converts"], TRACE_STATS["impl_traces"]
+
+    reset_trace_stats()
+    assert facts() == (0, 0, 0)
+    for m in (16, T, 1024):
+        _trace_w1_call(m, scales=jnp.int16)
+    _trace_w1_call(16, "blockdot", scales=jnp.int16)
+    assert facts() == (4, 0, 4)
+    _trace_w1_call(16, layers=32, scales=jnp.int16)  # 117 MB of scales: in place
+    assert facts() == (5, 0, 5)
+    _trace_w1_call(16, layers=8, scales=jnp.int16)  # 29 MB: its plane sliced out
+    assert facts() == (5, 0, 6)
+    _trace_w1_call(16)  # float16: sliced and converted, and counted as such
+    _trace_w1_call(16, layers=32)
+    assert facts() == (5, 2, 8)
+    reset_trace_stats()
+
+
+def test_a_stack_is_read_in_place_only_where_xla_cannot_stage_it():
+    """``reads_scales_in_place`` from shapes alone, in either form: the scale
+    stacks of a 7B or 9B model's FFN (117-134 MB) cannot sit in fast memory
+    beside the kernel's 64 MiB and are read in place (every configuration of
+    the benchmark: tests/test_weight_residency.py); attention projections'
+    (8-34 MB), a 9-layer 66 MB stack and every test's can, and XLA would copy
+    them there whole a call (compiled for a v5e:
+    tests/test_chip_compile_steps.py; what that costs on the chip: the
+    predicate's docstring); a stack of one is its plane, and a plane (a
+    head's) has no layer to slice out: the engine converts neither."""
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int16)
+    in_place = [sds(32, 128, 14336), sds(32, 448, 4096), sds(28, 112, 18944),
+                sds(32, 128, 16384), sds(32, 512, 4096)]  # the last two: MiniCPM-SALA's FFN
+    sliced = [sds(32, 128, 4096), sds(32, 128, 1024), sds(28, 112, 3584),
+              sds(9, 512, 7168), sds(8, 128, 16384), sds(2, 4, 256), sds(1, 592, 3584),
+              sds(112, 152064)]
+    f16 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float16)
+    for form in (lambda s: s, f16):
+        assert [pq.reads_scales_in_place(form(s)) for s in in_place] == [True] * len(in_place)
+        assert [pq.reads_scales_in_place(form(s)) for s in sliced] == [False] * len(sliced)
 
 
 # sha256[:16] of the output bytes of the seeded call below, 112 rows, on the

@@ -81,11 +81,14 @@ ROUTES = {
 }
 
 
+@pytest.mark.parametrize("scales", ["f16", "bits"])
 @pytest.mark.parametrize("case", sorted(ROUTES))
-def test_matmul_routes_by_what_the_weight_is(monkeypatch, case):
+def test_matmul_routes_by_what_the_weight_is(monkeypatch, case, scales):
     """The route rule of ``ops.linear.matmul``, by spies on the three
     functions it can reach; x is the same raw array in every case, and the
-    result equals the XLA dequant's."""
+    result equals the XLA dequant's of the float16 plane. The form the scales
+    arrive in (float16, or at rest as their int16 bits) moves no route: the
+    kernel and the XLA dequant each take either."""
     rng = np.random.default_rng(46)
     ran = []
 
@@ -108,8 +111,9 @@ def test_matmul_routes_by_what_the_weight_is(monkeypatch, case):
     assert (pq._plan_blocks(64, d_out) is None) == (d_out == 8224)
     stack = PackedQ40(packed=jnp.stack([plane.packed] * 2), scales=jnp.stack([plane.scales] * 2))
     x = jnp.asarray(rng.standard_normal((2, 3, 64), dtype=np.float32))
-    w = {"q40_layer": Q40Layer(stack, jnp.int32(1)), "stack_without_a_layer": stack,
-         "dense": jnp.ones((64, 128), jnp.float32)}.get(case, plane)
+    form = packed_mod.q40_at_rest if scales == "bits" else (lambda w: w)
+    w = {"q40_layer": Q40Layer(form(stack), jnp.int32(1)), "stack_without_a_layer": form(stack),
+         "dense": jnp.ones((64, 128), jnp.float32)}.get(case, form(plane))
     linear.set_pallas_interpret(case != "plane_kernel_off")
     try:
         got = linear.matmul(x, w)

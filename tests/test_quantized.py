@@ -18,7 +18,9 @@ from distributed_llama_multiusers_tpu.quants.packed import (
     PackedQ40,
     pack_q40_from_blocks,
     pack_q40_host,
+    q40_at_rest,
     q40_matmul_xla,
+    scale_bits,
     unpack_q40,
 )
 
@@ -50,13 +52,45 @@ def test_pack_q40_host_equals_pack_from_blocks():
         np.testing.assert_array_equal(sc[layer], sc1)
 
 
-def test_q40_matmul_xla_matches_dense():
+@pytest.mark.parametrize("on", ["device", "host"])
+def test_q40_at_rest_holds_the_scales_bits_once(on):
+    """The one function that makes the form the program serves from: every
+    ``PackedQ40`` leaf's scales as the int16 bits of their float16 values,
+    nothing else in the tree touched, a tree at rest handed back as it is
+    (the same leaves), a host plane viewed and not copied; ``unpack_q40``
+    gives the same values from either form."""
+    rng = np.random.default_rng(55)
+    pk, sc = pack_q40_host(rng.standard_normal((2, 16, 64)).astype(np.float32))
+    up = jnp.asarray if on == "device" else (lambda a: a)
+    other = up(np.ones(3, np.float16))  # a float16 leaf that is no scale plane
+    tree = {"w": PackedQ40(up(pk), up(sc)), "nested": [PackedQ40(up(pk[0]), up(sc[0]))],
+            "other": other}
+    rest = q40_at_rest(tree)
+    assert rest["other"] is other and rest["w"].packed is tree["w"].packed
+    for leaf, was in ((rest["w"], sc), (rest["nested"][0], sc[0])):
+        assert isinstance(leaf, PackedQ40) and leaf.scales.dtype == jnp.int16
+        np.testing.assert_array_equal(np.asarray(leaf.scales), was.view(np.int16))
+    if on == "host":
+        assert np.shares_memory(rest["w"].scales, sc)
+    again = q40_at_rest(rest)
+    assert again["w"].scales is rest["w"].scales
+    assert again["nested"][0].scales is rest["nested"][0].scales
+    np.testing.assert_array_equal(
+        np.asarray(unpack_q40(PackedQ40(jnp.asarray(pk), jnp.asarray(rest["w"].scales)))),
+        np.asarray(unpack_q40(PackedQ40(jnp.asarray(pk), jnp.asarray(sc)))))
+    with pytest.raises(TypeError, match="float16 or their int16 bits"):
+        scale_bits(np.ones((1, 4), np.float32))
+
+
+@pytest.mark.parametrize("scales", ["f16", "bits"])
+def test_q40_matmul_xla_matches_dense(scales):
     rng = np.random.default_rng(2)
     d_in, d_out, b = 128, 96, 4
     w = rng.standard_normal((d_out, d_in)).astype(np.float32)
     x = rng.standard_normal((b, d_in)).astype(np.float32)
     pk, sc = pack_q40_host(w)
-    pq = PackedQ40(jnp.asarray(pk), jnp.asarray(sc))
+    # either form of the scales: the float16 values, or their bits at rest
+    pq = PackedQ40(jnp.asarray(pk), jnp.asarray(sc if scales == "f16" else sc.view(np.int16)))
 
     golden_w = dequantize_q40(quantize_q40(w.reshape(-1))).reshape(d_out, d_in)
     want = x @ golden_w.T
@@ -142,6 +176,10 @@ def test_load_params_from_m_quantized(tiny_model):
     config, qparams = load_params_from_m_quantized(path, header2, dtype=jnp.float32)
     _, dparams = load_params_from_m(path, header2, dtype=jnp.float32)
     assert isinstance(qparams.layers.wq, PackedQ40)
+    # the loader's planes arrive as packed, float16: what rests as int16 bits
+    # is the engine's to say, where it takes them (``q40_at_rest``)
+    assert {w.scales.dtype for w in (qparams.layers.wq, qparams.layers.w2, qparams.wcls)} == {
+        jnp.dtype(jnp.float16)}
 
     tokens = jnp.asarray([[1, 2, 3]], jnp.int32)
     positions = jnp.arange(3, dtype=jnp.int32)[None]

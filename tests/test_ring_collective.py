@@ -29,6 +29,7 @@ from distributed_llama_multiusers_tpu.quants.jax_codec import qdq_q80
 from distributed_llama_multiusers_tpu.quants.packed import (
     PackedQ40,
     pack_q40_host,
+    q40_at_rest,
     q40_matmul_xla,
 )
 
@@ -233,10 +234,12 @@ def test_ring_sync_matmul_rejects_indivisible():
 # ---------------------------------------------------------------------------
 
 
-def test_escape_hatch_restores_psum_path():
+@pytest.mark.parametrize("scales", ["f16", "bits"])
+def test_escape_hatch_restores_psum_path(scales):
     """set_ring_sync(False): the partitioned Q40 matmul's col-sliced sync
     is lax.psum again — bit-for-bit the manual shard_map psum reference —
-    and ring_sync_engages goes False everywhere."""
+    and ring_sync_engages goes False everywhere. The scale plane passes
+    through the mesh paths by shape, float16 or at rest as its int16 bits."""
     from distributed_llama_multiusers_tpu.models.config import LlamaConfig
     from distributed_llama_multiusers_tpu.ops.pallas_q40 import (
         _q40_mm_impl,
@@ -248,6 +251,8 @@ def test_escape_hatch_restores_psum_path():
     rng = np.random.default_rng(10)
     x = rng.standard_normal((2, 128)).astype(np.float32)
     w = _packed_weight(128, 64, seed=10)
+    if scales == "bits":
+        w = q40_at_rest(w)
 
     # col-sliced layout: x last dim + packed plane rows sharded over tp.
     # interpret=True is the CPU convention for the partitioned kernel
@@ -360,8 +365,9 @@ def test_forward_ring_on_off_parity():
     assert np.array_equal(ring.argmax(-1), ref.argmax(-1))
 
 
+@pytest.mark.parametrize("scales", ["f16", "bits"])
 @pytest.mark.parametrize("ring", [True, False])
-def test_pure_tp_packed_forward_needs_no_custom_partitioner(ring, monkeypatch):
+def test_pure_tp_packed_forward_needs_no_custom_partitioner(ring, scales, monkeypatch):
     """libtpu has no custom-call partitioner, so on a pure-TP mesh the
     Q40 forward must not contain the GSPMD kernel wrapper at all: every
     matmul runs the kernel per shard under shard_map (sliced: no sync;
@@ -382,6 +388,8 @@ def test_pure_tp_packed_forward_needs_no_custom_partitioner(ring, monkeypatch):
                          n_kv_heads=4, vocab_size=512, seq_len=32)
     dense = params_from_random(config, seed=3, dtype=jnp.float32)
     packed = quantize_params(dense)
+    if scales == "bits":  # as the engine serves it; shard_map takes it by shape
+        packed = q40_at_rest(packed)
     mesh = make_mesh(MeshPlan(tp=2))
     toks = jnp.asarray([[3, 9, 27, 81]], jnp.int32)
     pos = jnp.arange(4, dtype=jnp.int32)[None]

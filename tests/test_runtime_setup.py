@@ -71,7 +71,10 @@ def test_load_stack_reports_device_on_startup_line_and_stats(
         "--model", tiny_model["model"],
         "--tokenizer", tiny_model["tokenizer"], "--max-lanes", "2",
     ])
-    _, _, tokenizer, engine = runtime_setup.load_stack(args)
+    _, params, tokenizer, engine = runtime_setup.load_stack(args)
+    # the tree handed back is the one the engine serves from: no second copy
+    # of a leaf the engine put at rest is held for the process's life
+    assert params is engine.params
     line = next(
         json.loads(s) for s in capsys.readouterr().out.splitlines()
         if s.startswith('{"event": "runtime_device"')

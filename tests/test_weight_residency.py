@@ -161,5 +161,90 @@ def test_one_traced_forward_counts_seven_stack_reads(loaded, kernel_on):
         lambda p, c: llama_forward(config, p, tokens, tokens, c)
     )(params, init_kv_cache(config, N_LANES))
     assert TRACE_STATS["stacked_consumes"] == 7, TRACE_STATS
-    # whatever kernel body this trace made was handed x as it is
+    # whatever kernel body this trace made was handed x as it is, and (the
+    # loader's tree: float16 scales, stacks of two layers) a converted plane
     assert TRACE_STATS["natural_x_consumes"] == TRACE_STATS["impl_traces"], TRACE_STATS
+    assert TRACE_STATS["scale_converts"] == TRACE_STATS["impl_traces"], TRACE_STATS
+    assert TRACE_STATS["scale_stack_reads"] == 0, TRACE_STATS
+
+
+@pytest.mark.parametrize("handed", ["float16_scales", "at_rest"])
+def test_the_engine_rests_the_stacks_the_kernel_reads_in_place(loaded, handed, monkeypatch):
+    """Where it takes its weights the engine makes the kernel's form of the
+    scale stacks whose tiles the kernel will read in place
+    (``reads_scales_in_place``: stacks XLA cannot stage whole; here every
+    stack, the budget set to nothing), once, and holds no float16 copy of
+    them; a leaf whose plane is sliced out a call (the head's plane; without
+    the patch every stack of a model this small) stays as it arrived, as the
+    loader made it, and so does a tree that is at rest already, leaf for
+    leaf. The bits are the float16 values', so every product is the same."""
+    from distributed_llama_multiusers_tpu.ops import pallas_q40 as pq
+    from distributed_llama_multiusers_tpu.quants.packed import q40_at_rest
+    from distributed_llama_multiusers_tpu.runtime import InferenceEngine
+
+    import latent_toy
+
+    config, params = loaded
+    int16, float16 = np.dtype(np.int16), np.dtype(np.float16)
+    assert latent_toy.scale_dtypes(params) == {float16}  # the loader's
+    untouched = InferenceEngine(config, params, n_lanes=N_LANES, prefill_buckets=(8,))
+    assert untouched.params.layers.w1.scales is params.layers.w1.scales  # 2 layers: sliced
+    monkeypatch.setattr(pq, "VMEM_BYTES", pq.VMEM_LIMIT_BYTES)  # nothing can be staged
+    if handed == "at_rest":
+        params = q40_at_rest(params, only=pq.reads_scales_in_place)
+    engine = InferenceEngine(config, params, n_lanes=N_LANES, prefill_buckets=(8,))
+    stacks = {f: getattr(engine.params.layers, f).scales for f in Q40_FIELDS}
+    assert {np.dtype(s.dtype) for s in stacks.values()} == {int16}
+    assert engine.params.wcls.scales is params.wcls.scales  # a plane: as it arrived
+    assert engine.params.wcls.scales.dtype == float16
+    for f, s in stacks.items():
+        if handed == "at_rest":
+            assert s is getattr(params.layers, f).scales
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(s), np.asarray(getattr(loaded[1].layers, f).scales).view(np.int16))
+
+
+# which Q40 scale stacks of each benchmark configuration the engine puts at
+# rest, and so which cells' step programs differ from a float16 tree's
+RESTED = {
+    "mistral-7b-v0.3": {".layers.w1", ".layers.w2", ".layers.w3"},
+    "qwen2.5-7b": {".layers.w1", ".layers.w2", ".layers.w3"},
+    "minicpm-sala": {".dense.w1", ".dense.w2", ".dense.w3"},  # [32, 128, 16384]: 128 MiB each
+    "kanana-2-30b-a3b": set(), "lfm2-24b-a2b": set(), "deepseek-v3.2": set(),  # wo: 63 MiB
+    "jamba2-3b": set(), "command-a-plus-05-2026": set(), "mimo-v2-flash": set(),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RESTED))
+def test_which_stacks_of_a_benchmark_configuration_rest(name, monkeypatch):
+    """The parameter tree of each of the benchmark's configurations, by shape
+    alone (``eval_shape`` of its family's generator: nothing is made), through
+    the engine's rule: the three FFN stacks of the dense 7B models and of
+    MiniCPM-SALA (112-128 MiB each) are read in place; every other stack and
+    every head is under the line, stays float16, and its cell's programs are
+    a float16 tree's. A configuration that is added gets a line here."""
+    import json
+    import os
+    import sys
+
+    from distributed_llama_multiusers_tpu.ops.pallas_q40 import reads_scales_in_place
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    assert {c["name"] for c in bench["configs"]} == set(RESTED)
+    cfg = json.load(open(os.path.join(
+        root, next(c["file"] for c in bench["configs"] if c["name"] == name))))
+    monkeypatch.setattr(sys, "path", [os.path.join(root, "benchmarks"), root] + sys.path)
+    monkeypatch.setattr(jax, "block_until_ready", lambda t: t)  # tracers, here
+    from harness import cells
+
+    family = cells.load_family(cfg)
+    config = family.program_config(cfg)
+    tree = jax.eval_shape(lambda: family.assemble_params(
+        config, family.device_weights(config, 55, jnp.bfloat16)))
+    leaves = jax.tree_util.tree_flatten_with_path(
+        tree, is_leaf=lambda x: isinstance(x, PackedQ40))[0]
+    q40 = {jax.tree_util.keystr(path): w for path, w in leaves if isinstance(w, PackedQ40)}
+    assert len(q40) >= 8 and all(w.scales.dtype == jnp.float16 for w in q40.values())
+    assert {k for k, w in q40.items() if reads_scales_in_place(w.scales)} == RESTED[name]
