@@ -109,6 +109,17 @@ def load_config(folder: str, weight_type: int) -> tuple[ModelHeader, dict]:
         # rotates in part, scaled values: the KEY_ROTARY_DIM ... keys
         "mimo_v2_flash": ArchType.LLAMA,
     }.get(cfg["model_type"])
+    if cfg["model_type"] == "solar_open2":
+        # the program runs the family (models/hybrid.py LayerKind.DELTA, the
+        # walk in formats/model_file.py) but no checkpoint, safetensors index
+        # or modeling code of it is on this machine to read the names from
+        raise ValueError(
+            "Unsupported arch type: solar_open2: the checkpoint's tensor names are not known "
+            "here (a delta-rule layer's q / k / v projections and their three conv1d weights, "
+            "the decay's and the output gate's two low-rank factors, A_log, dt_bias, the b "
+            "projection, the output norm; a GQA layer's gate; the router's selection bias): "
+            "formats/synthetic.py tiny_delta_header and benchmarks/families/solar_open2.py "
+            "make such a model, formats/model_file.py _pattern_block_specs says the walk")
     if arch is None:
         raise ValueError(f"Unsupported arch type: {cfg['model_type']}")
     # lfm2_moe publishes no hidden_act: its FFNs are SwiGLU

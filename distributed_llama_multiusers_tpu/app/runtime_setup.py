@@ -118,7 +118,8 @@ def load_stack(args, n_lanes: int | None = None):
         from ..quants.packed import PackedQ40
 
         if config.layer_kinds:
-            first = next(m for m in (params.conv, params.ssm, params.linear, params.attn) if m is not None)[0]
+            first = next(m for m in (params.conv, params.ssm, params.linear, params.delta,
+                                      params.attn) if m is not None)[0]
         else:
             first = (params.attn if config.latent_attention else params.layers).wq
         if any(isinstance(x, PackedQ40) for x in [params.wcls, first]):

@@ -183,6 +183,21 @@ KEY_WINDOW_N_KV_HEADS = 70
 KEY_WINDOW_ROPE_THETA = 71
 KEY_ATTN_VALUE_SCALE_E6 = 72
 KEY_WINDOW_SINK = 73
+# framework extension: what ``model_type: solar_open2`` adds to a block of
+# mixed layers, each key written only where it is set. A gated delta-rule
+# layer (``LayerKind.DELTA``, Kimi Delta Attention): its heads and their
+# width (keys and values alike; a float32 matrix state ``[head, head]`` a head
+# a lane under a decay a key CHANNEL), the taps of the three causal depthwise
+# convs its q, k and v pass through, the rank of its two low-rank gates (the
+# decay's and the output's), and whether ``b`` spans (0, 2) and not (0, 1).
+# ``attn_output_gate``: a full-context layer's output is multiplied by
+# ``sigmoid(W_g n)`` before wo, as a block-sparse layer's always is.
+KEY_DELTA_N_HEADS = 74
+KEY_DELTA_HEAD_DIM = 75
+KEY_DELTA_CONV_KERNEL = 76
+KEY_DELTA_GATE_RANK = 77
+KEY_DELTA_NEG_EIGVAL = 78
+KEY_ATTN_OUTPUT_GATE = 79
 
 
 class ArchType:
@@ -216,6 +231,9 @@ class LayerKind:
     # GQA over the blocks of the KV cache that its compressed keys choose,
     # with an output gate ("minicpm4"); its planes are stacked with ATTENTION's
     SPARSE = 5
+    # gated delta rule: a float32 matrix state a head a lane under a decay a
+    # key channel, q, k and v through short causal convs ("kda")
+    DELTA = 6
 
 
 class NormKind:
@@ -319,6 +337,13 @@ class ModelHeader:
     window_rope_theta: float = 0.0  # 0: rope_theta
     attn_value_scale: float = 1.0
     window_sink: int = 0
+    # what solar_open2 adds (KEY_DELTA_N_HEADS ...); unset elsewhere
+    delta_n_heads: int = 0
+    delta_head_dim: int = 0
+    delta_conv_kernel: int = 0
+    delta_gate_rank: int = 0
+    delta_neg_eigval: int = 0
+    attn_output_gate: int = 0
     header_size: int = 0
     file_size: int = 0
 
@@ -411,7 +436,10 @@ class ModelHeader:
         ] + (
             [(KEY_ATTN_VALUE_SCALE_E6, int(round(self.attn_value_scale * 1e6)))]
             if self.attn_value_scale != 1.0 else []
-        )
+        ) + [
+            (key, int(getattr(self, name))) for key, name in _DELTA_INT_KEYS.items()
+            if getattr(self, name)
+        ]
 
 
 _LATENT_INT_KEYS = {
@@ -475,6 +503,14 @@ _MIXED_HEAD_INT_KEYS = {
     KEY_WINDOW_ROPE_THETA: "window_rope_theta",
     KEY_WINDOW_SINK: "window_sink",
 }
+_DELTA_INT_KEYS = {
+    KEY_DELTA_N_HEADS: "delta_n_heads",
+    KEY_DELTA_HEAD_DIM: "delta_head_dim",
+    KEY_DELTA_CONV_KERNEL: "delta_conv_kernel",
+    KEY_DELTA_GATE_RANK: "delta_gate_rank",
+    KEY_DELTA_NEG_EIGVAL: "delta_neg_eigval",
+    KEY_ATTN_OUTPUT_GATE: "attn_output_gate",
+}
 
 
 def _floor_to_exp10(floor: float) -> int:
@@ -495,6 +531,27 @@ WINDOW_FIELDS = (*_WINDOW_INT_KEYS.values(), "shared_expert_scale")
 LINEAR_SPARSE_FIELDS = (*_LINEAR_SPARSE_INT_KEYS.values(), *_SCALE_E6_KEYS.values())
 # every header field mimo_v2_flash added, as models/config.py takes them
 MIXED_HEAD_FIELDS = (*_MIXED_HEAD_INT_KEYS.values(), "attn_value_scale")
+# every header field solar_open2 added, as models/config.py takes them
+DELTA_FIELDS = tuple(_DELTA_INT_KEYS.values())
+
+
+def check_delta(h) -> None:
+    """What a delta-rule layer and a gated full-context layer need of the
+    header (or of a config: the fields have the same names)."""
+    if LayerKind.DELTA in h.layer_kinds and not (
+            h.delta_n_heads > 0 and h.delta_head_dim > 0 and h.delta_conv_kernel >= 2
+            and h.delta_gate_rank > 0):
+        raise ValueError(
+            "a delta-rule layer needs delta_n_heads, delta_head_dim, "
+            "delta_conv_kernel >= 2 and delta_gate_rank")
+    if LayerKind.DELTA in h.layer_kinds and h.parallel_block:
+        raise ValueError("a delta-rule layer belongs to a sequential block")
+    if h.attn_output_gate and (
+            LayerKind.ATTENTION not in h.layer_kinds or LayerKind.WINDOW in h.layer_kinds
+            or LayerKind.SPARSE in h.layer_kinds or h.parallel_block):
+        raise ValueError(
+            "attn_output_gate gates the full-context layers of a sequential block "
+            "without window or block-sparse layers")
 
 
 def check_mixed_heads(h) -> None:
@@ -631,6 +688,8 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
                 setattr(h, _MIXED_HEAD_INT_KEYS[key], value)
             elif key == KEY_ATTN_VALUE_SCALE_E6:
                 h.attn_value_scale = value / 1e6
+            elif key in _DELTA_INT_KEYS:
+                setattr(h, _DELTA_INT_KEYS[key], value)
             else:
                 raise ValueError(f"Unsupported header key {key}")
         if h.weight_type == -1:
@@ -647,6 +706,7 @@ def load_model_header(path: str, max_seq_len: int = 0) -> ModelHeader:
             raise ValueError("a window layer needs sliding_window >= 1")
         check_linear_sparse(h)
         check_mixed_heads(h)
+        check_delta(h)
         h.header_size = header_size
         h.orig_seq_len = h.seq_len
         if max_seq_len > 0 and h.seq_len > max_seq_len:
@@ -812,7 +872,20 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
     ``lin_k``, ``lin_v`` (``linear_n_heads * linear_head_dim`` rows each, q
     and k permuted as an attention layer's), the per-head gains of q's and
     k's norms (F32, permuted alike), ``lin_gate``, the output norm's gain a
-    head channel (F32), ``lin_out``."""
+    head channel (F32), ``lin_out``. A full-context layer under
+    ``attn_output_gate`` (``model_type: solar_open2``): ``attn_gate`` before
+    wo, as a block-sparse layer's. A delta-rule layer, ``D = delta_n_heads *
+    delta_head_dim``, ``r = delta_gate_rank``: ``delta_q``, ``delta_k``,
+    ``delta_v`` (``D`` rows each, nothing permuted: nothing rotates), the
+    three convs' taps in that order (F32, ``[3 D, delta_conv_kernel]``), the
+    decay's low-rank gate ``delta_f1`` (``r`` rows) and ``delta_f2`` (F32,
+    ``[D, r]``: it steers an exponential, and ``r`` need not be whole Q40
+    blocks), ``delta_dt_bias`` (F32, ``D``), ``delta_a_log`` (F32, a number a
+    head), ``delta_b`` (F32, ``[delta_n_heads, dim]``: the step a head steers
+    the update, and 64 outputs are under the Q40 kernel's 128-wide tile), the
+    output gate's ``delta_g1``
+    (``r`` rows) and ``delta_g2`` (F32, ``[D, r]``), the output norm's gain a
+    head channel (F32), ``delta_out``."""
     wt, dim, kv_dim = h.weight_type, h.dim, h.kv_dim
     e, n, r = h.ssm_d_inner, h.ssm_d_state, h.ssm_dt_rank
     for l, kind in enumerate(h.layer_kinds):
@@ -843,6 +916,8 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
             if h.qk_norm:
                 add("block_q_norm", l, FloatType.F32, (1, h.head_size))
                 add("block_k_norm", l, FloatType.F32, (1, h.head_size))
+            if h.attn_output_gate:
+                add("block_matmul_attn_gate", l, wt, (h.o_dim, dim))
             add("block_matmul_wo", l, wt, (dim, h.o_dim))
             if h.window_sink and kind == LayerKind.WINDOW:
                 add("block_attn_sink", l, FloatType.F32, (1, h.n_heads))
@@ -865,6 +940,21 @@ def _pattern_block_specs(h: ModelHeader, add) -> None:
             add("block_matmul_lin_gate", l, wt, (lin, dim))
             add("block_lin_o_norm", l, FloatType.F32, (1, h.linear_head_dim))
             add("block_matmul_lin_out", l, wt, (dim, lin))
+        elif kind == LayerKind.DELTA:
+            dd, rank = h.delta_n_heads * h.delta_head_dim, h.delta_gate_rank
+            add("block_matmul_delta_q", l, wt, (dd, dim))
+            add("block_matmul_delta_k", l, wt, (dd, dim))
+            add("block_matmul_delta_v", l, wt, (dd, dim))
+            add("block_delta_conv_taps", l, FloatType.F32, (3 * dd, h.delta_conv_kernel))
+            add("block_matmul_delta_f1", l, wt, (rank, dim))
+            add("block_delta_f2", l, FloatType.F32, (dd, rank))
+            add("block_delta_dt_bias", l, FloatType.F32, (1, dd))
+            add("block_delta_a_log", l, FloatType.F32, (1, h.delta_n_heads))
+            add("block_delta_b", l, FloatType.F32, (h.delta_n_heads, dim))
+            add("block_matmul_delta_g1", l, wt, (rank, dim))
+            add("block_delta_g2", l, FloatType.F32, (dd, rank))
+            add("block_delta_o_norm", l, FloatType.F32, (1, h.delta_head_dim))
+            add("block_matmul_delta_out", l, wt, (dim, dd))
         else:
             raise ValueError(f"layer {l}: unknown layer kind {kind}")
         if l < h.n_dense_layers or h.n_experts == 0:

@@ -188,15 +188,45 @@ def tiny_sala_header(
     return h
 
 
+def tiny_delta_header(
+    pattern: str = "GDDDGDDD",
+    n_experts: int = 16,
+    experts_held: tuple = (0, 4),
+    gate_rank: int = 8,
+    **kw,
+) -> ModelHeader:
+    """A toy of what ``solar_open2`` adds (models/hybrid.py): ``pattern`` a
+    letter a layer, ``D`` a gated delta-rule layer (a float32 matrix state a
+    head under a decay a key channel, q, k and v through convs of 4 taps, low
+    rank gates of ``gate_rank``, ``b`` in (0, 2)), ``G`` full-context GQA that
+    rotates nothing and gates its output; every FFN routed (sigmoid scores, a
+    selection bias, 4 chosen, one shared expert), of which a share is held."""
+    kw = {"dim": 64, "hidden_dim": 128, "n_heads": 4, "n_kv_heads": 2, "seq_len": 128, **kw}
+    h = tiny_header(n_layers=len(pattern), rope_type=RopeType.NONE, **kw)
+    h.layer_kinds = [LayerKind.DELTA if c == "D" else LayerKind.ATTENTION for c in pattern]
+    h.head_dim, h.attn_output_gate = h.dim // h.n_heads, 1
+    h.delta_n_heads, h.delta_head_dim = h.n_heads, h.dim // h.n_heads
+    h.delta_conv_kernel, h.delta_gate_rank, h.delta_neg_eigval = 4, gate_rank, 1
+    h.n_experts, h.n_active_experts, h.moe_hidden_dim = n_experts, 4, 32
+    h.shared_hidden_dim, h.n_dense_layers = 32, 0
+    h.moe_score_func, h.moe_select_bias, h.moe_norm_topk = MoeScore.SIGMOID, 1, 1
+    h.moe_norm_floor = 0.0
+    h.experts_held_first, h.experts_held_count = experts_held
+    return h
+
+
 def ssm_steering_init(name: str, shape, rng) -> np.ndarray | None:
     """What steers a state-space layer's exponential, as the mixer's own
     published initialisation draws it (it decides how long the state
     remembers): ``A_log = log(1..N)`` a channel, the step's bias such that
-    its softplus is log-uniform in ``[1e-3, 1e-1]``, ``D = 1``. None for any
-    other tensor."""
+    its softplus is log-uniform in ``[1e-3, 1e-1]``, ``D = 1``; and a
+    delta-rule layer's: ``A_log = log(uniform(1, 16))`` a head, the same bias a
+    channel. None for any other tensor."""
     if name == "block_ssm_a_log":
         return np.broadcast_to(np.log(np.arange(1, shape[1] + 1, dtype=np.float32)), shape)
-    if name == "block_ssm_dt_bias":
+    if name == "block_delta_a_log":
+        return np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
+    if name in ("block_ssm_dt_bias", "block_delta_dt_bias"):
         dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape)).astype(np.float32)
         return dt + np.log(-np.expm1(-dt))  # softplus^-1
     if name == "block_ssm_d":

@@ -10,6 +10,8 @@ from ..formats.model_file import (
     WINDOW_FIELDS,
     LINEAR_SPARSE_FIELDS,
     MIXED_HEAD_FIELDS,
+    DELTA_FIELDS,
+    check_delta,
     check_linear_sparse,
     check_mixed_heads,
     HiddenAct,
@@ -172,6 +174,21 @@ class LlamaConfig:
     window_rope_theta: float = 0.0
     attn_value_scale: float = 1.0
     window_sink: int = 0
+    # What ``model_type: solar_open2`` adds, each engaged by its own field.
+    # LayerKind.DELTA among the kinds: a gated delta rule (ops/delta_rule.py),
+    # delta_n_heads heads of delta_head_dim (keys and values alike) whose lane
+    # state is a float32 [head, head] matrix a head under a decay a key
+    # CHANNEL; q, k and v pass through causal depthwise convs of
+    # delta_conv_kernel taps (a lane keeps their last delta_conv_kernel - 1
+    # inputs), the decay and the output gate are low-rank of delta_gate_rank,
+    # and b spans (0, 2) where delta_neg_eigval. attn_output_gate: a
+    # full-context layer's output is gated by sigmoid(W_g n) before wo.
+    delta_n_heads: int = 0
+    delta_head_dim: int = 0
+    delta_conv_kernel: int = 0
+    delta_gate_rank: int = 0
+    delta_neg_eigval: int = 0
+    attn_output_gate: int = 0
 
     def __post_init__(self):
         if self.n_experts > 0 and not (1 <= self.n_active_experts <= self.n_experts):
@@ -261,6 +278,7 @@ class LlamaConfig:
                     "a linear-attention layer's heads are as wide as the attention "
                     "layers' (one rotation table)")
         check_mixed_heads(self)
+        check_delta(self)
         if self.residual_scale != 1.0 and (
             self.n_linear_layers + self.n_sparse_layers != self.n_layers or not self.layer_kinds
         ):
@@ -296,6 +314,15 @@ class LlamaConfig:
         return sum(k == LayerKind.SPARSE for k in self.layer_kinds)
 
     @property
+    def n_delta_layers(self) -> int:
+        return sum(k == LayerKind.DELTA for k in self.layer_kinds)
+
+    @property
+    def delta_dim(self) -> int:
+        """Width of a delta-rule layer's queries, keys and values."""
+        return self.delta_n_heads * self.delta_head_dim
+
+    @property
     def linear_dim(self) -> int:
         """Width of a linear-attention layer's queries, keys and values."""
         return self.linear_n_heads * self.linear_head_dim
@@ -306,7 +333,7 @@ class LlamaConfig:
         stack holds (a window layer keeps a ring of its own; a block-sparse
         layer's planes are among them)."""
         return (self.n_layers - self.n_conv_layers - self.n_ssm_layers
-                - self.n_window_layers - self.n_linear_layers)
+                - self.n_window_layers - self.n_linear_layers - self.n_delta_layers)
 
     @property
     def recurrent_state(self) -> bool:
@@ -315,7 +342,7 @@ class LlamaConfig:
         copies one at another position than its last holds for it. A window
         layer's ring is such a state."""
         return (self.n_conv_layers > 0 or self.n_ssm_layers > 0 or self.n_window_layers > 0
-                or self.n_linear_layers > 0)
+                or self.n_linear_layers > 0 or self.n_delta_layers > 0)
 
     @property
     def n_routed_layers(self) -> int:
@@ -431,4 +458,5 @@ class LlamaConfig:
             **{name: getattr(h, name) for name in WINDOW_FIELDS},
             **{name: getattr(h, name) for name in LINEAR_SPARSE_FIELDS},
             **{name: getattr(h, name) for name in MIXED_HEAD_FIELDS},
+            **{name: getattr(h, name) for name in DELTA_FIELDS},
         )

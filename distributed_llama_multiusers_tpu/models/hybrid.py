@@ -1,6 +1,7 @@
 """A block whose layers differ in their mixer (``model_type: lfm2_moe``,
-``jamba``, ``cohere2_moe``, ``minicpm_sala``): gated short convolutions,
-selective state-space mixers, linear attention, GQA attention over the whole
+``jamba``, ``cohere2_moe``, ``minicpm_sala``, ``solar_open2``): gated short
+convolutions, selective state-space mixers, linear attention, gated
+delta-rule layers, GQA attention over the whole
 context, over a window of it and over the blocks of it that a row chooses, in
 a published per-layer pattern, leading dense FFNs, then routed ones (or dense
 ones throughout). Assembled from the parts of the other two
@@ -41,6 +42,17 @@ The layer (``h`` the stream, ``K = conv_kernel``):
                 first and the window's among them (ops/block_sparse.py), a
                 set a kv head; a row under it over every s <= t
                 h' = h + c Wo (o * sigmoid(W_g n))
+    delta:      ``delta_n_heads`` heads of ``d = delta_head_dim``, nothing
+                rotated; q~, k~, v~ = W_q n, W_k n, W_v n, each through its own
+                causal depthwise conv of ``delta_conv_kernel`` taps and a silu;
+                q = q' / |q'| / sqrt(d), k = k' / |k'| per head (eps 1e-6)
+                g_t = -exp(A_log_i) * softplus(W_f2 (W_f1 n) + dt_bias)  a key channel
+                b_t = 2 sigmoid(W_b n) (``delta_neg_eigval``; else sigmoid)
+                S_t = (I - b_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + b_t k_t v_t^T,  o_t = S_t^T q_t
+                h' = h + W_out (rmsnorm(o_t, g_o) * sigmoid(W_g2 (W_g1 n)))
+                (ops/delta_rule.py; float32)
+    gated:      ``attn_output_gate``: a full-context layer's output is
+                multiplied by sigmoid(W_g n) before wo, as a sparse layer's is
     ``c = residual_scale`` multiplies every mixer's and FFN's term in such a
     block, ``embed_scale`` the embedding, and the final norm's output is
     divided by ``logit_divisor`` before the head.
@@ -73,7 +85,12 @@ and, where the block has state-space layers, their running sum ``ssm``
 conv's window ``ssm_conv`` ``[SSM layers, lanes, (K'-1) * E]`` (both None in a
 block without such layers: its programs are what they were); the linear
 layers' matrix state ``lin`` ``[linear layers, lanes, H * d * d]``, FLOAT32
-too and under the running sum's rule; and, kept by position like the planes,
+too and under the running sum's rule; the delta-rule layers' matrix state
+``delta`` ``[delta layers, lanes, H * d * d]``, FLOAT32 and under the same rule
+(a row that is not real takes ``g = 0`` and ``b = 0``), and the windows of
+their three convs' inputs in ONE leaf ``delta_conv`` ``[delta layers, lanes,
+(K-1) * 3 * H * d]`` (q~, k~, v~ side by side in a row, under the conv
+state's rule below); and, kept by position like the planes,
 the sparse layers' compressed keys ``ck`` ``[sparse layers, lanes, S /
 kernel_stride, n_kv * head]`` (a sparse layer's K and V are planes of ``k``
 and ``v``).
@@ -155,6 +172,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..formats.model_file import LayerKind, NormKind
 from ..ops import block_sparse, blocked_attention, pallas_attention
 from ..ops.linear import head, matmul, pallas_interpret, pallas_kernel_active
+from ..ops.delta_rule import delta_rule
 from ..ops.linear_attention import decay_slopes, linear_attention
 from ..ops.norm import layer_norm, rms_norm
 from ..ops.rope import apply_rope
@@ -166,6 +184,8 @@ from ..telemetry.names import (
     SCOPE_BLOCK_SCORES,
     SCOPE_CONV,
     SCOPE_CONV_STATE,
+    SCOPE_DELTA,
+    SCOPE_DELTA_CONV,
     SCOPE_EMBED,
     SCOPE_KV_WRITE,
     SCOPE_LAYERS,
@@ -209,7 +229,8 @@ class GqaParams(NamedTuple):
     q_norm: jnp.ndarray | None  # [La, head] f32 (config.qk_norm)
     k_norm: jnp.ndarray | None
     rms: jnp.ndarray  # [La, dim]: the layer's operator norm
-    # block-sparse layers only (None elsewhere): the output gate
+    # the output gate: every block-sparse layer's, and the full-context
+    # layers' under config.attn_output_gate (None elsewhere)
     gate: jnp.ndarray | None = None  # [La, dim, n_heads * head_size]
     # the window kind's own (None elsewhere): its K/V projections, and the
     # sink's logit a query head (config.window_sink), float32
@@ -231,6 +252,27 @@ class LinearParams(NamedTuple):
     o_norm: jnp.ndarray  # [Ll, head] f32: the per-head norm of the output
     w_out: jnp.ndarray  # [Ll, D, dim]
     rms: jnp.ndarray  # [Ll, dim]: the layer's input norm
+
+
+class DeltaParams(NamedTuple):
+    """The delta-rule layers' weights, stacked ``[delta layers, ...]``; ``D =
+    delta_n_heads * delta_head_dim``, ``r = delta_gate_rank``. What steers the
+    state's exponential is float32 whatever the activations are."""
+
+    wq: jnp.ndarray  # [Ld, dim, D]
+    wk: jnp.ndarray
+    wv: jnp.ndarray
+    taps: jnp.ndarray  # [Ld, K, 3 * D] f32: q's, k's and v's convs side by side
+    f1: jnp.ndarray  # [Ld, dim, r]: the decay's low-rank gate
+    f2: jnp.ndarray  # [Ld, r, D] f32
+    dt_bias: jnp.ndarray  # [Ld, D] f32
+    a_log: jnp.ndarray  # [Ld, H] f32: the decay's rate a head is -exp(a_log)
+    wb: jnp.ndarray  # [Ld, dim, H] f32: the step b a head
+    g1: jnp.ndarray  # [Ld, dim, r]: the output gate, low-rank
+    g2: jnp.ndarray  # [Ld, r, D] f32
+    o_norm: jnp.ndarray  # [Ld, head] f32: the per-head norm of the output
+    w_out: jnp.ndarray  # [Ld, D, dim]
+    rms: jnp.ndarray  # [Ld, dim]: the layer's input norm
 
 
 class ConvParams(NamedTuple):
@@ -277,6 +319,7 @@ class HybridParams(NamedTuple):
     # (config.window_rope_theta; None: the ones above)
     rope_cos_w: jnp.ndarray | None = None
     rope_sin_w: jnp.ndarray | None = None
+    delta: DeltaParams | None = None
 
 
 class HybridCache(NamedTuple):
@@ -298,6 +341,10 @@ class HybridCache(NamedTuple):
     # block-sparse layers only (None elsewhere): the compressed keys, one a kv
     # head every kernel_stride positions, kept by position like the planes
     ck: jnp.ndarray | None = None  # [Lp, lanes, S / kernel_stride, n_kv * head]
+    # delta-rule layers only (None elsewhere): the matrix a head, float32
+    # whatever the cache's type, and the three convs' last K-1 inputs
+    delta: jnp.ndarray | None = None  # [Ld, lanes, H * head * head]
+    delta_conv: jnp.ndarray | None = None  # [Ld, lanes, (K-1) * 3 * H * head]
 
 
 def ring_rows(config: LlamaConfig, max_chunk: int) -> int:
@@ -314,7 +361,11 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
     bucket): it sizes the ring (``ring_rows``)."""
     plane = (config.n_attention_layers, n_lanes, config.seq_len)
     k_dim, v_dim = config.kv_widths()
-    ssm = ssm_conv = wk = wv = lin = ck = None
+    ssm = ssm_conv = wk = wv = lin = ck = delta = delta_conv = None
+    if config.n_delta_layers:
+        ld, dd = config.n_delta_layers, config.delta_dim
+        delta = jnp.zeros((ld, n_lanes, dd * config.delta_head_dim), jnp.float32)
+        delta_conv = jnp.zeros((ld, n_lanes, (config.delta_conv_kernel - 1) * 3 * dd), dtype)
     if config.n_linear_layers:
         lin = jnp.zeros(
             (config.n_linear_layers, n_lanes,
@@ -336,6 +387,7 @@ def init_hybrid_cache(config: LlamaConfig, n_lanes: int, dtype=jnp.float32,
         conv=jnp.zeros(
             (config.n_conv_layers, n_lanes, max(config.conv_kernel - 1, 0) * config.dim), dtype),
         ssm=ssm, ssm_conv=ssm_conv, wk=wk, wv=wv, lin=lin, ck=ck,
+        delta=delta, delta_conv=delta_conv,
     )
 
 
@@ -345,7 +397,8 @@ def state_leaves(cache) -> tuple:
     if not isinstance(cache, HybridCache):
         return ()
     return tuple(
-        x for x in (cache.conv, cache.ssm, cache.ssm_conv, cache.wk, cache.wv, cache.lin)
+        x for x in (cache.conv, cache.ssm, cache.ssm_conv, cache.wk, cache.wv, cache.lin,
+                    cache.delta, cache.delta_conv)
         if x is not None)
 
 
@@ -379,7 +432,13 @@ def hybrid_params(t: dict, rope_cos, rope_sin, rope_cos_w=None, rope_sin_w=None)
     def experts(w):
         return Q40Experts.from_packed(w) if isinstance(w, PackedQ40) else w
 
-    attn = conv = dense = routed = ssm = linear = None
+    attn = conv = dense = routed = ssm = linear = delta = None
+    if "delta_q" in t:
+        delta = DeltaParams(
+            wq=t["delta_q"], wk=t["delta_k"], wv=t["delta_v"], taps=t["delta_taps"],
+            f1=t["delta_f1"], f2=t["delta_f2"], dt_bias=t["delta_dt_bias"],
+            a_log=t["delta_a_log"], wb=t["delta_b"], g1=t["delta_g1"], g2=t["delta_g2"],
+            o_norm=t["delta_o_norm"], w_out=t["delta_out"], rms=t["delta_rms"])
     if "wq" in t:
         attn = GqaParams(
             wq=t["wq"], wk=t["wk"], wv=t["wv"], wo=t["wo"],
@@ -415,6 +474,7 @@ def hybrid_params(t: dict, rope_cos, rope_sin, rope_cos_w=None, rope_sin_w=None)
         embedding=t["embedding"], attn=attn, conv=conv, dense=dense, routed=routed,
         rms_final=t["rms_final"], wcls=t["wcls"], rope_cos=rope_cos, rope_sin=rope_sin,
         ssm=ssm, linear=linear, rope_cos_w=rope_cos_w, rope_sin_w=rope_sin_w,
+        delta=delta,
     )
 
 
@@ -440,6 +500,17 @@ def short_conv(window, taps, t: int):
     )
 
 
+def output_gate(yq, w):
+    """``sigmoid(W_g n)``, float32: what a gated mixer's output is multiplied
+    by before its out-projection (the block-sparse kind, the full-context
+    kind under ``attn_output_gate``, linear attention)."""
+    return jax.nn.sigmoid(matmul(yq, w).astype(jnp.float32))
+
+
+# the eps under a delta-rule layer's L2 norms of q and k
+DELTA_L2_EPS = 1e-6
+
+
 # layers of one kind in a row, inside a period, that run as a scan of their
 # own and not unrolled: a period of thirteen state-space layers and one
 # attention layer unrolled is fourteen layers compiled into every one of a
@@ -460,11 +531,11 @@ def kind_runs(kinds: tuple) -> list:
 
 # a layer kind's place in the counts a layer is read by
 KIND_SLOTS = (LayerKind.ATTENTION, LayerKind.CONV, LayerKind.SSM, LayerKind.WINDOW,
-              LayerKind.LINEAR, LayerKind.SPARSE)
+              LayerKind.LINEAR, LayerKind.SPARSE, LayerKind.DELTA)
 
 
 def kinds_after(kind, nth: tuple) -> tuple:
-    """The (attention, conv, state-space, window, linear, sparse) counts after
+    """The (attention, conv, state-space, window, linear, sparse, delta) counts after
     one more layer of ``kind``."""
     slot = KIND_SLOTS.index(kind)
     return tuple(n + (k == slot) for k, n in enumerate(nth))
@@ -601,6 +672,7 @@ def hybrid_forward_counted(
                 cos if rotate else None, sin if rotate else None,
                 norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None, n_kv=n_kv,
             )
+            gate = output_gate(yq, ap.gate) if ap.gate is not None else None
         with jax.named_scope(SCOPE_KV_WRITE):
             k_all, v_all = kv_append(
                 k_all, v_all, (ci, lane_idx, ring_at if windowed else positions),
@@ -628,7 +700,10 @@ def hybrid_forward_counted(
             if cfg.attn_value_scale != 1.0:
                 # every value is scaled: by linearity the float32 sum is
                 attn = attn.astype(jnp.float32) * cfg.attn_value_scale
-            attn = attn.reshape(b, t, cfg.o_dim).astype(dtype)
+            attn = attn.reshape(b, t, cfg.o_dim)
+            if gate is not None:
+                attn = attn * gate
+            attn = attn.astype(dtype)
         with jax.named_scope(SCOPE_ATTN_OUT):
             out = maybe_qdq(matmul(maybe_qdq(attn), ap.wo))
             if not cfg.parallel_block:
@@ -647,7 +722,7 @@ def hybrid_forward_counted(
             q, k, v = gqa_project(
                 cfg, yq, ap.wq, ap.wk, ap.wv, positions, None, None,
                 norms=(ap.q_norm, ap.k_norm) if cfg.qk_norm else None)
-            gate = jax.nn.sigmoid(matmul(yq, ap.gate).astype(jnp.float32))
+            gate = output_gate(yq, ap.gate)
         with jax.named_scope(SCOPE_KV_WRITE):
             k_all, v_all = kv_append(
                 k_all, v_all, (at, lane_idx, positions),
@@ -700,12 +775,46 @@ def hybrid_forward_counted(
             v = matmul(yq, lp.wv).reshape(heads)
             q = apply_rope(q, params.rope_cos, params.rope_sin, positions)
             k = apply_rope(k, params.rope_cos, params.rope_sin, positions)
-            gate = jax.nn.sigmoid(matmul(yq, lp.gate).astype(jnp.float32))
+            gate = output_gate(yq, lp.gate)
             o, lin_all = linear_attention(
                 lin_all, li, from_zero, q, k, v, real_row[:, :, 0], slopes, lin_scale)
             o = rms_norm(o, lp.o_norm, eps).reshape(b, t, cfg.linear_dim)
             x = x + res * maybe_qdq(matmul(maybe_qdq((o * gate).astype(dtype)), lp.w_out))
         return x, lin_all
+
+    def delta(x, di, s_all, w_all):
+        """A delta-rule layer: q, k and v through their short convs and a
+        silu, q and k L2-normed per head, the decay a key channel and the step
+        a head from the normed input, the matrix state (ops/delta_rule.py),
+        the output normed per head and gated."""
+        dp = DeltaParams(*(_pick(leaf, di) for leaf in params.delta))
+        n_h, d = cfg.delta_n_heads, cfg.delta_head_dim
+        heads, f32 = (b, t, n_h, d), jnp.float32
+        # the float32 factors (what steers the state: module header)
+        low_rank = lambda y, w: jnp.einsum(  # noqa: E731
+            "btr,rd->btd", y.astype(f32), w, precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope(SCOPE_DELTA):
+            y = norm(x, dp.rms)
+            yq = maybe_qdq(y)
+            qkv = jnp.concatenate([matmul(yq, w) for w in (dp.wq, dp.wk, dp.wv)], axis=-1)
+            with jax.named_scope(SCOPE_DELTA_CONV):
+                window, w_all = window_step(w_all, di, qkv, cfg.delta_conv_kernel)
+                qkv = jax.nn.silu(short_conv(window, dp.taps, t))  # float32
+            q, k, v = (a.reshape(heads) for a in jnp.split(qkv, 3, axis=-1))
+            l2 = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+                jnp.sum(a * a, axis=-1, keepdims=True) + DELTA_L2_EPS)
+            q, k = l2(q) * (1.0 / float(d) ** 0.5), l2(k)
+            # the decay steers an exponential: a float32 product
+            g = jax.nn.softplus(low_rank(matmul(yq, dp.f1), dp.f2) + dp.dt_bias)
+            g = -jnp.exp(dp.a_log)[:, None] * g.reshape(heads)
+            beta = jax.nn.sigmoid(low_rank(yq, dp.wb))
+            if cfg.delta_neg_eigval:
+                beta = 2.0 * beta
+            gate = jax.nn.sigmoid(low_rank(matmul(yq, dp.g1), dp.g2))
+            o, s_all = delta_rule(s_all, di, from_zero, q, k, v, g, beta, real_row[:, :, 0])
+            o = rms_norm(o, dp.o_norm, eps).reshape(b, t, cfg.delta_dim)
+            x = x + maybe_qdq(matmul(maybe_qdq((o * gate).astype(dtype)), dp.w_out))
+        return x, s_all, w_all
 
     def window_attention(q, wi, k_all, v_all, n_kv, sink):
         """Window layer ``wi``'s read of its ring, after the append: the
@@ -778,11 +887,11 @@ def hybrid_forward_counted(
 
     # the carry: the stream, then every state stack (a kind the block lacks
     # is None and no leaf), then a routed model's counts (ROUTED_COUNTS)
-    def mixer(kind, carry, ai, ci, si, wi, li, pi):
+    def mixer(kind, carry, ai, ci, si, wi, li, pi, di):
         """The layer's mixer on the carry; with it, in a parallel block, the
         mixer's term and the normed input it read (else None)."""
         (x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, lin_all, ck_all,
-         *counts) = carry
+         dl_all, dlw_all, *counts) = carry
         parallel = None
         if kind == LayerKind.CONV:
             x, s_all = conv(x, ci, s_all)
@@ -790,6 +899,8 @@ def hybrid_forward_counted(
             x, ssm_all, win_all = ssm(x, si, ssm_all, win_all)
         elif kind == LayerKind.LINEAR:
             x, lin_all = linear(x, li, lin_all)
+        elif kind == LayerKind.DELTA:
+            x, dl_all, dlw_all = delta(x, di, dl_all, dlw_all)
         elif kind == LayerKind.SPARSE:
             x, k_all, v_all, ck_all = sparse_attention(x, ai + pi, pi, k_all, v_all, ck_all)
         else:
@@ -802,7 +913,7 @@ def hybrid_forward_counted(
                 out, normed, k_all, v_all = attention(x, at, ai, k_all, v_all)
             x, parallel = (x, (out, normed)) if cfg.parallel_block else (out, None)
         return (x, k_all, v_all, s_all, ssm_all, win_all, wk_all, wv_all, lin_all, ck_all,
-                *counts), parallel
+                dl_all, dlw_all, *counts), parallel
 
     def kinds_before(lo, hi):
         """Layers of each kind (``KIND_SLOTS``) among ``kinds[lo:hi]``."""

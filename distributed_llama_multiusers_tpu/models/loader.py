@@ -175,7 +175,9 @@ _LATENT_BY_LAYER = {"wq", "wkva", "wkvb", "wo", "rms_att", "rms_kv", "wqa", "rms
 # float32 tensors the walk stores [d_out, d_in] (or [channels, taps]) and the
 # blocks read the other way round
 _TRANSPOSED_F32 = {"block_moe_gate", "block_conv_taps", "block_idx_weights",
-                   "block_ssm_conv_taps", "block_ssm_dt_proj", "block_ssm_a_log"}
+                   "block_ssm_conv_taps", "block_ssm_dt_proj", "block_ssm_a_log",
+                   "block_delta_conv_taps", "block_delta_f2", "block_delta_g2",
+                   "block_delta_b"}
 
 
 def _load_stacked(path: str, header: ModelHeader, dtype, put, quantized: bool,
@@ -306,12 +308,28 @@ _PATTERN_NAME_MAP = {
     "block_matmul_lin_out": "lin_out",
     # a window layer's learned sink a query head (header.window_sink)
     "block_attn_sink": "attn_sink",
+    # a delta-rule layer (LayerKind.DELTA)
+    "block_matmul_delta_q": "delta_q",
+    "block_matmul_delta_k": "delta_k",
+    "block_matmul_delta_v": "delta_v",
+    "block_delta_conv_taps": "delta_taps",
+    "block_matmul_delta_f1": "delta_f1",
+    "block_delta_f2": "delta_f2",
+    "block_delta_dt_bias": "delta_dt_bias",
+    "block_delta_a_log": "delta_a_log",
+    "block_delta_b": "delta_b",
+    "block_matmul_delta_g1": "delta_g1",
+    "block_delta_g2": "delta_g2",
+    "block_delta_o_norm": "delta_o_norm",
+    "block_matmul_delta_out": "delta_out",
 }
 _PATTERN_F32 = {"attn_sink", "conv_taps", "q_norm", "k_norm", "moe_gate", "moe_bias",
                 "rms_ffn", "dense_rms_ffn", "attn_rms", "conv_rms", "ssm_rms", "ssm_taps",
                 "ssm_conv_bias", "ssm_dt_norm", "ssm_b_norm", "ssm_c_norm",
                 "ssm_dt_proj", "ssm_dt_bias", "ssm_a_log", "ssm_d",
-                "lin_rms", "lin_q_norm", "lin_k_norm", "lin_o_norm"}
+                "lin_rms", "lin_q_norm", "lin_k_norm", "lin_o_norm",
+                "delta_rms", "delta_taps", "delta_f2", "delta_dt_bias", "delta_a_log",
+                "delta_b", "delta_g2", "delta_o_norm"}
 
 
 def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat16,
@@ -320,13 +338,14 @@ def load_pattern_params_from_m(path: str, header: ModelHeader, dtype=jnp.bfloat1
     models/hybrid.py runs: each kind's tensors stacked by the count of that
     kind, the dense FFNs by layer, the routed ones by routed layer (and
     expert). The taps, the per-head norm gains, the router, its bias, the
-    norms and what steers a state-space layer's exponential are float32
-    whatever ``dtype`` is."""
+    norms and what steers a state-space or a delta-rule layer's exponential
+    are float32 whatever ``dtype`` is."""
     from ..formats.model_file import LayerKind
     from .hybrid import hybrid_params
 
     mixer_rms = {LayerKind.CONV: "conv_rms", LayerKind.SSM: "ssm_rms",
-                 LayerKind.ATTENTION: "attn_rms", LayerKind.LINEAR: "lin_rms"}
+                 LayerKind.ATTENTION: "attn_rms", LayerKind.LINEAR: "lin_rms",
+                 LayerKind.DELTA: "delta_rms"}
     config = LlamaConfig.from_header(header)
     put = device_put_fn or (lambda name, x: jnp.asarray(x))
     n_dense = config.n_dense_layers if config.n_experts else config.n_layers

@@ -61,7 +61,7 @@ from ..models.hybrid import (
     ring_attention_engages,
     state_leaves,
 )
-from ..ops import blocked_attention, pallas_attention
+from ..ops import blocked_attention, delta_rule, pallas_attention
 from ..quants.packed import q40_at_rest
 from ..telemetry.logs import log_event
 from ..telemetry import names
@@ -416,6 +416,13 @@ class EngineStats:
     # summed over the linear layers, every row of the bucket the chunk rode
     linear_state_bytes_moved: int = 0
     linear_rows_computed: int = 0
+    # delta-rule layers (config.n_delta_layers; 0 elsewhere), counted as the
+    # two above: bytes of float32 matrix state the decode steps read and
+    # wrote (a live lane's, every delta layer, in and out), by the scheduler;
+    # (row, layer) pairs through the chunk form, every row of the bucket a
+    # chunk rode, by the engine
+    delta_state_bytes_moved: int = 0
+    delta_rows_computed: int = 0
     attn_blocks_read: int = 0
     attn_blocks_held: int = 0
     sparse_lane_steps: int = 0
@@ -491,7 +498,8 @@ class EngineStats:
             "indexer_rows_scored", "sparse_rows_selected",
             "recurrent_state_bytes", "state_zero_starts", "prefix_reuse_declined",
             "ssm_lane_steps", "ssm_rows_scanned", "ssm_rows_computed",
-            "linear_state_bytes_moved", "linear_rows_computed", "attn_blocks_read",
+            "linear_state_bytes_moved", "linear_rows_computed",
+            "delta_state_bytes_moved", "delta_rows_computed", "attn_blocks_read",
             "attn_blocks_held",
             "sparse_lane_steps",
             "attn_window_rows_read", "attn_full_rows_read", "attn_window_rows_plane",
@@ -543,6 +551,7 @@ class EngineStats:
             self.state_zero_starts = self.prefix_reuse_declined = 0
             self.ssm_lane_steps = self.ssm_rows_scanned = self.ssm_rows_computed = 0
             self.linear_state_bytes_moved = self.attn_blocks_read = self.linear_rows_computed = 0
+            self.delta_state_bytes_moved = self.delta_rows_computed = 0
             self.attn_blocks_held = self.sparse_lane_steps = 0
             self.attn_window_rows_read = self.attn_full_rows_read = 0
             self.attn_window_rows_plane = 0
@@ -681,6 +690,7 @@ class InferenceEngine:
                 "a model with a per-layer pattern of mixers "
                 f"({config.n_conv_layers} conv, {config.n_ssm_layers} state-space, "
                 f"{config.n_linear_layers} linear-attention, "
+                f"{config.n_delta_layers} delta-rule, "
                 f"{config.n_attention_layers} attention layers) keeps a stack a kind "
                 "on one device",
                 "a lane's state is not pages of a K/V pair a layer",
@@ -1837,6 +1847,18 @@ class InferenceEngine:
                 linear_attention_layers=cfg.n_linear_layers,
                 linear_state_bytes=self.cache.lin.nbytes,
             )
+        if cfg.n_delta_layers:
+            # two leaves a lane beside the planes: the float32 matrix state
+            # and the three convs' windows; and which one-row path a decode
+            # step's update takes (ops/delta_rule.py)
+            facts.update(
+                delta_rule_layers=cfg.n_delta_layers,
+                delta_state_bytes=self.cache.delta.nbytes,
+                delta_conv_window_bytes=self.cache.delta_conv.nbytes,
+                delta_state_path=delta_rule.one_row_path(
+                    self.n_lanes, cfg.delta_n_heads, cfg.delta_head_dim),
+                kv_plane_bytes=self.cache.k.nbytes + self.cache.v.nbytes,
+            )
         if cfg.n_sparse_layers:
             # a third kind of cache a lane beside planes and matrix state
             facts.update(
@@ -2009,6 +2031,7 @@ class InferenceEngine:
             self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
             self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
             self.stats.linear_rows_computed += bucket * self.config.n_linear_layers
+            self.stats.delta_rows_computed += bucket * self.config.n_delta_layers
             visited, causal = self._prefill_attn_blocks(start_pos, len(chunk), bucket)
             self.stats.prefill_attn_blocks_visited += visited
             self.stats.prefill_attn_blocks_causal += causal
@@ -2405,6 +2428,7 @@ class InferenceEngine:
             self.stats.ssm_rows_scanned += len(chunk) * self.config.n_ssm_layers
             self.stats.ssm_rows_computed += bucket * self.config.n_ssm_layers
             self.stats.linear_rows_computed += bucket * self.config.n_linear_layers
+            self.stats.delta_rows_computed += bucket * self.config.n_delta_layers
             visited, causal = self._prefill_attn_blocks(p_start, len(chunk), bucket)
             self.stats.prefill_attn_blocks_visited += visited
             self.stats.prefill_attn_blocks_causal += causal

@@ -483,9 +483,14 @@ class ContinuousBatchingScheduler:
         # linear-attention layers' state bytes a live lane a decode step (in
         # and out), and the block-sparse layers' sizes: the decode steps'
         # linear_state_bytes_moved, attn_blocks_* and sparse_lane_steps
-        self._linear_step_bytes = 2 * 4 * int(getattr(cfg, "n_linear_layers", 0) or 0) * (
-            int(getattr(cfg, "linear_n_heads", 0) or 0)
-            * int(getattr(cfg, "linear_head_dim", 0) or 0) ** 2)
+        # (and the delta-rule layers': delta_state_bytes_moved)
+        def matrix_step_bytes(kind: str) -> int:  # float32, in and out, every layer of the kind
+            layers, heads, width = (int(getattr(cfg, name.format(kind), 0) or 0) for name in (
+                "n_{}_layers", "{}_n_heads", "{}_head_dim"))
+            return 2 * 4 * layers * heads * width ** 2
+
+        self._linear_step_bytes = matrix_step_bytes("linear")
+        self._delta_step_bytes = matrix_step_bytes("delta")
         self._sparse_sizes = (
             SparseSizes.of(cfg) if int(getattr(cfg, "n_sparse_layers", 0) or 0) else None)
         # a held share of the routed experts, "16/256" (None: every expert)
@@ -952,7 +957,8 @@ class ContinuousBatchingScheduler:
         # fetched is `read` itself (the pair above stays that kind's)
         ring_read = full_needed = window_needed = 0
         at = None
-        if self._window or self._linear_step_bytes or self._sparse_sizes:
+        if (self._window or self._linear_step_bytes or self._delta_step_bytes
+                or self._sparse_sizes):
             # dlint: ok[host-sync] the host's own lane positions (numpy ints), no device value
             at = np.asarray(positions, np.int64)[:, None] + np.arange(steps)[None, :]
             at = at[at < seq_len]  # a lane's steps inside the context
@@ -966,7 +972,7 @@ class ContinuousBatchingScheduler:
                 else sum(ring_rows_read(positions + s, seq_len, self._window, ring_block)
                          for s in range(steps)))
         blocks_read = blocks_held = choosing = live_steps = 0
-        if self._linear_step_bytes or self._sparse_sizes:
+        if at is not None:  # the lanes' steps were laid out above: one condition, not two
             live_steps = int(at.size)
             if self._sparse_sizes:
                 attended, held = blocks_attended(at, self._sparse_sizes)
@@ -979,6 +985,7 @@ class ContinuousBatchingScheduler:
             engine.stats.attn_kv_rows_whole += whole
             engine.stats.ssm_lane_steps += ssm
             engine.stats.linear_state_bytes_moved += live_steps * self._linear_step_bytes
+            engine.stats.delta_state_bytes_moved += live_steps * self._delta_step_bytes
             engine.stats.attn_blocks_read += blocks_read
             engine.stats.attn_blocks_held += blocks_held
             engine.stats.sparse_lane_steps += choosing

@@ -437,6 +437,9 @@ class ApiServer:
             # at or past sparse_dense_len; all 0 for a model without them
             "linear_state_bytes_moved": stats["linear_state_bytes_moved"],
             "linear_rows_computed": stats["linear_rows_computed"],
+            # delta-rule layers, counted alike (0 for a model without them)
+            "delta_state_bytes_moved": stats["delta_state_bytes_moved"],
+            "delta_rows_computed": stats["delta_rows_computed"],
             "attn_blocks_read": stats["attn_blocks_read"],
             "attn_blocks_held": stats["attn_blocks_held"],
             "sparse_lane_steps": stats["sparse_lane_steps"],

@@ -91,6 +91,12 @@ SCOPE_WINDOW_ATTENTION = "dl.window_attention"  # under dl.attention: a window l
 SCOPE_LINEAR_ATTENTION = "dl.linear_attention"  # the mixer: norm, projections, head norms, rotation, gate, out-projection
 SCOPE_LINEAR_STATE = "dl.linear_state"  # under dl.linear_attention: the matrix state's read, the recurrence and its commit
 LINEAR_MIXER_SCOPES = (SCOPE_LINEAR_ATTENTION, SCOPE_LINEAR_STATE)
+# a gated delta-rule layer in such a block (LayerKind.DELTA; ops/delta_rule.py)
+# takes dl.delta in the place of the four attention scopes
+SCOPE_DELTA = "dl.delta"  # the mixer: norm, projections, L2 norms, the decay's and b's gates, output norm and gate, out-projection
+SCOPE_DELTA_CONV = "dl.delta_conv"  # under dl.delta: the three short convs and their windows' read and commit
+SCOPE_DELTA_STATE = "dl.delta_state"  # under dl.delta: the matrix state's read, the recurrence or chunk form, its commit
+DELTA_MIXER_SCOPES = (SCOPE_DELTA, SCOPE_DELTA_CONV, SCOPE_DELTA_STATE)
 # a block-sparse layer (LayerKind.SPARSE) keeps the four attention scopes and
 # adds two beside dl.attention: dl.block_scores, and dl.sparse_select (above)
 # for the top-k of the block scores and the list or mask made of it

@@ -20,7 +20,7 @@ import sys
 
 TOYS = ("tiny.json", "tiny_bias.json", "tiny_moe.json", "tiny_latent.json",
         "tiny_deepseek_v32.json", "tiny_lfm2.json", "tiny_jamba.json", "tiny_cohere2_moe.json",
-        "tiny_minicpm_sala.json")
+        "tiny_minicpm_sala.json", "tiny_mimo_v2_flash.json", "tiny_solar_open2.json")
 
 
 def _normalized(text: str) -> str:

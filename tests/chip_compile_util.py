@@ -184,7 +184,7 @@ def _scales_stack_converted_whole(hlo: str, d_in: int, d_out: int) -> bool:
 
 
 # PR 45: one block of rows a call. Every distinct (d_in, d_out) that the
-# benchmark's eight configurations send through the slab kernel (the routed
+# benchmark's ten configurations send through the slab kernel (the routed
 # experts' [L, E, ...] slabs go through ops/pallas_q40_grouped.py), and
 # whether the configuration holds it as a stack of layers (the kernel is
 # handed the stack and a layer index) or as one plane (the heads). The list
@@ -198,9 +198,12 @@ CELL_SHAPES = [
     (2560, 10240, True), (2560, 65536, False), (3584, 512, True),
     (3584, 3584, True), (3584, 18944, True), (3584, 152064, False),
     (4096, 256, True), (4096, 512, True), (4096, 768, True), (4096, 1024, True),
-    (4096, 1536, True), (4096, 2048, True), (4096, 4096, True), (4096, 12288, True),
-    (4096, 14336, True), (4096, 16384, True), (4096, 19072, False), (4096, 32768, False),
-    (4096, 73728, False),
+    (4096, 128, True), (4096, 1280, True),
+    (4096, 1536, True), (4096, 2048, True), (4096, 4096, True), (4096, 8192, True),
+    (4096, 12288, True),
+    (4096, 14336, True), (4096, 16384, True), (4096, 19072, False), (4096, 24576, False),
+    (4096, 32768, False),
+    (4096, 73728, False), (1280, 4096, True),
     (5120, 192, True),
     (5120, 2560, True), (6144, 2048, True), (7168, 128, True),
     (7168, 576, True), (7168, 1536, True), (7168, 2048, True),

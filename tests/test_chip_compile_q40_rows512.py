@@ -23,7 +23,7 @@ from chip_compile_util import (  # noqa: F401  (v5e, v5e_devices: the fixtures)
 
 def _config_file_shapes():
     """{(d_in, d_out, stacked)} of every PackedQ40 leaf of rank 2 or 3 in the
-    parameter trees the benchmark's families build from the nine files under
+    parameter trees the benchmark's families build from the ten files under
     benchmarks/configs/, by shape only (nothing is generated)."""
     import sys
 
@@ -57,7 +57,7 @@ def _config_file_shapes():
 def test_cell_shapes_are_what_the_config_files_give():
     found = _config_file_shapes()
     assert len(os.listdir(os.path.join(
-        os.path.dirname(__file__), "..", "benchmarks", "configs"))) == 9
+        os.path.dirname(__file__), "..", "benchmarks", "configs"))) == 10
     assert all(pq._plan_blocks(d_in, d_out) for d_in, d_out, _ in found)
     assert found == set(CELL_SHAPES), found ^ set(CELL_SHAPES)
     # the 8192-wide tiles among them: the heads of Mistral and Qwen (6912 x

@@ -110,7 +110,7 @@ def heavy_op_names(text: str) -> list[str]:
     return op_names(text, HEAVY)
 
 
-def check_classes_and_halves(text: str, attr: str) -> None:
+def check_classes_and_halves(text: str, attr: str, ffn_scopes=(names.SCOPE_FFN,)) -> None:
     cls, halves = EXPECTED[attr]
     heavy = heavy_op_names(text)
     assert len(heavy) >= 8
@@ -128,7 +128,10 @@ def check_classes_and_halves(text: str, attr: str) -> None:
         # each half does a forward of its own: both hold the layers' scopes
         for half in names.HALVES:
             under = {names.scope_of(n) for n in heavy if names.half_of(n) == half}
-            assert {names.SCOPE_QKV, names.SCOPE_FFN, names.SCOPE_KV_WRITE} <= under, half
+            assert {names.SCOPE_QKV, names.SCOPE_KV_WRITE} <= under, half
+            # (``ffn_scopes``: a caller whose every FFN routes names the scope
+            # its products lie one deeper under, beside dl.ffn)
+            assert under & set(ffn_scopes), half
         # what joins them (no heavy operation among it: selects, a scatter of
         # one lane, the pack) is the closing dl.carry block and nothing else
         joins = [n for n in op_names(text)

@@ -213,6 +213,7 @@ RESTED = {
     "minicpm-sala": {".dense.w1", ".dense.w2", ".dense.w3"},  # [32, 128, 16384]: 128 MiB each
     "kanana-2-30b-a3b": set(), "lfm2-24b-a2b": set(), "deepseek-v3.2": set(),  # wo: 63 MiB
     "jamba2-3b": set(), "command-a-plus-05-2026": set(), "mimo-v2-flash": set(),
+    "solar-open2-250b": set(),  # the widest: nine layers' delta_q / k / v / out, 18 MiB each
 }
 
 
