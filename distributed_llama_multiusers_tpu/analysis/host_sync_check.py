@@ -63,7 +63,7 @@ SCOPE = (
     # may ever touch a device value
     "serving/breaker.py", "serving/watchdog.py", "utils/faults.py",
     # crash durability rides it the same way: admit/finish records are
-    # enqueued from the serving loop, relay pushes run inside _consume,
+    # enqueued from the serving loop, relay pushes run inside _stream,
     # and recovery re-admits through submit() — all host-side by
     # contract, never holding a device value
     "serving/journal.py", "serving/recovery.py", "serving/resume.py",

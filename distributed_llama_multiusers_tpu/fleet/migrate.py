@@ -3,7 +3,7 @@
 The client half of journal-based migration (PR 10's deterministic replay
 as a fleet primitive). The replica side lives in server/http.py:
 ``GET /admin/session/<id>`` exports a live session's admit wire record
-(prompt tokens + RESOLVED seed + params + consumed-token watermark) and
+(prompt tokens + RESOLVED seed + params + streamed-token watermark) and
 ``POST /admin/migrate`` feeds one into ``scheduler.build_recovered_request``
 through normal breaker-gated admission. This module is what the router
 does with those two endpoints:

@@ -264,7 +264,14 @@ def _common_prefix_len(a, b) -> int:
 class _Lane:
     request: Request | None = None
     pos: int = 0  # next write position
-    next_token: int = 0  # token to feed at pos
+    # token to feed at pos. On a GENERATING lane it is already STREAMED
+    # (the readback that produced it streamed it) and not yet COMMITTED
+    # (the readback of the step that feeds it commits it)
+    next_token: int = 0
+    # set when ``next_token`` ended the stream (EOS / stop string: "stop";
+    # max_tokens: "length"): nothing more is streamed, and the request
+    # finishes with this reason where next_token commits
+    ended: str | None = None
     sampler: Sampler | None = None
     eos: EosDetector | None = None
     decoder: object = None
@@ -276,10 +283,10 @@ class _Lane:
     drafter: NgramDraftIndex = field(default_factory=NgramDraftIndex)
     # grammar-constrained decoding (grammar/): the attached slab handle
     # (None = unconstrained) and the HOST MIRROR of the lane's automaton
-    # state — absolute slab id, advanced by every emitted token the host
-    # consumes. Exact on the sync paths; one step behind on the
-    # pipelined chain (where the device carry is authoritative and the
-    # mirror only steers draft pre-filtering).
+    # state — absolute slab id, advanced by every token the host streams
+    # (at the readback that delivers it). Exact on the sync paths; one
+    # step behind on the pipelined chain (where the device carry is
+    # authoritative and the mirror only steers draft pre-filtering).
     grammar: object = None
     g_state: int = 0
 
@@ -764,9 +771,11 @@ class ContinuousBatchingScheduler:
         ``GET /admin/session/<id>``): its admit wire record — prompt
         tokens, sampler params with the RESOLVED seed, QoS class,
         deadlines (serving/journal.admit_record) — plus a ``watermark``
-        (tokens consumed so far, informational: the migration target
-        re-buffers from 0 and the client's ``Last-Event-ID`` picks the
-        resume point). ``None`` for unknown/finished requests — only an
+        (tokens STREAMED so far, the last of which may not be committed
+        yet; informational: the migration target regenerates from 0,
+        re-buffers, and the client's ``Last-Event-ID`` picks the resume
+        point, so a token streamed here is never emitted twice nor lost).
+        ``None`` for unknown/finished requests — only an
         ADMITTED request has a resolved seed to regenerate from; queued
         ones are re-sent by the router, not migrated."""
         got = self._session_records.get(int(request_id))
@@ -890,7 +899,8 @@ class ContinuousBatchingScheduler:
 
     def _g_adv(self, lane: _Lane, tok: int) -> None:
         """Advance a constrained lane's HOST automaton mirror by one
-        emitted token — called exactly once per NEW emitted token, so the
+        emitted token — called exactly once per NEW emitted token, by the
+        stream half (``_stream``) at the readback that delivers it, so the
         mirror equals the device carry on the sync paths and trails it by
         the ring lag on the pipelined chain (where it only steers draft
         pre-filtering; the device state is authoritative)."""
@@ -1441,8 +1451,10 @@ class ContinuousBatchingScheduler:
         self._paged_commit(lane_idx)
         if lane.pending:
             return True
-        # prompt complete: pick the first generated token (which the next
-        # decode step's consume emits: first_token_hold_ms)
+        # prompt complete: pick the first generated token and stream it
+        # here, at the readback that delivers it (first_token_hold_ms is
+        # the stream work alone); the next decode step is fed it and its
+        # readback commits it
         self.telemetry.on_prefill_done(req, time.monotonic())
         if req.temperature == 0.0:
             first = int(greedy)
@@ -1452,43 +1464,47 @@ class ContinuousBatchingScheduler:
         else:
             first = int(sampled)  # sampled inside the compiled prefill step
         lane.next_token = first
-        self._g_adv(lane, first)
         req.state = RequestState.GENERATING
+        self._stream(lane_idx, lane, first)
         return True
 
-    def _consume(self, lane_idx: int, lane: _Lane, tok: int) -> bool:
-        """Emit one generated token on a lane: stream-decode, EOS/stop
-        detection, delta callbacks, position advance, length check. Returns
-        False when the lane finished (EOS or length — or failed: a
-        detokenize/EOS/delta raise is request-scoped, failing only this
-        request while the batch keeps decoding)."""
+    def _contained(self, half, lane_idx: int, lane: _Lane, tok: int) -> bool:
+        """Run one half of a token's host work (``_stream_inner`` /
+        ``_commit_inner``) request-scoped: everything in either is
+        host-side per-request work (stream decoder, EOS detector, delta
+        callback, the resident-KV map), so a raise of ANY type says
+        nothing about engine health and fails this request only, while
+        the batch keeps decoding. Returns False when it did."""
         req = lane.request
         try:
-            return self._consume_inner(lane_idx, lane, req, tok)
+            return half(lane_idx, lane, req, tok)
         except Exception as e:  # noqa: BLE001 — request-scoped by construction
-            # everything in here is host-side per-request work (stream
-            # decoder, EOS detector, delta callback): a raise of ANY type
-            # says nothing about engine health, so it fails this request
-            # only — no classification needed
             self._fail_request(lane_idx, req, str(e), exc=e)
             self.breaker.record_request_failure()
             return False
 
-    def _consume_inner(self, lane_idx: int, lane: _Lane, req: Request,
-                       tok: int) -> bool:
+    def _stream(self, lane_idx: int, lane: _Lane, tok: int) -> bool:
+        """STREAM one generated token, at the readback that first gives it
+        to the host: count it, stamp it, advance the grammar mirror,
+        stream-decode, EOS/stop detection, the delta callback. A token
+        that ends the stream (EOS, a stop string, ``max_tokens``) sets
+        ``lane.ended``; the lane is released where that token COMMITS.
+        Returns False when the request failed (a raise in here)."""
+        return self._contained(self._stream_inner, lane_idx, lane, tok)
+
+    def _stream_inner(self, lane_idx: int, lane: _Lane, req: Request,
+                      tok: int) -> bool:
         req.generated_tokens.append(tok)
         # per-token stamp: first token observes TTFT, later ones the
         # inter-token gap (multi-step/spec bursts land near-zero gaps —
         # that IS when their stream deltas reach the client)
         self.telemetry.on_token(req)
-        self._lane_kv[lane_idx].append(tok)  # its KV write is committed
-        self._paged_commit(lane_idx)
-        lane.drafter.append(tok)
+        self._g_adv(lane, tok)
         piece = lane.decoder.decode(tok)
         result = lane.eos.append(tok, piece)
         if result == EosResult.EOS:
-            self._finish(lane_idx, req)
-            return False
+            lane.ended = "stop"
+            return True
         if result == EosResult.NOT_EOS:
             delta = lane.eos.get_delta()
             if delta:
@@ -1497,14 +1513,53 @@ class ContinuousBatchingScheduler:
                     req.on_delta(delta)
             lane.eos.reset()
         # MAYBE_EOS: hold back
+        if len(req.generated_tokens) >= req.max_tokens:
+            lane.ended = "length"
+        return True
+
+    def _commit(self, lane_idx: int, lane: _Lane, tok: int) -> bool:
+        """COMMIT one streamed token, at the readback of the step that was
+        fed it (its KV write has executed): the resident-KV map and the
+        paged prefix tree, the draft index, the position, the length
+        check. Returns False when the lane finished here (the token had
+        ended the stream, or the context is full) or failed."""
+        return self._contained(self._commit_inner, lane_idx, lane, tok)
+
+    def _commit_inner(self, lane_idx: int, lane: _Lane, req: Request,
+                      tok: int) -> bool:
+        self._lane_kv[lane_idx].append(tok)  # its KV write is committed
+        self._paged_commit(lane_idx)
+        lane.drafter.append(tok)
+        if lane.ended == "stop":
+            self._finish(lane_idx, req)
+            return False
         lane.pos += 1
-        if (
-            len(req.generated_tokens) >= req.max_tokens
-            or lane.pos >= self.engine.config.seq_len
-        ):
+        if lane.ended or lane.pos >= self.engine.config.seq_len:
             self._finish(lane_idx, req, reason="length")
             return False
         return True
+
+    def _advance(self, lane_idx: int, lane: _Lane,
+                 produced: list[int]) -> tuple[bool, int]:
+        """One readback's worth of one lane, the one rule of every decode
+        path: COMMIT the token the step was fed (``lane.next_token``,
+        streamed at the readback before), then STREAM what the step
+        produced, in order. Every produced token but the last was fed
+        inside the same step too (a verify step's accepted drafts, a
+        multi-step horizon's chain), so it commits as soon as it is
+        streamed; the last becomes ``next_token`` and waits for the step
+        that feeds it. Stops at the token whose commit releases the lane:
+        what the step produced past it is never streamed. Returns (lane
+        still live, tokens committed — the finishing one included)."""
+        n_fed = 0
+        for tok in produced:
+            n_fed += 1
+            if not self._commit(lane_idx, lane, lane.next_token):
+                return False, n_fed
+            lane.next_token = tok
+            if not self._stream(lane_idx, lane, tok):
+                return False, n_fed
+        return True, n_fed
 
     def _multi_horizon(self, active, prefilled: bool) -> int:
         """How many decode steps to chain in one device dispatch (0/1 =
@@ -1528,8 +1583,10 @@ class ContinuousBatchingScheduler:
         rem = 0
         for _, lane in active:
             req = lane.request
+            # tokens still to COMMIT: next_token is streamed (counted in
+            # generated_tokens) and commits in this dispatch
             rem = max(rem, min(
-                req.max_tokens - len(req.generated_tokens),
+                req.max_tokens - len(req.generated_tokens) + 1,
                 self.engine.config.seq_len - lane.pos,
             ))
         from .spec import pow2_floor
@@ -1566,7 +1623,7 @@ class ContinuousBatchingScheduler:
         A hit is a pipeline flush condition ONLY for engines without the
         in-chain verify family (``_spec_pl_ok`` False) — there the sync
         spec path emits >1 token per forward and wins. Lanes still
-        mid-admission (their first token not yet consumed) are skipped:
+        mid-admission (their final chunk not yet read back) are skipped:
         their ``next_token`` is not set."""
         spec_k = (
             getattr(self.engine, "SPEC_DRAFT", 0)
@@ -1830,11 +1887,16 @@ class ContinuousBatchingScheduler:
         """Consume half, one step behind: block on the oldest in-flight
         step's packed token readback and run the host work the synchronous
         loop does inline — stream decode, EOS/stop, cancel/budget checks —
-        while the younger dispatches keep the device busy. ``entry`` is
-        ``(step_lanes, fused, t_dispatch, spec_drafted, step, dry_s,
-        p_start)`` recorded AT DISPATCH TIME: ``step_lanes`` pairs each
-        live lane index with its
-        lane OBJECT — the identity check skips both lanes that finished at
+        while the younger dispatches keep the device busy. At a step's
+        readback each live lane COMMITS the token the step was fed
+        (resident-KV map, draft index, position, release of the lane) and
+        STREAMS the token the step produced (count, stamp, detokenize,
+        EOS/stop, ``on_delta``): a token reaches its client at the
+        readback that first gives it to the host, and is committed one
+        readback later, when the step that wrote its KV has returned.
+        ``entry`` is ``(step_lanes, fused, t_dispatch, spec_drafted, step,
+        dry_s, p_start)`` recorded AT DISPATCH TIME: ``step_lanes`` pairs
+        each live lane index with its lane OBJECT — the identity check skips both lanes that finished at
         an earlier consumed step AND lanes already reclaimed by a NEW
         request while this step was still in flight (either way the
         column is junk, and its in-flight KV writes die under the
@@ -1842,12 +1904,14 @@ class ContinuousBatchingScheduler:
         ``(lane_idx, lane, final, n_chunk)`` for a chunk-carrying step,
         whose extra readback column (row, for a spec pack) carries the
         chunk's boundary token pair: on the FINAL chunk that token is the
-        request's first generated token, committed here exactly one step
-        behind — the same point the synchronous path would have read it.
+        request's first generated token, STREAMED at this readback (the
+        same point the synchronous path reads and streams it) and
+        committed at the next one.
         ``spec_drafted`` (None for a plain step) marks the step as a spec
         verify: the readback is ``decode_spec``'s (emitted, n_emit) pack,
         each live lane commits a VARIABLE-LENGTH accept — next_token + the
-        accepted drafts, exactly the sync spec path's feed sequence — and
+        accepted drafts, exactly the sync spec path's feed sequence —
+        streams the accepted drafts and the model's token after them, and
         drafted lanes feed the acceptance counters (consumed-only, and
         only when the lane actually fed tokens: a lane cancelled mid-draft
         must not count a lane-step with zero emitted, which would push the
@@ -1905,8 +1969,11 @@ class ContinuousBatchingScheduler:
                          spec_drafted, record: StepRecord, bucket,
                          t_done: float, out_a, out_b) -> None:
         """The host half of one consumed step (``_pipeline_consume``'s
-        contract): per-lane ``_consume`` with detokenize/``on_delta``,
-        finishes, and the fused boundary token."""
+        contract): cancel/budget checks, then per lane ``_advance`` —
+        commit the token the step was fed (finishing the request if that
+        token had ended its stream), stream what the step produced with
+        detokenize/``on_delta`` — and the fused boundary token, streamed
+        here as its request's first."""
         self.breaker.record_success()
         now = record.at
         is_spec = spec_drafted is not None
@@ -1932,59 +1999,42 @@ class ContinuousBatchingScheduler:
                 self._finish(i, req, reason="timeout")
                 live.pop(i)
                 continue
+            # the one rule (_advance): commit what the step was FED, stream
+            # what it PRODUCED. A spec verify step produced a variable-length
+            # accept (the plain-decode stream, per the verification
+            # identity): next_token and the accepted drafts commit, the
+            # model's token after the accepted prefix becomes the new
+            # next_token — the sync spec path's rule verbatim. A plain step
+            # produced the token this lane fed into the NEXT in-flight step
+            # (the on-device feed rule, reconstructed for host bookkeeping)
             if is_spec:
-                # variable-length commit: next_token + the accepted drafts
-                # (the plain-decode stream, per the verification identity);
-                # the model's token after the accepted prefix becomes the
-                # new pending token — the sync spec path's rule verbatim
-                cnt = int(n_emit[i])
-                if lane.grammar is not None:
-                    # catch the host mirror up by the whole lagged window
-                    for t in emitted[i, :cnt]:
-                        self._g_adv(lane, int(t))
-                seq = [lane.next_token] + [
-                    int(t) for t in emitted[i, : cnt - 1]
-                ]
-                alive = True
-                n_fed = 0
-                for t in seq:
-                    n_fed += 1
-                    if not self._consume(i, lane, t):
-                        alive = False
-                        break
-                if spec_drafted.get(i) and n_fed:
-                    with self.engine.stats.lock:
-                        self.engine.stats.spec_lane_steps += 1
-                        self.engine.stats.spec_emitted += n_fed
-                        acc = cnt - 1  # the device's accept count
-                        self.engine.stats.spec_accept_hist[acc] = (
-                            self.engine.stats.spec_accept_hist.get(acc, 0)
-                            + 1
-                        )
-                if not alive:
-                    live.pop(i)
-                    continue
-                lane.next_token = int(emitted[i, cnt - 1])
-                continue
-            if not self._consume(i, lane, lane.next_token):
-                live.pop(i)
-                continue
-            # the token this lane fed into the NEXT in-flight step — the
-            # on-device feed rule, reconstructed for host bookkeeping
-            if req.temperature == 0.0:
-                lane.next_token = int(greedy_np[i])
+                produced = [int(t) for t in emitted[i, : int(n_emit[i])]]
+            elif req.temperature == 0.0:
+                produced = [int(greedy_np[i])]
             else:
-                lane.next_token = int(sampled_np[i])
-            self._g_adv(lane, lane.next_token)
+                produced = [int(sampled_np[i])]
+            alive, n_fed = self._advance(i, lane, produced)
+            if is_spec and spec_drafted.get(i):
+                with self.engine.stats.lock:
+                    self.engine.stats.spec_lane_steps += 1
+                    self.engine.stats.spec_emitted += n_fed
+                    acc = len(produced) - 1  # the device's accept count
+                    self.engine.stats.spec_accept_hist[acc] = (
+                        self.engine.stats.spec_accept_hist.get(acc, 0)
+                        + 1
+                    )
+            if not alive:
+                live.pop(i)
         if fused is not None:
             i, lane, final, _n_chunk = fused
             if final and live.get(i) is lane:
                 # prompt complete: adopt the boundary token as the first
                 # generated token (greedy at temp 0, fused-sampled else —
-                # host-exact admissions never take the fused path) and go
-                # GENERATING. The lane already joined the dispatch half's
+                # host-exact admissions never take the fused path), go
+                # GENERATING and STREAM it, here, at the readback that
+                # delivers it. The lane already joined the dispatch half's
                 # live set when its final chunk went out; the carry fed it
-                # on device, and the NEXT consumed step emits this token.
+                # on device, and the NEXT consumed step commits this token.
                 # Spec packs carry the boundary pair in the extra ROW's
                 # first two columns; token packs in the extra COLUMN.
                 req = lane.request
@@ -1998,9 +2048,11 @@ class ContinuousBatchingScheduler:
                 lane.next_token = (
                     b_greedy if req.temperature == 0.0 else b_sampled
                 )
-                # mirror: start state advanced by the boundary emission
-                self._g_adv(lane, lane.next_token)
                 req.state = RequestState.GENERATING
+                # (the grammar mirror's start state advances by the
+                # boundary emission inside the stream half)
+                if not self._stream(i, lane, lane.next_token):
+                    live.pop(i)
 
     def _pipeline_admit(self, live: dict, admitting: dict, fused: bool,
                         spec_chain: bool, probe_drafts: bool) -> bool:
@@ -2068,15 +2120,17 @@ class ContinuousBatchingScheduler:
         (``_claim_admissions``), its prompt chunks ride fused dispatches
         (``_pipeline_dispatch``), and when the final chunk goes out the
         lane joins the decode half fed by the on-device carry — the chain
-        never breaks and ``pipeline_flushes`` stays 0 under churn.
+        never breaks and ``pipeline_flushes`` stays 0 under churn. The
+        readback of that final chunk's step streams the first token.
 
         Speculation is part of steady state too (the zero-flush tentpole):
         when the engine compiles the in-chain verify family
         (``_spec_pl_ok``), a greedy lane whose history drafts ships its
         candidates WITH the dispatch (``decode_spec_pipelined``, or the
         chunk-carrying ``decode_spec_prefill_fused``) and the consume half
-        commits the variable-length accept one step behind — speculation's
-        extra tokens MULTIPLY with the overlap instead of aborting it.
+        streams and commits the variable-length accept one step behind —
+        speculation's extra tokens MULTIPLY with the overlap instead of
+        aborting it.
         Probing is gated to dispatches whose ring lag is <= 1 with no
         other spec step in flight: past that the host's one-step-behind
         carry candidate cannot align, so drafts would verify-and-miss
@@ -2601,72 +2655,34 @@ class ContinuousBatchingScheduler:
 
             for i, lane in active:
                 req = lane.request
-                if draft_len is not None:
-                    # feed sequence: next_token + the accepted drafts (they
-                    # equal the greedy continuations, so this is exactly the
-                    # plain-decode token stream); the model's token after
-                    # the accepted prefix becomes the new pending token.
-                    # Acceptance counters cover DRAFTED lanes only — sampled
-                    # and draft-less lanes ride the same batched verify call
-                    # but always emit 1, which would dilute the metric
-                    drafted = int(draft_len[i]) > 0
-                    cnt = int(n_emit[i])
-                    if lane.grammar is not None:
-                        # every emitted token is a NEW emission: the last
-                        # becomes next_token, the rest are consumed below
-                        for t in emitted[i, :cnt]:
-                            self._g_adv(lane, int(t))
-                    seq = [lane.next_token] + [
-                        int(t) for t in emitted[i, : cnt - 1]
-                    ]
-                    alive = True
-                    n_fed = 0
-                    for t in seq:
-                        n_fed += 1  # consumed (finishing token included)
-                        if not self._consume(i, lane, t):
-                            alive = False
-                            break
-                    if drafted:
-                        with self.engine.stats.lock:
-                            self.engine.stats.spec_lane_steps += 1
-                            self.engine.stats.spec_emitted += n_fed
-                    if not alive:
-                        continue
-                    nxt_greedy = int(emitted[i, cnt - 1])
-                    nxt_sampled = int(emitted[i, 0])  # n_emit==1 for temp>0
+                # what the step produced for this lane, in order; the
+                # synchronous ladder holds tokens by the chain's rule
+                # (_advance): next_token, streamed when the step before
+                # produced it, commits now, and these stream now
+                if req.temperature > 0.0 and lane.host_exact:
+                    # (never a multi-step horizon: the gate excludes it;
+                    # a verify step emits one token for temp > 0)
+                    produced = [lane.sampler.sample(logits_np[i])]
+                elif draft_len is not None:
+                    # the accepted drafts (they equal the greedy
+                    # continuations, so this is exactly the plain-decode
+                    # token stream) and the model's token after them
+                    produced = [int(t) for t in emitted[i, : int(n_emit[i])]]
                 elif chosen is not None:
-                    # multi-step horizon: consume next_token + the first
-                    # h-1 chained choices; the last choice becomes the new
-                    # pending token. Tokens past a stop are discarded (their
-                    # junk KV is rewritten before any query reads it).
-                    if lane.grammar is not None:
-                        for j in range(h):  # h new emissions this horizon
-                            self._g_adv(lane, int(chosen[j, i]))
-                    seq = [lane.next_token] + [
-                        int(chosen[j, i]) for j in range(h - 1)
-                    ]
-                    alive = True
-                    for t in seq:
-                        if not self._consume(i, lane, t):
-                            alive = False
-                            break
-                    if not alive:
-                        continue
-                    lane.next_token = int(chosen[h - 1, i])
-                    continue  # greedy/sampled feed already encoded in chosen
+                    # multi-step horizon: h chained choices (greedy /
+                    # sampled already encoded). Tokens past a stop are
+                    # discarded (their junk KV is rewritten before any
+                    # query reads it)
+                    produced = [int(chosen[j, i]) for j in range(h)]
+                elif req.temperature == 0.0:
+                    produced = [int(greedy[i])]
                 else:
-                    if not self._consume(i, lane, lane.next_token):
-                        continue
-                    nxt_greedy = int(greedy[i])
-                    nxt_sampled = int(sampled[i])
-                if req.temperature == 0.0:
-                    lane.next_token = nxt_greedy
-                elif lane.host_exact:
-                    lane.next_token = lane.sampler.sample(logits_np[i])
-                else:
-                    lane.next_token = nxt_sampled
-                if draft_len is None:
-                    # plain step: ONE new emission (the spec branch
-                    # advanced its whole window above; multi continues
-                    # before reaching here)
-                    self._g_adv(lane, lane.next_token)
+                    produced = [int(sampled[i])]
+                _alive, n_fed = self._advance(i, lane, produced)
+                # acceptance counters cover DRAFTED lanes only — sampled
+                # and draft-less lanes ride the same batched verify call
+                # but always emit 1, which would dilute the metric
+                if draft_len is not None and int(draft_len[i]) > 0:
+                    with self.engine.stats.lock:
+                        self.engine.stats.spec_lane_steps += 1
+                        self.engine.stats.spec_emitted += n_fed

@@ -163,8 +163,8 @@ class ApiServer:
                 relay = StreamRelay(req.id, capacity=0)
                 req.future.add_done_callback(lambda _f: relay.finish())
             # on_delta runs on the scheduler thread right after the token
-            # was consumed, so len(generated_tokens) IS the delta's
-            # token index
+            # was streamed (counted), so len(generated_tokens) IS the
+            # delta's token index
             req.on_delta = lambda d: relay.push(len(req.generated_tokens), d)
         return req, relay
 
@@ -761,7 +761,7 @@ class ApiServer:
                 """``GET /admin/session/<request_id>``: export a live
                 session's migration ticket — the admit wire record
                 (prompt tokens + RESOLVED seed + params) plus the
-                consumed-token watermark. 404 for unknown/finished
+                streamed-token watermark. 404 for unknown/finished
                 requests and for schedulers without the export surface.
                 The router caches this at stream start so a replica
                 death can still be migrated after the source is gone."""

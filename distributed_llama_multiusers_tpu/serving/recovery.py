@@ -79,7 +79,7 @@ def attach_recovered_stream(scheduler, entry: JournalEntry, registry=None):
     if registry is not None and entry.stream:
         relay = registry.register(req, kind=entry.kind)
         registered = True
-        # token index = consumed-token count at emit time
+        # token index = streamed-token count at emit time
         req.on_delta = (
             lambda d, r=req, rel=relay: rel.push(
                 len(r.generated_tokens), d

@@ -323,7 +323,8 @@ class Telemetry:
 
     def on_prefill_done(self, req, now: float) -> None:
         """The step that carried ``req``'s final prompt chunk has been
-        read back: the host now knows the boundary token."""
+        read back: the host now knows the boundary token, and streams
+        it right after this stamp."""
         self.trace_of(req).prefill_done_at = now
 
     def on_prefill_chunk(self, req, lane: int, t0: float, n_tokens: int,
@@ -358,7 +359,7 @@ class Telemetry:
             ))
 
     def on_token(self, req, now: float | None = None) -> None:
-        """One consumed token (``now`` = time.monotonic()). First token
+        """One streamed token (``now`` = time.monotonic()). First token
         observes TTFT; every later one observes the inter-token gap."""
         tel = self.trace_of(req)
         if now is None:

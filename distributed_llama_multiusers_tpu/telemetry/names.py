@@ -134,7 +134,7 @@ LOOP_TRACK = "loop"
 LOOP_ADMIT = "loop.admit"        # queue sweep, cancel checks, _claim_admissions (+ tokenization)
 LOOP_DISPATCH = "loop.dispatch"  # the call to _pipeline_dispatch
 LOOP_WAIT = "loop.wait"          # engine.pipeline_consume() alone: the lagged readback
-LOOP_STREAM = "loop.stream"      # the rest of _pipeline_consume: _consume, detokenize, on_delta
+LOOP_STREAM = "loop.stream"      # the rest of _pipeline_consume: commit the fed token, stream the produced one (detokenize, on_delta)
 LOOP_SPANS = (LOOP_ADMIT, LOOP_DISPATCH, LOOP_WAIT, LOOP_STREAM)
 # the loop's own work: what the host does while the device may run dry
 LOOP_HOST_SPANS = (LOOP_ADMIT, LOOP_DISPATCH, LOOP_STREAM)

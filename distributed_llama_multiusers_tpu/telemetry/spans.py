@@ -227,7 +227,10 @@ class StepRecord(NamedTuple):
 def _ttft_parts(submitted, admitted, first_dispatch, prefill_done,
                 first_token) -> tuple:
     """(queue wait, dispatch wait, prefill, first-token hold) in seconds,
-    each None without a first token or an admission. Consecutive
+    each None without a first token or an admission. The hold runs from
+    the readback that gave the host the first token to its stream: the
+    stream work of that same readback alone (a whole decode step until
+    PR 57, when the next step's consume emitted it). Consecutive
     differences of one monotone chain, so they sum to ``first_token -
     submitted`` exactly."""
     if first_token is None or admitted is None:
@@ -263,8 +266,9 @@ class RequestTrace:
         self.admitted_at: float | None = None
         # the request's first prompt chunk handed to the engine (fused or
         # synchronous), and the readback of the step that carried its
-        # final chunk — where the host learns the boundary token, which
-        # the NEXT consumed step emits as the first token
+        # final chunk — where the host learns the boundary token and
+        # streams it as the first token (the NEXT consumed step, the one
+        # fed it, commits it)
         self.first_dispatch_at: float | None = None
         self.prefill_done_at: float | None = None
         # one StepRecord a prompt chunk, in dispatch order: why prefill_ms
@@ -284,7 +288,7 @@ class RequestTrace:
         self.swap_in_s = 0.0
 
     def on_token(self, now: float) -> None:
-        """Stamp one consumed token (``now`` = time.monotonic())."""
+        """Stamp one streamed token (``now`` = time.monotonic())."""
         if self.first_token_at is None:
             self.first_token_at = now
         else:

@@ -1192,7 +1192,7 @@ def test_host_sync_covers_containment_files(tmp_path):
 def test_crash_durability_files_in_all_scopes(tmp_path):
     """ISSUE-10 satellite: serving/journal.py, serving/recovery.py and
     serving/resume.py ride the serving loop (admit/finish records
-    enqueue from it, relay pushes run inside _consume, recovery
+    enqueue from it, relay pushes run inside _stream, recovery
     re-admits through submit()) — so they sit in the host-sync scope,
     the package-wide clock ban, and the guarded-by discipline like the
     containment files before them. Known-bad fixtures per check, plus

@@ -174,7 +174,9 @@ def test_horizon_gating(loaded):
                 generated_tokens = []
             self.request = _R()
             self.request.max_tokens = max_tokens
-            self.request.generated_tokens = [0] * gen
+            # `gen` tokens committed; a generating lane's next_token is
+            # streamed (counted) before the step that commits it (PR 57)
+            self.request.generated_tokens = [0] * (gen + 1)
             self.host_exact = host_exact
             self.pos = pos
 
