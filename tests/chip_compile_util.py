@@ -15,7 +15,9 @@ chip_smoke.py's kernel phase.
 
 One file was ten minutes on one xdist worker (``--dist loadfile`` keeps a
 file together); since PR 46 the cases are split by kernel and row class into
-files of under two minutes each (the cases and their names are the same):
+sixteen files (the cases and their names are the same) of 11 to 210 s of worker
+time each in a whole run under six workers (PR 58's reading: eleven of them over
+a minute, 1200-1630 s together, a quarter of tier-1; a compile cannot be shared):
 
   test_chip_compile_q40_decode.py     the dense Q40 kernel, decode-width rows
   test_chip_compile_q40_prefill.py    ... at 1024 rows, the 1B / 8B shapes, planes

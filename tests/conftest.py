@@ -16,6 +16,17 @@ force_cpu_mesh(n_devices=8)
 import pytest  # noqa: E402
 
 
+@pytest.fixture
+def pallas_interpret():
+    """The Pallas kernels on, in interpret mode, for one case: what it traces
+    and builds meanwhile takes the kernels' paths."""
+    from distributed_llama_multiusers_tpu.ops import linear
+
+    linear.set_pallas_interpret(True)
+    yield
+    linear.set_pallas_interpret(False)
+
+
 @pytest.fixture(scope="session")
 def tiny_model(tmp_path_factory):
     """A tiny Q40 .m + .t pair on disk, shared across the session."""

@@ -9,18 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_llama_multiusers_tpu.ops import linear
-
 import latent_toy
 
-CFG, FAMILY, CORRECT = latent_toy.load()
-
-
-@pytest.fixture
-def pallas_interpret():
-    linear.set_pallas_interpret(True)
-    yield
-    linear.set_pallas_interpret(False)
+CFG, FAMILY, CORRECT = latent_toy.toy("latent")
 
 
 def _compare(seed, dtype=None):
@@ -43,8 +34,13 @@ def test_bfloat16_stays_near_the_reference_and_the_routes_read_zero():
     assert (r["route_greedy_gap"], r["route_nucleus_excess"], r["route_kv_rel_err"]) == (0, 0, 0)
 
 
-def test_a_parked_lane_is_left_alone_and_routes_nowhere():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 7)
+@pytest.fixture(scope="module")
+def eng():
+    """The one engine of the cases that only read and write lanes."""
+    return latent_toy.engine(FAMILY, CFG, 7)[0]
+
+
+def test_a_parked_lane_is_left_alone_and_routes_nowhere(eng):
     n, seq = eng.n_lanes, eng.config.seq_len
     prompt = list(range(3, 23))
     eng.prefill(0, prompt)
@@ -61,8 +57,7 @@ def test_a_parked_lane_is_left_alone_and_routes_nowhere():
         np.testing.assert_array_equal(b[:, 0, : len(prompt)], a[:, 0, : len(prompt)])
 
 
-def test_prefill_in_chunks_gives_the_rows_of_a_prefill_in_one():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 8, lanes=4)
+def test_prefill_in_chunks_gives_the_rows_of_a_prefill_in_one(eng):
     prompt = [int(x) for x in np.random.default_rng(0).integers(2, 250, size=60)]
     whole, _, _ = eng.prefill(0, prompt)
     eng.prefill(1, prompt[:16])

@@ -6,7 +6,13 @@ that the selection bites, expert groups, 8 of 16 experts held, YaRN. Prefill
 then decode through the three-leaf cache, chunked against whole prefill, a
 lane bit-identical whatever the other lanes hold, prefix reuse by lane copy,
 and the faults the comparison must tell apart: a shorter list, the most
-recent rows, dense attention."""
+recent rows, dense attention.
+
+One engine for the file (``sample``'s): every case that only reads and writes
+lanes, counts or asks what is declined takes it and prefills the lanes it
+reads first. A case builds an engine of its own only where the construction is
+its subject (interpret mode, bfloat16, a monkeypatched selection, another
+configuration)."""
 
 import dataclasses
 import functools
@@ -18,30 +24,27 @@ import numpy as np
 import pytest
 
 from distributed_llama_multiusers_tpu.models import deepseek
-from distributed_llama_multiusers_tpu.ops import linear
 from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
 
 import latent_toy
 
-CFG, FAMILY, CORRECT = latent_toy.load("tiny_deepseek_v32.json")
+CFG, FAMILY, CORRECT = latent_toy.toy("sparse")
 TOPK = CFG["index_topk"]
-
-
-@pytest.fixture
-def pallas_interpret():
-    linear.set_pallas_interpret(True)
-    yield
-    linear.set_pallas_interpret(False)
 
 
 @pytest.fixture(scope="module")
 def sample():
-    """An engine, its arrays, the sample sequences, its logits at them."""
+    """The file's engine, its arrays, the sample sequences, its logits at them."""
     eng, tensors = latent_toy.engine(FAMILY, CFG, 21)
     prompts, forced = CORRECT.sample_sequences(CFG, 21)
     prefixes = [CORRECT.prefix_lengths(CFG, len(p)) for p in prompts]
     got = CORRECT.engine_logits(eng, prompts, forced, prefixes)
     return eng, tensors, (prompts, forced, prefixes), got
+
+
+@pytest.fixture(scope="module")
+def eng(sample):
+    return sample[0]
 
 
 def _reference(tensors, seqs, select):
@@ -105,8 +108,7 @@ def test_a_program_that_departs_from_the_selection_fails_the_comparison(monkeypa
     assert np.sqrt(np.mean(err ** 2)) > 10 * limits["prefill_rel_err"], err
 
 
-def test_no_step_program_holds_an_approximate_top_k():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 3, lanes=4)
+def test_no_step_program_holds_an_approximate_top_k(eng):
     cfg, n = eng.config, eng.n_lanes
     for b, t in ((n, 1), (1, 64)):
         cache = deepseek.init_latent_cache(cfg, b)
@@ -142,8 +144,7 @@ def test_the_exact_topk_is_the_stable_sorts_first_k_with_ties_and_short_rows(mon
     np.testing.assert_array_equal(np.where(finite, got, -1), np.where(finite, want, -1))
 
 
-def test_prefill_in_chunks_gives_the_rows_of_a_prefill_in_one():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 8, lanes=4)
+def test_prefill_in_chunks_gives_the_rows_of_a_prefill_in_one(eng):
     prompt = [int(x) for x in np.random.default_rng(0).integers(2, 250, size=120)]
     whole, _, _ = eng.prefill(0, prompt)
     eng.prefill(1, prompt[:64])
@@ -152,8 +153,7 @@ def test_prefill_in_chunks_gives_the_rows_of_a_prefill_in_one():
     assert FAMILY.lane_state_rel_err(eng, 0, 1, 120) < 1e-4
 
 
-def test_a_lane_is_bit_identical_whatever_the_other_lanes_hold():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 9)
+def test_a_lane_is_bit_identical_whatever_the_other_lanes_hold(eng):
     n, seq = eng.n_lanes, eng.config.seq_len
     rng = np.random.default_rng(1)
     prompt = [int(x) for x in rng.integers(2, 250, size=70)]
@@ -172,8 +172,7 @@ def test_a_lane_is_bit_identical_whatever_the_other_lanes_hold():
     np.testing.assert_array_equal(rows[0], rows[2])
 
 
-def test_a_parked_lane_is_left_alone_in_all_three_leaves():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 7)
+def test_a_parked_lane_is_left_alone_in_all_three_leaves(eng):
     assert isinstance(eng.cache, deepseek.IndexedLatentCache)
     assert eng.cache.ik.shape == (3, 8, 128, CFG["index_head_dim"])
     n, seq = eng.n_lanes, eng.config.seq_len
@@ -190,8 +189,7 @@ def test_a_parked_lane_is_left_alone_in_all_three_leaves():
         np.testing.assert_array_equal(b[:, 0, : len(prompt)], a[:, 0, : len(prompt)])
 
 
-def test_prefix_reuse_by_lane_copy_carries_the_index_keys():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 10, lanes=4)
+def test_prefix_reuse_by_lane_copy_carries_the_index_keys(eng):
     prompt = [int(x) for x in np.random.default_rng(2).integers(2, 250, size=110)]
     whole, _, _ = eng.prefill(0, prompt)
     eng.copy_lane(0, 2, prefix_len=64)
@@ -200,8 +198,7 @@ def test_prefix_reuse_by_lane_copy_carries_the_index_keys():
     assert FAMILY.lane_state_rel_err(eng, 0, 2, 110) < 1e-4
 
 
-def test_what_is_declined_is_declined_by_name_and_the_facts_are_said():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 4, lanes=4)
+def test_what_is_declined_is_declined_by_name_and_the_facts_are_said(eng):
     facts = eng.path_facts()
     assert facts["attention_path"] == "sparse_topk" and facts["index_topk"] == TOPK
     assert facts["sparse_rows"] == "gathered"
@@ -216,8 +213,7 @@ def test_what_is_declined_is_declined_by_name_and_the_facts_are_said():
             InferenceEngine(config, params, n_lanes=4, **kw)
 
 
-def test_the_counters_count_rows_scored_rows_chosen_and_pairs_unheld():
-    eng, _ = latent_toy.engine(FAMILY, CFG, 12)
+def test_the_counters_count_rows_scored_rows_chosen_and_pairs_unheld(eng):
     n, seq = eng.n_lanes, eng.config.seq_len
     lengths = [10, 40, 100]
     for lane, m in enumerate(lengths):

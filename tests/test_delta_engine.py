@@ -4,9 +4,11 @@ routed experts (models/hybrid.py ``LayerKind.DELTA``, ops/delta_rule.py) through
 4 heads of 16, convs of 4 taps, gates of rank 8, 2 kv heads, 16 experts of
 which 4 are chosen and 4 held, one shared. Against the benchmark's plain
 reference on logits: prefill whole and in chunks with a padded tail, decode
-through the cache, a fused admission beside decoding lanes, parked twins; the
-rule for the matrix state AND the convs' windows in every step family; a
-request that follows another on a lane; what is declined and counted."""
+through the cache, a fused admission beside decoding lanes, parked twins; a
+state at rest in bfloat16 told apart; what is declined and counted. (The rule
+for the matrix state AND the convs' windows in every step family, a request
+that follows another on a lane and the fused step's splice of every leaf are
+tests/test_lane_state_contract.py's row ``solar``.)"""
 
 import jax.numpy as jnp
 import numpy as np
@@ -19,36 +21,21 @@ from distributed_llama_multiusers_tpu.telemetry import names
 
 import latent_toy
 
-CFG, FAMILY, CORRECT = latent_toy.load("tiny_solar_open2.json")
+CFG, FAMILY, CORRECT = latent_toy.toy("solar")
 SEQ = CFG["max_position_embeddings"]
 PROMPT = [int(x) for x in np.random.default_rng(0).integers(2, CFG["vocab_size"], size=120)]
 G, D = LayerKind.ATTENTION, LayerKind.DELTA
-LEAVES = ("k", "v", "delta", "delta_conv")
 
 
-@pytest.fixture(scope="module")
-def built():
-    """One engine for the file and ONE bucket, 64 rows (its programs compile
-    once: the tier-1 clock): a prompt of 100 is 64 + 36 of 64, one of 20 rides
-    44 padded rows, and a chunk of the chunk form is 32 of them."""
-    return latent_toy.engine(FAMILY, CFG, seed=5, lanes=8, prefill_buckets=(64,))
+# One engine for the file and ONE bucket, 64 rows (its programs compile once:
+# the tier-1 clock): a prompt of 100 is 64 + 36 of 64, one of 20 rides 44
+# padded rows, and a chunk of the chunk form is 32 of them.
+built = latent_toy.module_engine(FAMILY, CFG, seed=5, lanes=8, prefill_buckets=(64,))
 
 
 @pytest.fixture(scope="module")
 def eng(built):
     return built[0]
-
-
-def _lane(eng, lane):
-    return {name: np.asarray(getattr(eng.cache, name)[:, lane]) for name in LEAVES}
-
-
-def _park(eng, live: dict):
-    tokens = np.zeros(eng.n_lanes, np.int32)
-    positions = np.full(eng.n_lanes, SEQ, np.int32)
-    for lane, (tok, pos) in live.items():
-        tokens[lane], positions[lane] = tok, pos
-    return tokens, positions
 
 
 def test_engine_agrees_with_the_plain_reference_and_the_routes_read_zero(built):
@@ -89,28 +76,6 @@ def test_the_cache_has_two_new_leaves_and_the_state_is_counted(eng):
     assert eng.stats.delta_rows_computed > 0 and eng.stats.delta_rows_computed % 6 == 0
 
 
-@pytest.mark.parametrize("family", ["decode", "decode_pl", "fused"])
-def test_a_parked_lane_keeps_both_states_in_every_step_family(eng, family):
-    eng.prefill(0, PROMPT[:20])
-    eng.prefill(1, PROMPT[:30])
-    before, live_before = _lane(eng, 1), _lane(eng, 0)
-    tokens, positions = _park(eng, {0: (5, 20)})
-    if family == "decode":
-        eng.decode(tokens, positions)
-    elif family == "decode_pl":
-        eng.decode_pipelined(positions, tokens=tokens)
-        eng.decode_pipelined(np.where(positions < SEQ, -1, positions).astype(np.int32))
-        eng.pipeline_flush()
-    else:
-        eng.decode_prefill_fused(positions, p_lane=2, chunk=PROMPT[:10], tokens=tokens)
-        eng.pipeline_flush()
-    after = _lane(eng, 1)
-    for name in LEAVES:
-        np.testing.assert_array_equal(after[name], before[name], err_msg=name)
-    for name in ("delta", "delta_conv"):
-        assert not np.array_equal(_lane(eng, 0)[name], live_before[name]), name
-
-
 def test_a_state_at_rest_in_bfloat16_is_told_though_both_lanes_agree(eng):
     """Two lanes of one engine agree whatever precision both keep their state
     in, and the logits do not show a state in bfloat16 (the cell's
@@ -126,83 +91,6 @@ def test_a_state_at_rest_in_bfloat16_is_told_though_both_lanes_agree(eng):
         assert FAMILY.lane_state_rel_err(eng, 0, 1, 20) == 1.0
     finally:
         eng.cache = eng.cache._replace(delta=was)
-
-
-def test_a_padded_tail_is_ignored_and_token_by_token_is_the_same_state(eng):
-    """20 tokens through the 64 bucket (44 rows of padding) against the same
-    tokens one decode step each, and as a fused admission: other programs, the
-    same matrix state and the same windows."""
-    eng.prefill(0, PROMPT[:20])
-    for i, tok in enumerate(PROMPT[:20]):
-        eng.decode(*_park(eng, {1: (tok, i)}))
-    assert FAMILY.lanes_rel_err(eng, 0, 1, 20) < 1e-5
-    eng.decode_prefill_fused(np.full(8, SEQ, np.int32), p_lane=2, chunk=PROMPT[:20],
-                             tokens=np.zeros(8, np.int32))
-    eng.pipeline_flush()
-    assert FAMILY.lanes_rel_err(eng, 0, 2, 20) < 1e-5
-    # a state that absorbed the padding would differ in every head
-    eng.prefill(3, PROMPT[:20] + [0] * 12)
-    assert FAMILY.lanes_rel_err(eng, 0, 3, 20) > 1e-3
-
-
-def test_a_second_chunk_continues_the_first(eng):
-    eng.prefill(0, PROMPT[:100])  # 64 + 36 of 64
-    eng.prefill(1, PROMPT[:29])   # an odd cut: inside a 32-row chunk of the chunk form
-    eng.prefill(1, PROMPT[29:100], start_pos=29)
-    assert FAMILY.lanes_rel_err(eng, 0, 1, 100) < 1e-5
-    park = np.full(8, SEQ, np.int32)
-    eng.decode_prefill_fused(park, p_lane=2, chunk=PROMPT[:15], tokens=np.zeros(8, np.int32))
-    eng.decode_prefill_fused(park, p_lane=2, chunk=PROMPT[15:60], p_start=15)  # parked between
-    eng.pipeline_flush()
-    eng.prefill(3, PROMPT[:60])
-    assert FAMILY.lanes_rel_err(eng, 3, 2, 60) < 1e-5
-    # a second chunk that restarted from zero is another state
-    eng.prefill(4, PROMPT[15:60])
-    assert FAMILY.lanes_rel_err(eng, 3, 4, 1) > 1e-3
-
-
-def test_a_request_that_follows_another_on_a_lane_reads_zeros(eng):
-    eng.prefill(4, PROMPT[40:90])  # what an earlier request left behind
-    dirty = _lane(eng, 4)
-    zero_starts = eng.stats.state_zero_starts
-    eng.prefill(4, PROMPT[:20])
-    eng.prefill(5, PROMPT[60:70])
-    eng.cache = eng.cache._replace(  # a lane never used
-        delta=eng.cache.delta.at[:, 5].set(0.0),
-        delta_conv=eng.cache.delta_conv.at[:, 5].set(0.0))
-    eng.prefill(5, PROMPT[:20])
-    assert eng.stats.state_zero_starts == zero_starts + 3
-    for name in ("delta", "delta_conv"):
-        np.testing.assert_array_equal(_lane(eng, 4)[name], _lane(eng, 5)[name], err_msg=name)
-        assert not np.array_equal(_lane(eng, 4)[name], dirty[name])
-    # a decode step at position 0 starts a sequence too
-    eng.cache = eng.cache._replace(
-        delta=eng.cache.delta.at[:, 6].set(3.0),
-        delta_conv=eng.cache.delta_conv.at[:, 6].set(3.0))
-    eng.decode(*_park(eng, {6: (9, 0), 7: (9, 0)}))
-    for name in ("delta", "delta_conv"):
-        np.testing.assert_array_equal(_lane(eng, 6)[name], _lane(eng, 7)[name], err_msg=name)
-
-
-def test_a_lane_taken_out_and_put_back_carries_every_leaf(eng):
-    """The fused step's splice of the admitted lane: lane 2's rows of every
-    leaf after a fused admission are the rows a synchronous prefill writes,
-    and no other lane's rows moved; a copy of a lane is refused by name."""
-    eng.prefill(5, PROMPT[:50])
-    others = _lane(eng, 5)
-    eng.prefill(3, PROMPT[:60])
-    eng.decode_prefill_fused(np.full(8, SEQ, np.int32), p_lane=2, chunk=PROMPT[:60],
-                             tokens=np.zeros(8, np.int32))
-    eng.pipeline_flush()
-    assert FAMILY.lanes_rel_err(eng, 3, 2, 60) < 1e-5
-    for name, was in others.items():
-        np.testing.assert_array_equal(_lane(eng, 5)[name], was, err_msg=name)
-    with pytest.raises(RuntimeError, match="recurrent state"):
-        eng.copy_lane(0, 1)
-    n = eng.n_lanes
-    z = np.zeros(n, np.int32)
-    with pytest.raises(ValueError, match="without speculation"):
-        eng.decode_spec(z, np.zeros((n, eng.SPEC_DRAFT), np.int32), z, z)
 
 
 def test_the_fused_step_carries_the_delta_scopes_in_both_halves(eng):

@@ -3,49 +3,19 @@ loader and the scheduler on the CPU: admissions into lanes that served before
 ride fused steps (zero starts) and give the tokens they give alone; what is
 counted and declined (tests/test_delta_engine.py has the engine's cases)."""
 
-import jax.numpy as jnp
 import pytest
 
-from distributed_llama_multiusers_tpu.formats import load_model_header
-from distributed_llama_multiusers_tpu.formats.synthetic import (
-    tiny_delta_header,
-    write_synthetic_model,
-    write_synthetic_tokenizer,
-)
-from distributed_llama_multiusers_tpu.models import load_params_from_m
-from distributed_llama_multiusers_tpu.runtime import ContinuousBatchingScheduler, Request
-from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine, warmup_engine
-from distributed_llama_multiusers_tpu.tokenizer import Tokenizer
+from distributed_llama_multiusers_tpu.formats.synthetic import tiny_delta_header
+
+import latent_toy
 
 
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """A synthetic ``G D D D`` checkpoint through the real writer and loader."""
-    d = tmp_path_factory.mktemp("delta")
-    header = tiny_delta_header("GDDD")
-    write_synthetic_model(str(d / "m.m"), header, seed=3, scale=0.1)
-    write_synthetic_tokenizer(str(d / "t.t"), vocab_size=header.vocab_size)
-    h = load_model_header(str(d / "m.m"))
-    config, params = load_params_from_m(str(d / "m.m"), h, dtype=jnp.float32)
-    return config, params, Tokenizer(str(d / "t.t"))
-
-
-def _serve(served, prompts, lanes=2, max_tokens=8):
-    config, params, tok = served
-    engine = InferenceEngine(config, params, n_lanes=lanes, prefill_buckets=(16,))
-    sched = ContinuousBatchingScheduler(engine, tok)
     # (the pipelined loop takes the multi-step programs' place: none is warmed, none compiles)
-    warmup_engine(engine, spec=sched.speculative, multi_step=0)
-    sched.start()
-    try:
-        reqs = [sched.submit(Request(prompt=p, max_tokens=max_tokens, temperature=0.0))
-                for p in prompts]
-        for r in reqs:
-            r.future.result(timeout=600)
-            assert r.error is None, r.error
-    finally:
-        sched.stop()
-    return [list(r.generated_tokens) for r in reqs], engine.stats.snapshot()
+    return latent_toy.serving(tiny_delta_header("GDDD"), tmp_path_factory.mktemp("delta"),
+                              scale=0.1, buckets=(16,), multi_step=0)
 
 
 def test_a_loaded_checkpoint_serves_and_admissions_reuse_lanes(served):
@@ -57,7 +27,7 @@ def test_a_loaded_checkpoint_serves_and_admissions_reuse_lanes(served):
     shared = "the same long opening words of two requests, and more of them, "
     prompts = [shared + "then one end", shared + "then another", "ab ab ab ab ab ab",
                "hello world hello", "lo lo lo world", shared + "then one end"]
-    tokens, stats = _serve(served, prompts)
+    tokens, stats = served.serve(prompts)
     assert stats["state_zero_starts"] == 6 and stats["jit_compiles_after_warmup"] == 0
     assert stats["prefix_hits"] == 0 and stats["prefix_tokens_saved"] == 0
     assert stats["spec_steps"] == 0 and stats["pipeline_flushes"] == 0 and stats["fused_steps"] > 0

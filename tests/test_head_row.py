@@ -15,6 +15,7 @@
     as it did.
 """
 
+import functools
 import re
 
 import jax
@@ -33,8 +34,10 @@ import latent_toy
 from test_tracing import lowered_with_debug_info
 
 BUCKETS = (8, 16)
+ODD_BUCKET, ODD_VOCAB = 12, 160
 FAMILIES = ("llama", "latent", "hybrid")
-TOYS = {"latent": "tiny_latent.json", "hybrid": "tiny_lfm2.json"}
+# (family -> its row of the one table of toys, tests/latent_toy.py)
+TOYS = {"latent": "latent", "hybrid": "lfm2"}
 PROMPT = [int(t) for t in np.random.default_rng(53).integers(2, 96, size=16)]
 SAMPLER = dict(temp=0.8, topp=0.9, seed=1234)
 # (greedy, sampled) of ``engine.prefill(0, PROMPT[:n], **SAMPLER)`` at the
@@ -57,7 +60,7 @@ def build(family: str, model_dir, lanes: int = 3, buckets=BUCKETS, quantized=Fal
         load = load_params_from_m_quantized if quantized else load_params_from_m
         config, params = load(path, load_model_header(path), dtype=jnp.float32)
         return InferenceEngine(config, params, n_lanes=lanes, prefill_buckets=buckets)
-    cfg, toy, _ = latent_toy.load(TOYS[family])  # Q40 at rest
+    cfg, toy, _ = latent_toy.toy(TOYS[family])  # Q40 at rest
     if vocab_size:
         cfg = {**cfg, "vocab_size": vocab_size}
     return latent_toy.engine(toy, cfg, lanes=lanes, prefill_buckets=buckets)[0]
@@ -81,17 +84,50 @@ def q40_engines(tmp_path_factory):
     linear.set_pallas_interpret(False)
 
 
+@pytest.fixture(scope="module")
+def warmed(tmp_path_factory):
+    """A family's engine on ``BUCKETS`` (three lanes), warmed: the engine
+    itself, what ``path_facts()`` said of the head's rows before and after the
+    warm-up, and how many programs either prefill family then held. One
+    engine a family for the file (an engine a case traced and compiled each
+    bucket's program anew: the tier-1 clock)."""
+    @functools.cache
+    def get(family):
+        engine = build(family, tmp_path_factory.mktemp(family + "_warmed"))
+        before = engine.path_facts()["prefill_head_rows"]
+        warmup_engine(engine, spec=False)
+        return (engine, before, engine.path_facts()["prefill_head_rows"],
+                engine._prefill_fn._cache_size(), engine._decode_prefill_fn._cache_size())
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def odd_engines(tmp_path_factory):
+    """A family's engine with one bucket and a vocabulary of sizes no other
+    axis of the toys has: one a family, both of its prefill programs lowered
+    from it."""
+    return functools.cache(lambda family: build(
+        family, tmp_path_factory.mktemp(family + "_odd"), lanes=2, buckets=(ODD_BUCKET,),
+        vocab_size=ODD_VOCAB))
+
+
 def whole_and_cut(engine, tokens, head_row):
     """(every row's logits, the cut call's logits, both caches) of one forward
     over ``tokens`` ``[B, T]`` from position 0 on the engine's first B lanes."""
+    forward = _forward(engine, *tokens.shape)
+    return forward(tokens, None), forward(tokens, head_row)
+
+
+@functools.cache
+def _forward(engine, b, t):
+    """The jitted forward of ``engine`` over ``[b, t]`` tokens, one for the
+    cases of one shape (a new ``jax.jit`` a case compiled both calls anew)."""
     cfg = engine.config
-    b, t = tokens.shape
     cache = jax.tree_util.tree_map(lambda a: a[:, :b], engine.cache)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    forward = jax.jit(
-        lambda tok, row: forward_counted(cfg)(
-            cfg, engine.params, tok, positions, cache, head_row=row)[:2])
-    return forward(tokens, None), forward(tokens, head_row)
+    return jax.jit(lambda tok, row: forward_counted(cfg)(
+        cfg, engine.params, tok, positions, cache, head_row=row)[:2])
 
 
 @pytest.mark.parametrize("rows", [
@@ -117,8 +153,8 @@ def test_forward_returns_the_named_row_of_the_whole_head(q40_engines, family, ro
 
 @pytest.mark.parametrize("n_tokens", [5, 16], ids=["padded_tail", "whole_bucket"])
 @pytest.mark.parametrize("family", FAMILIES)
-def test_prefill_returns_what_the_whole_head_gave(tmp_path, family, n_tokens):
-    engine = build(family, tmp_path)
+def test_prefill_returns_what_the_whole_head_gave(warmed, family, n_tokens):
+    engine = warmed(family)[0]
     cfg, prompt = engine.config, PROMPT[:n_tokens]
     bucket = next(b for b in BUCKETS if b >= n_tokens)
     # the chunk as _prefill_half ran it before the cut: the bucket's every row
@@ -144,10 +180,9 @@ def test_prefill_returns_what_the_whole_head_gave(tmp_path, family, n_tokens):
 
 @pytest.mark.parametrize("attr", ["_prefill_fn", "_decode_prefill_fn"])
 @pytest.mark.parametrize("family", FAMILIES)
-def test_no_prefill_program_holds_a_bucket_of_logits(tmp_path, family, attr):
-    # one bucket and a vocabulary, of sizes no other axis of the toys has
-    bucket, vocab = 12, 160
-    engine = build(family, tmp_path, lanes=2, buckets=(bucket,), vocab_size=vocab)
+def test_no_prefill_program_holds_a_bucket_of_logits(odd_engines, family, attr):
+    bucket, vocab = ODD_BUCKET, ODD_VOCAB
+    engine = odd_engines(family)
     assert engine.config.vocab_size == vocab
     # rows by the vocabulary, or by wcls's columns where the loader padded them
     wcls = engine.params.wcls
@@ -159,18 +194,18 @@ def test_no_prefill_program_holds_a_bucket_of_logits(tmp_path, family, attr):
         engine.config, engine.params, tok, pos, lane_cache)[0])
     zeros = jnp.zeros((1, bucket), jnp.int32)
     assert seen.search(whole.lower(zeros, zeros).as_text())
-    # lowered_with_debug_info runs a chunk of 3 tokens through the program
+    # lowered_with_debug_info hands the program a chunk of 3 tokens
     text = lowered_with_debug_info(engine, attr)
     assert f"tensor<1x1x{vocab}xf32>" in text
     assert not seen.search(text)
 
 
 @pytest.mark.parametrize("family", FAMILIES)
-def test_warm_up_says_one_head_row_and_compiles_a_program_a_bucket(tmp_path, family):
-    engine = build(family, tmp_path, lanes=2)
-    assert engine.path_facts()["prefill_head_rows"] == BUCKETS[-1]  # none traced yet
-    warmup_engine(engine, spec=False)
-    assert engine.path_facts()["prefill_head_rows"] == 1
-    # one program a bucket and family, as before the cut: no new program
-    assert engine._prefill_fn._cache_size() == len(BUCKETS)
-    assert engine._decode_prefill_fn._cache_size() == len(BUCKETS)
+def test_warm_up_says_one_head_row_and_compiles_a_program_a_bucket(warmed, family):
+    engine, before, after, prefills, fused = warmed(family)
+    assert before == BUCKETS[-1]  # none traced yet
+    assert after == engine.path_facts()["prefill_head_rows"] == 1
+    # one program a bucket and family, as before the cut: no new program, at
+    # the warm-up's end and after whatever the file's cases prefilled since
+    assert prefills == engine._prefill_fn._cache_size() == len(BUCKETS)
+    assert fused == engine._decode_prefill_fn._cache_size() == len(BUCKETS)

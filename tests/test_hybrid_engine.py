@@ -1,55 +1,43 @@
 """A block whose layers differ in their mixer (models/hybrid.py) through
-``InferenceEngine`` and the scheduler at a toy size on the CPU: the one rule
-for a state overwritten in place, in every step family (a parked lane keeps
-it, a bucket's padded tail is ignored, a second chunk continues the first,
-position 0 reads zeros whatever the lane held, the pipelined overshoot of a
-finished lane harms no later request); what is declined for such a model
-(prefix reuse by lane copy, speculation) and what is refused by name."""
+``InferenceEngine`` and the scheduler at a toy size on the CPU: the rule for
+a state overwritten in place by arithmetic, the pipelined overshoot of a
+finished lane that harms no later request, the kernels in interpret mode and
+the merged stack read in place; what is declined for such a model (prefix
+reuse by lane copy, speculation) and what is refused by name. (The rule in
+every step family, a parked lane, a padded tail, a second chunk, position 0,
+is tests/test_lane_state_contract.py's row ``lfm2``.)
+
+One engine for the file (``built``) and one warmed engine behind ``served``:
+a case builds an engine of its own only where the construction is its subject
+(interpret mode, another dtype or shape)."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_llama_multiusers_tpu.formats import load_model_header
-from distributed_llama_multiusers_tpu.formats.synthetic import (
-    tiny_pattern_header,
-    write_synthetic_model,
-    write_synthetic_tokenizer,
-)
-from distributed_llama_multiusers_tpu.models import load_params_from_m
+from distributed_llama_multiusers_tpu.formats.synthetic import tiny_pattern_header
 from distributed_llama_multiusers_tpu.models.hybrid import (
     HybridCache,
     layer_periods,
     window_state,
 )
 from distributed_llama_multiusers_tpu.ops import linear
-from distributed_llama_multiusers_tpu.runtime import ContinuousBatchingScheduler, Request
-from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine, warmup_engine
-from distributed_llama_multiusers_tpu.tokenizer import Tokenizer
+from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
 
 import latent_toy
+from latent_toy import park
 
-CFG, FAMILY, CORRECT = latent_toy.load("tiny_lfm2.json")
+CFG, FAMILY, CORRECT = latent_toy.toy("lfm2")
 SEQ = CFG["max_position_embeddings"]
 PROMPT = [int(x) for x in np.random.default_rng(0).integers(2, CFG["vocab_size"], size=100)]
 
 
+built = latent_toy.module_engine(FAMILY, CFG, seed=7, lanes=8)
+
+
 @pytest.fixture(scope="module")
-def eng():
-    return latent_toy.engine(FAMILY, CFG, seed=7, lanes=8)[0]
-
-
-def _state(eng, lane):
-    return np.asarray(eng.cache.conv[:, lane])
-
-
-def _park(eng, live: dict):
-    """Tokens and positions of a step in which only ``live`` lanes move."""
-    tokens = np.zeros(eng.n_lanes, np.int32)
-    positions = np.full(eng.n_lanes, SEQ, np.int32)
-    for lane, (tok, pos) in live.items():
-        tokens[lane], positions[lane] = tok, pos
-    return tokens, positions
+def eng(built):
+    return built[0]
 
 
 def test_the_rule_itself():
@@ -78,101 +66,31 @@ def test_the_cache_is_a_stack_a_kind(eng):
     assert eng.moe_slabs_per_step == 6 * 8 and not eng.supports_speculative
 
 
-@pytest.mark.parametrize("family", ["decode", "decode_nologits", "decode_multi", "decode_pl", "fused"])
-def test_a_parked_lane_keeps_its_state_in_every_step_family(eng, family):
-    eng.prefill(0, PROMPT[:20])
-    eng.prefill(1, PROMPT[:30])
-    before = _state(eng, 1)
-    tokens, positions = _park(eng, {0: (5, 20)})
-    if family == "decode":
-        eng.decode(tokens, positions)
-    elif family == "decode_nologits":
-        eng.decode(tokens, positions, want_logits=False)
-    elif family == "decode_multi":
-        eng.decode_multi(tokens, positions, h=2)
-    elif family == "decode_pl":
-        eng.decode_pipelined(positions, tokens=tokens)
-        eng.decode_pipelined(np.where(positions < SEQ, -1, positions).astype(np.int32))
-        eng.pipeline_flush()
-    else:
-        eng.decode_prefill_fused(positions, p_lane=2, chunk=PROMPT[:10], tokens=tokens)
-        eng.pipeline_flush()
-    np.testing.assert_array_equal(_state(eng, 1), before)
-    assert not np.array_equal(_state(eng, 0), before)  # the live lane moved
-
-
-def test_a_padded_tail_is_ignored_and_token_by_token_is_the_same_state(eng):
-    """20 tokens through the 64 bucket (44 rows of padding) against the same
-    tokens one decode step each: other programs, the same window."""
-    eng.prefill(0, PROMPT[:20])
-    for i, tok in enumerate(PROMPT[:20]):
-        eng.decode(*_park(eng, {1: (tok, i)}))
-    assert FAMILY.lane_state_rel_err(eng, 0, 1, 20) < 1e-5
-    eng.decode_prefill_fused(np.full(8, SEQ, np.int32), p_lane=2, chunk=PROMPT[:20],
-                             tokens=np.zeros(8, np.int32))
-    eng.pipeline_flush()
-    assert FAMILY.lane_state_rel_err(eng, 0, 2, 20) < 1e-5
-
-
-def test_a_second_chunk_continues_the_first(eng):
-    eng.prefill(0, PROMPT)  # 64 + 36 through the 64 bucket
-    eng.prefill(1, PROMPT[:30])
-    eng.prefill(1, PROMPT[30:], start_pos=30)
-    assert FAMILY.lane_state_rel_err(eng, 0, 1, 100) < 1e-5
-    park = np.full(8, SEQ, np.int32)
-    eng.decode_prefill_fused(park, p_lane=2, chunk=PROMPT[:16], tokens=np.zeros(8, np.int32))
-    eng.decode_prefill_fused(park, p_lane=2, chunk=PROMPT[16:60], p_start=16)  # parked between
-    eng.pipeline_flush()
-    eng.prefill(3, PROMPT[:60])
-    assert FAMILY.lane_state_rel_err(eng, 3, 2, 60) < 1e-5
-
-
-def test_position_zero_reads_zeros_whatever_the_lane_held(eng):
-    eng.prefill(4, PROMPT[40:90])  # what an earlier request left behind
-    dirty = _state(eng, 4).copy()
-    zero_starts = eng.stats.state_zero_starts
-    eng.prefill(4, PROMPT[:20])
-    eng.prefill(5, PROMPT[60:70])
-    eng.cache = eng.cache._replace(conv=eng.cache.conv.at[:, 5].set(0.0))  # a lane never used
-    eng.prefill(5, PROMPT[:20])
-    assert eng.stats.state_zero_starts == zero_starts + 3
-    np.testing.assert_array_equal(_state(eng, 4), _state(eng, 5))
-    assert not np.array_equal(_state(eng, 4), dirty)
-    # a decode step at position 0 starts a sequence too
-    eng.cache = eng.cache._replace(conv=eng.cache.conv.at[:, 6].set(3.0))
-    eng.decode(*_park(eng, {6: (9, 0), 7: (9, 0)}))
-    np.testing.assert_array_equal(_state(eng, 6), _state(eng, 7))
-
-
 def test_the_pipelined_overshoot_of_a_finished_lane_harms_no_later_request(eng):
     """Two steps are in flight when the host learns a lane has finished: the
     lane absorbed a token it never emits. The lane's next request starts at
     position 0 and reads none of it."""
     eng.prefill(0, PROMPT[:20])
-    tokens, positions = _park(eng, {0: (5, 20)})
+    tokens, positions = park(eng, {0: (5, 20)})
     eng.decode_pipelined(positions, tokens=tokens)
     eng.decode_pipelined(np.where(positions < SEQ, -1, positions).astype(np.int32))  # the overshoot
     eng.pipeline_flush()
     first, _, _ = eng.prefill(0, PROMPT[20:50])
     fresh, _, _ = eng.prefill(1, PROMPT[20:50])
     np.testing.assert_array_equal(np.asarray(first), np.asarray(fresh))
-    np.testing.assert_array_equal(_state(eng, 0), _state(eng, 1))
+    np.testing.assert_array_equal(np.asarray(eng.cache.conv[:, 0]), np.asarray(eng.cache.conv[:, 1]))
 
 
-def test_kernels_in_interpret_mode_agree_with_the_reference():
-    linear.set_pallas_interpret(True)
-    try:
-        e, tensors = latent_toy.engine(FAMILY, CFG, 5)
-        assert e.path_facts()["expert_path"] == "q40_grouped_kernel"
-        r = CORRECT.compare(FAMILY, CFG, tensors, e, 5)
-    finally:
-        linear.set_pallas_interpret(False)
+def test_kernels_in_interpret_mode_agree_with_the_reference(pallas_interpret):
+    e, tensors = latent_toy.engine(FAMILY, CFG, 5)
+    assert e.path_facts()["expert_path"] == "q40_grouped_kernel"
+    r = CORRECT.compare(FAMILY, CFG, tensors, e, 5)
     assert r["ok"], r
     assert r["prefill_rel_err"] < 1e-5 and r["decode_rel_err"] < 1e-5
     assert (r["route_greedy_gap"], r["route_nucleus_excess"], r["route_kv_rel_err"]) == (0, 0, 0)
 
 
-def test_decode_steps_attend_the_merged_stack_in_place_where_the_kernel_takes_it():
+def test_decode_steps_attend_the_merged_stack_in_place_where_the_kernel_takes_it(pallas_interpret):
     """A bfloat16 cache whose rows are whole 128-lane tiles and whose context
     is whole blocks, Pallas on (interpret mode): the engine says so, and the
     tokens of a pipelined chain, of a fused admission's decode half and of the
@@ -180,15 +98,11 @@ def test_decode_steps_attend_the_merged_stack_in_place_where_the_kernel_takes_it
     from distributed_llama_multiusers_tpu.ops import pallas_attention
 
     cfg, family, correct = latent_toy.wide_lfm2()
-    linear.set_pallas_interpret(True)
-    try:
-        e, tensors = latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16)
-        assert e.cache.k.shape == (2, 8, 512, 128) and e.cache.k.dtype == jnp.bfloat16
-        assert e.path_facts()["attention_path"] == "pallas_in_place"
-        assert e.decode_attention_block == pallas_attention.BLOCK_ROWS
-        r = correct.compare(family, cfg, tensors, e, 5, keep_rows=True)
-    finally:
-        linear.set_pallas_interpret(False)
+    e, tensors = latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16)
+    assert e.cache.k.shape == (2, 8, 512, 128) and e.cache.k.dtype == jnp.bfloat16
+    assert e.path_facts()["attention_path"] == "pallas_in_place"
+    assert e.decode_attention_block == pallas_attention.BLOCK_ROWS
+    r = correct.compare(family, cfg, tensors, e, 5, keep_rows=True)
     assert (r["route_greedy_gap"], r["route_nucleus_excess"], r["route_kv_rel_err"]) == (0, 0, 0)
     assert r["route_tokens"] >= 20 and r["route_token_mismatches"] == 0
     # bfloat16 against the float32 reference: a row reads 0.008-0.014, as the
@@ -251,7 +165,7 @@ def test_the_dense_path_is_said_where_the_kernel_does_not_take_the_cache(dtype, 
     cfg["max_position_embeddings"] = seq
     linear.set_pallas_interpret(why != "Pallas off")
     try:
-        e, _ = latent_toy.engine(family, cfg, 5, dtype=dtype)
+        e, _ = latent_toy.engine(family, cfg, 5, dtype=dtype, lanes=2)
         assert e.path_facts()["attention_path"] == "xla_dense", why
         assert e.decode_attention_block is None
     finally:
@@ -269,60 +183,29 @@ def test_bfloat16_where_float32_is_stated_is_told_apart():
     (dict(paged_kv=True, kv_host_bytes=1 << 20), "paged KV pool"),
     (dict(mesh=object()), "a mesh"),
 ])
-def test_what_the_block_does_not_serve_is_refused_by_name(kw, names):
-    config = FAMILY.program_config(CFG)
-    params = FAMILY.assemble_params(config, FAMILY.device_weights(config, 3, jnp.float32))
+def test_what_the_block_does_not_serve_is_refused_by_name(eng, kw, names):
     with pytest.raises(ValueError, match=names):
-        InferenceEngine(config, params, n_lanes=4, **kw)
-
-
-def test_verify_steps_and_lane_copies_are_refused(eng):
-    n = eng.n_lanes
-    z = np.zeros(n, np.int32)
-    with pytest.raises(ValueError, match="without speculation"):
-        eng.decode_spec(z, np.zeros((n, eng.SPEC_DRAFT), np.int32), z, z)
-    with pytest.raises(ValueError, match="without speculation"):
-        eng.decode_spec_pipelined(z, np.zeros((n, eng.SPEC_DRAFT + 1), np.int32), z, tokens=z)
-    with pytest.raises(RuntimeError, match="recurrent state"):
-        eng.copy_lane(0, 1)
-    eng.copy_lane(2, 2)  # nothing moves
+        InferenceEngine(eng.config, eng.params, n_lanes=4, **kw)
 
 
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
-    d = tmp_path_factory.mktemp("pattern")
-    header = tiny_pattern_header(seq_len=128)
-    write_synthetic_model(str(d / "m.m"), header, seed=3)
-    write_synthetic_tokenizer(str(d / "t.t"), vocab_size=header.vocab_size)
-    h = load_model_header(str(d / "m.m"))
-    config, params = load_params_from_m(str(d / "m.m"), h, dtype=jnp.float32)
-    return config, params, Tokenizer(str(d / "t.t"))
-
-
-def _serve(served, prompts, **kw):
-    config, params, tok = served
-    engine = InferenceEngine(config, params, n_lanes=2, prefill_buckets=(8, 16))
-    sched = ContinuousBatchingScheduler(engine, tok, **kw)
-    warmup_engine(engine, spec=sched.speculative, multi_step=sched.multi_step)
-    sched.start()
-    try:
-        out = []
-        for p in prompts:  # one after the other: the second finds the first resident
-            r = sched.submit(Request(prompt=p, max_tokens=8, temperature=0.0))
-            r.future.result(timeout=300)
-            assert r.error is None, r.error
-            out.append(list(r.generated_tokens))
-    finally:
-        sched.stop()
-    return out, engine.stats.snapshot()
+    # (both of its schedulers keep the pipelined loop: no multi-step program is
+    # warmed; one bucket: a prompt is admitted in chunks of 16 with a padded tail)
+    return latent_toy.serving(tiny_pattern_header(seq_len=128), tmp_path_factory.mktemp("pattern"),
+                              buckets=(16,), multi_step=0)
 
 
 def test_prefix_reuse_is_declined_and_speculation_too_with_the_same_tokens(served):
+    """One after the other, so that the second finds the first resident: the
+    defaults (prefix reuse at 16, speculation on), then the same warmed
+    engine under a scheduler with both off."""
     shared = "the same long opening words of two requests, "
     prompts = [shared + "then one end", shared + "then another", "ab ab ab ab ab ab ab ab ab"]
-    tokens, stats = _serve(served, prompts)  # the defaults: prefix reuse at 16, speculation on
+    tokens, stats = served.serve(prompts, in_turn=True)
     assert stats["prefix_reuse_declined"] >= 1 and stats["prefix_hits"] == 0
     assert stats["prefix_tokens_saved"] == 0 and stats["spec_steps"] == 0
     assert stats["state_zero_starts"] == 3 and stats["jit_compiles_after_warmup"] == 0
-    plain, off = _serve(served, prompts, prefix_min_tokens=0, speculative=False)
+    plain, off = served.serve(prompts, in_turn=True, prefix_min_tokens=0, speculative=False)
     assert tokens == plain and off["prefix_reuse_declined"] == 0
+    assert off["state_zero_starts"] == 3 and off["jit_compiles_after_warmup"] == 0

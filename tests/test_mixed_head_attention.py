@@ -9,7 +9,6 @@ reference in every step family."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -28,14 +27,12 @@ from distributed_llama_multiusers_tpu.models.loader import (
     load_params_from_m_quantized,
 )
 from distributed_llama_multiusers_tpu.ops import blocked_attention as ba
-from distributed_llama_multiusers_tpu.ops import linear
 from distributed_llama_multiusers_tpu.ops import pallas_attention as pa
 from distributed_llama_multiusers_tpu.ops.rope import apply_rope, apply_rope_first
-from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
 
 import latent_toy
 
-CFG, FAMILY, CORRECT = latent_toy.load("tiny_mimo_v2_flash.json")
+CFG, FAMILY, CORRECT = latent_toy.toy("mimo")
 SEQ = CFG["max_position_embeddings"]
 NEW_KEYS = (mf.KEY_ROTARY_DIM, mf.KEY_WINDOW_N_KV_HEADS, mf.KEY_WINDOW_ROPE_THETA,
             mf.KEY_ATTN_VALUE_SCALE_E6, mf.KEY_WINDOW_SINK)
@@ -232,8 +229,11 @@ def test_a_key_block_at_a_time_takes_the_same_widths_and_the_same_column(
 
 @pytest.fixture(scope="module")
 def chunked():
-    """(engine, tensors) with a ladder of 2 and 4: a ring of 12 rows."""
-    return latent_toy.engine(FAMILY, CFG, seed=7, lanes=10, prefill_buckets=(2, 4))
+    """(engine, tensors) with a ladder of 2 and 4, a ring of 12 rows: the
+    file's engine, taken by the compare in chunks and by every case that only
+    reads and writes lanes (tests/test_lane_state_contract.py's row ``mimo``
+    holds the rings to the rule every per-lane state keeps)."""
+    return latent_toy.engine(FAMILY, CFG, seed=5, lanes=10, prefill_buckets=(2, 4))
 
 
 def _reference_rows(tensors, tokens, rows):
@@ -251,7 +251,7 @@ def _rel(got, want):
 
 
 @pytest.mark.parametrize("ladder", [(64,), (2, 4)])
-def test_every_step_family_agrees_with_the_reference(ladder):
+def test_every_step_family_agrees_with_the_reference(chunked, ladder):
     """`correct.compare` at the toy's size: prompts under the window, one that
     crosses it while it decodes (6 + 6 steps over a window of 8), and prompts
     past the ring's wrap; prefilled whole (a ladder of one bucket as long as
@@ -261,7 +261,8 @@ def test_every_step_family_agrees_with_the_reference(ladder):
     twin parked while its other steps: logits at float32's noise, the chain's
     tokens the synchronous programs', and all four cache leaves of each pair
     of lanes the same."""
-    eng, tensors = latent_toy.engine(FAMILY, CFG, seed=5, lanes=10, prefill_buckets=ladder)
+    eng, tensors = chunked if ladder == (2, 4) else latent_toy.engine(
+        FAMILY, CFG, seed=5, lanes=10, prefill_buckets=ladder)
     assert eng.ring_rows == (12 if ladder == (2, 4) else SEQ)
     r = CORRECT.compare(FAMILY, CFG, tensors, eng, 5)
     assert r["ok"], r
@@ -323,7 +324,7 @@ def test_the_start_up_line_names_the_path_and_the_bytes_a_kind(chunked):
         eng.copy_lane(0, 1)  # a ring is overwritten in place: no copy at another position
 
 
-def test_the_kernels_in_interpret_mode_run_the_toy_at_the_dense_paths_numbers():
+def test_the_kernels_in_interpret_mode_run_the_toy_at_the_dense_paths_numbers(pallas_interpret):
     """Keys of 192 and values of 128 on 2 and 4 kv heads of a 256-wide stream,
     a context of two blocks, a bfloat16 cache, Pallas in interpret mode: both
     kinds' decode steps read their stacks in place (the ring through the
@@ -339,19 +340,15 @@ def test_the_kernels_in_interpret_mode_run_the_toy_at_the_dense_paths_numbers():
     # across the window's edge while decoding (125 + 8 steps), and past the
     # wrap of the 256-row ring (256 + 8; admitted in chunks of 128 + 128)
     cfg["correctness"].update(prompt_tokens=[125, 256], decode_steps=8)
-    linear.set_pallas_interpret(True)
-    try:
-        eng, tensors = latent_toy.engine(
-            FAMILY, cfg, 5, dtype=jnp.bfloat16, lanes=10, prefill_buckets=(64, 128))
-        facts = eng.path_facts()
-        assert facts["attention_path_by_kind"] == {
-            "full": "pallas_in_place", "window": "pallas_in_place"}
-        assert eng.cache.wk.shape == (2, 10, 256, 768) and eng.cache.wv.shape[-1] == 512
-        assert eng.cache.k.shape == (2, 10, 512, 384) and eng.cache.v.shape[-1] == 256
-        assert facts["window_attention_path"] == "pallas_in_place_ring"
-        kernel = CORRECT.compare(FAMILY, cfg, tensors, eng, 5, keep_rows=True)
-    finally:
-        linear.set_pallas_interpret(False)
+    eng, tensors = latent_toy.engine(
+        FAMILY, cfg, 5, dtype=jnp.bfloat16, lanes=10, prefill_buckets=(64, 128))
+    facts = eng.path_facts()
+    assert facts["attention_path_by_kind"] == {
+        "full": "pallas_in_place", "window": "pallas_in_place"}
+    assert eng.cache.wk.shape == (2, 10, 256, 768) and eng.cache.wv.shape[-1] == 512
+    assert eng.cache.k.shape == (2, 10, 512, 384) and eng.cache.v.shape[-1] == 256
+    assert facts["window_attention_path"] == "pallas_in_place_ring"
+    kernel = CORRECT.compare(FAMILY, cfg, tensors, eng, 5, keep_rows=True)
     assert (kernel["route_greedy_gap"], kernel["route_nucleus_excess"]) == (0, 0)
     assert kernel["route_kv_rel_err"] == 0 and kernel["route_token_mismatches"] == 0
     # bfloat16 against the float32 reference: a row reads 0.005-0.026, as on
@@ -404,16 +401,17 @@ def test_the_four_shares_of_four_experts_add_up_to_the_uncut_layer():
 # -- the scheduler's arithmetic ----------------------------------------------------
 
 
-def test_the_scheduler_counts_the_rows_either_kind_needs():
+def test_the_scheduler_counts_the_rows_either_kind_needs(chunked):
     from distributed_llama_multiusers_tpu.runtime.scheduler import ContinuousBatchingScheduler
     from distributed_llama_multiusers_tpu.utils.testing import StubStreamTokenizer
 
-    eng, _ = latent_toy.engine(FAMILY, CFG, seed=7, lanes=4, prefill_buckets=(2, 4))
+    eng, _ = chunked
     sched = ContinuousBatchingScheduler(
         eng, StubStreamTokenizer(CFG["vocab_size"]), speculative=False, prefix_min_tokens=0)
-    sched._count_attention_rows(np.asarray([2, 7, 30, SEQ]), steps=2)
-    stats = eng.stats.snapshot()
-    # pos + 1 a live lane a step, and min(pos + 1, 8); the parked lane counts nothing
+    before = eng.stats.snapshot()
+    sched._count_attention_rows(np.asarray([2, 7, 30] + [SEQ] * 7), steps=2)
+    stats = {k: v - before[k] for k, v in eng.stats.snapshot().items() if k.startswith("attn_")}
+    # pos + 1 a live lane a step, and min(pos + 1, 8); a parked lane counts nothing
     assert stats["attn_full_rows_needed"] == (3 + 4) + (8 + 9) + (31 + 32)
     assert stats["attn_window_rows_needed"] == (3 + 4) + (8 + 8) + (8 + 8)
-    assert stats["attn_full_rows_read"] == 4 * SEQ * 2  # the dense path reads whole planes
+    assert stats["attn_full_rows_read"] == 10 * SEQ * 2  # the dense path reads whole planes

@@ -4,6 +4,8 @@ their height, by the path ``slabs`` and ``assignments`` take (out of
 the pairs that took a row, from a fused step's prompt chunk too: into
 ``EngineStats.moe_tile_pairs`` and ``moe_tile_rows``."""
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -54,13 +56,24 @@ def test_engine_stats_keep_snapshot_and_reset_the_counts(field):
     assert stats.snapshot()[field] == 0
 
 
+@pytest.fixture(scope="module")
+def toy_engines():
+    """One engine a toy for the file: both of its cases prefill the lanes they
+    read and reset the counters before they count."""
+    @functools.cache
+    def get(toy: str):
+        cfg, family, _ = latent_toy.load(toy)
+        return latent_toy.engine(family, cfg, 4)[0]
+
+    return get
+
+
 @pytest.mark.parametrize("toy", ["tiny_latent.json", "tiny_deepseek_v32.json", "tiny_lfm2.json"])
-def test_a_decode_step_brings_the_count_back_with_its_tokens(toy):
+def test_a_decode_step_brings_the_count_back_with_its_tokens(toy_engines, toy):
     """Three live lanes a step: no expert gets more than three rows, so every
     fetched slab is one tile of 8 rows, in each block that routes (the latent
     block, a held share beside an indexer, the layer pattern)."""
-    cfg, family, _ = latent_toy.load(toy)
-    eng, _ = latent_toy.engine(family, cfg, 4)
+    eng = toy_engines(toy)
     assert eng._count_names[:3] == deepseek.ROUTED_COUNTS
     n, seq = eng.n_lanes, eng.config.seq_len
     for lane in range(3):
@@ -85,13 +98,12 @@ def test_a_decode_step_brings_the_count_back_with_its_tokens(toy):
 
 
 @pytest.mark.parametrize("toy", ["tiny_latent.json", "tiny_deepseek_v32.json", "tiny_lfm2.json"])
-def test_a_fused_steps_chunk_brings_its_tiles_back_too(toy):
+def test_a_fused_steps_chunk_brings_its_tiles_back_too(toy_engines, toy):
     """The boundary column of a fused step carries the chunk's pairs and the
     rows of its tiles into the tile counters alone: the decode steps' counts
     (``moe_assignments``, ``moe_slabs_read`` and what reads them) stay the
     decode half's."""
-    cfg, family, _ = latent_toy.load(toy)
-    eng, _ = latent_toy.engine(family, cfg, 4)
+    eng = toy_engines(toy)
     c = eng.config
     n, seq = eng.n_lanes, c.seq_len
     eng.prefill(0, list(range(5, 25)))

@@ -27,7 +27,8 @@ import latent_toy
 from test_tracing import lowered_with_debug_info
 
 BUCKET = 64
-TOYS = {"llama": "tiny.json", "latent": "tiny_latent.json", "pattern": "tiny_lfm2.json"}
+# (block -> its row of the one table of toys, tests/latent_toy.py)
+TOYS = {"llama": "llama", "latent": "latent", "pattern": "lfm2"}
 ENTRY = re.compile(r"_q40_matmul_\w*_impl")
 
 
@@ -41,7 +42,7 @@ def toy_engines():
     try:
         def get(block: str):
             if block not in made:
-                cfg, family, _ = latent_toy.load(TOYS[block])
+                cfg, family, _ = latent_toy.toy(TOYS[block])
                 made[block] = latent_toy.engine(
                     family, cfg, seed=3, lanes=4, prefill_buckets=(BUCKET,))[0]
             return made[block]

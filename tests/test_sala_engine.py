@@ -9,7 +9,12 @@ reference; the rule for the matrix state and for the compressed keys in every
 step family; dense and sparse lanes in one decode step; the kernels in
 interpret mode at a shape they tile; what is declined and counted
 (tests/test_sala_serving.py: a checkpoint through the writer, the loader and
-the scheduler)."""
+the scheduler; tests/test_lane_state_contract.py's row ``sala``: the rule for
+the matrix state and the compressed keys in every step family).
+
+One engine for the file (``built``, the default ladder: one bucket of 64): a
+case builds an engine of its own only where the construction is its subject
+(another ladder, another shape, a monkeypatched selection or layer loop)."""
 
 import copy
 
@@ -24,28 +29,20 @@ from distributed_llama_multiusers_tpu.ops import linear
 from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine
 
 import latent_toy
+from latent_toy import park
 
-CFG, FAMILY, CORRECT = latent_toy.load("tiny_minicpm_sala.json")
+CFG, FAMILY, CORRECT = latent_toy.toy("sala")
 SEQ = CFG["max_position_embeddings"]
 PROMPT = [int(x) for x in np.random.default_rng(0).integers(2, CFG["vocab_size"], size=120)]
 L, S = LayerKind.LINEAR, LayerKind.SPARSE
 
 
+built = latent_toy.module_engine(FAMILY, CFG, seed=5, lanes=8)
+
+
 @pytest.fixture(scope="module")
-def eng():
-    return latent_toy.engine(FAMILY, CFG, seed=7, lanes=8, prefill_buckets=(16, 32, 64))[0]
-
-
-def _state(eng, lane):
-    return np.asarray(eng.cache.lin[:, lane]).ravel()
-
-
-def _park(eng, live: dict):
-    tokens = np.zeros(eng.n_lanes, np.int32)
-    positions = np.full(eng.n_lanes, SEQ, np.int32)
-    for lane, (tok, pos) in live.items():
-        tokens[lane], positions[lane] = tok, pos
-    return tokens, positions
+def eng(built):
+    return built[0]
 
 
 def test_the_cache_has_four_kinds_of_leaf_and_the_state_is_counted(eng):
@@ -71,14 +68,15 @@ def test_the_cache_has_four_kinds_of_leaf_and_the_state_is_counted(eng):
 
 
 @pytest.mark.parametrize("buckets", [None, (16, 32, 64)], ids=["whole", "chunks"])
-def test_engine_agrees_with_the_plain_reference_and_the_routes_read_zero(buckets):
+def test_engine_agrees_with_the_plain_reference_and_the_routes_read_zero(built, buckets):
     """Prompts of 20, 46, 60 and 100 whole (one bucket each) and in chunks of
     at most 64 with a padded tail (100 = 64 + 32 + 4 of 16); four decode steps through the cache, the 46
     crossing dense_len (48) and a kernel's end while it decodes; the pipelined
     and fused programs against the synchronous ones on twins left parked, the
     whole state compared pair by pair."""
-    kw = {} if buckets is None else {"prefill_buckets": buckets}
-    e, tensors = latent_toy.engine(FAMILY, CFG, 5, **kw)
+    # (the file's engine has the default ladder)
+    e, tensors = built if buckets is None else latent_toy.engine(
+        FAMILY, CFG, 5, prefill_buckets=buckets)
     r = CORRECT.compare(FAMILY, CFG, tensors, e, 5)
     assert r["ok"], r
     assert r["prefill_rel_err"] < 1e-5 and r["decode_rel_err"] < 1e-5
@@ -86,8 +84,8 @@ def test_engine_agrees_with_the_plain_reference_and_the_routes_read_zero(buckets
     assert r["route_token_mismatches"] == 0 and r["route_tokens"] >= 20
 
 
-def test_the_scalars_of_the_parametrisation_are_told_apart():
-    e, tensors = latent_toy.engine(FAMILY, CFG, 5)
+def test_the_scalars_of_the_parametrisation_are_told_apart(built):
+    e, tensors = built
     for wrong in (dict(scale_emb=1), dict(scale_depth=1.0), dict(dim_model_base=128)):
         r = CORRECT.compare(FAMILY, dict(CFG, **wrong), tensors, e, 5)
         assert not r["ok"] and r["prefill_rel_err"] > 0.01, wrong
@@ -137,80 +135,6 @@ def test_kernels_in_interpret_mode_agree_with_the_masked_and_chunked_paths():
     assert np.median(err) < 0.01 and (err > 0.1).mean() <= 0.1, err
 
 
-@pytest.mark.parametrize("family", ["decode", "decode_nologits", "decode_multi", "decode_pl", "fused"])
-def test_a_parked_lane_keeps_its_state_in_every_step_family(eng, family):
-    eng.prefill(0, PROMPT[:20])
-    eng.prefill(1, PROMPT[:30])
-    before, ck_before = _state(eng, 1), np.asarray(eng.cache.ck[:, 1])
-    tokens, positions = _park(eng, {0: (5, 20)})
-    if family == "decode":
-        eng.decode(tokens, positions)
-    elif family == "decode_nologits":
-        eng.decode(tokens, positions, want_logits=False)
-    elif family == "decode_multi":
-        eng.decode_multi(tokens, positions, h=2)
-    elif family == "decode_pl":
-        eng.decode_pipelined(positions, tokens=tokens)
-        eng.decode_pipelined(np.where(positions < SEQ, -1, positions).astype(np.int32))
-        eng.pipeline_flush()
-    else:
-        eng.decode_prefill_fused(positions, p_lane=2, chunk=PROMPT[:10], tokens=tokens)
-        eng.pipeline_flush()
-    np.testing.assert_array_equal(_state(eng, 1), before)
-    np.testing.assert_array_equal(np.asarray(eng.cache.ck[:, 1]), ck_before)
-    assert not np.array_equal(_state(eng, 0), before)
-
-
-def test_a_padded_tail_is_ignored_and_token_by_token_is_the_same_state(eng):
-    """20 tokens through the 32 bucket (12 rows of padding) against the same
-    tokens one decode step each: other programs, the same matrix state and the
-    same compressed keys, each written by the step that brought its last row."""
-    eng.prefill(0, PROMPT[:20])
-    for i, tok in enumerate(PROMPT[:20]):
-        eng.decode(*_park(eng, {1: (tok, i)}))
-    assert FAMILY.lane_state_rel_err(eng, 0, 1, 20) < 1e-5
-    eng.decode_prefill_fused(np.full(8, SEQ, np.int32), p_lane=2, chunk=PROMPT[:20],
-                             tokens=np.zeros(8, np.int32))
-    eng.pipeline_flush()
-    assert FAMILY.lane_state_rel_err(eng, 0, 2, 20) < 1e-5
-    # a state that absorbed the padding would differ in every head
-    eng.prefill(3, PROMPT[:20] + [0] * 12)
-    assert FAMILY.lane_state_rel_err(eng, 0, 3, 20) > 1e-3
-
-
-def test_a_second_chunk_continues_the_first(eng):
-    eng.prefill(0, PROMPT[:100])  # 64 + 32 + 4 through three buckets
-    eng.prefill(1, PROMPT[:29])   # an odd cut: a kernel's rows on both sides
-    eng.prefill(1, PROMPT[29:100], start_pos=29)
-    assert FAMILY.lane_state_rel_err(eng, 0, 1, 100) < 1e-5
-    park = np.full(8, SEQ, np.int32)
-    eng.decode_prefill_fused(park, p_lane=2, chunk=PROMPT[:15], tokens=np.zeros(8, np.int32))
-    eng.decode_prefill_fused(park, p_lane=2, chunk=PROMPT[15:60], p_start=15)  # parked between
-    eng.pipeline_flush()
-    eng.prefill(3, PROMPT[:60])
-    assert FAMILY.lane_state_rel_err(eng, 3, 2, 60) < 1e-5
-    # a second chunk that restarted from zero is another state
-    eng.prefill(4, PROMPT[15:60])
-    assert FAMILY.lane_state_rel_err(eng, 3, 4, 1) > 1e-3
-
-
-def test_position_zero_reads_zeros_in_a_lane_that_served_before(eng):
-    eng.prefill(4, PROMPT[40:90])  # what an earlier request left behind
-    dirty = _state(eng, 4).copy()
-    zero_starts = eng.stats.state_zero_starts
-    eng.prefill(4, PROMPT[:20])
-    eng.prefill(5, PROMPT[60:70])
-    eng.cache = eng.cache._replace(lin=eng.cache.lin.at[:, 5].set(0.0))  # never used
-    eng.prefill(5, PROMPT[:20])
-    assert eng.stats.state_zero_starts == zero_starts + 3
-    np.testing.assert_array_equal(_state(eng, 4), _state(eng, 5))
-    assert not np.array_equal(_state(eng, 4), dirty)
-    # a decode step at position 0 starts a sequence too
-    eng.cache = eng.cache._replace(lin=eng.cache.lin.at[:, 6].set(3.0))
-    eng.decode(*_park(eng, {6: (9, 0), 7: (9, 0)}))
-    np.testing.assert_array_equal(np.asarray(eng.cache.lin[:, 6]), np.asarray(eng.cache.lin[:, 7]))
-
-
 def test_dense_and_sparse_lanes_decode_in_one_step_as_each_does_alone(eng):
     """Lane 0 at position 30 (under dense_len: every block it holds), lane 1
     at 100 (chooses 4 of 13): one step for both gives each lane the logits a
@@ -219,38 +143,38 @@ def test_dense_and_sparse_lanes_decode_in_one_step_as_each_does_alone(eng):
     eng.prefill(1, PROMPT[:100])
     eng.prefill(2, PROMPT[:30])
     eng.prefill(3, PROMPT[:100])
-    both, _, _ = eng.decode(*_park(eng, {0: (7, 30), 1: (9, 100)}), want_logits=True)
-    a, _, _ = eng.decode(*_park(eng, {2: (7, 30)}), want_logits=True)
-    b, _, _ = eng.decode(*_park(eng, {3: (9, 100)}), want_logits=True)
+    both, _, _ = eng.decode(*park(eng, {0: (7, 30), 1: (9, 100)}), want_logits=True)
+    a, _, _ = eng.decode(*park(eng, {2: (7, 30)}), want_logits=True)
+    b, _, _ = eng.decode(*park(eng, {3: (9, 100)}), want_logits=True)
     np.testing.assert_allclose(np.asarray(both[0]), np.asarray(a[2]), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(both[1]), np.asarray(b[3]), rtol=1e-5, atol=1e-5)
 
 
-def test_the_selection_is_in_the_logits(monkeypatch):
+def test_the_selection_is_in_the_logits(eng, monkeypatch):
     """With the selection left out of the program (every row takes every
     block it holds) a prompt past dense_len reads other logits and a prompt
     under it the same: what the reference's control shows from its side."""
     from distributed_llama_multiusers_tpu.ops import block_sparse
 
-    e, _ = latent_toy.engine(FAMILY, CFG, 5)
-    short, long_ = np.asarray(e.prefill(0, PROMPT[:40])[0]), np.asarray(e.prefill(1, PROMPT[:110])[0])
+    short, long_ = np.asarray(eng.prefill(0, PROMPT[:40])[0]), np.asarray(eng.prefill(1, PROMPT[:110])[0])
     monkeypatch.setattr(block_sparse, "choose", lambda r, pos, sizes: jnp.broadcast_to(
         block_sparse.held_blocks(pos, r.shape[-1], sizes), r.shape))
-    e2, _ = latent_toy.engine(FAMILY, CFG, 5)
+    e2, _ = latent_toy.engine(FAMILY, CFG, 5, lanes=2)
     np.testing.assert_allclose(np.asarray(e2.prefill(0, PROMPT[:40])[0]), short, rtol=1e-5, atol=1e-5)
     other = np.asarray(e2.prefill(1, PROMPT[:110])[0])
     assert CORRECT.relative_errors(other[None], long_[None]).max() > 0.01
 
 
-def test_the_layer_loop_with_its_runs_scanned_is_the_unrolled_one(monkeypatch):
+def test_the_layer_loop_with_its_runs_scanned_is_the_unrolled_one(eng, monkeypatch):
     """``S L L L L S S L`` has no period: its run of four linear layers is a
-    scan of its own, against every layer unrolled; and a tail's long run
-    scans too (``S S`` and nine ``L``)."""
-    scanned, _ = latent_toy.engine(FAMILY, CFG, seed=9, lanes=4)
+    scan of its own (the file's engine), against every layer unrolled (the
+    same seed's weights); and a tail's long run scans too (``S S`` and nine
+    ``L``)."""
+    scanned = eng
     row_s = np.asarray(scanned.prefill(0, PROMPT[:70])[0])
     monkeypatch.setattr(hybrid, "layer_periods", lambda kinds: (1, 0))
     monkeypatch.setattr(hybrid, "RUN_SCAN_MIN", 99)
-    unrolled, _ = latent_toy.engine(FAMILY, CFG, seed=9, lanes=4)
+    unrolled, _ = latent_toy.engine(FAMILY, CFG, seed=5, lanes=2)
     row_u = np.asarray(unrolled.prefill(0, PROMPT[:70])[0])
     np.testing.assert_allclose(row_s, row_u, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(scanned.cache.lin[:, 0]), np.asarray(unrolled.cache.lin[:, 0]),
@@ -260,37 +184,9 @@ def test_the_layer_loop_with_its_runs_scanned_is_the_unrolled_one(monkeypatch):
     monkeypatch.undo()
     cfg = dict(CFG, mixer_types=["minicpm4"] * 2 + ["lightning-attn"] * 9, num_hidden_layers=11)
     assert layer_periods(FAMILY.program_config(cfg).layer_kinds) == (6, 1)  # then a tail of five
-    tail_scanned, _ = latent_toy.engine(FAMILY, cfg, seed=9, lanes=4)
+    tail_scanned, _ = latent_toy.engine(FAMILY, cfg, seed=9, lanes=2)
     row_t = np.asarray(tail_scanned.prefill(0, PROMPT[:70])[0])
     monkeypatch.setattr(hybrid, "RUN_SCAN_MIN", 99)
-    tail_unrolled, _ = latent_toy.engine(FAMILY, cfg, seed=9, lanes=4)
+    tail_unrolled, _ = latent_toy.engine(FAMILY, cfg, seed=9, lanes=2)
     np.testing.assert_allclose(row_t, np.asarray(tail_unrolled.prefill(0, PROMPT[:70])[0]),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_verify_steps_and_lane_copies_are_refused(eng):
-    n = eng.n_lanes
-    z = np.zeros(n, np.int32)
-    with pytest.raises(ValueError, match="without speculation"):
-        eng.decode_spec(z, np.zeros((n, eng.SPEC_DRAFT), np.int32), z, z)
-    with pytest.raises(RuntimeError, match="recurrent state"):
-        eng.copy_lane(0, 1)
-    config = FAMILY.program_config(CFG)
-    params = FAMILY.assemble_params(config, FAMILY.device_weights(config, 3, jnp.float32))
-    with pytest.raises(ValueError, match="5 linear-attention"):
-        InferenceEngine(config, params, n_lanes=4, paged_kv=True)
-
-
-def test_a_lane_taken_out_and_put_back_carries_both_new_leaves(eng):
-    """The fused step's splice of the admitted lane: lane 2's rows of every
-    leaf (planes, compressed keys, matrix state) after a fused admission are
-    the rows a synchronous prefill writes, and no other lane's rows moved."""
-    eng.prefill(5, PROMPT[:50])
-    others = [np.asarray(leaf[:, 5]) for leaf in (eng.cache.k, eng.cache.ck, eng.cache.lin)]
-    eng.prefill(3, PROMPT[:60])
-    eng.decode_prefill_fused(np.full(8, SEQ, np.int32), p_lane=2, chunk=PROMPT[:60],
-                             tokens=np.zeros(8, np.int32))
-    eng.pipeline_flush()
-    assert FAMILY.lane_state_rel_err(eng, 3, 2, 60) < 1e-5
-    for leaf, was in zip((eng.cache.k, eng.cache.ck, eng.cache.lin), others):
-        np.testing.assert_array_equal(np.asarray(leaf[:, 5]), was)

@@ -3,58 +3,28 @@ the real writer, the loader and the scheduler on the CPU: admissions into
 lanes that served before ride fused steps and give the tokens they give
 alone; what is counted (tests/test_sala_engine.py has the engine's cases)."""
 
-import jax.numpy as jnp
 import pytest
 
-from distributed_llama_multiusers_tpu.formats import load_model_header
-from distributed_llama_multiusers_tpu.formats.synthetic import (
-    tiny_sala_header,
-    write_synthetic_model,
-    write_synthetic_tokenizer,
-)
-from distributed_llama_multiusers_tpu.models import load_params_from_m
-from distributed_llama_multiusers_tpu.runtime import ContinuousBatchingScheduler, Request
-from distributed_llama_multiusers_tpu.runtime.engine import InferenceEngine, warmup_engine
-from distributed_llama_multiusers_tpu.tokenizer import Tokenizer
+from distributed_llama_multiusers_tpu.formats.synthetic import tiny_sala_header
+
+import latent_toy
 
 
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """A synthetic ``S L L S`` checkpoint through the real writer and loader."""
-    d = tmp_path_factory.mktemp("sala")
-    header = tiny_sala_header("SLLS")
-    write_synthetic_model(str(d / "m.m"), header, seed=3, scale=0.1)
-    write_synthetic_tokenizer(str(d / "t.t"), vocab_size=header.vocab_size)
-    h = load_model_header(str(d / "m.m"))
-    config, params = load_params_from_m(str(d / "m.m"), h, dtype=jnp.float32)
-    return config, params, Tokenizer(str(d / "t.t"))
-
-
-def _serve(served, prompts, lanes=2, max_tokens=8, **kw):
-    config, params, tok = served
-    engine = InferenceEngine(config, params, n_lanes=lanes, prefill_buckets=(8, 16))
-    sched = ContinuousBatchingScheduler(engine, tok, **kw)
-    warmup_engine(engine, spec=sched.speculative, multi_step=sched.multi_step)
-    sched.start()
-    try:
-        reqs = [sched.submit(Request(prompt=p, max_tokens=max_tokens, temperature=0.0))
-                for p in prompts]
-        for r in reqs:
-            r.future.result(timeout=600)
-            assert r.error is None, r.error
-    finally:
-        sched.stop()
-    return [list(r.generated_tokens) for r in reqs], engine.stats.snapshot()
+    return latent_toy.serving(tiny_sala_header("SLLS"), tmp_path_factory.mktemp("sala"), scale=0.1)
 
 
 def test_a_loaded_checkpoint_serves_and_admissions_reuse_lanes(served):
     """Six requests on two lanes, the longest past dense_len (48): four are
     admitted into lanes that served before, by fused steps, and give the
-    tokens they give alone."""
+    tokens they give alone (the same warmed engine under a scheduler with the
+    pipelined loop and fused admissions off)."""
     shared = "the same long opening words of two requests, and more of them, "
     prompts = [shared + "then one end", shared + "then another", "ab ab ab ab ab ab",
                "hello world hello", "lo lo lo world", shared + "and a third"]
-    tokens, stats = _serve(served, prompts)
+    tokens, stats = served.serve(prompts)
     assert stats["state_zero_starts"] == 6 and stats["jit_compiles_after_warmup"] == 0
     assert stats["prefix_hits"] == 0 and stats["prefix_tokens_saved"] == 0
     assert stats["spec_steps"] == 0 and stats["pipeline_flushes"] == 0 and stats["fused_steps"] > 0
@@ -64,5 +34,6 @@ def test_a_loaded_checkpoint_serves_and_admissions_reuse_lanes(served):
     assert 0 < stats["attn_blocks_read"] < stats["attn_blocks_held"]
     assert stats["sparse_lane_steps"] > 0
     for i in (0, 2, 5):  # a first admission, a short one, one into a lane that served twice
-        alone, _ = _serve(served, [prompts[i]], pipelined=False, fused_prefill=False)
+        alone, off = served.serve([prompts[i]], pipelined=False, fused_prefill=False)
         assert alone[0] == tokens[i], (i, prompts[i])
+        assert off["jit_compiles_after_warmup"] == 0 and off["fused_steps"] == 0

@@ -51,15 +51,16 @@ EXPECTED = {
 # what closes a program after its halves: the carry and the packed readback,
 # the logits row a synchronous program hands back
 JOIN_SCOPES = {names.SCOPE_CARRY, names.SCOPE_HEAD}
+# (its row of the one table of toys, tests/latent_toy.py; the scopes its block adds)
 TOYS = {
-    "latent": ("tiny_latent.json", names.LATENT_BLOCK_SCOPES),
-    "hybrid": ("tiny_lfm2.json", names.CONV_MIXER_SCOPES + (names.SCOPE_ROUTER, names.SCOPE_EXPERTS)),
+    "latent": ("latent", names.LATENT_BLOCK_SCOPES),
+    "hybrid": ("lfm2", names.CONV_MIXER_SCOPES + (names.SCOPE_ROUTER, names.SCOPE_EXPERTS)),
     # an indexer beside the latent block's attention: indexer_step_ms and
     # sparse_select_step_ms read these two
-    "sparse": ("tiny_deepseek_v32.json", names.LATENT_BLOCK_SCOPES + names.SPARSE_ATTENTION_SCOPES),
+    "sparse": ("sparse", names.LATENT_BLOCK_SCOPES + names.SPARSE_ATTENTION_SCOPES),
     # linear-attention layers beside block-sparse ones: the six readers of the
     # minicpm_sala cell read these scopes
-    "sala": ("tiny_minicpm_sala.json", names.LINEAR_MIXER_SCOPES
+    "sala": ("sala", names.LINEAR_MIXER_SCOPES
              + (names.SCOPE_BLOCK_SCORES, names.SCOPE_SPARSE_SELECT, names.SCOPE_ATTENTION)),
 }
 
@@ -152,7 +153,7 @@ def toy_engines():
 
     def get(toy: str):
         if toy not in made:
-            cfg, family, _ = latent_toy.load(TOYS[toy][0])
+            cfg, family, _ = latent_toy.toy(TOYS[toy][0])
             made[toy] = latent_toy.engine(family, cfg, 5, lanes=2,
                                           prefill_buckets=(BUCKET,))[0]
         return made[toy]

@@ -46,7 +46,7 @@ MODELS = {
     "moe": dict(n_experts=4, n_active_experts=2),
 }
 PROGRAMS = {
-    # attribute of the engine -> how to make it run once
+    # attribute of the engine -> the entry point's call that reaches it
     "_decode_pl_fn": lambda e, z: (e.decode_pipelined(z, tokens=z), e.pipeline_flush()),
     "_decode_prefill_fn": lambda e, z: (
         e.decode_prefill_fused(np.full(len(z), e.config.seq_len, np.int32),
@@ -82,21 +82,29 @@ def engines(tmp_path_factory):
     return get
 
 
+class _Lowered(Exception):
+    """Ends an entry point's call where its program is lowered."""
+
+
 def lowered_with_debug_info(engine, attr: str) -> str:
     """The StableHLO text, locations included, of the program ``attr`` as the
-    engine's own entry point calls it."""
-    fn, seen = getattr(engine, attr), []
+    engine's own entry point calls it. The call ends there: the program is
+    neither compiled nor run (every entry point does its bookkeeping after
+    its program returns, so nothing of the engine has moved), which is most
+    of what a case that reads the text used to cost."""
+    fn = getattr(engine, attr)
 
     def spy(*args):
-        seen.append(fn.lower(*args).as_text(debug_info=True))
-        return fn(*args)
+        raise _Lowered(fn.lower(*args).as_text(debug_info=True))
 
     setattr(engine, attr, spy)
     try:
         PROGRAMS[attr](engine, np.zeros(engine.n_lanes, np.int32))
+    except _Lowered as lowered:
+        return lowered.args[0]
     finally:
         setattr(engine, attr, fn)
-    return seen[0]
+    raise AssertionError(f"{attr} was not called")
 
 
 @pytest.mark.parametrize("attr", sorted(PROGRAMS))
@@ -237,23 +245,35 @@ def test_loop_slices_partition_every_step(traced_run):
     assert fused_steps and {e.args["step"] for e in chunks} == fused_steps
 
 
+UNCOVERED_BOUND = 0.4
+
+
 def typical_uncovered_share(loop) -> tuple[float, int]:
-    """(the share of a typical iteration no span covers, the iterations) of
-    the loop in ``loop``, its slices by time.
+    """(the share of the loop's own time in a typical iteration that no span
+    covers, the iterations) of the loop in ``loop``, its slices by time.
 
     The rule. An iteration is the slices up to and including a
-    ``loop.stream``. Its covered time is the sum of its slices; its uncovered
-    time is the sum of the gaps between neighbouring slices, the gap behind
-    it included, so every gap is charged to exactly one iteration (the last
-    iteration, and one behind which the loop parked on an empty queue for
-    over 50 ms, have no gap behind them: a parked loop is not the pipelined
-    loop's time). The share is the MEDIAN uncovered time over the median
-    uncovered plus the median covered time: the typical iteration, not the
-    sum of the seconds. The loop's thread losing the CPU between two spans
-    lengthens one gap of one iteration, however long it was away, and moves
-    no median (summed over the run's 100 ms, one 5 ms absence was the whole
-    bound); loop code without a span runs in every iteration that takes its
-    branch, and moves the median as soon as most iterations take it."""
+    ``loop.stream``. Its uncovered time is the sum of the gaps between
+    neighbouring slices, the gap behind it included, so every gap is charged
+    to exactly one iteration (the last iteration, and one behind which the
+    loop parked on an empty queue for over 50 ms, have no gap behind them: a
+    parked loop is not the pipelined loop's time). Its own time is that and
+    its slices but ``loop.wait``: what this thread's Python took, and not the
+    device's step it waited out. The share is the MEDIAN uncovered time over
+    the median own time: the typical iteration, not the sum of the seconds.
+    The loop's thread losing the CPU between two spans lengthens one gap of
+    one iteration, however long it was away, and moves no median; loop code
+    without a span runs in every iteration that takes its branch, and moves
+    the median as soon as most iterations take it.
+
+    Why the wait is left out (PR 58). The uncovered time was held to 5 % of
+    the whole iteration beside the mock's 2 ms step: 0.1 ms, where the spans'
+    own time is 0.15 ms. Load stretches gaps and spans alike and the mock's
+    sleep not at all, so that share rose with the load (0.030-0.043 quiet,
+    0.031-0.060 beside a running suite: 3 runs of 9 over the bound) while the
+    share of the own time stood still (0.26-0.33 in 100 runs at load averages
+    of 3 to 22; the control 0.95-0.97). ``UNCOVERED_BOUND`` is the same 0.1 ms
+    on a quiet machine, in the unit a loaded one does not move."""
     iterations, cur = [], []
     for e in loop:
         cur.append(e)
@@ -262,15 +282,14 @@ def typical_uncovered_share(loop) -> tuple[float, int]:
             cur = []
     if cur:
         iterations.append(cur)
-    covered, uncovered = [], []
+    own, uncovered = [], []
     for it, nxt in zip(iterations, iterations[1:] + [None]):
         end = it[-1].ts + it[-1].dur
         if nxt is not None and nxt[0].ts - end <= 0.05:
             end = nxt[0].ts
-        covered.append(sum(e.dur for e in it))
-        uncovered.append(end - it[0].ts - covered[-1])
-    c, u = np.median(covered), np.median(uncovered)
-    return float(u / (u + c)), len(iterations)
+        uncovered.append(end - it[0].ts - sum(e.dur for e in it))
+        own.append(uncovered[-1] + sum(e.dur for e in it if e.name != names.LOOP_WAIT))
+    return float(np.median(uncovered) / np.median(own)), len(iterations)
 
 
 def test_loop_slices_do_not_overlap_and_cover_the_loop(traced_run):
@@ -279,13 +298,14 @@ def test_loop_slices_do_not_overlap_and_cover_the_loop(traced_run):
     for a, b in zip(loop, loop[1:]):
         assert a.ts + a.dur <= b.ts + 1e-9, (a, b)
     share, n = typical_uncovered_share(loop)
-    assert n > 20 and share <= 0.05, (share, n)
+    assert n > 20 and share <= UNCOVERED_BOUND, (share, n)
 
 
 def test_a_loop_branch_without_a_span_fails_the_coverage(traced_run):
-    """The control: the same run with two of the loop's branches left without
-    their span (dispatch and stream: 6 % of an iteration beside the mock
-    engine's 2 ms step) is past the bound in its typical iteration."""
+    """The control: the same run, read by the same rule at the same bound,
+    with two of the loop's branches left without their span (dispatch and
+    stream: all but a twentieth of the loop's own time) is past the bound in
+    its typical iteration."""
     _reqs, _sched, tel, _log = traced_run
     loop = sorted(loop_slices(tel), key=lambda e: e.ts)
     bare = {names.LOOP_DISPATCH, names.LOOP_STREAM}
@@ -298,7 +318,7 @@ def test_a_loop_branch_without_a_span_fails_the_coverage(traced_run):
             # duration stands for it
             kept.append(dataclasses.replace(e, ts=e.ts + e.dur, dur=0.0))
     share, n = typical_uncovered_share(kept)
-    assert n > 20 and share > 0.05, (share, n)
+    assert n > 20 and share > UNCOVERED_BOUND, (share, n)
 
 
 def test_every_span_slice_has_its_annotation(traced_run):
