@@ -31,11 +31,17 @@ a tenth of the speed (42 of a 70 ms decode step on a v5e, PERF.md section 6,
 PR 33), so the rope leaf is padded to whole tiles: 1280 bytes a token a layer
 in bf16 at rank 512, not 1152. Both ride the layer scan's
 carry and are appended in place, then read, exactly as K and V are
-(``models/llama.py``, "How the cache moves"). Attention is XLA's dense path
-over the layer's latent plane sliced out of the carry; the in-place decode
-kernel (ops/pallas_attention.py) takes K and V stacks of heads (128-wide on
-an axis of their own, or narrower ones merged into rows of whole tiles), not
-a latent row and its rope part, and does not engage.
+(``models/llama.py``, "How the cache moves"). How they are read: at one row
+a lane the in-place decode kernel (ops/pallas_attention.py, "Latent rows") is
+handed the two stacks as the carry holds them and fetches each lane's rows
+``[0, pos]`` of the layer in whole blocks, the latent block as keys and as
+values at once, where ``llama.decode_attention_engages`` says so (bf16 leaves
+of whole 128-lane tiles, a context of whole blocks, one device, Pallas on; the
+question the other two blocks' forwards and the engine's counters ask).
+Elsewhere (a prefill chunk or a verify step's rows, a rank that is no whole
+tile, an f8 or f32 cache, the CPU) attention is XLA's dense path over the
+layer's latent plane sliced out of the carry (``latent_plane_attention``).
+With an indexer neither: ``sparse_attention``, below.
 
 The FFN. The first ``n_dense_layers`` layers run a dense gated FFN, before
 the scan; the others are the scan: a router in float32 (sigmoid or softmax
@@ -109,6 +115,7 @@ from ..ops.linear import (
     reads_q40_stack,
 )
 from ..ops.norm import layer_norm, rms_norm
+from ..ops.pallas_attention import block_rows, decode_attention, lane_blocks
 from ..ops.pallas_q40_grouped import (
     HEIGHTS,
     grouped_matmul_xla,
@@ -135,7 +142,7 @@ from ..telemetry.names import (
     SCOPE_SPARSE_SELECT,
 )
 from .config import LlamaConfig
-from .llama import KVCache, _qdq_q80, _to_cache_dtype, kv_append
+from .llama import KVCache, _qdq_q80, _to_cache_dtype, decode_attention_engages, kv_append
 
 
 class LatentAttnParams(NamedTuple):
@@ -315,19 +322,31 @@ def _product_dtype(cached):
     return cached.dtype if cached.dtype in (jnp.bfloat16, jnp.float32) else jnp.float32
 
 
-def absorbed_attention(q_nope, q_pe, wuk, wuv, c_plane, r_plane, mask, scale):
-    """All heads against the one latent row a token. q_nope ``[B,T,H,nope]``,
-    q_pe ``[B,T,H,rope]`` (rotated), wuk ``[H, nope, rank]``, wuv
-    ``[H, rank, v]``, c_plane
-    ``[B,S,rank]``, r_plane ``[B,S,rope]`` (rotated; both rope parts may be
-    padded with zeros alike), mask ``[B,T,S]``.
-    Returns ``[B,T,H,v]`` f32. The planes are multiplied in the dtype they are
-    cached in (bf16, f32), accumulated in f32."""
+def absorb_queries(q_nope, wuk):
+    """``q~_i = Wuk_i^T q_nope_i``: q_nope ``[B,T,H,nope]``, wuk ``[H, nope,
+    rank]`` -> ``[B,T,H,rank]`` in wuk's dtype. (The two products by head take
+    no float32 result type: a bf16 pair is rounded once either way, to the
+    cached rows' dtype here and to the stream's after ``expand_values``, and
+    XLA:CPU has no bf16 x bf16 -> f32 batched dot.)"""
+    return jnp.einsum("bthn,hnc->bthc", q_nope.astype(wuk.dtype), wuk)
+
+
+def expand_values(o_lat, wuv):
+    """``o_i = Wuv_i o~_i``: o_lat ``[B,T,H,rank]``, wuv ``[H, rank, v]`` ->
+    ``[B,T,H,v]`` f32."""
+    return jnp.einsum("bthc,hcv->bthv", o_lat.astype(wuv.dtype), wuv).astype(jnp.float32)
+
+
+def latent_plane_attention(q_abs, q_pe, c_plane, r_plane, mask, scale):
+    """The absorbed queries against one layer's whole planes: q_abs
+    ``[B,T,H,rank]``, q_pe ``[B,T,H,rope]`` (rotated), c_plane ``[B,S,rank]``,
+    r_plane ``[B,S,rope]`` (rotated; both rope parts may be padded with zeros
+    alike), mask ``[B,T,S]``. Returns ``o~`` ``[B,T,H,rank]`` f32: the
+    probabilities over the latent rows themselves. The planes are multiplied
+    in the dtype they are cached in (bf16, f32), accumulated in f32, the scale
+    on the f32 scores: the operands and rounding points the in-place kernel
+    keeps (``ops/pallas_attention.py``, "Latent rows")."""
     plane_dtype = _product_dtype(c_plane)
-    # (the two products by head take no float32 result type: a bf16 pair is
-    # rounded once either way, to the plane's dtype here and to the stream's
-    # after the second, and XLA:CPU has no bf16 x bf16 -> f32 batched dot)
-    q_abs = jnp.einsum("bthn,hnc->bthc", q_nope.astype(wuk.dtype), wuk)
     scores = jnp.einsum(
         "bthc,bsc->bths", q_abs.astype(plane_dtype), c_plane.astype(plane_dtype),
         preferred_element_type=jnp.float32,
@@ -337,11 +356,10 @@ def absorbed_attention(q_nope, q_pe, wuk, wuv, c_plane, r_plane, mask, scale):
     )
     scores = jnp.where(mask[:, :, None, :], scores * scale, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum(
+    return jnp.einsum(
         "bths,bsc->bthc", probs.astype(plane_dtype), c_plane.astype(plane_dtype),
         preferred_element_type=jnp.float32,
     )
-    return jnp.einsum("bthc,hcv->bthv", o_lat.astype(wuv.dtype), wuv).astype(jnp.float32)
 
 # -- learned sparse attention -------------------------------------------------
 
@@ -637,7 +655,13 @@ def deepseek_forward_counted(
     dtype = x.dtype
     lane_idx = jnp.arange(b)[:, None]
     live = (positions < cfg.seq_len).reshape(b * t)
-    if not sparse:
+    # one row a lane over the two latent leaves alone: read in place (module
+    # header, "The cache"); the one question models/llama.py's forward asks
+    in_place = t == 1 and decode_attention_engages(cache, mesh, n_heads, latent=True)
+    if in_place:
+        with jax.named_scope(SCOPE_ATTENTION):
+            attn_plan = lane_blocks(positions, cfg.seq_len, block_rows(latent=True))
+    elif not sparse:
         with jax.named_scope(SCOPE_ATTENTION):
             s_idx = jnp.arange(cfg.seq_len)
             attn_mask = s_idx[None, None, :] <= positions[:, :, None]  # [B, T, S]
@@ -689,12 +713,22 @@ def deepseek_forward_counted(
             c_all, r_all = kv_append(c_all, r_all, at, c, k_pe, row_major)
         if not sparse:
             with jax.named_scope(SCOPE_ATTENTION):
-                # the layer's latent plane, read out of the carry AFTER the append
-                c_plane = jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
-                r_plane = jax.lax.dynamic_index_in_dim(r_all, l, 0, keepdims=False)
-                o = absorbed_attention(
-                    q[..., :nope], q_pe, ap.wuk, ap.wuv, c_plane, r_plane, attn_mask, scale,
-                )
+                q_abs = absorb_queries(q[..., :nope], ap.wuk)
+                if in_place:
+                    # the kernel fetches each lane's rows [0, pos] of layer l
+                    # out of the carry, AFTER the append: no plane is sliced
+                    # out, and a latent block is keys and values at once
+                    o_lat = decode_attention(
+                        jnp.concatenate([q_abs, q_pe], axis=-1)[:, 0],
+                        c_all, r_all, l, attn_plan, scale, interpret=pallas_interpret(),
+                        latent=True)[:, None]
+                else:
+                    # the layer's latent plane, read out of the carry AFTER the append
+                    c_plane = jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
+                    r_plane = jax.lax.dynamic_index_in_dim(r_all, l, 0, keepdims=False)
+                    o_lat = latent_plane_attention(
+                        q_abs, q_pe, c_plane, r_plane, attn_mask, scale)
+                o = expand_values(o_lat, ap.wuv)
             leaves, seen = (c_all, r_all), ()
         else:
             qi, ki, w = fresh[4:]

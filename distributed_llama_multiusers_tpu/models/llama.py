@@ -513,23 +513,28 @@ def dense_plane_attention(q, k_all, v_all, l, attn_mask, scale, n_kv: int, sink=
     return _dense_attention(qf, *planes, attn_mask, scale, sink)
 
 
-def decode_attention_engages(cache, mesh, n_heads: int, n_kv: int | None = None) -> bool:
+def decode_attention_engages(cache, mesh, n_heads: int, n_kv: int | None = None,
+                             latent: bool = False) -> bool:
     """Whether a step of one row a lane (``t == 1``) attends this cache in
-    place through ``ops/pallas_attention.py``: contiguous K and V stacks of
-    one shape that the kernel tiles (a ``KVCache``'s, or a
-    ``models/hybrid.py`` ``HybridCache``'s merged ones; not the paged pool,
-    not a latent cache's two unlike leaves), on one device, where Pallas
-    compiles (a TPU, or interpret mode). ``n_kv``: the kv heads of a merged
-    row, which its shape does not say. What the inputs are decides it, as
-    ``reads_q40_stack`` does for the weights; both blocks' forwards and the
-    engine's counters ask this one question."""
+    place through ``ops/pallas_attention.py``: contiguous stacks that the
+    kernel tiles (a ``KVCache``'s, or a ``models/hybrid.py`` ``HybridCache``'s
+    merged ones; not the paged pool), on one device, where Pallas compiles (a
+    TPU, or interpret mode). A leaf's shape does not say what its row holds,
+    so the caller does: ``n_kv``, the kv heads of a merged row; ``latent``
+    (``models/deepseek.py``), the two leaves one latent row and its rope part
+    a position, read in place where the cache is those two alone (with an
+    indexer's keys beside them, attention reads the rows the indexer chooses).
+    What the inputs are decides it, as ``reads_q40_stack`` does for the
+    weights; the three blocks' forwards and the engine's counters ask this
+    one question."""
     return (
         not isinstance(cache, PagedKVCache)
+        and (not latent or isinstance(cache, KVCache))
         and mesh is None
         and pallas_kernel_active()
-        # (a latent cache's two unlike leaves, or a merged value stack of
-        # another width than the keys': the kernel says which it takes)
-        and pallas_attention.supports(cache.k, n_heads, n_kv, cache.v)
+        # (which stacks, and a value stack of another width than the keys',
+        # in which form: the kernel says what it takes)
+        and pallas_attention.supports(cache.k, n_heads, n_kv, cache.v, latent)
     )
 
 

@@ -10,8 +10,8 @@ masks afterwards. This kernel is handed the stack itself, the layer index and
 the lanes' positions as scalar-prefetch operands, and fetches for lane ``b``
 only the row blocks ``[0, ceil((pos_b + 1) / BLOCK_ROWS))`` of layer ``l``.
 
-How the stack goes in. Two forms of stack, told apart by rank, and neither
-is moved:
+How the stack goes in. Two forms of K / V stack, told apart by rank, and
+neither is moved (a third, a latent cache, is "Latent rows" below):
 
 - ``[L, lanes, S, n_kv, hd]`` with ``hd == 128`` (models/llama.py), ``(n_kv,
   hd)`` tiled. Merging ``(S, n_kv)`` into one axis of ``S * n_kv`` rows of
@@ -81,6 +81,29 @@ may hold heads narrower than the keys' (192-wide keys beside 128-wide values):
 the K and V blocks, the output and the accumulator take their own widths, and
 a key head that straddles 128-lane tiles costs nothing here, since the
 block-diagonal product runs over the whole row.
+
+Latent rows. A latent cache (models/deepseek.py) keeps ONE row a position
+that every query head meets whole, in two leaves: the normed latent ``c'``
+(``[L, lanes, S, rank]``) and its rotated rope part (``[L, lanes, S, rope
+leaf]``, zero past the rope width), each whole 128-lane tiles. In the absorbed
+form the key row is ``[c' ; k_pe]`` and the value row is ``c'`` itself, so a
+block is
+
+    s   = (q_abs . c_blk^T + q_pe . r_blk^T) * scale      [heads, rows]
+    acc = online-softmax(s) . c_blk                       [heads, rank]
+
+the plain product: no head bias, no block-diagonal queries, no select
+afterwards. The queries go in side by side (``[q_abs ; q_pe]``, ``rank + rope
+leaf`` wide), the two leaves as the K and the V operand, and the latent block,
+fetched once, is keys and values at once: 1280 bytes a position a layer at
+rank 512, not twice the latent. It is ``decode_attention`` itself under a
+static ``latent`` and not a sibling: the work list, the parked-lane rule, the
+mask by position, the zeroed stale rows and the online softmax are the
+kernel's body, and the form changes three lines of it (two score products and
+which block the probabilities meet). A rank-4 leaf does not say whether its
+row is merged heads or a latent, so the caller says (``supports``). What the
+caller keeps: ``q_abs = q_nope . wuk`` before and ``o = o~ . wuv`` after are
+XLA's, a head at a time over weights, not over the cache.
 
 Chosen blocks. A block-sparse layer (models/hybrid.py, ops/block_sparse.py)
 reads, for every (lane, kv head), the blocks of ``block_size`` positions (64
@@ -155,6 +178,12 @@ from jax.experimental.pallas import tpu as pltpu
 # cache rows (positions) a block; chosen on a v5e from the two 7B head shapes
 # (n_kv 8 / group 4 at 16 lanes, n_kv 4 / group 7 at 32): PERF.md section 6
 BLOCK_ROWS = 256
+# ... of a latent cache, whose position is 1280 bytes at rank 512 where K and V
+# rows of heads are 2 to 4 KB: an item of the work list costs 0.55 us and its
+# rows the HBM's rate, so the taller block wins under the chat mix's positions
+# (1.03 against 1.22 ms for 24 layers of 32 lanes on a v5e, and 1.55 at 1024
+# rows: scripts/latent_attention_lab.py, PERF.md section 6, PR 59)
+LATENT_BLOCK_ROWS = 512
 HEAD_SIZE = 128  # one lane tile: the scratch statistics are [heads, 128]
 # the widest merged row taken: 8 kv heads of 192 a position, a K block of 768
 # KB (compiled for a v5e and run there, PR 54; 1024 before: the 128-wide
@@ -164,29 +193,52 @@ MAX_ROW_WIDTH = 1536
 FULL, LAST, FIRST, FINAL = 1, 2, 4, 8
 
 
-def supports(k_all, n_heads: int, n_kv: int | None = None, v_all=None) -> bool:
+def block_rows(latent: bool = False) -> int:
+    """Cache rows a block of ``decode_attention`` fetches, by the form of the
+    cache: what a work list is built by (``lane_blocks``) and the scheduler's
+    counter counts by (``rows_read``)."""
+    return LATENT_BLOCK_ROWS if latent else BLOCK_ROWS
+
+
+def supports(k_all, n_heads: int, n_kv: int | None = None, v_all=None,
+             latent: bool = False) -> bool:
     """Whether the kernel takes this cache: a bf16 stack whose context is
-    whole blocks, query heads a multiple of kv heads, and either form of the
-    module header: ``[L, lanes, S, n_kv, HEAD_SIZE]``, or ``[A, lanes, S,
-    n_kv * hd]`` whose rows are whole 128-lane tiles of the caller's ``n_kv``
-    heads (a merged row does not say how many heads it holds). ``v_all``: the
-    value stack where it may differ from the keys' (None: of ``k_all``'s
-    shape): merged rows of another width (a value head narrower than a key
-    head) are taken, under the same rule; anything else unlike the keys is
-    not."""
+    whole blocks, in one of the module header's three forms. A rank-4 leaf
+    does not say what its row holds, so the caller says: ``n_kv``, the heads
+    of a merged row, or ``latent``, one latent row and its rope part a
+    position that every query head meets whole.
+
+    - ``[L, lanes, S, n_kv, HEAD_SIZE]``: query heads a multiple of kv heads.
+    - ``[A, lanes, S, n_kv * hd]``: rows of whole 128-lane tiles that the
+      caller's ``n_kv`` heads divide, at most ``MAX_ROW_WIDTH`` wide.
+      ``v_all``: the value stack where it may differ from the keys' (None:
+      of ``k_all``'s shape): merged rows of another width (a value head
+      narrower than a key head) are taken, under the same rule; anything
+      else unlike the keys is not.
+    - ``latent``: ``k_all`` ``[L, lanes, S, rank]`` and ``v_all`` ``[L, lanes,
+      S, rope leaf]``, each whole 128-lane tiles, a block of the two together
+      (``LATENT_BLOCK_ROWS`` positions) within the widest merged K block's
+      bytes; no heads to divide it."""
     if k_all.dtype != jnp.bfloat16 or k_all.ndim not in (4, 5):
         return False
     if v_all is not None and v_all.shape != k_all.shape and (
             k_all.ndim != 4 or v_all.dtype != k_all.dtype or v_all.shape[:3] != k_all.shape[:3]):
         return False
-    if k_all.ndim == 5:
+    widths = {k_all.shape[-1], k_all.shape[-1] if v_all is None else v_all.shape[-1]}
+    if latent:
+        n_kv = 1  # every query head reads the one row
+        tiled = (k_all.ndim == 4 and v_all is not None
+                 and all(width % HEAD_SIZE == 0 for width in widths)
+                 and (k_all.shape[3] + v_all.shape[3]) * LATENT_BLOCK_ROWS
+                 <= MAX_ROW_WIDTH * BLOCK_ROWS)
+    elif k_all.ndim == 5:
         n_kv = k_all.shape[3]
         tiled = k_all.shape[4] == HEAD_SIZE
     else:
         tiled = bool(n_kv) and all(
             width % HEAD_SIZE == 0 and width % n_kv == 0 and width <= MAX_ROW_WIDTH
-            for width in {k_all.shape[3], k_all.shape[3] if v_all is None else v_all.shape[3]})
-    return tiled and k_all.shape[2] % BLOCK_ROWS == 0 and n_heads % n_kv == 0
+            for width in widths)
+    return tiled and k_all.shape[2] % block_rows(latent) == 0 and n_heads % n_kv == 0
 
 
 def rows_read(positions, seq_len: int, block: int = BLOCK_ROWS) -> int:
@@ -198,9 +250,10 @@ def rows_read(positions, seq_len: int, block: int = BLOCK_ROWS) -> int:
     return int((block * (live // block + 1)).sum())
 
 
-def lane_blocks(positions: jnp.ndarray, seq_len: int):
+def lane_blocks(positions: jnp.ndarray, seq_len: int, block: int = BLOCK_ROWS):
     """The work list of a step, layer invariant and built once outside the
-    layer scan: ``(n_items, plan)``. Item ``w < n_items`` is one block of one
+    layer scan: ``(n_items, plan)``. Item ``w < n_items`` is one block (of
+    ``block`` positions: ``block_rows`` of the cache's form) of one
     lane, the lanes in order and each lane's blocks in order; a parked lane is
     one item that computes nothing. ``plan`` is ``int32 [5, lanes * blocks]``:
     the item's lane, the lane and block it fetches, the lane's position, and
@@ -210,11 +263,11 @@ def lane_blocks(positions: jnp.ndarray, seq_len: int):
     pos = positions.reshape(-1).astype(jnp.int32)
     n_lanes = pos.shape[0]
     lanes = jnp.arange(n_lanes, dtype=jnp.int32)
-    n = jnp.where((pos >= 0) & (pos < seq_len), pos // BLOCK_ROWS + 1, 0)
+    n = jnp.where((pos >= 0) & (pos < seq_len), pos // block + 1, 0)
     live = n > 0
     items = jnp.maximum(n, 1)
     end = jnp.cumsum(items)
-    w = jnp.arange(n_lanes * (seq_len // BLOCK_ROWS), dtype=jnp.int32)
+    w = jnp.arange(n_lanes * (seq_len // block), dtype=jnp.int32)
     lane = jnp.minimum(jnp.searchsorted(end, w, side="right", method="compare_all"), n_lanes - 1)
     lane = lane.astype(jnp.int32)
     j = w - (end - items)[lane]
@@ -304,7 +357,7 @@ def _own_columns(n_heads: int, heads_pad: int, n_kv: int) -> np.ndarray:
 
 
 def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_pos,
-                             biased, sunk):
+                             height, biased, sunk, latent):
     del layer_ref  # spent in the index maps
     # the head bias goes in with 128-wide heads only (module header); the
     # sink with a layer that has one ("A sink")
@@ -336,10 +389,16 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
 
     def block(last: bool):
         k, v = k_ref[...], v_ref[...]  # [rows, key width], [rows, value width]
-        s = jax.lax.dot_general(
-            q_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [heads, rows]
+        scores = partial(jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+        if latent:
+            # the one key row a position is [c' ; k_pe], in two blocks: the
+            # queries' two parts against them, and the value block IS the first
+            rank = k.shape[1]
+            s = (scores(q_ref[:, :rank], k) + scores(q_ref[:, rank:], v)) * scale
+            v = k
+        else:
+            s = scores(q_ref[...], k) * scale  # [heads, rows]
         if bias_ref is not None:
             s = s + bias_ref[...]
         if last and ring:
@@ -355,7 +414,7 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
         elif last:
             # rows above the lane's position: out of the scores, and out of
             # the values (0 x NaN is NaN: a stale row must not reach the sum)
-            limit = (pos - block_index * BLOCK_ROWS + 1) * rows_per_pos
+            limit = (pos - block_index * height + 1) * rows_per_pos
             col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col < limit, s, -jnp.inf)
             row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
@@ -384,7 +443,7 @@ def _decode_attention_kernel(layer_ref, plan_ref, q_ref, *refs, scale, rows_per_
 
 
 def decode_attention(q, k_all, v_all, layer, work, scale: float,
-                     interpret: bool = False, sink=None) -> jnp.ndarray:
+                     interpret: bool = False, sink=None, latent: bool = False) -> jnp.ndarray:
     """One query row a lane against layer ``layer`` of the stacked cache.
 
     q ``[lanes, n_heads, hd]`` (head ``h * group + g`` reads kv head ``h``);
@@ -396,20 +455,31 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
     ``[n_heads]`` float32 (None: none): a logit a head that joins the softmax
     as one more column and gives no value. Returns ``[lanes, n_heads, vd]``
     float32; a lane's result depends on that lane's rows ``[0, pos]`` alone
-    (a ring: on the rows that hold ``(pos - window, pos]``)."""
+    (a ring: on the rows that hold ``(pos - window, pos]``).
+
+    ``latent`` (module header, "Latent rows"): ``k_all`` ``[L, lanes, S,
+    rank]`` the latent rows and ``v_all`` ``[L, lanes, S, rope leaf]`` their
+    rope parts, q ``[lanes, n_heads, rank + rope leaf]`` the absorbed queries
+    beside the rotated ones, ``work`` from ``lane_blocks`` at
+    ``block_rows(latent=True)``; returns ``[lanes, n_heads, rank]``: the
+    probabilities over the latent rows themselves."""
     n_heads, hd = q.shape[1:]
     n_layers, lanes, seq_len = k_all.shape[:3]
-    merged = k_all.ndim == 4  # one row a position, every kv head in it
+    heads_axis = k_all.ndim == 5  # 128-wide heads, n_kv rows a position
+    merged = not heads_axis and not latent  # one row a position, every kv head in it
     width, v_width = k_all.shape[-1], v_all.shape[-1]
-    n_kv = width // hd if merged else k_all.shape[3]
-    vd = v_width // n_kv if merged else hd
-    rows_per_pos = 1 if merged else n_kv
+    rows_per_pos = k_all.shape[3] if heads_axis else 1
+    # the queries' columns and the result's: a latent row is keys over both
+    # leaves' widths and values over the first's
+    q_width, o_width = (width + v_width, width) if latent else (width, v_width)
     n_items, plan = work
     heads_pad = -(-n_heads // 16) * 16  # whole bf16 sublane tiles
-    rows = BLOCK_ROWS * rows_per_pos
+    height = block_rows(latent)
+    rows = height * rows_per_pos
     q = jnp.pad(q.astype(k_all.dtype), ((0, 0), (0, heads_pad - n_heads), (0, 0)))
     if merged:
         # block-diagonal queries: a head's values in its kv head's columns
+        n_kv = width // hd
         own = _own_columns(n_heads, heads_pad, n_kv)
         q = jnp.where(own, q[:, :, None, :], 0).reshape(lanes, heads_pad, width)
 
@@ -423,8 +493,8 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
             (None, heads_pad, w_), lambda w, layer_ref, plan_ref: (plan_ref[0, w], 0, 0))
 
     extra, extra_spec = [], []
-    if not merged:
-        extra.append(_head_bias(n_heads, heads_pad, n_kv, rows))
+    if heads_axis:
+        extra.append(_head_bias(n_heads, heads_pad, rows_per_pos, rows))
         extra_spec.append(pl.BlockSpec((heads_pad, rows), lambda w, *_: (0, 0)))
     if sink is not None:
         # a lane tile wide, as the running maximum it starts is kept
@@ -434,16 +504,16 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
         extra_spec.append(pl.BlockSpec((heads_pad, HEAD_SIZE), lambda w, *_: (0, 0)))
     out = pl.pallas_call(
         partial(_decode_attention_kernel, scale=scale, rows_per_pos=rows_per_pos,
-                biased=not merged, sunk=sink is not None),
+                height=height, biased=heads_axis, sunk=sink is not None, latent=latent),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # the layer index and the work list
             grid=(n_items,),
-            in_specs=[lane_spec(width), *extra_spec, stack_spec(width), stack_spec(v_width)],
-            out_specs=lane_spec(v_width),
+            in_specs=[lane_spec(q_width), *extra_spec, stack_spec(width), stack_spec(v_width)],
+            out_specs=lane_spec(o_width),
             scratch_shapes=[pltpu.VMEM((heads_pad, HEAD_SIZE), jnp.float32)] * 2
-            + [pltpu.VMEM((heads_pad, v_width), jnp.float32)],
+            + [pltpu.VMEM((heads_pad, o_width), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, v_width), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads_pad, o_width), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
@@ -456,7 +526,7 @@ def decode_attention(q, k_all, v_all, layer, work, scale: float,
     if merged:
         # a head keeps its kv head's columns of the value product: a select
         # and a sum with exact zeros, no product
-        out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, vd), 0.0).sum(axis=2)
+        out = jnp.where(own, out.reshape(lanes, heads_pad, n_kv, -1), 0.0).sum(axis=2)
     return out[:, :n_heads]
 
 

@@ -820,10 +820,10 @@ class InferenceEngine:
         # where decode steps read whole planes (the scheduler's
         # attn_kv_rows_* counters ask)
         self.decode_attention_block = (
-            pallas_attention.BLOCK_ROWS
-            # (latent rows are no K/V heads: that block's forward never asks)
-            if not config.latent_attention and decode_attention_engages(
-                self.cache, mesh, config.n_heads, config.n_kv_heads)
+            pallas_attention.block_rows(config.latent_attention)
+            if decode_attention_engages(
+                self.cache, mesh, config.n_heads, config.n_kv_heads,
+                latent=config.latent_attention)
             else None
         )
         # the same for a window layer's ring (None: no window layers, or
@@ -1746,14 +1746,14 @@ class InferenceEngine:
         from ..ops.pallas_q40_grouped import grouped_supports
 
         cfg = self.config
-        if self.decode_attention_block is not None:
-            attention = "pallas_in_place"
-        elif cfg.sparse_attention:
-            attention = "sparse_topk"
+        # how a step reads the cache where no kernel reads it in place
+        if cfg.sparse_attention:
+            planes = "sparse_topk"
         elif cfg.latent_attention:
-            attention = "xla_dense_latent_absorbed"
+            planes = "xla_dense_latent_absorbed"
         else:
-            attention = "xla_dense"
+            planes = "xla_dense"
+        attention = "pallas_in_place" if self.decode_attention_block is not None else planes
         if cfg.n_experts == 0:
             experts = None
         elif not cfg.n_routed_layers:
@@ -1766,10 +1766,11 @@ class InferenceEngine:
         # plain full-context plane: the key blocks the chunk can see, in
         # place on the chip; the same walk as an XLA loop (a layer-pattern
         # block's long planes); or dense scores over the whole plane. A latent
-        # cache has no such plane: its own path's name
+        # cache has no such plane, and its chunks keep the absorbed form over
+        # the latent plane (or the indexer's rows): that path's name
         chunk = self.prefill_buckets[-1]
         if cfg.latent_attention:
-            prefill = attention
+            prefill = planes
         elif prefill_attention_engages(
                 self.cache, self.mesh, 1, chunk, cfg.n_heads, cfg.n_kv_heads):
             prefill = "in_place_kernel"

@@ -52,6 +52,21 @@ def wide_lfm2():
     return cfg, family, correct
 
 
+def wide_latent():
+    """(cfg, family, correct) of the latent toy at a shape the in-place decode
+    attention takes (ops/pallas_attention.py ``supports(latent=True)``): a
+    latent rank of one whole 128-lane tile beside the rope leaf's one, and a
+    context of two of its 512-row blocks, with sample prompts on both sides
+    of the block's edge. Served in bfloat16 (``engine(..., dtype=jnp.bfloat16)``)."""
+    import copy
+
+    cfg, family, correct = load()
+    cfg = copy.deepcopy(cfg)
+    cfg.update(kv_lora_rank=128, max_position_embeddings=1024)
+    cfg["correctness"]["prompt_tokens"] = [20, 500, 600]
+    return cfg, family, correct
+
+
 def engine(family, cfg, seed=11, dtype=None, lanes=8, **kw):
     """(engine, tensors) as the benchmark builds them, at ``dtype``
     (activations and cache; float32 by default)."""

@@ -105,28 +105,34 @@ def test_a_multi_step_dispatch_counts_each_of_its_steps():
 
 
 @pytest.mark.parametrize("pallas", [True, False], ids=["in_place", "pallas_off"])
-def test_a_layer_pattern_engine_counts_what_its_decode_attention_fetches(pallas):
-    """A real engine on the lfm2_moe toy at a shape the kernel takes (bf16
-    rows of whole tiles, two blocks of context), served synchronously so that
-    every step's positions are the host's own: with Pallas on (interpret mode)
-    the engine says `pallas_in_place` and the counter is the hand count over
-    those positions, under the whole planes; with Pallas off it says
-    `xla_dense` and counts whole planes."""
+@pytest.mark.parametrize("toy,block,planes,chunks", [
+    ("wide_lfm2", BLOCK, "xla_dense", ("in_place_kernel", "dense")),
+    # latent rows go by taller blocks, and a latent cache's chunks keep the
+    # absorbed form over the plane (PR 59)
+    ("wide_latent", 2 * BLOCK, "xla_dense_latent_absorbed", ("xla_dense_latent_absorbed",) * 2),
+], ids=["layer_pattern", "latent"])
+def test_an_engine_counts_what_its_decode_attention_fetches(toy, block, planes, chunks, pallas):
+    """A real engine on the lfm2_moe toy and on the latent toy, each at a
+    shape the kernel takes (bf16 rows of whole tiles, two blocks of context),
+    served synchronously so that every step's positions are the host's own:
+    with Pallas on (interpret mode) the engine says `pallas_in_place` and the
+    counter is the hand count over those positions, under the whole planes;
+    with Pallas off it names its plane read and counts whole planes."""
     import jax.numpy as jnp
 
     import latent_toy
     from distributed_llama_multiusers_tpu.ops import linear
 
-    cfg, family, _ = latent_toy.wide_lfm2()
+    cfg, family, _ = getattr(latent_toy, toy)()
     seq, lanes = cfg["max_position_embeddings"], 4
     linear.set_pallas_interpret(pallas)
     try:
         engine, _ = latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16, lanes=lanes,
                                       pipeline_depth=0, prefill_buckets=(64,))
         facts = engine.path_facts()
-        assert facts["attention_path"] == ("pallas_in_place" if pallas else "xla_dense")
-        assert facts["prefill_attention_path"] == ("in_place_kernel" if pallas else "dense")
-        assert engine.decode_attention_block == (BLOCK if pallas else None)
+        assert facts["attention_path"] == ("pallas_in_place" if pallas else planes)
+        assert facts["prefill_attention_path"] == chunks[0 if pallas else 1]
+        assert engine.decode_attention_block == (block if pallas else None)
         seen, real = [], engine.decode
         engine.decode = lambda tokens, positions, *a, **kw: (
             seen.append(np.asarray(positions).copy()) or real(tokens, positions, *a, **kw))
@@ -136,7 +142,7 @@ def test_a_layer_pattern_engine_counts_what_its_decode_attention_fetches(pallas)
         # one lane crosses the block's edge while it generates, one stays low,
         # two stay parked
         reqs = [Request(prompt="a" * n, max_tokens=m, temperature=0.0)
-                for n, m in ((250, 10), (30, 6))]
+                for n, m in ((block - 6, 10), (30, 6))]
         sched.start()
         try:
             for r in reqs:
@@ -149,13 +155,55 @@ def test_a_layer_pattern_engine_counts_what_its_decode_attention_fetches(pallas)
     finally:
         linear.set_pallas_interpret(False)
     stats = engine.stats.snapshot()
-    assert len(seen) >= 9 and any(BLOCK <= p < seq for at in seen for p in at)
+    assert len(seen) >= 9 and any(block <= p < seq for at in seen for p in at)
     assert stats["attn_kv_rows_whole"] == len(seen) * lanes * seq
-    hand = sum(BLOCK * (p // BLOCK + 1) for at in seen for p in at if 0 <= p < seq)
+    hand = sum(block * (p // block + 1) for at in seen for p in at if 0 <= p < seq)
     if pallas:
         assert stats["attn_kv_rows_read"] == hand < stats["attn_kv_rows_whole"] // 2
     else:
         assert stats["attn_kv_rows_read"] == stats["attn_kv_rows_whole"]
+
+
+def test_a_latent_engine_decodes_in_place_what_the_plane_read_decodes():
+    """The latent toy widened to whole tiles, through the engine with the
+    kernels in interpret mode: prompts on both sides of a block's edge go in
+    by 64-row chunks (the absorbed form over the plane), then one decode step
+    with two lanes parked reads the latent rows in place; its logits against
+    the forward's plane read (Pallas off) over the same cache and weights."""
+    import jax
+    import jax.numpy as jnp
+
+    import latent_toy
+    from distributed_llama_multiusers_tpu.models import deepseek
+    from distributed_llama_multiusers_tpu.ops import linear, pallas_attention
+
+    cfg, family, _ = latent_toy.wide_latent()
+    calls, real = [], pallas_attention.decode_attention
+    linear.set_pallas_interpret(True)
+    try:
+        engine, _ = latent_toy.engine(family, cfg, 5, dtype=jnp.bfloat16, lanes=4,
+                                      pipeline_depth=0, prefill_buckets=(64,))
+        assert engine.decode_attention_block == pallas_attention.block_rows(latent=True)
+        rng = np.random.default_rng(2)
+        at = {0: 600, 2: 40}  # lane 0 holds two blocks, lane 2 one; 1 and 3 stand parked
+        for lane, n in at.items():
+            engine.prefill(lane, [int(x) for x in rng.integers(2, cfg["vocab_size"], size=n)])
+        tokens, positions = latent_toy.park(engine, {lane: (7 + lane, n) for lane, n in at.items()})
+        cache = jax.tree_util.tree_map(jnp.copy, engine.cache)
+        deepseek.decode_attention = lambda *a, **kw: calls.append(kw) or real(*a, **kw)
+        logits = np.asarray(engine.decode(tokens, positions)[0], np.float32)
+    finally:
+        deepseek.decode_attention = real
+        linear.set_pallas_interpret(False)
+    # layer 0 before the scan and the scan's body, each traced once
+    assert len(calls) == 2 and all(kw["latent"] for kw in calls)
+    want, _ = deepseek.deepseek_forward(
+        engine.config, engine.params, jnp.asarray(tokens)[:, None], jnp.asarray(positions)[:, None],
+        cache)
+    want = np.asarray(want, np.float32)[:, 0]
+    live = sorted(at)
+    assert np.isfinite(logits[live]).all()
+    assert np.abs(logits[live] - want[live]).max() <= 2e-2 * np.abs(want[live]).max()
 
 
 def _llama_engine(**kw):

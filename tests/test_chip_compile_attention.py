@@ -1,5 +1,6 @@
 """Attention in place (ops/pallas_attention.py: decode width, PR 32; the
-layer-pattern block's merged stack, PR 36; prefill width, PR 51), compiled for
+layer-pattern block's merged stack, PR 36; prefill width, PR 51; latent rows,
+PR 59), compiled for
 a described v5e (tests/chip_compile_util.py)."""
 
 import jax
@@ -43,6 +44,32 @@ def test_decode_attention_compiles_for_v5e(v5e, lanes, n_heads, n_kv):
     assert "tpu_custom_call" in hlo and "decode_attention" in hlo
     # merging (S, n_kv) for the kernel moved no byte of either stack
     assert not _cache_sized_results(hlo, STACK_LAYERS, lanes, 2048, n_kv)
+
+
+def test_latent_decode_attention_compiles_for_v5e(v5e):
+    """Mosaic takes the latent form (PR 59) at Kanana's cell shapes: 32 lanes
+    of 2048 positions, 32 heads against a 512-wide latent row and its 128-wide
+    rope leaf, the stacks of a few layers as the carry holds them, the layer
+    and the work list traced; and neither stack is moved for it."""
+    from distributed_llama_multiusers_tpu.ops import pallas_attention as pa
+
+    lanes, n_heads, rank, rope_leaf = 32, 32, 512, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    latent = sds((STACK_LAYERS, lanes, 2048, rank), jnp.bfloat16)
+    rope = sds((STACK_LAYERS, lanes, 2048, rope_leaf), jnp.bfloat16)
+    assert pa.supports(latent, n_heads, None, rope, latent=True)
+
+    def attend(q, c, r, layer, positions):
+        return pa.decode_attention(
+            q, c, r, layer, pa.lane_blocks(positions, 2048), 192 ** -0.5, latent=True)
+
+    hlo = jax.jit(attend).lower(
+        sds((lanes, n_heads, rank + rope_leaf), jnp.bfloat16), latent, rope,
+        sds((), jnp.int32), sds((lanes,), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo and "decode_attention" in hlo
+    assert not _results_of_shape(
+        hlo, rf"(?:bf16|f32)\[(?:{STACK_LAYERS},|1,)?{lanes},2048,(?:{rank}|{rope_leaf})\]")
 
 
 @pytest.mark.parametrize("in_place", [True, False],

@@ -14,6 +14,7 @@ from chip_compile_util import (  # noqa: F401  (v5e, v5e_devices: the fixtures)
     DEFAULT_MODE,
     _lane_splits,
     _pattern_decode_hlo,
+    _results_of_shape,
     _three_layer_decode_hlo,
     engine_scales,
     v5e,
@@ -126,22 +127,17 @@ def test_decode_forward_reads_scale_tiles_out_of_the_stack_for_v5e(v5e, monkeypa
         r"(?!parameter\(|get-tuple-element\(|bitcast\()\S+?\(", hlo)
 
 
-def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, monkeypatch):
-    """Three layers (one dense, two routed) of the benchmark's latent block at
-    its published widths, one row a lane, the cache donated: the kernels are
-    there (wq, wkva, wo and the dense FFN or the grouped and shared experts,
-    the head), the latent stack is the result of its in-place scatters alone
-    (no copy, no relayout: a size-one head axis cost four whole-stack copies,
-    PR 33), and no expert plane leaves its stack."""
-    import re
-
+def _latent_decode_hlo(v5e, monkeypatch, lanes=32, seq=512):
+    """The optimized HLO of three layers (one dense, two routed) of the
+    benchmark's latent block at its published widths, one row a lane, the
+    cache donated; and its dimensions."""
     from distributed_llama_multiusers_tpu.models import deepseek
     from distributed_llama_multiusers_tpu.models.config import LlamaConfig
     from distributed_llama_multiusers_tpu.models.llama import KVCache
     from distributed_llama_multiusers_tpu.quants.packed import Q40Experts
 
     monkeypatch.setattr(linear, "_pallas_q40_matmul", lambda: pq.q40_matmul_pallas)
-    L, Lm, E, d, lanes, seq, vocab = 3, 2, 128, 2048, 32, 512, 8192
+    L, Lm, E, d, vocab = 3, 2, 128, 2048, 8192
     cfg = LlamaConfig(
         dim=d, hidden_dim=6144, n_layers=L, n_heads=32, n_kv_heads=32, vocab_size=vocab,
         seq_len=seq, norm_epsilon=1e-6, n_experts=E, n_active_experts=6, kv_lora_rank=512,
@@ -176,15 +172,75 @@ def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, 
     hlo = jax.jit(
         lambda p, t, c: deepseek.deepseek_forward(cfg, p, t, t, c), donate_argnums=(2,)
     ).lower(params, tok, cache).compile().as_text()
-    # layer 0: wq, wkva, wo, w1, w3, w2; the scan's body: wq, wkva, wo, three
-    # grouped products, the shared experts' three; the head
-    assert hlo.count("tpu_custom_call") == 16
+    return hlo, dict(L=L, E=E, lanes=lanes, seq=seq, heads=32)
+
+
+def test_latent_decode_forward_copies_no_cache_and_no_expert_stack_for_v5e(v5e, monkeypatch):
+    """Three layers (one dense, two routed) of the benchmark's latent block at
+    its published widths, one row a lane, the cache donated: the kernels are
+    there (wq, wkva, wo, since PR 59 the decode attention that reads the
+    latent rows in place, and the dense FFN or the grouped and shared experts;
+    the head), the latent stack is the result of its in-place scatters alone
+    (no copy, no relayout: a size-one head axis cost four whole-stack copies,
+    PR 33), and no expert plane leaves its stack."""
+    import re
+
+    hlo, dims = _latent_decode_hlo(v5e, monkeypatch)
+    L, E, lanes, seq = (dims[k] for k in ("L", "E", "lanes", "seq"))
+    # layer 0: wq, wkva, the decode attention, wo, w1, w3, w2; the scan's body:
+    # wq, wkva, the decode attention, wo, three grouped products, the shared
+    # experts' three; the head
+    assert hlo.count("tpu_custom_call") == 18
     stack = rf"bf16\[{L},{lanes},{seq},512\]"
     # (a stack this small XLA may stage whole in fast memory by copy-start /
     # copy-done of its own, as the Llama block's test above notes; a plain
     # copy of it is what is looked for)
     assert not re.search(rf"= {stack}\S* copy\(", hlo)
     assert f"= u8[{E},1024,768]" not in hlo and f"= u8[{E},384,2048]" not in hlo
+
+
+def _latent_plane_results(hlo: str, L: int, lanes: int, seq: int, heads: int) -> tuple[list, list]:
+    """(what makes an array of the size of one layer's latent or rope plane,
+    bf16 or float32, or of a stack; the float32 ``[lanes, heads, S]`` score
+    and probability tensors, in any order of their axes)."""
+    import re
+
+    planes = _results_of_shape(
+        hlo, rf"(?:bf16|f32)\[(?:{L},|1,)?{lanes},{seq},(?:512|128)\]")
+    scores = sorted(set(re.findall(
+        rf"f32\[{lanes},(?:1,)?(?:{heads},(?:1,)?{seq}|{seq},(?:1,)?{heads})\]", hlo)))
+    return planes, scores
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel_reads_in_place", "control_plane_reads"])
+def test_latent_decode_forward_reads_no_latent_plane_for_v5e(v5e, monkeypatch, in_place):
+    """The same three layers at the cell's cache (32 lanes of 2048 positions):
+    a ``decode_attention`` kernel in layer 0 and in the scan's body, and
+    nothing has a ``[32, 2048, 512]`` latent plane, a ``[32, 2048, 128]`` rope
+    plane or a stack as its result but the in-place appends, nor is there a
+    float32 ``[32, 32, 2048]`` score: the kernel is handed the carry. The
+    control patches the predicate off, as the program was before PR 59, and
+    shows what the check looks for: each layer's planes read out of the stack
+    and scores over every position (5.45 of Kanana's 22.45 ms decode step on a
+    v5e: PERF.md section 6, PR 59)."""
+    import re
+
+    from distributed_llama_multiusers_tpu.models import deepseek
+
+    if not in_place:
+        monkeypatch.setattr(deepseek, "decode_attention_engages", lambda *a, **kw: False)
+    hlo, dims = _latent_decode_hlo(v5e, monkeypatch, seq=2048)
+    kernels = len(re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call".*decode_attention', hlo))
+    planes, scores = _latent_plane_results(
+        hlo, dims["L"], dims["lanes"], dims["seq"], dims["heads"])
+    if in_place:
+        assert kernels == 2 and planes == [] and scores == [], (kernels, planes, scores)
+    else:
+        assert kernels == 0 and scores, scores
+        reads = [m for m in planes if "dynamic-slice" in m or "fusion" in m or "convert" in m]
+        assert len(reads) >= 2, planes  # the latent plane, in layer 0 and in the body
 
 
 def test_pattern_decode_forward_copies_no_cache_no_state_and_no_expert_stack_for_v5e(v5e, monkeypatch):
@@ -285,3 +341,4 @@ def test_sparse_latent_chunk_compiles_for_v5e_and_gathers_in_blocks(v5e, monkeyp
     assert not re.search(rf"\[(1,)?1024,{c.index_topk},(512|128|640)\]", hlo)
     assert re.search(rf"\[(1,)?256,{c.index_topk},512\]", hlo)  # one block's gathered rows
     assert " sort(" in hlo and "approx" not in hlo.lower()
+

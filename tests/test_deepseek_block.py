@@ -28,9 +28,10 @@ def test_absorbed_attention_is_the_expanded_attention():
     positions = jnp.array([[4, 5, 6], [7, 8, 9]])
     mask = jnp.arange(s)[None, None, :] <= positions[:, :, None]
     scale = 1.0 / np.sqrt(nope + rope)
-    got = deepseek.absorbed_attention(
-        q_nope, q_pe, jnp.transpose(wkvb[..., :nope], (1, 2, 0)),
-        jnp.transpose(wkvb[..., nope:], (1, 0, 2)), c, r, mask, scale)
+    q_abs = deepseek.absorb_queries(q_nope, jnp.transpose(wkvb[..., :nope], (1, 2, 0)))
+    got = deepseek.expand_values(
+        deepseek.latent_plane_attention(q_abs, q_pe, c, r, mask, scale),
+        jnp.transpose(wkvb[..., nope:], (1, 0, 2)))
     # expanded, as published: every head's own keys and values
     kv = jnp.einsum("bsc,chx->bshx", c, wkvb)
     k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(r[:, :, None, :], (b, s, h, rope))], -1)

@@ -1,5 +1,6 @@
-"""Attention in place (ops/pallas_attention.py: the decode kernel, and at the
-end of the file the prefill kernel of PR 51) against the dense path.
+"""Attention in place (ops/pallas_attention.py: the decode kernel, its latent
+form of PR 59, and at the end of the file the prefill kernel of PR 51) against
+the dense path.
 
 The kernel runs in interpret mode on the CPU, as tests/test_pallas_q40.py
 runs its kernel: that proves its arithmetic and its work list, not that it
@@ -231,6 +232,120 @@ def test_forward_takes_the_kernel_only_where_its_inputs_allow(monkeypatch):
             np.asarray(got[0], np.float32), np.asarray(want[0], np.float32))
     got, want = np.asarray(logits)[live], np.asarray(dense_logits)[live]
     assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
+
+
+# ---- latent rows: one key row a position, its own value (PR 59) --------------
+
+RANK, ROPE_LEAF, ROPE, LATENT_HEADS = 512, 128, 64, 32
+LATENT_SCALE = (128 + ROPE) ** -0.5
+LBLOCK = pa.block_rows(latent=True)  # taller than the heads' (PERF.md section 6, PR 59)
+LSEQ = 4 * LBLOCK
+# what a lane's position may be to its blocks: lanes of one step, by kind
+LATENT_POSITIONS = {
+    "inside_a_block": [77, LBLOCK + 5, 2 * LBLOCK + 77],
+    "a_blocks_last_row": [LBLOCK - 1, 2 * LBLOCK - 1, 3 * LBLOCK - 1],
+    "a_blocks_first_row": [0, LBLOCK, 3 * LBLOCK],
+    "the_last_block": [LSEQ - 1, LSEQ - LBLOCK, LSEQ - 9],
+    "parked_beside_live": [LSEQ, 300, LSEQ + 5],
+    "every_lane_parked": [LSEQ, LSEQ, LSEQ],
+}
+
+
+def _latent_stack(lanes, seed):
+    """The absorbed and rotated queries side by side, and the two leaves of a
+    latent cache: the latent rows and their rope parts, zero past the rope
+    width as ``models/deepseek.py`` pads them."""
+    rng = np.random.default_rng(seed)
+    c = jnp.asarray(rng.standard_normal((LAYERS, lanes, LSEQ, RANK)), jnp.bfloat16)
+    r = jnp.asarray(rng.standard_normal((LAYERS, lanes, LSEQ, ROPE_LEAF)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((lanes, LATENT_HEADS, RANK + ROPE_LEAF)) * 0.3,
+                    jnp.bfloat16)
+    return q.at[..., RANK + ROPE:].set(0), c, r.at[..., ROPE:].set(0)
+
+
+def _latent_kernel(q, c, r, positions, layer=LAYER):
+    work = pa.lane_blocks(jnp.asarray(positions, jnp.int32), LSEQ, LBLOCK)
+    return np.asarray(pa.decode_attention(q, c, r, layer, work, LATENT_SCALE,
+                                          interpret=True, latent=True))
+
+
+def _latent_planes(q, c, r, positions, layer=LAYER):
+    from distributed_llama_multiusers_tpu.models import deepseek
+
+    mask = jnp.arange(LSEQ)[None, None, :] <= jnp.asarray(positions)[:, None, None]
+    return np.asarray(deepseek.latent_plane_attention(
+        q[:, None, :, :RANK], q[:, None, :, RANK:], c[layer], r[layer], mask, LATENT_SCALE))[:, 0]
+
+
+@pytest.mark.parametrize("kind", LATENT_POSITIONS, ids=list(LATENT_POSITIONS))
+def test_latent_kernel_matches_the_plane_read_and_stale_rows_reach_nothing(kind):
+    """`o~` of `models/deepseek.py`'s plane read on a bf16 cache at rank 512
+    beside a rope leaf of 128, with NaN in every row past each lane's position
+    (its last block's tail, the blocks it never fetches, every row of a parked
+    lane): a live lane agrees, a parked lane is zeros."""
+    positions = np.asarray(LATENT_POSITIONS[kind], np.int32)
+    q, c, r = _latent_stack(len(positions), seed=len(kind))
+    want = _latent_planes(q, c, r, positions)
+    stale = (np.arange(LSEQ)[None, :] > positions[:, None])[None, :, :, None]
+    got = _latent_kernel(q, jnp.where(stale, jnp.nan, c), jnp.where(stale, jnp.nan, r), positions)
+    assert got.shape == (len(positions), LATENT_HEADS, RANK) and np.isfinite(got).all()
+    live = positions < LSEQ
+    for b in np.flatnonzero(live):
+        np.testing.assert_allclose(
+            got[b], want[b], rtol=0, atol=6e-3 * np.abs(want[b]).max(),
+            err_msg=f"lane {b} at position {positions[b]}")
+    assert not got[~live].any()
+
+
+def test_a_latent_row_of_its_own_is_its_own_value():
+    """Position 0: a softmax over one score, so `o~` is the latent row itself,
+    bit for bit, for every head."""
+    q, c, r = _latent_stack(2, seed=4)
+    got = _latent_kernel(q, c, r, [0, 0])
+    np.testing.assert_array_equal(
+        got, np.broadcast_to(np.asarray(c[LAYER, :, 0], np.float32)[:, None, :], got.shape))
+
+
+@pytest.mark.parametrize("k,v,dtype,want", [
+    ((LAYERS, 2, SEQ, 512), (LAYERS, 2, SEQ, 128), jnp.bfloat16, True),  # Kanana's leaves
+    ((LAYERS, 2, SEQ, 128), (LAYERS, 2, SEQ, 128), jnp.bfloat16, True),
+    ((LAYERS, 2, SEQ, 64), (LAYERS, 2, SEQ, 128), jnp.bfloat16, False),  # tests/latent_toy.py's rank
+    ((LAYERS, 2, SEQ, 512), (LAYERS, 2, SEQ, 64), jnp.bfloat16, False),  # an unpadded rope leaf
+    ((LAYERS, 2, SEQ, 512), (LAYERS, 2, SEQ, 128), jnp.float32, False),
+    ((LAYERS, 2, SEQ, 512), (LAYERS, 2, SEQ, 128), jnp.float8_e4m3fn, False),
+    ((LAYERS, 2, 2000, 512), (LAYERS, 2, 2000, 128), jnp.bfloat16, False),  # not whole blocks
+    ((LAYERS, 2, 3 * BLOCK, 512), (LAYERS, 2, 3 * BLOCK, 128), jnp.bfloat16, False),  # ... of 512
+    ((LAYERS, 2, SEQ, 512), (LAYERS, 3, SEQ, 128), jnp.bfloat16, False),  # leaves of unlike lanes
+    ((LAYERS, 2, SEQ, 1536), (LAYERS, 2, SEQ, 128), jnp.bfloat16, False),  # past the budgeted block
+    ((LAYERS, 2, SEQ, 512), None, jnp.bfloat16, False),  # no rope leaf
+    ((LAYERS, 2, SEQ, 4, 128), (LAYERS, 2, SEQ, 4, 128), jnp.bfloat16, False),  # heads, not rows
+], ids=["rank512", "rank128", "rank64", "rope64", "f32", "f8", "ctx2000", "ctx768", "unlike_lanes",
+        "rank1536", "no_rope_leaf", "rank5"])
+def test_supports_says_which_latent_caches_are_taken(k, v, dtype, want):
+    leaf = lambda shape: shape and jax.ShapeDtypeStruct(shape, dtype)
+    assert pa.supports(leaf(k), LATENT_HEADS, None, leaf(v), latent=True) is want
+
+
+def test_a_latent_cache_is_not_taken_for_merged_heads_nor_merged_heads_for_one():
+    """A rank-4 leaf does not say what its row holds: Kanana's `[.., 512]` and
+    `[.., 128]` leaves would pass for merged K / V rows of 32 heads 16 and 4
+    wide. The caller says the form, and the engagement question keeps an
+    indexer's cache (a third leaf: the rows are chosen, not read in place) out
+    of the latent one."""
+    from distributed_llama_multiusers_tpu.models import deepseek
+
+    k = jax.ShapeDtypeStruct((LAYERS, 2, SEQ, 512), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((LAYERS, 2, SEQ, 128), jnp.bfloat16)
+    assert pa.supports(k, 32, 32, v) and pa.supports(k, 32, None, v, latent=True)
+    linear.set_pallas_interpret(True)
+    try:
+        assert llama.decode_attention_engages(llama.KVCache(k, v), None, 32, latent=True)
+        assert not llama.decode_attention_engages(
+            deepseek.IndexedLatentCache(k, v, v), None, 32, latent=True)
+        assert not llama.decode_attention_engages(llama.KVCache(k, v), object(), 32, latent=True)
+    finally:
+        linear.set_pallas_interpret(False)
+    assert not llama.decode_attention_engages(llama.KVCache(k, v), None, 32, latent=True)  # the CPU
 
 
 # ---- more than one row a lane: the prefill kernel (PR 51) -------------------
